@@ -83,11 +83,7 @@ def main():
 
     import chainermn_tpu
 
-    here = os.path.dirname(os.path.abspath(__file__))
-    cache = os.path.join(os.path.dirname(here), '.jax_compile_cache')
-    jax.config.update('jax_compilation_cache_dir', cache)
-    jax.config.update('jax_persistent_cache_min_compile_time_secs', 1.0)
-    jax.config.update('jax_persistent_cache_min_entry_size_bytes', -1)
+    chainermn_tpu.utils.enable_compilation_cache()
 
     n_dev = jax.device_count()
     inter = 2 if n_dev % 2 == 0 and n_dev > 1 else 1

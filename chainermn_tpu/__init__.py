@@ -16,10 +16,6 @@ TPU-native extras):
 - :func:`create_multi_node_optimizer` -- gradient-allreduce optimizer wrapper
 """
 
-from chainermn_tpu.utils import jax_compat as _jax_compat
-
-_jax_compat.ensure()
-
 from chainermn_tpu.communicators import create_communicator  # noqa
 from chainermn_tpu.communicators.base import CommunicatorBase  # noqa
 from chainermn_tpu.dataset import scatter_dataset  # noqa

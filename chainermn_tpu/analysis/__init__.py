@@ -6,7 +6,7 @@ TPU-native rebuild the sharding decisions live in traced code, so the
 same invariants are PROVEN statically: each communicator strategy's
 collective surface and each train step is traced with
 ``jax.make_jaxpr`` (no device computation, CPU-only) and the jaxpr is
-walked -- recursing into ``pjit``/``shard_map``/``scan``/``cond``
+walked -- recursing into ``jit``/``shard_map``/``scan``/``cond``
 sub-jaxprs -- against the rule catalogue in
 :mod:`chainermn_tpu.analysis.rules` (see ``docs/static_analysis.md``).
 

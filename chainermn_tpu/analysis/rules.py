@@ -285,7 +285,7 @@ def rule_donation(ctx):
     if ctx.jaxpr is None:
         return out
     for eqn, _path in walker.iter_eqns(ctx.jaxpr):
-        if eqn.primitive.name != 'pjit':
+        if eqn.primitive.name != 'jit':
             continue
         donated = eqn.params.get('donated_invars')
         if not donated or not any(donated):
@@ -663,14 +663,24 @@ def rule_cross_axis_chain(ctx):
 # replicated, or resharded to another axis) cannot alias -- XLA must
 # materialize the resharded output next to the donated buffer and
 # the donation frees nothing.  The shard_map equation carries the
-# in/out axis mappings (``in_names``/``out_names``), so the mismatch
-# is statically visible.
+# in/out ``PartitionSpec``s (``in_specs``/``out_specs``), so the
+# mismatch is statically visible.
+def _spec_axes(spec):
+    """``{dim: (axis names)}`` of a ``PartitionSpec`` (sharded dims
+    only): two specs place a buffer alike iff these are equal."""
+    out = {}
+    for dim, entry in enumerate(spec):
+        if entry is not None:
+            out[dim] = entry if isinstance(entry, tuple) else (entry,)
+    return out
+
+
 def rule_tp_donation(ctx):
     out = []
     if ctx.jaxpr is None or ctx.plan_axes is None:
         return out
     for eqn, _path in walker.iter_eqns(ctx.jaxpr):
-        if eqn.primitive.name != 'pjit':
+        if eqn.primitive.name != 'jit':
             continue
         donated = eqn.params.get('donated_invars')
         if not donated or not any(donated):
@@ -682,27 +692,26 @@ def rule_tp_donation(ctx):
         for inner, _p in walker.iter_eqns(sub):
             if inner.primitive.name != 'shard_map':
                 continue
-            in_names = inner.params.get('in_names')
-            out_names = inner.params.get('out_names')
-            if in_names is None or out_names is None:
-                continue  # primitive layout changed; stay silent
+            in_names = [_spec_axes(s) for s in inner.params['in_specs']]
+            out_names = [_spec_axes(s)
+                         for s in inner.params['out_specs']]
             out_sig = []
             for var, names in zip(inner.outvars, out_names):
                 aval = getattr(var, 'aval', None)
                 if aval is not None:
                     out_sig.append((tuple(aval.shape),
-                                    str(aval.dtype), dict(names)))
+                                    str(aval.dtype), names))
             for pos, (var, names) in enumerate(
                     zip(inner.invars, in_names)):
                 arg_i = donated_vars.get(id(var))
-                if arg_i is None or not dict(names):
+                if arg_i is None or not names:
                     continue  # not donated, or replicated anyway
                 aval = var.aval
                 sig = (tuple(aval.shape), str(aval.dtype))
                 matches = [o for o in out_sig if o[:2] == sig]
                 if not matches:
                     continue  # SL005's finding, not ours
-                if not any(o[2] == dict(names) for o in matches):
+                if not any(o[2] == names for o in matches):
                     out.append(ctx.finding(
                         'SL012', SEV_WARNING,
                         'donated argument %d (%s%s, sharded %r into '
@@ -710,8 +719,7 @@ def rule_tp_donation(ctx):
                         'different sharding (%s): the resharded '
                         'output cannot alias the donated shard and '
                         'the donation frees nothing'
-                        % (arg_i, aval.dtype, list(aval.shape),
-                           dict(names),
+                        % (arg_i, aval.dtype, list(aval.shape), names,
                            [o[2] for o in matches]), inner))
     return out
 

@@ -2,7 +2,7 @@
 
 One walker for every rule: :func:`iter_eqns` yields each equation of a
 (closed) jaxpr depth-first, recursing into EVERY sub-jaxpr an equation
-carries in its params -- ``pjit``'s ``jaxpr``, ``shard_map``'s
+carries in its params -- ``jit``'s ``jaxpr``, ``shard_map``'s
 ``jaxpr``, ``scan``'s ``jaxpr``, ``cond``'s ``branches``,
 ``while``'s ``cond_jaxpr``/``body_jaxpr``, ``custom_*_call``'s
 ``call_jaxpr``/``fun_jaxpr``, remat, ...  Discovery is structural
@@ -12,11 +12,7 @@ change here.
 """
 
 import jax
-
-try:  # jax >= 0.4: public-ish location used by jax itself
-    from jax._src import source_info_util as _src_info
-except ImportError:  # pragma: no cover - internals moved
-    _src_info = None
+from jax._src import source_info_util as _src_info
 
 #: collectives that REDUCE values across an axis (the topology rule's
 #: subjects).  ``pmean``/``psum_scatter`` trace to psum/reduce_scatter.
@@ -26,8 +22,8 @@ REDUCE_PRIMS = ('psum', 'pmax', 'pmin', 'reduce_scatter',
 MOVE_PRIMS = ('all_gather', 'ppermute', 'pbroadcast', 'all_to_all')
 COLLECTIVE_PRIMS = REDUCE_PRIMS + MOVE_PRIMS
 #: primitives that round-trip through the host at run time
-CALLBACK_PRIMS = ('pure_callback', 'debug_callback', 'io_callback',
-                  'callback')
+CALLBACK_PRIMS = ('pure_callback', 'debug_callback', 'debug_print',
+                  'io_callback', 'callback')
 
 
 def raw_jaxpr(j):
@@ -80,12 +76,9 @@ def eqn_source(eqn):
     """``"file.py:line"`` of the user frame that emitted ``eqn``, or
     ``None`` when source info is unavailable."""
     info = getattr(eqn, 'source_info', None)
-    if info is None or _src_info is None:
+    if info is None:
         return None
-    try:
-        frame = _src_info.user_frame(info)
-    except Exception:
-        frame = None
+    frame = _src_info.user_frame(info.traceback)
     if frame is None:
         return None
     return '%s:%d' % (frame.file_name, frame.start_line)

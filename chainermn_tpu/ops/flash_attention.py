@@ -491,14 +491,16 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 # against the sequence's cached K/V -- pure HBM bandwidth, no reuse.
 # The decode kernel therefore reuses the forward kernel's
 # online-softmax recurrence (running m/l/acc in VMEM scratch) but
-# carries a single query row per grid cell, streams the cache in ONE
-# HBM pass, and masks by a PER-SEQUENCE dynamic length (each cache
+# carries one query row per head per grid cell, streams the cache in
+# ONE HBM pass, and masks by a PER-SEQUENCE dynamic length (each cache
 # slot is filled to a different depth under continuous batching).
 # Forward-only by design: decode is inference, there is no backward.
+# On the TPU the slab cache is read by the PAGED kernel below through
+# an identity page table (_decode_pallas).
 #
 # int8 KV cache: pass int8 k/v plus per-(position, head) symmetric
 # scales (precision.quantize_kv) and the dequant multiply runs in
-# VMEM right before each tile's matmul -- the HBM bytes the step is
+# VMEM right before each tile's products -- the HBM bytes the step is
 # bound by are the int8 ones.
 # ----------------------------------------------------------------------
 
@@ -574,108 +576,30 @@ def _decode_blockwise_jnp(q, k, v, lengths, scale, block_k,
     return (acc / l_safe[:, None]).astype(q.dtype)
 
 
-def _decode_kernel(len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                   o_ref, m_ref, l_ref, acc_ref, *, scale, block_k,
-                   quantized):
-    """One (batch*head, key-block) grid cell: a single query row's
-    online-softmax update against one cache tile.  The running
-    (m, l, acc) state lives in VMEM scratch across the sequential
-    key-block axis; the per-sequence length arrives via SMEM and
-    gates both the mask and the whole-tile skip."""
-    import jax.experimental.pallas as pl
-
-    kj = pl.program_id(1)
-    n_kv = pl.num_programs(1)
-
-    @pl.when(kj == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    length = len_ref[0, 0]
-
-    # tiles entirely beyond this sequence's fill level contribute
-    # nothing; the dynamic pl.when skips their VPU/MXU work
-    @pl.when(kj * block_k < length)
-    def _accum():
-        q = q_ref[0].astype(jnp.float32) * scale       # (1, D)
-        k = k_ref[0].astype(jnp.float32)               # (block_k, D)
-        v = v_ref[0].astype(jnp.float32)
-        if quantized:
-            k = k * ks_ref[0].astype(jnp.float32)
-            v = v * vs_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)        # (1, block_k)
-        k_pos = (kj * block_k
-                 + lax.broadcasted_iota(jnp.int32, (1, block_k), 1))
-        s = jnp.where(k_pos < length, s, NEG_INF)
-        m_prev = m_ref[...]                            # (1, 128)
-        l_prev = l_ref[...]
-        m_new = jnp.maximum(m_prev,
-                            jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, :1])
-        m_ref[...] = m_new
-        l_ref[...] = (l_prev * alpha
-                      + jnp.sum(p, axis=-1, keepdims=True))
-        acc_ref[...] = acc_ref[...] * alpha[:, :1] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    @pl.when(kj == n_kv - 1)
-    def _finalize():
-        l_safe = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l_safe[:, :1]).astype(o_ref.dtype)
-
-
 def _decode_pallas(q, k, v, lengths, scale, block_k,
                    k_scale=None, v_scale=None):
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+    """Slab decode through the paged kernel: a (B, S, H, D) cache IS a
+    page pool of ``S / block_k`` pages per sequence (a leading-dim
+    reshape, no copy) read through the identity page table -- one
+    Mosaic kernel serves both cache layouts, which is what makes
+    ``page_size == block_k`` arithmetic identical by construction."""
+    b, t_kv = k.shape[:2]
+    pad_k = (-t_kv) % block_k
+    n_blocks = (t_kv + pad_k) // block_k
 
-    bh, t_kv, d = k.shape
-    quantized = k_scale is not None
-    q3 = q[:, None, :]                                 # (bh, 1, d)
-    len2 = lengths.astype(jnp.int32)[:, None]          # (bh, 1)
-    if quantized:
-        ks3 = k_scale[..., None].astype(jnp.float32)   # (bh, S, 1)
-        vs3 = v_scale[..., None].astype(jnp.float32)
-    else:
-        # zero-size placeholders keep one kernel signature; the
-        # quantized flag compiles the dequant multiply in or out
-        ks3 = jnp.zeros((bh, t_kv, 1), jnp.float32)
-        vs3 = ks3
-    out = pl.pallas_call(
-        functools.partial(_decode_kernel, scale=scale,
-                          block_k=block_k, quantized=quantized),
-        grid=(bh, t_kv // block_k),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda b, j: (b, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, d), lambda b, j: (b, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, 1), lambda b, j: (b, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, 1), lambda b, j: (b, j, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((1, 1, d), lambda b, j: (b, 0, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((bh, 1, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((1, 128), jnp.float32),         # m (replicated)
-            pltpu.VMEM((1, 128), jnp.float32),         # l (replicated)
-            pltpu.VMEM((1, d), jnp.float32),           # acc
-        ],
-        interpret=interpret_flag(),
-    )(len2, q3, k, v, ks3, vs3)
-    return out[:, 0, :]
+    def pages(x):
+        if x is None:
+            return None
+        if pad_k:
+            x = jnp.pad(x, ((0, 0), (0, pad_k))
+                        + ((0, 0),) * (x.ndim - 2))
+        return x.reshape((b * n_blocks, block_k) + x.shape[2:])
+
+    tables = (jnp.arange(b, dtype=jnp.int32)[:, None] * n_blocks
+              + jnp.arange(n_blocks, dtype=jnp.int32)[None, :])
+    return _decode_paged_pallas(
+        q, pages(k), pages(v), tables, lengths.astype(jnp.int32),
+        scale, pages(k_scale), pages(v_scale))
 
 
 def flash_attention_decode(q, k, v, lengths, scale=None,
@@ -715,6 +639,9 @@ def flash_attention_decode(q, k, v, lengths, scale=None,
     if scale is None:
         scale = d ** -0.5
     block_k = min(block_k, max(t_kv, 1))
+    if pallas_mode() != 'fallback':
+        return _decode_pallas(q, k, v, lengths, scale, block_k,
+                              k_scale, v_scale)
 
     def merge(x):
         # (B, S, H, D) -> (B*H, S, D)
@@ -736,12 +663,8 @@ def flash_attention_decode(q, k, v, lengths, scale=None,
         if ksm is not None:
             ksm = jnp.pad(ksm, ((0, 0), (0, pad_k)))
             vsm = jnp.pad(vsm, ((0, 0), (0, pad_k)))
-    if pallas_mode() == 'fallback':
-        out = _decode_blockwise_jnp(qm, km, vm, lengths_bh, scale,
-                                    block_k, ksm, vsm)
-    else:
-        out = _decode_pallas(qm, km, vm, lengths_bh, scale, block_k,
-                             ksm, vsm)
+    out = _decode_blockwise_jnp(qm, km, vm, lengths_bh, scale, block_k,
+                                ksm, vsm)
     return out.reshape(b, h, d)
 
 
@@ -842,15 +765,21 @@ def _decode_paged_blockwise_jnp(q, k, v, page_tables, lengths, scale,
 
 def _decode_paged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref,
                          ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref,
-                         *, scale, page_size, n_heads, quantized):
-    """One (batch*head, page) grid cell: a single query row's
-    online-softmax update against one PAGE of the pool.  The page
-    table and per-sequence lengths are scalar-prefetched (SMEM), so
-    the k/v block specs fetch ``page_tables[b, j]`` directly -- the
-    indirection lives in the DMA descriptor, not the compute."""
+                         *, scale, page_size, quantized):
+    """One (sequence, page) grid cell: the online-softmax update of
+    ALL heads' single query rows against one PAGE of the pool.  The
+    page table and per-sequence lengths are scalar-prefetched (SMEM),
+    so the k/v block specs fetch ``page_tables[b, j]`` directly -- the
+    indirection lives in the DMA descriptor, not the compute.
+
+    A page arrives as its natural (page_size, H, D) tile (Mosaic wants
+    the last two block dims whole), so the one-row-per-head products
+    run on the VPU -- multiply by the broadcast query, reduce over the
+    lane (D) axis -- instead of H separate M=1 matmuls; the softmax
+    state is per head, (H, 1) / (H, D) in VMEM scratch."""
     import jax.experimental.pallas as pl
 
-    bh = pl.program_id(0)
+    b = pl.program_id(0)
     j = pl.program_id(1)
     n_pages = pl.num_programs(1)
 
@@ -860,41 +789,34 @@ def _decode_paged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    length = len_ref[bh // n_heads]
+    length = len_ref[b]
 
     # pages entirely beyond this sequence's fill level contribute
     # nothing; their fetch was clamped to the live frontier (elided)
     @pl.when(j * page_size < length)
     def _accum():
-        q = q_ref[0].astype(jnp.float32) * scale       # (1, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)      # (ps, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0].astype(jnp.float32) * scale       # (H, D)
+        k = k_ref[0].astype(jnp.float32)               # (ps, H, D)
+        v = v_ref[0].astype(jnp.float32)
         if quantized:
-            k = k * ks_ref[0].astype(jnp.float32)
-            v = v * vs_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)        # (1, ps)
+            k = k * ks_ref[0]                          # (ps, H, 1)
+            v = v * vs_ref[0]
+        s = jnp.sum(k * q[None], axis=-1, keepdims=True)  # (ps, H, 1)
         k_pos = (j * page_size
-                 + lax.broadcasted_iota(jnp.int32, (1, page_size), 1))
+                 + lax.broadcasted_iota(jnp.int32, s.shape, 0))
         s = jnp.where(k_pos < length, s, NEG_INF)
-        m_prev = m_ref[...]                            # (1, 128)
-        l_prev = l_ref[...]
-        m_new = jnp.maximum(m_prev,
-                            jnp.max(s, axis=-1, keepdims=True))
+        m_prev = m_ref[...]                            # (H, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, :1])
+        p = jnp.exp(s - m_new[None])
         m_ref[...] = m_new
-        l_ref[...] = (l_prev * alpha
-                      + jnp.sum(p, axis=-1, keepdims=True))
-        acc_ref[...] = acc_ref[...] * alpha[:, :1] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=0)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.sum(p * v, axis=0)
 
     @pl.when(j == n_pages - 1)
     def _finalize():
         l_safe = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l_safe[:, :1]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
 
 
 def _decode_paged_pallas(q, k, v, page_tables, lengths, scale,
@@ -903,62 +825,52 @@ def _decode_paged_pallas(q, k, v, page_tables, lengths, scale,
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, d = q.shape
-    n_pool, ps = k.shape[0], k.shape[1]
+    ps = k.shape[1]
     n_max = page_tables.shape[1]
     quantized = k_scale is not None
-    q3 = q.reshape(b * h, 1, d)
-
-    def page_at(i, j, table_ref, len_ref):
-        # clamp the fetched page at the live frontier: dead steps
-        # re-fetch the last live page, which Pallas elides
-        seq = i // h
-        last = jnp.maximum((len_ref[seq] - 1) // ps, 0)
-        return table_ref[seq, jnp.minimum(j, last)]
 
     def kv_ix(i, j, table_ref, len_ref):
-        return (page_at(i, j, table_ref, len_ref), 0, i % h, 0)
-
-    def scale_ix(i, j, table_ref, len_ref):
-        return (page_at(i, j, table_ref, len_ref), 0, i % h)
-
-    def scale_ix0(i, j, table_ref, len_ref):
-        return (page_at(i, j, table_ref, len_ref), 0, 0)
+        # clamp the fetched page at the live frontier: dead steps
+        # re-fetch the last live page, which Pallas elides
+        last = jnp.maximum((len_ref[i] - 1) // ps, 0)
+        return (table_ref[i, jnp.minimum(j, last)], 0, 0, 0)
 
     if quantized:
-        ks, vs = k_scale, v_scale
-        ks_ix = vs_ix = scale_ix
+        # (P, ps, H) -> (P, ps, H, 1): the scale tile lines up with
+        # the page tile's (H, D) minor dims and broadcasts over lanes
+        ks = k_scale.astype(jnp.float32)[..., None]
+        vs = v_scale.astype(jnp.float32)[..., None]
+        scale_spec = pl.BlockSpec((1, ps, h, 1), kv_ix)
     else:
-        # zero-size-free placeholders keep one kernel signature; the
+        # one-tile placeholder keeps one kernel signature; the
         # quantized flag compiles the dequant multiply in or out
-        ks = jnp.zeros((n_pool, ps, 1), jnp.float32)
-        vs = ks
-        ks_ix = vs_ix = scale_ix0
+        ks = vs = jnp.zeros((1, 1, h, 1), jnp.float32)
+        scale_spec = pl.BlockSpec((1, 1, h, 1),
+                                  lambda i, j, t, n: (0, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,       # page_tables, lengths
-        grid=(b * h, n_max),
+        grid=(b, n_max),
         in_specs=[
-            pl.BlockSpec((1, 1, d), lambda i, j, t, n: (i, 0, 0)),
-            pl.BlockSpec((1, ps, 1, d), kv_ix),
-            pl.BlockSpec((1, ps, 1, d), kv_ix),
-            pl.BlockSpec((1, ps, 1), ks_ix),
-            pl.BlockSpec((1, ps, 1), vs_ix),
+            pl.BlockSpec((1, h, d), lambda i, j, t, n: (i, 0, 0)),
+            pl.BlockSpec((1, ps, h, d), kv_ix),
+            pl.BlockSpec((1, ps, h, d), kv_ix),
+            scale_spec,
+            scale_spec,
         ],
-        out_specs=pl.BlockSpec((1, 1, d), lambda i, j, t, n: (i, 0, 0)),
+        out_specs=pl.BlockSpec((1, h, d), lambda i, j, t, n: (i, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((1, 128), jnp.float32),         # m (replicated)
-            pltpu.VMEM((1, 128), jnp.float32),         # l (replicated)
-            pltpu.VMEM((1, d), jnp.float32),           # acc
+            pltpu.VMEM((h, 1), jnp.float32),           # m
+            pltpu.VMEM((h, 1), jnp.float32),           # l
+            pltpu.VMEM((h, d), jnp.float32),           # acc
         ],
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_decode_paged_kernel, scale=scale,
-                          page_size=ps, n_heads=h,
-                          quantized=quantized),
+                          page_size=ps, quantized=quantized),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b * h, 1, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         interpret=interpret_flag(),
-    )(page_tables, lengths, q3, k, v, ks, vs)
-    return out.reshape(b, h, d)
+    )(page_tables, lengths, q, k, v, ks, vs)
 
 
 def flash_attention_decode_paged(q, k, v, page_tables, lengths,

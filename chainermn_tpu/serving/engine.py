@@ -7,17 +7,19 @@ The engine closes that hole with three mechanisms:
 
 - **Pre-lowered per-bucket executables.**  For every bucket edge the
   batcher can emit, the forward-only ``apply`` is compiled ONCE --
-  through the modern AOT path ``jax.jit(...).lower(...).compile()``
-  when the runtime has it (:func:`chainermn_tpu.utils.jax_compat.
-  aot_compile`), plain ``jit`` otherwise -- and stored keyed on the
-  bucket.  ``warmup()`` compiles all buckets eagerly so the first
-  request pays file-read latency, not trace latency.
-- **Persistent compilation cache.**  ``cache_dir`` points jax's
-  persistent compilation cache at a directory
-  (:func:`~chainermn_tpu.utils.jax_compat.enable_compilation_cache`),
-  so a RESTARTED engine's warmup deserializes executables instead of
-  re-tracing -- cold start becomes a file read.  The cache layout is
-  jax's own (one ``...-cache`` entry per executable fingerprint);
+  ahead of time, ``jax.jit(...).lower(...).compile()`` (a lowering
+  error is an error; ``aot=False`` asks for plain ``jit``) -- and
+  stored keyed on the bucket.  ``warmup()`` compiles all buckets
+  eagerly so no request ever pays a trace or a compile.
+- **Persistent compilation cache.**  Every engine turns jax's
+  persistent compilation cache on
+  (:func:`~chainermn_tpu.utils.platform.enable_compilation_cache`:
+  ``JAX_COMPILATION_CACHE_DIR`` where set, else the checkout's
+  ``.jax_compile_cache``), so a RESTARTED engine's warmup
+  deserializes executables instead of re-running XLA's compile
+  (tracing and lowering are paid again).  The cache layout is jax's
+  own (one
+  ``...-cache`` entry per executable fingerprint);
   ``docs/serving.md`` documents it.
 - **No-recompile runtime guard.**  The SL007 recompilation rule's
   signature machinery (:func:`chainermn_tpu.analysis.walker.
@@ -43,7 +45,6 @@ same phases plus per-request ``serve_latency_seconds`` and per-batch
 averaged percentiles.
 """
 
-import os
 import threading
 import time
 
@@ -56,7 +57,7 @@ from chainermn_tpu import telemetry as _telemetry
 from chainermn_tpu.analysis.walker import abstract_signature
 from chainermn_tpu.serving.batcher import bucket_edges
 from chainermn_tpu.utils import chaos as _chaos
-from chainermn_tpu.utils import jax_compat
+from chainermn_tpu.utils.platform import enable_compilation_cache
 
 
 def load_params(path, template, prefix='params'):
@@ -91,10 +92,8 @@ class InferenceEngine:
         over the data axes, params per ``param_specs`` or
         replicated).  Buckets not divisible by the data-axis size are
         dropped (a shard_map batch must split evenly).
-      cache_dir: persistent compilation cache directory (AOT
-        executables survive restarts).  ``aot=False`` forces the
-        plain-jit fallback (what a runtime without the AOT surface
-        degrades to anyway).
+      aot: compile bucket executables ahead of time (default);
+        ``aot=False`` serves from plain ``jit`` instead.
       label / version: fleet identity.  ``label`` names this engine
         as a replica; when set, every serve-path record (spans,
         request stage spans, complete/shed events) carries
@@ -106,7 +105,7 @@ class InferenceEngine:
 
     def __init__(self, apply_fn, params, example, max_batch=32,
                  edges=None, policy=None, plan=None, param_specs=None,
-                 cache_dir=None, aot=True, label=None, version=0):
+                 aot=True, label=None, version=0):
         self.apply_fn = apply_fn
         self.policy = policy
         self.plan = plan
@@ -124,12 +123,7 @@ class InferenceEngine:
                     % (edges, plan.data_size))
             edges = kept
         self.edges = edges
-        self.cache_dir = cache_dir
-        self.cache_persistent = False
-        if cache_dir:
-            os.makedirs(cache_dir, exist_ok=True)
-            self.cache_persistent = jax_compat.enable_compilation_cache(
-                cache_dir)
+        self.cache_dir = enable_compilation_cache()
         self.aot_requested = bool(aot)
 
         ex = (example if hasattr(example, 'shape')
@@ -243,17 +237,13 @@ class InferenceEngine:
                                     self._in_dtype)
 
     def _compile_bucket(self, bucket):
-        jitted = jax.jit(self._mapped)
-        exe = None
+        exe = jax.jit(self._mapped)
         if self.aot_requested:
-            exe = jax_compat.aot_compile(jitted, self.params,
-                                         self._batch_struct(bucket))
-        if exe is None:
-            # no AOT surface on this runtime (or aot=False): plain
-            # jit -- first call traces+compiles, later calls hit the
-            # jit cache; results identical, cold start slower
-            exe = jitted
-        self._aot[bucket] = exe is not jitted
+            exe = exe.lower(self.params,
+                            self._batch_struct(bucket)).compile()
+        # aot=False: plain jit -- first call traces+compiles, later
+        # calls hit the jit cache; results identical
+        self._aot[bucket] = self.aot_requested
         self._compiled[bucket] = exe
         self._signatures[bucket] = abstract_signature(
             (self._batch_struct(bucket),))
@@ -275,7 +265,7 @@ class InferenceEngine:
                 t0 = time.perf_counter()
                 exe = self._compile_bucket(bucket)
                 if not self._aot[bucket]:
-                    # fallback jit: force the compile NOW -- warmup
+                    # plain jit: force the compile NOW -- warmup
                     # exists so traffic never traces
                     x = jnp.zeros((bucket,) + self._item_shape,
                                   self._in_dtype)
@@ -531,7 +521,6 @@ class InferenceEngine:
             'aot': dict(self._aot),
             'aot_requested': self.aot_requested,
             'cache_dir': self.cache_dir,
-            'cache_persistent': self.cache_persistent,
             'quantized': self.quantized,
             'trace_count': self.trace_count,
             'compile_count': self.compile_count,

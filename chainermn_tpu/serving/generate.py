@@ -28,8 +28,8 @@ decode rate instead of the worst sequence's (the batch-level
 alternative idles every finished slot until the whole batch drains).
 
 Both executable families reuse the engine machinery wholesale: AOT
-compilation through :func:`~chainermn_tpu.utils.jax_compat.
-aot_compile` over the persistent compilation cache, the SL007
+compilation (``jit(...).lower(...).compile()``) over the persistent
+compilation cache, the SL007
 ``abstract_signature`` set as a runtime no-recompile guard (refused,
 never retraced -- the static twin is the ``step:decode_forward``
 shardlint target), :class:`~chainermn_tpu.parallel.MeshPlan`
@@ -65,7 +65,7 @@ from chainermn_tpu.serving.batcher import (bucket_edges, bucket_of,
                                            next_request_id,
                                            record_shed)
 from chainermn_tpu.utils import chaos as _chaos
-from chainermn_tpu.utils import jax_compat
+from chainermn_tpu.utils.platform import enable_compilation_cache
 from chainermn_tpu.utils.failure import OverloadError
 
 #: default admission knobs (the generation twins of batcher's)
@@ -426,8 +426,9 @@ class GenerationEngine:
         whenever anything is accepted).
       plan / param_specs: MeshPlan tensor-parallel serving (the cache
         shards its head dim over ``plan.model_axis``).
-      cache_dir / aot: the engine's persistent-compilation-cache and
-        AOT knobs, verbatim.
+      aot: the engine's AOT knob, verbatim (the persistent
+        compilation cache is always on, placed by
+        :func:`~chainermn_tpu.utils.platform.enable_compilation_cache`).
       label / version: fleet identity (the engine.py contract): when
         ``label`` is set, serve-path records carry
         ``replica``/``version`` attrs for per-replica SLO filtering;
@@ -444,10 +445,8 @@ class GenerationEngine:
                  int8_kv=False, paged=False, page_size=16,
                  n_pages=None, prefill_chunk=None, prefix_sharing=True,
                  draft_model=None, draft_params=None, spec_tokens=4,
-                 plan=None, param_specs=None, cache_dir=None, aot=True,
-                 label=None, version=0):
-        import os
-
+                 plan=None, param_specs=None, aot=True, label=None,
+                 version=0):
         from chainermn_tpu.models import (init_kv_cache,
                                           init_paged_kv_cache,
                                           kv_cache_specs)
@@ -479,12 +478,7 @@ class GenerationEngine:
                 'serve a tp_axis model over a plan and a plain model '
                 'without one (tp_axis=%r, plan=%r)'
                 % (model.tp_axis, plan))
-        self.cache_dir = cache_dir
-        self.cache_persistent = False
-        if cache_dir:
-            os.makedirs(cache_dir, exist_ok=True)
-            self.cache_persistent = jax_compat.enable_compilation_cache(
-                cache_dir)
+        self.cache_dir = enable_compilation_cache()
         self.aot_requested = bool(aot)
 
         self.prefill_edges = bucket_edges(self.max_prompt_len)
@@ -860,15 +854,12 @@ class GenerationEngine:
 
     # -- compilation ---------------------------------------------------
     def _compile(self, fn, args, table, key, params=None):
-        jitted = jax.jit(fn, donate_argnums=(1,))
-        exe = None
-        if self.aot_requested:
-            exe = jax_compat.aot_compile(
-                jitted, self.params if params is None else params,
-                *args)
-        aot = exe is not None
-        if exe is None:
-            exe = jitted
+        exe = jax.jit(fn, donate_argnums=(1,))
+        aot = self.aot_requested
+        if aot:
+            exe = exe.lower(
+                self.params if params is None else params,
+                *args).compile()
         table[key] = (exe, aot)
         self._signatures.add(abstract_signature(args))
         self.compile_count += 1
@@ -1187,7 +1178,7 @@ class GenerationEngine:
 
     def warmup(self):
         """Compile (or cache-load) every prefill and decode bucket
-        executable eagerly, largest first.  Fallback (plain-jit)
+        executable eagerly, largest first.  Plain-jit (``aot=False``)
         executables are forced to compile by running them on the real
         cache -- slots are all free, so the garbage they write is
         never attended (reads mask by live length).  Returns
@@ -2240,7 +2231,7 @@ class GenerationEngine:
                     'decode': {b: a for b, (_, a)
                                in sorted(self._decode.items())}},
             'aot_requested': self.aot_requested,
-            'cache_persistent': self.cache_persistent,
+            'cache_dir': self.cache_dir,
             'quantized': self.quantized,
             'int8_kv': self.int8_kv,
             'prefill_trace_count': self.prefill_trace_count,

@@ -25,10 +25,10 @@ class Trainer:
     """``async_metrics=True`` keeps per-iteration metrics on the
     device: the updater is called with ``sync=False`` so the loop
     dispatches step n+1 while step n still runs, instead of blocking a
-    full host-device round trip every iteration (material on a
-    tunneled/remote TPU).  Extensions convert to floats lazily (see
-    ``extensions._as_float``); a lightweight sync every
-    ``sync_interval`` iterations bounds the in-flight queue."""
+    full host-device round trip every iteration.  Extensions convert
+    to floats lazily (see ``extensions._as_float``); a lightweight
+    sync every ``sync_interval`` iterations bounds the in-flight
+    queue."""
 
     def __init__(self, updater, stop_trigger=(1, 'epoch'), out='result',
                  async_metrics=False, sync_interval=16):

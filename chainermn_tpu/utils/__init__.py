@@ -2,7 +2,7 @@
 and recovery primitives, chaos (fault) injection, distributed LR
 recipes."""
 
-from chainermn_tpu.utils.platform import enable_host_cpu_backend  # noqa
+from chainermn_tpu.utils.platform import enable_compilation_cache  # noqa
 from chainermn_tpu.utils.platform import force_host_devices  # noqa
 from chainermn_tpu.utils import profiling  # noqa
 from chainermn_tpu.utils import chaos  # noqa

@@ -1,0 +1,639 @@
+#!/usr/bin/env python3
+"""Does the system still start on the chip?  One command, one process.
+
+    python chip_smoke.py            # one TPU chip: train + serve
+    python chip_smoke.py --chips 4  # one four-chip host: the mesh only
+
+Drives the two main paths through the entry points a user calls, at
+the widths ``bench.py`` uses, with random weights made from a seed:
+
+- *train*: ``create_communicator('xla')`` ->
+  ``create_multi_node_optimizer`` -> ``StandardUpdater.update()`` x 3
+  for ResNet-50 (batch 32, 224 px, ``Policy.bf16()``) and for
+  ``TransformerLM`` d512 / L6 / V32k at batch 8 x seq 1024;
+- *serve*: ``GenerationEngine`` + ``GenerationQueue`` on the d512 / L6
+  / V32k model (32 slots, cache 512, prompts <= 128, 32 new tokens),
+  slab cache and paged cache, 8 seeded requests each;
+- ``--chips 4`` runs ONLY the transformer step over four devices
+  (data-parallel, then dp 2 x tp 2) and the one-device loss both are
+  compared with.
+
+Every phase checks what came out (finite moving losses, the Pallas
+kernels IN the compiled programs, agreement with the kernel-free path
+of the same model on the same chip) and any failure fails the run.
+Without a TPU it exits non-zero and prints no result.  Earlier lines
+are information -- seconds printed here are NOT benchmark numbers.
+The last line of stdout is the result::
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+"""
+
+import argparse
+import contextlib
+import json
+import os
+import sys
+import time
+
+import numpy as np
+
+SEED = 0
+#: ``tests/test_tpu_mosaic.py``'s bound, max |a - b| / (|b| + 1): losses
+#: of the kernel path, the kernel-free path and the meshes
+TOLERANCE = 2e-2
+#: the same measure on bf16 LOGITS: the repo's own bound for the cache
+#: path against the full forward in bf16 (``tests/test_transformer.py``
+#: ``TestIncrementalDecode``, rtol = atol = 5e-2).  At d512 / L6 two
+#: differently compiled bf16 programs sit ~3e-2 apart, each ~3e-2 off
+#: float32 (chip run, PR 21), so 2e-2 is below the dtype's own noise.
+BF16_LOGITS_BOUND = 5e-2
+KERNEL_MARK = 'tpu_custom_call'
+_T0 = time.monotonic()
+
+
+class SmokeFailure(Exception):
+    """A phase ran and what came out is wrong."""
+
+
+def say(msg):
+    print('[chip_smoke %6.1fs] %s' % (time.monotonic() - _T0, msg),
+          flush=True)
+
+
+def require(cond, msg):
+    if not cond:
+        raise SmokeFailure(msg)
+
+
+def rel_err(a, b):
+    a = np.asarray(a, np.float32)
+    b = np.asarray(b, np.float32)
+    return float(np.max(np.abs(a - b) / (np.abs(b) + 1.0)))
+
+
+def summary_line(device):
+    """The contract's last line (the driver reads these keys); only a
+    run in which every phase passed gets to print it."""
+    return json.dumps({'ok': True, 'device': {
+        'platform': device['platform'], 'kind': device['kind'],
+        'count': int(device['count'])}})
+
+
+@contextlib.contextmanager
+def kernel_free():
+    """Trace what runs inside with every Pallas kernel off (the jnp
+    oracle of a kernel-backed model on the SAME device):
+    ``pallas_mode()`` reads the switch at trace time, so jit a fresh
+    function inside."""
+    prior = os.environ.get('CHAINERMN_TPU_PALLAS')
+    os.environ['CHAINERMN_TPU_PALLAS'] = '0'
+    try:
+        yield
+    finally:
+        if prior is None:
+            del os.environ['CHAINERMN_TPU_PALLAS']
+        else:
+            os.environ['CHAINERMN_TPU_PALLAS'] = prior
+
+
+def require_kernels(text, kernels, what):
+    """On the chip the kernels must be IN the program, not bypassed
+    (interpret-mode rehearsals lower them to plain HLO)."""
+    if kernels == 'native':
+        require(KERNEL_MARK in text,
+                '%s holds no %s: its kernels were bypassed'
+                % (what, KERNEL_MARK))
+        return text.count(KERNEL_MARK)
+    return 0
+
+
+def peak_bytes(device):
+    stats = device.memory_stats() or {}
+    return stats.get('peak_bytes_in_use')
+
+
+# ----------------------------------------------------------------------
+# device
+
+def check_device(chips, platform='tpu', kernels='native'):
+    """The device as JAX reports it; fails unless it is the chip this
+    program was written for, with the kernels on and its peaks known."""
+    import jax
+
+    import bench
+    from chainermn_tpu.ops._common import pallas_mode
+
+    devices = jax.devices()
+    first = devices[0]
+    require(first.platform == platform,
+            'no %s: jax.devices() gives %r' % (platform, devices))
+    require(len(devices) >= chips,
+            'need %d chip(s), JAX sees %d' % (chips, len(devices)))
+    require(pallas_mode() == kernels,
+            'pallas_mode() is %r, not %r' % (pallas_mode(), kernels))
+    peak = bench.spec_lookup(bench.BF16_PEAK_TFLOPS, first.device_kind)
+    hbm = bench.spec_lookup(bench.HBM_SPEC_GBS, first.device_kind)
+    device = {'platform': first.platform, 'kind': first.device_kind,
+              'count': len(devices)}
+    say('device: %s x%d (%s), table peaks %.0f bf16 TFLOP/s, %.0f GB/s;'
+        ' jax %s' % (first.device_kind, len(devices), first.platform,
+                     peak, hbm, jax.__version__))
+    return device
+
+
+# ----------------------------------------------------------------------
+# train
+
+def run_updater(upd, steps, what):
+    """``update()`` x steps ending in ``block_until_ready``; losses
+    must be finite and moving."""
+    import jax
+
+    losses, walls = [], []
+    for _ in range(steps):
+        t0 = time.perf_counter()
+        losses.append(upd.update()['loss'])
+        walls.append(time.perf_counter() - t0)
+    jax.block_until_ready(upd.params)
+    say('%s: losses %s; update() wall s %s (first holds the compile)'
+        % (what, ['%.5f' % v for v in losses],
+           ['%.3f' % w for w in walls]))
+    require(all(np.isfinite(losses)), '%s: loss not finite' % what)
+    require(losses[-1] != losses[0],
+            '%s: loss did not move in %d steps' % (what, steps))
+    return losses
+
+
+def train_resnet(model=None, batch=32, insize=224, n_classes=1000,
+                 steps=3):
+    """The conv trainer: ResNet-50 under ``Policy.bf16()`` the way a
+    user builds it (``model`` swaps in a shallower net for the CPU
+    rehearsal)."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    import chainermn_tpu
+    from chainermn_tpu import training
+    from chainermn_tpu.models import ResNet50, StatefulClassifier
+
+    if model is None:
+        model = ResNet50(num_classes=n_classes)
+    rng = np.random.RandomState(SEED)
+    x = rng.rand(batch, insize, insize, 3).astype(np.float32)
+    y = rng.randint(0, n_classes, batch).astype(np.int32)
+    dataset = [(x[i], y[i]) for i in range(batch)]
+
+    comm = chainermn_tpu.create_communicator('xla')
+    variables = model.init({'params': jax.random.PRNGKey(SEED)},
+                           jnp.zeros((1, insize, insize, 3)),
+                           train=False)
+    model_state = {k: v for k, v in variables.items() if k != 'params'}
+    optimizer = chainermn_tpu.create_multi_node_optimizer(
+        optax.sgd(0.1, momentum=0.9), comm)
+    upd = training.StandardUpdater(
+        training.SerialIterator(dataset, batch, shuffle=False),
+        optimizer, StatefulClassifier(model).loss, variables['params'],
+        comm, model_state=model_state,
+        policy=chainermn_tpu.Policy.bf16())
+    losses = run_updater(upd, steps, 'train %s b%d/%dpx'
+                         % (type(model).__name__, batch, insize))
+    return {'losses': losses}
+
+
+def init_lm(model, seq):
+    import jax
+    import jax.numpy as jnp
+    return model.init(jax.random.PRNGKey(SEED),
+                      jnp.zeros((1, seq), jnp.int32))['params']
+
+
+def build_lm_updater(model, params, batch, seq, comm, param_specs=None):
+    """Multi-node optimizer -> StandardUpdater on ``comm`` over a
+    seeded token dataset (one global batch, repeated)."""
+    import optax
+
+    import chainermn_tpu
+    from chainermn_tpu import training
+    from chainermn_tpu.models import lm_loss
+
+    rng = np.random.RandomState(SEED)
+    toks = rng.randint(0, model.vocab_size, (batch, seq)).astype(np.int32)
+    tgts = rng.randint(0, model.vocab_size, (batch, seq)).astype(np.int32)
+    loss = lm_loss(lambda p, t: model.apply({'params': p}, t))
+    optimizer = chainermn_tpu.create_multi_node_optimizer(
+        optax.adam(1e-3), comm)
+    upd = training.StandardUpdater(
+        training.SerialIterator(
+            [(toks[i], tgts[i]) for i in range(batch)], batch,
+            shuffle=False),
+        optimizer, loss, params, comm, has_aux=True,
+        param_specs=param_specs)
+    return upd, toks, tgts
+
+
+def train_transformer(d_model=512, n_heads=8, n_layers=6, d_ff=2048,
+                      vocab=32000, seq=1024, batch=8, steps=3,
+                      kernels='native'):
+    """The LM trainer at ``bench.py``'s transformer widths."""
+    import bench
+    import chainermn_tpu
+    from chainermn_tpu.models import TransformerLM
+
+    model = TransformerLM(vocab_size=vocab, d_model=d_model,
+                          n_heads=n_heads, n_layers=n_layers,
+                          d_ff=d_ff, max_len=seq)
+    params = init_lm(model, seq)
+    upd, toks, tgts = build_lm_updater(
+        model, params, batch, seq,
+        chainermn_tpu.create_communicator('xla'))
+    what = 'train TransformerLM d%d/L%d/V%d b%dxseq%d' % (
+        d_model, n_layers, vocab, batch, seq)
+
+    # the step the updater is about to compile, as lowered
+    fn, args = upd.traceable_step(upd.shard_batch(
+        [(toks[i], tgts[i]) for i in range(batch)]))
+    n_kernels = require_kernels(fn.lower(*args).as_text(), kernels,
+                                'the lowered transformer step')
+    say('%s: %d %s in the lowered step' % (what, n_kernels,
+                                           KERNEL_MARK))
+
+    # kernel path vs kernel-free path, same params and batch, on this
+    # device (the comparison bench.py --check has)
+    check = bench._transformer_numerics_check(model, params, toks, tgts)
+    say('%s: kernel vs kernel-free loss rel err %.2e, grad-norm rel '
+        'err %.2e' % (what, check['numerics_loss_rel_err'],
+                      check['numerics_gnorm_rel_err']))
+    require(check['numerics_vs_oracle_ok'],
+            '%s: kernel path disagrees with the kernel-free path: %r'
+            % (what, check))
+
+    losses = run_updater(upd, steps, what)
+    return {'losses': losses, 'n_kernels': n_kernels, 'check': check}
+
+
+# ----------------------------------------------------------------------
+# serve
+
+def _full_forward_logits(model, params, prompts, next_tokens=None):
+    """Plain full-sequence forward (``model.apply``, no cache): the
+    logits that follow ``prompt`` (-> the first generated token) and,
+    given ``next_tokens``, those that follow ``prompt + next_token``
+    (-> the first decode step)."""
+    import jax
+    import jax.numpy as jnp
+
+    lens = np.asarray([len(p) for p in prompts])
+    rows = np.zeros((len(prompts), lens.max() + 1), np.int32)
+    for i, p in enumerate(prompts):
+        rows[i, :len(p)] = p
+    idx = np.arange(len(prompts))
+    if next_tokens is not None:
+        rows[idx, lens] = next_tokens  # causal: the pad after is unseen
+    logits = np.asarray(
+        jax.jit(lambda p, t: model.apply({'params': p}, t))(
+            params, jnp.asarray(rows)), np.float32)
+    return logits[idx, lens - 1], logits[idx, lens]
+
+
+def _float32_logits(model, params, prompts, next_tokens):
+    """The same forward as plain float32 jnp: no kernels, float32
+    weights and activations, full-precision matmuls."""
+    import jax
+    import jax.numpy as jnp
+
+    wide = jax.tree_util.tree_map(
+        lambda x: x.astype(jnp.float32), params)
+    with kernel_free(), jax.default_matmul_precision('highest'):
+        return _full_forward_logits(model.clone(dtype=jnp.float32),
+                                    wide, prompts, next_tokens)
+
+
+def _cache_logits(model, params, prompts, next_tokens, n_slots,
+                  max_len, buckets, page_size=None):
+    """Prefill each prompt into the cache, then ONE decode step of
+    ``next_tokens`` for all of them, through the models' serving API
+    (what the engine's executables wrap): ``(prefill logits, first
+    decode-step logits)`` per prompt."""
+    import jax
+    import jax.numpy as jnp
+
+    from chainermn_tpu import models as M
+
+    n = len(prompts)
+    if page_size is None:
+        cache = M.init_kv_cache(model, n_slots, max_len)
+        prefill = jax.jit(lambda p, c, t, ln, i: M.prefill(
+            model, p, c, t, ln, i), donate_argnums=(1,))
+        decode = jax.jit(lambda p, c, t, pos: M.decode_step(
+            model, p, c, t, pos), donate_argnums=(1,))
+        extra = ()
+    else:
+        per_seq = -(-max_len // page_size)
+        cache = M.init_paged_kv_cache(model, 1 + n_slots * per_seq,
+                                      page_size)
+        # page 0 is the allocator's scratch page; idle rows point there
+        tables = np.zeros((n_slots, per_seq), np.int32)
+        tables[:n] = 1 + np.arange(n * per_seq).reshape(n, per_seq)
+        tables = jnp.asarray(tables)
+        prefill = jax.jit(lambda p, c, t, ln, i: M.prefill_paged(
+            model, p, c, t, ln, tables[i], 0), donate_argnums=(1,))
+        decode = jax.jit(lambda p, c, t, pos, tb: M.decode_step_paged(
+            model, p, c, t, pos, tb), donate_argnums=(1,))
+        extra = (tables,)
+    first = []
+    for i, prompt in enumerate(prompts):
+        width = next(b for b in buckets if b >= len(prompt))
+        padded = np.zeros((1, width), np.int32)
+        padded[0, :len(prompt)] = prompt
+        logits, cache = prefill(params, cache, jnp.asarray(padded),
+                                jnp.asarray(len(prompt), jnp.int32),
+                                jnp.asarray(i, jnp.int32))
+        first.append(np.asarray(logits, np.float32))
+    first = np.stack(first)
+    tokens = np.zeros((n_slots,), np.int32)
+    positions = np.zeros((n_slots,), np.int32)
+    tokens[:n] = next_tokens
+    positions[:n] = [len(p) for p in prompts]
+    logits, cache = decode(params, cache, jnp.asarray(tokens),
+                           jnp.asarray(positions), *extra)
+    return first, np.asarray(logits, np.float32)[:n]
+
+
+def _serve_requests(engine, prompts, max_new, kernels, what):
+    """Warm the engine up, push the prompts through a
+    ``GenerationQueue`` and drain it: the token stream of each."""
+    from chainermn_tpu import serving
+
+    t0 = time.perf_counter()
+    aot = engine.warmup()
+    warm_s = time.perf_counter() - t0
+    require(all(aot['prefill'].values()) and all(aot['decode'].values()),
+            '%s: an executable was not compiled ahead of time' % what)
+    n_kernels = min(
+        require_kernels(exe.as_text(), kernels,
+                        '%s decode executable (bucket %d)' % (what, b))
+        for b, (exe, _) in sorted(engine._decode.items()))
+    queue = serving.GenerationQueue(
+        max_prompt_len=engine.max_prompt_len,
+        max_queue=4 * engine.n_slots,
+        page_size=engine.page_size if engine.paged else None)
+    t0 = time.perf_counter()
+    requests = [queue.submit(p, max_new) for p in prompts]
+    deadline = time.monotonic() + 300.0
+    while not all(r.done() for r in requests):
+        engine.step(queue)
+        require(time.monotonic() < deadline,
+                '%s: requests still open after 300 s' % what)
+    streams = [r.result(timeout=1.0).tolist() for r in requests]
+    serve_s = time.perf_counter() - t0
+    require(all(len(s) == max_new for s in streams),
+            '%s: a request came back short: %r'
+            % (what, [len(s) for s in streams]))
+    say('%s: warm-up %.1f s (%d executables, AOT), %d/%d requests '
+        'completed x %d tokens in %.2f s, %d decode steps, >= %d %s '
+        'per decode executable'
+        % (what, warm_s, engine.compile_count, len(streams),
+           len(prompts), max_new, serve_s, engine.decode_steps,
+           n_kernels, KERNEL_MARK))
+    return streams
+
+
+def serve(d_model=512, n_heads=8, n_layers=6, d_ff=2048, vocab=32000,
+          max_len=512, n_slots=32, max_prompt=128, max_new=32,
+          n_requests=8, page_sizes=(16, 128), kernels='native'):
+    """The generation server on ``bench.py``'s non-quick model, slab
+    cache and paged cache."""
+    import jax
+    import jax.numpy as jnp
+
+    from chainermn_tpu import serving
+    from chainermn_tpu.models import TransformerLM
+    from chainermn_tpu.ops.flash_attention import _env_block
+    from chainermn_tpu.precision import Policy
+
+    model = TransformerLM(vocab_size=vocab, d_model=d_model,
+                          n_heads=n_heads, n_layers=n_layers,
+                          d_ff=d_ff, max_len=max_len)
+    params = model.init(jax.random.PRNGKey(SEED),
+                        jnp.zeros((1, 8), jnp.int32))['params']
+    rng = np.random.RandomState(SEED)
+    prompts = [rng.randint(0, vocab, size=int(n)).astype(np.int32)
+               for n in rng.randint(4, max_prompt + 1, size=n_requests)]
+    policy = Policy.bf16()
+    base = 'serve d%d/L%d/V%d %d slots' % (d_model, n_layers, vocab,
+                                           n_slots)
+
+    def engine(**kw):
+        return serving.GenerationEngine(
+            model, params, n_slots=n_slots, max_prompt_len=max_prompt,
+            policy=policy, **kw)
+
+    slab = engine()
+    streams = {'slab': _serve_requests(slab, prompts, max_new, kernels,
+                                       base + ' slab')}
+    for page in page_sizes:
+        name = 'paged%d' % page
+        streams[name] = _serve_requests(
+            engine(paged=True, page_size=page), prompts, max_new,
+            kernels, '%s paged(page %d)' % (base, page))
+
+    # correctness at the logits, all on the SAME (policy-cast) params:
+    # the cache path (prefill, then one decode step) against the plain
+    # full-sequence forward in the serving dtype AND against plain
+    # float32 jnp, both inside BF16_LOGITS_BOUND
+    served = slab.params
+    plain_first, _ = _full_forward_logits(model, served, prompts)
+    next_tokens = plain_first.argmax(-1)
+    plain = _full_forward_logits(model, served, prompts, next_tokens)
+    exact = _float32_logits(model, served, prompts, next_tokens)
+    say('%s plain forward (%s) vs float32 jnp: rel err %.2e / %.2e '
+        '(after prompt / after first token)'
+        % (base, np.dtype(model.dtype).name, rel_err(plain[0], exact[0]),
+           rel_err(plain[1], exact[1])))
+    errors = {}
+    for name, page in [('slab', None)] + [('paged%d' % p, p)
+                                          for p in page_sizes]:
+        got = _cache_logits(model, served, prompts, next_tokens,
+                            n_slots, max_len, slab.prefill_edges,
+                            page_size=page)
+        require(got[1].shape == (n_requests, vocab)
+                and np.all(np.isfinite(got[1])),
+                '%s %s: bad decode logits' % (base, name))
+        errors[name] = tuple(rel_err(g, r) for ref in (plain, exact)
+                             for g, r in zip(got, ref))
+        say('%s %s: prefill / first decode-step logits vs plain '
+            'forward: rel err %.2e / %.2e; vs float32 jnp: %.2e / '
+            '%.2e (bound %.0e)'
+            % ((base, name) + errors[name] + (BF16_LOGITS_BOUND,)))
+        require(max(errors[name]) < BF16_LOGITS_BOUND,
+                '%s %s: logits off the plain forward / float32 jnp by '
+                '%r' % (base, name, errors[name]))
+
+    # token streams: slab vs paged.  Equal token for token where the
+    # page IS the slab's key block (identical arithmetic by
+    # construction); elsewhere a different block order may flip a
+    # bf16 near-tie, so the count is information
+    slab_block = min(_env_block('CHAINERMN_TPU_FA_BLOCK_K'), max_len)
+    total = n_requests * max_new
+    for page in page_sizes:
+        got = streams['paged%d' % page]
+        same = sum(a == b for s, g in zip(streams['slab'], got)
+                   for a, b in zip(s, g))
+        say('%s: slab vs paged(page %d) token streams agree on %d/%d'
+            % (base, page, same, total))
+        if page == slab_block:
+            require(same == total,
+                    '%s: paged(page %d) must equal slab token for '
+                    'token: %r vs %r' % (base, page, got,
+                                         streams['slab']))
+    say('%s: slab stream of request 0: %s' % (base, streams['slab'][0]))
+    return {'streams': streams, 'errors': errors}
+
+
+# ----------------------------------------------------------------------
+# four chips
+
+def _distinct_devices(tree):
+    import jax
+    return {s.device for leaf in jax.tree_util.tree_leaves(tree)
+            for s in leaf.addressable_shards}
+
+
+def train_multichip(n_devices=4, d_model=512, n_heads=8, n_layers=6,
+                    d_ff=2048, vocab=32000, seq=1024, global_batch=32,
+                    tp=2, steps=3, kernels='native'):
+    """The transformer step over ``n_devices``: data-parallel through
+    ``create_communicator('xla')``, then ``MeshPlan.create(tp=tp)``
+    (dp x tp); the first-step loss of each against the one-device
+    loss of the same global batch."""
+    import jax
+
+    import chainermn_tpu
+    from chainermn_tpu.communicators.mesh_utility import detect_topology
+    from chainermn_tpu.models import (TransformerLM, lm_loss,
+                                      tp_param_specs)
+    from chainermn_tpu.parallel.meshplan import MeshPlan
+
+    devices = jax.devices()[:n_devices]
+    topology = detect_topology(devices)
+    require(topology == (1, n_devices),
+            'detect_topology gives %r, not (1, %d)'
+            % (topology, n_devices))
+    shape = dict(vocab_size=vocab, d_model=d_model, n_heads=n_heads,
+                 n_layers=n_layers, d_ff=d_ff, max_len=seq)
+    model = TransformerLM(**shape)
+    what = 'TransformerLM d%d/L%d/V%d global b%dxseq%d' % (
+        d_model, n_layers, vocab, global_batch, seq)
+    out = {}
+
+    def run(name, upd, toks, tgts, sharded_leaf=None):
+        arrays = upd.shard_batch([(toks[i], tgts[i])
+                                  for i in range(global_batch)])
+        fn, args = upd.traceable_step(arrays)
+        n_kernels = require_kernels(fn.lower(*args).as_text(), kernels,
+                                    'the lowered %s step' % name)
+        for label, tree in (('parameters', upd.params),
+                            ('batch', arrays)):
+            on = _distinct_devices(tree)
+            require(on == set(devices),
+                    '%s: %s live on %d device(s), not %d: %r'
+                    % (name, label, len(on), n_devices, sorted(
+                        str(d) for d in on)))
+        shard = arrays[0].addressable_shards[0].data.shape
+        note = ''
+        if sharded_leaf is not None:
+            leaf = sharded_leaf(upd.params)
+            local = leaf.addressable_shards[0].data.shape
+            require(local != leaf.shape,
+                    '%s: tensor-parallel kernel %r is not split'
+                    % (name, leaf.shape))
+            note = ', qkv kernel %r -> %r per device' % (leaf.shape,
+                                                         local)
+        say('%s %s: mesh %r, batch shard %r%s, %d %s in the lowered '
+            'step' % (what, name, dict(upd.comm.mesh.shape), shard,
+                      note, n_kernels, KERNEL_MARK))
+        out[name] = run_updater(upd, steps, '%s %s' % (what, name))
+
+    params = init_lm(model, seq)
+    upd, toks, tgts = build_lm_updater(
+        model, params, global_batch, seq,
+        chainermn_tpu.create_communicator('xla', devices=devices))
+
+    # what both are compared with: the same global batch on ONE device
+    loss = lm_loss(lambda p, t: model.apply({'params': p}, t))
+    one = float(jax.jit(lambda p, t, y: loss(p, t, y)[0])(
+        *jax.device_put((params, toks, tgts), devices[0])))
+    say('%s: one-device loss %.5f' % (what, one))
+    out['one_device'] = one
+
+    run('dp%d' % n_devices, upd, toks, tgts)
+    del upd
+
+    plan = MeshPlan.create(tp=tp, devices=devices)
+    require(plan.model_size == tp,
+            'MeshPlan gave tp=%d, not %d' % (plan.model_size, tp))
+    # the tp model's parameter tree IS the unsharded model's
+    upd, toks, tgts = build_lm_updater(
+        TransformerLM(tp_axis=plan.model_axis, **shape), params,
+        global_batch, seq, plan.communicator(),
+        param_specs=tp_param_specs(params, plan.model_axis))
+    run('dp%dxtp%d' % (plan.data_size, tp), upd, toks, tgts,
+        sharded_leaf=lambda p: p['block_0']['qkv']['kernel'])
+
+    for name, losses in out.items():
+        if name == 'one_device':
+            continue
+        err = abs(losses[0] - one) / (abs(one) + 1.0)
+        say('%s %s: first-step loss %.5f vs one device %.5f (rel err '
+            '%.2e, bound %.0e)' % (what, name, losses[0], one, err,
+                                   TOLERANCE))
+        require(err < TOLERANCE,
+                '%s %s: loss %r is off the one-device loss %r'
+                % (what, name, losses[0], one))
+    return out
+
+
+# ----------------------------------------------------------------------
+
+def main(argv=None):
+    parser = argparse.ArgumentParser(description=__doc__.split('\n')[0])
+    parser.add_argument(
+        '--chips', type=int, choices=(1, 4), default=1,
+        help='4: run only the four-chip mesh phase and its one-device '
+             'comparison (default 1: train + serve on one chip)')
+    args = parser.parse_args(argv)
+
+    if os.environ.get('CHAINERMN_TPU_PALLAS') == '0':
+        say('FAILED: CHAINERMN_TPU_PALLAS=0 turns every kernel off; '
+            'unset it')
+        return 1
+    phase = 'device'
+    try:
+        from chainermn_tpu.utils import enable_compilation_cache
+        say('compilation cache: %s' % enable_compilation_cache())
+        device = check_device(args.chips)
+        import jax
+        if args.chips == 4:
+            phases = [('multichip', train_multichip)]
+        else:
+            phases = [('train_resnet', train_resnet),
+                      ('train_transformer', train_transformer),
+                      ('serve', serve)]
+        for phase, fn in phases:
+            t0 = time.perf_counter()
+            fn()
+            say('phase %s passed in %.1f s; peak_bytes_in_use %s'
+                % (phase, time.perf_counter() - t0,
+                   peak_bytes(jax.devices()[0])))
+    except Exception as e:
+        import traceback
+        traceback.print_exc()
+        say('FAILED in phase %s: %s: %s' % (phase, type(e).__name__, e))
+        return 1
+    print(summary_line(device), flush=True)
+    return 0
+
+
+if __name__ == '__main__':
+    sys.exit(main())

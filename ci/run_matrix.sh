@@ -6,6 +6,8 @@
 # distributed tests run additionally at 1, 2 and 3.
 set -euo pipefail
 cd "$(dirname "$0")/.."
+# the suite is CPU-only (tests/conftest.py adds the virtual devices)
+export JAX_PLATFORMS=cpu
 
 for N in 1 2 3; do
   echo "=== device matrix: ${N} virtual device(s) ==="

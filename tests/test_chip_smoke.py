@@ -1,0 +1,141 @@
+"""``chip_smoke.py`` rehearsed on the CPU.
+
+The script itself needs a TPU and must FAIL without one (the driver
+checks that first); its phase functions take their sizes as arguments,
+so the same code runs here at tiny sizes with the Pallas kernels in
+interpret mode and four of conftest's virtual devices as the
+"four-chip host".  What only the chip can show -- Mosaic lowering,
+full widths, real device placement -- is the script's own run there.
+"""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+import jax
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import chip_smoke  # noqa: E402
+
+TINY_LM = dict(d_model=16, n_heads=2, n_layers=1, d_ff=32, vocab=32)
+
+
+@pytest.fixture
+def interpret(monkeypatch):
+    monkeypatch.setenv('CHAINERMN_TPU_PALLAS_INTERPRET', '1')
+    monkeypatch.delenv('CHAINERMN_TPU_PALLAS', raising=False)
+
+
+def test_script_fails_without_a_tpu():
+    env = dict(os.environ, JAX_PLATFORMS='cpu')
+    p = subprocess.run([sys.executable, 'chip_smoke.py'], cwd=REPO,
+                       env=env, capture_output=True, text=True,
+                       timeout=120)
+    assert p.returncode != 0
+    assert '"ok": true' not in p.stdout
+    assert 'FAILED in phase device' in p.stdout
+    assert 'no tpu' in p.stdout
+
+
+def test_script_refuses_kernels_switched_off():
+    env = dict(os.environ, CHAINERMN_TPU_PALLAS='0')
+    p = subprocess.run([sys.executable, 'chip_smoke.py'], cwd=REPO,
+                       env=env, capture_output=True, text=True,
+                       timeout=120)
+    assert p.returncode != 0
+    assert '"ok": true' not in p.stdout
+    assert 'CHAINERMN_TPU_PALLAS=0' in p.stdout
+
+
+def test_summary_line_is_the_contract():
+    dev = jax.devices()[0]
+    line = chip_smoke.summary_line({'platform': dev.platform,
+                                    'kind': dev.device_kind,
+                                    'count': len(jax.devices())})
+    assert '\n' not in line
+    assert json.loads(line) == {'ok': True, 'device': {
+        'platform': 'cpu', 'kind': dev.device_kind, 'count': 8}}
+
+
+def test_check_device_knows_the_cpu_is_no_chip(interpret):
+    with pytest.raises(chip_smoke.SmokeFailure, match='no tpu'):
+        chip_smoke.check_device(1)
+    # the right platform, but a device in no peaks table: an error
+    with pytest.raises(KeyError, match='no spec table'):
+        chip_smoke.check_device(1, platform='cpu', kernels='interpret')
+
+
+def test_train_resnet_phase_tiny():
+    from chainermn_tpu.models import ResNet
+    out = chip_smoke.train_resnet(
+        model=ResNet(stage_sizes=[1], num_classes=10, width=8),
+        batch=8, insize=16, n_classes=10)
+    assert len(out['losses']) == 3
+
+
+def test_train_transformer_phase_tiny(interpret):
+    out = chip_smoke.train_transformer(seq=16, batch=8,
+                                       kernels='interpret', **TINY_LM)
+    assert len(out['losses']) == 3
+    assert out['check']['numerics_vs_oracle_ok']
+
+
+def test_serve_phase_tiny(interpret):
+    out = chip_smoke.serve(max_len=16, n_slots=2, max_prompt=4,
+                           max_new=3, n_requests=2, page_sizes=(16,),
+                           kernels='interpret', **TINY_LM)
+    streams = out['streams']
+    assert sorted(streams) == ['paged16', 'slab']
+    # page 16 == the slab key block here: equal token for token
+    assert streams['paged16'] == streams['slab']
+    assert all(len(s) == 3 for s in streams['slab'])
+    assert max(max(e) for e in out['errors'].values()) < 5e-2
+
+
+def test_multichip_phase_on_four_virtual_devices(interpret):
+    out = chip_smoke.train_multichip(n_devices=4, seq=16,
+                                     global_batch=4, tp=2,
+                                     kernels='interpret', **TINY_LM)
+    assert sorted(out) == ['dp2xtp2', 'dp4', 'one_device']
+    for name in ('dp4', 'dp2xtp2'):
+        assert abs(out[name][0] - out['one_device']) < 2e-2
+
+
+def test_missing_kernel_fails_the_phase():
+    with pytest.raises(chip_smoke.SmokeFailure, match='bypassed'):
+        chip_smoke.require_kernels('HloModule plain', 'native', 'x')
+    assert chip_smoke.require_kernels(
+        'custom_call_target="tpu_custom_call"', 'native', 'x') == 1
+
+
+def test_cache_helper_honours_the_environment(monkeypatch, tmp_path):
+    from chainermn_tpu.utils import platform
+
+    calls = []
+    monkeypatch.setattr(jax.config, 'update',
+                        lambda *a: calls.append(a))
+    monkeypatch.setenv('JAX_COMPILATION_CACHE_DIR', str(tmp_path))
+    assert platform.enable_compilation_cache() == str(tmp_path)
+    assert calls == []          # JAX reads the variable itself
+    monkeypatch.delenv('JAX_COMPILATION_CACHE_DIR')
+    monkeypatch.setattr(platform, '_CHECKOUT', str(tmp_path / 'co'))
+    monkeypatch.setattr(
+        'jax.experimental.compilation_cache.compilation_cache.'
+        'reset_cache', lambda: calls.append('reset'))
+    want = str(tmp_path / 'co' / '.jax_compile_cache')
+    assert platform.enable_compilation_cache() == want
+    assert calls == [('jax_compilation_cache_dir', want), 'reset']
+
+
+def test_cache_helper_default_is_in_the_checkout(monkeypatch):
+    from chainermn_tpu.utils import platform
+    monkeypatch.delenv('JAX_COMPILATION_CACHE_DIR', raising=False)
+    assert platform.enable_compilation_cache() == os.path.join(
+        REPO, '.jax_compile_cache')
+    assert jax.config.jax_compilation_cache_dir == os.path.join(
+        REPO, '.jax_compile_cache')

@@ -21,7 +21,7 @@ from chainermn_tpu.models import MLP
 from chainermn_tpu.serving import (InferenceEngine, OverloadError,
                                    RequestQueue, bucket_edges,
                                    bucket_of, pack_sizes)
-from chainermn_tpu.utils import chaos, jax_compat
+from chainermn_tpu.utils import chaos
 
 
 def _mlp_setup(n_units=16, n_in=48, n_out=10, seed=0):
@@ -262,57 +262,70 @@ class TestInferenceEngine:
         with pytest.raises(RuntimeError, match='no-recompile guard'):
             eng.guard_signature(np.ones((3, 48), np.float32))
 
-    def test_plain_jit_fallback_when_aot_unavailable(self, monkeypatch):
-        """The jax_compat satellite: a runtime without
-        ``.lower().compile()`` degrades to plain jit -- the engine
-        serves identically, just without AOT persistence."""
-        monkeypatch.setattr(jax_compat, 'aot_compile',
-                            lambda jitted, *a, **k: None)
+    def test_plain_jit_when_aot_off(self):
+        """``aot=False`` is the explicit way to ask for plain jit:
+        the engine serves identically, and warmup still forces every
+        compile so traffic never traces."""
         _m, params, apply_fn, example = _mlp_setup()
-        eng = InferenceEngine(apply_fn, params, example, max_batch=4)
+        eng = InferenceEngine(apply_fn, params, example, max_batch=4,
+                              aot=False)
         aot = eng.warmup()
         assert not any(aot.values())
         y = eng.infer(np.ones((4, 48), np.float32))
         assert np.asarray(y).shape == (4, 10)
-        # warmup's forced compile means traffic still never traces
         t0 = eng.trace_count
         eng.infer(np.ones((4, 48), np.float32))
         assert eng.trace_count == t0
 
-    def test_aot_compile_guard_returns_none_without_lower(self):
-        class NoLower:
-            pass
+    def test_aot_lowering_error_propagates(self):
+        """No silent fall to plain jit: a forward that cannot lower
+        fails warmup."""
+        _m, params, _apply, example = _mlp_setup()
 
-        assert jax_compat.aot_compile(NoLower()) is None
+        def broken(p, x):
+            raise TypeError('cannot lower this')
 
-    def test_enable_compilation_cache_bad_runtime(self, monkeypatch):
-        def boom(*a, **k):
-            raise AttributeError('no such config')
+        eng = InferenceEngine(broken, params, example, max_batch=2)
+        with pytest.raises(TypeError, match='cannot lower'):
+            eng.warmup()
 
-        monkeypatch.setattr(jax.config, 'update', boom)
-        ok = jax_compat.enable_compilation_cache('/tmp/nope')
-        assert ok is False  # degraded, not crashed
-
-    def test_persistent_cache_writes_executables(self, tmp_path):
-        cache = str(tmp_path / 'cc')
-        _m, params, apply_fn, example = _mlp_setup()
-        eng = InferenceEngine(apply_fn, params, example, max_batch=4,
-                              cache_dir=cache)
-        eng.warmup()
-        if not eng.cache_persistent:
-            pytest.skip('runtime has no persistent-cache surface')
-        entries = [f for f in os.listdir(cache)
-                   if f.endswith('-cache')]
-        assert len(entries) >= len(eng.edges)
-        # a second engine (cold start simulation) warms up against
-        # the SAME cache dir and serves identically
-        eng2 = InferenceEngine(apply_fn, params, example, max_batch=4,
-                               cache_dir=cache)
-        eng2.warmup()
-        x = np.ones((4, 48), np.float32)
-        np.testing.assert_allclose(np.asarray(eng.infer(x)),
-                                   np.asarray(eng2.infer(x)),
-                                   rtol=1e-6)
+    def test_persistent_cache_writes_executables(self, tmp_path,
+                                                 monkeypatch):
+        from jax.experimental.compilation_cache import (
+            compilation_cache)
+        from chainermn_tpu.utils import platform
+        # the engine's cache lives where the environment says, else
+        # in the checkout: stand a scratch checkout in for this test
+        monkeypatch.delenv('JAX_COMPILATION_CACHE_DIR', raising=False)
+        monkeypatch.setattr(platform, '_CHECKOUT', str(tmp_path))
+        was_dir = jax.config.jax_compilation_cache_dir
+        was_min = jax.config.jax_persistent_cache_min_compile_time_secs
+        jax.config.update(
+            'jax_persistent_cache_min_compile_time_secs', 0.0)
+        try:
+            _m, params, apply_fn, example = _mlp_setup()
+            eng = InferenceEngine(apply_fn, params, example,
+                                  max_batch=4)
+            cache = str(tmp_path / '.jax_compile_cache')
+            assert eng.cache_dir == cache
+            eng.warmup()
+            entries = [f for f in os.listdir(cache)
+                       if f.endswith('-cache')]
+            assert len(entries) >= len(eng.edges)
+            # a second engine (cold start simulation) warms up
+            # against the SAME cache dir and serves identically
+            eng2 = InferenceEngine(apply_fn, params, example,
+                                   max_batch=4)
+            eng2.warmup()
+            x = np.ones((4, 48), np.float32)
+            np.testing.assert_allclose(np.asarray(eng.infer(x)),
+                                       np.asarray(eng2.infer(x)),
+                                       rtol=1e-6)
+        finally:
+            jax.config.update(
+                'jax_persistent_cache_min_compile_time_secs', was_min)
+            jax.config.update('jax_compilation_cache_dir', was_dir)
+            compilation_cache.reset_cache()
 
     def test_policy_bf16_casts_params_and_outputs_f32(self):
         _m, params, apply_fn, example = _mlp_setup()
