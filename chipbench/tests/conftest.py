@@ -1,0 +1,14 @@
+"""The benchmark's own tests run on the CPU, at tiny sizes, on four
+virtual devices (the flag must be set before JAX's first backend use)."""
+
+import os
+import sys
+
+os.environ.setdefault('JAX_PLATFORMS', 'cpu')
+flags = os.environ.get('XLA_FLAGS', '')
+if 'xla_force_host_platform_device_count' not in flags:
+    os.environ['XLA_FLAGS'] = (
+        flags + ' --xla_force_host_platform_device_count=8').strip()
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))))
