@@ -1067,7 +1067,7 @@ def phase_stats(cfg, quick, trace_steps=3):
     try:
         from chainermn_tpu import telemetry
         from chainermn_tpu.telemetry import diagnosis
-        was_active = telemetry.active()
+        was_active = telemetry.live()
         rec = was_active or telemetry.enable()  # in-memory recorder
         try:
             n0 = len(rec.events)

@@ -244,7 +244,7 @@ class CommunicatorBase:
         every ``_allreduce_impl`` sees already-narrowed leaves and the
         declared dtype stays in lockstep with the executed one.
         """
-        if _telemetry._active is not None:
+        if _telemetry.live() is not None:
             # trace-time collective-issue mark (fires once per
             # compilation, not per step): correlates WHICH strategy
             # issued a gradient reduction into the program with the
@@ -302,7 +302,7 @@ class CommunicatorBase:
                                  seq=self._next_eager_seq(
                                      'broadcast_data')):
                 return self.replicate(params)
-        if _telemetry._active is not None:
+        if _telemetry.live() is not None:
             _telemetry.event(
                 '%s:broadcast_data' % type(self).__name__,
                 kind='collective_trace', axes=list(AXES))
@@ -411,7 +411,7 @@ class CommunicatorBase:
         # hand the liveness dir off to the telemetry session: the
         # post-mortem doctor pairs this capture's flight records with
         # these heartbeat files to name the dead/stalled peer
-        rec = _telemetry.active()
+        rec = _telemetry.live()
         if rec is not None:
             rec.liveness_dir = _os.path.abspath(directory)
             _telemetry.event('liveness_enabled', kind='liveness',

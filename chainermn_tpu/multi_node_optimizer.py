@@ -98,7 +98,7 @@ def create_multi_node_optimizer(actual_optimizer, communicator,
         def first_call(_):
             # Initial weight sync in place of a step (reference :23-26);
             # like the reference, no gradient allreduce is paid here.
-            if _telemetry._active is not None:
+            if _telemetry.live() is not None:
                 # trace-time mark: the L4 wrapper's broadcast is in
                 # the program.  Fires once per COMPILATION -- the
                 # broadcast-appears-exactly-once regression test pins
@@ -112,14 +112,15 @@ def create_multi_node_optimizer(actual_optimizer, communicator,
             return updates, state.actual_state
 
         def reduce_now():
-            if _telemetry._active is not None:
+            if _telemetry.live() is not None:
                 _telemetry.event('multi_node_optimizer:allreduce_grad',
                                  kind='collective_trace')
             g = grads
             if allreduce_dtype is not None:
                 g = jax.tree_util.tree_map(
                     lambda x: x.astype(allreduce_dtype), g)
-            reduced = communicator.allreduce_grad(g)
+            with jax.named_scope('grad_allreduce'):
+                reduced = communicator.allreduce_grad(g)
             if allreduce_dtype is not None:
                 reduced = jax.tree_util.tree_map(
                     lambda r, orig: r.astype(orig.dtype), reduced,
@@ -131,19 +132,21 @@ def create_multi_node_optimizer(actual_optimizer, communicator,
             # the branch are issued (or not) in lockstep on all devices.
             reduced = reduce_now()
             if not double_buffering:
-                return actual_optimizer.update(
-                    reduced, state.actual_state, params)
+                with jax.named_scope('optimizer_update'):
+                    return actual_optimizer.update(
+                        reduced, state.actual_state, params)
             db = state.actual_state
             # apply the PREVIOUS reduction; this step's `reduced` goes
             # only into the carried state, so nothing in this step
             # waits on the collective
             zero_updates = jax.tree_util.tree_map(jnp.zeros_like,
                                                   grads)
-            updates, new_inner = lax.cond(
-                db.have_pending,
-                lambda _: actual_optimizer.update(db.pending, db.inner,
-                                                  params),
-                lambda _: (zero_updates, db.inner), operand=None)
+            with jax.named_scope('optimizer_update'):
+                updates, new_inner = lax.cond(
+                    db.have_pending,
+                    lambda _: actual_optimizer.update(
+                        db.pending, db.inner, params),
+                    lambda _: (zero_updates, db.inner), operand=None)
             return updates, DoubleBufferState(
                 inner=new_inner, pending=reduced,
                 have_pending=jnp.asarray(True))

@@ -119,6 +119,7 @@ def _stats_pallas(x2d, block_m):
         out_shape=[jax.ShapeDtypeStruct((1, c), jnp.float32),
                    jax.ShapeDtypeStruct((1, c), jnp.float32)],
         interpret=interpret_flag(),
+        name='batch_norm_stats',
     )(x2d)
 
 
@@ -166,6 +167,7 @@ def _apply_pallas(x2d, res2d, mean, rstd, scale, bias, relu, block_m):
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((m, c), x2d.dtype),
         interpret=interpret_flag(),
+        name='batch_norm_apply',
     )(*args)
 
 

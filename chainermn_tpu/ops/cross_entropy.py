@@ -65,6 +65,7 @@ def _ce_pallas(logits, labels, block_b):
             jax.ShapeDtypeStruct((b, 1), jnp.float32),
         ],
         interpret=interpret_flag(),
+        name='cross_entropy_fwd',
     )(logits, labels[:, None].astype(jnp.int32))
     return loss[:, 0], lse[:, 0]
 

@@ -170,6 +170,7 @@ def _fwd_pallas(q, k, v, causal, scale, kv_len, block_q, block_k):
             pltpu.VMEM((block_q, d), jnp.float32),     # acc
         ],
         interpret=interpret_flag(),
+        name='flash_attention_fwd',
     )(q, k, v)
     return out, lse[..., 0]
 
@@ -383,6 +384,7 @@ def _bwd_pallas(q, k, v, out, lse, g, causal, scale, kv_len,
         out_shape=jax.ShapeDtypeStruct((bh, t_q, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret_flag(),
+        name='flash_attention_bwd_dq',
     )(q, k, v, g, lse3, delta3)
 
     # dk/dv: (b, i=key block, j=query block); for causal, query
@@ -407,6 +409,7 @@ def _bwd_pallas(q, k, v, out, lse, g, causal, scale, kv_len,
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=interpret_flag(),
+        name='flash_attention_bwd_dkv',
     )(q, k, v, g, lse3, delta3)
     return dq, dk, dv
 
@@ -870,6 +873,7 @@ def _decode_paged_pallas(q, k, v, page_tables, lengths, scale,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         interpret=interpret_flag(),
+        name='flash_attention_decode_paged',
     )(page_tables, lengths, q, k, v, ks, vs)
 
 

@@ -52,6 +52,7 @@ def _ln_pallas(x2d, gamma, beta, eps, block_b):
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((b, d), x2d.dtype),
         interpret=interpret_flag(),
+        name='layer_norm_fwd',
     )(x2d, gamma[None, :], beta[None, :])
 
 

@@ -73,6 +73,7 @@ def _leaf_update_pallas(g, v, lr, momentum):
             jax.ShapeDtypeStruct((total_rows, _LANES), jnp.float32),
         ],
         interpret=interpret_flag(),
+        name='sgd_momentum_update',
     )(g2, v2)
 
     def from2d(x, out_dtype):

@@ -523,7 +523,7 @@ class MeshPlanCommunicator(CommunicatorBase):
                 'eager broadcast_data is undefined for a plan-sharded '
                 'tree; place it with '
                 'plan.param_shardings(specs) / multihost_device_put')
-        if _telemetry._active is not None:
+        if _telemetry.live() is not None:
             _telemetry.event(
                 '%s:broadcast_data' % type(self).__name__,
                 kind='collective_trace',

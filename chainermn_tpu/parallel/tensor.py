@@ -39,7 +39,7 @@ def _tp_mark(name, axis):
     model-axis twin of the strategies' allreduce_grad mark, so the
     telemetry report can split dp vs tp collective issues."""
     from chainermn_tpu import telemetry as _telemetry
-    if _telemetry._active is not None:
+    if _telemetry.live() is not None:
         _telemetry.event(name, kind='collective_trace',
                          axes=[axis] if isinstance(axis, str)
                          else list(axis))

@@ -186,7 +186,7 @@ class Request:
         self.t_submit = t_submit
         self.synthetic = synthetic
         self.request_id = request_id or next_request_id()
-        rec = _telemetry.active()
+        rec = _telemetry.live()
         self.t_trace0 = rec.now() if rec is not None else None
         self._done = threading.Event()
         self._result = None

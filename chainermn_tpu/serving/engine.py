@@ -106,6 +106,8 @@ class InferenceEngine:
     def __init__(self, apply_fn, params, example, max_batch=32,
                  edges=None, policy=None, plan=None, param_specs=None,
                  aot=True, label=None, version=0):
+        _telemetry.maybe_enable_from_env()
+        _telemetry.install_compile_log()
         self.apply_fn = apply_fn
         self.policy = policy
         self.plan = plan
@@ -319,8 +321,6 @@ class InferenceEngine:
         self.params = new
         self.param_version = (int(version) if version is not None
                               else self.param_version + 1)
-        _telemetry.event('weight_swap', kind='serve',
-                         **self._ident())
         del old  # the double buffer: freed after cutover
         return self.param_version
 
@@ -400,7 +400,7 @@ class InferenceEngine:
         ``complete``, tiled so the stage budgets sum to the
         end-to-end latency)."""
         clock = clock or time.monotonic
-        rec = _telemetry.active()
+        rec = _telemetry.live()
         reg = _telemetry.registry()
         ident = self._ident()
         t_exec0 = clock()

@@ -526,7 +526,7 @@ def _fresh_monitor(label, version, slos=None):
     replica, even on a recorder shared by the whole fleet.  Returns
     None when telemetry is off."""
     from chainermn_tpu.telemetry.slo import SLOMonitor
-    rec = _telemetry.active()
+    rec = _telemetry.live()
     if rec is None:
         return None
     mon = SLOMonitor(

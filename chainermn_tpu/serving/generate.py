@@ -72,6 +72,14 @@ from chainermn_tpu.utils.failure import OverloadError
 DEFAULT_MAX_QUEUE = 256
 
 
+def _named(fn, name):
+    """``fn`` under the name a jitted executable is to carry."""
+    def call(*args):
+        return fn(*args)
+    call.__name__ = call.__qualname__ = name
+    return call
+
+
 class GenRequest:
     """One in-flight generation request: ``prompt`` (1-D int32 token
     ids), ``max_new_tokens``, optional absolute ``deadline``
@@ -120,7 +128,7 @@ class GenRequest:
         self.prefix_key = prefix_key
         self.on_token = on_token
         self.request_id = request_id or next_request_id()
-        rec = _telemetry.active()
+        rec = _telemetry.live()
         self.t_trace0 = rec.now() if rec is not None else None
         self._done = threading.Event()
         self._result = None
@@ -453,6 +461,10 @@ class GenerationEngine:
         from chainermn_tpu.serving.paged import (PagePool,
                                                  RadixPrefixIndex)
 
+        # a serving process may build neither a communicator nor an
+        # updater: the variable is read here too
+        _telemetry.maybe_enable_from_env()
+        _telemetry.install_compile_log()
         self.model = model
         self.label = label
         self.param_version = int(version)
@@ -695,8 +707,6 @@ class GenerationEngine:
         self.params = new
         self.param_version = (int(version) if version is not None
                               else self.param_version + 1)
-        _telemetry.event('weight_swap', kind='serve',
-                         **self._ident())
         del old  # double buffer freed after cutover
         return self.param_version
 
@@ -853,8 +863,12 @@ class GenerationEngine:
             out_specs=(P(), self._cache_specs), check_vma=False)
 
     # -- compilation ---------------------------------------------------
-    def _compile(self, fn, args, table, key, params=None):
-        exe = jax.jit(fn, donate_argnums=(1,))
+    def _compile(self, fn, args, table, key, name, params=None):
+        """``name`` is the executable's, whatever wraps the body
+        (``shard_map`` or not): ``jit_<name>`` on the profiler's ``XLA
+        Modules`` line.  The decode executables, and no other, hold
+        ``decode`` in theirs; a trace reduction keys on that."""
+        exe = jax.jit(_named(fn, name), donate_argnums=(1,))
         aot = self.aot_requested
         if aot:
             exe = exe.lower(
@@ -939,7 +953,7 @@ class GenerationEngine:
             exe, _ = self._compile(
                 body,
                 (self._cache_struct(),) + self._token_structs(bucket),
-                self._prefill, bucket)
+                self._prefill, bucket, 'serve_prefill')
             return exe
 
     def _get_copy(self):
@@ -958,7 +972,7 @@ class GenerationEngine:
                 (self._cache_struct(),
                  jax.ShapeDtypeStruct((), jnp.int32),
                  jax.ShapeDtypeStruct((), jnp.int32)),
-                table, 'copy')
+                table, 'copy', 'serve_page_copy')
             self._copy = table['copy']
             return exe
 
@@ -1030,7 +1044,7 @@ class GenerationEngine:
             exe, _ = self._compile(
                 self._decode_mapped(bucket),
                 (self._cache_struct(),) + self._decode_structs(bucket),
-                self._decode, bucket)
+                self._decode, bucket, 'serve_decode')
             return exe
 
     def traceable_decode(self, bucket=None):
@@ -1070,7 +1084,7 @@ class GenerationEngine:
             exe, _ = self._compile(
                 body, (self._draft_cache_struct(),)
                 + self._token_structs(bucket),
-                self._draft_prefill, bucket,
+                self._draft_prefill, bucket, 'serve_draft_prefill',
                 params=self._draft_params)
             return exe
 
@@ -1098,7 +1112,7 @@ class GenerationEngine:
                 self._draft_decode_mapped(bucket),
                 (self._draft_cache_struct(),)
                 + self._decode_structs(bucket),
-                self._draft_decode, bucket,
+                self._draft_decode, bucket, 'serve_draft_decode',
                 params=self._draft_params)
             return exe
 
@@ -1130,7 +1144,7 @@ class GenerationEngine:
             exe, _ = self._compile(
                 self._verify_mapped(bucket),
                 (self._cache_struct(),) + self._verify_structs(bucket),
-                self._verify, bucket)
+                self._verify, bucket, 'serve_verify')
             return exe
 
     def _get_draft_copy(self):
@@ -1152,7 +1166,8 @@ class GenerationEngine:
                 (self._draft_cache_struct(),
                  jax.ShapeDtypeStruct((), jnp.int32),
                  jax.ShapeDtypeStruct((), jnp.int32)),
-                table, 'copy', params=self._draft_params)
+                table, 'copy', 'serve_draft_page_copy',
+                params=self._draft_params)
             self._draft_copy = table['copy']
             return exe
 
@@ -1430,12 +1445,20 @@ class GenerationEngine:
         prompt bucket + pad fraction) and ``prefill`` (-> first
         token), each starting where the previous ended."""
         if self.paged:
-            self._admit_paged(queue, now, clock)
+            # no executable runs in here but the rare copy-on-write
+            # page copy: queue pop, prefix lookup, page allocation
+            with _telemetry.span('serve_admit', kind='serve',
+                                 step=self._step_index,
+                                 **self._ident()):
+                self._admit_paged(queue, now, clock)
             return
-        rec = _telemetry.active()
+        rec = _telemetry.live()
         reg = _telemetry.registry()
         ident = self._ident()
-        for req in queue.pop(self._admit_budget()):
+        with _telemetry.span('serve_admit', kind='serve',
+                             step=self._step_index, **ident):
+            popped = queue.pop(self._admit_budget())
+        for req in popped:
             sid = self._free.pop(0)
             prompt = req.prompt
             t_pop = rec.now() if rec is not None else None
@@ -1467,7 +1490,7 @@ class GenerationEngine:
             with _telemetry.span('serve_prefill', kind='serve',
                                  bucket=bucket, slot=sid,
                                  iteration=self._step_index,
-                                 **ident):
+                                 step=self._step_index, **ident):
                 tok, cache = exe(self.params, self._cache, *args)
                 tok = int(jax.block_until_ready(tok))
             self._cache = cache
@@ -1485,7 +1508,7 @@ class GenerationEngine:
                                      stage='prefill', bucket=bucket,
                                      slot=sid,
                                      iteration=self._step_index,
-                                     **ident):
+                                     step=self._step_index, **ident):
                     dtok, dcache = dexe(self._draft_params,
                                         self._draft_cache, *args)
                     jax.block_until_ready(dtok)
@@ -1529,7 +1552,7 @@ class GenerationEngine:
         ONCE, here), and park the request in ``self._prefilling`` --
         the actual prefill work happens chunk-by-chunk in
         :meth:`_prefill_tick`, interleaved with decode steps."""
-        rec = _telemetry.active()
+        rec = _telemetry.live()
         reg = _telemetry.registry()
         ident = self._ident()
         group = self._prefix_index is not None
@@ -1598,7 +1621,7 @@ class GenerationEngine:
         unchanged); intermediate chunks emit ``prefill_chunk`` spans
         the SLO monitor ignores.  A finished prompt's pages are banked
         into the prefix index before the sequence moves to decode."""
-        rec = _telemetry.active()
+        rec = _telemetry.live()
         reg = _telemetry.registry()
         ident = self._ident()
         worked = False
@@ -1649,7 +1672,8 @@ class GenerationEngine:
             with _telemetry.span('serve_prefill', kind='serve',
                                  bucket=width, slot=sid,
                                  chunk=st.chunks, pos=st.pos,
-                                 iteration=self._step_index, **ident):
+                                 iteration=self._step_index,
+                                 step=self._step_index, **ident):
                 tok, cache = exe(self.params, self._cache, *args)
                 tok = jax.block_until_ready(tok)
             self._cache = cache
@@ -1666,7 +1690,7 @@ class GenerationEngine:
                                      stage='prefill', bucket=width,
                                      slot=sid, chunk=st.chunks,
                                      iteration=self._step_index,
-                                     **ident):
+                                     step=self._step_index, **ident):
                     dtok, dcache = dexe(self._draft_params,
                                         self._draft_cache, *args)
                     jax.block_until_ready(dtok)
@@ -1727,10 +1751,11 @@ class GenerationEngine:
                                      pages=st.pages)
         return worked
 
-    def _decode_once(self, clock):
-        """One decode step over every active slot, compacted to the
-        smallest slot-count bucket; finished sequences resolve and
-        free their slots (refilled at the NEXT step)."""
+    def _decode_operands(self):
+        """What one decode step is called with: the rows (slot ids,
+        padded to the smallest slot-count bucket), the live count, the
+        bucket, its executable and the uploaded operands -- or None
+        when growing the page tables shed every live sequence."""
         if self.paged:
             # grow page tables across page boundaries BEFORE dispatch
             # (a sequence whose next token starts a new page gets one
@@ -1748,7 +1773,7 @@ class GenerationEngine:
                         break
                     slot.pages.append(page)
             if not self._slots:
-                return
+                return None
         active = sorted(self._slots)
         k = len(active)
         bucket = bucket_of(k, self.decode_edges)
@@ -1792,9 +1817,24 @@ class GenerationEngine:
                     jnp.asarray(positions))
         self.guard_signature((self._cache_struct(),) + tuple(
             jax.ShapeDtypeStruct(a.shape, a.dtype) for a in args))
-        rec = _telemetry.active()
-        reg = _telemetry.registry()
+        return rows, k, bucket, exe, args
+
+    def _decode_once(self, clock):
+        """One decode step over every active slot, compacted to the
+        smallest slot-count bucket; finished sequences resolve and
+        free their slots (refilled at the NEXT step).  Three spans
+        split it: ``serve_decode_prep`` (numpy operands and their
+        upload), ``serve_decode`` (the executable until the tokens are
+        on the host), ``serve_emit`` (the per-slot loop)."""
         ident = self._ident()
+        with _telemetry.span('serve_decode_prep', kind='serve',
+                             step=self._step_index, **ident):
+            operands = self._decode_operands()
+        if operands is None:
+            return
+        rows, k, bucket, exe, args = operands
+        rec = _telemetry.live()
+        reg = _telemetry.registry()
         if reg is not None:
             reg.gauge('active_slots',
                       help='live sequences at this decode step'
@@ -1805,6 +1845,7 @@ class GenerationEngine:
         t0 = clock()
         with _telemetry.span('serve_decode', kind='serve',
                              iteration=self._step_index,
+                             step=self._step_index,
                              active_slots=k, bucket=bucket,
                              n_slots=self.n_slots,
                              queue_depth=self._last_queue_depth,
@@ -1824,45 +1865,48 @@ class GenerationEngine:
                              help='per-sequence gap between '
                                   'consecutive tokens (s)')
                if reg is not None else None)
-        for i, sid in enumerate(rows):
-            slot = self._slots.get(sid)
-            if slot is None:
-                continue   # free pad row (or inactive full-bucket row)
-            tok = int(toks[i])
-            slot.generated.append(tok)
-            slot.request.notify_tokens([tok])
-            slot.position += 1
-            slot.remaining -= 1
-            if itl is not None:
-                itl.observe(now - slot.t_last_token)
-            slot.t_last_token = now
-            if rec is not None:
-                # one decode stage per live slot per tick, starting at
-                # the request's previous stage end: the span absorbs
-                # any scheduler wait between ticks (a neighbor's slow
-                # prefill IS latency this request paid), which is
-                # exactly what makes the stage budgets sum to the
-                # end-to-end latency
-                t_prev = slot.t_stage_end
-                if t_prev is None:
-                    t_prev = now_tele - (now - t0)
-                rec.child_span(slot.request.request_id, 'decode',
-                               t_prev, now_tele, slot=sid,
-                               step=self._step_index,
-                               token_index=len(slot.generated) - 1,
-                               **ident)
-                slot.t_stage_end = now_tele
-            if slot.remaining == 0 or (self.eos_id is not None
-                                       and tok == self.eos_id):
-                slot.request.set_result(slot.generated)
+        with _telemetry.span('serve_emit', kind='serve',
+                             step=self._step_index, active_slots=k,
+                             **ident):
+            for i, sid in enumerate(rows):
+                slot = self._slots.get(sid)
+                if slot is None:
+                    continue   # free pad row (or inactive full row)
+                tok = int(toks[i])
+                slot.generated.append(tok)
+                slot.request.notify_tokens([tok])
+                slot.position += 1
+                slot.remaining -= 1
+                if itl is not None:
+                    itl.observe(now - slot.t_last_token)
+                slot.t_last_token = now
                 if rec is not None:
-                    rec.event('complete', kind='request',
-                              request_id=slot.request.request_id,
-                              tokens=len(slot.generated), slot=sid,
-                              **ident)
-                self._release_pages(slot.pages)
-                del self._slots[sid]
-                self._free.append(sid)
+                    # one decode stage per live slot per tick, starting at
+                    # the request's previous stage end: the span absorbs
+                    # any scheduler wait between ticks (a neighbor's slow
+                    # prefill IS latency this request paid), which is
+                    # exactly what makes the stage budgets sum to the
+                    # end-to-end latency
+                    t_prev = slot.t_stage_end
+                    if t_prev is None:
+                        t_prev = now_tele - (now - t0)
+                    rec.child_span(slot.request.request_id, 'decode',
+                                   t_prev, now_tele, slot=sid,
+                                   step=self._step_index,
+                                   token_index=len(slot.generated) - 1,
+                                   **ident)
+                    slot.t_stage_end = now_tele
+                if slot.remaining == 0 or (self.eos_id is not None
+                                           and tok == self.eos_id):
+                    slot.request.set_result(slot.generated)
+                    if rec is not None:
+                        rec.event('complete', kind='request',
+                                  request_id=slot.request.request_id,
+                                  tokens=len(slot.generated), slot=sid,
+                                  **ident)
+                    self._release_pages(slot.pages)
+                    del self._slots[sid]
+                    self._free.append(sid)
         self.decode_steps += 1
         self.tokens_generated += k
 
@@ -1924,7 +1968,7 @@ class GenerationEngine:
                 if sid is not None:
                     pages = self._slots[sid].pages
                     tables[i, :len(pages)] = pages
-        rec = _telemetry.active()
+        rec = _telemetry.live()
         reg = _telemetry.registry()
         ident = self._ident()
         if reg is not None:
@@ -1953,6 +1997,7 @@ class GenerationEngine:
         with _telemetry.span('serve_draft', kind='serve',
                              stage='decode',
                              iteration=self._step_index,
+                             step=self._step_index,
                              active_slots=k, bucket=bucket,
                              window=kk, **ident):
             for j in range(kk):
@@ -1986,6 +2031,7 @@ class GenerationEngine:
             jax.ShapeDtypeStruct(a.shape, a.dtype) for a in vargs))
         with _telemetry.span('serve_verify', kind='serve',
                              iteration=self._step_index,
+                             step=self._step_index,
                              active_slots=k, bucket=bucket,
                              window=kk, n_slots=self.n_slots,
                              queue_depth=self._last_queue_depth,
@@ -2129,8 +2175,23 @@ class GenerationEngine:
         ``serve_decode_backlog`` (live slots still generating) -- so
         pressure ONSET is visible in captures, not just its latency
         consequences; the engine's in-flight request table is also
-        registered as a flight-dump source."""
-        rec = _telemetry.active()
+        registered as a flight-dump source.  The tick is one
+        ``serve_tick`` span, the parent of ``serve_admit``,
+        ``serve_prefill``, ``serve_decode_prep``, ``serve_decode`` and
+        ``serve_emit``: what they leave uncovered is the tick's own
+        host time."""
+        with _telemetry.span('serve_tick', kind='serve',
+                             step=self._step_index,
+                             **self._ident()) as tick:
+            prefills = self.prefills
+            worked = self._tick(queue, clock)
+            tick.set(queue_depth=self._last_queue_depth,
+                     prefills=self.prefills - prefills,
+                     active_slots=len(self._slots))
+        return worked
+
+    def _tick(self, queue, clock):
+        rec = _telemetry.live()
         depth = queue.depth()
         self._last_queue_depth = depth
         if rec is not None:

@@ -97,7 +97,7 @@ def open_loop_generate(engine, queue, rate, n_requests, seed=0,
                for n in lens]
 
     _installed = None
-    if _telemetry.active() is None:
+    if _telemetry.live() is None:
         _installed = _telemetry.enable()
     recorder = _telemetry.active()
     if slo_monitor is not None:
@@ -303,7 +303,7 @@ def open_loop(engine, queue, rate, n_requests, seed=0,
     # recorder for the window (the bench skew-capture idiom) so the
     # report never fabricates and never comes back empty-handed
     _installed = None
-    if _telemetry.active() is None:
+    if _telemetry.live() is None:
         _installed = _telemetry.enable()
     recorder = _telemetry.active()
 

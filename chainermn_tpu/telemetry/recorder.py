@@ -5,7 +5,8 @@ other env-activated runtime layer):
 
 - **Zero cost when off.**  Nothing in this module runs on a
   telemetry-free hot path; call sites guard on the package-level
-  ``telemetry._active is None`` (one attribute load + identity check)
+  ``telemetry.live() is None`` (one attribute load, an identity check
+  and ``TraceAnnotation.is_enabled()``)
   or go through :func:`chainermn_tpu.telemetry.span`, whose off path
   returns a preallocated no-op context.
 - **Monotonic spans, wall-aligned at record time.**  Durations come
@@ -14,18 +15,25 @@ other env-activated runtime layer):
   pair captured at construction, so per-rank logs from one machine
   (the CPU multi-controller harness) merge into one timeline without
   post-hoc skew fitting.
-- **Optional device-sync fences.**  A span wrapping device work
-  measures DISPATCH unless the telemetry session requests fences
-  (``CHAINERMN_TPU_TELEMETRY_SYNC=1``): then ``span.sync(out)``
-  blocks on the device values before the span closes and the span is
-  tagged ``synced=True``.  Fences serialize the device -- they are a
-  measurement mode, not a default.
+- **One span primitive, two sinks.**  :meth:`Recorder.span` writes its
+  record AND enters ``jax.profiler.TraceAnnotation('cmn:<name>')``, so
+  every layer-boundary span also lands in the profiler's trace, on the
+  clock the device events are on.  A span around device work measures
+  DISPATCH; completion is what the trace's device lines show (the
+  fences that made a host span wait for the device are gone: they
+  serialised what they measured).
+- **Every span says what caused it.**  A span record carries ``id``,
+  ``parent`` (the innermost span open on the same thread when it was
+  entered, or None) and ``thread`` (the OS thread id the profiler's
+  host lines are keyed by), so a layer's self time is its span less
+  what its children cover.
 
 Event-log schema (JSONL, one file per rank, first line is ``meta``)::
 
     {"type": "meta", "rank": 0, "pid": 123, "wall0": ..., "argv": ...}
     {"type": "span", "name": "jitted_step", "kind": "compute",
-     "t0": <wall s>, "t1": <wall s>, "rank": 0, ...attrs}
+     "t0": <wall s>, "t1": <wall s>, "id": 7, "parent": 5,
+     "thread": 4242, "rank": 0, ...attrs}
     {"type": "event", "name": "chaos:drop_send", "kind": "chaos",
      "t": <wall s>, "rank": 0, ...attrs}
 
@@ -38,12 +46,14 @@ marks -- they fire once per compilation, not per step).
 """
 
 import collections
-import contextlib
+import itertools
 import json
 import os
 import sys
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
 
 #: histogram sample retention cap -- long trainings must not grow
 #: memory without bound; percentile accuracy over the newest samples
@@ -56,6 +66,10 @@ MAX_EVENTS = 1 << 20
 #: preserves (`Recorder.dump_flight`); small on purpose: the flight
 #: record is the black box read AFTER a death, not the full log
 FLIGHT_RING = 256
+#: prefix of a span's name in the profiler's trace
+TRACE_PREFIX = 'cmn:'
+#: the scalar attributes a span's ``TraceAnnotation`` carries
+TRACE_ATTRS = ('iteration', 'step', 'bucket', 'active_slots')
 
 
 def _percentile(sorted_vals, q):
@@ -276,29 +290,58 @@ def snapshot_to_prometheus(snapshot, prefix='chainermn_tpu_'):
 
 
 class _SpanHandle:
-    """What ``with recorder.span(...) as sp`` yields: lets the caller
-    attach attributes discovered mid-span and request the device-sync
-    fence."""
+    """``recorder.span(...)``: the context manager, and what ``with
+    ... as sp`` yields -- the caller attaches attributes discovered
+    mid-span with :meth:`set`."""
 
-    __slots__ = ('_recorder', 'attrs', 'synced')
+    __slots__ = ('_recorder', '_annotation', 'name', 'kind', 'attrs',
+                 'id', 'parent', 't0')
 
-    def __init__(self, recorder, attrs):
+    def __init__(self, recorder, name, kind, attrs):
         self._recorder = recorder
-        self.attrs = attrs
-        self.synced = False
+        self.name, self.kind, self.attrs = name, kind, attrs
 
     def set(self, **attrs):
         self.attrs.update(attrs)
 
-    def sync(self, value):
-        """Block on device values before the span closes -- only when
-        the telemetry session requested fences; otherwise a no-op, so
-        call sites need no conditional."""
-        if self._recorder.sync_fences and value is not None:
-            import jax
-            jax.block_until_ready(value)
-            self.synced = True
-        return value
+    def __enter__(self):
+        rec = self._recorder
+        stack = rec._span_stack()
+        self.parent = stack[-1] if stack else None
+        self.id = next(rec._span_ids)
+        stack.append(self.id)
+        attrs = self.attrs
+        self._annotation = TraceAnnotation(
+            TRACE_PREFIX + self.name,
+            **{k: attrs[k] for k in TRACE_ATTRS if k in attrs})
+        self._annotation.__enter__()
+        self.t0 = rec.now()
+        # `attrs` is the LIVE dict: attributes set mid-span are visible
+        # in a flight dump of the open span.  Lock-free on purpose
+        # (id-keyed dict set/del are GIL-atomic): this sits on the
+        # enabled hot path the <2% overhead pin bounds; dump_flight
+        # tolerates a transiently-inconsistent view
+        rec._open_spans[self.id] = {'name': self.name,
+                                    'kind': self.kind, 't0': self.t0,
+                                    'attrs': attrs}
+        return self
+
+    def __exit__(self, *exc):
+        rec = self._recorder
+        t1 = rec.now()
+        self._annotation.__exit__(*exc)
+        stack = rec._span_stack()
+        if stack and stack[-1] == self.id:
+            stack.pop()
+        rec._open_spans.pop(self.id, None)
+        record = {'type': 'span', 'name': self.name, 'kind': self.kind,
+                  't0': self.t0, 't1': t1, 'id': self.id,
+                  'parent': self.parent,
+                  'thread': threading.get_native_id()}
+        if self.attrs:
+            record.update(self.attrs)
+        rec._append(record)
+        return False
 
 
 class _NullSpan:
@@ -306,7 +349,6 @@ class _NullSpan:
 
     __slots__ = ()
     attrs = None
-    synced = False
 
     def __enter__(self):
         return self
@@ -317,9 +359,6 @@ class _NullSpan:
     def set(self, **attrs):
         pass
 
-    def sync(self, value):
-        return value
-
 
 NULL_SPAN = _NullSpan()
 
@@ -328,10 +367,12 @@ class Recorder:
     """One process's telemetry session: spans, events, metrics, and
     the per-rank JSONL/JSON flush."""
 
-    def __init__(self, outdir=None, sync_fences=False,
-                 flight_ring=FLIGHT_RING):
+    #: set by ``telemetry.live`` on the recorder it installs because
+    #: a JAX profiler session was found open: records only while one is
+    follows_profiler = False
+
+    def __init__(self, outdir=None, flight_ring=FLIGHT_RING):
         self.outdir = outdir
-        self.sync_fences = bool(sync_fences)
         self.registry = Registry()
         self.events = []
         self._lock = threading.Lock()
@@ -341,6 +382,8 @@ class Recorder:
         self._wall0 = time.time()
         self._flushed_upto = 0
         self._meta_written = False
+        self._span_ids = itertools.count(1)
+        self._threads = threading.local()
         # flight recorder: the last N records, cheap to maintain and
         # small enough to dump atomically from a dying process
         self._flight = collections.deque(maxlen=flight_ring)
@@ -373,6 +416,20 @@ class Recorder:
     # -- clock ---------------------------------------------------------
     def now(self):
         return self._wall0 + (time.perf_counter() - self._mono0)
+
+    def to_perf_counter(self, t):
+        """A recorded time on ``time.perf_counter()``'s axis -- what a
+        reader needs to lay records onto a window it timed itself."""
+        return t - self._wall0 + self._mono0
+
+    def _span_stack(self):
+        """Ids of the spans open on the calling thread, innermost
+        last."""
+        try:
+            return self._threads.stack
+        except AttributeError:
+            stack = self._threads.stack = []
+            return stack
 
     # -- recording -----------------------------------------------------
     def _append(self, rec):
@@ -412,29 +469,10 @@ class Recorder:
         except ValueError:
             pass
 
-    @contextlib.contextmanager
     def span(self, name, kind='generic', **attrs):
-        handle = _SpanHandle(self, attrs)
-        t0 = self.now()
-        # `attrs` is the handle's LIVE dict: attributes set mid-span
-        # (sp.set(...)) are visible in a flight dump of the open span.
-        # Lock-free on purpose (id-keyed dict set/del are GIL-atomic):
-        # this sits on the enabled hot path the <2% overhead pin
-        # bounds; dump_flight tolerates a transiently-inconsistent
-        # view
-        self._open_spans[id(handle)] = {'name': name, 'kind': kind,
-                                        't0': t0, 'attrs': attrs}
-        try:
-            yield handle
-        finally:
-            self._open_spans.pop(id(handle), None)
-            rec = {'type': 'span', 'name': name, 'kind': kind,
-                   't0': t0, 't1': self.now()}
-            if handle.synced:
-                rec['synced'] = True
-            if handle.attrs:
-                rec.update(handle.attrs)
-            self._append(rec)
+        """Context manager timing the enclosed block: one record here
+        and one ``cmn:<name>`` annotation in the profiler's trace."""
+        return _SpanHandle(self, name, kind, attrs)
 
     def event(self, name, kind='event', **attrs):
         rec = {'type': 'event', 'name': name, 'kind': kind,
@@ -502,7 +540,6 @@ class Recorder:
                 f.write(json.dumps({
                     'type': 'meta', 'rank': rank, 'pid': os.getpid(),
                     'wall0': self._wall0,
-                    'sync_fences': self.sync_fences,
                     'argv': list(sys.argv)}) + '\n')
                 self._meta_written = True
             for rec in pending:

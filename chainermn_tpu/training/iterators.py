@@ -11,6 +11,8 @@ import queue as queue_mod
 
 import numpy as np
 
+from chainermn_tpu import telemetry as _telemetry
+
 
 class SerialIterator:
     """Single-thread batch iterator with epoch accounting."""
@@ -280,7 +282,11 @@ class MultiprocessIterator(_PrefetchingIterator):
 
     def _produce(self):
         inner = self._source
-        batch = next(inner)
+        # dataset indexing to a list of examples, in the producer
+        # thread (so the span has no parent)
+        with _telemetry.span('batch_fetch', kind='host',
+                             iteration=inner.iteration):
+            batch = next(inner)
         return (batch, inner.epoch, inner.iteration,
                 inner.is_new_epoch, inner._pos)
 

@@ -326,6 +326,7 @@ class PipelineUpdater:
                         'Probe result: %s  Pass schedule_check=False '
                         'to bypass.' % e) from e
         _telemetry.maybe_enable_from_env()
+        _telemetry.install_compile_log()
         self.iterator = iterator
         self.optimizer = optimizer
         self.mesh = mesh
@@ -480,12 +481,11 @@ class PipelineUpdater:
             """Trace-time telemetry (fires once per compilation, like
             the strategies' collective-issue marks): the schedule's
             static bubble accounting -- what `telemetry report` turns
-            into the per-stage bubble fraction -- plus the stage-
-            boundary ppermute tagged with its mesh axis, and the
-            trace counter behind the flat-trace acceptance pin."""
+            into the per-stage bubble fraction -- and the trace
+            counter behind the flat-trace acceptance pin."""
             from chainermn_tpu.parallel.pipeline import schedule_ticks
             updater_self.trace_count += 1
-            if _telemetry._active is None:
+            if _telemetry.live() is None:
                 return
             _telemetry.event(
                 'pipeline:schedule', kind='pipeline',
@@ -494,8 +494,6 @@ class PipelineUpdater:
                 total_ticks=schedule_ticks(n_micro_, n_stages,
                                            schedule),
                 axes=[ax_s])
-            _telemetry.event('pipeline:ppermute',
-                             kind='collective_trace', axes=[ax_s])
 
         # IMPORTANT: differentiate OUTSIDE the shard_map.  With
         # ``check_vma=False`` (which the ragged metrics outputs need),
@@ -569,7 +567,7 @@ class PipelineUpdater:
                 out_specs=(P(), P()), check_vma=False)(
                     params, extra, x, y)
 
-        def train_step(params, extra, opt_state, x, y):
+        def pipeline_train_step(params, extra, opt_state, x, y):
             _mark_schedule()
             (loss, metrics), grads = jax.value_and_grad(
                 mapped_loss, argnums=(0, 1), has_aux=True)(
@@ -770,7 +768,7 @@ class PipelineUpdater:
                 s_local, opt_specs)
             return p_out, new_extra, s_out, dict(metrics, loss=loss)
 
-        def train_step_1f1b(params, extra, opt_state, x, y):
+        def pipeline_train_step_1f1b(params, extra, opt_state, x, y):
             _mark_schedule()
             return jax.shard_map(
                 device_step_1f1b, mesh=mesh,
@@ -786,8 +784,8 @@ class PipelineUpdater:
             kw = {}
         # the raw (unjitted, undonated) step: bench scan makers wrap
         # it in their own outer jit to run k steps as one program
-        self._raw_step = (train_step if schedule == 'gpipe'
-                          else train_step_1f1b)
+        self._raw_step = (pipeline_train_step if schedule == 'gpipe'
+                          else pipeline_train_step_1f1b)
         self._step = jax.jit(self._raw_step, **kw)
         # forward-only path for evaluation: same pipeline schedule and
         # loss, NO gradient/optimizer (params not donated)
@@ -808,9 +806,9 @@ class PipelineUpdater:
                 arrays = tuple(arrays.values())
         data_sharding = NamedSharding(self.mesh, P(self._axis_data))
         with _telemetry.span('h2d', kind='h2d',
-                             iteration=self.iteration) as sp:
-            return sp.sync(tuple(jax.device_put(a, data_sharding)
-                                 for a in arrays))
+                             iteration=self.iteration):
+            return tuple(jax.device_put(a, data_sharding)
+                         for a in arrays)
 
     def traceable_step(self, arrays, iteration=None):
         """``(fn, args)`` of the jitted pipeline train step for
@@ -824,13 +822,8 @@ class PipelineUpdater:
                             self.opt_state) + tuple(arrays)
 
     def update_core(self, arrays):
-        if _telemetry._active is not None:
-            with _telemetry.span('jitted_step', kind='compute',
-                                 iteration=self.iteration) as sp:
-                out = self._step(self.params, self.extra,
-                                 self.opt_state, *arrays)
-                sp.sync(out)
-        else:
+        with _telemetry.span('jitted_step', kind='compute',
+                             iteration=self.iteration):
             out = self._step(self.params, self.extra, self.opt_state,
                              *arrays)
         self.params, self.extra, self.opt_state, metrics = out
@@ -842,12 +835,18 @@ class PipelineUpdater:
         ``StandardUpdater.update``: ``sync=False`` returns the
         device-resident metric arrays (no host round trip) for
         ``Trainer(async_metrics=True)``."""
-        metrics = self.update_core(self.shard_batch(next(self.iterator)))
-        if not sync:
-            return dict(metrics)
-        with _telemetry.span('metrics_sync', kind='host',
-                             iteration=self.iteration - 1):
-            return {k: float(v) for k, v in metrics.items()}
+        iteration = self.iteration
+        with _telemetry.span('train_update', kind='step',
+                             iteration=iteration):
+            with _telemetry.span('input_wait', kind='host',
+                                 iteration=iteration):
+                batch = next(self.iterator)
+            metrics = self.update_core(self.shard_batch(batch))
+            if not sync:
+                return dict(metrics)
+            with _telemetry.span('metrics_sync', kind='host',
+                                 iteration=iteration):
+                return {k: float(v) for k, v in metrics.items()}
 
     def evaluate(self, arrays):
         """Forward-only metrics on already-sharded arrays: runs the

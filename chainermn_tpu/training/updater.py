@@ -136,6 +136,7 @@ class StandardUpdater:
         ``bench.py --donate``.
         """
         _telemetry.maybe_enable_from_env()
+        _telemetry.install_compile_log()
         self.iterator = iterator
         self.optimizer = optimizer
         self.comm = comm
@@ -446,8 +447,9 @@ class StandardUpdater:
                     # is widened back for the optimizer update
                     g = jax.tree_util.tree_map(
                         lambda x: x.astype(reduce_dtype), g)
-                g_sh = jax.tree_util.tree_map(
-                    lambda g_: z.scatter_grad_leaf(g_, n, axes), g)
+                with jax.named_scope('grad_allreduce'):
+                    g_sh = jax.tree_util.tree_map(
+                        lambda g_: z.scatter_grad_leaf(g_, n, axes), g)
                 if reduce_dtype is not None:
                     g_sh = jax.tree_util.tree_map(
                         lambda r, g0: r.astype(g0.dtype), g_sh, grads)
@@ -462,7 +464,8 @@ class StandardUpdater:
                 # per-shard sums
                 with z.mesh_norm_scope(
                         lambda t: z.axes_sumsq(t, axes),
-                        leaf_sumsq=lambda x: z.axes_sumsq(x, axes)):
+                        leaf_sumsq=lambda x: z.axes_sumsq(x, axes)), \
+                        jax.named_scope('optimizer_update'):
                     updates, new_opt = optimizer.update(
                         g_sh, opt_local, p_sh)
                 upd_full = jax.tree_util.tree_map(
@@ -530,8 +533,10 @@ class StandardUpdater:
         n_lead = len(lead_specs)
 
         # arity of in_specs depends on the batch tuple; resolved at
-        # trace time (jit caches per shape signature)
-        def mapped_call(*args):
+        # trace time (jit caches per shape signature).  The name is
+        # the executable's (``jit_train_step`` on the profiler's
+        # ``XLA Modules`` line): what a trace reduction keys on
+        def train_step(*args):
             self.trace_count += 1  # fires per compilation, not per step
             n_batch = len(args) - n_lead
             fn = jax.shard_map(
@@ -541,7 +546,7 @@ class StandardUpdater:
             return fn(*args)
 
         jit_kwargs = {'donate_argnums': (0, 1, 2)} if donate else {}
-        return jax.jit(mapped_call, static_argnums=(), **jit_kwargs)
+        return jax.jit(train_step, static_argnums=(), **jit_kwargs)
 
     def shard_batch(self, batch):
         """Collate a list of examples and place it sharded on the mesh
@@ -564,13 +569,9 @@ class StandardUpdater:
                     % (n, self.comm.size, self._accum_steps))
         # comm.shard_batch records its own 'h2d' span; tag the step
         # index on a sibling so the timeline groups H2D per iteration
-        if _telemetry._active is not None:
-            with _telemetry.span('h2d', kind='h2d',
-                                 iteration=self.iteration) as sp:
-                out = self.comm.shard_batch(arrays)
-                sp.sync(out)
-            return out
-        return self.comm.shard_batch(arrays)
+        with _telemetry.span('h2d', kind='h2d',
+                             iteration=self.iteration):
+            return self.comm.shard_batch(arrays)
 
     def _step_args(self, arrays, iteration=None):
         """The exact argument tuple one train-step call receives at
@@ -606,15 +607,10 @@ class StandardUpdater:
         overlap)."""
         if _chaos._active is not None:  # sigterm_step / kill_step
             _chaos.on_step(self.iteration)
-        if _telemetry._active is not None:
-            # measures DISPATCH unless the session requested fences
-            # (CHAINERMN_TPU_TELEMETRY_SYNC=1): sp.sync then blocks on
-            # the step's outputs so the span covers device completion
-            with _telemetry.span('jitted_step', kind='compute',
-                                 iteration=self.iteration) as sp:
-                out = self._step(*self._step_args(arrays))
-                sp.sync(out)
-        else:
+        # measures DISPATCH: completion is on the device's own lines
+        # of the profiler's trace, under ``jit_train_step``
+        with _telemetry.span('jitted_step', kind='compute',
+                             iteration=self.iteration):
             out = self._step(*self._step_args(arrays))
         if self._loss_scale is not None:
             (self.params, self.model_state, self.opt_state,
@@ -633,17 +629,24 @@ class StandardUpdater:
         ahead and the device never idles between steps; convert with
         ``float()`` only where a value is actually consumed (see
         ``Trainer(async_metrics=True)``)."""
-        batch = next(self.iterator)
-        metrics = self.update_core(
-            batch if self._device_prefetch else self.shard_batch(batch))
-        if not sync:
-            return dict(metrics)
-        if _telemetry._active is not None:
+        iteration = self.iteration
+        with _telemetry.span('train_update', kind='step',
+                             iteration=iteration):
+            # the consumer's wait for a batch: what the producer
+            # threads (``batch_fetch``; ``host_batch_prep`` and ``h2d``
+            # under ``device_prefetch``) did not hide
+            with _telemetry.span('input_wait', kind='host',
+                                 iteration=iteration):
+                batch = next(self.iterator)
+            metrics = self.update_core(
+                batch if self._device_prefetch
+                else self.shard_batch(batch))
+            if not sync:
+                return dict(metrics)
             # the host-device round trip the sync=True contract pays
             with _telemetry.span('metrics_sync', kind='host',
-                                 iteration=self.iteration - 1):
+                                 iteration=iteration):
                 return {k: float(v) for k, v in metrics.items()}
-        return {k: float(v) for k, v in metrics.items()}
 
     def compiled_cost_analysis(self, arrays):
         """XLA cost analysis (flops etc.) of the compiled train step

@@ -335,7 +335,7 @@ class FaultInjector:
             # Lazy import: chaos must stay importable standalone, and
             # the kill/exit sites flush below before the process dies.
             from chainermn_tpu import telemetry
-            if telemetry._active is not None:
+            if telemetry.live() is not None:
                 telemetry.event('chaos:' + site, kind='chaos',
                                 occurrence=idx, arg=rule.arg)
                 if site in ('kill_step', 'kill_recv', 'ckpt_kill',

@@ -52,7 +52,7 @@ def _flight_dump(reason, **attrs):
     typed verdict)."""
     try:
         from chainermn_tpu import telemetry
-        if telemetry._active is not None:
+        if telemetry.live() is not None:
             telemetry.dump_flight(reason, **attrs)
     except Exception:
         pass

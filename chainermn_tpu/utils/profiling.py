@@ -41,8 +41,11 @@ def trace(logdir):
 
 
 def annotate(name):
-    """Named region visible in the device trace."""
-    return jax.profiler.TraceAnnotation(name)
+    """Named region: a :func:`chainermn_tpu.telemetry.span`, so it is
+    ``cmn:<name>`` in the profiler's trace and a record of the
+    telemetry session -- the repo's one way to name a region.  With
+    neither a profiler session nor a recorder it is a no-op."""
+    return _telemetry.span(name, kind='region')
 
 
 class StepTimer:
@@ -86,7 +89,7 @@ class StepTimer:
         if self._last is not None:
             dt = now - self._last
             self._hist.observe(dt)
-            rec = _telemetry.active()
+            rec = _telemetry.live()
             if rec is not None:
                 rec._append({'type': 'span', 'name': 'step',
                              'kind': 'compute',

@@ -42,7 +42,10 @@ def main():
                         help='override mesh shape, e.g. 2x4')
     parser.add_argument('--profile', default='',
                         help='capture a device trace into this dir '
-                             '(view in TensorBoard)')
+                             '(view in TensorBoard); the program\'s '
+                             'own spans (cmn:train_update, '
+                             'cmn:jitted_step, ...) are in it with no '
+                             'further flag')
     parser.add_argument('--quick', action='store_true',
                         help='tiny run for smoke testing')
     parser.add_argument('--policy', default=None,

@@ -1,0 +1,507 @@
+"""The program's own spans in the profiler's trace (ISSUE 25): an open
+JAX profiler session is an enabled telemetry session, every
+layer-boundary span is a ``cmn:<name>`` annotation on the clock the
+device events are on and a recorder record that names its parent and
+its thread; executables and kernels carry stable names; and the
+benchmark's two new readers read what the program keeps."""
+
+import glob
+import importlib.util
+import os
+import time
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+import chainermn_tpu
+from chainermn_tpu import serving, telemetry, training
+from chainermn_tpu.models import MLP, Classifier, TransformerLM
+from chainermn_tpu.telemetry import recorder as rec_mod
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(autouse=True)
+def _clean_telemetry():
+    telemetry.disable()
+    yield
+    telemetry.disable()
+
+
+def _updater(device_prefetch):
+    comm = chainermn_tpu.create_communicator('xla', mesh_shape=(2, 4))
+    model = MLP(n_units=16, n_out=10)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 784), jnp.float32))
+    opt = chainermn_tpu.create_multi_node_optimizer(optax.sgd(0.1), comm)
+    rs = np.random.RandomState(0)
+    data = [(rs.randn(784).astype(np.float32), np.int32(i % 10))
+            for i in range(64)]
+    if device_prefetch:
+        iterator = training.iterators.MultiprocessIterator(
+            data, 16, shuffle=False)
+    else:
+        iterator = training.SerialIterator(data, 16, shuffle=False)
+    return training.StandardUpdater(
+        iterator, opt, Classifier(model.apply), params, comm,
+        has_aux=True, device_prefetch=device_prefetch)
+
+
+def _engine():
+    model = TransformerLM(vocab_size=32, d_model=32, n_heads=4,
+                          n_layers=1, d_ff=32, max_len=64)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 4), jnp.int32))['params']
+    eng = serving.GenerationEngine(model, params, n_slots=2,
+                                   max_prompt_len=8, paged=True,
+                                   page_size=8)
+    eng.warmup()
+    return eng, serving.GenerationQueue(max_prompt_len=8, page_size=8)
+
+
+class _Traced:
+    """What one profiled block left: the ``cmn:`` events of the
+    ``.xplane.pb`` by host line, and the recorder's records on
+    ``time.perf_counter``, with the host-clock window around both."""
+
+    def __init__(self, tmp_path, body):
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 2
+        self.t0 = time.perf_counter()
+        jax.profiler.start_trace(str(tmp_path),
+                                 profiler_options=options)
+        try:
+            body()
+        finally:
+            jax.profiler.stop_trace()
+        self.t1 = time.perf_counter()
+        path, = glob.glob(os.path.join(str(tmp_path), '**',
+                                       '*.xplane.pb'), recursive=True)
+        self.lines = {}     # line id -> [(name, start, end, stats)]
+        for plane in jax.profiler.ProfileData.from_file(path).planes:
+            if not plane.name.startswith('/host:'):
+                continue
+            for index, line in enumerate(plane.lines):
+                events = [(ev.name[len(rec_mod.TRACE_PREFIX):],
+                           ev.start_ns, ev.start_ns + ev.duration_ns,
+                           {k: v for k, v in ev.stats})
+                          for ev in line.events
+                          if ev.name.startswith(rec_mod.TRACE_PREFIX)]
+                if events:
+                    self.lines[index] = events
+        rec = telemetry.active()
+        self.records = [
+            dict(r, p0=rec.to_perf_counter(r['t0']),
+                 p1=rec.to_perf_counter(r['t1']))
+            for r in rec.events if r['type'] == 'span']
+
+    def line_of(self, name):
+        """The one host line whose events include ``name``."""
+        line, = [k for k, events in self.lines.items()
+                 if any(n == name for n, _, _, _ in events)]
+        return line
+
+    def trace_events(self, name):
+        return [e for events in self.lines.values() for e in events
+                if e[0] == name]
+
+    def named(self, name):
+        return [r for r in self.records if r['name'] == name]
+
+    def check_children_inside_parents(self):
+        by_id = {r['id']: r for r in self.records if 'id' in r}
+        linked = 0
+        for r in by_id.values():
+            parent = by_id.get(r['parent'])
+            if parent is None:
+                continue
+            linked += 1
+            assert parent['thread'] == r['thread']
+            assert parent['p0'] <= r['p0'] and r['p1'] <= parent['p1']
+        assert linked
+        for events in self.lines.values():
+            for name, a, b, _ in events:
+                for other, c, d, _ in events:
+                    # one thread's spans nest or are disjoint
+                    assert b <= c or d <= a or (a <= c and d <= b) \
+                        or (c <= a and b <= d), (name, other)
+
+    def check_records_inside_the_window(self):
+        assert self.records
+        for r in self.records:
+            assert self.t0 <= r['p0'] <= r['p1'] <= self.t1, r['name']
+
+
+@pytest.mark.parametrize('device_prefetch', [0, 2])
+def test_profiler_session_switches_the_trainers_spans_on(
+        tmp_path, device_prefetch):
+    """(a) No telemetry enabled: the profiler's trace holds the
+    trainer's ``cmn:`` spans, the producers' on threads of their own,
+    children inside their parents, and the recorder's records lie in
+    the host-clock window around the session."""
+    upd = _updater(device_prefetch)
+    try:
+        upd.update()
+        upd.update()
+        assert telemetry.active() is None
+        traced = _Traced(tmp_path, lambda: [upd.update()
+                                            for _ in range(4)])
+    finally:
+        finalize = getattr(upd.iterator, 'finalize', None)
+        if finalize is not None:
+            finalize()
+    assert telemetry.active().follows_profiler
+    assert telemetry.live() is None     # the session is closed
+    expected = ['train_update', 'input_wait', 'host_batch_prep', 'h2d',
+                'jitted_step', 'metrics_sync']
+    if device_prefetch:
+        expected.append('batch_fetch')
+    for name in expected:
+        assert traced.trace_events(name), name
+        assert traced.named(name), name
+    main = traced.line_of('train_update')
+    for name in ('input_wait', 'jitted_step', 'metrics_sync'):
+        assert traced.line_of(name) == main
+    update_ids = {r['id'] for r in traced.named('train_update')}
+    assert len(update_ids) == 4
+    for name in ('input_wait', 'jitted_step', 'metrics_sync'):
+        assert {r['parent'] for r in traced.named(name)} == update_ids
+    if device_prefetch:
+        # both producers are threads of their own, with no parent
+        fetch = traced.line_of('batch_fetch')
+        place = traced.line_of('host_batch_prep')
+        assert len({main, fetch, place}) == 3
+        assert traced.line_of('h2d') == place
+        for name in ('batch_fetch', 'host_batch_prep', 'h2d'):
+            assert {r['parent'] for r in traced.named(name)} == {None}
+    else:
+        assert traced.line_of('host_batch_prep') == main
+        assert {r['parent'] for r in traced.named('host_batch_prep')} \
+            == update_ids
+    # the trainer's spans carry the iteration, in both sinks
+    iterations = [r['iteration'] for r in traced.named('train_update')]
+    assert iterations == [2, 3, 4, 5]
+    assert [s['iteration'] for _, _, _, s in
+            traced.trace_events('train_update')] == iterations
+    traced.check_children_inside_parents()
+    traced.check_records_inside_the_window()
+
+
+def test_profiler_session_switches_the_schedulers_spans_on(tmp_path):
+    """(a) The paged engine: one ``serve_tick`` per tick, parent of
+    admission, prefill, decode preparation, decode and emission; the
+    request stages stay recorder-only."""
+    eng, queue = _engine()
+    assert telemetry.active() is None
+
+    def ticks():
+        for prompt, n_out in (([1, 2, 3], 6), ([4, 5], 4), ([6], 5)):
+            queue.submit(prompt, n_out)
+        while eng.step(queue):
+            pass
+
+    traced = _Traced(tmp_path, ticks)
+    children = ('serve_admit', 'serve_prefill', 'serve_decode_prep',
+                'serve_decode', 'serve_emit')
+    tick_ids = {r['id']: r for r in traced.named('serve_tick')}
+    assert len(tick_ids) >= 6
+    assert len(traced.trace_events('serve_tick')) == len(tick_ids)
+    main = traced.line_of('serve_tick')
+    for name in children:
+        assert traced.trace_events(name), name
+        assert traced.line_of(name) == main
+        for r in traced.named(name):
+            assert r['parent'] in tick_ids
+            assert r['step'] == tick_ids[r['parent']]['step']
+    assert len(traced.named('serve_prefill')) == 3
+    assert sum(r['prefills'] for r in tick_ids.values()) == 3
+    assert [r['step'] for r in traced.named('serve_tick')] == \
+        sorted(r['step'] for r in tick_ids.values())
+    # per-request stages: records, no annotation
+    stages = {'queue_wait', 'bucket_pack', 'prefill', 'decode'}
+    assert stages <= {r['name'] for r in traced.records}
+    for name in stages:
+        assert not traced.trace_events(name)
+        assert all('request_id' in r for r in traced.named(name))
+    traced.check_children_inside_parents()
+    traced.check_records_inside_the_window()
+
+
+def test_with_neither_profiler_nor_recorder_nothing_is_recorded():
+    """(b) The off path: ``NULL_SPAN``, no recorder installed, no
+    record made -- through a trainer step and a scheduler tick."""
+    assert telemetry.live() is None
+    assert telemetry.span('train_update') is rec_mod.NULL_SPAN
+    upd = _updater(0)
+    upd.update()
+    eng, queue = _engine()
+    queue.submit([1, 2, 3], 3)
+    while eng.step(queue):
+        pass
+    assert telemetry.active() is None and telemetry.live() is None
+
+
+def test_a_lone_engine_reads_the_environment_variable(monkeypatch):
+    """``CHAINERMN_TPU_TELEMETRY`` used to be read by updaters and
+    communicators only: a serving process with neither ignored it."""
+    monkeypatch.setenv(telemetry.ENV_VAR, '1')
+    eng, queue = _engine()
+    rec = telemetry.active()
+    assert rec is not None and rec.outdir is None
+    assert not rec.follows_profiler
+    queue.submit([1, 2, 3], 2)
+    while eng.step(queue):
+        pass
+    assert {'serve_tick', 'serve_decode', 'queue_wait'} <= {
+        r['name'] for r in rec.events}
+
+
+def test_one_predicate_after_the_profile_ends(tmp_path, monkeypatch):
+    """``live()`` is the one predicate for "telemetry is on":
+    ``enabled()`` and the guards at the communicator, the optimizer
+    wrapper and the chaos sites agree with it once the profiler
+    session that installed the recorder has closed; and such a
+    recorder does not stand in for ``CHAINERMN_TPU_TELEMETRY``."""
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        assert telemetry.enabled()
+        with telemetry.span('profiled'):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    rec = telemetry.active()
+    assert rec.follows_profiler and telemetry.live() is None
+    assert not telemetry.enabled() and telemetry.registry() is None
+    n = len(rec.events)
+    upd = _updater(0)       # a communicator, a wrapper, an updater
+    upd.update()
+    upd.comm.broadcast_data({'w': jnp.ones(3)})
+    telemetry.event('late')
+    assert len(rec.events) == n
+    # the variable is read all the same, and makes the recorder last
+    assert telemetry.maybe_enable_from_env() is rec
+    monkeypatch.setenv(telemetry.ENV_VAR, '1')
+    telemetry._env_checked = False
+    assert telemetry.maybe_enable_from_env() is rec
+    assert not rec.follows_profiler and telemetry.live() is rec
+    upd.update()
+    assert 'train_update' in {r['name'] for r in rec.events[n:]}
+
+
+def test_two_threads_install_one_recorder(tmp_path):
+    """The rule under contention: many threads find the profiler open
+    at once and install exactly one recorder, losing no record."""
+    import sys
+    import threading
+    n_threads, n_spans = 16, 50
+    start = threading.Barrier(n_threads)
+    seen = []
+
+    def worker():
+        start.wait(timeout=30)
+        for _ in range(n_spans):
+            with telemetry.span('contended'):
+                pass
+        seen.append(telemetry.active())
+
+    threads = [threading.Thread(target=worker) for _ in range(n_threads)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        jax.profiler.stop_trace()
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert len(seen) == n_threads and len(set(map(id, seen))) == 1
+    records = [r for r in seen[0].events if r['name'] == 'contended']
+    assert len(records) == n_threads * n_spans
+    assert len({r['id'] for r in records}) == len(records)
+
+
+def test_executables_carry_stable_names():
+    """(c) The lowered trainer step is ``jit_train_step``; of the
+    engine's executables exactly the decode ones hold ``decode``."""
+    upd = _updater(0)
+    arrays = upd.shard_batch([next(upd.iterator)[0]] * 16)
+    text = upd._step.lower(*upd._step_args(arrays)).as_text()
+    assert 'module @jit_train_step ' in text
+
+    names = {}
+
+    def lower_name(exe):
+        # an AOT executable's HLO module carries the jitted name
+        return exe.as_text().split('HloModule ', 1)[1].split(
+            ',', 1)[0].strip()
+
+    eng, _ = _engine()
+    for kind, table in (('prefill', eng._prefill),
+                        ('decode', eng._decode)):
+        for bucket, (exe, aot) in table.items():
+            assert aot
+            names[kind, bucket] = lower_name(exe)
+    eng._get_copy()
+    names['copy', 0] = lower_name(eng._copy[0])
+    assert {k for k, _ in names} == {'prefill', 'decode', 'copy'}
+    for (kind, _), name in names.items():
+        assert ('decode' in name) == (kind == 'decode'), name
+        assert ('prefill' in name) == (kind == 'prefill'), name
+    assert set(names.values()) == {
+        'jit_serve_prefill', 'jit_serve_decode', 'jit_serve_page_copy'}
+
+
+def test_compile_log_is_always_on_and_bounded():
+    """The one always-on counter: building an updater registers the
+    listener once; a compile appends ``(perf_counter, event, s)``."""
+    _updater(0)
+    telemetry.install_compile_log()     # idempotent
+    x = jnp.ones(7)
+    n0 = len(telemetry.compile_log)
+    t0 = time.perf_counter()
+    jax.jit(lambda x: x * 3 + n0)(x).block_until_ready()
+    t1 = time.perf_counter()
+    compiles = list(telemetry.compile_log)[n0:]
+    assert len(compiles) == 1
+    t, event, seconds = compiles[0]
+    assert event == 'backend_compile'
+    assert t0 <= t <= t1 and 0 < seconds < t1 - t0
+    assert telemetry.compile_log.maxlen == 4096
+    assert telemetry.active() is None
+
+
+# ---------------------------------------------------------------------
+# (d) the benchmark's two new readers, on hand-made runs
+
+def _reader(name):
+    spec = importlib.util.spec_from_file_location(
+        'reader_' + name,
+        os.path.join(ROOT, 'chipbench', 'readers', name + '.py'))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module.read
+
+
+def _run(window):
+    said = []
+    return types.SimpleNamespace(window=window, say=said.append,
+                                 said=said)
+
+
+def _ms(value):
+    # a record's wall-clock times (1.8e9 s) resolve to ~0.2 us
+    return pytest.approx(value, abs=0.01)
+
+
+def _record(rec, name, t0, t1, **attrs):
+    """A span record at perf_counter times ``t0``..``t1``."""
+    base = rec.now() - time.perf_counter()
+    rec._append(dict({'type': 'span', 'name': name, 'kind': 'x',
+                      't0': base + t0, 't1': base + t1}, **attrs))
+
+
+def test_program_span_reader_on_a_hand_made_run():
+    read = _reader('program_span')
+    window = (100.0, 110.0)
+    assert read(_run(window), stat='mean', span='jitted_step') is None
+    rec = telemetry.enable()
+    assert read(_run(window), stat='mean', span='jitted_step') is None
+    # before the window, and straddling its end: left out
+    _record(rec, 'jitted_step', 99.0, 99.5, id=1, parent=None)
+    _record(rec, 'jitted_step', 109.9, 110.1, id=2, parent=None)
+    for i, ms in enumerate((2.0, 4.0, 6.0, 8.0)):
+        _record(rec, 'jitted_step', 101.0 + i, 101.0 + i + ms / 1e3,
+                id=10 + i, parent=None)
+    # producer time per batch, in whatever thread
+    for i in range(2):
+        _record(rec, 'batch_fetch', 102.0 + i, 102.010 + i, id=20 + i,
+                parent=None, thread=7)
+        _record(rec, 'host_batch_prep', 103.0 + i, 103.030 + i,
+                id=30 + i, parent=None, thread=8)
+        _record(rec, 'h2d', 103.5 + i, 103.505 + i, id=40 + i,
+                parent=None, thread=8)
+    # two ticks with children, one decode outside any tick
+    for i, tick in enumerate((50, 51)):
+        t = 105.0 + i
+        _record(rec, 'serve_tick', t, t + 0.100, id=tick, parent=None)
+        _record(rec, 'serve_admit', t, t + 0.001, id=60 + i,
+                parent=tick)
+        _record(rec, 'serve_decode', t + 0.010, t + 0.080, id=70 + i,
+                parent=tick)
+    _record(rec, 'serve_prefill', 105.081, 105.095, id=80, parent=50)
+    _record(rec, 'serve_decode', 107.0, 107.070, id=81, parent=None)
+
+    run = _run(window)      # one run: the records are laid out once
+    assert read(run, stat='mean', span='jitted_step') == _ms(5.0)
+    assert read(run, stat='p75', span='jitted_step') == _ms(6.5)
+    assert read(run, stat='mean', span='absent') is None
+    assert 'program spans: 18 records' in run.said[0]
+    assert len(run.said) == 1
+    assert read(run, stat='sum_per', per='host_batch_prep',
+                spans=['batch_fetch', 'host_batch_prep', 'h2d']) == \
+        _ms(45.0)
+    assert read(run, stat='sum_per', per='absent',
+                spans=['h2d']) is None
+    # a tick's host-only part: the tick less two of its children
+    host = read(run, stat='self_mean', span='serve_tick',
+                less=['serve_prefill', 'serve_decode'])
+    assert host == _ms((16.0 + 30.0) / 2)
+    assert 'serve_admit 1.000' in run.said[-1]
+    assert 'self 22.000' in run.said[-1]
+    with pytest.raises(KeyError):
+        read(run, stat='median', span='serve_tick')
+
+
+def test_trainer_span_metrics_on_a_hand_made_run():
+    """``input_wait_ms`` and ``train_update_self_ms``, with the
+    arguments their committed files give the reader."""
+    import json
+    read = _reader('program_span')
+    args = {}
+    for name in ('input_wait_ms', 'train_update_self_ms'):
+        with open(os.path.join(ROOT, 'chipbench', 'layer_metrics',
+                               name + '.json')) as f:
+            args[name] = json.load(f)['args']
+    rec = telemetry.enable()
+    for i, (wait, sync) in enumerate(((0.100, 0.150), (0.120, 0.130))):
+        t, up = 101.0 + i, 10 + i
+        _record(rec, 'train_update', t, t + 0.300, id=up, parent=None)
+        _record(rec, 'input_wait', t, t + wait, id=20 + i, parent=up)
+        _record(rec, 'host_batch_prep', t + wait, t + wait + 0.010,
+                id=30 + i, parent=up)
+        _record(rec, 'h2d', t + wait + 0.010, t + wait + 0.015,
+                id=40 + i, parent=up)
+        _record(rec, 'jitted_step', t + wait + 0.015,
+                t + wait + 0.020, id=50 + i, parent=up)
+        _record(rec, 'metrics_sync', t + 0.300 - sync, t + 0.300,
+                id=60 + i, parent=up)
+    # a producer thread's spans have no parent: not the update's
+    _record(rec, 'host_batch_prep', 101.0, 101.2, id=70, parent=None,
+            thread=9)
+    run = _run((100.0, 110.0))
+    assert read(run, **args['input_wait_ms']) == _ms(110.0)
+    assert read(run, **args['train_update_self_ms']) == _ms(30.0)
+    assert 'self 30.000' in run.said[-1]
+
+
+def test_compile_log_reader_on_a_hand_made_run(monkeypatch):
+    read = _reader('compile_log')
+    log = [(99.0, 'backend_compile', 1.0),
+           (101.0, 'backend_compile', 2.5),
+           (111.0, 'backend_compile', 0.3)]
+    monkeypatch.setattr(telemetry, 'compile_log', log)
+    run = _run((100.0, 110.0))
+    assert read(run) == 1
+    assert 'compiled in the window: 2.500 s' in run.said[0]
+    assert read(_run((120.0, 130.0))) == 0
+    assert read(_run(None)) is None
+    monkeypatch.delattr(telemetry, 'compile_log')
+    assert read(run) is None    # a program that keeps no such log

@@ -58,7 +58,7 @@ def test_disabled_by_default_nullspan_and_noop_event():
     sp = telemetry.span('x', kind='compute')
     assert sp is rec_mod.NULL_SPAN
     with sp as handle:
-        assert handle.sync('value') == 'value'  # passthrough
+        handle.set(anything=1)  # no-op, no crash
     telemetry.event('x')  # no-op, no crash
     assert telemetry.registry() is None
     assert telemetry.flush() is None
@@ -100,14 +100,34 @@ def test_maybe_enable_from_env(tmp_path, monkeypatch):
     assert telemetry.maybe_enable_from_env() is None
 
 
-def test_sync_fences_block_and_tag(monkeypatch):
-    monkeypatch.setenv(telemetry.ENV_SYNC, '1')
+def test_span_records_say_what_caused_them():
+    """Every span record carries ``id``, ``parent`` (the innermost
+    span open on the same thread) and ``thread``; a span entered on
+    another thread has no parent here."""
+    import threading
     rec = telemetry.enable()
-    assert rec.sync_fences
-    with rec.span('jitted_step', kind='compute') as sp:
-        out = jax.jit(lambda x: x * 2)(jnp.ones(8))
-        sp.sync(out)
-    assert rec.events[-1]['synced'] is True
+
+    def elsewhere():
+        with rec.span('elsewhere'):
+            pass
+
+    with rec.span('outer', kind='step', iteration=4) as outer:
+        with rec.span('inner', kind='host'):
+            pass
+        worker = threading.Thread(target=elsewhere)
+        worker.start()
+        worker.join(timeout=10)
+        assert not worker.is_alive()
+    by_name = {e['name']: e for e in rec.events}
+    assert by_name['inner']['parent'] == by_name['outer']['id'] \
+        == outer.id
+    assert by_name['outer']['parent'] is None
+    assert by_name['elsewhere']['parent'] is None
+    assert by_name['elsewhere']['thread'] != by_name['outer']['thread']
+    assert by_name['outer']['thread'] == threading.get_native_id()
+    # the recorder's clock, laid onto time.perf_counter()
+    t = time.perf_counter()
+    assert abs(rec.to_perf_counter(rec.now()) - t) < 0.05
 
 
 # ---------------------------------------------------------------------
