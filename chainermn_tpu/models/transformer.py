@@ -334,11 +334,22 @@ def tp_param_specs(params, axis='model'):
 # a cache SLOT; each DECODE step then runs one token per live slot,
 # appends its K/V at the slot's position, and attends the single query
 # row against the cache (ops.flash_attention_decode -- one HBM pass,
-# per-slot dynamic lengths).  The cache is a plain pytree of stacked
-# per-layer arrays, so it threads through jit/AOT executables, is
-# donatable (the serving engine updates it in place across calls), and
-# shards over a MeshPlan 'model' axis on its HEAD dim exactly like the
-# attention weights (kv_cache_specs).
+# per-slot dynamic lengths).  The cache is a plain pytree holding ONE
+# ARRAY PER LAYER (a tuple per leaf name), so it threads through
+# jit/AOT executables, is donatable, and ``cache['k'][layer]`` is a
+# pytree index and never an XLA slice: each layer's buffer is the
+# executable's own donated parameter, written where it lies by one
+# scatter and read whole by the kernel or the context gather (a stacked
+# ``(n_layers, ...)`` array made XLA copy the whole pool on every call
+# on the chip: PERF.md, PR 26).  Its last axis is d_head PADDED TO THE
+# 128 LANES of a TPU tile (_LANES): the decode kernel's page tile
+# occupies whole lanes whatever d_head is, and only an array whose
+# minor axis fills them lies row-major on the chip by default -- a
+# (pages, 16, 16, 64) leaf lies page-minor there, and every executable
+# copied the whole pool into the kernel's layout and back.  The pad
+# lanes hold zeros and the queries' pad lanes are zero, so no product
+# changes.  It shards over a MeshPlan 'model' axis on its HEAD dim
+# exactly like the attention weights (kv_cache_specs).
 #
 # These are module-level functions doing the SAME arithmetic as
 # TransformerLM.__call__ over the SAME parameter tree (the
@@ -346,36 +357,54 @@ def tp_param_specs(params, axis='model'):
 # the parameters, and the parity pins in tests/test_transformer.py
 # hold the two paths together (f32 rtol 1e-5, bf16/int8-KV 5e-2).
 
+#: lanes of a TPU vector tile: the cache's head dim is padded to them
+_LANES = 128
+
+
+def _zero_cache(model, lead, dtype, tp, int8_kv):
+    """The cache of both addressings: per leaf name ``n_layers``
+    SEPARATE zeroed arrays ``(*lead, H_local, lanes)``, ``lanes`` being
+    ``d_head`` rounded up to :data:`_LANES` (scales: ``(*lead,
+    H_local)``)."""
+    if model.n_heads % tp:
+        raise ValueError('tp=%d must divide n_heads=%d'
+                         % (tp, model.n_heads))
+    d_head = model.d_model // model.n_heads
+    shape = tuple(int(n) for n in lead) + (
+        model.n_heads // tp, d_head + -d_head % _LANES)
+
+    def leaves(shape, dtype):
+        return tuple(jnp.zeros(shape, dtype)
+                     for _ in range(model.n_layers))
+
+    if int8_kv:
+        return {'k': leaves(shape, jnp.int8),
+                'v': leaves(shape, jnp.int8),
+                'k_scale': leaves(shape[:-1], jnp.float32),
+                'v_scale': leaves(shape[:-1], jnp.float32)}
+    dtype = dtype or model.dtype
+    return {'k': leaves(shape, dtype), 'v': leaves(shape, dtype)}
+
+
 def init_kv_cache(model, n_slots, max_len=None, dtype=None, tp=1,
                   int8_kv=False):
     """Zeroed slot-addressed KV cache for ``model``.
 
-    Layout: ``{'k'|'v': (n_layers, n_slots, S, H_local, d_head)}``
-    with ``S = max_len or model.max_len`` and ``H_local =
-    n_heads / tp`` (pass the mesh's model-axis size as ``tp`` when the
-    cache lives sharded inside ``shard_map``).  ``int8_kv=True`` adds
-    ``'k_scale'``/``'v_scale'`` ``(n_layers, n_slots, S, H_local)``
-    f32 trees and stores k/v as int8 (:func:`chainermn_tpu.precision.
-    quantize_kv` at write time) -- half the decode-bound HBM bytes of
-    bf16.  Slots are REUSED without zeroing: reads mask by the live
-    length, so a previous occupant's stale rows are never attended.
+    Layout: ``{'k'|'v': n_layers x (n_slots, S, H_local, lanes)}`` --
+    a tuple with one array per layer -- with ``S = max_len or
+    model.max_len``, ``H_local = n_heads / tp`` (pass the mesh's
+    model-axis size as ``tp`` when the cache lives sharded inside
+    ``shard_map``) and ``lanes`` = ``d_head`` rounded up to 128, the
+    pad zero (why: the comment above).  ``int8_kv=True`` adds
+    ``'k_scale'``/``'v_scale'``
+    ``n_layers x (n_slots, S, H_local)`` f32 trees and stores k/v as
+    int8 (:func:`chainermn_tpu.precision.quantize_kv` at write time)
+    -- half the decode-bound HBM bytes of bf16.  Slots are REUSED
+    without zeroing: reads mask by the live length, so a previous
+    occupant's stale rows are never attended.
     """
-    if model.n_heads % tp:
-        raise ValueError('tp=%d must divide n_heads=%d'
-                         % (tp, model.n_heads))
-    n_layers = model.n_layers
-    h_local = model.n_heads // tp
-    d_head = model.d_model // model.n_heads
-    s = int(max_len or model.max_len)
-    dtype = dtype or model.dtype
-    shape = (n_layers, int(n_slots), s, h_local, d_head)
-    if int8_kv:
-        return {'k': jnp.zeros(shape, jnp.int8),
-                'v': jnp.zeros(shape, jnp.int8),
-                'k_scale': jnp.zeros(shape[:-1], jnp.float32),
-                'v_scale': jnp.zeros(shape[:-1], jnp.float32)}
-    return {'k': jnp.zeros(shape, dtype),
-            'v': jnp.zeros(shape, dtype)}
+    return _zero_cache(model, (n_slots, max_len or model.max_len),
+                       dtype, tp, int8_kv)
 
 
 def init_paged_kv_cache(model, n_pages, page_size, dtype=None, tp=1,
@@ -383,8 +412,8 @@ def init_paged_kv_cache(model, n_pages, page_size, dtype=None, tp=1,
     """Zeroed PAGED KV cache: a fixed pool of ``n_pages`` pages of
     ``page_size`` token positions each, shared by every sequence.
 
-    Layout: ``{'k'|'v': (n_layers, n_pages, page_size, H_local,
-    d_head)}`` (+ ``'k_scale'``/``'v_scale'`` ``(n_layers, n_pages,
+    Layout: ``{'k'|'v': n_layers x (n_pages, page_size, H_local,
+    lanes)}`` (+ ``'k_scale'``/``'v_scale'`` ``n_layers x (n_pages,
     page_size, H_local)`` f32 under ``int8_kv``) -- the slot cache's
     layout with the ``(n_slots, S)`` slab axes re-cut into
     ``(n_pages, page_size)``, so :func:`kv_cache_specs` shards it
@@ -397,21 +426,8 @@ def init_paged_kv_cache(model, n_pages, page_size, dtype=None, tp=1,
     ever points at it, so garbage writes are structurally harmless.
     Pages are reused without zeroing -- reads mask by live length.
     """
-    if model.n_heads % tp:
-        raise ValueError('tp=%d must divide n_heads=%d'
-                         % (tp, model.n_heads))
-    h_local = model.n_heads // tp
-    d_head = model.d_model // model.n_heads
-    dtype = dtype or model.dtype
-    shape = (model.n_layers, int(n_pages), int(page_size), h_local,
-             d_head)
-    if int8_kv:
-        return {'k': jnp.zeros(shape, jnp.int8),
-                'v': jnp.zeros(shape, jnp.int8),
-                'k_scale': jnp.zeros(shape[:-1], jnp.float32),
-                'v_scale': jnp.zeros(shape[:-1], jnp.float32)}
-    return {'k': jnp.zeros(shape, dtype),
-            'v': jnp.zeros(shape, dtype)}
+    return _zero_cache(model, (n_pages, page_size), dtype, tp,
+                       int8_kv)
 
 
 def kv_cache_specs(cache, axis='model'):
@@ -423,9 +439,9 @@ def kv_cache_specs(cache, axis='model'):
     from jax.sharding import PartitionSpec as P
 
     def one(leaf):
-        if leaf.ndim == 5:                      # k / v
-            return P(None, None, None, axis, None)
-        return P(None, None, None, axis)        # scales
+        if leaf.ndim == 4:                      # k / v
+            return P(None, None, axis, None)
+        return P(None, None, axis)              # scales
     return jax.tree_util.tree_map(one, cache)
 
 
@@ -447,51 +463,91 @@ def _qkv_proj(h, bp, dtype):
     return jnp.einsum('...d,dchf->...chf', h.astype(dtype), w) + b
 
 
-def _write_kv(cache, layer, k_new, v_new, slots, positions):
-    """Append one token's K/V per row: ``k_new``/``v_new``
-    (N, H_local, d_head) written at ``(layer, slots[i],
-    positions[i])``.  ``slots=None`` means row i IS slot i."""
+def _pad_last(x, width):
+    """``x`` with zeros appended on its last axis up to ``width``
+    (k, v and q up to the cache's lanes; a scale is there already)."""
+    pad = width - x.shape[-1]
+    return x if not pad else jnp.pad(
+        x, ((0, 0),) * (x.ndim - 1) + ((0, pad),))
+
+
+def _update_kv(cache, layer, k_new, v_new, put):
+    """``cache`` with ``layer``'s leaves rewritten by ``put(leaf,
+    value) -> leaf``: the one place a cache leaf is written.  Each
+    leaf gets exactly one write per traced call, so XLA updates the
+    donated buffer where it lies.  An int8 cache stores the quantized
+    values and their scales (:func:`~chainermn_tpu.precision.
+    quantize_kv`); a float cache the values in its own dtype."""
     from chainermn_tpu.precision import quantize_kv
-    n = k_new.shape[0]
-    idx_slots = (jnp.arange(n) if slots is None
-                 else slots.astype(jnp.int32))
+
     out = dict(cache)
-    if _cache_int8(cache):
-        for name, val in (('k', k_new), ('v', v_new)):
-            q, scale = quantize_kv(val)
-            out[name] = cache[name].at[
-                layer, idx_slots, positions].set(q)
-            out[name + '_scale'] = cache[name + '_scale'].at[
-                layer, idx_slots, positions].set(scale)
-        return out
-    dt = cache['k'].dtype
-    out['k'] = cache['k'].at[layer, idx_slots, positions].set(
-        k_new.astype(dt))
-    out['v'] = cache['v'].at[layer, idx_slots, positions].set(
-        v_new.astype(dt))
+
+    def write(name, val):
+        leaves = cache[name]
+        leaf = leaves[layer]
+        leaf = put(leaf, _pad_last(val, leaf.shape[-1]).astype(
+            leaf.dtype))
+        out[name] = leaves[:layer] + (leaf,) + leaves[layer + 1:]
+
+    for name, val in (('k', k_new), ('v', v_new)):
+        if _cache_int8(cache):
+            val, scale = quantize_kv(val)
+            write(name + '_scale', scale)
+        write(name, val)
     return out
+
+
+def _scatter_kv(cache, layer, k_new, v_new, idx):
+    """Write ``k_new``/``v_new`` at ``layer``'s ``[idx]``: ``idx`` is
+    the pair of index arrays addressing the leaf's two leading axes
+    (slot, position) or (page, offset)."""
+    return _update_kv(cache, layer, k_new, v_new,
+                      lambda leaf, val: leaf.at[idx].set(val))
+
+
+def _layer_kv(cache, layer, rows=lambda leaf: leaf, d_head=None):
+    """The attention operands of one layer, each leaf through
+    ``rows`` (identity: the layer's buffer as it lies): positional
+    ``(k, v)`` and the ``k_scale``/``v_scale`` keywords of an int8
+    cache.  ``d_head`` cuts the pad lanes off k and v, for a reader
+    that gathered its rows; the decode kernels take the padded
+    buffers whole (:func:`_decode_attend`)."""
+    kv = {name: rows(leaves[layer]) for name, leaves in cache.items()}
+    k, v = kv.pop('k'), kv.pop('v')
+    if d_head is not None:
+        k, v = k[..., :d_head], v[..., :d_head]
+    return (k, v), kv
+
+
+def _decode_attend(kernel, cache, layer, q, *operands,
+                   rows=lambda leaf: leaf):
+    """``kernel(q, k, v, *operands)`` over ``layer``'s lane-padded
+    buffers: the query is padded with zero lanes to match (every
+    product with a pad lane is zero), the softmax scale stays that of
+    the true ``d_head``, and the output is cut back to it."""
+    d_head = q.shape[-1]
+    (k, v), scales = _layer_kv(cache, layer, rows)
+    out = kernel(_pad_last(q, k.shape[-1]), k, v, *operands,
+                 scale=d_head ** -0.5, **scales)
+    return out[..., :d_head]
 
 
 def _attend_cache(cache, layer, q, slots, lengths):
     """One decode-attention read: row i's query against its slot's
     cache prefix.  With ``slots=None`` (full-slot decode bucket) the
-    cache rows are consumed IN PLACE -- one HBM read, the jaxpr pin in
-    tests/test_transformer.py; a compacted bucket gathers its rows
+    layer's buffer is the kernel's operand as it lies (the jaxpr pin
+    in tests/test_transformer.py; what the chip's compiler makes of
+    it is chip_smoke.py's check); a compacted bucket gathers its rows
     first (one extra pass -- the cost of running a smaller executable,
     documented in docs/serving.md)."""
     from chainermn_tpu import ops
 
-    def rows(name):
-        full = cache[name][layer]
-        return full if slots is None else jnp.take(
-            full, slots.astype(jnp.int32), axis=0)
+    def rows(leaf):
+        return leaf if slots is None else jnp.take(
+            leaf, slots.astype(jnp.int32), axis=0)
 
-    if _cache_int8(cache):
-        return ops.flash_attention_decode(
-            q, rows('k'), rows('v'), lengths,
-            k_scale=rows('k_scale'), v_scale=rows('v_scale'))
-    return ops.flash_attention_decode(q, rows('k'), rows('v'),
-                                      lengths)
+    return _decode_attend(ops.flash_attention_decode, cache, layer, q,
+                          lengths, rows=rows)
 
 
 def _tp_embed_rows(params, tokens, vocab_size, d_model, dtype, axis):
@@ -597,15 +653,19 @@ def decode_step(model, params, cache, tokens, positions, slots=None):
     half-block); parity vs the full-sequence causal forward is pinned
     in tests/test_transformer.py, including across slot refills.
     """
-    if slots is None and tokens.shape[0] != cache['k'].shape[1]:
+    n = tokens.shape[0]
+    if slots is None and n != cache['k'][0].shape[0]:
         raise ValueError(
             'full-bucket decode needs one row per cache slot '
             '(%d rows vs %d slots); pass slots= for a compacted '
-            'bucket' % (tokens.shape[0], cache['k'].shape[1]))
+            'bucket' % (n, cache['k'][0].shape[0]))
     lengths = positions.astype(jnp.int32) + 1
+    idx_slots = (jnp.arange(n) if slots is None
+                 else slots.astype(jnp.int32))
 
     def write(cache, layer, k_new, v_new):
-        return _write_kv(cache, layer, k_new, v_new, slots, positions)
+        return _scatter_kv(cache, layer, k_new, v_new,
+                           (idx_slots, positions))
 
     def attend(cache, layer, q):
         return _attend_cache(cache, layer, q, slots, lengths)
@@ -631,9 +691,8 @@ def decode_step_paged(model, params, cache, tokens, positions,
     tests/test_transformer.py.
     """
     from chainermn_tpu import ops
-    from chainermn_tpu.precision import quantize_kv
 
-    ps = cache['k'].shape[2]
+    ps = cache['k'][0].shape[1]
     positions = positions.astype(jnp.int32)
     lengths = positions + 1
     n = tokens.shape[0]
@@ -641,31 +700,12 @@ def decode_step_paged(model, params, cache, tokens, positions,
     offsets = positions % ps
 
     def write(cache, layer, k_new, v_new):
-        out = dict(cache)
-        if _cache_int8(cache):
-            for name, val in (('k', k_new), ('v', v_new)):
-                qv, scale = quantize_kv(val)
-                out[name] = cache[name].at[
-                    layer, pages, offsets].set(qv)
-                out[name + '_scale'] = cache[name + '_scale'].at[
-                    layer, pages, offsets].set(scale)
-            return out
-        dt = cache['k'].dtype
-        out['k'] = cache['k'].at[layer, pages, offsets].set(
-            k_new.astype(dt))
-        out['v'] = cache['v'].at[layer, pages, offsets].set(
-            v_new.astype(dt))
-        return out
+        return _scatter_kv(cache, layer, k_new, v_new,
+                           (pages, offsets))
 
     def attend(cache, layer, q):
-        if _cache_int8(cache):
-            return ops.flash_attention_decode_paged(
-                q, cache['k'][layer], cache['v'][layer], page_tables,
-                lengths, k_scale=cache['k_scale'][layer],
-                v_scale=cache['v_scale'][layer])
-        return ops.flash_attention_decode_paged(
-            q, cache['k'][layer], cache['v'][layer], page_tables,
-            lengths)
+        return _decode_attend(ops.flash_attention_decode_paged, cache,
+                              layer, q, page_tables, lengths)
 
     return _decode_core(model, params, cache, tokens, positions,
                         write, attend)
@@ -678,12 +718,11 @@ def prefill(model, params, cache, tokens, length, slot):
     -- decode lengths start at ``length``).  Runs the full causal
     forward ONCE (the compute-bound regime: whole-prompt matmuls
     through the fused flash kernel), banks every layer's K/V at
-    ``cache[:, slot, :T]``, and returns ``(logits (vocab,) f32 at
+    every layer's ``[slot, :T]``, and returns ``(logits (vocab,) f32 at
     position length-1, new_cache)`` -- the distribution the first
     generated token is sampled from."""
     from chainermn_tpu import ops
     from chainermn_tpu.parallel import tensor
-    from chainermn_tpu.precision import quantize_kv
 
     dtype = model.dtype
     tp_mode = model.tp_axis is not None
@@ -700,8 +739,11 @@ def prefill(model, params, cache, tokens, length, slot):
                      axis=0).astype(dtype)
     x = x + params['pos_embed'][:t].astype(dtype)
     slot = jnp.asarray(slot, jnp.int32)
-    int8_kv = _cache_int8(cache)
-    cache = dict(cache)
+
+    def bank(leaf, val):
+        return lax.dynamic_update_slice(
+            leaf, val[None], (slot,) + (0,) * (leaf.ndim - 1))
+
     for i in range(model.n_layers):
         bp = params['block_%d' % i]
         h = ops.layer_norm(x, bp['ln1_scale'],
@@ -710,20 +752,7 @@ def prefill(model, params, cache, tokens, length, slot):
         q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
         attn = ops.flash_attention(q, k, v, causal=True)
         attn = attn.reshape(1, t, -1)
-        for name, val in (('k', k[0]), ('v', v[0])):
-            if int8_kv:
-                qv, scale = quantize_kv(val)
-                cache[name] = lax.dynamic_update_slice(
-                    cache[name], qv[None, None],
-                    (i, slot, 0, 0, 0))
-                cache[name + '_scale'] = lax.dynamic_update_slice(
-                    cache[name + '_scale'], scale[None, None],
-                    (i, slot, 0, 0))
-            else:
-                cache[name] = lax.dynamic_update_slice(
-                    cache[name],
-                    val.astype(cache[name].dtype)[None, None],
-                    (i, slot, 0, 0, 0))
+        cache = _update_kv(cache, i, k[0], v[0], bank)
         if tp_mode:
             out = tensor.row_parallel_dense(
                 attn, bp['proj']['kernel'].astype(dtype),
@@ -779,7 +808,6 @@ def prefill_paged(model, params, cache, tokens, length, page_table,
     """
     from chainermn_tpu import ops
     from chainermn_tpu.parallel import tensor
-    from chainermn_tpu.precision import quantize_kv
 
     dtype = model.dtype
     tp_mode = model.tp_axis is not None
@@ -788,7 +816,7 @@ def prefill_paged(model, params, cache, tokens, length, page_table,
         raise ValueError('prefill_paged takes one prompt chunk per '
                          'call, got batch %d' % b)
     n_max = page_table.shape[0]
-    ps = cache['k'].shape[2]
+    ps = cache['k'][0].shape[1]
     pos0 = jnp.asarray(pos0, jnp.int32)
     length = jnp.asarray(length, jnp.int32)
     if tp_mode:
@@ -809,37 +837,22 @@ def prefill_paged(model, params, cache, tokens, length, page_table,
         jnp.int32), 0)
     offsets = p_abs % ps
     ctx_len = pos0[None]                               # (B=1,)
-    int8_kv = _cache_int8(cache)
-    cache = dict(cache)
+
+    def gather(leaf):
+        g = jnp.take(leaf, page_table.astype(jnp.int32), axis=0)
+        return g.reshape((1, n_max * ps) + g.shape[2:])
+
     for i in range(model.n_layers):
         bp = params['block_%d' % i]
         h = ops.layer_norm(x, bp['ln1_scale'],
                            bp['ln1_bias']).astype(dtype)
         qkv = _qkv_proj(h, bp, dtype)           # (1, C, 3, H, d_head)
         q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        for name, val in (('k', k[0]), ('v', v[0])):
-            if int8_kv:
-                qv, scale = quantize_kv(val)
-                cache[name] = cache[name].at[
-                    i, pages, offsets].set(qv)
-                cache[name + '_scale'] = cache[name + '_scale'].at[
-                    i, pages, offsets].set(scale)
-            else:
-                cache[name] = cache[name].at[i, pages, offsets].set(
-                    val.astype(cache[name].dtype))
-
-        def gather(name):
-            g = jnp.take(cache[name][i], page_table.astype(jnp.int32),
-                         axis=0)
-            return g.reshape((1, n_max * ps) + g.shape[2:])
-
-        if int8_kv:
-            attn = ops.flash_attention_chunk(
-                q, k, v, gather('k'), gather('v'), ctx_len,
-                k_scale=gather('k_scale'), v_scale=gather('v_scale'))
-        else:
-            attn = ops.flash_attention_chunk(q, k, v, gather('k'),
-                                             gather('v'), ctx_len)
+        cache = _scatter_kv(cache, i, k[0], v[0], (pages, offsets))
+        (k_ctx, v_ctx), scales = _layer_kv(cache, i, gather,
+                                           q.shape[-1])
+        attn = ops.flash_attention_chunk(q, k, v, k_ctx, v_ctx,
+                                         ctx_len, **scales)
         attn = attn.reshape(1, c, -1)
         if tp_mode:
             out = tensor.row_parallel_dense(
@@ -939,7 +952,7 @@ def _roundtrip_kv(cache, k_new, v_new):
     if _cache_int8(cache):
         return (dequantize_kv(*quantize_kv(k_new)),
                 dequantize_kv(*quantize_kv(v_new)))
-    dt = cache['k'].dtype
+    dt = cache['k'][0].dtype
     return k_new.astype(dt), v_new.astype(dt)
 
 
@@ -969,50 +982,33 @@ def spec_verify(model, params, cache, tokens, positions, slots=None):
     K/V (and int8 scales) stay as masked garbage, exactly like a
     reused slot."""
 
-    if slots is None and tokens.shape[0] != cache['k'].shape[1]:
+    n, kk = tokens.shape
+    if slots is None and n != cache['k'][0].shape[0]:
         raise ValueError(
             'full-bucket verify needs one row per cache slot '
             '(%d rows vs %d slots); pass slots= for a compacted '
-            'bucket' % (tokens.shape[0], cache['k'].shape[1]))
+            'bucket' % (n, cache['k'][0].shape[0]))
     from chainermn_tpu import ops
 
-    n, kk = tokens.shape
     positions = positions.astype(jnp.int32)
     window = positions[:, None] + jnp.arange(kk, dtype=jnp.int32)
     idx_slots = (jnp.arange(n) if slots is None
                  else slots.astype(jnp.int32))
 
     def write(cache, layer, k_new, v_new):
-        from chainermn_tpu.precision import quantize_kv
-        out = dict(cache)
-        rows_idx = idx_slots[:, None]
-        if _cache_int8(cache):
-            for name, val in (('k', k_new), ('v', v_new)):
-                qv, scale = quantize_kv(val)
-                out[name] = cache[name].at[
-                    layer, rows_idx, window].set(qv)
-                out[name + '_scale'] = cache[name + '_scale'].at[
-                    layer, rows_idx, window].set(scale)
-            return out
-        dt = cache['k'].dtype
-        out['k'] = cache['k'].at[layer, rows_idx, window].set(
-            k_new.astype(dt))
-        out['v'] = cache['v'].at[layer, rows_idx, window].set(
-            v_new.astype(dt))
-        return out
+        return _scatter_kv(cache, layer, k_new, v_new,
+                           (idx_slots[:, None], window))
+
+    def rows(leaf):
+        return leaf if slots is None else jnp.take(leaf, idx_slots,
+                                                   axis=0)
 
     def attend(cache, layer, q, k_new, v_new):
-        def rows(name):
-            full = cache[name][layer]
-            return full if slots is None else jnp.take(
-                full, idx_slots, axis=0)
         k_att, v_att = _roundtrip_kv(cache, k_new, v_new)
-        if _cache_int8(cache):
-            return ops.flash_attention_chunk(
-                q, k_att, v_att, rows('k'), rows('v'), positions,
-                k_scale=rows('k_scale'), v_scale=rows('v_scale'))
+        (k_ctx, v_ctx), scales = _layer_kv(cache, layer, rows,
+                                           q.shape[-1])
         return ops.flash_attention_chunk(
-            q, k_att, v_att, rows('k'), rows('v'), positions)
+            q, k_att, v_att, k_ctx, v_ctx, positions, **scales)
 
     return _verify_core(model, params, cache, tokens, positions,
                         write, attend)
@@ -1030,11 +1026,10 @@ def spec_verify_paged(model, params, cache, tokens, positions,
     at ``positions``; arithmetic is otherwise identical to the slab
     verify -- paging stays a storage indirection."""
     from chainermn_tpu import ops
-    from chainermn_tpu.precision import quantize_kv
 
     n, kk = tokens.shape
     n_max = page_tables.shape[1]
-    ps = cache['k'].shape[2]
+    ps = cache['k'][0].shape[1]
     positions = positions.astype(jnp.int32)
     window = positions[:, None] + jnp.arange(kk, dtype=jnp.int32)
     page_idx = jnp.clip(window // ps, 0, n_max - 1)
@@ -1045,34 +1040,19 @@ def spec_verify_paged(model, params, cache, tokens, positions,
     offsets = window % ps
 
     def write(cache, layer, k_new, v_new):
-        out = dict(cache)
-        if _cache_int8(cache):
-            for name, val in (('k', k_new), ('v', v_new)):
-                qv, scale = quantize_kv(val)
-                out[name] = cache[name].at[
-                    layer, pages, offsets].set(qv)
-                out[name + '_scale'] = cache[name + '_scale'].at[
-                    layer, pages, offsets].set(scale)
-            return out
-        dt = cache['k'].dtype
-        out['k'] = cache['k'].at[layer, pages, offsets].set(
-            k_new.astype(dt))
-        out['v'] = cache['v'].at[layer, pages, offsets].set(
-            v_new.astype(dt))
-        return out
+        return _scatter_kv(cache, layer, k_new, v_new,
+                           (pages, offsets))
+
+    def gather(leaf):
+        g = jnp.take(leaf, page_tables.astype(jnp.int32), axis=0)
+        return g.reshape((n, n_max * ps) + g.shape[3:])
 
     def attend(cache, layer, q, k_new, v_new):
-        def gather(name):
-            g = jnp.take(cache[name][layer],
-                         page_tables.astype(jnp.int32), axis=0)
-            return g.reshape((n, n_max * ps) + g.shape[3:])
         k_att, v_att = _roundtrip_kv(cache, k_new, v_new)
-        if _cache_int8(cache):
-            return ops.flash_attention_chunk(
-                q, k_att, v_att, gather('k'), gather('v'), positions,
-                k_scale=gather('k_scale'), v_scale=gather('v_scale'))
+        (k_ctx, v_ctx), scales = _layer_kv(cache, layer, gather,
+                                           q.shape[-1])
         return ops.flash_attention_chunk(
-            q, k_att, v_att, gather('k'), gather('v'), positions)
+            q, k_att, v_att, k_ctx, v_ctx, positions, **scales)
 
     return _verify_core(model, params, cache, tokens, positions,
                         write, attend)

@@ -80,6 +80,16 @@ def _named(fn, name):
     return call
 
 
+def _struct_and_signature(cache):
+    """A cache's ``ShapeDtypeStruct`` tree and its
+    :func:`abstract_signature` (a leaf per layer): neither changes
+    over an engine's life, so they are taken once and not once a
+    tick."""
+    struct = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), cache)
+    return struct, abstract_signature(struct)
+
+
 class GenRequest:
     """One in-flight generation request: ``prompt`` (1-D int32 token
     ids), ``max_new_tokens``, optional absolute ``deadline``
@@ -455,9 +465,7 @@ class GenerationEngine:
                  draft_model=None, draft_params=None, spec_tokens=4,
                  plan=None, param_specs=None, aot=True, label=None,
                  version=0):
-        from chainermn_tpu.models import (init_kv_cache,
-                                          init_paged_kv_cache,
-                                          kv_cache_specs)
+        from chainermn_tpu.models import kv_cache_specs
         from chainermn_tpu.serving.paged import (PagePool,
                                                  RadixPrefixIndex)
 
@@ -524,8 +532,6 @@ class GenerationEngine:
             raise ValueError('prefill_chunk %d exceeds max_prompt_len '
                              '%d' % (self.prefill_chunk,
                                      self.max_prompt_len))
-        tp = plan.model_size if plan is not None else 1
-        del tp  # the GLOBAL cache is built unsharded; specs shard it
         if self.paged:
             self.pages_per_seq = -(-self.max_len // self.page_size)
             self.n_pages = int(
@@ -533,9 +539,6 @@ class GenerationEngine:
             self.pool = PagePool(self.n_pages, self.page_size)
             self._prefix_index = (RadixPrefixIndex(self.pool)
                                   if prefix_sharing else None)
-            cache = init_paged_kv_cache(model, self.n_pages,
-                                        self.page_size,
-                                        int8_kv=self.int8_kv, tp=1)
         else:
             if n_pages is not None:
                 raise ValueError('n_pages requires paged=True')
@@ -543,11 +546,13 @@ class GenerationEngine:
             self.n_pages = None
             self.pool = None
             self._prefix_index = None
-            cache = init_kv_cache(model, self.n_slots, self.max_len,
-                                  int8_kv=self.int8_kv, tp=1)
+        # the GLOBAL cache is built unsharded (tp=1); specs shard it
+        cache = self._new_cache(model)
         self._cache_specs = (kv_cache_specs(cache, plan.model_axis)
                              if plan is not None else None)
         self._cache = jax.device_put(cache, self._cache_sharding())
+        self._cache_struct, self._cache_sig = _struct_and_signature(
+            cache)
 
         # -- speculative decoding: the draft twin ----------------------
         self.spec_tokens = int(spec_tokens)
@@ -584,19 +589,14 @@ class GenerationEngine:
                 host = cast_floating(host, self.policy.compute_dtype)
             self._draft_params = jax.device_put(
                 host, self._draft_sharding())
-            if self.paged:
-                # SAME pool geometry as the target: the draft cache is
-                # addressed through the same page tables and refcounts,
-                # so one allocation/CoW/eviction decision serves both
-                dcache = init_paged_kv_cache(
-                    draft_model, self.n_pages, self.page_size,
-                    int8_kv=self.int8_kv, tp=1)
-            else:
-                dcache = init_kv_cache(
-                    draft_model, self.n_slots, self.max_len,
-                    int8_kv=self.int8_kv, tp=1)
+            # SAME geometry as the target's: a paged draft cache is
+            # addressed through the same page tables and refcounts, so
+            # one allocation/CoW/eviction decision serves both
+            dcache = self._new_cache(draft_model)
             self._draft_cache = jax.device_put(
                 dcache, self._draft_sharding())
+            self._draft_cache_struct, self._draft_cache_sig = \
+                _struct_and_signature(dcache)
 
         # prefill executable widths: chunked paged mode compiles ONE
         # fixed-width chunk executable; otherwise one per prompt bucket
@@ -718,6 +718,18 @@ class GenerationEngine:
             load_params(path, self._params_template), version=version,
             validate=validate)
 
+    def _new_cache(self, model):
+        """Zeroed cache of this engine's geometry for ``model`` (the
+        target or the draft)."""
+        from chainermn_tpu.models import (init_kv_cache,
+                                          init_paged_kv_cache)
+        if self.paged:
+            return init_paged_kv_cache(model, self.n_pages,
+                                       self.page_size,
+                                       int8_kv=self.int8_kv)
+        return init_kv_cache(model, self.n_slots, self.max_len,
+                             int8_kv=self.int8_kv)
+
     def _cache_sharding(self):
         if self.plan is None:
             return jax.devices()[0]
@@ -782,8 +794,8 @@ class GenerationEngine:
         decision duplicates the page in both pools."""
         del params
         self.copy_trace_count += 1     # trace-time counter
-        return {key: leaf.at[:, dst].set(leaf[:, src])
-                for key, leaf in cache.items()}
+        return jax.tree_util.tree_map(
+            lambda leaf: leaf.at[dst].set(leaf[src]), cache)
 
     # -- speculative traced bodies (the draft twin + verify) -----------
     def _draft_prefill_body(self, params, cache, tokens, length, slot):
@@ -925,16 +937,6 @@ class GenerationEngine:
                 jax.ShapeDtypeStruct((bucket,), i32),
                 jax.ShapeDtypeStruct((bucket,), i32))
 
-    def _cache_struct(self):
-        return jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
-            self._cache)
-
-    def _draft_cache_struct(self):
-        return jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
-            self._draft_cache)
-
     def _get_prefill(self, bucket):
         hit = self._prefill.get(bucket)
         if hit is not None:
@@ -952,7 +954,7 @@ class GenerationEngine:
                     else self._mapped(self._prefill_body, 3))
             exe, _ = self._compile(
                 body,
-                (self._cache_struct(),) + self._token_structs(bucket),
+                (self._cache_struct,) + self._token_structs(bucket),
                 self._prefill, bucket, 'serve_prefill')
             return exe
 
@@ -969,7 +971,7 @@ class GenerationEngine:
             table = {}
             exe, aot = self._compile(
                 body,
-                (self._cache_struct(),
+                (self._cache_struct,
                  jax.ShapeDtypeStruct((), jnp.int32),
                  jax.ShapeDtypeStruct((), jnp.int32)),
                 table, 'copy', 'serve_page_copy')
@@ -1043,7 +1045,7 @@ class GenerationEngine:
                     % (bucket, list(self.decode_edges)))
             exe, _ = self._compile(
                 self._decode_mapped(bucket),
-                (self._cache_struct(),) + self._decode_structs(bucket),
+                (self._cache_struct,) + self._decode_structs(bucket),
                 self._decode, bucket, 'serve_decode')
             return exe
 
@@ -1082,7 +1084,7 @@ class GenerationEngine:
                     else self._draft_mapped(self._draft_prefill_body,
                                             3))
             exe, _ = self._compile(
-                body, (self._draft_cache_struct(),)
+                body, (self._draft_cache_struct,)
                 + self._token_structs(bucket),
                 self._draft_prefill, bucket, 'serve_draft_prefill',
                 params=self._draft_params)
@@ -1110,7 +1112,7 @@ class GenerationEngine:
                 return hit[0]
             exe, _ = self._compile(
                 self._draft_decode_mapped(bucket),
-                (self._draft_cache_struct(),)
+                (self._draft_cache_struct,)
                 + self._decode_structs(bucket),
                 self._draft_decode, bucket, 'serve_draft_decode',
                 params=self._draft_params)
@@ -1143,7 +1145,7 @@ class GenerationEngine:
                     % (bucket, list(self.decode_edges)))
             exe, _ = self._compile(
                 self._verify_mapped(bucket),
-                (self._cache_struct(),) + self._verify_structs(bucket),
+                (self._cache_struct,) + self._verify_structs(bucket),
                 self._verify, bucket, 'serve_verify')
             return exe
 
@@ -1163,7 +1165,7 @@ class GenerationEngine:
             table = {}
             exe, aot = self._compile(
                 body,
-                (self._draft_cache_struct(),
+                (self._draft_cache_struct,
                  jax.ShapeDtypeStruct((), jnp.int32),
                  jax.ShapeDtypeStruct((), jnp.int32)),
                 table, 'copy', 'serve_draft_page_copy',
@@ -1331,7 +1333,17 @@ class GenerationEngine:
         contract): refuse any operand signature outside the
         precompiled prefill/decode set instead of silently
         retracing."""
-        sig = abstract_signature(args)
+        return self._check_signature(abstract_signature(args))
+
+    def _guard_call(self, cache_sig, operands):
+        """:meth:`guard_signature` for one call of an executable over
+        ``(cache, *operands)``.  The cache's half of the signature (a
+        leaf per layer) never changes, so it is the one taken at
+        construction and a tick abstracts its few operands only."""
+        return self._check_signature(
+            cache_sig + abstract_signature(operands))
+
+    def _check_signature(self, sig):
         if sig not in self._signatures:
             raise RuntimeError(
                 'no-recompile guard: operand signature %r is outside '
@@ -1475,8 +1487,7 @@ class GenerationEngine:
             args = (jnp.asarray(tokens),
                     jnp.asarray(prompt.size, jnp.int32),
                     jnp.asarray(sid, jnp.int32))
-            self.guard_signature((self._cache_struct(),) + tuple(
-                jax.ShapeDtypeStruct(a.shape, a.dtype) for a in args))
+            self._guard_call(self._cache_sig, args)
             t_pf0 = rec.now() if rec is not None else None
             if rec is not None:
                 rec.child_span(
@@ -1492,18 +1503,15 @@ class GenerationEngine:
                                  iteration=self._step_index,
                                  step=self._step_index, **ident):
                 tok, cache = exe(self.params, self._cache, *args)
+                self._cache = cache
                 tok = int(jax.block_until_ready(tok))
-            self._cache = cache
             if self.speculative:
                 # the draft prefills the same prompt into ITS cache at
                 # the same slot (its proposals need the prompt's K/V);
                 # the draft's own first-token logits are discarded --
                 # the target's token is authoritative
                 dexe = self._get_draft_prefill(bucket)
-                self.guard_signature(
-                    (self._draft_cache_struct(),) + tuple(
-                        jax.ShapeDtypeStruct(a.shape, a.dtype)
-                        for a in args))
+                self._guard_call(self._draft_cache_sig, args)
                 with _telemetry.span('serve_draft', kind='serve',
                                      stage='prefill', bucket=bucket,
                                      slot=sid,
@@ -1511,8 +1519,8 @@ class GenerationEngine:
                                      step=self._step_index, **ident):
                     dtok, dcache = dexe(self._draft_params,
                                         self._draft_cache, *args)
+                    self._draft_cache = dcache
                     jax.block_until_ready(dtok)
-                self._draft_cache = dcache
             self.prefills += 1
             self.tokens_generated += 1
             t_first = clock()
@@ -1656,8 +1664,7 @@ class GenerationEngine:
                     jnp.asarray(n, jnp.int32),
                     jnp.asarray(st.pos, jnp.int32),
                     jnp.asarray(self._table_array(st.pages)))
-            self.guard_signature((self._cache_struct(),) + tuple(
-                jax.ShapeDtypeStruct(a.shape, a.dtype) for a in args))
+            self._guard_call(self._cache_sig, args)
             if rec is not None and st.chunks == 0:
                 t_c0 = rec.now()
                 rec.child_span(
@@ -1675,17 +1682,14 @@ class GenerationEngine:
                                  iteration=self._step_index,
                                  step=self._step_index, **ident):
                 tok, cache = exe(self.params, self._cache, *args)
+                self._cache = cache
                 tok = jax.block_until_ready(tok)
-            self._cache = cache
             if self.speculative:
                 # same chunk, same pages, into the draft cache: banked
                 # prefix pages stay valid for BOTH caches, so a future
                 # prefix hit serves the draft too
                 dexe = self._get_draft_prefill(width)
-                self.guard_signature(
-                    (self._draft_cache_struct(),) + tuple(
-                        jax.ShapeDtypeStruct(a.shape, a.dtype)
-                        for a in args))
+                self._guard_call(self._draft_cache_sig, args)
                 with _telemetry.span('serve_draft', kind='serve',
                                      stage='prefill', bucket=width,
                                      slot=sid, chunk=st.chunks,
@@ -1693,8 +1697,8 @@ class GenerationEngine:
                                      step=self._step_index, **ident):
                     dtok, dcache = dexe(self._draft_params,
                                         self._draft_cache, *args)
+                    self._draft_cache = dcache
                     jax.block_until_ready(dtok)
-                self._draft_cache = dcache
             st.pos += n
             st.chunks += 1
             self.prefill_chunks += 1
@@ -1815,8 +1819,7 @@ class GenerationEngine:
             args = (jnp.asarray(tokens),
                     jnp.asarray(np.asarray(rows, np.int32)),
                     jnp.asarray(positions))
-        self.guard_signature((self._cache_struct(),) + tuple(
-            jax.ShapeDtypeStruct(a.shape, a.dtype) for a in args))
+        self._guard_call(self._cache_sig, args)
         return rows, k, bucket, exe, args
 
     def _decode_once(self, clock):
@@ -1851,8 +1854,11 @@ class GenerationEngine:
                              queue_depth=self._last_queue_depth,
                              **ident):
             toks, cache = exe(self.params, self._cache, *args)
+            # rebound BEFORE the wait (here and at every call of the
+            # tick): the donated cache is a husk per layer, and they
+            # die while the device runs, not after it
+            self._cache = cache
             toks = np.asarray(jax.block_until_ready(toks))
-        self._cache = cache
         now = clock()
         now_tele = rec.now() if rec is not None else None
         if reg is not None:
@@ -2007,10 +2013,7 @@ class GenerationEngine:
                 pos = np.minimum(base_pos + j,
                                  self.max_len - 1).astype(np.int32)
                 args = operand_args(cur, pos)
-                self.guard_signature(
-                    (self._draft_cache_struct(),) + tuple(
-                        jax.ShapeDtypeStruct(a.shape, a.dtype)
-                        for a in args))
+                self._guard_call(self._draft_cache_sig, args)
                 toks, dcache = d_exe(self._draft_params,
                                      self._draft_cache, *args)
                 self._draft_cache = dcache
@@ -2027,8 +2030,7 @@ class GenerationEngine:
         # -- the ONE target pass --------------------------------------
         v_exe = self._get_verify(bucket)
         vargs = operand_args(win, base_pos)
-        self.guard_signature((self._cache_struct(),) + tuple(
-            jax.ShapeDtypeStruct(a.shape, a.dtype) for a in vargs))
+        self._guard_call(self._cache_sig, vargs)
         with _telemetry.span('serve_verify', kind='serve',
                              iteration=self._step_index,
                              step=self._step_index,
@@ -2037,8 +2039,8 @@ class GenerationEngine:
                              queue_depth=self._last_queue_depth,
                              **ident):
             tgt, cache = v_exe(self.params, self._cache, *vargs)
+            self._cache = cache
             tgt = np.asarray(jax.block_until_ready(tgt))
-        self._cache = cache
         self.verify_steps += 1
         now = clock()
         now_tele = rec.now() if rec is not None else None

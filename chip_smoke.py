@@ -13,7 +13,10 @@ the widths ``bench.py`` uses, with random weights made from a seed:
   ``TransformerLM`` d512 / L6 / V32k at batch 8 x seq 1024;
 - *serve*: ``GenerationEngine`` + ``GenerationQueue`` on the d512 / L6
   / V32k model (32 slots, cache 512, prompts <= 128, 32 new tokens),
-  slab cache and paged cache, 8 seeded requests each;
+  slab cache and paged cache, 8 seeded requests each; then the decode
+  and prefill executables at the benchmark's serving shapes
+  (gpt2-medium, 2,049 pages of 16), compiled and read for copies of
+  the KV page pool -- what only the chip's compiler can show;
 - ``--chips 4`` runs ONLY the transformer step over four devices
   (data-parallel, then dp 2 x tp 2) and the one-device loss both are
   compared with.
@@ -492,6 +495,129 @@ def serve(d_model=512, n_heads=8, n_layers=6, d_ff=2048, vocab=32000,
 
 
 # ----------------------------------------------------------------------
+# the KV page pool stays where it lies
+
+#: result-producing HLO instructions that move no data of their own
+_PLUMBING = ('parameter', 'tuple', 'get-tuple-element', 'bitcast')
+_WRITES = ('scatter', 'dynamic-update-slice')
+_HLO_DTYPE = {'bfloat16': 'bf16', 'float32': 'f32', 'int8': 's8'}
+
+
+def _hlo_instructions(text):
+    """``(computation, name, result type, opcode, text)`` of each
+    instruction of an HLO module's text."""
+    computation = None
+    for line in text.splitlines():
+        line = line.strip()
+        if line.endswith('{') and ' = ' not in line.split('(')[0]:
+            computation = line.split('(')[0].split()[-1].lstrip('%')
+            continue
+        head, eq, rest = line.partition(' = ')
+        if not eq:
+            continue
+        if rest.startswith('('):           # a tuple type: match it
+            depth = 0
+            for end, ch in enumerate(rest):
+                depth += (ch == '(') - (ch == ')')
+                if depth == 0:
+                    break
+            rtype, tail = rest[:end + 1], rest[end + 1:]
+        else:
+            rtype, _, tail = rest.partition(' ')
+        yield (computation, head.split()[-1].lstrip('%'), rtype,
+               tail.split('(')[0].strip(), line)
+
+
+def pool_shaped(text, leaves):
+    """The instructions of a compiled module that make a value of a
+    cache leaf's shape, other than the write itself (the scatter, and
+    the fusion that holds nothing pool-shaped but it) and plumbing:
+    ``[(opcode, name)]``.  Each one is a pass over a whole leaf, the
+    copies that were 84% of the device's time before PR 26."""
+    marks = {'%s[%s]' % (_HLO_DTYPE[np.dtype(leaf.dtype).name],
+                         ','.join(map(str, leaf.shape)))
+             for leaf in leaves}
+    hits, writers = [], set()
+    calls = {}
+    for comp, name, rtype, opcode, line in _hlo_instructions(text):
+        if not any(m in rtype for m in marks) or opcode in _PLUMBING:
+            continue
+        if opcode in _WRITES:
+            writers.add(comp)
+        elif opcode == 'fusion':
+            calls[name] = line.split('calls=')[1].split(',')[0].lstrip(
+                '%')
+        else:
+            hits.append((comp, opcode, name))
+    dirty = {comp for comp, _, _ in hits}
+    hits = [(opcode, name) for _, opcode, name in hits]
+    hits += [('fusion', name) for name, callee in calls.items()
+             if callee not in writers or callee in dirty]
+    return hits
+
+
+def serving_pool_check(d_model=1024, n_heads=16, n_layers=24,
+                       d_ff=4096, vocab=50257, max_len=1024,
+                       n_slots=32, max_prompt=512, page_size=16,
+                       prompt_bucket=128):
+    """The check the CPU cannot make: the decode and prefill
+    executables of ``GenerationEngine`` at the shapes of the
+    benchmark's serving cell (gpt2-medium, 32 slots, 2,049 pages of
+    16), compiled by the chip's own compiler, hold NO instruction
+    that makes a value of a pool leaf's shape except the in-place
+    write, and need less scratch than one layer's leaf.
+
+    A jaxpr shows that the program asks for no copy
+    (``tests/test_transformer.py``); it cannot show what XLA
+    materialises on the TPU, where a ``(pages, 16, 16, 64)`` array
+    lies page-minor (hence the cache's head dim padded to 128 lanes)
+    and a custom call takes a buffer, never a view: PR 25's trace held
+    two copies of the whole pool in every call behind a jaxpr pin
+    that passed.  Weights are zeros: nothing runs."""
+    import jax
+    import jax.numpy as jnp
+
+    from chainermn_tpu import serving
+    from chainermn_tpu.models import TransformerLM
+    from chainermn_tpu.precision import Policy
+
+    model = TransformerLM(vocab_size=vocab, d_model=d_model,
+                          n_heads=n_heads, n_layers=n_layers,
+                          d_ff=d_ff, max_len=max_len)
+    shapes = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(SEED),
+                           jnp.zeros((1, 8), jnp.int32))['params'])
+    params = jax.tree_util.tree_map(
+        lambda x: np.zeros(x.shape, x.dtype), shapes)
+    engine = serving.GenerationEngine(
+        model, params, n_slots=n_slots, max_prompt_len=max_prompt,
+        max_len=max_len, paged=True, page_size=page_size,
+        policy=Policy.bf16())
+    leaves = jax.tree_util.tree_leaves(engine._cache)
+    leaf_bytes = max(leaf.nbytes for leaf in leaves)
+    what = 'serve d%d/L%d %d slots, %d pages of %d' % (
+        d_model, n_layers, n_slots, engine.n_pages, page_size)
+    out = {}
+    for name, exe in (('decode', engine._get_decode(n_slots)),
+                      ('prefill', engine._get_prefill(prompt_bucket))):
+        hits = pool_shaped(exe.as_text(), leaves)
+        temp = exe.memory_analysis().temp_size_in_bytes
+        say('%s %s executable: %d pool-shaped instruction(s) besides '
+            'the write %r, temp_size_in_bytes %d (one layer\'s leaf: '
+            '%d)' % (what, name, len(hits), hits[:6], temp,
+                     leaf_bytes))
+        require(not hits, '%s %s executable makes pool-shaped values '
+                'outside the write: %r' % (what, name, hits))
+        require(temp < leaf_bytes,
+                '%s %s executable needs %d bytes of scratch, more '
+                'than one layer\'s leaf (%d): a copy of the pool'
+                % (what, name, temp, leaf_bytes))
+        out[name] = {'pool_shaped': hits, 'temp_bytes': temp}
+    out['leaf_bytes'] = leaf_bytes
+    return out
+
+
+# ----------------------------------------------------------------------
 # four chips
 
 def _distinct_devices(tree):
@@ -619,7 +745,8 @@ def main(argv=None):
         else:
             phases = [('train_resnet', train_resnet),
                       ('train_transformer', train_transformer),
-                      ('serve', serve)]
+                      ('serve', serve),
+                      ('serving_pool', serving_pool_check)]
         for phase, fn in phases:
             t0 = time.perf_counter()
             fn()

@@ -147,3 +147,63 @@ def test_kernel_compiles_for_v5e(case, one_chip, mosaic):
     compiled = jax.jit(fn).lower(*args).compile()
     assert 'tpu_custom_call' in compiled.as_text(), (
         '%s compiled without its Mosaic kernel' % case)
+
+
+@pytest.mark.parametrize('body', ['decode', 'prefill'])
+def test_serving_executable_leaves_the_page_pool_in_place(
+        body, one_chip, mosaic):
+    """The serving executables at the widths of the benchmark's cell
+    (gpt2-medium, 2,049 pages of 16, 32 rows; two layers of its 24),
+    jitted as ``GenerationEngine._compile`` jits them and compiled for
+    the described chip: besides the in-place write nothing makes a
+    value of a pool leaf's shape, and the scratch is under one leaf.
+    With the head dim left at 64 (the array then lies page-minor on
+    the chip), or with one stacked array for all layers, this compile
+    holds whole-pool ``copy`` and per-layer ``slice`` instructions
+    (PERF.md, PR 26); the jaxpr pin in ``tests/test_transformer.py``
+    cannot see either."""
+    import os
+    import sys
+
+    from chainermn_tpu import models as M
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    import chip_smoke
+
+    model = M.TransformerLM(vocab_size=50257, d_model=1024, n_heads=16,
+                            n_layers=2, d_ff=4096, max_len=1024)
+    params = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, BF16, sharding=one_chip),
+        jax.eval_shape(lambda: model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), I32))['params']))
+    cache = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                       sharding=one_chip),
+        jax.eval_shape(lambda: M.init_paged_kv_cache(model, 2049, 16)))
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, I32, sharding=one_chip)
+
+    def decode(p, c, tokens, positions, tables):
+        logits, c = M.decode_step_paged(model, p, c, tokens, positions,
+                                        tables)
+        return jnp.argmax(logits, axis=-1).astype(I32), c
+
+    def prefill(p, c, tokens, length, pos0, table):
+        logits, c = M.prefill_paged(model, p, c, tokens, length, table,
+                                    pos0)
+        return jnp.argmax(logits).astype(I32), c
+
+    fn, operands = {
+        'decode': (decode, (ints(32), ints(32), ints(32, 64))),
+        'prefill': (prefill, (ints(1, 128), ints(), ints(), ints(64))),
+    }[body]
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *operands).compile()
+    leaves = jax.tree_util.tree_leaves(cache)
+    assert {leaf.shape for leaf in leaves} == {(2049, 16, 16, 128)}
+    assert chip_smoke.pool_shaped(compiled.as_text(), leaves) == []
+    leaf_bytes = 2049 * 16 * 16 * 128 * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < leaf_bytes
+    if body == 'decode':
+        assert 'tpu_custom_call' in compiled.as_text()

@@ -106,6 +106,66 @@ def test_multichip_phase_on_four_virtual_devices(interpret):
         assert abs(out[name][0] - out['one_device']) < 2e-2
 
 
+_LEAF = 'bf16[9,8,2,8]{3,2,1,0:T(8,128)(2,1)}'
+_CLEAN_HLO = '\n'.join([
+    'HloModule jit_serve_decode, is_scheduled=true',
+    '%fused_computation.3 (param_0.9: bf16[9,8,2,8], param_1: s32[2], '
+    'param_2: bf16[2,2,8]) -> bf16[9,8,2,8] {',
+    '  %param_0.9 = ' + _LEAF + ' parameter(0)',
+    '  %param_2 = bf16[2,2,8]{2,1,0} parameter(2)',
+    '  ROOT %scatter.0 = ' + _LEAF + ' scatter(%param_0.9, %param_1, '
+    '%param_2), to_apply=%region_2.9',
+    '}',
+    'ENTRY %main.15 (p.1: bf16[16], c__k___0_.1: bf16[9,8,2,8]) -> '
+    '(s32[2], bf16[9,8,2,8]) {',
+    '  %c__k___0_.1 = ' + _LEAF + ' parameter(1)',
+    '  %fusion.3 = ' + _LEAF + ' fusion(%c__k___0_.1, %i, %u), '
+    'kind=kCustom, calls=%fused_computation.3, '
+    'metadata={op_name="jit(dec)/scatter"}',
+    '  %attn.2 = bf16[2,2,8]{2,1,0} custom-call(%t, %fusion.3), '
+    'custom_call_target="tpu_custom_call"',
+    '  ROOT %tuple.10 = (s32[2]{0}, ' + _LEAF + ') tuple(%tok, '
+    '%fusion.3)',
+    '}', ''])
+
+
+@pytest.mark.parametrize('extra, found', [
+    ('', []),
+    # the relayout of a pool the runtime keeps page-minor
+    ('  %copy.21 = bf16[9,8,2,8]{3,2,1,0:T(8,128)(2,1)} copy('
+     '%c__k___0_.1), sharding={replicated}\n',
+     [('copy', 'copy.21')]),
+    # one layer cut out of a stacked pool for the custom call
+    ('  %slice_bitcast_fusion.7 = bf16[9,8,2,8]{3,2,1,0} fusion('
+     '%stacked), kind=kLoop, calls=%fused_computation.9\n',
+     [('fusion', 'slice_bitcast_fusion.7')]),
+    # an asynchronous copy hides the leaf in a tuple type
+    ('  %slice-start.4 = ((bf16[9,8,2,8]{0,3,2,1}), bf16[9,2,2,8]'
+     '{0,3,2,1:S(1)}, s32[]) slice-start(%c__k___0_.1)\n',
+     [('slice-start', 'slice-start.4')]),
+    # another array of the same size is no pool leaf
+    ('  %copy.3 = f32[9,8,2,8]{3,2,1,0} copy(%x)\n', []),
+])
+def test_pool_shaped_finds_what_moves_a_leaf(extra, found):
+    import jax.numpy as jnp
+    leaf = jax.ShapeDtypeStruct((9, 8, 2, 8), jnp.bfloat16)
+    text = _CLEAN_HLO.replace('  %attn.2 =', extra + '  %attn.2 =')
+    assert chip_smoke.pool_shaped(text, [leaf]) == found
+
+
+def test_serving_pool_phase_tiny():
+    """The phase end to end at toy sizes.  XLA's CPU backend upcasts
+    a bfloat16 scatter through pool-shaped ``convert`` instructions,
+    so HERE the check must bite; that the chip's compiler leaves
+    nothing of the kind is ``tests/test_chip_compile.py`` (described
+    chip) and the script's own run (attached chip)."""
+    with pytest.raises(chip_smoke.SmokeFailure,
+                       match='makes pool-shaped values'):
+        chip_smoke.serving_pool_check(
+            max_len=32, n_slots=2, max_prompt=8, page_size=8,
+            prompt_bucket=8, **dict(TINY_LM, n_layers=2))
+
+
 def test_missing_kernel_fails_the_phase():
     with pytest.raises(chip_smoke.SmokeFailure, match='bypassed'):
         chip_smoke.require_kernels('HloModule plain', 'native', 'x')

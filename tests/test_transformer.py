@@ -25,6 +25,38 @@ def _tiny(seq_axis=None, sp_scheme='ring'):
                          sp_scheme=sp_scheme)
 
 
+def _inlined(jaxpr, rename=None):
+    """``jaxpr``'s equations with every nested ``jit`` call inlined
+    and its variables renamed to the caller's, so that a consumer
+    inside ``jnp.take`` counts as a consumer of the outer value."""
+    rename = {} if rename is None else rename
+
+    def outer(v):
+        return rename.get(id(v), v)
+
+    class Eqn:
+        def __init__(self, eqn):
+            self.primitive = eqn.primitive
+            self.invars = [outer(v) for v in eqn.invars]
+            self.outvars = list(eqn.outvars)
+
+        def __repr__(self):
+            return self.primitive.name
+
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == 'jit':
+            inner = eqn.params['jaxpr'].jaxpr
+            for v_in, v_out in zip(inner.invars, eqn.invars):
+                rename[id(v_in)] = outer(v_out)
+            out.extend(_inlined(inner, rename))
+            for v_in, v_out in zip(inner.outvars, eqn.outvars):
+                rename[id(v_out)] = outer(v_in)
+        else:
+            out.append(Eqn(eqn))
+    return out
+
+
 @pytest.fixture(scope='module')
 def setup():
     model = _tiny()
@@ -360,7 +392,8 @@ class TestIncrementalDecode:
         full = np.asarray(model.apply({'params': params},
                                       jnp.asarray([toks])))[0]
         cache = init_kv_cache(model, n_slots=1, int8_kv=True)
-        assert cache['k'].dtype == jnp.int8
+        assert all(leaf.dtype == jnp.int8 for leaf in cache['k'])
+        assert len(cache['k']) == model.n_layers
         got, _ = self._stepwise_logits(model, params, cache, toks,
                                        t_pre=3, slot=0)
         for p, lg in got.items():
@@ -397,33 +430,98 @@ class TestIncrementalDecode:
             np.testing.assert_allclose(got_b[p], full[p], rtol=1e-5,
                                        atol=1e-5)
 
-    def test_full_bucket_decode_reads_cache_in_place(self):
-        """The one-cache-read jaxpr pin at the model layer: a
-        full-slot decode step (slots=None) consumes each cache leaf
-        exactly once per layer -- no gather copy."""
-        from chainermn_tpu.models import decode_step, init_kv_cache
-        model = self._model(jnp.float32)
+    @pytest.mark.parametrize('kv', ['bf16', 'int8'])
+    @pytest.mark.parametrize('fn', ['decode_step', 'decode_step_paged',
+                                    'prefill', 'prefill_paged',
+                                    'spec_verify_paged'])
+    def test_full_bucket_decode_reads_cache_in_place(self, fn, kv,
+                                                     monkeypatch):
+        """The in-place jaxpr pin at the model layer, for every
+        serving function whose cache read is a kernel or a gather:
+        each cache leaf (one array per layer) has exactly ONE
+        consumer, its write; the write's result reaches the kernel
+        (full-slot and paged decode) or the context gather (paged
+        prefill / verify) with nothing that cuts, turns or copies it
+        in between; and nothing but the writes makes a value of a
+        leaf's shape.
+
+        This proves there is no gather, slice or copy of the cache IN
+        THE PROGRAM.  What XLA materialises for a custom call's
+        operand on the TPU no jaxpr shows: that is
+        ``chip_smoke.serving_pool_check`` on the chip and
+        ``tests/test_chip_compile.py`` for a described one."""
+        import importlib
+
+        from chainermn_tpu import models as M
+        model = self._model(jnp.bfloat16)
         params = model.init(jax.random.PRNGKey(1),
                             jnp.zeros((1, 8), jnp.int32))['params']
-        cache = init_kv_cache(model, n_slots=4)
-
-        def step(cache, tokens, positions):
-            return decode_step(model, params, cache, tokens,
-                               positions)
-
-        jaxpr = jax.make_jaxpr(step)(
-            cache, jnp.zeros((4,), jnp.int32),
-            jnp.zeros((4,), jnp.int32))
-        # cache leaves are the first invars (dict order k, v)
-        n_leaves = len(jax.tree_util.tree_leaves(cache))
-        for var in jaxpr.jaxpr.invars[:n_leaves]:
-            readers = [e for e in jaxpr.jaxpr.eqns
-                       if var in e.invars]
-            # one scatter (the token write) consumes the original
-            # leaf; every read flows from its output -- no second
-            # consumer means no gather copy of the cache
-            assert len(readers) == 1, (
-                'cache leaf consumed %d times' % len(readers))
+        # the kernels' path, as on the chip: traced, never lowered
+        fa = importlib.import_module('chainermn_tpu.ops.flash_attention')
+        monkeypatch.setattr(fa, 'pallas_mode', lambda: 'native')
+        monkeypatch.setattr(fa, 'interpret_flag', lambda: False)
+        int8 = kv == 'int8'
+        i32 = jnp.int32
+        if fn in ('decode_step', 'prefill'):
+            cache = M.init_kv_cache(model, n_slots=4, int8_kv=int8)
+        else:
+            cache = M.init_paged_kv_cache(model, n_pages=9, page_size=8,
+                                          int8_kv=int8)
+        rows, tables = jnp.zeros((4,), i32), jnp.zeros((4, 8), i32)
+        call, operands = {
+            'decode_step': (M.decode_step, (rows, rows)),
+            'decode_step_paged': (M.decode_step_paged,
+                                  (rows, rows, tables)),
+            'prefill': (M.prefill, (jnp.zeros((1, 16), i32),
+                                    jnp.asarray(5, i32),
+                                    jnp.asarray(1, i32))),
+            'prefill_paged': (M.prefill_paged,
+                              (jnp.zeros((1, 16), i32),
+                               jnp.asarray(5, i32), tables[0],
+                               jnp.asarray(8, i32))),
+            'spec_verify_paged': (M.spec_verify_paged,
+                                  (jnp.zeros((4, 3), i32), rows,
+                                   tables)),
+        }[fn]
+        jaxpr = jax.make_jaxpr(
+            lambda cache, *a: call(model, params, cache, *a))(
+                cache, *operands)
+        leaves = jax.tree_util.tree_leaves(cache)
+        assert len(leaves) == model.n_layers * (4 if int8 else 2)
+        eqns = _inlined(jaxpr.jaxpr)
+        writes = set()
+        for var in jaxpr.jaxpr.invars[:len(leaves)]:
+            readers = [e for e in eqns if var in e.invars]
+            assert [e.primitive.name for e in readers] in (
+                ['scatter'], ['dynamic_update_slice']), (
+                    'cache leaf %s consumed by %r' % (var.aval, readers))
+            writes.add(id(readers[0]))
+            # from the written leaf to its reader: a reshape (slab ->
+            # pages, scale -> scale tile) at most, then the kernel or
+            # the gather; a whole-prompt slot prefill reads nothing
+            frontier = list(readers[0].outvars)
+            reads = []
+            while frontier:
+                var = frontier.pop()
+                for e in eqns:
+                    if var not in e.invars:
+                        continue
+                    name = e.primitive.name
+                    assert name in ('pallas_call', 'gather', 'reshape',
+                                    'broadcast_in_dim'), (
+                        '%s between the write and the read of a cache '
+                        'leaf' % name)
+                    if name in ('pallas_call', 'gather'):
+                        reads.append(name)
+                    else:
+                        frontier.extend(e.outvars)
+            assert reads == ([] if fn == 'prefill' else
+                             ['pallas_call'] if 'decode' in fn
+                             else ['gather']), reads
+        shapes = {leaf.shape for leaf in leaves}
+        made = [e for e in eqns if id(e) not in writes
+                and any(v.aval.shape in shapes for v in e.outvars)]
+        assert not made, 'leaf-shaped values made by %r' % (made,)
 
     def test_compacted_vs_full_bucket_same_logits(self):
         from chainermn_tpu.models import (decode_step, init_kv_cache,
