@@ -288,6 +288,67 @@ class TransformerLM(nn.Module):
         return logits
 
 
+    # -- the serving protocol: what GenerationEngine calls on a model --
+    # (docs/serving.md).  Thin: the bodies are this module's functions;
+    # every step returns ``(logits, cache, counters)`` with ``counters``
+    # the model's ``serve_counters``, none here.
+    serve_counters = ()
+
+    @nn.nowrap
+    def check_serving(self, **asked):
+        """Every engine option has a path in this family."""
+
+    @nn.nowrap
+    def window_ring(self, page_size):
+        """Pages in a window layer's ring: no window layers, 0."""
+        return 0
+
+    @nn.nowrap
+    def init_kv_cache(self, n_slots, max_len=None, int8_kv=False):
+        return init_kv_cache(self, n_slots, max_len, int8_kv=int8_kv)
+
+    @nn.nowrap
+    def init_paged_kv_cache(self, n_pages, page_size, int8_kv=False):
+        return init_paged_kv_cache(self, n_pages, page_size,
+                                   int8_kv=int8_kv)
+
+    @nn.nowrap
+    def kv_cache_specs(self, cache, axis='model'):
+        return kv_cache_specs(cache, axis)
+
+    @nn.nowrap
+    def prefill(self, params, cache, tokens, length, slot):
+        return prefill(self, params, cache, tokens, length, slot) + ((),)
+
+    @nn.nowrap
+    def decode_step(self, params, cache, tokens, positions, slots=None):
+        return decode_step(self, params, cache, tokens, positions,
+                           slots=slots) + ((),)
+
+    @nn.nowrap
+    def prefill_paged(self, params, cache, tokens, length, page_table,
+                      pos0):
+        return prefill_paged(self, params, cache, tokens, length,
+                             page_table, pos0) + ((),)
+
+    @nn.nowrap
+    def decode_step_paged(self, params, cache, tokens, positions,
+                          page_tables):
+        return decode_step_paged(self, params, cache, tokens, positions,
+                                 page_tables) + ((),)
+
+    @nn.nowrap
+    def spec_verify(self, params, cache, tokens, positions, slots=None):
+        return spec_verify(self, params, cache, tokens, positions,
+                           slots=slots)
+
+    @nn.nowrap
+    def spec_verify_paged(self, params, cache, tokens, positions,
+                          page_tables):
+        return spec_verify_paged(self, params, cache, tokens, positions,
+                                 page_tables)
+
+
 def tp_oracle(model):
     """The unsharded twin of a ``tp_axis`` model: same config, same
     parameter tree (init THIS one to get params for either)."""

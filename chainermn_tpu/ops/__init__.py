@@ -18,7 +18,8 @@ interpret mode only when explicitly requested
 from chainermn_tpu.ops.flash_attention import (  # noqa
     chunk_attention_reference, decode_attention_paged_reference,
     decode_attention_reference, flash_attention, flash_attention_chunk,
-    flash_attention_decode, flash_attention_decode_paged, mha_reference)
+    flash_attention_decode, flash_attention_decode_paged, mha_reference,
+    paged_kv_append)
 from chainermn_tpu.ops.cross_entropy import (  # noqa
     softmax_cross_entropy, softmax_cross_entropy_reference)
 from chainermn_tpu.ops.layer_norm import layer_norm, layer_norm_reference  # noqa
@@ -27,3 +28,5 @@ from chainermn_tpu.ops.batch_norm_act import (  # noqa
 from chainermn_tpu.ops.optimizer import fused_momentum_sgd, momentum_sgd  # noqa
 from chainermn_tpu.ops.int8_matmul import (  # noqa
     dequant, dequant_matmul, dequant_matmul_reference)
+from chainermn_tpu.ops.grouped_matmul import (  # noqa
+    dropless_experts, grouped_swiglu, grouped_swiglu_reference)

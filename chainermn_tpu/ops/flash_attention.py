@@ -55,7 +55,7 @@ def mha_reference(q, k, v, causal=False, scale=None):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
                 acc_ref, *, scale, causal, kv_len, block_q, block_k,
-                t_kv):
+                t_kv, window=None):
     """One (batch*head, query-block, key-block) grid cell.
 
     The key-block axis is the innermost (sequential) grid dimension;
@@ -63,7 +63,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
     steps, so only one K/V tile is resident at a time.  ``m``/``l``
     are kept lane-replicated at (block_q, 128) -- the Mosaic-friendly
     layout for per-row scalars.  ``kv_len`` (static) masks out padded
-    key positions >= kv_len.
+    key positions >= kv_len.  ``window`` (static, causal only) keeps
+    the ``window`` keys ending at the query's own position: a key
+    block wholly before every row's window is skipped like one past
+    the causal frontier.  (A row whose first visited block lies wholly
+    before ITS window accumulates garbage at ``m = NEG_INF``; the
+    first live block's ``alpha = exp(NEG_INF - m_new) = 0`` wipes it.)
     """
     import jax.experimental.pallas as pl
 
@@ -96,6 +101,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
             ok = k_pos < kv_len
             if causal:
                 ok = jnp.logical_and(ok, q_pos >= k_pos)
+            if window is not None:
+                ok = jnp.logical_and(ok, k_pos > q_pos - window)
             s = jnp.where(ok, s, NEG_INF)
         m_prev = m_ref[...]                           # (block_q, 128)
         l_prev = l_ref[...]
@@ -112,7 +119,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
 
     if causal:
         # key blocks strictly after this query block contribute nothing
-        pl.when(kj * block_k < (qi + 1) * block_q)(_accum)
+        live = kj * block_k < (qi + 1) * block_q
+        if window is not None:
+            # ... nor do those that end before its first row's window
+            live = jnp.logical_and(
+                live, (kj + 1) * block_k > qi * block_q - window + 1)
+        pl.when(live)(_accum)
     else:
         _accum()
 
@@ -123,28 +135,44 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
         lse_ref[0] = (m_ref[...] + jnp.log(l_safe))[:, :1]
 
 
-def _fwd_pallas(q, k, v, causal, scale, kv_len, block_q, block_k):
+def _fwd_pallas(q, k, v, causal, scale, kv_len, block_q, block_k,
+                group=1, window=None):
+    """``group`` query heads read one K/V head: row ``b`` of the merged
+    ``(B*H, T, D)`` queries takes its keys from row ``b // group`` of
+    the ``(B*H/group, T, D)`` keys, in the index map, so the repeat is
+    never materialised."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, t_q, d = q.shape
     t_kv = k.shape[1]
     grid = (bh, t_q // block_q, t_kv // block_k)
+
+    def kv_row(b):
+        # no divide on the scalar core in every grid step of the
+        # ungrouped call (the trainer's)
+        return b if group == 1 else b // group
+
     if causal:
         # clamp the fetched K/V block at the causal frontier: steps
         # beyond it are compute-skipped (pl.when), and the repeated
         # block index makes Pallas elide the now-useless DMA instead
-        # of streaming ~2x the needed K/V traffic
+        # of streaming ~2x the needed K/V traffic; a window clamps the
+        # other end the same way
         def kv_ix(b, i, j):
             frontier = ((i + 1) * block_q + block_k - 1) // block_k - 1
-            return (b, jnp.minimum(j, frontier), 0)
+            j = jnp.minimum(j, frontier)
+            if window is not None:
+                j = jnp.maximum(j, jnp.maximum(
+                    i * block_q - window + 1, 0) // block_k)
+            return (kv_row(b), j, 0)
     else:
         def kv_ix(b, i, j):
-            return (b, j, 0)
+            return (kv_row(b), j, 0)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           kv_len=kv_len, block_q=block_q,
-                          block_k=block_k, t_kv=t_kv),
+                          block_k=block_k, t_kv=t_kv, window=window),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0),
@@ -175,24 +203,31 @@ def _fwd_pallas(q, k, v, causal, scale, kv_len, block_q, block_k):
     return out, lse[..., 0]
 
 
-def _fwd_blockwise_jnp(q, k, v, causal, scale, kv_len, block_k):
-    """Fallback forward: same recurrence as the kernel, via lax.scan."""
+def _fwd_blockwise_jnp(q, k, v, causal, scale, kv_len, block_k,
+                       group=1, window=None):
+    """Fallback forward: same recurrence as the kernel, via lax.scan
+    (grouped K/V heads are repeated here, a key block at a time)."""
     bh, t_q, d = q.shape
     t_kv = k.shape[1]
     qf = q.astype(jnp.float32) * scale
     n_blocks = t_kv // block_k
-    kb = k.reshape(bh, n_blocks, block_k, d).astype(jnp.float32)
-    vb = v.reshape(bh, n_blocks, block_k, d).astype(jnp.float32)
+    kb = k.reshape(-1, n_blocks, block_k, d).astype(jnp.float32)
+    vb = v.reshape(-1, n_blocks, block_k, d).astype(jnp.float32)
 
     def body(carry, inp):
         m, l, acc = carry
         j, kj, vj = inp
+        if group != 1:
+            kj = jnp.repeat(kj, group, axis=0)
+            vj = jnp.repeat(vj, group, axis=0)
         s = jnp.einsum('bqd,bkd->bqk', qf, kj)
         q_pos = jnp.arange(t_q)[:, None]
         k_pos = j * block_k + jnp.arange(block_k)[None, :]
         ok = k_pos < kv_len
         if causal:
             ok = jnp.logical_and(ok, q_pos >= k_pos)
+        if window is not None:
+            ok = jnp.logical_and(ok, k_pos > q_pos - window)
         s = jnp.where(ok, s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
         alpha = jnp.exp(m - m_new)
@@ -721,23 +756,45 @@ def decode_attention_paged_reference(q, k, v, page_tables, lengths,
 
 
 def _decode_paged_blockwise_jnp(q, k, v, page_tables, lengths, scale,
-                                k_scale=None, v_scale=None):
+                                k_scale=None, v_scale=None, group=1,
+                                window=None, head_major=False):
     """Fallback paged decode: ``lax.scan`` over the page-table axis --
     each step gathers ONE page per sequence and applies the kernel's
     online-softmax update.  The pool operands enter the scan once
     (one consumption in the jaxpr) and nothing (S,)-wide is ever
-    materialized beyond the per-page tile."""
+    materialized beyond the per-page tile.  ``group`` / ``window`` /
+    ``head_major`` as in :func:`flash_attention_decode_paged`."""
     b, h, d = q.shape
-    ps = k.shape[1]
+    ps = k.shape[2] if head_major else k.shape[1]
     n_max = page_tables.shape[1]
     qf = q.astype(jnp.float32) * scale                 # (B, H, D)
     quantized = k_scale is not None
+    if window is not None:
+        start = jnp.maximum(lengths - window, 0)       # (B,)
+
+    def page_tile(x, pages):
+        tile = jnp.take(x, pages, axis=0)
+        if head_major:                        # (B, Hkv, ps, D)
+            tile = jnp.swapaxes(tile, 1, 2)
+        if group != 1:                        # (B, ps, Hkv, D)
+            tile = jnp.repeat(tile, group, axis=2)
+        return tile
 
     def body(carry, j):
         m, l, acc = carry
-        pages = page_tables[:, j]                      # (B,)
-        kj = jnp.take(k, pages, axis=0)                # (B, ps, H, D)
-        vj = jnp.take(v, pages, axis=0)
+        if window is None:
+            pages = page_tables[:, j]                  # (B,)
+            k_pos = (j * ps + jnp.arange(ps))[None, None, :]
+        else:
+            # the ring: logical page ``first + j`` lies in column
+            # ``(first + j) mod n_max``
+            page_no = start // ps + j
+            pages = jnp.take_along_axis(
+                page_tables, (page_no % n_max)[:, None], axis=1)[:, 0]
+            k_pos = (page_no[:, None] * ps
+                     + jnp.arange(ps)[None, :])[:, None, :]
+        kj = page_tile(k, pages)                       # (B, ps, H, D)
+        vj = page_tile(v, pages)
         kjf = kj.astype(jnp.float32)
         vjf = vj.astype(jnp.float32)
         if quantized:
@@ -746,9 +803,10 @@ def _decode_paged_blockwise_jnp(q, k, v, page_tables, lengths, scale,
             vjf = vjf * jnp.take(v_scale, pages,
                                  axis=0).astype(jnp.float32)[..., None]
         s = jnp.einsum('bhd,bkhd->bhk', qf, kjf)       # (B, H, ps)
-        k_pos = j * ps + jnp.arange(ps)
-        s = jnp.where(k_pos[None, None, :] < lengths[:, None, None],
-                      s, NEG_INF)
+        ok = k_pos < lengths[:, None, None]
+        if window is not None:
+            ok = jnp.logical_and(ok, k_pos >= start[:, None, None])
+        s = jnp.where(ok, s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
         alpha = jnp.exp(m - m_new)
         p = jnp.exp(s - m_new[..., None])
@@ -768,7 +826,8 @@ def _decode_paged_blockwise_jnp(q, k, v, page_tables, lengths, scale,
 
 def _decode_paged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref,
                          ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref,
-                         *, scale, page_size, quantized):
+                         *, scale, page_size, quantized, window=None,
+                         head_major=False):
     """One (sequence, page) grid cell: the online-softmax update of
     ALL heads' single query rows against one PAGE of the pool.  The
     page table and per-sequence lengths are scalar-prefetched (SMEM),
@@ -779,7 +838,14 @@ def _decode_paged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref,
     the last two block dims whole), so the one-row-per-head products
     run on the VPU -- multiply by the broadcast query, reduce over the
     lane (D) axis -- instead of H separate M=1 matmuls; the softmax
-    state is per head, (H, 1) / (H, D) in VMEM scratch."""
+    state is per head, (H, 1) / (H, D) in VMEM scratch.
+
+    ``head_major``: the page is (Hkv, page_size, D) and the queries
+    (Hkv, G, D), the G query heads of a group riding ONE read of their
+    K/V head's page: two batched MXU products per page, state
+    (Hkv, G, 1) / (Hkv, G, D).  ``window``: only the ``window``
+    positions before ``length`` are live and step ``j`` is LOGICAL
+    page ``first + j`` (the index map addresses it through the ring)."""
     import jax.experimental.pallas as pl
 
     b = pl.program_id(0)
@@ -793,11 +859,47 @@ def _decode_paged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     length = len_ref[b]
+    if window is not None:
+        start = jnp.maximum(length - window, 0)
+        j = start // page_size + j
+
+    def live(k_pos):
+        ok = k_pos < length
+        if window is not None:
+            ok = jnp.logical_and(ok, k_pos >= start)
+        return ok
 
     # pages entirely beyond this sequence's fill level contribute
     # nothing; their fetch was clamped to the live frontier (elided)
     @pl.when(j * page_size < length)
     def _accum():
+        if head_major:
+            q = q_ref[0]                               # (Hkv, G, D)
+            k = k_ref[0]                               # (Hkv, ps, D)
+            v = v_ref[0]
+            # one MXU pass in the pool's own dtype, whatever the
+            # process-wide default precision (Mosaic refuses 'highest'
+            # on bfloat16 operands)
+            s = lax.dot_general(
+                q, k, (((2,), (2,)), ((0,), (0,))),
+                precision=lax.Precision.DEFAULT,
+                preferred_element_type=jnp.float32) * scale
+            k_pos = (j * page_size
+                     + lax.broadcasted_iota(jnp.int32, s.shape, 2))
+            s = jnp.where(live(k_pos), s, NEG_INF)     # (Hkv, G, ps)
+            m_prev = m_ref[...]                        # (Hkv, G, 1)
+            m_new = jnp.maximum(m_prev,
+                                jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            m_ref[...] = m_new
+            l_ref[...] = l_ref[...] * alpha + jnp.sum(
+                p, axis=-1, keepdims=True)
+            acc_ref[...] = acc_ref[...] * alpha + lax.dot_general(
+                p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+                precision=lax.Precision.DEFAULT,
+                preferred_element_type=jnp.float32)
+            return
         q = q_ref[0].astype(jnp.float32) * scale       # (H, D)
         k = k_ref[0].astype(jnp.float32)               # (ps, H, D)
         v = v_ref[0].astype(jnp.float32)
@@ -807,7 +909,7 @@ def _decode_paged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref,
         s = jnp.sum(k * q[None], axis=-1, keepdims=True)  # (ps, H, 1)
         k_pos = (j * page_size
                  + lax.broadcasted_iota(jnp.int32, s.shape, 0))
-        s = jnp.where(k_pos < length, s, NEG_INF)
+        s = jnp.where(live(k_pos), s, NEG_INF)
         m_prev = m_ref[...]                            # (H, 1)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
         alpha = jnp.exp(m_prev - m_new)
@@ -816,19 +918,20 @@ def _decode_paged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref,
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=0)
         acc_ref[...] = acc_ref[...] * alpha + jnp.sum(p * v, axis=0)
 
-    @pl.when(j == n_pages - 1)
+    @pl.when(pl.program_id(1) == n_pages - 1)
     def _finalize():
         l_safe = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
 
 
 def _decode_paged_pallas(q, k, v, page_tables, lengths, scale,
-                         k_scale=None, v_scale=None):
+                         k_scale=None, v_scale=None, group=1,
+                         window=None, head_major=False):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, d = q.shape
-    ps = k.shape[1]
+    ps = k.shape[2] if head_major else k.shape[1]
     n_max = page_tables.shape[1]
     quantized = k_scale is not None
 
@@ -836,7 +939,12 @@ def _decode_paged_pallas(q, k, v, page_tables, lengths, scale,
         # clamp the fetched page at the live frontier: dead steps
         # re-fetch the last live page, which Pallas elides
         last = jnp.maximum((len_ref[i] - 1) // ps, 0)
-        return (table_ref[i, jnp.minimum(j, last)], 0, 0, 0)
+        if window is None:
+            column = jnp.minimum(j, last)
+        else:
+            first = jnp.maximum(len_ref[i] - window, 0) // ps
+            column = jnp.minimum(first + j, last) % n_max
+        return (table_ref[i, column], 0, 0, 0)
 
     if quantized:
         # (P, ps, H) -> (P, ps, H, 1): the scale tile lines up with
@@ -850,36 +958,51 @@ def _decode_paged_pallas(q, k, v, page_tables, lengths, scale,
         ks = vs = jnp.zeros((1, 1, h, 1), jnp.float32)
         scale_spec = pl.BlockSpec((1, 1, h, 1),
                                   lambda i, j, t, n: (0, 0, 0, 0))
+    if head_major:
+        # (B, H, D) -> (B, Hkv, G, D): a group's query heads are
+        # adjacent, so this is a view
+        h_kv = h // group
+        q = q.reshape(b, h_kv, group, d)
+        row, state = (1, h_kv, group, d), (h_kv, group)
+        row_ix = lambda i, j, t, n: (i, 0, 0, 0)       # noqa: E731
+        page = (1, h_kv, ps, d)
+    else:
+        row, state = (1, h, d), (h,)
+        row_ix = lambda i, j, t, n: (i, 0, 0)          # noqa: E731
+        page = (1, ps, h, d)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,       # page_tables, lengths
         grid=(b, n_max),
         in_specs=[
-            pl.BlockSpec((1, h, d), lambda i, j, t, n: (i, 0, 0)),
-            pl.BlockSpec((1, ps, h, d), kv_ix),
-            pl.BlockSpec((1, ps, h, d), kv_ix),
+            pl.BlockSpec(row, row_ix),
+            pl.BlockSpec(page, kv_ix),
+            pl.BlockSpec(page, kv_ix),
             scale_spec,
             scale_spec,
         ],
-        out_specs=pl.BlockSpec((1, h, d), lambda i, j, t, n: (i, 0, 0)),
+        out_specs=pl.BlockSpec(row, row_ix),
         scratch_shapes=[
-            pltpu.VMEM((h, 1), jnp.float32),           # m
-            pltpu.VMEM((h, 1), jnp.float32),           # l
-            pltpu.VMEM((h, d), jnp.float32),           # acc
+            pltpu.VMEM(state + (1,), jnp.float32),     # m
+            pltpu.VMEM(state + (1,), jnp.float32),     # l
+            pltpu.VMEM(state + (d,), jnp.float32),     # acc
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_decode_paged_kernel, scale=scale,
-                          page_size=ps, quantized=quantized),
+                          page_size=ps, quantized=quantized,
+                          window=window, head_major=head_major),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret_flag(),
         name='flash_attention_decode_paged',
     )(page_tables, lengths, q, k, v, ks, vs)
+    return out.reshape(b, h, d)
 
 
 def flash_attention_decode_paged(q, k, v, page_tables, lengths,
                                  scale=None, k_scale=None,
-                                 v_scale=None):
+                                 v_scale=None, group=1, window=None,
+                                 head_major=False):
     """Single-token decode attention against a PAGED KV cache.
 
     q: (B, H, D) -- one query row per sequence; k/v:
@@ -903,6 +1026,24 @@ def flash_attention_decode_paged(q, k, v, page_tables, lengths,
     scales ``k_scale``/``v_scale`` (P, page_size, H) from
     :func:`chainermn_tpu.precision.quantize_kv`, dequantized per tile
     in VMEM exactly like the slot-cache kernel.
+
+    Three static arguments, all off by default (the call then lowers
+    to what it lowered to before they existed):
+
+    ``group``: query heads per K/V head.  The pool holds
+    ``Hkv = H / group`` heads; query head ``i`` reads K/V head
+    ``i // group``, and the ``group`` heads of one K/V head ride ONE
+    read of its page.
+    ``head_major``: the pool is (P, Hkv, page_size, D), so that a
+    leaf's two minor dims are a whole ``(page_size, D)`` tile however
+    few K/V heads there are (4 heads as the second-minor dim would pad
+    to 16 sublanes in bf16, four times the bytes).  ``group > 1``
+    needs it; no int8 scales in this layout yet.
+    ``window``: only positions ``lengths[b] - window .. lengths[b] - 1``
+    are attended, and ``page_tables`` is a RING: position ``p`` lives
+    in column ``(p // page_size) % n_max_pages``.  The ring must hold
+    the window from any offset: ``n_max_pages >= ceil(window /
+    page_size) + 1``.
     """
     b, h, d = q.shape
     if k.ndim != 4:
@@ -911,15 +1052,82 @@ def flash_attention_decode_paged(q, k, v, page_tables, lengths,
     if (k_scale is None) != (v_scale is None):
         raise ValueError('int8 KV decode needs BOTH k_scale and '
                          'v_scale (or neither)')
+    if group != 1 and not head_major:
+        raise ValueError('grouped K/V heads need the head-major pool '
+                         '(head_major=True)')
+    if head_major and k_scale is not None:
+        raise NotImplementedError(
+            'int8 K/V in the head-major paged layout')
+    if h != group * k.shape[1 if head_major else 2]:
+        raise ValueError('%d query heads are not %d groups of %d'
+                         % (h, k.shape[1 if head_major else 2], group))
+    if window is not None:
+        ps = k.shape[2] if head_major else k.shape[1]
+        if page_tables.shape[1] < -(-window // ps) + 1:
+            raise ValueError(
+                'a ring of %d pages of %d cannot hold a window of %d '
+                'from every offset' % (page_tables.shape[1], ps, window))
     if scale is None:
         scale = d ** -0.5
     tables = page_tables.astype(jnp.int32)
     lens = lengths.astype(jnp.int32)
+    run = (_decode_paged_blockwise_jnp if pallas_mode() == 'fallback'
+           else _decode_paged_pallas)
+    return run(q, k, v, tables, lens, scale, k_scale, v_scale, group,
+               window, head_major)
+
+
+def _kv_append_kernel(pages_ref, offsets_ref, k_new_ref, v_new_ref,
+                      k_ref, v_ref, k_out_ref, v_out_ref):
+    import jax.experimental.pallas as pl
+
+    at = offsets_ref[pl.program_id(0)]
+    for new_ref, page_ref, out_ref in ((k_new_ref, k_ref, k_out_ref),
+                                       (v_new_ref, v_ref, v_out_ref)):
+        page = page_ref[0]                             # (Hkv, ps, D)
+        row = lax.broadcasted_iota(jnp.int32, page.shape, 1)
+        out_ref[0] = jnp.where(row == at, new_ref[0], page)
+
+
+def paged_kv_append(k, v, k_new, v_new, pages, offsets):
+    """One token a sequence into a HEAD-MAJOR page pool, in place:
+    ``k`` / ``v`` (P, Hkv, page_size, D), ``k_new`` / ``v_new``
+    (B, Hkv, D), written at ``[pages[b], :, offsets[b]]``.  Returns
+    the two pools.
+
+    Why a kernel: an XLA scatter whose update is a (Hkv, D) slab wants
+    the slab contiguous, so on the chip it relays the whole pool out to
+    ``(P, page_size, Hkv, D)`` and back, every call (what
+    ``chip_smoke.serving_pool_check`` catches).  Here a grid step
+    takes one row's page through VMEM and puts the token's row into it
+    with a select; the pools are the call's aliased outputs, so nothing
+    else of them moves.  Rows that share a page (idle rows on the
+    scratch page) overwrite each other, as a scatter's would."""
     if pallas_mode() == 'fallback':
-        return _decode_paged_blockwise_jnp(q, k, v, tables, lens,
-                                           scale, k_scale, v_scale)
-    return _decode_paged_pallas(q, k, v, tables, lens, scale,
-                                k_scale, v_scale)
+        return (k.at[pages, :, offsets].set(k_new.astype(k.dtype)),
+                v.at[pages, :, offsets].set(v_new.astype(v.dtype)))
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h_kv, d = k_new.shape
+    ps = k.shape[2]
+    new = pl.BlockSpec((1, h_kv, 1, d), lambda i, p, o: (i, 0, 0, 0))
+    page = pl.BlockSpec((1, h_kv, ps, d),
+                        lambda i, p, o: (p[i], 0, 0, 0))
+    return pl.pallas_call(
+        _kv_append_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(b,),
+            in_specs=[new, new, page, page], out_specs=[page, page]),
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        # operands count the two prefetched scalars: k is 4, v is 5
+        input_output_aliases={4: 0, 5: 1},
+        interpret=interpret_flag(),
+        name='paged_kv_append',
+    )(pages.astype(jnp.int32), offsets.astype(jnp.int32),
+      k_new.astype(k.dtype)[:, :, None], v_new.astype(v.dtype)[:, :, None],
+      k, v)
 
 
 # ----------------------------------------------------------------------
@@ -1129,12 +1337,20 @@ def _env_block(name, default=128):
 
 
 def flash_attention(q, k, v, causal=False, scale=None,
-                    block_q=None, block_k=None):
-    """Fused attention. q: (B, Tq, H, D), k/v: (B, Tkv, H, D).
+                    block_q=None, block_k=None, window=None):
+    """Fused attention. q: (B, Tq, H, D), k/v: (B, Tkv, Hkv, D).
 
     Sequence lengths are padded to kernel block multiples internally
     (padded keys are masked out; padded query rows are dropped); with
     ``causal=True``, Tq must equal Tkv (self-attention).
+
+    Grouped K/V heads: ``Hkv`` may divide ``H``; query head ``i`` then
+    reads K/V head ``i // (H / Hkv)`` through the kernel's index map
+    (no repeated copy of K/V).  ``window`` (causal only): the query at
+    position ``p`` sees the ``window`` keys ``p - window + 1 .. p``;
+    key blocks wholly outside are neither fetched nor computed.  Both
+    are FORWARD-ONLY (serving): the backward kernels know neither, so
+    such a call has no gradient.
 
     Block sizes default to 128x128; ``CHAINERMN_TPU_FA_BLOCK_Q`` /
     ``CHAINERMN_TPU_FA_BLOCK_K`` override the defaults per process
@@ -1147,10 +1363,17 @@ def flash_attention(q, k, v, causal=False, scale=None,
     if block_k is None:
         block_k = _env_block('CHAINERMN_TPU_FA_BLOCK_K')
     b, t_q, h, d = q.shape
-    t_kv = k.shape[1]
+    t_kv, h_kv = k.shape[1:3]
     if causal and t_q != t_kv:
         raise ValueError('causal attention requires t_q == t_kv, got '
                          '%d vs %d' % (t_q, t_kv))
+    if h % h_kv:
+        raise ValueError('%d K/V heads do not divide %d query heads'
+                         % (h_kv, h))
+    if window is not None and not causal:
+        raise ValueError('a window bounds CAUSAL attention; pass '
+                         'causal=True')
+    group = h // h_kv
     if scale is None:
         scale = d ** -0.5
     block_q = min(block_q, max(t_q, 1))
@@ -1158,7 +1381,7 @@ def flash_attention(q, k, v, causal=False, scale=None,
 
     def merge(x):
         # (B, T, H, D) -> (B*H, T, D)
-        return jnp.swapaxes(x, 1, 2).reshape(b * h, x.shape[1], d)
+        return jnp.swapaxes(x, 1, 2).reshape(-1, x.shape[1], d)
 
     pad_q = (-t_q) % block_q
     pad_k = (-t_kv) % block_k
@@ -1168,6 +1391,13 @@ def flash_attention(q, k, v, causal=False, scale=None,
     if pad_k:
         km = jnp.pad(km, ((0, 0), (0, pad_k), (0, 0)))
         vm = jnp.pad(vm, ((0, 0), (0, pad_k), (0, 0)))
-    out = _flash(qm, km, vm, causal, scale, t_kv, block_q, block_k)
+    if group == 1 and window is None:
+        out = _flash(qm, km, vm, causal, scale, t_kv, block_q, block_k)
+    elif pallas_mode() == 'fallback':
+        out, _ = _fwd_blockwise_jnp(qm, km, vm, causal, scale, t_kv,
+                                    block_k, group, window)
+    else:
+        out, _ = _fwd_pallas(qm, km, vm, causal, scale, t_kv, block_q,
+                             block_k, group, window)
     out = out[:, :t_q]
     return jnp.swapaxes(out.reshape(b, h, t_q, d), 1, 2)

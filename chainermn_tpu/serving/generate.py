@@ -338,10 +338,10 @@ class _Slot:
     """Host-side state of one cache slot."""
 
     __slots__ = ('request', 'position', 'remaining', 'generated',
-                 't_last_token', 't_stage_end', 'pages')
+                 't_last_token', 't_stage_end', 'pages', 'ring')
 
     def __init__(self, request, position, remaining, first_token,
-                 t_now, t_stage_end=None, pages=None):
+                 t_now, t_stage_end=None, pages=None, ring=()):
         self.request = request
         self.position = position          # next token's position
         self.remaining = remaining        # tokens still to generate
@@ -354,6 +354,8 @@ class _Slot:
         # paged engine: this sequence's page table (one pool ref per
         # entry, released on completion/cancel); None on slot engines
         self.pages = pages
+        # ... and its window layers' ring (a model with none: empty)
+        self.ring = ring
 
 
 class _PrefillState:
@@ -362,13 +364,14 @@ class _PrefillState:
     scheduler tick, so a long prompt spends several ticks here before
     graduating to a :class:`_Slot`."""
 
-    __slots__ = ('request', 'pages', 'pos', 'matched', 'chunks',
-                 't_pop', 't_stage_end')
+    __slots__ = ('request', 'pages', 'ring', 'pos', 'matched',
+                 'chunks', 't_pop', 't_stage_end')
 
     def __init__(self, request, pages, pos, matched, t_pop=None,
                  t_stage_end=None):
         self.request = request
         self.pages = pages       # page table so far (refs held)
+        self.ring = []           # window layers' ring pages so far
         self.pos = pos           # next absolute position to prefill
         self.matched = matched   # prefix tokens reused from the index
         self.chunks = 0          # chunks dispatched so far
@@ -377,8 +380,12 @@ class _PrefillState:
 
 
 class GenerationEngine:
-    """Continuous-batching autoregressive server for one
-    :class:`~chainermn_tpu.models.TransformerLM`.
+    """Continuous-batching autoregressive server for one causal LM.
+    The engine names no model family: the cache constructors and the
+    prefill / decode / verify bodies are METHODS OF THE MODEL
+    (``docs/serving.md``, "the model protocol"), which
+    :class:`~chainermn_tpu.models.TransformerLM` and
+    :class:`~chainermn_tpu.models.AfmoeLM` both have.
 
     Args:
       model: the flax module (``tp_axis`` set when serving over
@@ -465,7 +472,6 @@ class GenerationEngine:
                  draft_model=None, draft_params=None, spec_tokens=4,
                  plan=None, param_specs=None, aot=True, label=None,
                  version=0):
-        from chainermn_tpu.models import kv_cache_specs
         from chainermn_tpu.serving.paged import (PagePool,
                                                  RadixPrefixIndex)
 
@@ -474,6 +480,11 @@ class GenerationEngine:
         _telemetry.maybe_enable_from_env()
         _telemetry.install_compile_log()
         self.model = model
+        # a family refuses here, in one message, what it has no path for
+        model.check_serving(
+            paged=paged, int8_kv=int8_kv, prefill_chunk=prefill_chunk,
+            prefix_sharing=bool(paged and prefix_sharing),
+            draft_model=draft_model is not None, plan=plan is not None)
         self.label = label
         self.param_version = int(version)
         self._boot_version = self.param_version
@@ -539,6 +550,16 @@ class GenerationEngine:
             self.pool = PagePool(self.n_pages, self.page_size)
             self._prefix_index = (RadixPrefixIndex(self.pool)
                                   if prefix_sharing else None)
+            # window layers keep their pages in a RING per sequence,
+            # out of a pool of their own leaf shape; a sequence's two
+            # tables ride one operand, [full table | ring]
+            self._ring = int(model.window_ring(self.page_size))
+            self._window = (int(model.sliding_window) if self._ring
+                            else None)
+            self.window_pool = (
+                PagePool(1 + self.n_slots * self._ring, self.page_size)
+                if self._ring else None)
+            self._table_width = self.pages_per_seq + self._ring
         else:
             if n_pages is not None:
                 raise ValueError('n_pages requires paged=True')
@@ -546,10 +567,13 @@ class GenerationEngine:
             self.n_pages = None
             self.pool = None
             self._prefix_index = None
+            self._ring, self._window, self.window_pool = 0, None, None
+            self._table_width = None
         # the GLOBAL cache is built unsharded (tp=1); specs shard it
         cache = self._new_cache(model)
-        self._cache_specs = (kv_cache_specs(cache, plan.model_axis)
-                             if plan is not None else None)
+        self._cache_specs = (
+            model.kv_cache_specs(cache, plan.model_axis)
+            if plan is not None else None)
         self._cache = jax.device_put(cache, self._cache_sharding())
         self._cache_struct, self._cache_sig = _struct_and_signature(
             cache)
@@ -692,7 +716,7 @@ class GenerationEngine:
                         jnp.zeros((self.n_slots,), jnp.int32)]
             if self.paged:
                 val_args.append(jnp.zeros(
-                    (self.n_slots, self.pages_per_seq), jnp.int32))
+                    (self.n_slots, self._table_width), jnp.int32))
             try:
                 tok, cache = exe(new, self._cache, *val_args)
                 tok = jax.block_until_ready(tok)
@@ -720,15 +744,14 @@ class GenerationEngine:
 
     def _new_cache(self, model):
         """Zeroed cache of this engine's geometry for ``model`` (the
-        target or the draft)."""
-        from chainermn_tpu.models import (init_kv_cache,
-                                          init_paged_kv_cache)
-        if self.paged:
-            return init_paged_kv_cache(model, self.n_pages,
-                                       self.page_size,
+        target or the draft), from the model's own constructor."""
+        if not self.paged:
+            return model.init_kv_cache(self.n_slots, self.max_len,
                                        int8_kv=self.int8_kv)
-        return init_kv_cache(model, self.n_slots, self.max_len,
-                             int8_kv=self.int8_kv)
+        ring = ({'n_window_pages': self.window_pool.n_pages}
+                if self._ring else {})
+        return model.init_paged_kv_cache(
+            self.n_pages, self.page_size, int8_kv=self.int8_kv, **ring)
 
     def _cache_sharding(self):
         if self.plan is None:
@@ -749,40 +772,59 @@ class GenerationEngine:
             return self.policy.dequantize(params)
         return params
 
+    @staticmethod
+    def _sampled(logits, counters):
+        """What an executable hands back beside the cache: the greedy
+        token(s) and, for a model with ``serve_counters``, those
+        float32 scalars bit-cast behind them in the SAME int32 vector,
+        so one read brings both to the host."""
+        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        if not counters:
+            return tok
+        return jnp.concatenate([
+            tok.reshape(-1), jax.lax.bitcast_convert_type(
+                jnp.stack(counters).astype(jnp.float32), jnp.int32)])
+
+    def _split_sampled(self, out):
+        """The host's half of :meth:`_sampled`: ``(tokens, {counter:
+        value})`` of a fetched result."""
+        names = self.model.serve_counters
+        out = np.asarray(out)
+        if not names:
+            return out, {}
+        values = out[out.size - len(names):].view(np.float32)
+        return out[:out.size - len(names)], {
+            name: float(v) for name, v in zip(names, values)}
+
     def _prefill_body(self, params, cache, tokens, length, slot):
-        from chainermn_tpu.models import prefill as model_prefill
         self.prefill_trace_count += 1  # trace-time counter
-        logits, cache = model_prefill(
-            self.model, self._prepare_params(params), cache, tokens,
-            length, slot)
-        return jnp.argmax(logits).astype(jnp.int32), cache
+        logits, cache, counters = self.model.prefill(
+            self._prepare_params(params), cache, tokens, length, slot)
+        return self._sampled(logits, counters), cache
 
     def _decode_body(self, params, cache, tokens, positions,
                      slots=None):
-        from chainermn_tpu.models import decode_step
         self.decode_trace_count += 1   # trace-time counter
-        logits, cache = decode_step(
-            self.model, self._prepare_params(params), cache, tokens,
-            positions, slots=slots)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
+        logits, cache, counters = self.model.decode_step(
+            self._prepare_params(params), cache, tokens, positions,
+            slots=slots)
+        return self._sampled(logits, counters), cache
 
     def _prefill_body_paged(self, params, cache, tokens, length, pos0,
                             table):
-        from chainermn_tpu.models import prefill_paged
         self.prefill_trace_count += 1  # trace-time counter
-        logits, cache = prefill_paged(
-            self.model, self._prepare_params(params), cache, tokens,
-            length, table, pos0)
-        return jnp.argmax(logits).astype(jnp.int32), cache
+        logits, cache, counters = self.model.prefill_paged(
+            self._prepare_params(params), cache, tokens, length, table,
+            pos0)
+        return self._sampled(logits, counters), cache
 
     def _decode_body_paged(self, params, cache, tokens, positions,
                            tables):
-        from chainermn_tpu.models import decode_step_paged
         self.decode_trace_count += 1   # trace-time counter
-        logits, cache = decode_step_paged(
-            self.model, self._prepare_params(params), cache, tokens,
-            positions, tables)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
+        logits, cache, counters = self.model.decode_step_paged(
+            self._prepare_params(params), cache, tokens, positions,
+            tables)
+        return self._sampled(logits, counters), cache
 
     def _copy_body(self, params, cache, src, dst):
         """Copy-on-write page duplication: every leaf's page ``src``
@@ -799,55 +841,46 @@ class GenerationEngine:
 
     # -- speculative traced bodies (the draft twin + verify) -----------
     def _draft_prefill_body(self, params, cache, tokens, length, slot):
-        from chainermn_tpu.models import prefill as model_prefill
         self.draft_trace_count += 1    # trace-time counter
-        logits, cache = model_prefill(
-            self.draft_model, params, cache, tokens, length, slot)
+        logits, cache = self.draft_model.prefill(
+            params, cache, tokens, length, slot)[:2]
         return jnp.argmax(logits).astype(jnp.int32), cache
 
     def _draft_prefill_body_paged(self, params, cache, tokens, length,
                                   pos0, table):
-        from chainermn_tpu.models import prefill_paged
         self.draft_trace_count += 1    # trace-time counter
-        logits, cache = prefill_paged(
-            self.draft_model, params, cache, tokens, length, table,
-            pos0)
+        logits, cache = self.draft_model.prefill_paged(
+            params, cache, tokens, length, table, pos0)[:2]
         return jnp.argmax(logits).astype(jnp.int32), cache
 
     def _draft_decode_body(self, params, cache, tokens, positions,
                            slots=None):
-        from chainermn_tpu.models import decode_step
         self.draft_trace_count += 1    # trace-time counter
-        logits, cache = decode_step(
-            self.draft_model, params, cache, tokens, positions,
-            slots=slots)
+        logits, cache = self.draft_model.decode_step(
+            params, cache, tokens, positions, slots=slots)[:2]
         return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
 
     def _draft_decode_body_paged(self, params, cache, tokens,
                                  positions, tables):
-        from chainermn_tpu.models import decode_step_paged
         self.draft_trace_count += 1    # trace-time counter
-        logits, cache = decode_step_paged(
-            self.draft_model, params, cache, tokens, positions,
-            tables)
+        logits, cache = self.draft_model.decode_step_paged(
+            params, cache, tokens, positions, tables)[:2]
         return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
 
     def _verify_body(self, params, cache, tokens, positions,
                      slots=None):
-        from chainermn_tpu.models import spec_verify
         self.verify_trace_count += 1   # trace-time counter
-        logits, cache = spec_verify(
-            self.model, self._prepare_params(params), cache, tokens,
-            positions, slots=slots)
+        logits, cache = self.model.spec_verify(
+            self._prepare_params(params), cache, tokens, positions,
+            slots=slots)
         return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
 
     def _verify_body_paged(self, params, cache, tokens, positions,
                            tables):
-        from chainermn_tpu.models import spec_verify_paged
         self.verify_trace_count += 1   # trace-time counter
-        logits, cache = spec_verify_paged(
-            self.model, self._prepare_params(params), cache, tokens,
-            positions, tables)
+        logits, cache = self.model.spec_verify_paged(
+            self._prepare_params(params), cache, tokens, positions,
+            tables)
         return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
 
     def _draft_mapped(self, body, n_extra):
@@ -898,7 +931,7 @@ class GenerationEngine:
             return (jax.ShapeDtypeStruct((1, bucket), i32),
                     jax.ShapeDtypeStruct((), i32),
                     jax.ShapeDtypeStruct((), i32),
-                    jax.ShapeDtypeStruct((self.pages_per_seq,), i32))
+                    jax.ShapeDtypeStruct((self._table_width,), i32))
         return (jax.ShapeDtypeStruct((1, bucket), i32),
                 jax.ShapeDtypeStruct((), i32),
                 jax.ShapeDtypeStruct((), i32))
@@ -910,7 +943,7 @@ class GenerationEngine:
             # through tables, so there is no full-vs-compacted split
             return (jax.ShapeDtypeStruct((bucket,), i32),
                     jax.ShapeDtypeStruct((bucket,), i32),
-                    jax.ShapeDtypeStruct((bucket, self.pages_per_seq),
+                    jax.ShapeDtypeStruct((bucket, self._table_width),
                                          i32))
         if bucket == self.n_slots:
             return (jax.ShapeDtypeStruct((bucket,), i32),
@@ -928,7 +961,7 @@ class GenerationEngine:
         if self.paged:
             return (jax.ShapeDtypeStruct((bucket, kk), i32),
                     jax.ShapeDtypeStruct((bucket,), i32),
-                    jax.ShapeDtypeStruct((bucket, self.pages_per_seq),
+                    jax.ShapeDtypeStruct((bucket, self._table_width),
                                          i32))
         if bucket == self.n_slots:
             return (jax.ShapeDtypeStruct((bucket, kk), i32),
@@ -1061,7 +1094,7 @@ class GenerationEngine:
                 jnp.zeros((bucket,), jnp.int32)]
         if self.paged:
             args.append(jnp.zeros((bucket,), jnp.int32))
-            args.append(jnp.zeros((bucket, self.pages_per_seq),
+            args.append(jnp.zeros((bucket, self._table_width),
                                   jnp.int32))
             return fn, tuple(args)
         if bucket != self.n_slots:
@@ -1185,7 +1218,7 @@ class GenerationEngine:
                 jnp.zeros((bucket, self.spec_tokens), jnp.int32)]
         if self.paged:
             args.append(jnp.zeros((bucket,), jnp.int32))
-            args.append(jnp.zeros((bucket, self.pages_per_seq),
+            args.append(jnp.zeros((bucket, self._table_width),
                                   jnp.int32))
             return fn, tuple(args)
         if bucket != self.n_slots:
@@ -1211,7 +1244,7 @@ class GenerationEngine:
                     if self.paged:
                         # zero table: warmup garbage lands on the
                         # scratch page, never in a live table
-                        args.append(jnp.zeros((self.pages_per_seq,),
+                        args.append(jnp.zeros((self._table_width,),
                                               jnp.int32))
                     tok, cache = exe(self.params, self._cache, *args)
                     jax.block_until_ready(tok)
@@ -1225,7 +1258,7 @@ class GenerationEngine:
                         args = [jnp.zeros((bucket,), jnp.int32),
                                 jnp.zeros((bucket,), jnp.int32),
                                 jnp.zeros((bucket,
-                                           self.pages_per_seq),
+                                           self._table_width),
                                           jnp.int32)]
                     else:
                         args = [jnp.zeros((bucket,), jnp.int32),
@@ -1277,7 +1310,7 @@ class GenerationEngine:
                             jnp.asarray(1, jnp.int32),
                             jnp.asarray(0, jnp.int32)]
                     if self.paged:
-                        args.append(jnp.zeros((self.pages_per_seq,),
+                        args.append(jnp.zeros((self._table_width,),
                                               jnp.int32))
                     tok, dcache = exe(self._draft_params,
                                       self._draft_cache, *args)
@@ -1320,7 +1353,7 @@ class GenerationEngine:
         args = [jnp.zeros(shape, jnp.int32)]
         if self.paged:
             args.append(jnp.zeros((bucket,), jnp.int32))
-            args.append(jnp.zeros((bucket, self.pages_per_seq),
+            args.append(jnp.zeros((bucket, self._table_width),
                                   jnp.int32))
         else:
             if bucket != self.n_slots:
@@ -1371,7 +1404,7 @@ class GenerationEngine:
                 doomed.append(sid)
         for sid in doomed:
             slot = self._slots.pop(sid)
-            self._release_pages(slot.pages)
+            self._release_pages(slot.pages, slot.ring)
             self._free.append(sid)
             self.cancelled += 1
             slot.request.set_error(OverloadError(
@@ -1390,7 +1423,7 @@ class GenerationEngine:
                     if st.request.deadline is not None
                     and now > st.request.deadline]:
             state = self._prefilling.pop(sid)
-            self._release_pages(state.pages)
+            self._release_pages(state.pages, state.ring)
             self._free.append(sid)
             self.cancelled += 1
             doomed.append(sid)
@@ -1406,10 +1439,21 @@ class GenerationEngine:
         return len(doomed)
 
     # -- paged-mode page accounting ------------------------------------
-    def _release_pages(self, pages):
+    def _release_pages(self, pages, ring=()):
         if pages:
             for page in pages:
                 self.pool.release(page)
+        for page in ring:
+            self.window_pool.release(page)
+
+    def _grow_ring(self, ring, last_page):
+        """Window pages of a sequence whose newest position lies in
+        logical page ``last_page``: one ring column per page until the
+        ring is full, then nothing, ever (nothing at all for a model
+        without window layers, whose ring is 0).  The window pool
+        holds a full ring for every slot, so it cannot run dry."""
+        while len(ring) < min(last_page + 1, self._ring):
+            ring.append(self.window_pool.alloc())
 
     def _alloc_page(self):
         """One free page, LRU-evicting banked prefixes when the pool
@@ -1421,16 +1465,22 @@ class GenerationEngine:
             page = self.pool.alloc()
         return page
 
-    def _table_array(self, pages):
-        table = np.zeros((self.pages_per_seq,), np.int32)
+    def _table_array(self, pages, ring=(), out=None):
+        """One sequence's table operand, ``[full table | ring]``
+        (into ``out``, a zeroed row of the decode step's tables)."""
+        table = (np.zeros((self._table_width,), np.int32)
+                 if out is None else out)
         table[:len(pages)] = pages
+        if ring:
+            table[self.pages_per_seq:self.pages_per_seq + len(ring)] \
+                = ring
         return table
 
-    def _shed_paged(self, req, pages, where):
+    def _shed_paged(self, req, pages, where, ring=()):
         """Typed shed when the page pool is exhausted (the paged twin
         of queue_full): pages retained so far go back, the client
         gets ``OverloadError(reason='kv_pages')``."""
-        self._release_pages(pages)
+        self._release_pages(pages, ring)
         self.cancelled += 1
         record_shed('kv_pages', request_id=req.request_id,
                     queue_depth=self._last_queue_depth, where=where,
@@ -1653,9 +1703,10 @@ class GenerationEngine:
                 st.pages.append(page)
             if dry:
                 del self._prefilling[sid]
-                self._shed_paged(req, st.pages, 'prefill')
+                self._shed_paged(req, st.pages, 'prefill', st.ring)
                 self._free.append(sid)
                 continue
+            self._grow_ring(st.ring, last_page)
             worked = True
             tokens = np.zeros((1, width), np.int32)
             tokens[0, :n] = prompt[st.pos:st.pos + n]
@@ -1663,7 +1714,7 @@ class GenerationEngine:
             args = (jnp.asarray(tokens),
                     jnp.asarray(n, jnp.int32),
                     jnp.asarray(st.pos, jnp.int32),
-                    jnp.asarray(self._table_array(st.pages)))
+                    jnp.asarray(self._table_array(st.pages, st.ring)))
             self._guard_call(self._cache_sig, args)
             if rec is not None and st.chunks == 0:
                 t_c0 = rec.now()
@@ -1677,13 +1728,16 @@ class GenerationEngine:
                 _chaos.on_serve_slow(
                     self.param_version != self._boot_version)
             with _telemetry.span('serve_prefill', kind='serve',
-                                 bucket=width, slot=sid,
+                                 bucket=width, slot=sid, tokens=n,
                                  chunk=st.chunks, pos=st.pos,
                                  iteration=self._step_index,
-                                 step=self._step_index, **ident):
+                                 step=self._step_index,
+                                 **ident) as span:
                 tok, cache = exe(self.params, self._cache, *args)
                 self._cache = cache
-                tok = jax.block_until_ready(tok)
+                tok, counters = self._split_sampled(
+                    jax.block_until_ready(tok))
+                span.set(**counters)
             if self.speculative:
                 # same chunk, same pages, into the draft cache: banked
                 # prefix pages stay valid for BOTH caches, so a future
@@ -1712,7 +1766,7 @@ class GenerationEngine:
                                    **ident)
                     st.t_stage_end = now_tele
                 continue
-            tok = int(tok)
+            tok = int(tok.reshape(-1)[0])
             del self._prefilling[sid]
             self.prefills += 1
             self.tokens_generated += 1
@@ -1741,7 +1795,7 @@ class GenerationEngine:
             if self.eos_id is not None and tok == self.eos_id \
                     or req.max_new_tokens == 1:
                 req.set_result([tok])
-                self._release_pages(st.pages)
+                self._release_pages(st.pages, st.ring)
                 self._free.append(sid)
                 if rec is not None:
                     rec.event('complete', kind='request',
@@ -1752,7 +1806,7 @@ class GenerationEngine:
                                      req.max_new_tokens - 1, tok,
                                      t_first,
                                      t_stage_end=t_first_tele,
-                                     pages=st.pages)
+                                     pages=st.pages, ring=st.ring)
         return worked
 
     def _decode_operands(self):
@@ -1772,10 +1826,12 @@ class GenerationEngine:
                     if page is None:
                         del self._slots[sid]
                         self._shed_paged(slot.request, slot.pages,
-                                         'decode')
+                                         'decode', slot.ring)
                         self._free.append(sid)
                         break
                     slot.pages.append(page)
+                else:           # not shed: its window pages too
+                    self._grow_ring(slot.ring, need)
             if not self._slots:
                 return None
         active = sorted(self._slots)
@@ -1806,11 +1862,12 @@ class GenerationEngine:
              for s in rows], np.int32)
         exe = self._get_decode(bucket)
         if self.paged:
-            tables = np.zeros((bucket, self.pages_per_seq), np.int32)
+            tables = np.zeros((bucket, self._table_width), np.int32)
             for i, sid in enumerate(rows):
                 if sid is not None:
-                    pages = self._slots[sid].pages
-                    tables[i, :len(pages)] = pages
+                    slot = self._slots[sid]
+                    self._table_array(slot.pages, slot.ring,
+                                      out=tables[i])
             args = (jnp.asarray(tokens), jnp.asarray(positions),
                     jnp.asarray(tables))
         elif bucket == self.n_slots:
@@ -1852,13 +1909,16 @@ class GenerationEngine:
                              active_slots=k, bucket=bucket,
                              n_slots=self.n_slots,
                              queue_depth=self._last_queue_depth,
-                             **ident):
+                             **ident) as span:
             toks, cache = exe(self.params, self._cache, *args)
             # rebound BEFORE the wait (here and at every call of the
             # tick): the donated cache is a husk per layer, and they
             # die while the device runs, not after it
             self._cache = cache
-            toks = np.asarray(jax.block_until_ready(toks))
+            toks, counters = self._split_sampled(
+                jax.block_until_ready(toks))
+            if counters and rec is not None:
+                span.set(**counters, **self._attended(rows))
         now = clock()
         now_tele = rec.now() if rec is not None else None
         if reg is not None:
@@ -1910,11 +1970,24 @@ class GenerationEngine:
                                   request_id=slot.request.request_id,
                                   tokens=len(slot.generated), slot=sid,
                                   **ident)
-                    self._release_pages(slot.pages)
+                    self._release_pages(slot.pages, slot.ring)
                     del self._slots[sid]
                     self._free.append(sid)
         self.decode_steps += 1
         self.tokens_generated += k
+
+    def _attended(self, rows):
+        """Positions this decode step's rows attend, by layer kind:
+        every live position in a full layer, at most the window in a
+        window layer (what the kernels' roofline shares count bytes
+        from)."""
+        live = [self._slots[s].position + 1 for s in rows
+                if s in self._slots]
+        out = {'kv_positions': sum(live)}
+        if self._window is not None:
+            out['kv_window_positions'] = sum(
+                min(n, self._window) for n in live)
+        return out
 
     def _spec_once(self, clock):
         """One SPECULATIVE tick over every active slot: ``spec_tokens``
@@ -2190,6 +2263,9 @@ class GenerationEngine:
             tick.set(queue_depth=self._last_queue_depth,
                      prefills=self.prefills - prefills,
                      active_slots=len(self._slots))
+            if self._ring:
+                tick.set(full_pages_in_use=self.pool.in_use(),
+                         window_pages_in_use=self.window_pool.in_use())
         return worked
 
     def _tick(self, queue, clock):
@@ -2273,6 +2349,13 @@ class GenerationEngine:
                 'cow_copies': self.cow_copies,
                 'copy_trace_count': self.copy_trace_count,
                 'prefilling': len(self._prefilling),
+                'full_pages_in_use': self.pool.in_use(),
+                'peak_full_pages_in_use': self.pool.peak_in_use,
+                'window_ring': self._ring,
+                'window_pages_in_use': (
+                    self.window_pool.in_use() if self._ring else 0),
+                'peak_window_pages_in_use': (
+                    self.window_pool.peak_in_use if self._ring else 0),
             }
             if self._prefix_index is not None:
                 paged.update(

@@ -17,6 +17,10 @@ the widths ``bench.py`` uses, with random weights made from a seed:
   and prefill executables at the benchmark's serving shapes
   (gpt2-medium, 2,049 pages of 16), compiled and read for copies of
   the KV page pool -- what only the chip's compiler can show;
+- *serve, afmoe*: a small ``AfmoeLM`` (grouped K/V heads, window and
+  full layers, dropless experts) through the same engine, its served
+  tokens held against the float32 forward; then the same pool check at
+  the ``trinity-mini`` cell's shapes, both kinds of cache leaf;
 - ``--chips 4`` runs ONLY the transformer step over four devices
   (data-parallel, then dp 2 x tp 2) and the one-device loss both are
   compared with.
@@ -32,7 +36,9 @@ The last line of stdout is the result::
 """
 
 import argparse
+import collections
 import contextlib
+import dataclasses
 import json
 import os
 import sys
@@ -500,6 +506,9 @@ def serve(d_model=512, n_heads=8, n_layers=6, d_ff=2048, vocab=32000,
 #: result-producing HLO instructions that move no data of their own
 _PLUMBING = ('parameter', 'tuple', 'get-tuple-element', 'bitcast')
 _WRITES = ('scatter', 'dynamic-update-slice')
+#: ... and the Pallas call that is the write of a head-major pool
+#: (``ops.paged_kv_append``: the pools are its aliased outputs)
+_WRITE_KERNEL = 'paged_kv_append'
 _HLO_DTYPE = {'bfloat16': 'bf16', 'float32': 'f32', 'int8': 's8'}
 
 
@@ -542,7 +551,7 @@ def pool_shaped(text, leaves):
     for comp, name, rtype, opcode, line in _hlo_instructions(text):
         if not any(m in rtype for m in marks) or opcode in _PLUMBING:
             continue
-        if opcode in _WRITES:
+        if opcode in _WRITES or name.startswith(_WRITE_KERNEL):
             writers.add(comp)
         elif opcode == 'fusion':
             calls[name] = line.split('calls=')[1].split(',')[0].lstrip(
@@ -556,16 +565,59 @@ def pool_shaped(text, leaves):
     return hits
 
 
+def _pool_check(engine, what, prompt_bucket):
+    """The decode and prefill executables of ``engine``, compiled by
+    the chip's own compiler, hold NO instruction that makes a value of
+    a pool leaf's shape except the in-place write, need less scratch
+    than one leaf, and keep the cache at its nominal bytes (what the
+    executable aliases in place is the leaves as they lie on the
+    device, padding and all)."""
+    import jax
+
+    leaves = jax.tree_util.tree_leaves(engine._cache)
+    leaf_bytes = max(leaf.nbytes for leaf in leaves)
+    nominal = sum(leaf.nbytes for leaf in leaves)
+    say('%s: cache leaves %s' % (what, ', '.join(
+        '%d x %s%r = %d bytes' % (n, np.dtype(dtype).name, shape,
+                                  n * int(np.prod(shape))
+                                  * np.dtype(dtype).itemsize)
+        for (shape, dtype), n in sorted(collections.Counter(
+            (leaf.shape, leaf.dtype) for leaf in leaves).items(),
+            key=str))))
+    out = {}
+    for name, exe in (('decode', engine._get_decode(engine.n_slots)),
+                      ('prefill', engine._get_prefill(prompt_bucket))):
+        hits = pool_shaped(exe.as_text(), leaves)
+        memory = exe.memory_analysis()
+        temp, held = memory.temp_size_in_bytes, memory.alias_size_in_bytes
+        say('%s %s executable: %d pool-shaped instruction(s) besides '
+            'the write %r, temp_size_in_bytes %d (one layer\'s leaf: '
+            '%d), cache on the device %d bytes (nominal %d)'
+            % (what, name, len(hits), hits[:6], temp, leaf_bytes, held,
+               nominal))
+        require(not hits, '%s %s executable makes pool-shaped values '
+                'outside the write: %r' % (what, name, hits))
+        require(temp < leaf_bytes,
+                '%s %s executable needs %d bytes of scratch, more '
+                'than one layer\'s leaf (%d): a copy of the pool'
+                % (what, name, temp, leaf_bytes))
+        require(nominal <= held <= 1.01 * nominal,
+                '%s %s executable holds the cache in %d bytes, not '
+                'its nominal %d: a leaf is padded on the device'
+                % (what, name, held, nominal))
+        out[name] = {'pool_shaped': hits, 'temp_bytes': temp,
+                     'cache_bytes': held}
+    out['leaf_bytes'] = leaf_bytes
+    return out
+
+
 def serving_pool_check(d_model=1024, n_heads=16, n_layers=24,
                        d_ff=4096, vocab=50257, max_len=1024,
                        n_slots=32, max_prompt=512, page_size=16,
                        prompt_bucket=128):
-    """The check the CPU cannot make: the decode and prefill
-    executables of ``GenerationEngine`` at the shapes of the
-    benchmark's serving cell (gpt2-medium, 32 slots, 2,049 pages of
-    16), compiled by the chip's own compiler, hold NO instruction
-    that makes a value of a pool leaf's shape except the in-place
-    write, and need less scratch than one layer's leaf.
+    """The check the CPU cannot make, at the shapes of the benchmark's
+    ``gpt2m-serve-closed32`` cell (gpt2-medium, 32 slots, 2,049 pages
+    of 16): :func:`_pool_check` on a real ``GenerationEngine``.
 
     A jaxpr shows that the program asks for no copy
     (``tests/test_transformer.py``); it cannot show what XLA
@@ -593,28 +645,124 @@ def serving_pool_check(d_model=1024, n_heads=16, n_layers=24,
         model, params, n_slots=n_slots, max_prompt_len=max_prompt,
         max_len=max_len, paged=True, page_size=page_size,
         policy=Policy.bf16())
-    leaves = jax.tree_util.tree_leaves(engine._cache)
-    leaf_bytes = max(leaf.nbytes for leaf in leaves)
-    what = 'serve d%d/L%d %d slots, %d pages of %d' % (
-        d_model, n_layers, n_slots, engine.n_pages, page_size)
-    out = {}
-    for name, exe in (('decode', engine._get_decode(n_slots)),
-                      ('prefill', engine._get_prefill(prompt_bucket))):
-        hits = pool_shaped(exe.as_text(), leaves)
-        temp = exe.memory_analysis().temp_size_in_bytes
-        say('%s %s executable: %d pool-shaped instruction(s) besides '
-            'the write %r, temp_size_in_bytes %d (one layer\'s leaf: '
-            '%d)' % (what, name, len(hits), hits[:6], temp,
-                     leaf_bytes))
-        require(not hits, '%s %s executable makes pool-shaped values '
-                'outside the write: %r' % (what, name, hits))
-        require(temp < leaf_bytes,
-                '%s %s executable needs %d bytes of scratch, more '
-                'than one layer\'s leaf (%d): a copy of the pool'
-                % (what, name, temp, leaf_bytes))
-        out[name] = {'pool_shaped': hits, 'temp_bytes': temp}
-    out['leaf_bytes'] = leaf_bytes
-    return out
+    return _pool_check(engine, 'serve d%d/L%d %d slots, %d pages of %d'
+                       % (d_model, n_layers, n_slots, engine.n_pages,
+                          page_size), prompt_bucket)
+
+
+# ----------------------------------------------------------------------
+# the afmoe family
+
+#: ``AfmoeLM`` at the ``trinity-mini`` cell's widths and depth
+#: (``chipbench/configs/trinity-mini.json``); the defaults are the
+#: published widths
+TRINITY_MINI = dict(num_hidden_layers=5, num_dense_layers=1,
+                    layer_types=('sliding_attention',) * 4
+                    + ('full_attention',))
+
+
+def serving_pool_check_afmoe(n_slots=64, max_prompt=3072, max_len=4096,
+                             page_size=64, prompt_bucket=1024,
+                             **shape):
+    """:func:`_pool_check` at the shapes of the benchmark's
+    ``trinity-mini-serve-closed64`` cell: two kinds of cache leaf,
+    ``(pages, 4, 64, 128)`` with the full layer's 4,097 pages or a
+    window layer's 2,113 (64 rings of 33), each of whose minor pair is
+    one whole bfloat16 tile.  Weights are zeros: nothing runs."""
+    import jax
+    import jax.numpy as jnp
+
+    from chainermn_tpu import serving
+    from chainermn_tpu.models import AfmoeLM
+    from chainermn_tpu.precision import Policy
+
+    model = AfmoeLM(**dict(TRINITY_MINI, **shape))
+    params = jax.tree_util.tree_map(
+        lambda shape: jnp.zeros(shape, jnp.bfloat16),
+        model.param_shapes(), is_leaf=lambda x: isinstance(x, tuple))
+    engine = serving.GenerationEngine(
+        model, params, n_slots=n_slots, max_prompt_len=max_prompt,
+        max_len=max_len, paged=True, page_size=page_size,
+        prefix_sharing=False, policy=Policy.bf16())
+    return _pool_check(
+        engine, 'serve afmoe d%d/L%d %d slots, %d + %d pages of %d'
+        % (model.hidden_size, model.num_hidden_layers, n_slots,
+           engine.n_pages, engine.window_pool.n_pages, page_size),
+        prompt_bucket)
+
+
+def serve_afmoe(hidden=512, heads=8, kv_heads=2, head_dim=128,
+                experts=8, top_k=2, width=256, dense_width=1024,
+                vocab=4096, window=128, page_size=64, n_slots=8,
+                max_prompt=256, max_len=512, max_new=48, n_requests=6,
+                kernels='native'):
+    """A small ``AfmoeLM`` with the family's every mechanism (grouped
+    K/V heads at the published head size, three window layers and a
+    full one behind a dense one, dropless experts beside a shared one)
+    through ``GenerationEngine`` + ``GenerationQueue``: prompts on
+    both sides of the window, every served token held against the
+    float32 kernel-free forward of the same weights."""
+    import jax
+    import jax.numpy as jnp
+
+    from chainermn_tpu import serving
+    from chainermn_tpu.models import AfmoeLM
+    from chainermn_tpu.precision import Policy
+
+    model = AfmoeLM(
+        vocab_size=vocab, hidden_size=hidden, intermediate_size=dense_width,
+        moe_intermediate_size=width, num_attention_heads=heads,
+        num_key_value_heads=kv_heads, head_dim=head_dim,
+        num_experts=experts, num_experts_per_tok=top_k,
+        sliding_window=window, max_position_embeddings=max_len,
+        **TRINITY_MINI)
+    params = model.init(jax.random.PRNGKey(SEED), jnp.bfloat16)
+    rng = np.random.RandomState(SEED)
+    lengths = [3, window - 7, window + 9, max_prompt] + list(
+        rng.randint(4, max_prompt + 1, size=n_requests - 4))
+    prompts = [rng.randint(0, vocab, size=int(n)).astype(np.int32)
+               for n in lengths]
+    what = 'serve afmoe d%d/L5/V%d %d experts top-%d, window %d' % (
+        hidden, vocab, experts, top_k, window)
+    engine = serving.GenerationEngine(
+        model, params, n_slots=n_slots, max_prompt_len=max_prompt,
+        max_len=max_len, paged=True, page_size=page_size,
+        prefix_sharing=False, policy=Policy.bf16())
+    streams = _serve_requests(engine, prompts, max_new, kernels, what)
+    stats = engine.stats()
+    require(stats['peak_window_pages_in_use']
+            <= n_slots * stats['window_ring'],
+            '%s: %d window pages in use, over %d rings of %d'
+            % (what, stats['peak_window_pages_in_use'], n_slots,
+               stats['window_ring']))
+
+    exact = dataclasses.replace(model, dtype=jnp.float32)
+    wide = jax.tree_util.tree_map(lambda x: x.astype(jnp.float32),
+                                  engine.params)
+    gaps = []
+    with kernel_free(), jax.default_matmul_precision('highest'):
+        forward = jax.jit(exact.apply)
+        for prompt, out in zip(prompts, streams):
+            row = np.zeros((1, max_prompt + max_new), np.int32)
+            seq = np.concatenate([prompt, out])
+            row[0, :len(seq)] = seq
+            at = np.arange(len(prompt) - 1, len(seq) - 1)
+            logits = np.asarray(forward(wide, jnp.asarray(row)))[0, at]
+            gaps.append(logits.max(-1)
+                        - logits[np.arange(len(at)), seq[at + 1]])
+    gaps = np.concatenate(gaps)
+    spread = float(np.std(logits))
+    say('%s: %d served tokens lie below the float32 forward\'s best '
+        'logit by at most %.4f, %.5f in the mean (logits spread %.3f); '
+        '%d window pages at the peak in rings of %d'
+        % (what, gaps.size, gaps.max(), gaps.mean(), spread,
+           stats['peak_window_pages_in_use'], stats['window_ring']))
+    require(np.all(np.isfinite(gaps)) and gaps.mean() < 0.1 * spread,
+            '%s: served tokens are %.5f below the float32 forward\'s '
+            'best in the mean, logits spreading %.3f'
+            % (what, gaps.mean(), spread))
+    return {'streams': streams, 'gap_widest': float(gaps.max()),
+            'gap_mean': float(gaps.mean())}
 
 
 # ----------------------------------------------------------------------
@@ -746,7 +894,9 @@ def main(argv=None):
             phases = [('train_resnet', train_resnet),
                       ('train_transformer', train_transformer),
                       ('serve', serve),
-                      ('serving_pool', serving_pool_check)]
+                      ('serving_pool', serving_pool_check),
+                      ('serve_afmoe', serve_afmoe),
+                      ('serving_pool_afmoe', serving_pool_check_afmoe)]
         for phase, fn in phases:
             t0 = time.perf_counter()
             fn()

@@ -31,7 +31,7 @@ from chainermn_tpu import ops
 KERNEL_MODULES = [importlib.import_module('chainermn_tpu.ops.' + name)
                   for name in ('flash_attention', 'layer_norm',
                                'cross_entropy', 'batch_norm_act',
-                               'optimizer')]
+                               'optimizer', 'grouped_matmul')]
 
 BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
 
@@ -84,7 +84,25 @@ def _bn_res(x, scale, bias, res):
 
 
 def _sgd_leaf(g, v):
-    return KERNEL_MODULES[-1]._leaf_update_pallas(g, v, 0.1, 0.9)
+    return KERNEL_MODULES[-2]._leaf_update_pallas(g, v, 0.1, 0.9)
+
+
+def _decode_ring(q, k, v, tables, lengths):
+    return ops.flash_attention_decode_paged(
+        q, k, v, tables, lengths, group=8, window=2048, head_major=True)
+
+
+def _decode_grouped(q, k, v, tables, lengths):
+    return ops.flash_attention_decode_paged(
+        q, k, v, tables, lengths, group=8, head_major=True)
+
+
+def _flash_window(q, k, v):
+    return ops.flash_attention(q, k, v, causal=True, window=2048)
+
+
+def _append(k, v, k_new, v_new, pages, offsets):
+    return ops.paged_kv_append(k, v, k_new, v_new, pages, offsets)[0]
 
 
 # bench widths: bench.py build_transformer (batch 8 x seq 1024, 8 heads
@@ -107,7 +125,36 @@ def _pool(page, dtype):
             ((32, 512 // page), I32), _LEN]
 
 
+# the trinity-mini cell's widths: 32 query heads on 4 K/V heads of 128,
+# 64 rows, pages of 64 (a ring of 33 for the 2,048 window), 128 experts
+# of 2048 x 1024, 8 a token
+_Q64 = ((64, 32, 128), BF16)
+
+
+def _head_major(n_pages):
+    return [((n_pages, 4, 64, 128), BF16)] * 2
+
+
+_EXPERTS = [((128, 2048, 1024), BF16)] * 2 + [((128, 1024, 2048), BF16),
+                                              ((128,), I32)]
+
 CASES = {
+    'decode_paged_ring_group8_page64': (
+        _decode_ring, [_Q64] + _head_major(2113)
+        + [((64, 33), I32), ((64,), I32)]),
+    'decode_paged_full_group8_page64': (
+        _decode_grouped, [_Q64] + _head_major(4097)
+        + [((64, 64), I32), ((64,), I32)]),
+    'flash_fwd_window2048_group8_t3072': (
+        _flash_window, [((1, 3072, 32, 128), BF16)]
+        + [((1, 3072, 4, 128), BF16)] * 2),
+    'paged_kv_append_64rows': (
+        _append, _head_major(2113) + [((64, 4, 128), BF16)] * 2
+        + [((64,), I32)] * 2),
+    'grouped_swiglu_decode_512rows': (
+        ops.grouped_swiglu, [((512, 2048), BF16)] + _EXPERTS),
+    'grouped_swiglu_prefill_8192rows': (
+        ops.grouped_swiglu, [((8192, 2048), BF16)] + _EXPERTS),
     'flash_fwd_causal_t1024': (_flash, _QKV),
     'flash_fwd_bwd_causal_t1024': (
         jax.grad(_sum_sq(_flash), argnums=(0, 1, 2)), _QKV),
@@ -207,3 +254,68 @@ def test_serving_executable_leaves_the_page_pool_in_place(
     assert compiled.memory_analysis().temp_size_in_bytes < leaf_bytes
     if body == 'decode':
         assert 'tpu_custom_call' in compiled.as_text()
+
+
+@pytest.mark.parametrize('body', ['decode', 'prefill'])
+def test_afmoe_serving_executable_leaves_both_pools_in_place(
+        body, one_chip, mosaic):
+    """The ``afmoe`` serving executables at the widths of the
+    ``trinity-mini`` cell (64 rows, pages of 64, the full layer's
+    4,097 pages and a window layer's 2,113; one window and one full
+    expert layer of its five), compiled for the described chip:
+    nothing makes a value of either leaf's shape besides the write
+    (the ``paged_kv_append`` call in decode, the page scatter in
+    prefill), and the cache is held at its nominal bytes.  With an
+    XLA scatter as the decode write this compile holds two ``copy`` of
+    every leaf (PERF.md, PR 27)."""
+    import os
+    import sys
+
+    from chainermn_tpu import models as M
+    from chainermn_tpu.serving.generate import GenerationEngine
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    import chip_smoke
+
+    model = M.AfmoeLM(num_hidden_layers=2, num_dense_layers=0,
+                      layer_types=('sliding_attention',
+                                   'full_attention'))
+    params = jax.tree_util.tree_map(
+        lambda shape: jax.ShapeDtypeStruct(shape, BF16,
+                                           sharding=one_chip),
+        model.param_shapes(), is_leaf=lambda x: isinstance(x, tuple))
+    cache = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                       sharding=one_chip),
+        jax.eval_shape(lambda: model.init_paged_kv_cache(
+            4097, 64, n_window_pages=2113)))
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, I32, sharding=one_chip)
+
+    def decode(p, c, tokens, positions, tables):
+        logits, c, counters = model.decode_step_paged(
+            p, c, tokens, positions, tables)
+        return GenerationEngine._sampled(logits, counters), c
+
+    def prefill(p, c, tokens, length, pos0, table):
+        logits, c, counters = model.prefill_paged(
+            p, c, tokens, length, table, pos0)
+        return GenerationEngine._sampled(logits, counters), c
+
+    fn, operands = {
+        'decode': (decode, (ints(64), ints(64), ints(64, 97))),
+        'prefill': (prefill, (ints(1, 1024), ints(), ints(), ints(97))),
+    }[body]
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *operands).compile()
+    leaves = jax.tree_util.tree_leaves(cache)
+    assert {leaf.shape for leaf in leaves} == {(4097, 4, 64, 128),
+                                               (2113, 4, 64, 128)}
+    assert chip_smoke.pool_shaped(compiled.as_text(), leaves) == []
+    memory = compiled.memory_analysis()
+    nominal = 2 * (4097 + 2113) * 4 * 64 * 128 * 2
+    assert memory.alias_size_in_bytes == nominal
+    assert memory.temp_size_in_bytes < 2113 * 4 * 64 * 128 * 2
+    # attention, the append (decode) and the expert kernel are all in
+    assert compiled.as_text().count('tpu_custom_call') >= 3

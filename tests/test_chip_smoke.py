@@ -97,6 +97,34 @@ def test_serve_phase_tiny(interpret):
     assert max(max(e) for e in out['errors'].values()) < 5e-2
 
 
+TINY_AFMOE = dict(hidden=32, heads=4, kv_heads=2, head_dim=8, experts=8,
+                  top_k=2, width=16, dense_width=48, vocab=64, window=8,
+                  page_size=4)
+
+
+def test_serve_afmoe_phase_tiny(interpret):
+    out = chip_smoke.serve_afmoe(n_slots=3, max_prompt=24, max_len=48,
+                                 max_new=12, n_requests=5,
+                                 kernels='interpret', **TINY_AFMOE)
+    assert len(out['streams']) == 5
+    assert all(len(s) == 12 for s in out['streams'])
+    assert out['gap_mean'] < 0.01
+
+
+def test_serving_pool_afmoe_phase_tiny():
+    """As ``test_serving_pool_phase_tiny``: on the CPU the check must
+    bite (a bfloat16 scatter goes through pool-shaped ``convert``
+    instructions there); both kinds of leaf are named first."""
+    with pytest.raises(chip_smoke.SmokeFailure,
+                       match='makes pool-shaped values'):
+        chip_smoke.serving_pool_check_afmoe(
+            n_slots=2, max_prompt=8, max_len=32, page_size=4,
+            prompt_bucket=8, vocab_size=64, hidden_size=32,
+            intermediate_size=48, moe_intermediate_size=16,
+            num_attention_heads=4, num_key_value_heads=2, head_dim=8,
+            num_experts=8, num_experts_per_tok=2, sliding_window=8)
+
+
 def test_multichip_phase_on_four_virtual_devices(interpret):
     out = chip_smoke.train_multichip(n_devices=4, seq=16,
                                      global_batch=4, tp=2,
