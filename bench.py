@@ -1252,12 +1252,6 @@ def measure(argv):
                     by_axis.items())}
         except Exception as e:
             result['collective_bytes_per_axis_error'] = repr(e)[:300]
-    # flash-attention block overrides (ci/run_fa_tuned.sh adoption
-    # path): the row must record the kernel config it measured
-    if os.environ.get('CHAINERMN_TPU_FA_BLOCK_Q'):
-        result['fa_block_q'] = os.environ['CHAINERMN_TPU_FA_BLOCK_Q']
-    if os.environ.get('CHAINERMN_TPU_FA_BLOCK_K'):
-        result['fa_block_k'] = os.environ['CHAINERMN_TPU_FA_BLOCK_K']
     # headline-tuning adoption provenance (set by adopt_tuned_config
     # in the parent; inherited by this child via the environment)
     if os.environ.get('CHAINERMN_TPU_ADOPTED_FROM'):

@@ -4,11 +4,12 @@
 Times the repo's fused blockwise attention (``chainermn_tpu.ops``)
 against the unfused jnp oracle (``mha_reference``: materializes the
 (T, T) score matrix and lets XLA fuse what it can) on the SAME chip,
-fwd and fwd+bwd, across sequence lengths -- and sweeps kernel block
-sizes at one config to pick the best.  This quantifies the custom
-hot-path the reference delegates to hand-written native code
-(``/root/reference/chainermn/nccl/nccl.pyx:153-199``); here the
-native analogue is the Mosaic-compiled kernel.
+fwd and fwd+bwd, across sequence lengths -- and, with ``--sweep``,
+times each of the three kernels alone over explicit tiles at the
+training cell's shapes, beside the tile the rule derives.  This
+quantifies the custom hot-path the reference delegates to hand-written
+native code (``/root/reference/chainermn/nccl/nccl.pyx:153-199``); here
+the native analogue is the Mosaic-compiled kernel.
 
 Measurement follows ``bench.py``: a per-call Python loop times
 dispatch as much as the kernel, so each
@@ -22,7 +23,7 @@ Usage::
 
     python benchmarks/flash_attention_bench.py            # real TPU
     python benchmarks/flash_attention_bench.py --cpu      # plumbing
-    python benchmarks/flash_attention_bench.py --sweep    # + block sweep
+    python benchmarks/flash_attention_bench.py --sweep    # + tile sweep
 
 Writes JSONL to ``benchmarks/results/flash_attention_<platform>.jsonl``
 (one line per measurement) and prints a summary table.
@@ -51,7 +52,7 @@ def attn_flops(b, t, h, d, causal, bwd):
 
 
 def bench_config(b, t, h, d, causal, dtype, use_pallas, bwd,
-                 block_q=128, block_k=128, quick=False):
+                 block_q=None, block_k=None, quick=False):
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -232,32 +233,74 @@ def _run_all(configs, seqs_note, dtype, cpu, sweep, quick, platform,
                     row['error'] = str(e)[-300:]
                 record(row)
 
-    if sweep and not cpu:
-        b, t, h, d = 4, 2048, 8, 64
-        for bq in (128, 256, 512):
-            for bk in (128, 256, 512):
+
+
+def kernel_chain(kernel, bh, t, d, dtype, block_q, block_k, n):
+    """``n`` calls of ONE of the three flash kernels at explicit tiles,
+    chained through the operand its result replaces, in one program."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    fa = importlib.import_module('chainermn_tpu.ops.flash_attention')
+
+    keys = jax.random.split(jax.random.PRNGKey(0), 5)
+    q, k, v, g = ((jax.random.normal(kk, (bh, t, d), jnp.float32) * 0.5
+                   ).astype(dtype) for kk in keys[:4])
+    lse = jnp.log(jnp.arange(1, t + 1, dtype=jnp.float32)
+                  )[None, None, :].repeat(bh, 0)
+    delta = jax.random.normal(keys[4], (bh, 1, t), jnp.float32) * 0.1
+    scale = d ** -0.5
+
+    def body(_, c):
+        if kernel == 'fwd':
+            return fa._fwd_pallas(c, k, v, True, scale, t, block_q,
+                                  block_k)[0]
+        if kernel == 'dq':
+            return fa._bwd_dq_pallas(c, k, v, g, lse, delta, True,
+                                     scale, t, block_q, block_k)
+        return fa._bwd_dkv_pallas(q, c, v, g, lse, delta, True, scale,
+                                  t, block_q, block_k)[0]
+
+    start = k if kernel == 'dkv' else q
+    run = jax.jit(lambda x: lax.fori_loop(0, n, body, x))
+    return lambda: run(start).block_until_ready()
+
+
+def sweep_tiles(record, cpu):
+    """Each of the three kernels alone over (block_q, block_k) at the
+    training cell's shapes (``gpt2m-train-1k``: bf16[128,1024,64],
+    causal), next to the tile ``_flash_blocks`` derives: the rule is
+    written to reproduce the winners (PERF.md section 6, PR 28)."""
+    import importlib
+    import time
+
+    import jax.numpy as jnp
+    fa = importlib.import_module('chainermn_tpu.ops.flash_attention')
+
+    bh, t, d, n = (2, 256, 64, 2) if cpu else (128, 1024, 64, 24)
+    dtype = jnp.float32 if cpu else jnp.bfloat16
+    sizes = (128, 256) if cpu else (128, 256, 512, 1024)
+    for kernel in ('fwd', 'dq', 'dkv'):
+        derived = fa._flash_blocks(t, t, d, dtype, kernel=kernel)
+        for bq in sizes:
+            for bk in sizes:
+                row = {'sweep': kernel, 'block_q': bq, 'block_k': bk,
+                       'bh': bh, 't': t, 'd': d, 'calls': n,
+                       'derived': (bq, bk) == derived}
                 try:
-                    per, lin, weak = bench_config(
-                        b, t, h, d, True, dtype, True, True,
-                        block_q=bq, block_k=bk, quick=quick)
-                    row = {'sweep': True, 'block_q': bq, 'block_k': bk,
-                           'b': b, 't': t, 'h': h, 'd': d,
-                           'causal': True, 'bwd': True,
-                           'pallas_ms': per * 1e3,
-                           'linearity_rel_err': round(lin, 4),
-                           'platform': platform}
-                    if lin > LINEARITY_GATE:
-                        row['suspect'] = True
-                        row['suspect_reason'] = (
-                            'timing nonlinear (%.0f%%)' % (lin * 100))
-                    if weak:
-                        row['suspect'] = True
-                        row['suspect_reason'] = (
-                            row.get('suspect_reason', '') +
-                            '; signal below noise floor').lstrip('; ')
+                    once = kernel_chain(kernel, bh, t, d, dtype, bq, bk,
+                                        n)
+                    once()                         # compile, warm
+                    best = float('inf')
+                    for _ in range(3):
+                        t0 = time.perf_counter()
+                        once()
+                        best = min(best, time.perf_counter() - t0)
+                    row['ms_per_call'] = best / n * 1e3
                 except Exception as e:  # Mosaic lowering limits
-                    row = {'sweep': True, 'block_q': bq, 'block_k': bk,
-                           'error': str(e)[-300:], 'platform': platform}
+                    row['error'] = str(e)[-300:]
                 record(row)
 
 

@@ -13,10 +13,16 @@ Layout: inputs are (B, T, H, D) like the rest of the framework; the
 kernel grid is (B*H, T/block_q, T/block_k) -- the opposite-operand
 stream is a *grid dimension*, so VMEM holds one (block_q, block_k)
 tile plus the running (m, l, acc) scratch regardless of sequence
-length (a full-stream block spec would put K+V linear-in-T in VMEM
-and blow the ~16MB budget at the 32k lengths TransformerLM allows).
-The softmax recurrence carries across the innermost grid axis in VMEM
-scratch; outputs are written on its final step.
+length.  The tiles are a function of the shapes (``_flash_blocks``):
+a grid step costs ~0.4 us whatever it computes, so a tile is as large
+as the sweep on the chip found worth it (512 to 1,024 positions a side
+at the trainer's 1,024 x 64 heads) and the VMEM the kernels ask for
+holds.  bfloat16 operands go to the MXU as stored and the computed
+ones (``p``, ``ds``) are rounded to bfloat16 for their products; the
+softmax statistics and the accumulators are float32.  The softmax
+recurrence carries across the innermost grid axis in VMEM scratch;
+outputs are written on its final step.  Per-row scalars (``lse``,
+``delta``) cross HBM as (B*H, 1, T) rows, the sequence along the lanes.
 
 The backward pass is the standard flash backward split into two Mosaic
 kernels on TPU (dq over query blocks; dk/dv over key blocks, each
@@ -27,7 +33,6 @@ numerics oracle.
 """
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -50,25 +55,188 @@ def mha_reference(q, k, v, causal=False, scale=None):
 
 
 # ----------------------------------------------------------------------
+# tiles: a function of the shapes
+# ----------------------------------------------------------------------
+
+_LANES = 128
+
+# What one of the three kernels may ask of a core's VMEM (a v5e core
+# has 128 MiB; the compiler's default scoped limit is 16 MiB).  Stated
+# in each pallas_call; _flash_blocks keeps a step's working set under
+# it.  The largest step the rule emits (float32 inputs, d 128, a
+# 1,024 x 1,024 tile) takes 14.6 MB compiled for a v5e.
+_VMEM_LIMIT = 32 * 1024 * 1024
+
+# Largest (block_q, block_k) per kernel, from the sweep on the chip at
+# the training cell's shapes (bf16[128,1024,64], causal; PERF.md
+# section 6, PR 28): the forward and dQ are fastest with a head's whole
+# 1,024 x 1,024 square in one step, dead half included; dK/dV, which
+# streams queries past a resident key tile, at 512 x 512.
+_TILE_CAP = {'fwd': (1024, 1024), 'dq': (1024, 1024), 'dkv': (512, 512)}
+
+# float32 (block_q, block_k) temporaries a step holds at once (the
+# score tile and what is made of it); Mosaic keeps 1.25 (forward) to
+# 1.65 (backward) of them, read off its allocation for a described v5e
+_TILE_TEMPS = 2
+
+
+def _flash_vmem_bytes(kernel, block_q, block_k, d, itemsize):
+    """A grid step's working set: the float32 score-tile temporaries,
+    the double-buffered operand and result blocks, the scratch."""
+    tile = _TILE_TEMPS * block_q * block_k * 4
+    lanes = max(d, _LANES)                  # a row pads to 128 lanes
+    n_q, n_k = {'fwd': (2, 2), 'dq': (3, 2), 'dkv': (2, 4)}[kernel]
+    blocks = 2 * (n_q * block_q + n_k * block_k) * lanes * itemsize
+    acc_rows = block_k if kernel == 'dkv' else block_q
+    scratch = (2 * acc_rows * lanes + 2 * block_q * _LANES) * 4
+    return tile + blocks + scratch
+
+
+def _padded_len(t):
+    """What a sequence is padded to.  Up to 128 positions: itself (one
+    block, the array's full extent).  Above: a multiple of the largest
+    power-of-two multiple of 128 that is at most a quarter of it (512
+    at most), so the padding stays under a quarter of the sequence and
+    a tile of at least that size divides the result."""
+    if t <= _LANES:
+        return t
+    unit = _LANES
+    while unit < 512 and unit * 8 <= t:
+        unit *= 2
+    return -(-t // unit) * unit
+
+
+def _flash_blocks(t_q, t_kv, d, dtype, window=None, kernel='fwd'):
+    """``(block_q, block_k)`` for one of the three kernels from what
+    the call can see.  Each is the largest power-of-two multiple of
+    128 that divides the padded length, stays under the kernel's cap
+    and keeps the step's working set inside ``_VMEM_LIMIT``; a
+    sequence of at most 128 positions is one block.  A key tile wider
+    than the window of a windowed layer would fetch keys no row of the
+    query tile sees, so the window caps it."""
+    cap_q, cap_k = _TILE_CAP[kernel]
+    if window is not None:
+        cap_k = min(cap_k, max(_LANES, -(-window // _LANES) * _LANES))
+    itemsize = jnp.dtype(dtype).itemsize
+
+    def largest(t, cap):
+        t = _padded_len(t)
+        if t <= _LANES:
+            return t
+        b = _LANES
+        while b * 2 <= cap and t % (b * 2) == 0:
+            b *= 2
+        return b
+
+    block_q, block_k = largest(t_q, cap_q), largest(t_kv, cap_k)
+    # halve the longer side until the step fits (the operand blocks
+    # grow with d past 128 and double for float32 inputs)
+    while (_flash_vmem_bytes(kernel, block_q, block_k, d, itemsize)
+           > _VMEM_LIMIT):
+        if block_k >= block_q and block_k > _LANES:
+            block_k //= 2
+        elif block_q > _LANES:
+            block_q //= 2
+        else:
+            break
+    return block_q, block_k
+
+
+def _compiler_params():
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT)
+
+
+# ----------------------------------------------------------------------
+# what the three kernels share
+# ----------------------------------------------------------------------
+
+_NT = ((1,), (1,))      # a @ b.T
+_NN = ((1,), (0,))      # a @ b
+
+
+def _dot(a, b, dims):
+    """One MXU product with a float32 result.  bfloat16 operands go in
+    as stored, one pass (a product of two bfloat16 numbers is exact in
+    float32); Mosaic takes no other precision for them, and the tests'
+    process-wide default is ``highest``.  float32 operands keep the
+    caller's precision."""
+    precision = (lax.Precision.DEFAULT if a.dtype == jnp.bfloat16
+                 else None)
+    return lax.dot_general(a, b, (dims, ((), ())), precision=precision,
+                           preferred_element_type=jnp.float32)
+
+
+def _col_to_row(col):
+    """(rows, 128) lane-replicated per-row scalars -> (1, rows): the
+    layout they cross HBM in, a sequence along the lanes."""
+    return col.T[:1, :]
+
+
+def _row_to_col(row):
+    """(1, rows) -> (rows, 128) lane-replicated."""
+    return jnp.broadcast_to(row, (_LANES, row.shape[1])).T
+
+
+def _tile_mask(shape, q0, k0, q_axis, causal, kv_len, window):
+    """Which entries of a score tile count.  ``q0`` / ``k0`` are the
+    tile's first query and key positions, ``q_axis`` the axis queries
+    run along (0; 1 in the dK/dV kernel's transposed tile).  ``kv_len``
+    is None where no key of the call is padding."""
+    # q_pos - k_pos, one subtraction for both bounds
+    rel = (lax.broadcasted_iota(jnp.int32, shape, q_axis)
+           - lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+           + (q0 - k0))
+    ok = None
+    if causal:
+        ok = rel >= 0
+        if window is not None:
+            ok = jnp.logical_and(ok, rel < window)
+    if kv_len is not None:
+        k_pos = k0 + lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+        pad = k_pos < kv_len
+        ok = pad if ok is None else jnp.logical_and(ok, pad)
+    return ok
+
+
+def _when_live(q0, q1, k0, k1, causal, window, accum):
+    """Run ``accum`` if the tile of queries ``[q0, q1)`` and keys
+    ``[k0, k1)`` has an entry that counts: its first key is not past
+    its last query, and with a window its last key is inside the first
+    query's window.  (Masking only the tiles an edge crosses bought
+    nothing on the chip: the kernels wait for the MXU, not the VPU.)"""
+    import jax.experimental.pallas as pl
+
+    if not causal:
+        accum()
+        return
+    live = k0 < q1
+    if window is not None:
+        live = jnp.logical_and(live, k1 - 1 > q0 - window)
+    pl.when(live)(accum)
+
+
+# ----------------------------------------------------------------------
 # forward
 # ----------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
                 acc_ref, *, scale, causal, kv_len, block_q, block_k,
-                t_kv, window=None):
+                window=None):
     """One (batch*head, query-block, key-block) grid cell.
 
     The key-block axis is the innermost (sequential) grid dimension;
     the running (m, l, acc) state lives in VMEM scratch across its
     steps, so only one K/V tile is resident at a time.  ``m``/``l``
     are kept lane-replicated at (block_q, 128) -- the Mosaic-friendly
-    layout for per-row scalars.  ``kv_len`` (static) masks out padded
-    key positions >= kv_len.  ``window`` (static, causal only) keeps
-    the ``window`` keys ending at the query's own position: a key
-    block wholly before every row's window is skipped like one past
-    the causal frontier.  (A row whose first visited block lies wholly
-    before ITS window accumulates garbage at ``m = NEG_INF``; the
-    first live block's ``alpha = exp(NEG_INF - m_new) = 0`` wipes it.)
+    layout for per-row scalars; ``lse`` leaves as a (1, block_q) row.
+    ``kv_len`` (static, or None) masks out padded key positions
+    >= kv_len.  ``window`` (static, causal only) keeps the ``window``
+    keys ending at the query's own position: a key block wholly before
+    every row's window is skipped like one past the causal frontier.
+    (A row whose first visited block lies wholly before ITS window
+    accumulates garbage at ``m = NEG_INF``; the first live block's
+    ``alpha = exp(NEG_INF - m_new) = 0`` wipes it.)
     """
     import jax.experimental.pallas as pl
 
@@ -82,65 +250,47 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    masked = causal or kv_len < t_kv
+    q0, k0 = qi * block_q, kj * block_k
+    masked = causal or kv_len is not None
 
     def _accum():
-        q = q_ref[0].astype(jnp.float32) * scale      # (block_q, D)
-        k = k_ref[0].astype(jnp.float32)              # (block_k, D)
-        v = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)       # (block_q, block_k)
+        v = v_ref[0]                                  # (block_k, D)
+        # the scale on the score tile, not on q: q goes to the MXU as
+        # it is stored
+        s = _dot(q_ref[0], k_ref[0], _NT) * scale     # (block_q, block_k)
         if masked:
-            q_pos = (qi * block_q
-                     + lax.broadcasted_iota(jnp.int32,
-                                            (block_q, block_k), 0))
-            k_pos = (kj * block_k
-                     + lax.broadcasted_iota(jnp.int32,
-                                            (block_q, block_k), 1))
-            ok = k_pos < kv_len
-            if causal:
-                ok = jnp.logical_and(ok, q_pos >= k_pos)
-            if window is not None:
-                ok = jnp.logical_and(ok, k_pos > q_pos - window)
-            s = jnp.where(ok, s, NEG_INF)
+            s = jnp.where(
+                _tile_mask(s.shape, q0, k0, 0, causal, kv_len, window),
+                s, NEG_INF)
         m_prev = m_ref[...]                           # (block_q, 128)
-        l_prev = l_ref[...]
         m_new = jnp.maximum(m_prev,
                             jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new[:, :1])
         m_ref[...] = m_new
-        l_ref[...] = (l_prev * alpha
+        l_ref[...] = (l_ref[...] * alpha
                       + jnp.sum(p, axis=-1, keepdims=True))
-        acc_ref[...] = acc_ref[...] * alpha[:, :1] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc_ref[...] = (acc_ref[...] * alpha[:, :1]
+                        + _dot(p.astype(v.dtype), v, _NN))
 
-    if causal:
-        # key blocks strictly after this query block contribute nothing
-        live = kj * block_k < (qi + 1) * block_q
-        if window is not None:
-            # ... nor do those that end before its first row's window
-            live = jnp.logical_and(
-                live, (kj + 1) * block_k > qi * block_q - window + 1)
-        pl.when(live)(_accum)
-    else:
-        _accum()
+    _when_live(q0, q0 + block_q, k0, k0 + block_k, causal, window,
+               _accum)
 
     @pl.when(kj == n_kv - 1)
     def _finalize():
         l_safe = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0] = (acc_ref[...] / l_safe[:, :1]).astype(o_ref.dtype)
-        lse_ref[0] = (m_ref[...] + jnp.log(l_safe))[:, :1]
+        lse_ref[0] = _col_to_row(m_ref[...] + jnp.log(l_safe))
 
 
 def _fwd_pallas(q, k, v, causal, scale, kv_len, block_q, block_k,
                 group=1, window=None):
-    """``group`` query heads read one K/V head: row ``b`` of the merged
-    ``(B*H, T, D)`` queries takes its keys from row ``b // group`` of
-    the ``(B*H/group, T, D)`` keys, in the index map, so the repeat is
-    never materialised."""
+    """``(out, lse)``, ``lse`` as ``(B*H, 1, T)``: the sequence along
+    the lanes, so a block of it is ``block_q`` floats beside the
+    query block.  ``group`` query heads read one K/V head: row ``b``
+    of the merged ``(B*H, T, D)`` queries takes its keys from row
+    ``b // group`` of the ``(B*H/group, T, D)`` keys, in the index map,
+    so the repeat is never materialised."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -169,10 +319,11 @@ def _fwd_pallas(q, k, v, causal, scale, kv_len, block_q, block_k,
     else:
         def kv_ix(b, i, j):
             return (kv_row(b), j, 0)
-    out, lse = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                          kv_len=kv_len, block_q=block_q,
-                          block_k=block_k, t_kv=t_kv, window=window),
+                          kv_len=kv_len if kv_len < t_kv else None,
+                          block_q=block_q, block_k=block_k,
+                          window=window),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0),
@@ -185,22 +336,22 @@ def _fwd_pallas(q, k, v, causal, scale, kv_len, block_q, block_k,
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0),
+            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, t_q, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, t_q, 1), jnp.float32),
+            jax.ShapeDtypeStruct((bh, 1, t_q), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, 128), jnp.float32),   # m (replicated)
-            pltpu.VMEM((block_q, 128), jnp.float32),   # l (replicated)
-            pltpu.VMEM((block_q, d), jnp.float32),     # acc
+            pltpu.VMEM((block_q, _LANES), jnp.float32),  # m (replicated)
+            pltpu.VMEM((block_q, _LANES), jnp.float32),  # l (replicated)
+            pltpu.VMEM((block_q, d), jnp.float32),       # acc
         ],
+        compiler_params=_compiler_params(),
         interpret=interpret_flag(),
         name='flash_attention_fwd',
     )(q, k, v)
-    return out, lse[..., 0]
 
 
 def _fwd_blockwise_jnp(q, k, v, causal, scale, kv_len, block_k,
@@ -251,13 +402,18 @@ def _fwd_blockwise_jnp(q, k, v, causal, scale, kv_len, block_k,
 # ----------------------------------------------------------------------
 # backward -- Pallas kernels (dq; dk/dv) on TPU, jnp scan fallback.
 # Standard flash backward: delta = rowsum(g * out) precomputed, then
-#   p  = exp(s - lse);  dp = g @ v^T;  ds = p * (dp - delta) * scale
-#   dq += ds @ k;  dk += ds^T @ q;  dv += p^T @ g
+#   p  = exp(s - lse);  dp = g @ v^T;  ds = p * (dp - delta)
+#   dq = scale * sum ds @ k;  dk = scale * sum ds^T @ q;  dv = sum p^T @ g
+# lse and delta come in as (B*H, 1, T) rows.  The dq kernel turns its
+# query block's rows into columns once, at its first key step; the
+# dK/dV kernel works on the TRANSPOSED tile (keys down the sublanes,
+# queries along the lanes), where a row is what broadcasts and every
+# product is a plain or a b-transposed one.
 # ----------------------------------------------------------------------
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
-                   dq_ref, acc_ref, *, scale, causal, kv_len, block_q,
-                   block_k, t_kv):
+                   dq_ref, acc_ref, lse_col, delta_col, *, scale,
+                   causal, kv_len, block_q, block_k):
     """dq: grid (bh, query-block, key-block); K/V tiles stream over
     the innermost axis, dq accumulates in VMEM scratch."""
     import jax.experimental.pallas as pl
@@ -269,54 +425,37 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
     @pl.when(kj == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
+        lse_col[...] = _row_to_col(lse_ref[0])
+        delta_col[...] = _row_to_col(delta_ref[0])
 
-    masked = causal or kv_len < t_kv
+    q0, k0 = qi * block_q, kj * block_k
+    masked = causal or kv_len is not None
 
     def _accum():
-        q = q_ref[0].astype(jnp.float32)              # (block_q, D)
-        g = g_ref[0].astype(jnp.float32)
-        lse = lse_ref[0][:, 0]                        # (block_q,)
-        delta = delta_ref[0][:, 0]
-        k = k_ref[0].astype(jnp.float32)              # (block_k, D)
-        v = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+        k = k_ref[0]                                  # (block_k, D)
+        s = _dot(q_ref[0], k, _NT) * scale            # (block_q, block_k)
         if masked:
-            q_pos = (qi * block_q
-                     + lax.broadcasted_iota(jnp.int32,
-                                            (block_q, block_k), 0))
-            k_pos = (kj * block_k
-                     + lax.broadcasted_iota(jnp.int32,
-                                            (block_q, block_k), 1))
-            ok = k_pos < kv_len
-            if causal:
-                ok = jnp.logical_and(ok, q_pos >= k_pos)
-            s = jnp.where(ok, s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])
-        dp = jax.lax.dot_general(
-            g, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
-        acc_ref[...] = acc_ref[...] + jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            s = jnp.where(
+                _tile_mask(s.shape, q0, k0, 0, causal, kv_len, None),
+                s, NEG_INF)
+        p = jnp.exp(s - lse_col[:, :1])
+        dp = _dot(g_ref[0], v_ref[0], _NT)
+        ds = p * (dp - delta_col[:, :1])
+        acc_ref[...] = acc_ref[...] + _dot(ds.astype(k.dtype), k, _NN)
 
-    if causal:
-        pl.when(kj * block_k < (qi + 1) * block_q)(_accum)
-    else:
-        _accum()
+    _when_live(q0, q0 + block_q, k0, k0 + block_k, causal, None, _accum)
 
     @pl.when(kj == n_kv - 1)
     def _finalize():
-        dq_ref[0] = acc_ref[...].astype(dq_ref.dtype)
+        dq_ref[0] = (acc_ref[...] * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal,
-                    kv_len, t_kv, block_q, block_k, t_q):
+                    kv_len, block_q, block_k):
     """dk/dv: grid (bh, key-block, query-block); Q/G/lse/delta tiles
-    stream over the innermost axis, dk/dv accumulate in VMEM scratch."""
+    stream over the innermost axis, dk/dv accumulate in VMEM scratch.
+    The tile is (block_k, block_q): s^T, p^T, dp^T, ds^T."""
     import jax.experimental.pallas as pl
 
     ki = pl.program_id(1)
@@ -328,64 +467,34 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    masked = causal or kv_len < t_kv
+    q0, k0 = qj * block_q, ki * block_k
+    masked = causal or kv_len is not None
 
     def _accum():
-        k = k_ref[0].astype(jnp.float32)              # (block_k, D)
-        v = v_ref[0].astype(jnp.float32)
-        q = q_ref[0].astype(jnp.float32)              # (block_q, D)
-        g = g_ref[0].astype(jnp.float32)
-        lse = lse_ref[0][:, 0]                        # (block_q,)
-        delta = delta_ref[0][:, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (bq, bk)
+        q = q_ref[0]                                  # (block_q, D)
+        g = g_ref[0]
+        s = _dot(k_ref[0], q, _NT) * scale            # (block_k, block_q)
         if masked:
-            q_pos = (qj * block_q
-                     + lax.broadcasted_iota(jnp.int32,
-                                            (block_q, block_k), 0))
-            k_pos = (ki * block_k
-                     + lax.broadcasted_iota(jnp.int32,
-                                            (block_q, block_k), 1))
-            ok = k_pos < kv_len
-            if causal:
-                ok = jnp.logical_and(ok, q_pos >= k_pos)
-            s = jnp.where(ok, s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])
-        dv_acc[...] = dv_acc[...] + jax.lax.dot_general(
-            p, g, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            g, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
-        dk_acc[...] = dk_acc[...] + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            s = jnp.where(
+                _tile_mask(s.shape, q0, k0, 1, causal, kv_len, None),
+                s, NEG_INF)
+        p = jnp.exp(s - lse_ref[0])                   # row (1, block_q)
+        dv_acc[...] = dv_acc[...] + _dot(p.astype(g.dtype), g, _NN)
+        dp = _dot(v_ref[0], g, _NT)
+        ds = p * (dp - delta_ref[0])
+        dk_acc[...] = dk_acc[...] + _dot(ds.astype(q.dtype), q, _NN)
 
-    if causal:
-        # query blocks strictly before this key block contribute nothing
-        pl.when((qj + 1) * block_q > ki * block_k)(_accum)
-    else:
-        _accum()
+    _when_live(q0, q0 + block_q, k0, k0 + block_k, causal, None, _accum)
 
     @pl.when(qj == n_q - 1)
     def _finalize():
-        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _bwd_pallas(q, k, v, out, lse, g, causal, scale, kv_len,
-                block_q, block_k):
+def _bwd_specs(d, block_q, block_k):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-
-    bh, t_q, d = q.shape
-    t_kv = k.shape[1]
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1)                           # (bh, t_q)
-    lse3 = lse[..., None]
-    delta3 = delta[..., None]
 
     def q_blk(ix):
         return pl.BlockSpec((1, block_q, d), ix,
@@ -396,10 +505,25 @@ def _bwd_pallas(q, k, v, out, lse, g, causal, scale, kv_len,
                             memory_space=pltpu.VMEM)
 
     def row_blk(ix):
-        return pl.BlockSpec((1, block_q, 1), ix,
+        # a query block's lse / delta: block_q floats along the lanes
+        def row_ix(*grid_ix):
+            b, i, _ = ix(*grid_ix)
+            return (b, 0, i)
+        return pl.BlockSpec((1, 1, block_q), row_ix,
                             memory_space=pltpu.VMEM)
 
-    # dq: (b, i=query block, j=key block)
+    return q_blk, kv_blk, row_blk
+
+
+def _bwd_dq_pallas(q, k, v, g, lse, delta, causal, scale, kv_len,
+                   block_q, block_k):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    bh, t_q, d = q.shape
+    t_kv = k.shape[1]
+    q_blk, kv_blk, row_blk = _bwd_specs(d, block_q, block_k)
+    # (b, i=query block, j=key block)
     by_i = lambda b, i, j: (b, i, 0)   # noqa: E731
     if causal:
         # same causal DMA elision as the forward (see _fwd_pallas)
@@ -408,33 +532,45 @@ def _bwd_pallas(q, k, v, out, lse, g, causal, scale, kv_len,
             return (b, jnp.minimum(j, frontier), 0)
     else:
         by_j = lambda b, i, j: (b, j, 0)   # noqa: E731
-    dq = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          kv_len=kv_len, block_q=block_q,
-                          block_k=block_k, t_kv=t_kv),
+                          kv_len=kv_len if kv_len < t_kv else None,
+                          block_q=block_q, block_k=block_k),
         grid=(bh, t_q // block_q, t_kv // block_k),
         in_specs=[q_blk(by_i), kv_blk(by_j), kv_blk(by_j), q_blk(by_i),
                   row_blk(by_i), row_blk(by_i)],
         out_specs=q_blk(by_i),
         out_shape=jax.ShapeDtypeStruct((bh, t_q, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
+                        pltpu.VMEM((block_q, _LANES), jnp.float32),
+                        pltpu.VMEM((block_q, _LANES), jnp.float32)],
+        compiler_params=_compiler_params(),
         interpret=interpret_flag(),
         name='flash_attention_bwd_dq',
-    )(q, k, v, g, lse3, delta3)
+    )(q, k, v, g, lse, delta)
 
-    # dk/dv: (b, i=key block, j=query block); for causal, query
-    # blocks before the key block are skipped -- clamp the fetch from
-    # below so the leading dead steps re-fetch (elide) the first
-    # contributing block
+
+def _bwd_dkv_pallas(q, k, v, g, lse, delta, causal, scale, kv_len,
+                    block_q, block_k):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    bh, t_q, d = q.shape
+    t_kv = k.shape[1]
+    q_blk, kv_blk, row_blk = _bwd_specs(d, block_q, block_k)
+    # (b, i=key block, j=query block); for causal, query blocks before
+    # the key block are skipped -- clamp the fetch from below so the
+    # leading dead steps re-fetch (elide) the first contributing block
+    by_i = lambda b, i, j: (b, i, 0)   # noqa: E731
     if causal:
         def by_jq(b, i, j):
             return (b, jnp.maximum(j, (i * block_k) // block_q), 0)
     else:
         by_jq = lambda b, i, j: (b, j, 0)  # noqa: E731
-    dk, dv = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          kv_len=kv_len, t_kv=t_kv, block_q=block_q,
-                          block_k=block_k, t_q=t_q),
+                          kv_len=kv_len if kv_len < t_kv else None,
+                          block_q=block_q, block_k=block_k),
         grid=(bh, t_kv // block_k, t_q // block_q),
         in_specs=[q_blk(by_jq), kv_blk(by_i), kv_blk(by_i),
                   q_blk(by_jq), row_blk(by_jq), row_blk(by_jq)],
@@ -443,9 +579,29 @@ def _bwd_pallas(q, k, v, out, lse, g, causal, scale, kv_len,
                    jax.ShapeDtypeStruct((bh, t_kv, d), v.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
+        compiler_params=_compiler_params(),
         interpret=interpret_flag(),
         name='flash_attention_bwd_dkv',
-    )(q, k, v, g, lse3, delta3)
+    )(q, k, v, g, lse, delta)
+
+
+def _bwd_pallas(q, k, v, out, lse, g, causal, scale, kv_len, blocks):
+    """``lse`` as the forward kernel left it, ``(B*H, 1, T)``.
+    ``blocks``: the caller's explicit ``(block_q, block_k)`` for both
+    kernels, or None for each kernel's own from the shapes."""
+    t_q, d = q.shape[1:]
+    t_kv = k.shape[1]
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1)[:, None, :]               # (bh, 1, t_q)
+
+    def tiles(kernel):
+        return blocks or _flash_blocks(t_q, t_kv, d, q.dtype,
+                                       kernel=kernel)
+
+    dq = _bwd_dq_pallas(q, k, v, g, lse, delta, causal, scale, kv_len,
+                        *tiles('dq'))
+    dk, dv = _bwd_dkv_pallas(q, k, v, g, lse, delta, causal, scale,
+                             kv_len, *tiles('dkv'))
     return dq, dk, dv
 
 
@@ -493,29 +649,40 @@ def _bwd_blockwise(q, k, v, out, lse, g, causal, scale, kv_len, block_k):
 # public op
 # ----------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, causal, scale, kv_len, block_q, block_k):
-    out, _ = _flash_fwd(q, k, v, causal, scale, kv_len, block_q, block_k)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, causal, scale, kv_len, blocks):
+    """``blocks``: the caller's explicit ``(block_q, block_k)``, used
+    by all three kernels, or None: each kernel's own tiles from the
+    (padded) shapes."""
+    out, _ = _flash_fwd(q, k, v, causal, scale, kv_len, blocks)
     return out
 
 
-def _flash_fwd(q, k, v, causal, scale, kv_len, block_q, block_k):
+def _scan_block(blocks, t_kv):
+    """The jnp fallback's key block: the caller's, else 128 keys a scan
+    step as ever (the derived tiles are the Mosaic kernels')."""
+    return blocks[1] if blocks else min(_LANES, t_kv)
+
+
+def _flash_fwd(q, k, v, causal, scale, kv_len, blocks):
     if pallas_mode() == 'fallback':
         out, lse = _fwd_blockwise_jnp(q, k, v, causal, scale, kv_len,
-                                      block_k)
+                                      _scan_block(blocks, k.shape[1]))
     else:
+        block_q, block_k = blocks or _flash_blocks(
+            q.shape[1], k.shape[1], q.shape[2], q.dtype)
         out, lse = _fwd_pallas(q, k, v, causal, scale, kv_len,
                                block_q, block_k)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, scale, kv_len, block_q, block_k, res, g):
+def _flash_bwd(causal, scale, kv_len, blocks, res, g):
     q, k, v, out, lse = res
     if pallas_mode() == 'fallback':
         return _bwd_blockwise(q, k, v, out, lse, g, causal, scale,
-                              kv_len, block_k)
+                              kv_len, _scan_block(blocks, k.shape[1]))
     return _bwd_pallas(q, k, v, out, lse, g, causal, scale, kv_len,
-                       block_q, block_k)
+                       blocks)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -664,11 +831,10 @@ def flash_attention_decode(q, k, v, lengths, scale=None,
     in VMEM per tile, so the HBM traffic the decode step is bound by
     is halved vs bf16 (quartered vs f32).
 
-    ``block_k`` defaults to 128 (``CHAINERMN_TPU_FA_BLOCK_K``
-    overrides, same knob as :func:`flash_attention`).
+    ``block_k`` defaults to 128.
     """
     if block_k is None:
-        block_k = _env_block('CHAINERMN_TPU_FA_BLOCK_K')
+        block_k = _LANES
     b, h, d = q.shape
     t_kv = k.shape[1]
     if (k_scale is None) != (v_scale is None):
@@ -1255,9 +1421,9 @@ def flash_attention_chunk(q, k_new, v_new, k_ctx, v_ctx, ctx_len,
     slot engine's prefill (``tests/test_transformer.py``).
     """
     if block_q is None:
-        block_q = _env_block('CHAINERMN_TPU_FA_BLOCK_Q')
+        block_q = _LANES
     if block_k is None:
-        block_k = _env_block('CHAINERMN_TPU_FA_BLOCK_K')
+        block_k = _LANES
     b, c, h, d = q.shape
     s_ctx = k_ctx.shape[1]
     if (k_scale is None) != (v_scale is None):
@@ -1290,6 +1456,7 @@ def flash_attention_chunk(q, k_new, v_new, k_ctx, v_ctx, ctx_len,
     else:
         out_c, lse_c = _fwd_pallas(qm_p, km_new, vm_new, True, scale,
                                    c, block_q, block_k)
+        lse_c = lse_c[:, 0]
     out_c, lse_c = out_c[:, :c], lse_c[:, :c]
 
     # context half: dynamic-length blockwise scan over banked rows
@@ -1318,24 +1485,6 @@ def flash_attention_chunk(q, k_new, v_new, k_ctx, v_ctx, ctx_len,
     return jnp.swapaxes(out.reshape(b, h, c, d), 1, 2)
 
 
-def _env_block(name, default=128):
-    """Validated env-sourced block size: a fleet-wide launcher knob
-    must fail naming itself, not as an opaque int()/ZeroDivision deep
-    inside the model step."""
-    raw = os.environ.get(name)
-    if raw is None:
-        return default
-    try:
-        val = int(raw)
-    except ValueError:
-        raise ValueError('%s must be a positive integer, got %r'
-                         % (name, raw)) from None
-    if val <= 0:
-        raise ValueError('%s must be a positive integer, got %r'
-                         % (name, raw))
-    return val
-
-
 def flash_attention(q, k, v, causal=False, scale=None,
                     block_q=None, block_k=None, window=None):
     """Fused attention. q: (B, Tq, H, D), k/v: (B, Tkv, Hkv, D).
@@ -1352,16 +1501,14 @@ def flash_attention(q, k, v, causal=False, scale=None,
     are FORWARD-ONLY (serving): the backward kernels know neither, so
     such a call has no gradient.
 
-    Block sizes default to 128x128; ``CHAINERMN_TPU_FA_BLOCK_Q`` /
-    ``CHAINERMN_TPU_FA_BLOCK_K`` override the defaults per process
-    (read at trace time) -- how a winner from the benchmark sweep
-    (``benchmarks/flash_attention_bench.py --sweep``) is adopted for
-    every model without code edits.  Explicit arguments win.
+    The tiles are a function of the shapes (:func:`_flash_blocks`, the
+    forward's and each backward kernel's own).  An explicit
+    ``block_q`` / ``block_k`` is used by all three kernels instead
+    (one left out is 128).  bfloat16 inputs go to the MXU as stored,
+    and the probabilities are rounded to bfloat16 for their products;
+    the softmax statistics and every accumulator are float32.  float32
+    inputs keep float32 products.
     """
-    if block_q is None:
-        block_q = _env_block('CHAINERMN_TPU_FA_BLOCK_Q')
-    if block_k is None:
-        block_k = _env_block('CHAINERMN_TPU_FA_BLOCK_K')
     b, t_q, h, d = q.shape
     t_kv, h_kv = k.shape[1:3]
     if causal and t_q != t_kv:
@@ -1376,15 +1523,20 @@ def flash_attention(q, k, v, causal=False, scale=None,
     group = h // h_kv
     if scale is None:
         scale = d ** -0.5
-    block_q = min(block_q, max(t_q, 1))
-    block_k = min(block_k, max(t_kv, 1))
+    if block_q is None and block_k is None:
+        blocks = None
+        pad_q = _padded_len(t_q) - t_q
+        pad_k = _padded_len(t_kv) - t_kv
+    else:
+        blocks = (min(block_q or _LANES, max(t_q, 1)),
+                  min(block_k or _LANES, max(t_kv, 1)))
+        pad_q = (-t_q) % blocks[0]
+        pad_k = (-t_kv) % blocks[1]
 
     def merge(x):
         # (B, T, H, D) -> (B*H, T, D)
         return jnp.swapaxes(x, 1, 2).reshape(-1, x.shape[1], d)
 
-    pad_q = (-t_q) % block_q
-    pad_k = (-t_kv) % block_k
     qm, km, vm = merge(q), merge(k), merge(v)
     if pad_q:
         qm = jnp.pad(qm, ((0, 0), (0, pad_q), (0, 0)))
@@ -1392,11 +1544,14 @@ def flash_attention(q, k, v, causal=False, scale=None,
         km = jnp.pad(km, ((0, 0), (0, pad_k), (0, 0)))
         vm = jnp.pad(vm, ((0, 0), (0, pad_k), (0, 0)))
     if group == 1 and window is None:
-        out = _flash(qm, km, vm, causal, scale, t_kv, block_q, block_k)
+        out = _flash(qm, km, vm, causal, scale, t_kv, blocks)
     elif pallas_mode() == 'fallback':
-        out, _ = _fwd_blockwise_jnp(qm, km, vm, causal, scale, t_kv,
-                                    block_k, group, window)
+        out, _ = _fwd_blockwise_jnp(
+            qm, km, vm, causal, scale, t_kv,
+            _scan_block(blocks, km.shape[1]), group, window)
     else:
+        block_q, block_k = blocks or _flash_blocks(t_q, t_kv, d, q.dtype,
+                                                   window)
         out, _ = _fwd_pallas(qm, km, vm, causal, scale, t_kv, block_q,
                              block_k, group, window)
     out = out[:, :t_q]

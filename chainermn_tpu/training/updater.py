@@ -10,6 +10,9 @@ backward pass with gradient collectives and there is no per-iteration
 Python work beyond feeding the next batch.
 """
 
+import functools
+import weakref
+
 import jax
 import jax.numpy as jnp
 import optax
@@ -18,6 +21,34 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from chainermn_tpu import telemetry as _telemetry
 from chainermn_tpu.training.convert import concat_examples
 from chainermn_tpu.utils import chaos as _chaos
+
+
+class _held_weakly:
+    """A method whose bound form holds its updater weakly.
+
+    The updater owns gigabytes on the device, and whoever drops the
+    last reference to it expects them back at once.  A caller that
+    stores a wrapper of ``upd.update_core`` on ``upd`` itself (a
+    profiler shim does: ``upd.update_core = spanned(upd.update_core)``)
+    would otherwise close a reference cycle through the bound method,
+    and the device state would wait for the interpreter's next FULL
+    collection -- which a process holding millions of long-lived
+    objects (a loaded profiler trace) puts off past the next thing that
+    needs the memory (PERF.md section 6, PR 28)."""
+
+    def __init__(self, fn):
+        self._fn = fn
+        functools.update_wrapper(self, fn)
+
+    def __get__(self, obj, owner=None):
+        if obj is None:
+            return self._fn
+        ref, fn = weakref.ref(obj), self._fn
+
+        @functools.wraps(fn)
+        def bound(*args, **kwargs):
+            return fn(ref(), *args, **kwargs)
+        return bound
 
 
 class StandardUpdater:
@@ -536,8 +567,10 @@ class StandardUpdater:
         # trace time (jit caches per shape signature).  The name is
         # the executable's (``jit_train_step`` on the profiler's
         # ``XLA Modules`` line): what a trace reduction keys on
+        me = weakref.ref(self)   # the step must not keep its updater
+
         def train_step(*args):
-            self.trace_count += 1  # fires per compilation, not per step
+            me().trace_count += 1  # fires per compilation, not per step
             n_batch = len(args) - n_lead
             fn = jax.shard_map(
                 core, mesh=comm.mesh,
@@ -548,6 +581,7 @@ class StandardUpdater:
         jit_kwargs = {'donate_argnums': (0, 1, 2)} if donate else {}
         return jax.jit(train_step, static_argnums=(), **jit_kwargs)
 
+    @_held_weakly
     def shard_batch(self, batch):
         """Collate a list of examples and place it sharded on the mesh
         (under a policy, floating columns are cast to compute dtype on
@@ -601,6 +635,7 @@ class StandardUpdater:
         device computation."""
         return self._step, self._step_args(arrays, iteration)
 
+    @_held_weakly
     def update_core(self, arrays):
         """Advance one iteration on already-sharded device arrays;
         returns device-resident metrics (no host sync -- steps can

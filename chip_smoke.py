@@ -418,7 +418,6 @@ def serve(d_model=512, n_heads=8, n_layers=6, d_ff=2048, vocab=32000,
 
     from chainermn_tpu import serving
     from chainermn_tpu.models import TransformerLM
-    from chainermn_tpu.ops.flash_attention import _env_block
     from chainermn_tpu.precision import Policy
 
     model = TransformerLM(vocab_size=vocab, d_model=d_model,
@@ -483,7 +482,7 @@ def serve(d_model=512, n_heads=8, n_layers=6, d_ff=2048, vocab=32000,
     # page IS the slab's key block (identical arithmetic by
     # construction); elsewhere a different block order may flip a
     # bf16 near-tie, so the count is information
-    slab_block = min(_env_block('CHAINERMN_TPU_FA_BLOCK_K'), max_len)
+    slab_block = min(128, max_len)    # flash_attention_decode's key block
     total = n_requests * max_new
     for page in page_sizes:
         got = streams['paged%d' % page]

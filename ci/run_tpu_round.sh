@@ -386,13 +386,10 @@ run bench_seq2seq $QT python bench.py --model seq2seq --quick
 run bench_transformer $QT python bench.py --model transformer --quick
 run bench_transformer_check $QT python bench.py --model transformer --quick --check
 
-# flash-attention kernel vs XLA attention + block-size sweep
+# flash-attention kernel vs XLA attention + the per-kernel tile sweep
+# (beside the tiles _flash_blocks derives)
 run_with pred_wrote flash_attn 3000 \
     python benchmarks/flash_attention_bench.py --sweep
-
-# transformer re-bench with the sweep's crowned block sizes (adopts
-# the winner automatically; exits un-banked when no sweep row yet)
-run bench_transformer_fatuned $QT bash ci/run_fa_tuned.sh
 
 # measured strategy comparison + profiler traces (VERDICT r3 item 9)
 run_with pred_wrote strategy_trace $QT \

@@ -109,6 +109,8 @@ def _append(k, v, k_new, v_new, pages, offsets):
 # x 64, d512, V32k), measure_generate (32 slots, cache 512, prompts
 # <=128) and ResNet-50 batch 32 at 224 px
 _QKV = [((8, 1024, 8, 64), BF16)] * 3
+# the gpt2m-train-1k cell's own call: 8 sequences x 16 heads
+_QKV_CELL = [((8, 1024, 16, 64), BF16)] * 3
 _Q1 = ((32, 8, 64), BF16)
 _LEN = ((32,), I32)
 
@@ -158,6 +160,17 @@ CASES = {
     'flash_fwd_causal_t1024': (_flash, _QKV),
     'flash_fwd_bwd_causal_t1024': (
         jax.grad(_sum_sq(_flash), argnums=(0, 1, 2)), _QKV),
+    'flash_fwd_bwd_causal_t1024_train_cell': (
+        jax.grad(_sum_sq(_flash), argnums=(0, 1, 2)), _QKV_CELL),
+    'flash_fwd_causal_prefill_bucket16': (
+        _flash, [((1, 16, 16, 64), BF16)] * 3),
+    # the largest step the tile rule emits: float32, d 128, 1,024 x 1,024
+    'flash_fwd_bwd_causal_f32_d128_t2048': (
+        jax.grad(_sum_sq(_flash), argnums=(0, 1, 2)),
+        [((1, 2048, 2, 128), F32)] * 3),
+    'flash_fwd_bwd_causal_t1000_padded': (
+        jax.grad(_sum_sq(_flash), argnums=(0, 1, 2)),
+        [((2, 1000, 4, 64), BF16)] * 3),
     'decode_slab_bf16': (_decode, _slab(BF16)),
     'decode_slab_int8': (
         _decode, _slab(I8) + [((32, 512, 8), F32)] * 2),

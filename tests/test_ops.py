@@ -6,6 +6,8 @@ interpreter mode -- the CPU-side analogue of compiling the Mosaic
 kernels on TPU.
 """
 
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -14,6 +16,9 @@ import pytest
 
 from chainermn_tpu import ops
 from chainermn_tpu.ops import _common
+
+# the module: ``ops`` re-exports a function under the same name
+_fa = importlib.import_module('chainermn_tpu.ops.flash_attention')
 
 
 @pytest.fixture(params=['fallback', 'interpret'])
@@ -83,6 +88,58 @@ class TestFlashAttention:
         k = _rand((1, 32, 1, 8), 1)
         with pytest.raises(ValueError):
             ops.flash_attention(q, k, k, causal=True)
+
+    def test_derived_tiles_bf16_causal(self, mode):
+        """Forward and gradients at the tiles ``_flash_blocks`` derives
+        (no explicit block), bfloat16, causal, long enough that every
+        kernel visits at least two key tiles and two query tiles: a
+        dropped tile or a frontier off by one tile moves an output row
+        by O(0.1), the rounding of bfloat16 by O(0.01).  The backward
+        reads ``lse`` / ``delta`` in their (B*H, 1, T) row layout."""
+        t, d = 2048, 64
+        for kernel in ('fwd', 'dq', 'dkv'):
+            bq, bk = _fa._flash_blocks(t, t, d, jnp.bfloat16,
+                                       kernel=kernel)
+            assert t // bq >= 2 and t // bk >= 2, (kernel, bq, bk)
+        q, k, v = (_rand((1, t, 1, d), i, jnp.bfloat16) * 0.5
+                   for i in (20, 21, 22))
+        w = _rand((1, t, 1, d), 23)
+
+        def f(attn):
+            return lambda q, k, v: jnp.sum(
+                attn(q, k, v, causal=True).astype(jnp.float32) * w)
+
+        f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+        ref = ops.mha_reference(*f32, causal=True)
+        out = ops.flash_attention(q, k, v, causal=True)
+        assert out.dtype == jnp.bfloat16
+        np.testing.assert_allclose(out.astype(jnp.float32), ref,
+                                   atol=2e-2, rtol=2e-2)
+        g = jax.grad(f(ops.flash_attention), argnums=(0, 1, 2))(q, k, v)
+        g_ref = jax.grad(f(ops.mha_reference), argnums=(0, 1, 2))(*f32)
+        for name, a, b in zip('qkv', g, g_ref):
+            a = np.asarray(a.astype(jnp.float32))
+            b = np.asarray(b)
+            # per-row: late query rows average ~2,000 keys and carry
+            # small gradients, early ones few keys and large ones
+            assert np.max(np.abs(a - b)) < 0.03 * np.max(np.abs(b)), name
+            assert (np.linalg.norm(a - b)
+                    < 0.02 * np.linalg.norm(b)), name
+
+    def test_window_and_groups_at_derived_tiles(self, mode):
+        """The serving forward (grouped K/V heads, a window) at derived
+        tiles, over more than one key tile and past the window."""
+        t, d, window = 640, 128, 256
+        q = _rand((1, t, 4, d), 30) * 0.5
+        k = _rand((1, t, 2, d), 31) * 0.5
+        v = _rand((1, t, 2, d), 32) * 0.5
+        out = ops.flash_attention(q, k, v, causal=True, window=window)
+        kr, vr = (jnp.repeat(x, 2, axis=2) for x in (k, v))
+        s = jnp.einsum('bqhd,bkhd->bhqk', q, kr) * d ** -0.5
+        rel = jnp.arange(t)[:, None] - jnp.arange(t)[None, :]
+        s = jnp.where((rel >= 0) & (rel < window), s, -1e30)
+        ref = jnp.einsum('bhqk,bkhd->bqhd', jax.nn.softmax(s, -1), vr)
+        np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
 
 class TestDecodeAttention:
@@ -510,38 +567,44 @@ class TestFusedSGD:
         assert params['w'].dtype == jnp.bfloat16
 
 
-def test_flash_attention_block_env_override(monkeypatch):
-    """CHAINERMN_TPU_FA_BLOCK_Q/_K set the default block sizes (the
-    sweep-adoption path).  Numerics are block-size independent, so the
-    teeth here are CONSUMPTION and PRECEDENCE, proven via the
-    validation error: a poisoned env must fire exactly when (and only
-    when) the env default would be consulted."""
-    rng = jax.random.PRNGKey(0)
-    kq, kk, kv = jax.random.split(rng, 3)
-    q = jax.random.normal(kq, (1, 64, 2, 16), jnp.float32)
-    k = jax.random.normal(kk, (1, 64, 2, 16), jnp.float32)
-    v = jax.random.normal(kv, (1, 64, 2, 16), jnp.float32)
-    explicit = ops.flash_attention(q, k, v, causal=True,
-                                   block_q=32, block_k=32)
+# what the benchmark's cells send: (t_q = t_kv, d, dtype, window)
+_FLASH_SHAPES = {
+    'train_t1024_d64': (1024, 64, jnp.bfloat16, None),
+    'prefill_bucket16_d64': (16, 64, jnp.bfloat16, None),
+    'prefill_bucket64_d64': (64, 64, jnp.bfloat16, None),
+    'prefill_bucket512_d64': (512, 64, jnp.bfloat16, None),
+    'prefill_t2048_d128_window2048': (2048, 128, jnp.bfloat16, 2048),
+    'prefill_t3072_d128_window2048': (3072, 128, jnp.bfloat16, 2048),
+    'prefill_t3072_d128_full': (3072, 128, jnp.bfloat16, None),
+    'no_multiple_of_a_tile_t1000': (1000, 64, jnp.bfloat16, None),
+    'odd_multiple_of_128_t1664': (1664, 64, jnp.bfloat16, None),
+    'under_128_t100': (100, 64, jnp.bfloat16, None),
+    'float32_t4096_d128': (4096, 128, jnp.float32, None),
+    'narrow_window_t1024': (1024, 64, jnp.bfloat16, 200),
+}
 
-    # a malformed value fails loudly, NAMING the variable -- and only
-    # when the default is actually consulted, which also proves the
-    # env is consumed at all
-    monkeypatch.setenv('CHAINERMN_TPU_FA_BLOCK_Q', 'bogus')
-    with pytest.raises(ValueError, match='CHAINERMN_TPU_FA_BLOCK_Q'):
-        ops.flash_attention(q, k, v, causal=True)
-    with pytest.raises(ValueError, match='CHAINERMN_TPU_FA_BLOCK_Q'):
-        ops.flash_attention(q, k, v, causal=True, block_k=32)
-    monkeypatch.setenv('CHAINERMN_TPU_FA_BLOCK_K', '0')
-    # explicit arguments win: the poisoned env is never consulted
-    wins = ops.flash_attention(q, k, v, causal=True,
-                               block_q=32, block_k=32)
-    np.testing.assert_allclose(np.asarray(wins), np.asarray(explicit),
-                               atol=1e-6)
 
-    # a valid env value is adopted and matches its explicit twin
-    monkeypatch.setenv('CHAINERMN_TPU_FA_BLOCK_Q', '32')
-    monkeypatch.setenv('CHAINERMN_TPU_FA_BLOCK_K', '32')
-    via_env = ops.flash_attention(q, k, v, causal=True)
-    np.testing.assert_allclose(np.asarray(via_env),
-                               np.asarray(explicit), atol=1e-6)
+@pytest.mark.parametrize('kernel', ['fwd', 'dq', 'dkv'])
+@pytest.mark.parametrize('case', sorted(_FLASH_SHAPES))
+def test_flash_blocks_rule(case, kernel):
+    """The tiles are a function of the shapes: they divide the padded
+    length, never exceed it, keep a step's working set inside the VMEM
+    the kernels ask for, and a windowed layer's key tile is no wider
+    than its window rounded up to the lanes."""
+    fa = _fa
+    t, d, dtype, window = _FLASH_SHAPES[case]
+    if window is not None and kernel != 'fwd':
+        pytest.skip('the windowed call has no backward')
+    bq, bk = fa._flash_blocks(t, t, d, dtype, window, kernel=kernel)
+    padded = fa._padded_len(t)
+    assert 0 <= padded - t < max(128, t // 4)
+    for block in (bq, bk):
+        assert 0 < block <= padded and padded % block == 0
+        assert block == padded or block % 128 == 0
+    assert fa._flash_vmem_bytes(
+        kernel, bq, bk, d, jnp.dtype(dtype).itemsize) <= fa._VMEM_LIMIT
+    if window is not None:
+        assert bk <= max(128, -(-window // 128) * 128)
+    if t >= 1024:
+        # the point of the rule: a step that carries work
+        assert bq * bk >= 256 * 256
