@@ -417,6 +417,10 @@ def tp_param_specs(params, axis='model'):
 # pipeline_parts idiom): the flax module stays the single source of
 # the parameters, and the parity pins in tests/test_transformer.py
 # hold the two paths together (f32 rtol 1e-5, bf16/int8-KV 5e-2).
+# The forward-only layer is written ONCE (_layer); the six entry
+# points -- decode_step, decode_step_paged, prefill, prefill_paged,
+# spec_verify, spec_verify_paged -- differ in their ``attend`` closure
+# and in how they fetch their position rows, and in nothing else.
 
 #: lanes of a TPU vector tile: the cache's head dim is padded to them
 _LANES = 128
@@ -611,18 +615,21 @@ def _attend_cache(cache, layer, q, slots, lengths):
                           lengths, rows=rows)
 
 
-def _tp_embed_rows(params, tokens, vocab_size, d_model, dtype, axis):
-    """Forward-only twin of ``TransformerLM._tp_embed`` for a flat
-    (N,) token vector: masked local lookup + one psum."""
-    tp = lax.axis_size(axis)
-    v_local = vocab_size // tp
+def _embed(model, params, tokens):
+    """Token rows in the model's dtype, the forward-only twin of
+    ``nn.Embed`` / ``TransformerLM._tp_embed``: under ``tp_axis`` the
+    masked local lookup + one psum.  Each entry point adds its own
+    position rows (a ``take``, a static slice, a ``dynamic_slice``)."""
     emb = params['embed']['embedding']
-    local = tokens - lax.axis_index(axis) * v_local
-    in_shard = (local >= 0) & (local < v_local)
-    rows = jnp.take(emb, jnp.clip(local, 0, v_local - 1), axis=0)
-    x = jnp.where(in_shard[..., None], rows,
-                  jnp.zeros((), rows.dtype)).astype(dtype)
-    return lax.psum(x, axis)
+    if model.tp_axis is not None:
+        v_local = model.vocab_size // lax.axis_size(model.tp_axis)
+        local = tokens - lax.axis_index(model.tp_axis) * v_local
+        in_shard = (local >= 0) & (local < v_local)
+        rows = jnp.take(emb, jnp.clip(local, 0, v_local - 1), axis=0)
+        x = jnp.where(in_shard[..., None], rows,
+                      jnp.zeros((), rows.dtype)).astype(model.dtype)
+        return lax.psum(x, model.tp_axis)
+    return jnp.take(emb, tokens, axis=0).astype(model.dtype)
 
 
 def _head_logits(model, params, x):
@@ -645,59 +652,84 @@ def _head_logits(model, params, x):
         model.tp_axis, params['lm_head']['bias'])
 
 
-def _decode_core(model, params, cache, tokens, positions, write,
-                 attend):
-    """Shared single-token decode body: embed + per-layer
-    (norm -> qkv -> ``write`` one token's K/V -> ``attend`` the cache
-    -> proj residual -> MLP residual) -> final norm -> head.  The
-    ``write(cache, layer, k_new, v_new)`` / ``attend(cache, layer,
-    q)`` closures are the ONLY difference between the slot-addressed
-    (:func:`decode_step`) and paged (:func:`decode_step_paged`)
-    caches -- paging is a storage indirection, never a model change.
-    """
-    from chainermn_tpu import ops
+def _logits(model, params, x):
+    """Final norm + lm head on (..., d_model) activations."""
+    x = ops.layer_norm(x, params['lnf_scale'], params['lnf_bias'])
+    return _head_logits(model, params, x)
+
+
+def _last_logits(model, params, x, length):
+    """A prefill's answer: the logits at row ``length - 1`` of the one
+    prompt in ``x`` (1, T, d).  The head only needs the LAST VALID
+    position's activation -- a (1, d) slice instead of a (T, vocab)
+    logits block."""
+    x_last = lax.dynamic_slice_in_dim(
+        x[0], jnp.asarray(length, jnp.int32) - 1, 1, axis=0)
+    return _logits(model, params, x_last)[0]
+
+
+def _proj(model, bp, attn):
+    """The attention output projection on flattened heads."""
     from chainermn_tpu.parallel import tensor
 
     dtype = model.dtype
-    tp_mode = model.tp_axis is not None
-    if tp_mode:
-        x = _tp_embed_rows(params, tokens, model.vocab_size,
-                           model.d_model, dtype, model.tp_axis)
-    else:
-        x = jnp.take(params['embed']['embedding'], tokens,
-                     axis=0).astype(dtype)
-    x = x + jnp.take(params['pos_embed'], positions,
-                     axis=0).astype(dtype)
+    if model.tp_axis is not None:
+        return tensor.row_parallel_dense(
+            attn, bp['proj']['kernel'].astype(dtype), model.tp_axis,
+            bp['proj']['bias'].astype(dtype))
+    return _dense(attn, bp['proj'], dtype)
+
+
+def _mlp(model, bp, h):
+    """The feed-forward: ``ff_out(gelu(ff_in(h)))``."""
+    from chainermn_tpu.parallel import tensor
+
+    dtype = model.dtype
+    if model.tp_axis is not None:
+        g = nn.gelu(tensor.column_parallel_dense(
+            h, bp['ff_in']['kernel'].astype(dtype),
+            bp['ff_in']['bias'].astype(dtype)))
+        return tensor.row_parallel_dense(
+            g, bp['ff_out']['kernel'].astype(dtype), model.tp_axis,
+            bp['ff_out']['bias'].astype(dtype))
+    return _dense(nn.gelu(_dense(h, bp['ff_in'], dtype)), bp['ff_out'],
+                  dtype)
+
+
+def _layer(model, bp, x, cache, layer, attend):
+    """One forward-only layer on ``x`` (..., d): norm -> qkv ->
+    ``attend`` -> proj residual -> norm -> MLP residual.
+    ``attend(cache, layer, q, k, v) -> (attn, cache)``, with q / k / v
+    (..., H, d_head), is ALL that differs between the six entry points
+    below: where this call's K/V are written and what the queries read
+    -- a cache mode is a storage indirection, never a model change
+    (``AfmoeLM._layer`` has the same contract)."""
+    dtype = model.dtype
+    h = ops.layer_norm(x, bp['ln1_scale'], bp['ln1_bias']).astype(dtype)
+    qkv = _qkv_proj(h, bp, dtype)                  # (..., 3, H, d_head)
+    q, k, v = qkv[..., 0, :, :], qkv[..., 1, :, :], qkv[..., 2, :, :]
+    attn, cache = attend(cache, layer, q, k, v)
+    x = x + _proj(model, bp, attn.reshape(x.shape[:-1] + (-1,)))
+    h = ops.layer_norm(x, bp['ln2_scale'], bp['ln2_bias']).astype(dtype)
+    return x + _mlp(model, bp, h), cache
+
+
+def _layers(model, params, x, cache, attend):
+    """Every layer in turn over the one cache."""
     for i in range(model.n_layers):
-        bp = params['block_%d' % i]
-        h = ops.layer_norm(x, bp['ln1_scale'],
-                           bp['ln1_bias']).astype(dtype)
-        qkv = _qkv_proj(h, bp, dtype)               # (N, 3, H, d_head)
-        q, k_new, v_new = qkv[:, 0], qkv[:, 1], qkv[:, 2]
-        cache = write(cache, i, k_new, v_new)
-        attn = attend(cache, i, q)
-        attn = attn.reshape(attn.shape[0], -1)
-        if tp_mode:
-            out = tensor.row_parallel_dense(
-                attn, bp['proj']['kernel'].astype(dtype),
-                model.tp_axis, bp['proj']['bias'].astype(dtype))
-        else:
-            out = _dense(attn, bp['proj'], dtype)
-        x = x + out
-        h = ops.layer_norm(x, bp['ln2_scale'],
-                           bp['ln2_bias']).astype(dtype)
-        if tp_mode:
-            g = nn.gelu(tensor.column_parallel_dense(
-                h, bp['ff_in']['kernel'].astype(dtype),
-                bp['ff_in']['bias'].astype(dtype)))
-            x = x + tensor.row_parallel_dense(
-                g, bp['ff_out']['kernel'].astype(dtype),
-                model.tp_axis, bp['ff_out']['bias'].astype(dtype))
-        else:
-            x = x + _dense(nn.gelu(_dense(h, bp['ff_in'], dtype)),
-                           bp['ff_out'], dtype)
-    x = ops.layer_norm(x, params['lnf_scale'], params['lnf_bias'])
-    return _head_logits(model, params, x), cache
+        x, cache = _layer(model, params['block_%d' % i], x, cache, i,
+                          attend)
+    return x, cache
+
+
+def _step(model, params, cache, tokens, positions, attend):
+    """Decode and verify: ``tokens`` (...) int32 at absolute
+    ``positions`` (...) -> ``(logits (..., vocab) f32 at every one of
+    them, new_cache)``."""
+    x = _embed(model, params, tokens) + jnp.take(
+        params['pos_embed'], positions, axis=0).astype(model.dtype)
+    x, cache = _layers(model, params, x, cache, attend)
+    return _logits(model, params, x), cache
 
 
 def decode_step(model, params, cache, tokens, positions, slots=None):
@@ -724,15 +756,11 @@ def decode_step(model, params, cache, tokens, positions, slots=None):
     idx_slots = (jnp.arange(n) if slots is None
                  else slots.astype(jnp.int32))
 
-    def write(cache, layer, k_new, v_new):
-        return _scatter_kv(cache, layer, k_new, v_new,
-                           (idx_slots, positions))
+    def attend(cache, layer, q, k, v):
+        cache = _scatter_kv(cache, layer, k, v, (idx_slots, positions))
+        return _attend_cache(cache, layer, q, slots, lengths), cache
 
-    def attend(cache, layer, q):
-        return _attend_cache(cache, layer, q, slots, lengths)
-
-    return _decode_core(model, params, cache, tokens, positions,
-                        write, attend)
+    return _step(model, params, cache, tokens, positions, attend)
 
 
 def decode_step_paged(model, params, cache, tokens, positions,
@@ -751,8 +779,6 @@ def decode_step_paged(model, params, cache, tokens, positions,
     (including under ``tp_axis`` and int8 KV) is pinned in
     tests/test_transformer.py.
     """
-    from chainermn_tpu import ops
-
     ps = cache['k'][0].shape[1]
     positions = positions.astype(jnp.int32)
     lengths = positions + 1
@@ -760,16 +786,12 @@ def decode_step_paged(model, params, cache, tokens, positions,
     pages = page_tables[jnp.arange(n), positions // ps]
     offsets = positions % ps
 
-    def write(cache, layer, k_new, v_new):
-        return _scatter_kv(cache, layer, k_new, v_new,
-                           (pages, offsets))
-
-    def attend(cache, layer, q):
+    def attend(cache, layer, q, k, v):
+        cache = _scatter_kv(cache, layer, k, v, (pages, offsets))
         return _decode_attend(ops.flash_attention_decode_paged, cache,
-                              layer, q, page_tables, lengths)
+                              layer, q, page_tables, lengths), cache
 
-    return _decode_core(model, params, cache, tokens, positions,
-                        write, attend)
+    return _step(model, params, cache, tokens, positions, attend)
 
 
 def prefill(model, params, cache, tokens, length, slot):
@@ -782,64 +804,26 @@ def prefill(model, params, cache, tokens, length, slot):
     every layer's ``[slot, :T]``, and returns ``(logits (vocab,) f32 at
     position length-1, new_cache)`` -- the distribution the first
     generated token is sampled from."""
-    from chainermn_tpu import ops
-    from chainermn_tpu.parallel import tensor
-
-    dtype = model.dtype
-    tp_mode = model.tp_axis is not None
     b, t = tokens.shape
     if b != 1:
         raise ValueError('prefill takes one prompt per call, got '
                          'batch %d (prompt-length bucketing would be '
                          'meaningless across a batch)' % b)
-    if tp_mode:
-        x = _tp_embed_rows(params, tokens, model.vocab_size,
-                           model.d_model, dtype, model.tp_axis)
-    else:
-        x = jnp.take(params['embed']['embedding'], tokens,
-                     axis=0).astype(dtype)
-    x = x + params['pos_embed'][:t].astype(dtype)
+    x = _embed(model, params, tokens) + params['pos_embed'][:t].astype(
+        model.dtype)
     slot = jnp.asarray(slot, jnp.int32)
 
     def bank(leaf, val):
         return lax.dynamic_update_slice(
             leaf, val[None], (slot,) + (0,) * (leaf.ndim - 1))
 
-    for i in range(model.n_layers):
-        bp = params['block_%d' % i]
-        h = ops.layer_norm(x, bp['ln1_scale'],
-                           bp['ln1_bias']).astype(dtype)
-        qkv = _qkv_proj(h, bp, dtype)           # (1, T, 3, H, d_head)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+    def attend(cache, layer, q, k, v):
+        # the fresh K/V are attended as computed, then banked
         attn = ops.flash_attention(q, k, v, causal=True)
-        attn = attn.reshape(1, t, -1)
-        cache = _update_kv(cache, i, k[0], v[0], bank)
-        if tp_mode:
-            out = tensor.row_parallel_dense(
-                attn, bp['proj']['kernel'].astype(dtype),
-                model.tp_axis, bp['proj']['bias'].astype(dtype))
-        else:
-            out = _dense(attn, bp['proj'], dtype)
-        x = x + out
-        h = ops.layer_norm(x, bp['ln2_scale'],
-                           bp['ln2_bias']).astype(dtype)
-        if tp_mode:
-            g = nn.gelu(tensor.column_parallel_dense(
-                h, bp['ff_in']['kernel'].astype(dtype),
-                bp['ff_in']['bias'].astype(dtype)))
-            x = x + tensor.row_parallel_dense(
-                g, bp['ff_out']['kernel'].astype(dtype),
-                model.tp_axis, bp['ff_out']['bias'].astype(dtype))
-        else:
-            x = x + _dense(nn.gelu(_dense(h, bp['ff_in'], dtype)),
-                           bp['ff_out'], dtype)
-    # the head only needs the LAST VALID position's activation --
-    # a (1, d) slice instead of a (T, vocab) logits block
-    x_last = lax.dynamic_slice_in_dim(
-        x[0], jnp.asarray(length, jnp.int32) - 1, 1, axis=0)
-    x_last = ops.layer_norm(x_last, params['lnf_scale'],
-                            params['lnf_bias'])
-    return _head_logits(model, params, x_last)[0], cache
+        return attn, _update_kv(cache, layer, k[0], v[0], bank)
+
+    x, cache = _layers(model, params, x, cache, attend)
+    return _last_logits(model, params, x, length), cache
 
 
 def prefill_paged(model, params, cache, tokens, length, page_table,
@@ -867,11 +851,6 @@ def prefill_paged(model, params, cache, tokens, length, page_table,
     prefix pages stay read-only -- the copy-on-write contract in
     ``docs/serving.md``).
     """
-    from chainermn_tpu import ops
-    from chainermn_tpu.parallel import tensor
-
-    dtype = model.dtype
-    tp_mode = model.tp_axis is not None
     b, c = tokens.shape
     if b != 1:
         raise ValueError('prefill_paged takes one prompt chunk per '
@@ -880,14 +859,8 @@ def prefill_paged(model, params, cache, tokens, length, page_table,
     ps = cache['k'][0].shape[1]
     pos0 = jnp.asarray(pos0, jnp.int32)
     length = jnp.asarray(length, jnp.int32)
-    if tp_mode:
-        x = _tp_embed_rows(params, tokens, model.vocab_size,
-                           model.d_model, dtype, model.tp_axis)
-    else:
-        x = jnp.take(params['embed']['embedding'], tokens,
-                     axis=0).astype(dtype)
-    x = x + lax.dynamic_slice_in_dim(
-        params['pos_embed'], pos0, c, axis=0).astype(dtype)
+    x = _embed(model, params, tokens) + lax.dynamic_slice_in_dim(
+        params['pos_embed'], pos0, c, axis=0).astype(model.dtype)
 
     # chunk-row -> (page, offset): pad rows (t >= length) go to the
     # scratch page so the scatter never touches a live table entry
@@ -903,103 +876,15 @@ def prefill_paged(model, params, cache, tokens, length, page_table,
         g = jnp.take(leaf, page_table.astype(jnp.int32), axis=0)
         return g.reshape((1, n_max * ps) + g.shape[2:])
 
-    for i in range(model.n_layers):
-        bp = params['block_%d' % i]
-        h = ops.layer_norm(x, bp['ln1_scale'],
-                           bp['ln1_bias']).astype(dtype)
-        qkv = _qkv_proj(h, bp, dtype)           # (1, C, 3, H, d_head)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        cache = _scatter_kv(cache, i, k[0], v[0], (pages, offsets))
-        (k_ctx, v_ctx), scales = _layer_kv(cache, i, gather,
+    def attend(cache, layer, q, k, v):
+        cache = _scatter_kv(cache, layer, k[0], v[0], (pages, offsets))
+        (k_ctx, v_ctx), scales = _layer_kv(cache, layer, gather,
                                            q.shape[-1])
-        attn = ops.flash_attention_chunk(q, k, v, k_ctx, v_ctx,
-                                         ctx_len, **scales)
-        attn = attn.reshape(1, c, -1)
-        if tp_mode:
-            out = tensor.row_parallel_dense(
-                attn, bp['proj']['kernel'].astype(dtype),
-                model.tp_axis, bp['proj']['bias'].astype(dtype))
-        else:
-            out = _dense(attn, bp['proj'], dtype)
-        x = x + out
-        h = ops.layer_norm(x, bp['ln2_scale'],
-                           bp['ln2_bias']).astype(dtype)
-        if tp_mode:
-            g = nn.gelu(tensor.column_parallel_dense(
-                h, bp['ff_in']['kernel'].astype(dtype),
-                bp['ff_in']['bias'].astype(dtype)))
-            x = x + tensor.row_parallel_dense(
-                g, bp['ff_out']['kernel'].astype(dtype),
-                model.tp_axis, bp['ff_out']['bias'].astype(dtype))
-        else:
-            x = x + _dense(nn.gelu(_dense(h, bp['ff_in'], dtype)),
-                           bp['ff_out'], dtype)
-    x_last = lax.dynamic_slice_in_dim(x[0], length - 1, 1, axis=0)
-    x_last = ops.layer_norm(x_last, params['lnf_scale'],
-                            params['lnf_bias'])
-    return _head_logits(model, params, x_last)[0], cache
+        return ops.flash_attention_chunk(q, k, v, k_ctx, v_ctx,
+                                         ctx_len, **scales), cache
 
-
-def _verify_core(model, params, cache, tokens, positions, write,
-                 attend):
-    """Shared k-token verify body (the windowed twin of
-    :func:`_decode_core`): ``tokens`` (N, K) int32 -- row i's window is
-    K consecutive tokens starting at absolute position
-    ``positions[i]`` -- embed + per-layer (norm -> qkv -> ``write`` the
-    window's K/V -> ``attend`` window-causal against the banked prefix
-    -> proj residual -> MLP residual) -> final norm -> head at ALL K
-    positions.  ``write(cache, layer, k_new, v_new)`` /
-    ``attend(cache, layer, q, k_new, v_new)`` close over the cache
-    addressing exactly as in :func:`_decode_core`; ``attend``
-    additionally receives the fresh window K/V because the chunk
-    kernel takes them as operands rather than re-reading the cache.
-    Returns ``(logits (N, K, vocab) f32, new_cache)``."""
-    from chainermn_tpu import ops
-    from chainermn_tpu.parallel import tensor
-
-    dtype = model.dtype
-    tp_mode = model.tp_axis is not None
-    n, kk = tokens.shape
-    window = (positions.astype(jnp.int32)[:, None]
-              + jnp.arange(kk, dtype=jnp.int32)[None, :])   # (N, K)
-    if tp_mode:
-        x = _tp_embed_rows(params, tokens, model.vocab_size,
-                           model.d_model, dtype, model.tp_axis)
-    else:
-        x = jnp.take(params['embed']['embedding'], tokens,
-                     axis=0).astype(dtype)
-    x = x + jnp.take(params['pos_embed'], window,
-                     axis=0).astype(dtype)
-    for i in range(model.n_layers):
-        bp = params['block_%d' % i]
-        h = ops.layer_norm(x, bp['ln1_scale'],
-                           bp['ln1_bias']).astype(dtype)
-        qkv = _qkv_proj(h, bp, dtype)           # (N, K, 3, H, d_head)
-        q, k_new, v_new = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        cache = write(cache, i, k_new, v_new)
-        attn = attend(cache, i, q, k_new, v_new)
-        attn = attn.reshape(n, kk, -1)
-        if tp_mode:
-            out = tensor.row_parallel_dense(
-                attn, bp['proj']['kernel'].astype(dtype),
-                model.tp_axis, bp['proj']['bias'].astype(dtype))
-        else:
-            out = _dense(attn, bp['proj'], dtype)
-        x = x + out
-        h = ops.layer_norm(x, bp['ln2_scale'],
-                           bp['ln2_bias']).astype(dtype)
-        if tp_mode:
-            g = nn.gelu(tensor.column_parallel_dense(
-                h, bp['ff_in']['kernel'].astype(dtype),
-                bp['ff_in']['bias'].astype(dtype)))
-            x = x + tensor.row_parallel_dense(
-                g, bp['ff_out']['kernel'].astype(dtype),
-                model.tp_axis, bp['ff_out']['bias'].astype(dtype))
-        else:
-            x = x + _dense(nn.gelu(_dense(h, bp['ff_in'], dtype)),
-                           bp['ff_out'], dtype)
-    x = ops.layer_norm(x, params['lnf_scale'], params['lnf_bias'])
-    return _head_logits(model, params, x), cache
+    x, cache = _layers(model, params, x, cache, attend)
+    return _last_logits(model, params, x, length), cache
 
 
 def _roundtrip_kv(cache, k_new, v_new):
@@ -1015,6 +900,18 @@ def _roundtrip_kv(cache, k_new, v_new):
                 dequantize_kv(*quantize_kv(v_new)))
     dt = cache['k'][0].dtype
     return k_new.astype(dt), v_new.astype(dt)
+
+
+def _verify_attend(cache, layer, q, k_new, v_new, rows, positions):
+    """One verify read: the window's queries (N, K, H, d_head) against
+    its own roundtripped K/V (window-causal) and each row's banked
+    context -- ``layer``'s leaves through ``rows`` -- masked at
+    ``positions``."""
+    k_att, v_att = _roundtrip_kv(cache, k_new, v_new)
+    (k_ctx, v_ctx), scales = _layer_kv(cache, layer, rows,
+                                       q.shape[-1])
+    return ops.flash_attention_chunk(
+        q, k_att, v_att, k_ctx, v_ctx, positions, **scales)
 
 
 def spec_verify(model, params, cache, tokens, positions, slots=None):
@@ -1049,30 +946,22 @@ def spec_verify(model, params, cache, tokens, positions, slots=None):
             'full-bucket verify needs one row per cache slot '
             '(%d rows vs %d slots); pass slots= for a compacted '
             'bucket' % (n, cache['k'][0].shape[0]))
-    from chainermn_tpu import ops
-
     positions = positions.astype(jnp.int32)
     window = positions[:, None] + jnp.arange(kk, dtype=jnp.int32)
     idx_slots = (jnp.arange(n) if slots is None
                  else slots.astype(jnp.int32))
 
-    def write(cache, layer, k_new, v_new):
-        return _scatter_kv(cache, layer, k_new, v_new,
-                           (idx_slots[:, None], window))
-
     def rows(leaf):
         return leaf if slots is None else jnp.take(leaf, idx_slots,
                                                    axis=0)
 
-    def attend(cache, layer, q, k_new, v_new):
-        k_att, v_att = _roundtrip_kv(cache, k_new, v_new)
-        (k_ctx, v_ctx), scales = _layer_kv(cache, layer, rows,
-                                           q.shape[-1])
-        return ops.flash_attention_chunk(
-            q, k_att, v_att, k_ctx, v_ctx, positions, **scales)
+    def attend(cache, layer, q, k, v):
+        cache = _scatter_kv(cache, layer, k, v,
+                            (idx_slots[:, None], window))
+        return _verify_attend(cache, layer, q, k, v, rows,
+                              positions), cache
 
-    return _verify_core(model, params, cache, tokens, positions,
-                        write, attend)
+    return _step(model, params, cache, tokens, window, attend)
 
 
 def spec_verify_paged(model, params, cache, tokens, positions,
@@ -1086,8 +975,6 @@ def spec_verify_paged(model, params, cache, tokens, positions,
     the page table (:func:`prefill_paged`'s read pattern) and masked
     at ``positions``; arithmetic is otherwise identical to the slab
     verify -- paging stays a storage indirection."""
-    from chainermn_tpu import ops
-
     n, kk = tokens.shape
     n_max = page_tables.shape[1]
     ps = cache['k'][0].shape[1]
@@ -1100,23 +987,16 @@ def spec_verify_paged(model, params, cache, tokens, positions,
                             axis=1), 0)                      # (N, K)
     offsets = window % ps
 
-    def write(cache, layer, k_new, v_new):
-        return _scatter_kv(cache, layer, k_new, v_new,
-                           (pages, offsets))
-
     def gather(leaf):
         g = jnp.take(leaf, page_tables.astype(jnp.int32), axis=0)
         return g.reshape((n, n_max * ps) + g.shape[3:])
 
-    def attend(cache, layer, q, k_new, v_new):
-        k_att, v_att = _roundtrip_kv(cache, k_new, v_new)
-        (k_ctx, v_ctx), scales = _layer_kv(cache, layer, gather,
-                                           q.shape[-1])
-        return ops.flash_attention_chunk(
-            q, k_att, v_att, k_ctx, v_ctx, positions, **scales)
+    def attend(cache, layer, q, k, v):
+        cache = _scatter_kv(cache, layer, k, v, (pages, offsets))
+        return _verify_attend(cache, layer, q, k, v, gather,
+                              positions), cache
 
-    return _verify_core(model, params, cache, tokens, positions,
-                        write, attend)
+    return _step(model, params, cache, tokens, window, attend)
 
 
 def pipeline_parts(model, params, n_stages, pad_id=-1, tp_axis=None,

@@ -72,6 +72,17 @@ from chainermn_tpu.utils.failure import OverloadError
 DEFAULT_MAX_QUEUE = 256
 
 
+def _as_given(*operands):
+    return operands
+
+
+#: what a warm-up call feeds an operand that cannot be all zeros
+_WARM_VALUES = {
+    'slots': lambda shape, dtype: jnp.arange(shape[0], dtype=dtype),
+    'length': jnp.ones,
+}
+
+
 def _named(fn, name):
     """``fn`` under the name a jitted executable is to carry."""
     def call(*args):
@@ -712,13 +723,9 @@ class GenerationEngine:
         new = self._place_params(params)
         if validate and self.n_slots in self._decode:
             exe = self._decode[self.n_slots][0]
-            val_args = [jnp.zeros((self.n_slots,), jnp.int32),
-                        jnp.zeros((self.n_slots,), jnp.int32)]
-            if self.paged:
-                val_args.append(jnp.zeros(
-                    (self.n_slots, self._table_width), jnp.int32))
             try:
-                tok, cache = exe(new, self._cache, *val_args)
+                tok, cache = exe(new, self._cache, *self._warm_operands(
+                    'decode', self.n_slots))
                 tok = jax.block_until_ready(tok)
             except Exception as e:
                 raise WeightSwapError(
@@ -796,40 +803,30 @@ class GenerationEngine:
         return out[:out.size - len(names)], {
             name: float(v) for name, v in zip(names, values)}
 
-    def _prefill_body(self, params, cache, tokens, length, slot):
-        self.prefill_trace_count += 1  # trace-time counter
-        logits, cache, counters = self.model.prefill(
-            self._prepare_params(params), cache, tokens, length, slot)
-        return self._sampled(logits, counters), cache
+    def _body(self, method, arrange, counter, draft=False):
+        """The traced body of one executable: it bumps the engine's
+        trace counter ``counter``, calls ``method`` of the target (on
+        :meth:`_prepare_params`' tree) or of the draft with the
+        operands in the order ``arrange`` puts them, and hands back
+        ``(_sampled(...), cache)`` -- the family's ``serve_counters``
+        riding the target's tokens, never the draft's."""
+        step = getattr(self.draft_model if draft else self.model, method)
 
-    def _decode_body(self, params, cache, tokens, positions,
-                     slots=None):
-        self.decode_trace_count += 1   # trace-time counter
-        logits, cache, counters = self.model.decode_step(
-            self._prepare_params(params), cache, tokens, positions,
-            slots=slots)
-        return self._sampled(logits, counters), cache
+        def body(params, cache, *operands):
+            # trace-time counter
+            setattr(self, counter, getattr(self, counter) + 1)
+            if not draft:
+                params = self._prepare_params(params)
+            out = step(params, cache, *arrange(*operands))
+            counters = out[2] if len(out) > 2 and not draft else ()
+            return self._sampled(out[0], counters), out[1]
 
-    def _prefill_body_paged(self, params, cache, tokens, length, pos0,
-                            table):
-        self.prefill_trace_count += 1  # trace-time counter
-        logits, cache, counters = self.model.prefill_paged(
-            self._prepare_params(params), cache, tokens, length, table,
-            pos0)
-        return self._sampled(logits, counters), cache
-
-    def _decode_body_paged(self, params, cache, tokens, positions,
-                           tables):
-        self.decode_trace_count += 1   # trace-time counter
-        logits, cache, counters = self.model.decode_step_paged(
-            self._prepare_params(params), cache, tokens, positions,
-            tables)
-        return self._sampled(logits, counters), cache
+        return body
 
     def _copy_body(self, params, cache, src, dst):
         """Copy-on-write page duplication: every leaf's page ``src``
         row copied to page ``dst`` in one donated pass.  ``params``
-        rides along unused to keep the shared ``_compile`` calling
+        rides along unused to keep the shared ``_build`` calling
         convention (one signature family, cache donated at arg 1).
         Shape-generic: the speculative engine compiles a second
         instance of this body over the DRAFT cache, so one CoW
@@ -839,188 +836,174 @@ class GenerationEngine:
         return jax.tree_util.tree_map(
             lambda leaf: leaf.at[dst].set(leaf[src]), cache)
 
-    # -- speculative traced bodies (the draft twin + verify) -----------
-    def _draft_prefill_body(self, params, cache, tokens, length, slot):
-        self.draft_trace_count += 1    # trace-time counter
-        logits, cache = self.draft_model.prefill(
-            params, cache, tokens, length, slot)[:2]
-        return jnp.argmax(logits).astype(jnp.int32), cache
-
-    def _draft_prefill_body_paged(self, params, cache, tokens, length,
-                                  pos0, table):
-        self.draft_trace_count += 1    # trace-time counter
-        logits, cache = self.draft_model.prefill_paged(
-            params, cache, tokens, length, table, pos0)[:2]
-        return jnp.argmax(logits).astype(jnp.int32), cache
-
-    def _draft_decode_body(self, params, cache, tokens, positions,
-                           slots=None):
-        self.draft_trace_count += 1    # trace-time counter
-        logits, cache = self.draft_model.decode_step(
-            params, cache, tokens, positions, slots=slots)[:2]
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
-
-    def _draft_decode_body_paged(self, params, cache, tokens,
-                                 positions, tables):
-        self.draft_trace_count += 1    # trace-time counter
-        logits, cache = self.draft_model.decode_step_paged(
-            params, cache, tokens, positions, tables)[:2]
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
-
-    def _verify_body(self, params, cache, tokens, positions,
-                     slots=None):
-        self.verify_trace_count += 1   # trace-time counter
-        logits, cache = self.model.spec_verify(
-            self._prepare_params(params), cache, tokens, positions,
-            slots=slots)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
-
-    def _verify_body_paged(self, params, cache, tokens, positions,
-                           tables):
-        self.verify_trace_count += 1   # trace-time counter
-        logits, cache = self.model.spec_verify_paged(
-            self._prepare_params(params), cache, tokens, positions,
-            tables)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
-
-    def _draft_mapped(self, body, n_extra):
-        """The draft twin of :meth:`_mapped`: everything replicated
-        (draft params, draft cache, small int operands)."""
+    def _mapped(self, body, n_operands, draft=False, sampled=True):
+        """Wrap a traced body in the plan's shard_map: the target's
+        params sharded per spec and its cache per its spec, the
+        draft's both replicated, the small int operands replicated.
+        A body hands back ``(sampled, cache)``; the page copy
+        (``sampled=False``) the cache alone."""
         if self.plan is None:
             return body
         from jax.sharding import PartitionSpec as P
+        pspecs = cspecs = P()
+        if not draft:
+            cspecs = self._cache_specs
+            if self.param_specs is not None:
+                pspecs = self.param_specs
         return jax.shard_map(
             body, mesh=self.plan.mesh,
-            in_specs=(P(), P()) + (P(),) * n_extra,
-            out_specs=(P(), P()), check_vma=False)
-
-    def _mapped(self, body, n_extra):
-        """Wrap a traced body in the plan's shard_map (params sharded
-        per spec, cache per its spec, small int operands replicated)."""
-        if self.plan is None:
-            return body
-        from jax.sharding import PartitionSpec as P
-        pspecs = (self.param_specs if self.param_specs is not None
-                  else P())
-        return jax.shard_map(
-            body, mesh=self.plan.mesh,
-            in_specs=(pspecs, self._cache_specs) + (P(),) * n_extra,
-            out_specs=(P(), self._cache_specs), check_vma=False)
+            in_specs=(pspecs, cspecs) + (P(),) * n_operands,
+            out_specs=(P(), cspecs) if sampled else cspecs,
+            check_vma=False)
 
     # -- compilation ---------------------------------------------------
-    def _compile(self, fn, args, table, key, name, params=None):
-        """``name`` is the executable's, whatever wraps the body
-        (``shard_map`` or not): ``jit_<name>`` on the profiler's ``XLA
-        Modules`` line.  The decode executables, and no other, hold
-        ``decode`` in theirs; a trace reduction keys on that."""
-        exe = jax.jit(_named(fn, name), donate_argnums=(1,))
-        aot = self.aot_requested
-        if aot:
-            exe = exe.lower(
-                self.params if params is None else params,
-                *args).compile()
-        table[key] = (exe, aot)
-        self._signatures.add(abstract_signature(args))
-        self.compile_count += 1
-        return exe, aot
+    #: family -> (the engine's table of it, its executables' name, the
+    #: phase whose operands they take, target or draft).  The decode
+    #: executables, and no other, hold ``decode`` in their name; a
+    #: trace reduction keys on that.
+    _FAMILIES = {
+        'prefill': ('_prefill', 'serve_prefill', 'prefill', False),
+        'decode': ('_decode', 'serve_decode', 'decode', False),
+        'copy_page': ('_copy', 'serve_page_copy', 'copy', False),
+        'draft_prefill': ('_draft_prefill', 'serve_draft_prefill',
+                          'prefill', True),
+        'draft_decode': ('_draft_decode', 'serve_draft_decode',
+                         'decode', True),
+        'verify': ('_verify', 'serve_verify', 'verify', False),
+        'draft_copy_page': ('_draft_copy', 'serve_draft_page_copy',
+                            'copy', True),
+    }
 
-    def _token_structs(self, bucket):
-        i32 = jnp.int32
+    def _operands(self, phase, bucket):
+        """THE statement of what an executable takes after ``(params,
+        cache)``: ``(method, operands, arrange)`` -- the model's
+        method of that name, a ``(name, shape)`` per int32 operand in
+        the order the scheduler passes them, and ``arrange`` putting
+        them in the method's own order.  Verify is decode with the
+        token vector widened to the ``(bucket, spec_tokens)`` window;
+        the page copy is the engine's own body."""
+        if phase == 'copy':
+            return None, (('src', ()), ('dst', ())), _as_given
+        if phase == 'prefill':
+            tokens, length = ('tokens', (1, bucket)), ('length', ())
+            if self.paged:
+                return ('prefill_paged',
+                        (tokens, length, ('pos0', ()),
+                         ('table', (self._table_width,))),
+                        lambda t, n, pos0, table: (t, n, table, pos0))
+            return 'prefill', (tokens, length, ('slot', ())), _as_given
+        method, tokens = {
+            'decode': ('decode_step', ('tokens', (bucket,))),
+            'verify': ('spec_verify',
+                       ('tokens', (bucket, self.spec_tokens)))}[phase]
+        positions = ('positions', (bucket,))
         if self.paged:
-            # (tokens, length, pos0, page_table)
-            return (jax.ShapeDtypeStruct((1, bucket), i32),
-                    jax.ShapeDtypeStruct((), i32),
-                    jax.ShapeDtypeStruct((), i32),
-                    jax.ShapeDtypeStruct((self._table_width,), i32))
-        return (jax.ShapeDtypeStruct((1, bucket), i32),
-                jax.ShapeDtypeStruct((), i32),
-                jax.ShapeDtypeStruct((), i32))
-
-    def _decode_structs(self, bucket):
-        i32 = jnp.int32
-        if self.paged:
-            # (tokens, positions, page_tables) -- every bucket reads
-            # through tables, so there is no full-vs-compacted split
-            return (jax.ShapeDtypeStruct((bucket,), i32),
-                    jax.ShapeDtypeStruct((bucket,), i32),
-                    jax.ShapeDtypeStruct((bucket, self._table_width),
-                                         i32))
+            # every bucket reads THROUGH the tables, so there is no
+            # full-vs-compacted split
+            return (method + '_paged',
+                    (tokens, positions,
+                     ('tables', (bucket, self._table_width))),
+                    _as_given)
         if bucket == self.n_slots:
-            return (jax.ShapeDtypeStruct((bucket,), i32),
-                    jax.ShapeDtypeStruct((bucket,), i32))
-        return (jax.ShapeDtypeStruct((bucket,), i32),
-                jax.ShapeDtypeStruct((bucket,), i32),
-                jax.ShapeDtypeStruct((bucket,), i32))
+            # full bucket: every slot decodes, the cache is read IN
+            # PLACE (no slots operand); rows are slots in order
+            return method, (tokens, positions), _as_given
+        # compacted bucket
+        return (method, (tokens, ('slots', (bucket,)), positions),
+                lambda t, slots, pos: (t, pos, slots))
 
-    def _verify_structs(self, bucket):
-        """Verify operand structs for one slot bucket: the decode
-        structs with the token vector widened to the (bucket,
-        spec_tokens) window."""
-        i32 = jnp.int32
-        kk = self.spec_tokens
-        if self.paged:
-            return (jax.ShapeDtypeStruct((bucket, kk), i32),
-                    jax.ShapeDtypeStruct((bucket,), i32),
-                    jax.ShapeDtypeStruct((bucket, self._table_width),
-                                         i32))
-        if bucket == self.n_slots:
-            return (jax.ShapeDtypeStruct((bucket, kk), i32),
-                    jax.ShapeDtypeStruct((bucket,), i32))
-        return (jax.ShapeDtypeStruct((bucket, kk), i32),
-                jax.ShapeDtypeStruct((bucket,), i32),
-                jax.ShapeDtypeStruct((bucket,), i32))
+    def _warm_operands(self, phase, bucket=None):
+        """Operands of :meth:`_operands`' shapes for a call whose
+        result nobody reads (warm-up, swap validation, a linter's
+        trace): zeros -- a zero table is the scratch page, and free
+        slots mask what lands in them -- but for a compacted bucket's
+        ``slots``, each row its own, and a prefill's ``length``, 1."""
+        return tuple(
+            _WARM_VALUES.get(name, jnp.zeros)(shape, jnp.int32)
+            for name, shape in self._operands(phase, bucket)[1])
 
-    def _get_prefill(self, bucket):
-        hit = self._prefill.get(bucket)
-        if hit is not None:
-            return hit[0]
+    def _traceable(self, phase, bucket=None, draft=False):
+        """``(fn, structs)``: the mapped callable of one executable --
+        what gets AOT-compiled, and what ``traceable_decode`` /
+        ``traceable_verify`` hand shardlint -- and the structs of its
+        operands."""
+        method, operands, arrange = self._operands(phase, bucket)
+        if method is None:
+            body = self._copy_body
+        else:
+            body = self._body(
+                method, arrange,
+                'draft_trace_count' if draft
+                else phase + '_trace_count', draft)
+        return (self._mapped(body, len(operands), draft,
+                             sampled=method is not None),
+                tuple(jax.ShapeDtypeStruct(shape, jnp.int32)
+                      for _, shape in operands))
+
+    def _build(self, family, bucket=None):
+        """The one way an executable comes to be, under the lock: two
+        threads asking for one bucket compile it once.  Hands back
+        its ``(exe, aot)``.  Its name is the family's, whatever wraps
+        the body (``shard_map`` or not): ``jit_<name>`` on the
+        profiler's ``XLA Modules`` line."""
+        attr, name, phase, draft = self._FAMILIES[family]
         with self._lock:
-            hit = self._prefill.get(bucket)
+            table = getattr(self, attr)
+            hit = table if bucket is None else table.get(bucket)
             if hit is not None:
-                return hit[0]
-            if bucket not in self._prefill_widths:
-                raise RuntimeError(
-                    'prompt bucket %d is not an edge %r'
-                    % (bucket, list(self._prefill_widths)))
-            body = (self._mapped(self._prefill_body_paged, 4)
-                    if self.paged
-                    else self._mapped(self._prefill_body, 3))
-            exe, _ = self._compile(
-                body,
-                (self._cache_struct,) + self._token_structs(bucket),
-                self._prefill, bucket, 'serve_prefill')
-            return exe
+                return hit
+            edges = (self._prefill_widths if phase == 'prefill'
+                     else self.decode_edges)
+            if bucket is not None and bucket not in edges:
+                raise RuntimeError('%s bucket %d is not an edge %r'
+                                   % (family, bucket, list(edges)))
+            fn, structs = self._traceable(phase, bucket, draft)
+            args = (self._draft_cache_struct if draft
+                    else self._cache_struct,) + structs
+            exe = jax.jit(_named(fn, name), donate_argnums=(1,))
+            aot = self.aot_requested
+            if aot:
+                exe = exe.lower(
+                    self._draft_params if draft else self.params,
+                    *args).compile()
+            if bucket is None:       # a page copy: the one entry
+                setattr(self, attr, (exe, aot))
+            else:
+                table[bucket] = (exe, aot)
+            self._signatures.add(abstract_signature(args))
+            self.compile_count += 1
+            return exe, aot
+
+    # the tick's way to an executable: a lock-free hit, else _build
+    def _get_prefill(self, bucket):
+        return (self._prefill.get(bucket)
+                or self._build('prefill', bucket))[0]
+
+    def _get_decode(self, bucket):
+        return (self._decode.get(bucket)
+                or self._build('decode', bucket))[0]
 
     def _get_copy(self):
         """The CoW page-copy executable (paged only): compiled once,
         shape-keyed like every bucket executable, so admission-time
         copies never retrace."""
-        if self._copy is not None:
-            return self._copy[0]
-        with self._lock:
-            if self._copy is not None:
-                return self._copy[0]
-            body = self._copy_mapped()
-            table = {}
-            exe, aot = self._compile(
-                body,
-                (self._cache_struct,
-                 jax.ShapeDtypeStruct((), jnp.int32),
-                 jax.ShapeDtypeStruct((), jnp.int32)),
-                table, 'copy', 'serve_page_copy')
-            self._copy = table['copy']
-            return exe
+        return (self._copy or self._build('copy_page'))[0]
 
-    def _copy_mapped(self):
-        if self.plan is None:
-            return self._copy_body
-        from jax.sharding import PartitionSpec as P
-        pspecs = (self.param_specs if self.param_specs is not None
-                  else P())
-        return jax.shard_map(
-            self._copy_body, mesh=self.plan.mesh,
-            in_specs=(pspecs, self._cache_specs, P(), P()),
-            out_specs=self._cache_specs, check_vma=False)
+    def _get_draft_prefill(self, bucket):
+        return (self._draft_prefill.get(bucket)
+                or self._build('draft_prefill', bucket))[0]
+
+    def _get_draft_decode(self, bucket):
+        return (self._draft_decode.get(bucket)
+                or self._build('draft_decode', bucket))[0]
+
+    def _get_verify(self, bucket):
+        return (self._verify.get(bucket)
+                or self._build('verify', bucket))[0]
+
+    def _get_draft_copy(self):
+        return (self._draft_copy
+                or self._build('draft_copy_page'))[0]
 
     def _copy_page(self, src, dst):
         """Duplicate pool page ``src`` into the private page ``dst``
@@ -1045,166 +1028,13 @@ class GenerationEngine:
                         help='copy-on-write page duplications at '
                              'prefix divergence').inc()
 
-    def _decode_mapped(self, bucket):
-        """The decode callable for one slot-count bucket -- what gets
-        AOT-compiled, and what ``traceable_decode`` hands shardlint."""
-        if self.paged:
-            # paged operand order: (tokens, positions, page_tables);
-            # the cache is read THROUGH the tables for every bucket
-            return self._mapped(self._decode_body_paged, 3)
-        if bucket == self.n_slots:
-            # full bucket: every slot decodes, the cache is read IN
-            # PLACE (no slots operand); rows are slots in order
-            return self._mapped(
-                lambda p, c, t, pos: self._decode_body(p, c, t, pos),
-                2)
-        # compacted bucket operand order: (tokens, slots, positions)
-        # -- what _decode_structs declares and the scheduler passes
-        return self._mapped(
-            lambda p, c, t, s, pos: self._decode_body(
-                p, c, t, pos, slots=s), 3)
-
-    def _get_decode(self, bucket):
-        hit = self._decode.get(bucket)
-        if hit is not None:
-            return hit[0]
-        with self._lock:
-            hit = self._decode.get(bucket)
-            if hit is not None:
-                return hit[0]
-            if bucket not in self.decode_edges:
-                raise RuntimeError(
-                    'decode bucket %d is not an edge %r'
-                    % (bucket, list(self.decode_edges)))
-            exe, _ = self._compile(
-                self._decode_mapped(bucket),
-                (self._cache_struct,) + self._decode_structs(bucket),
-                self._decode, bucket, 'serve_decode')
-            return exe
-
     def traceable_decode(self, bucket=None):
         """``(fn, args)`` for ``jax.make_jaxpr`` -- the EXACT mapped
         decode callable the engine compiles for ``bucket`` (default:
         the full-slot bucket, whose cache read is in place), on zero
         operands over the real cache/params: the shardlint
         ``step:decode_forward`` target traces production code."""
-        bucket = bucket or self.n_slots
-        fn = self._decode_mapped(bucket)
-        args = [self.params, self._cache,
-                jnp.zeros((bucket,), jnp.int32)]
-        if self.paged:
-            args.append(jnp.zeros((bucket,), jnp.int32))
-            args.append(jnp.zeros((bucket, self._table_width),
-                                  jnp.int32))
-            return fn, tuple(args)
-        if bucket != self.n_slots:
-            args.append(jnp.arange(bucket, dtype=jnp.int32))
-        args.append(jnp.zeros((bucket,), jnp.int32))
-        return fn, tuple(args)
-
-    # -- speculative executables ---------------------------------------
-    def _get_draft_prefill(self, bucket):
-        hit = self._draft_prefill.get(bucket)
-        if hit is not None:
-            return hit[0]
-        with self._lock:
-            hit = self._draft_prefill.get(bucket)
-            if hit is not None:
-                return hit[0]
-            body = (self._draft_mapped(self._draft_prefill_body_paged,
-                                       4)
-                    if self.paged
-                    else self._draft_mapped(self._draft_prefill_body,
-                                            3))
-            exe, _ = self._compile(
-                body, (self._draft_cache_struct,)
-                + self._token_structs(bucket),
-                self._draft_prefill, bucket, 'serve_draft_prefill',
-                params=self._draft_params)
-            return exe
-
-    def _draft_decode_mapped(self, bucket):
-        if self.paged:
-            return self._draft_mapped(self._draft_decode_body_paged,
-                                      3)
-        if bucket == self.n_slots:
-            return self._draft_mapped(
-                lambda p, c, t, pos: self._draft_decode_body(
-                    p, c, t, pos), 2)
-        return self._draft_mapped(
-            lambda p, c, t, s, pos: self._draft_decode_body(
-                p, c, t, pos, slots=s), 3)
-
-    def _get_draft_decode(self, bucket):
-        hit = self._draft_decode.get(bucket)
-        if hit is not None:
-            return hit[0]
-        with self._lock:
-            hit = self._draft_decode.get(bucket)
-            if hit is not None:
-                return hit[0]
-            exe, _ = self._compile(
-                self._draft_decode_mapped(bucket),
-                (self._draft_cache_struct,)
-                + self._decode_structs(bucket),
-                self._draft_decode, bucket, 'serve_draft_decode',
-                params=self._draft_params)
-            return exe
-
-    def _verify_mapped(self, bucket):
-        """The k-token verify callable for one slot bucket -- the
-        decode callable's windowed twin, same operand orders."""
-        if self.paged:
-            return self._mapped(self._verify_body_paged, 3)
-        if bucket == self.n_slots:
-            return self._mapped(
-                lambda p, c, t, pos: self._verify_body(p, c, t, pos),
-                2)
-        return self._mapped(
-            lambda p, c, t, s, pos: self._verify_body(
-                p, c, t, pos, slots=s), 3)
-
-    def _get_verify(self, bucket):
-        hit = self._verify.get(bucket)
-        if hit is not None:
-            return hit[0]
-        with self._lock:
-            hit = self._verify.get(bucket)
-            if hit is not None:
-                return hit[0]
-            if bucket not in self.decode_edges:
-                raise RuntimeError(
-                    'verify bucket %d is not an edge %r'
-                    % (bucket, list(self.decode_edges)))
-            exe, _ = self._compile(
-                self._verify_mapped(bucket),
-                (self._cache_struct,) + self._verify_structs(bucket),
-                self._verify, bucket, 'serve_verify')
-            return exe
-
-    def _get_draft_copy(self):
-        if self._draft_copy is not None:
-            return self._draft_copy[0]
-        with self._lock:
-            if self._draft_copy is not None:
-                return self._draft_copy[0]
-            body = self._copy_body
-            if self.plan is not None:
-                from jax.sharding import PartitionSpec as P
-                body = jax.shard_map(
-                    self._copy_body, mesh=self.plan.mesh,
-                    in_specs=(P(), P(), P(), P()), out_specs=P(),
-                    check_vma=False)
-            table = {}
-            exe, aot = self._compile(
-                body,
-                (self._draft_cache_struct,
-                 jax.ShapeDtypeStruct((), jnp.int32),
-                 jax.ShapeDtypeStruct((), jnp.int32)),
-                table, 'copy', 'serve_draft_page_copy',
-                params=self._draft_params)
-            self._draft_copy = table['copy']
-            return exe
+        return self._traceable_args('decode', bucket or self.n_slots)
 
     def traceable_verify(self, bucket=None):
         """``(fn, args)`` for ``jax.make_jaxpr`` -- the EXACT mapped
@@ -1212,19 +1042,32 @@ class GenerationEngine:
         ``bucket``, on zero operands over the real cache/params: the
         shardlint ``step:spec_verify_forward`` target traces
         production code (the :meth:`traceable_decode` contract)."""
-        bucket = bucket or self.n_slots
-        fn = self._verify_mapped(bucket)
-        args = [self.params, self._cache,
-                jnp.zeros((bucket, self.spec_tokens), jnp.int32)]
-        if self.paged:
-            args.append(jnp.zeros((bucket,), jnp.int32))
-            args.append(jnp.zeros((bucket, self._table_width),
-                                  jnp.int32))
-            return fn, tuple(args)
-        if bucket != self.n_slots:
-            args.append(jnp.arange(bucket, dtype=jnp.int32))
-        args.append(jnp.zeros((bucket,), jnp.int32))
-        return fn, tuple(args)
+        return self._traceable_args('verify', bucket or self.n_slots)
+
+    def _traceable_args(self, phase, bucket):
+        return (self._traceable(phase, bucket)[0],
+                (self.params, self._cache)
+                + self._warm_operands(phase, bucket))
+
+    def _warm(self, family, bucket=None):
+        """One executable of :meth:`warmup`, inside its span: built,
+        and a plain-jit one run once on :meth:`_warm_operands` over
+        its own (all-free) cache to force the compile."""
+        _, _, phase, draft = self._FAMILIES[family]
+        where = {} if bucket is None else {'bucket': bucket}
+        with _telemetry.span('serve_warmup', kind='serve',
+                             phase=family, **where):
+            exe, aot = self._build(family, bucket)
+            if aot:
+                return
+            cache = '_draft_cache' if draft else '_cache'
+            out = exe(self._draft_params if draft else self.params,
+                      getattr(self, cache),
+                      *self._warm_operands(phase, bucket))
+            if phase != 'copy':
+                tok, out = out
+                jax.block_until_ready(tok)
+            setattr(self, cache, out)
 
     def warmup(self):
         """Compile (or cache-load) every prefill and decode bucket
@@ -1232,134 +1075,30 @@ class GenerationEngine:
         executables are forced to compile by running them on the real
         cache -- slots are all free, so the garbage they write is
         never attended (reads mask by live length).  Returns
-        ``{'prefill': {bucket: aot}, 'decode': {bucket: aot}}``."""
-        for bucket in sorted(self._prefill_widths, reverse=True):
-            with _telemetry.span('serve_warmup', kind='serve',
-                                 phase='prefill', bucket=bucket):
-                exe = self._get_prefill(bucket)
-                if not self._prefill[bucket][1]:
-                    args = [jnp.zeros((1, bucket), jnp.int32),
-                            jnp.asarray(1, jnp.int32),
-                            jnp.asarray(0, jnp.int32)]
-                    if self.paged:
-                        # zero table: warmup garbage lands on the
-                        # scratch page, never in a live table
-                        args.append(jnp.zeros((self._table_width,),
-                                              jnp.int32))
-                    tok, cache = exe(self.params, self._cache, *args)
-                    jax.block_until_ready(tok)
-                    self._cache = cache
-        for bucket in sorted(self.decode_edges, reverse=True):
-            with _telemetry.span('serve_warmup', kind='serve',
-                                 phase='decode', bucket=bucket):
-                exe = self._get_decode(bucket)
-                if not self._decode[bucket][1]:
-                    if self.paged:
-                        args = [jnp.zeros((bucket,), jnp.int32),
-                                jnp.zeros((bucket,), jnp.int32),
-                                jnp.zeros((bucket,
-                                           self._table_width),
-                                          jnp.int32)]
-                    else:
-                        args = [jnp.zeros((bucket,), jnp.int32),
-                                jnp.zeros((bucket,), jnp.int32)]
-                        if bucket != self.n_slots:
-                            args.insert(1, jnp.arange(
-                                bucket, dtype=jnp.int32))
-                    tok, cache = exe(self.params, self._cache,
-                                     args[0], *args[1:])
-                    jax.block_until_ready(tok)
-                    self._cache = cache
+        ``{'prefill': {bucket: aot}, 'decode': {bucket: aot}}``, and
+        of a speculative engine the draft-prefill / draft-decode /
+        verify families beside them."""
+        widths = sorted(self._prefill_widths, reverse=True)
+        edges = sorted(self.decode_edges, reverse=True)
+        for bucket in widths:
+            self._warm('prefill', bucket)
+        for bucket in edges:
+            self._warm('decode', bucket)
         if self.paged:
-            with _telemetry.span('serve_warmup', kind='serve',
-                                 phase='copy_page'):
-                exe = self._get_copy()
-                if not self._copy[1]:
-                    zero = jnp.asarray(0, jnp.int32)
-                    self._cache = exe(self.params, self._cache,
-                                      zero, zero)
+            self._warm('copy_page')
+        families = ['prefill', 'decode']
         if self.speculative:
-            self._warmup_speculative()
-        out = {'prefill': {b: a for b, (_, a)
-                           in sorted(self._prefill.items())},
-               'decode': {b: a for b, (_, a)
-                          in sorted(self._decode.items())}}
-        if self.speculative:
-            out['draft_prefill'] = {
-                b: a for b, (_, a)
-                in sorted(self._draft_prefill.items())}
-            out['draft_decode'] = {
-                b: a for b, (_, a)
-                in sorted(self._draft_decode.items())}
-            out['verify'] = {b: a for b, (_, a)
-                             in sorted(self._verify.items())}
-        return out
-
-    def _warmup_speculative(self):
-        """Warm the draft-prefill / draft-decode / verify bucket
-        families (largest first, same fallback force-run contract as
-        the base families: free slots + zero tables make warmup
-        garbage structurally unattendable)."""
-        for bucket in sorted(self._prefill_widths, reverse=True):
-            with _telemetry.span('serve_warmup', kind='serve',
-                                 phase='draft_prefill',
-                                 bucket=bucket):
-                exe = self._get_draft_prefill(bucket)
-                if not self._draft_prefill[bucket][1]:
-                    args = [jnp.zeros((1, bucket), jnp.int32),
-                            jnp.asarray(1, jnp.int32),
-                            jnp.asarray(0, jnp.int32)]
-                    if self.paged:
-                        args.append(jnp.zeros((self._table_width,),
-                                              jnp.int32))
-                    tok, dcache = exe(self._draft_params,
-                                      self._draft_cache, *args)
-                    jax.block_until_ready(tok)
-                    self._draft_cache = dcache
-        for bucket in sorted(self.decode_edges, reverse=True):
-            with _telemetry.span('serve_warmup', kind='serve',
-                                 phase='draft_decode', bucket=bucket):
-                exe = self._get_draft_decode(bucket)
-                if not self._draft_decode[bucket][1]:
-                    args = self._zero_decode_args(bucket)
-                    tok, dcache = exe(self._draft_params,
-                                      self._draft_cache, *args)
-                    jax.block_until_ready(tok)
-                    self._draft_cache = dcache
-            with _telemetry.span('serve_warmup', kind='serve',
-                                 phase='verify', bucket=bucket):
-                exe = self._get_verify(bucket)
-                if not self._verify[bucket][1]:
-                    args = self._zero_decode_args(
-                        bucket, window=self.spec_tokens)
-                    tok, cache = exe(self.params, self._cache, *args)
-                    jax.block_until_ready(tok)
-                    self._cache = cache
-        if self.paged:
-            with _telemetry.span('serve_warmup', kind='serve',
-                                 phase='draft_copy_page'):
-                exe = self._get_draft_copy()
-                if not self._draft_copy[1]:
-                    zero = jnp.asarray(0, jnp.int32)
-                    self._draft_cache = exe(self._draft_params,
-                                            self._draft_cache,
-                                            zero, zero)
-
-    def _zero_decode_args(self, bucket, window=None):
-        """Zero operands matching :meth:`_decode_structs` (or the
-        verify structs when ``window`` is set) -- the warmup
-        force-run inputs."""
-        shape = (bucket,) if window is None else (bucket, window)
-        args = [jnp.zeros(shape, jnp.int32)]
-        if self.paged:
-            args.append(jnp.zeros((bucket,), jnp.int32))
-            args.append(jnp.zeros((bucket, self._table_width),
-                                  jnp.int32))
-        else:
-            if bucket != self.n_slots:
-                args.append(jnp.arange(bucket, dtype=jnp.int32))
-            args.append(jnp.zeros((bucket,), jnp.int32))
-        return args
+            for bucket in widths:
+                self._warm('draft_prefill', bucket)
+            for bucket in edges:
+                self._warm('draft_decode', bucket)
+                self._warm('verify', bucket)
+            if self.paged:
+                self._warm('draft_copy_page')
+            families += ['draft_prefill', 'draft_decode', 'verify']
+        return {family: {b: aot for b, (_, aot) in sorted(
+            getattr(self, self._FAMILIES[family][0]).items())}
+            for family in families}
 
     def guard_signature(self, args):
         """The SL007 machinery as a runtime pin (the engine.py

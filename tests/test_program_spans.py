@@ -51,12 +51,15 @@ def _updater(device_prefetch):
         has_aux=True, device_prefetch=device_prefetch)
 
 
-def _engine():
+def _lm():
     model = TransformerLM(vocab_size=32, d_model=32, n_heads=4,
                           n_layers=1, d_ff=32, max_len=64)
-    params = model.init(jax.random.PRNGKey(0),
-                        jnp.zeros((1, 4), jnp.int32))['params']
-    eng = serving.GenerationEngine(model, params, n_slots=2,
+    return model, model.init(jax.random.PRNGKey(0),
+                             jnp.zeros((1, 4), jnp.int32))['params']
+
+
+def _engine():
+    eng = serving.GenerationEngine(*_lm(), n_slots=2,
                                    max_prompt_len=8, paged=True,
                                    page_size=8)
     eng.warmup()
@@ -328,35 +331,100 @@ def test_two_threads_install_one_recorder(tmp_path):
     assert len({r['id'] for r in records}) == len(records)
 
 
-def test_executables_carry_stable_names():
-    """(c) The lowered trainer step is ``jit_train_step``; of the
-    engine's executables exactly the decode ones hold ``decode``."""
+def test_trainer_step_carries_its_name():
+    """(c) The lowered trainer step is ``jit_train_step``."""
     upd = _updater(0)
     arrays = upd.shard_batch([next(upd.iterator)[0]] * 16)
     text = upd._step.lower(*upd._step_args(arrays)).as_text()
     assert 'module @jit_train_step ' in text
 
-    names = {}
+
+#: the engine's executables by family: the names other code keys on
+_EXECUTABLE_NAMES = {
+    'prefill': 'jit_serve_prefill',
+    'decode': 'jit_serve_decode',
+    'copy': 'jit_serve_page_copy',
+    'draft_prefill': 'jit_serve_draft_prefill',
+    'draft_decode': 'jit_serve_draft_decode',
+    'verify': 'jit_serve_verify',
+    'draft_copy': 'jit_serve_draft_page_copy',
+}
+_ENGINE_MODES = {
+    'slab': {},
+    'paged': {'paged': True},
+    'paged_chunked': {'paged': True, 'prefill_chunk': 4},
+    'slab_draft': {'draft': True},
+    'paged_draft': {'paged': True, 'draft': True},
+}
+
+
+@pytest.mark.parametrize('mode', sorted(_ENGINE_MODES))
+def test_executables_carry_stable_names(mode):
+    """(c) In every mode of the engine ``warmup()`` builds one
+    executable per bucket of each family the mode has and no other,
+    each traced once and named for its family -- exactly the decode
+    ones hold ``decode`` -- and a second ``warmup()`` traces and
+    compiles nothing."""
+    options = dict(_ENGINE_MODES[mode])
+    draft = options.pop('draft', False)
+    paged = options.get('paged', False)
+    model, params = _lm()
+    if draft:       # any model of the same vocabulary can propose
+        options.update(draft_model=model, draft_params=params)
+    eng = serving.GenerationEngine(model, params, n_slots=2,
+                                   max_prompt_len=8, page_size=8,
+                                   **options)
+    warm = eng.warmup()
 
     def lower_name(exe):
         # an AOT executable's HLO module carries the jitted name
         return exe.as_text().split('HloModule ', 1)[1].split(
             ',', 1)[0].strip()
 
-    eng, _ = _engine()
-    for kind, table in (('prefill', eng._prefill),
-                        ('decode', eng._decode)):
+    n_prefill = 1 if 'prefill_chunk' in options else len(
+        eng.prefill_edges)
+    n_decode = len(eng.decode_edges)
+    tables = {'prefill': (eng._prefill, n_prefill),
+              'decode': (eng._decode, n_decode),
+              'copy': ({0: eng._copy} if eng._copy else {},
+                       int(paged)),
+              'draft_prefill': (eng._draft_prefill, n_prefill * draft),
+              'draft_decode': (eng._draft_decode, n_decode * draft),
+              'verify': (eng._verify, n_decode * draft),
+              'draft_copy': ({0: eng._draft_copy} if eng._draft_copy
+                             else {}, int(paged and draft))}
+    names = set()
+    for family, (table, n_buckets) in tables.items():
+        assert len(table) == n_buckets, (family, sorted(table))
         for bucket, (exe, aot) in table.items():
             assert aot
-            names[kind, bucket] = lower_name(exe)
-    eng._get_copy()
-    names['copy', 0] = lower_name(eng._copy[0])
-    assert {k for k, _ in names} == {'prefill', 'decode', 'copy'}
-    for (kind, _), name in names.items():
-        assert ('decode' in name) == (kind == 'decode'), name
-        assert ('prefill' in name) == (kind == 'prefill'), name
-    assert set(names.values()) == {
-        'jit_serve_prefill', 'jit_serve_decode', 'jit_serve_page_copy'}
+            name = lower_name(exe)
+            assert name == _EXECUTABLE_NAMES[family], (family, bucket)
+            assert ('decode' in name) == ('decode' in family), name
+            names.add(name)
+    assert names == {_EXECUTABLE_NAMES[family]
+                     for family, (_, n) in tables.items() if n}
+    assert set(warm) == {family for family, (_, n) in tables.items()
+                         if n and 'copy' not in family}
+
+    def counts():
+        return {'compile_count': eng.compile_count,
+                'prefill': eng.prefill_trace_count,
+                'decode': eng.decode_trace_count,
+                'copy': eng.copy_trace_count,
+                'draft': eng.draft_trace_count,
+                'verify': eng.verify_trace_count}
+
+    assert counts() == {
+        'compile_count': sum(n for _, n in tables.values()),
+        'prefill': n_prefill, 'decode': n_decode,
+        'copy': tables['copy'][1] + tables['draft_copy'][1],
+        'draft': tables['draft_prefill'][1] + tables['draft_decode'][1],
+        'verify': tables['verify'][1]}
+    before, compiles = counts(), len(telemetry.compile_log)
+    assert eng.warmup() == warm
+    assert counts() == before
+    assert len(telemetry.compile_log) == compiles
 
 
 def test_compile_log_is_always_on_and_bounded():

@@ -517,65 +517,58 @@ def test_benchmark_op_records_metric_when_enabled():
 
 
 # ---------------------------------------------------------------------
-# the acceptance pin: telemetry disabled-by-default adds no
-# measurable per-step overhead
+# the acceptance pin: telemetry disabled-by-default costs a step
+# nothing -- as a count of what the step reaches, not a clock
 
-def test_disabled_overhead_under_2pct_on_mlp_step():
-    """ISSUE 6 acceptance: telemetry disabled-by-default adds no
-    per-step overhead measurable by ``benchmark_op`` on the mlp step,
-    pinned at < 2%.  Measured as the STRONGER claim: the identical
-    ``update_core`` path with a live in-memory recorder (spans
-    actually recorded, no fences) stays within 2% of the disabled
-    path -- the disabled path does strictly less work (one attribute
-    load + identity check per guard), so the pin bounds it too.  A
-    large-ish mlp keeps the step in the tens-of-milliseconds range so
-    scheduler noise cannot fake a 2% delta.
+def test_step_reaches_no_recorder_disabled_and_records_its_spans_enabled(
+        monkeypatch):
+    """ISSUE 6 acceptance ("disabled by default adds no per-step
+    overhead"), decided by what a step REACHES and no longer by a
+    wall-clock ratio, which six xdist workers on one CPU cannot hold
+    to 2%.  Disabled: a whole ``update()`` -- input wait, collation,
+    placement, the jitted step, the metrics read-back -- calls no
+    method of ``Recorder`` and builds no span handle (every one of
+    them is patched to raise), and a span asked for is the one shared
+    ``NULL_SPAN``.  Enabled: the same step records exactly the spans
+    ``docs/observability.md`` lists for a step, each under its
+    documented parent, and nothing else."""
+    import types
 
-    Flake control (the <2% CONTRACT is unchanged): each arm is the
-    MEDIAN of interleaved rounds -- min-of-rounds compares two
-    extreme order statistics, whose ratio is far noisier than the
-    medians' on a loaded CI box -- plus ONE load-aware retry: a
-    failing first trial reruns once with more rounds, and only the
-    retry's verdict counts.  Ambient load that spans one trial (a
-    neighboring test's compile burst) gets a second look; a real
-    regression fails both."""
+    from chainermn_tpu.training.iterators import SerialIterator
+
     assert not telemetry.enabled()
-    upd, batch = _mlp_updater(n_units=256, batch=1024, donate=False)
-    arrays = upd.shard_batch(batch)
-    jax.block_until_ready(upd.update_core(arrays))  # compile
+    upd, batch = _mlp_updater()
+    upd.iterator = SerialIterator(batch, len(batch))
+    upd.update()                         # compile; the first broadcast
 
-    def step():
-        return upd.update_core(arrays)
+    def refuse(*args, **kwargs):
+        raise AssertionError('telemetry is off: nothing may reach '
+                             'the recorder')
 
-    def trial(rounds):
-        # INTERLEAVED arms: off/on alternate within each round, so
-        # ambient machine load lands on both arms equally (a
-        # sequential A-then-B layout flakes whenever a background
-        # process spans only one arm)
-        t_off, t_on = [], []
-        try:
-            for _ in range(rounds):
-                telemetry.disable()
-                t_off.append(profiling.benchmark_op(
-                    step, n_steps=8, warmup=1))
-                telemetry.enable()  # in-memory recorder, fences off
-                t_on.append(profiling.benchmark_op(
-                    step, n_steps=8, warmup=1))
-        finally:
-            telemetry.disable()
-        off = float(np.median(t_off))
-        on = float(np.median(t_on))
-        return on / off - 1.0, off, on
+    with monkeypatch.context() as patch:
+        for cls in (rec_mod.Recorder, rec_mod._SpanHandle):
+            for name, attr in vars(cls).items():
+                if isinstance(attr, types.FunctionType):
+                    patch.setattr(cls, name, refuse)
+        upd.update()
+        assert telemetry.span('x', kind='compute') is rec_mod.NULL_SPAN
+        assert telemetry.active() is None
 
-    overhead, off, on = trial(rounds=4)
-    if overhead >= 0.02:
-        # load-aware retry: one rerun with more rounds decides
-        overhead, off, on = trial(rounds=8)
-    assert overhead < 0.02, (
-        'telemetry-enabled update_core overhead %.2f%% (off %.3f ms, '
-        'on %.3f ms, median-of-rounds, after retry): the disabled-'
-        'by-default path is bounded by this and must stay '
-        'unmeasurable' % (overhead * 100, off * 1e3, on * 1e3))
+    rec = telemetry.enable()             # in memory, no directory
+    upd.update()
+    telemetry.disable()
+    assert {e['type'] for e in rec.events} == {'span'}
+    names = {e['id']: e['name'] for e in rec.events}
+    step = sorted((e['name'], names.get(e['parent']))
+                  for e in rec.events)
+    assert step == sorted([
+        ('train_update', None),
+        ('input_wait', 'train_update'),
+        ('host_batch_prep', 'train_update'),
+        ('h2d', 'train_update'),
+        ('shard_batch', 'h2d'),          # the communicator's own
+        ('jitted_step', 'train_update'),
+        ('metrics_sync', 'train_update')]), step
 
 
 # ---------------------------------------------------------------------
