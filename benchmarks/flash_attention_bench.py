@@ -6,7 +6,9 @@ against the unfused jnp oracle (``mha_reference``: materializes the
 (T, T) score matrix and lets XLA fuse what it can) on the SAME chip,
 fwd and fwd+bwd, across sequence lengths -- and, with ``--sweep``,
 times each of the three kernels alone over explicit tiles at the
-training cell's shapes, beside the tile the rule derives.  This
+training cell's shapes, beside the tile the rule derives, and the
+paged decode kernel alone over pages a grid step at the two serving
+cells' shapes (``--sweep-paged`` for that half alone).  This
 quantifies the custom hot-path the reference delegates to hand-written
 native code (``/root/reference/chainermn/nccl/nccl.pyx:153-199``); here
 the native analogue is the Mosaic-compiled kernel.
@@ -24,6 +26,8 @@ Usage::
     python benchmarks/flash_attention_bench.py            # real TPU
     python benchmarks/flash_attention_bench.py --cpu      # plumbing
     python benchmarks/flash_attention_bench.py --sweep    # + tile sweep
+    python benchmarks/flash_attention_bench.py --sweep-paged  # only the
+                                          # paged decode kernel's sweep
 
 Writes JSONL to ``benchmarks/results/flash_attention_<platform>.jsonl``
 (one line per measurement) and prints a summary table.
@@ -133,6 +137,7 @@ def main():
     argv = sys.argv[1:]
     cpu = '--cpu' in argv
     sweep = '--sweep' in argv
+    sweep_paged_only = '--sweep-paged' in argv
     quick = '--quick' in argv or cpu
     if cpu:
         os.environ.setdefault(
@@ -145,7 +150,8 @@ def main():
     platform = jax.default_backend()
     here = os.path.dirname(os.path.abspath(__file__))
     out_path = os.path.join(
-        here, 'results', 'flash_attention_%s.jsonl' % platform)
+        here, 'results', 'flash_attention_%s%s.jsonl' % (
+            'paged_' if sweep_paged_only else '', platform))
     os.makedirs(os.path.dirname(out_path), exist_ok=True)
     # write rows to a temp file, renamed into place at the end AND on
     # any partial failure with >=1 row -- an aborted run neither
@@ -175,8 +181,13 @@ def main():
 
     done = False
     try:
-        _run_all(configs, seqs_note, dtype, cpu, sweep, quick,
-                 platform, record)
+        if not sweep_paged_only:
+            _run_all(configs, seqs_note, dtype, cpu, sweep, quick,
+                     platform, record)
+        if sweep:
+            sweep_tiles(record, cpu)
+        if sweep or sweep_paged_only:
+            sweep_paged(record, cpu)
         done = True
     finally:
         out_file.close()
@@ -299,6 +310,146 @@ def sweep_tiles(record, cpu):
                         once()
                         best = min(best, time.perf_counter() - t0)
                     row['ms_per_call'] = best / n * 1e3
+                except Exception as e:  # Mosaic lowering limits
+                    row['error'] = str(e)[-300:]
+                record(row)
+
+
+def _cell_lengths(rows, prompt, output, seed=0):
+    """Live lengths of ``rows`` sequences caught mid-generation: a
+    prompt and a uniform share of an output, each log-normal
+    ``(median, sigma, lo, hi)`` like the serving cells' traffic
+    files."""
+    import numpy as np
+    rng = np.random.RandomState(seed)
+
+    def draw(median, sigma, lo, hi):
+        return np.clip(np.exp(rng.normal(np.log(median), sigma, rows)),
+                       lo, hi)
+
+    return (draw(*prompt) + rng.uniform(size=rows) * draw(*output)
+            ).astype(np.int32) + 1
+
+
+# the two serving cells' paged decode calls: rows, the page pool, the
+# table's width, the window and the traffic's prompts and outputs
+PAGED_CASES = {
+    'gpt2m-serve-closed32': dict(
+        rows=32, pool=(2049, 16, 16, 128), n_max=64,
+        prompt=(128, 0.8, 16, 512), output=(96, 0.6, 16, 256)),
+    'trinity-mini-serve-closed64.full': dict(
+        rows=64, pool=(4097, 4, 64, 128), n_max=64, group=8,
+        head_major=True,
+        prompt=(1024, 0.8, 128, 3072), output=(384, 0.6, 64, 1024)),
+    'trinity-mini-serve-closed64.window': dict(
+        rows=64, pool=(2113, 4, 64, 128), n_max=33, group=8,
+        head_major=True, window=2048,
+        prompt=(1024, 0.8, 128, 3072), output=(384, 0.6, 64, 1024)),
+}
+
+
+def paged_chain(case, lengths, pages, n, cpu=False):
+    """``n`` calls of the paged decode kernel at ``pages`` pages a grid
+    step, chained through the query, in one program."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax import lax
+    fa = importlib.import_module('chainermn_tpu.ops.flash_attention')
+
+    rows, pool, n_max = case['rows'], case['pool'], case['n_max']
+    head_major = case.get('head_major', False)
+    group = case.get('group', 1)
+    if cpu:
+        pool = (rows * n_max + 1,) + pool[1:]
+    heads = pool[1] if head_major else pool[2]
+    dtype = jnp.float32 if cpu else jnp.bfloat16
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(keys[0], (rows, heads * group, pool[-1]),
+                          dtype)
+    k = jax.random.normal(keys[1], pool, dtype)
+    v = jax.random.normal(keys[2], pool, dtype)
+    # distinct pages a row, scattered over the pool as an allocator
+    # that has served many requests leaves them
+    tables = jnp.asarray(np.stack([
+        1 + np.random.RandomState(r).permutation(pool[0] - 1)[:n_max]
+        for r in range(rows)]), jnp.int32)
+    lens = jnp.asarray(lengths, jnp.int32)
+    scale = pool[-1] ** -0.5
+
+    def body(_, c):
+        return fa._decode_paged_pallas(
+            c, k, v, tables, lens, scale, group=group,
+            window=case.get('window'), head_major=head_major,
+            pages=pages, interpret=fa.interpret_flag())
+
+    run = jax.jit(lambda x: lax.fori_loop(0, n, body, x))
+    return lambda: run(q).block_until_ready()
+
+
+def sweep_paged(record, cpu):
+    """The paged decode kernel alone over pages a grid step, at the
+    two serving cells' shapes and a length mix like theirs, next to
+    what ``_paged_pages_per_step`` derives (PERF.md section 6, PR 30).
+    ``gb_per_s`` counts the K and V pages a row's live positions touch,
+    as the pool stores them.  The ``one`` rows give every sequence one
+    live position, one page and one step: what a call costs before it
+    reads anything."""
+    import importlib
+    import time
+
+    import jax.numpy as jnp
+    import numpy as np
+    fa = importlib.import_module('chainermn_tpu.ops.flash_attention')
+
+    for name, case in PAGED_CASES.items():
+        if cpu:
+            case = dict(case, rows=2, n_max=min(case['n_max'], 9),
+                        pool=case['pool'][:-1] + (128,),
+                        prompt=(64, 0.8, 16, 128),
+                        output=(32, 0.6, 8, 64),
+                        window=case.get('window') and 256)
+        rows, pool, n_max = case['rows'], case['pool'], case['n_max']
+        head_major = case.get('head_major', False)
+        ps = pool[2] if head_major else pool[1]
+        dtype = jnp.float32 if cpu else jnp.bfloat16
+        derived = fa._paged_pages_per_step(
+            pool[1:], dtype, n_max, head_major=head_major)
+        n = 2 if cpu else 48
+        mix = _cell_lengths(rows, case['prompt'], case['output'])
+        if case.get('window') is None:
+            mix = np.minimum(mix, n_max * ps)      # what the table holds
+        for label, lengths in (('cell', mix),
+                               ('one', np.ones(rows, np.int32))):
+            live = fa._paged_live(np.asarray(lengths, np.int64), ps,
+                                  n_max, case.get('window'), xp=np)[1]
+            read = int(live.sum())
+            page_bytes = 2 * int(np.prod(pool[1:])) * jnp.dtype(
+                dtype).itemsize
+            for pages in (1, 2, 4, 8, 16, 32):
+                if pages > n_max or (label == 'one'
+                                     and pages not in (1, derived)):
+                    continue
+                row = {'sweep': 'decode_paged', 'case': name,
+                       'lengths': label, 'pages_per_step': pages,
+                       'rows': rows, 'pool': list(pool),
+                       'n_max': n_max, 'calls': n,
+                       'mean_length': float(np.mean(lengths)),
+                       'pages_read': read,
+                       'grid_steps': int((-(-live // pages)).sum()),
+                       'derived': pages == derived}
+                try:
+                    once = paged_chain(case, lengths, pages, n, cpu)
+                    once()                         # compile, warm
+                    best = float('inf')
+                    for _ in range(3):
+                        t0 = time.perf_counter()
+                        once()
+                        best = min(best, time.perf_counter() - t0)
+                    row['ms_per_call'] = best / n * 1e3
+                    row['gb_per_s'] = read * page_bytes / (best / n) / 1e9
                 except Exception as e:  # Mosaic lowering limits
                     row['error'] = str(e)[-300:]
                 record(row)

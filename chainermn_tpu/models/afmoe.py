@@ -387,6 +387,25 @@ class AfmoeLM:
             attend)
         return self._logits(params, x), cache, counters
 
+    def decode_paged_grid(self, cache, lengths, n_full, n_ring, tp=1):
+        """``(pages read, grid steps)`` of one ``decode_step_paged``
+        over rows of these live ``lengths`` (host integers), summed
+        over layers: a full layer's call over ``n_full`` table columns,
+        a window layer's over its ring."""
+        from chainermn_tpu import ops
+        total = [0, 0]
+        for window, n_max in ((None, n_full),
+                              (self.sliding_window, n_ring)):
+            layers = [i for i in range(self.num_hidden_layers)
+                      if self._window(i) == window]
+            if layers:
+                leaf = cache['k'][layers[0]]
+                grid = ops.decode_paged_grid(
+                    lengths, leaf.shape[1:], leaf.dtype, n_max,
+                    window=window, head_major=True)
+                total = [t + len(layers) * g for t, g in zip(total, grid)]
+        return tuple(total)
+
     def prefill_paged(self, params, cache, tokens, length, page_table,
                       pos0):
         """A whole prompt in one call: ``tokens`` (1, C) padded to a

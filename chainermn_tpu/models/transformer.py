@@ -338,6 +338,20 @@ class TransformerLM(nn.Module):
                                  page_tables) + ((),)
 
     @nn.nowrap
+    def decode_paged_grid(self, cache, lengths, n_full, n_ring=0, tp=1):
+        """``(pages read, grid steps)`` of one ``decode_step_paged``
+        over rows of these live ``lengths`` (host integers), summed
+        over layers, from shapes alone: the engine's ``kv_pages_read``
+        / ``kv_grid_steps`` counters.  ``cache`` may be its structs."""
+        from chainermn_tpu import ops
+        leaf = cache['k'][0]
+        ps, heads, lanes = leaf.shape[1:]
+        read, steps = ops.decode_paged_grid(
+            lengths, (ps, heads // tp, lanes), leaf.dtype, n_full,
+            quantized=_cache_int8(cache))
+        return self.n_layers * read, self.n_layers * steps
+
+    @nn.nowrap
     def spec_verify(self, params, cache, tokens, positions, slots=None):
         return spec_verify(self, params, cache, tokens, positions,
                            slots=slots)

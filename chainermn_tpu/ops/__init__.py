@@ -17,7 +17,8 @@ interpret mode only when explicitly requested
 
 from chainermn_tpu.ops.flash_attention import (  # noqa
     chunk_attention_reference, decode_attention_paged_reference,
-    decode_attention_reference, flash_attention, flash_attention_chunk,
+    decode_attention_reference, decode_paged_grid, flash_attention,
+    flash_attention_chunk,
     flash_attention_decode, flash_attention_decode_paged, mha_reference,
     paged_kv_append)
 from chainermn_tpu.ops.cross_entropy import (  # noqa

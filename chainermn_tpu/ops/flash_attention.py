@@ -802,9 +802,10 @@ def _decode_pallas(q, k, v, lengths, scale, block_k,
 
     tables = (jnp.arange(b, dtype=jnp.int32)[:, None] * n_blocks
               + jnp.arange(n_blocks, dtype=jnp.int32)[None, :])
-    return _decode_paged_pallas(
+    return _decode_paged_call(
         q, pages(k), pages(v), tables, lengths.astype(jnp.int32),
-        scale, pages(k_scale), pages(v_scale))
+        scale, pages(k_scale), pages(v_scale),
+        interpret=interpret_flag())
 
 
 def flash_attention_decode(q, k, v, lengths, scale=None,
@@ -878,12 +879,12 @@ def flash_attention_decode(q, k, v, lengths, scale=None,
 # fixed-size pages shared across sequences (vLLM-style PagedAttention)
 # and each sequence reads its own pages through a PER-SEQUENCE page
 # table: key-block j of sequence b lives at page ``page_tables[b, j]``.
-# The page table rides in SMEM (scalar prefetch), so the kernel's
-# key-block grid axis is INDIRECT -- one HBM pass over only the pages
-# the sequence actually owns, never the whole pool.  Pages past the
-# sequence's fill level are skipped (dynamic pl.when) and their DMA is
-# elided by clamping the fetched page index at the live frontier, the
-# same idiom as the causal frontier clamp in _fwd_pallas.
+# The page table rides in SMEM (scalar prefetch) and the pools stay in
+# HBM: the kernel copies the pages a sequence owns into VMEM itself,
+# several a grid step (_paged_pages_per_step), the next step's while
+# this one computes -- one HBM pass over only the live pages, never
+# the whole pool.  The grid is the sequences' LIVE steps one after the
+# other: none is stepped for a page past a sequence's fill level.
 #
 # int8 KV pages compose exactly like the slot cache: per-(position,
 # head) symmetric scales (precision.quantize_kv) stored page-shaped,
@@ -990,59 +991,203 @@ def _decode_paged_blockwise_jnp(q, k, v, page_tables, lengths, scale,
     return (acc / l_safe[..., None]).astype(q.dtype)
 
 
-def _decode_paged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref,
-                         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref,
-                         *, scale, page_size, quantized, window=None,
+# ----------------------------------------------------------------------
+# pages a grid step: a function of the shapes
+# ----------------------------------------------------------------------
+
+# K + V bytes (as they lie in VMEM) one grid step of the paged decode
+# kernel fetches.  From the sweep on the chip at the two serving cells'
+# shapes (PERF.md section 6, PR 30): a step costs ~1.4 us before it
+# reads a byte, the HBM's time for ~1.1 MB.  At 1 MiB (8 pages of
+# 64 KB) the page-major kernel is fastest (4 pages tie, 16 lose 12%:
+# a partly live step computes its whole tile) and the head-major one
+# within 5% of its best (32 pages).
+_PAGED_STEP_BYTES = 1024 * 1024
+
+# float32 copies of the step's K/V tile the page-major (VPU) path holds
+# at once: k, v and the product made of each
+_PAGED_TEMPS = 4
+
+
+def _sublanes(dtype):
+    """Rows of the dtype's VMEM tile: 8 of 32 bits, 16 of bfloat16,
+    32 of int8."""
+    return 8 * max(4 // jnp.dtype(dtype).itemsize, 1)
+
+
+def _vmem_bytes(shape, dtype):
+    """Bytes of an array as VMEM holds it: the minor dim padded to the
+    128 lanes, the second-minor to the dtype's sublane tile."""
+    n = jnp.dtype(dtype).itemsize
+    for extent in shape[:-2]:
+        n *= extent
+    rows = _sublanes(dtype)
+    return (n * (-(-shape[-2] // rows) * rows)
+            * (-(-shape[-1] // _LANES) * _LANES))
+
+
+def _paged_step_vmem(pages, page_shape, dtype, quantized, head_major):
+    """``(fetched, held)`` bytes of a grid step that carries ``pages``
+    pages: what its copies bring into VMEM (K and V, with their float32
+    scale tiles), and the two-slot scratch plus the float32 working
+    set of the compute."""
+    tile = 2 * _vmem_bytes(page_shape, dtype)
+    if quantized:
+        tile += 2 * _vmem_bytes(page_shape[:-1] + (1,), jnp.float32)
+    # the head-major path feeds the MXU the pages as stored: its float32
+    # values are score rows, under one page's worth
+    temps = ((1 if head_major else _PAGED_TEMPS)
+             * _vmem_bytes(page_shape, jnp.float32))
+    return pages * tile, pages * (2 * tile + temps)
+
+
+def _paged_pages_per_step(page_shape, dtype, n_max, quantized=False,
+                          head_major=False):
+    """How many pages one grid step of the paged decode kernel carries,
+    from what the call can see: the largest power of two that is at
+    most the table's width, whose step fetches at most
+    ``_PAGED_STEP_BYTES`` of K + V and holds its two-slot scratch and
+    working set inside ``_VMEM_LIMIT``.  1 where a page is that large
+    already or the table is one page wide.  The head-major layout
+    places a page at a sublane offset of the step's tile, so a page
+    size off the dtype's sublane tile is one page a step too."""
+    if head_major and page_shape[-2] % _sublanes(dtype):
+        return 1
+    pages = 1
+    while pages * 2 <= n_max:
+        fetched, held = _paged_step_vmem(pages * 2, page_shape, dtype,
+                                         quantized, head_major)
+        if fetched > _PAGED_STEP_BYTES or held > _VMEM_LIMIT:
+            break
+        pages *= 2
+    return pages
+
+
+def _paged_live(lengths, page_size, n_max, window, xp=jnp):
+    """A row's first live logical page and how many are live, from its
+    length: every page a live position lies in, at most the table's
+    width, at least one (a row of length 0 fetches a page and attends
+    nothing).  On traced arrays and SMEM scalars; on host integers with
+    ``xp=numpy``."""
+    first = (0 if window is None
+             else xp.maximum(lengths - window, 0) // page_size)
+    last = xp.maximum(lengths - 1, 0) // page_size
+    return first, xp.minimum(last - first + 1, n_max)
+
+
+def decode_paged_grid(lengths, page_shape, dtype, n_max, window=None,
+                      quantized=False, head_major=False):
+    """``(pages read, grid steps)`` of one paged decode call over rows
+    of these ``lengths`` (host integers; the engine sends a padded row
+    as length 1): what the kernel's copies fetch and how many steps
+    its grid takes.  For the engine's counters: integer arithmetic on
+    the host, no device read."""
+    import numpy as np
+    pages = _paged_pages_per_step(page_shape, dtype, n_max, quantized,
+                                  head_major)
+    ps = page_shape[-2] if head_major else page_shape[0]
+    lengths = np.asarray(lengths, np.int64)    # noqa: shardlint (host)
+    live = _paged_live(lengths, ps, n_max, window, xp=np)[1]
+    return int(live.sum()), int((-(-live // pages)).sum())
+
+
+def _decode_paged_kernel(table_ref, len_ref, row_ref, step_ref, q_ref,
+                         k_hbm, v_hbm, *refs, scale, page_size, pages,
+                         n_max, quantized, window=None,
                          head_major=False):
-    """One (sequence, page) grid cell: the online-softmax update of
-    ALL heads' single query rows against one PAGE of the pool.  The
-    page table and per-sequence lengths are scalar-prefetched (SMEM),
-    so the k/v block specs fetch ``page_tables[b, j]`` directly -- the
-    indirection lives in the DMA descriptor, not the compute.
+    """One LIVE (sequence, step) pair of the flattened grid: the
+    online-softmax update of ALL heads' single query rows against
+    ``pages`` PAGES of the pool, which the kernel fetches itself.  The
+    pools stay in HBM; the page table, the lengths and the grid's
+    ``(row, step)`` lists are scalar-prefetched (SMEM).  Grid step ``t``
+    starts one copy ``pool[table[row, column]] -> VMEM`` for each live
+    page of step ``t + 1`` (the row's next, or the next row's first)
+    into the other of two scratch slots, then waits for its own copies
+    and computes: the copies hide behind the compute.  The grid has no
+    dead step: it is as long as the rows' live steps together.
 
-    A page arrives as its natural (page_size, H, D) tile (Mosaic wants
-    the last two block dims whole), so the one-row-per-head products
-    run on the VPU -- multiply by the broadcast query, reduce over the
-    lane (D) axis -- instead of H separate M=1 matmuls; the softmax
-    state is per head, (H, 1) / (H, D) in VMEM scratch.
+    A step's pages land side by side in its slot, a (pages * page_size,
+    H, D) tile, so the one-row-per-head products run on the VPU --
+    multiply by the broadcast query, reduce over the lane (D) axis --
+    instead of H separate M=1 matmuls; the softmax state is per head,
+    (H, 1) / (H, D) in VMEM scratch.  Positions of a slot's dead pages
+    (stale K/V of an earlier step) are masked like any position past
+    the length.
 
-    ``head_major``: the page is (Hkv, page_size, D) and the queries
-    (Hkv, G, D), the G query heads of a group riding ONE read of their
-    K/V head's page: two batched MXU products per page, state
-    (Hkv, G, 1) / (Hkv, G, D).  ``window``: only the ``window``
-    positions before ``length`` are live and step ``j`` is LOGICAL
-    page ``first + j`` (the index map addresses it through the ring)."""
+    ``head_major``: the pages are (Hkv, page_size, D), the tile
+    (Hkv, pages * page_size, D) and the queries (Hkv, G, D), the G
+    query heads of a group riding ONE read of their K/V head's pages:
+    two batched MXU products a step, state (Hkv, G, 1) / (Hkv, G, D).
+    ``window``: only the ``window`` positions before ``length`` are
+    live, step ``s`` starts at LOGICAL page ``first + s * pages`` and
+    each page is addressed through the ring, ``(first + j) % n_max``."""
     import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    n_pages = pl.num_programs(1)
+    n = 2 * pages if quantized else 0      # a scale tile a page
+    scales, refs = refs[:n], refs[n:]
+    o_ref, k_buf, v_buf, sem, m_ref, l_ref, acc_ref = refs
+    t = pl.program_id(0)
 
-    @pl.when(j == 0)
+    def copies(at, act):
+        """Start (or wait for) the copies of grid step ``at``."""
+        row, slot = row_ref[at], at % 2
+        first, live = _paged_live(len_ref[row], page_size, n_max, window)
+        page0 = step_ref[at] * pages
+
+        def one(p, carry):
+            column = first + page0 + p
+            if window is not None:
+                column = column % n_max
+            page = table_ref[row, column]
+            where = pl.ds(pl.multiple_of(p * page_size, page_size),
+                          page_size)
+            for i, (pool, buf) in enumerate(((k_hbm, k_buf),
+                                             (v_hbm, v_buf))):
+                dst = (buf.at[slot, :, where] if head_major
+                       else buf.at[slot, where])
+                getattr(pltpu.make_async_copy(
+                    pool.at[page], dst, sem.at[slot, i]), act)()
+            return carry
+
+        lax.fori_loop(0, jnp.minimum(live - page0, pages), one, 0)
+
+    @pl.when(t == 0)
+    def _prime():
+        # a slot's dead pages are masked by position: p is 0 there, and
+        # 0 x what an untouched VMEM may hold must still be 0
+        v_buf[...] = jnp.zeros_like(v_buf)
+        copies(0, 'start')
+
+    @pl.when(t + 1 < pl.num_programs(0))
+    def _fetch_next():
+        copies(t + 1, 'start')
+
+    b, step, slot = row_ref[t], step_ref[t], t % 2
+    length = len_ref[b]
+    first, live = _paged_live(length, page_size, n_max, window)
+    k0 = (first + step * pages) * page_size            # first position
+
+    @pl.when(step == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    length = len_ref[b]
-    if window is not None:
-        start = jnp.maximum(length - window, 0)
-        j = start // page_size + j
+    copies(t, 'wait')
 
-    def live(k_pos):
+    def mask(k_pos):
         ok = k_pos < length
         if window is not None:
-            ok = jnp.logical_and(ok, k_pos >= start)
+            ok = jnp.logical_and(ok, k_pos >= length - window)
         return ok
 
-    # pages entirely beyond this sequence's fill level contribute
-    # nothing; their fetch was clamped to the live frontier (elided)
-    @pl.when(j * page_size < length)
+    @pl.when(k0 < length)
     def _accum():
         if head_major:
             q = q_ref[0]                               # (Hkv, G, D)
-            k = k_ref[0]                               # (Hkv, ps, D)
-            v = v_ref[0]
+            k = k_buf[slot]                            # (Hkv, keys, D)
+            v = v_buf[slot]
             # one MXU pass in the pool's own dtype, whatever the
             # process-wide default precision (Mosaic refuses 'highest'
             # on bfloat16 operands)
@@ -1050,9 +1195,8 @@ def _decode_paged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref,
                 q, k, (((2,), (2,)), ((0,), (0,))),
                 precision=lax.Precision.DEFAULT,
                 preferred_element_type=jnp.float32) * scale
-            k_pos = (j * page_size
-                     + lax.broadcasted_iota(jnp.int32, s.shape, 2))
-            s = jnp.where(live(k_pos), s, NEG_INF)     # (Hkv, G, ps)
+            k_pos = k0 + lax.broadcasted_iota(jnp.int32, s.shape, 2)
+            s = jnp.where(mask(k_pos), s, NEG_INF)     # (Hkv, G, keys)
             m_prev = m_ref[...]                        # (Hkv, G, 1)
             m_new = jnp.maximum(m_prev,
                                 jnp.max(s, axis=-1, keepdims=True))
@@ -1067,15 +1211,17 @@ def _decode_paged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref,
                 preferred_element_type=jnp.float32)
             return
         q = q_ref[0].astype(jnp.float32) * scale       # (H, D)
-        k = k_ref[0].astype(jnp.float32)               # (ps, H, D)
-        v = v_ref[0].astype(jnp.float32)
+        k = k_buf[slot].astype(jnp.float32)            # (keys, H, D)
+        v = v_buf[slot].astype(jnp.float32)
         if quantized:
-            k = k * ks_ref[0]                          # (ps, H, 1)
-            v = v * vs_ref[0]
-        s = jnp.sum(k * q[None], axis=-1, keepdims=True)  # (ps, H, 1)
-        k_pos = (j * page_size
-                 + lax.broadcasted_iota(jnp.int32, s.shape, 0))
-        s = jnp.where(live(k_pos), s, NEG_INF)
+            k_scale, v_scale = (
+                jnp.concatenate([ref[0] for ref in tiles], axis=0)
+                for tiles in (scales[:pages], scales[pages:]))
+            k = k * k_scale                            # (keys, H, 1)
+            v = v * v_scale
+        s = jnp.sum(k * q[None], axis=-1, keepdims=True)  # (keys, H, 1)
+        k_pos = k0 + lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        s = jnp.where(mask(k_pos), s, NEG_INF)
         m_prev = m_ref[...]                            # (H, 1)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
         alpha = jnp.exp(m_prev - m_new)
@@ -1084,7 +1230,7 @@ def _decode_paged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref,
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=0)
         acc_ref[...] = acc_ref[...] * alpha + jnp.sum(p * v, axis=0)
 
-    @pl.when(pl.program_id(1) == n_pages - 1)
+    @pl.when((step + 1) * pages >= live)
     def _finalize():
         l_safe = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
@@ -1092,7 +1238,12 @@ def _decode_paged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref,
 
 def _decode_paged_pallas(q, k, v, page_tables, lengths, scale,
                          k_scale=None, v_scale=None, group=1,
-                         window=None, head_major=False):
+                         window=None, head_major=False, pages=None,
+                         interpret=False):
+    """``pages``: pages a grid step, for the sweep alone
+    (``benchmarks/flash_attention_bench.py``); every caller leaves it
+    to the rule.  ``interpret``: the caller's ``interpret_flag()``,
+    an argument so that the jitted form below is keyed by it."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1100,54 +1251,75 @@ def _decode_paged_pallas(q, k, v, page_tables, lengths, scale,
     ps = k.shape[2] if head_major else k.shape[1]
     n_max = page_tables.shape[1]
     quantized = k_scale is not None
-
-    def kv_ix(i, j, table_ref, len_ref):
-        # clamp the fetched page at the live frontier: dead steps
-        # re-fetch the last live page, which Pallas elides
-        last = jnp.maximum((len_ref[i] - 1) // ps, 0)
-        if window is None:
-            column = jnp.minimum(j, last)
-        else:
-            first = jnp.maximum(len_ref[i] - window, 0) // ps
-            column = jnp.minimum(first + j, last) % n_max
-        return (table_ref[i, column], 0, 0, 0)
-
+    if pages is None:
+        pages = _paged_pages_per_step(k.shape[1:], k.dtype, n_max,
+                                      quantized, head_major)
+    pad = -d % _LANES
+    if pad:
+        # Mosaic copies no slice of an array whose minor dim is off the
+        # 128 lanes: a narrower pool is padded, a COPY of it every
+        # call (the models keep theirs lane-wide; a zero lane adds
+        # nothing to a product)
+        q, k, v = (jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, pad),))
+                   for x in (q, k, v))
+        d += pad
+    # the grid: the rows' live steps one after the other, step t being
+    # step ``step_of[t]`` of row ``row_of[t]``
+    steps = -(-_paged_live(lengths, ps, n_max, window)[1] // pages)
+    ends = jnp.cumsum(steps)
+    t = jnp.arange(b * -(-n_max // pages) + 1, dtype=jnp.int32)
+    row_of = jnp.minimum(
+        jnp.sum(t[:, None] >= ends[None, :], axis=1, dtype=jnp.int32),
+        b - 1)
+    step_of = t - (ends - steps)[row_of]
+    scales, scale_specs = [], []
     if quantized:
         # (P, ps, H) -> (P, ps, H, 1): the scale tile lines up with
-        # the page tile's (H, D) minor dims and broadcasts over lanes
-        ks = k_scale.astype(jnp.float32)[..., None]
-        vs = v_scale.astype(jnp.float32)[..., None]
-        scale_spec = pl.BlockSpec((1, ps, h, 1), kv_ix)
-    else:
-        # one-tile placeholder keeps one kernel signature; the
-        # quantized flag compiles the dequant multiply in or out
-        ks = vs = jnp.zeros((1, 1, h, 1), jnp.float32)
-        scale_spec = pl.BlockSpec((1, 1, h, 1),
-                                  lambda i, j, t, n: (0, 0, 0, 0))
+        # the page tile's (H, D) minor dims and broadcasts over lanes.
+        # One lane wide, so no copy of the kernel's own can carry it:
+        # the tiles of a step's pages come as that many blocks each,
+        # addressed like the pages, a dead one clamped to the row's
+        # last live page (a block fetched before is not fetched again)
+        def scale_ix(p, t, table_ref, len_ref, row_ref, step_ref):
+            i = row_ref[t]
+            first, live = _paged_live(len_ref[i], ps, n_max, window)
+            column = first + jnp.minimum(step_ref[t] * pages + p,
+                                         live - 1)
+            if window is not None:
+                column = column % n_max
+            return (table_ref[i, column], 0, 0, 0)
+
+        scales = [x for x in (k_scale.astype(jnp.float32)[..., None],
+                              v_scale.astype(jnp.float32)[..., None])
+                  for _ in range(pages)]
+        scale_specs = [
+            pl.BlockSpec((1, ps, h, 1), functools.partial(scale_ix, p))
+            for _ in range(2) for p in range(pages)]
     if head_major:
         # (B, H, D) -> (B, Hkv, G, D): a group's query heads are
         # adjacent, so this is a view
         h_kv = h // group
         q = q.reshape(b, h_kv, group, d)
         row, state = (1, h_kv, group, d), (h_kv, group)
-        row_ix = lambda i, j, t, n: (i, 0, 0, 0)       # noqa: E731
-        page = (1, h_kv, ps, d)
+        tile = (2, h_kv, pages * ps, d)
     else:
         row, state = (1, h, d), (h,)
-        row_ix = lambda i, j, t, n: (i, 0, 0)          # noqa: E731
-        page = (1, ps, h, d)
+        tile = (2, pages * ps, h, d)
+    row_spec = pl.BlockSpec(
+        row, lambda t, tables, lens, rows, steps: (rows[t],) + (0,) * (
+            len(row) - 1))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,       # page_tables, lengths
-        grid=(b, n_max),
-        in_specs=[
-            pl.BlockSpec(row, row_ix),
-            pl.BlockSpec(page, kv_ix),
-            pl.BlockSpec(page, kv_ix),
-            scale_spec,
-            scale_spec,
-        ],
-        out_specs=pl.BlockSpec(row, row_ix),
+        # page_tables, lengths, row_of, step_of
+        num_scalar_prefetch=4,
+        grid=(ends[-1],),
+        in_specs=[row_spec,
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)] + scale_specs,
+        out_specs=row_spec,
         scratch_shapes=[
+            pltpu.VMEM(tile, k.dtype),                 # two slots of K
+            pltpu.VMEM(tile, v.dtype),                 # and of V
+            pltpu.SemaphoreType.DMA((2, 2)),           # (slot, k / v)
             pltpu.VMEM(state + (1,), jnp.float32),     # m
             pltpu.VMEM(state + (1,), jnp.float32),     # l
             pltpu.VMEM(state + (d,), jnp.float32),     # acc
@@ -1155,14 +1327,30 @@ def _decode_paged_pallas(q, k, v, page_tables, lengths, scale,
     )
     out = pl.pallas_call(
         functools.partial(_decode_paged_kernel, scale=scale,
-                          page_size=ps, quantized=quantized,
-                          window=window, head_major=head_major),
+                          page_size=ps, pages=pages, n_max=n_max,
+                          quantized=quantized, window=window,
+                          head_major=head_major),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret_flag(),
+        # the slots and the prefetch run from one step into the next
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('arbitrary',),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
         name='flash_attention_decode_paged',
-    )(page_tables, lengths, q, k, v, ks, vs)
-    return out.reshape(b, h, d)
+    )(page_tables, lengths, row_of, step_of, q, k, v, *scales)
+    return out.reshape(b, h, d)[..., :d - pad]
+
+
+# One traced and lowered kernel for every layer of an executable: a
+# model's layers call with the same shapes, so under ``jit`` the kernel
+# is traced once and lowered to one function that each layer calls
+# (tracing and lowering a Pallas kernel is not cached across set-ups,
+# and it is most of a warm set-up: PERF.md section 6, PR 30).
+_decode_paged_call = jax.jit(
+    _decode_paged_pallas,
+    static_argnames=('scale', 'group', 'window', 'head_major', 'pages',
+                     'interpret'))
 
 
 def flash_attention_decode_paged(q, k, v, page_tables, lengths,
@@ -1180,13 +1368,16 @@ def flash_attention_decode_paged(q, k, v, page_tables, lengths,
     or beyond ``ceil(lengths[b] / page_size)`` are never read, so a
     host-side allocator can leave them pointing at its scratch page.
 
-    Arithmetic is IDENTICAL to :func:`flash_attention_decode` (same
-    online-softmax recurrence, key-block == page): paging only changes
-    where the blocks live.  The page table is scalar-prefetched into
-    SMEM so the kernel streams exactly the sequence's own pages in one
-    HBM pass -- memory traffic scales with LIVE tokens, not with pool
-    capacity, which is what lets N sequences sharing a prompt prefix
-    read one banked copy (``docs/serving.md``).
+    Arithmetic is that of :func:`flash_attention_decode` (same
+    online-softmax recurrence, a key block == the pages of one grid
+    step): paging only changes where the blocks live.  The page table
+    is scalar-prefetched into SMEM and the kernel copies exactly the
+    sequence's own pages out of HBM, several a step, in one pass --
+    memory traffic scales with LIVE tokens, not with pool capacity,
+    which is what lets N sequences sharing a prompt prefix read one
+    banked copy (``docs/serving.md``).  A pool whose minor dim is not
+    a multiple of 128 lanes is padded first, a copy of it: keep pools
+    lane-wide, as the models do.
 
     int8 KV pages: pass int8 ``k``/``v`` with per-(position, head)
     scales ``k_scale``/``v_scale`` (P, page_size, H) from
@@ -1237,10 +1428,13 @@ def flash_attention_decode_paged(q, k, v, page_tables, lengths,
         scale = d ** -0.5
     tables = page_tables.astype(jnp.int32)
     lens = lengths.astype(jnp.int32)
-    run = (_decode_paged_blockwise_jnp if pallas_mode() == 'fallback'
-           else _decode_paged_pallas)
-    return run(q, k, v, tables, lens, scale, k_scale, v_scale, group,
-               window, head_major)
+    if pallas_mode() == 'fallback':
+        return _decode_paged_blockwise_jnp(
+            q, k, v, tables, lens, scale, k_scale, v_scale, group, window,
+            head_major)
+    return _decode_paged_call(q, k, v, tables, lens, scale, k_scale,
+                              v_scale, group, window, head_major,
+                              interpret=interpret_flag())
 
 
 def _kv_append_kernel(pages_ref, offsets_ref, k_new_ref, v_new_ref,

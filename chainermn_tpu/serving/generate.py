@@ -1656,7 +1656,7 @@ class GenerationEngine:
             self._cache = cache
             toks, counters = self._split_sampled(
                 jax.block_until_ready(toks))
-            if counters and rec is not None:
+            if rec is not None:
                 span.set(**counters, **self._attended(rows))
         now = clock()
         now_tele = rec.now() if rec is not None else None
@@ -1726,6 +1726,17 @@ class GenerationEngine:
         if self._window is not None:
             out['kv_window_positions'] = sum(
                 min(n, self._window) for n in live)
+        if self.paged:
+            # how the paged decode kernel's grid engaged: the pages its
+            # copies fetched over the steps it took (a pad row attends
+            # position 0 of the scratch page), every layer's call
+            tp = (self.plan.mesh.shape[self.plan.model_axis]
+                  if self.plan is not None else 1)
+            lengths = live + [1] * (len(rows) - len(live))
+            out['kv_pages_read'], out['kv_grid_steps'] = (
+                self.model.decode_paged_grid(
+                    self._cache_struct, lengths, self.pages_per_seq,
+                    self._ring, tp=tp))
         return out
 
     def _spec_once(self, clock):

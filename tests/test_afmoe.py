@@ -506,6 +506,13 @@ def test_spans_carry_the_expert_counters_and_page_counts(model, params):
     # window layer sees 8
     assert decode[0]['kv_positions'] == 10
     assert decode[0]['kv_window_positions'] == 8
+    # the paged decode kernel's grid: 3 live pages of 4 in the full
+    # layer and in each of the 4 window layers (a pad row reads the
+    # scratch page), one page a step (a head-major page of 4 rows is
+    # under a sublane tile) and no dead step
+    pad = decode[0]['bucket'] - 1
+    assert decode[0]['kv_pages_read'] == 5 * (3 + pad)
+    assert decode[0]['kv_grid_steps'] == decode[0]['kv_pages_read']
     assert all(r['window_pages_in_use'] <= r['full_pages_in_use']
                for r in ticks)
     assert ticks[-1]['window_pages_in_use'] == 0

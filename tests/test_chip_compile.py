@@ -140,7 +140,22 @@ def _head_major(n_pages):
 _EXPERTS = [((128, 2048, 1024), BF16)] * 2 + [((128, 1024, 2048), BF16),
                                               ((128,), I32)]
 
+# the gpt2m-serve-closed32 cell's paged decode call: 32 rows, pages of
+# 16 positions x 16 heads x 128 lanes (the head dim 64 padded), a table
+# 64 wide
+_Q32 = ((32, 16, 128), BF16)
+
+
+def _page_major(dtype):
+    return [((2049, 16, 16, 128), dtype)] * 2 + [((32, 64), I32),
+                                                 ((32,), I32)]
+
+
 CASES = {
+    'decode_paged_gpt2m_cell': (_decode_paged, [_Q32] + _page_major(BF16)),
+    'decode_paged_gpt2m_cell_int8': (
+        _decode_paged, [_Q32] + _page_major(I8)
+        + [((2049, 16, 16), F32)] * 2),
     'decode_paged_ring_group8_page64': (
         _decode_ring, [_Q64] + _head_major(2113)
         + [((64, 33), I32), ((64,), I32)]),
@@ -207,6 +222,43 @@ def test_kernel_compiles_for_v5e(case, one_chip, mosaic):
     compiled = jax.jit(fn).lower(*args).compile()
     assert 'tpu_custom_call' in compiled.as_text(), (
         '%s compiled without its Mosaic kernel' % case)
+
+
+@pytest.mark.parametrize('case', [
+    'decode_paged_gpt2m_cell', 'decode_paged_gpt2m_cell_int8',
+    'decode_paged_full_group8_page64', 'decode_paged_ring_group8_page64'])
+def test_paged_decode_carries_several_pages_inside_its_vmem(
+        case, one_chip, mosaic):
+    """At both serving cells' exact shapes the rule gives a grid step
+    several pages, and the VMEM Mosaic allocates for the kernel
+    (``used_scoped_memory_configs`` of the compiled custom call) holds
+    the two slots of that many pages inside the limit the call
+    states."""
+    import re
+    fa = KERNEL_MODULES[0]
+    fn, shapes = CASES[case]
+    int8 = len(shapes) == 7
+    pool, dtype = shapes[1]
+    n_max = shapes[3][0][1]
+    head_major = 'group8' in case
+    pages = fa._paged_pages_per_step(pool[1:], dtype, n_max, int8,
+                                     head_major)
+    assert pages > 1
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    call, = [line for line in
+             jax.jit(fn).lower(*args).compile().as_text().split('\n')
+             if 'custom-call(' in line
+             and 'flash_attention_decode_paged' in line]
+    limit, used = (
+        int(re.search(r'"%s":\[\{[^\]]*"size":"(\d+)"' % key,
+                      call).group(1))
+        for key in ('scoped_memory_configs', 'used_scoped_memory_configs'))
+    fetched, held = fa._paged_step_vmem(pages, pool[1:], dtype, int8,
+                                        head_major)
+    assert limit == fa._VMEM_LIMIT
+    assert 2 * fetched <= used <= limit
+    assert used <= held, 'the rule counts less than Mosaic allocates'
 
 
 @pytest.mark.parametrize('body', ['decode', 'prefill'])

@@ -391,6 +391,182 @@ class TestDecodePagedAttention:
         walk(jaxpr.jaxpr)
 
 
+def _paged_oracle(q, k, v, tables, lengths, window=None,
+                  head_major=False, k_scale=None, v_scale=None):
+    """Dense numpy oracle of the paged decode call with every static
+    argument: each row's live positions gathered through its table (a
+    ring where there is a window), plain softmax in float64."""
+    q, k, v = (np.asarray(x, np.float64) for x in (q, k, v))
+    if k_scale is not None:
+        k = k * np.asarray(k_scale, np.float64)[..., None]
+        v = v * np.asarray(v_scale, np.float64)[..., None]
+    if head_major:                        # -> (P, ps, Hkv, D)
+        k, v = k.swapaxes(1, 2), v.swapaxes(1, 2)
+    ps, group = k.shape[1], q.shape[1] // k.shape[2]
+    tables, out = np.asarray(tables), []
+    for b, length in enumerate(np.asarray(lengths)):
+        pos = np.arange(0 if window is None else max(length - window, 0),
+                        length)
+        column = pos // ps
+        if window is not None:
+            column = column % tables.shape[1]
+        pages = tables[b, column]
+        kk = np.repeat(k[pages, pos % ps], group, axis=1)   # (n, H, D)
+        vv = np.repeat(v[pages, pos % ps], group, axis=1)
+        s = np.einsum('hd,khd->hk', q[b], kk) * q.shape[-1] ** -0.5
+        p = np.exp(s - s.max(-1, keepdims=True))
+        out.append(np.einsum('hk,khd->hd', p / p.sum(-1, keepdims=True),
+                             vv))
+    return np.stack(out)
+
+
+# layout -> (pool's page shape after the pool axis, group, window,
+# table width, lengths).  Page size 4 (8 in the head-major layouts: a
+# float32 sublane tile, below which that layout is one page a step).
+# The lengths hit each edge at every pages-a-step the rule can return:
+# a row of length 1; a length ending mid-page and mid-step; a row whose
+# steps are all live; a table whose width no step count divides; and,
+# in the ring, rows whose first live column (2, 5, 7, 8, 9, 10) puts
+# the wrap inside a step of 2, of 4 and of 8 pages.
+_PAGED_LAYOUTS = {
+    'page_major': ((4, 2, 16), 1, None, 11, [1, 11, 44, 16, 21, 32]),
+    'page_major_int8': ((4, 2, 16), 1, None, 11, [1, 11, 44, 16, 21, 32]),
+    'head_major_group': ((2, 8, 16), 4, None, 11, [1, 19, 88, 32, 41, 64]),
+    'head_major_ring': ((2, 8, 16), 4, 80, 11,
+                        [1, 80, 99, 123, 139, 147, 154, 163]),
+}
+
+
+@pytest.mark.parametrize('pages', [1, 2, 4, 8])
+@pytest.mark.parametrize('layout', sorted(_PAGED_LAYOUTS))
+def test_paged_decode_pages_a_step(mode, monkeypatch, layout, pages):
+    """The paged decode kernel at every number of pages a grid step,
+    forced through the rule's own input (what a step may fetch), in
+    each layout: rows of every edge (above), two rows sharing a page,
+    and dirty pages past every live prefix."""
+    fa = _fa
+    page, group, window, n_max, lengths = _PAGED_LAYOUTS[layout]
+    head_major = layout.startswith('head_major')
+    int8 = layout.endswith('int8')
+    ps = page[1] if head_major else page[0]
+    b, n_pages = len(lengths), 1 + len(lengths) * n_max
+    rng = np.random.RandomState(3)
+    q = _rand((b, (page[0] if head_major else page[1]) * group,
+               page[2]), 40)
+    k = _rand((n_pages,) + page, 41)
+    v = _rand((n_pages,) + page, 42)
+    tables = 1 + rng.permutation(n_pages - 1).reshape(b, n_max)
+    if window is None:
+        tables[1, :2] = tables[2, :2]     # a shared two-page prefix
+    else:
+        tables[3] = tables[4]             # two rows on one ring
+    scales = {}
+    if int8:
+        from chainermn_tpu.precision import quantize_kv
+        (k, ks), (v, vs) = quantize_kv(k), quantize_kv(v)
+        scales = dict(k_scale=ks, v_scale=vs)
+    # dirty what no row may see: the scratch page and every page no
+    # live position of any row lies in
+    live = set()
+    for row, length in zip(tables, lengths):
+        first = 0 if window is None else max(length - window, 0) // ps
+        live |= {row[j % n_max] for j in range(first,
+                                               (length - 1) // ps + 1)}
+    dead = np.asarray(sorted(set(range(n_pages)) - live))
+    k_dirty = k.at[dead].set(jnp.asarray(100, k.dtype))
+    v_dirty = v.at[dead].set(jnp.asarray(-100, v.dtype))
+    # the rule's input: a step may fetch this many pages' K + V
+    monkeypatch.setattr(fa, '_PAGED_STEP_BYTES', pages * (
+        2 * fa._vmem_bytes(page, k.dtype)
+        + (2 * fa._vmem_bytes(page[:-1] + (1,), jnp.float32)
+           if int8 else 0)))
+    assert fa._paged_pages_per_step(page, k.dtype, n_max, int8,
+                                    head_major) == pages
+    assert n_max % pages or pages == 1
+    tables, lengths = jnp.asarray(tables), jnp.asarray(lengths)
+    out = ops.flash_attention_decode_paged(
+        q, k_dirty, v_dirty, tables, lengths, group=group, window=window,
+        head_major=head_major, **scales)
+    want = _paged_oracle(q, k, v, tables, lengths, window, head_major,
+                         **scales)
+    np.testing.assert_allclose(out, want, atol=2e-5, rtol=2e-5)
+    if not head_major:
+        np.testing.assert_allclose(
+            out, ops.decode_attention_paged_reference(
+                q, k, v, tables, lengths, **scales),
+            atol=2e-5, rtol=2e-5)
+
+
+# (page shape, dtype, table width, int8, head-major) -> pages a step
+_PAGED_RULE = {
+    'gpt2m_cell': (((16, 16, 128), jnp.bfloat16, 64, False, False), 8),
+    'trinity_full': (((4, 64, 128), jnp.bfloat16, 64, False, True), 8),
+    'trinity_ring': (((4, 64, 128), jnp.bfloat16, 33, False, True), 8),
+    'one_page_wide_table': (((16, 16, 128), jnp.bfloat16, 1, False,
+                             False), 1),
+    'table_of_three': (((16, 16, 128), jnp.bfloat16, 3, False, False), 2),
+    'page_of_1mb': (((256, 16, 128), jnp.bfloat16, 64, False, False), 1),
+    'page_of_256kb': (((64, 16, 128), jnp.bfloat16, 64, False, False), 2),
+    'int8_scales_ride': (((16, 16, 128), jnp.int8, 64, True, False), 2),
+    'tp_shard_of_4_heads': (((16, 4, 128), jnp.bfloat16, 64, False,
+                             False), 8),
+    'head_major_off_the_sublanes': (((4, 8, 128), jnp.bfloat16, 64,
+                                     False, True), 1),
+}
+
+
+@pytest.mark.parametrize('case', sorted(_PAGED_RULE))
+def test_paged_pages_per_step_rule(case):
+    """Pages a grid step are a function of the shapes: a power of two,
+    at most the table's width, a step's K + V inside what a step may
+    fetch and its scratch and working set inside the VMEM the kernel
+    asks for; 1 where a page is that large already."""
+    fa = _fa
+    (page, dtype, n_max, int8, head_major), want = _PAGED_RULE[case]
+    pages = fa._paged_pages_per_step(page, dtype, n_max, int8,
+                                     head_major)
+    assert pages == want
+    assert pages & (pages - 1) == 0 and 1 <= pages <= n_max
+    fetched, held = fa._paged_step_vmem(pages, page, dtype, int8,
+                                        head_major)
+    assert held <= fa._VMEM_LIMIT
+    assert pages == 1 or fetched <= fa._PAGED_STEP_BYTES
+
+
+def test_paged_pages_per_step_rule_holds_the_vmem_bound(monkeypatch):
+    """With no bound on what a step fetches, the VMEM the kernel asks
+    for is what stops the doubling."""
+    fa = _fa
+    monkeypatch.setattr(fa, '_PAGED_STEP_BYTES', 1 << 40)
+    page = (16, 16, 128)
+    pages = fa._paged_pages_per_step(page, jnp.bfloat16, 4096)
+    held = lambda n: fa._paged_step_vmem(   # noqa: E731
+        n, page, jnp.bfloat16, False, False)[1]
+    assert 1 < pages < 4096
+    assert held(pages) <= fa._VMEM_LIMIT < held(2 * pages)
+
+
+def test_decode_paged_grid_counts_pages_and_steps():
+    """What the engine hangs on its ``serve_decode`` span: the pages
+    the kernel's copies fetch (a row's live pages, the window's in a
+    ring, one for a row of length 0 or 1) and the steps of its grid:
+    each row's live steps, none dead."""
+    fa = _fa
+    page = (16, 16, 128)
+    read, steps = fa.decode_paged_grid([1, 16, 17, 230, 1024], page,
+                                       jnp.bfloat16, 64)
+    assert fa._paged_pages_per_step(page, jnp.bfloat16, 64) == 8
+    assert read == 1 + 1 + 2 + 15 + 64
+    assert steps == 1 + 1 + 1 + 2 + 8     # live steps only
+    # a ring of 33 pages of 64 under a window of 2,048
+    ring = (4, 64, 128)
+    read, steps = fa.decode_paged_grid(
+        [1, 2048, 2049, 3000], ring, jnp.bfloat16, 33, window=2048,
+        head_major=True)
+    assert read == 1 + 32 + 33 + (2999 // 64 - 952 // 64 + 1)
+    assert steps == 1 + 4 + 5 + 5
+
+
 class TestChunkAttention:
     """Chunked prefill's attention: a C-token chunk attends causally
     within itself AND to ``ctx_len`` banked context tokens, merged

@@ -235,6 +235,34 @@ def test_profiler_session_switches_the_schedulers_spans_on(tmp_path):
     traced.check_records_inside_the_window()
 
 
+def test_decode_span_says_how_the_paged_kernels_grid_engaged():
+    """``serve_decode`` carries the pages the paged decode kernel's
+    copies fetched and the grid steps it took, over rows and layers,
+    from the lengths and the rule alone (``decode_pages_per_grid_step``
+    reads their ratio)."""
+    from chainermn_tpu import ops
+    eng, queue = _engine()
+    recorder = telemetry.enable()
+    request = queue.submit([1, 2, 3, 4, 5], 12)
+    while not request.done():
+        eng.step(queue)
+    decode = [r for r in recorder.events if r.get('type') == 'span'
+              and r['name'] == 'serve_decode']
+    assert len(decode) == 11
+    leaf = eng._cache_struct['k'][0]
+    for i, r in enumerate(decode):
+        # one live row of 6 + i positions on pages of 8, the bucket's
+        # other rows on the scratch page; one layer
+        lengths = [6 + i] + [1] * (r['bucket'] - 1)
+        assert r['kv_positions'] == 6 + i
+        assert (r['kv_pages_read'], r['kv_grid_steps']) == \
+            ops.decode_paged_grid(lengths, leaf.shape[1:], leaf.dtype,
+                                  eng.pages_per_seq)
+        assert r['kv_pages_read'] == -(-(6 + i) // 8) + r['bucket'] - 1
+        # every row's live pages fit one step of the rule's 8
+        assert r['kv_grid_steps'] == r['bucket']
+
+
 def test_with_neither_profiler_nor_recorder_nothing_is_recorded():
     """(b) The off path: ``NULL_SPAN``, no recorder installed, no
     record made -- through a trainer step and a scheduler tick."""

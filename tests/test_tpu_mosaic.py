@@ -92,6 +92,47 @@ def test_decode_slab_mosaic(int8):
     _close(out, ref, name='slab decode')
 
 
+# the two serving cells' paged decode calls (rows, pool, table width,
+# group, window), each with rows of length 1, ending mid-page and
+# mid-step, full, and (the ring) past the window
+_CELL_CALLS = {
+    'gpt2m_page_major': (32, (2049, 16, 16, 128), 64, 1, None, False),
+    'trinity_full': (64, (4097, 4, 64, 128), 64, 8, None, True),
+    'trinity_ring': (64, (2113, 4, 64, 128), 33, 8, 2048, True),
+}
+
+
+@pytest.mark.parametrize('case', sorted(_CELL_CALLS))
+def test_decode_paged_cells_mosaic(case):
+    """The kernel at the benchmark cells' exact shapes, several pages
+    a grid step by the rule, against the jnp twin on the device."""
+    import importlib
+    fa = importlib.import_module('chainermn_tpu.ops.flash_attention')
+    rows, pool, n_max, group, window, head_major = _CELL_CALLS[case]
+    ps = pool[2] if head_major else pool[1]
+    assert fa._paged_pages_per_step(
+        pool[1:], jnp.bfloat16, n_max, head_major=head_major) > 1
+    rng = np.random.RandomState(7)
+    top = n_max * ps if window is None else 4096
+    lengths = rng.randint(1, top + 1, rows)
+    lengths[:6] = [1, ps, ps + 3, 9 * ps + 5, top, top - 1]
+    heads = (pool[1] if head_major else pool[2]) * group
+    keys = jax.random.split(jax.random.PRNGKey(7), 3)
+    q = jax.random.normal(keys[0], (rows, heads, pool[-1]), jnp.bfloat16)
+    k = jax.random.normal(keys[1], pool, jnp.bfloat16)
+    v = jax.random.normal(keys[2], pool, jnp.bfloat16)
+    tables = jnp.asarray(np.stack([
+        1 + rng.permutation(pool[0] - 1)[:n_max] for _ in range(rows)]),
+        jnp.int32)
+    args = (q, k, v, tables, jnp.asarray(lengths, jnp.int32))
+    static = dict(scale=pool[-1] ** -0.5, group=group, window=window,
+                  head_major=head_major)
+    out = jax.jit(lambda *a: fa._decode_paged_pallas(*a, **static))(*args)
+    ref = jax.jit(lambda *a: fa._decode_paged_blockwise_jnp(
+        *a, **static))(*args)
+    _close(out, ref, name=case)
+
+
 @pytest.mark.parametrize('int8', [False, True], ids=['bf16', 'int8'])
 @pytest.mark.parametrize('page', [16, 128])
 def test_decode_paged_mosaic(page, int8):

@@ -28,17 +28,25 @@ def _tiny(seq_axis=None, sp_scheme='ring'):
 def _inlined(jaxpr, rename=None):
     """``jaxpr``'s equations with every nested ``jit`` call inlined
     and its variables renamed to the caller's, so that a consumer
-    inside ``jnp.take`` counts as a consumer of the outer value."""
+    inside ``jnp.take`` counts as a consumer of the outer value.  A
+    jitted function that every layer calls is ONE inner jaxpr with one
+    set of variables: each call's results get variables of their own,
+    so that a value inside one layer's call is not read by another's."""
     rename = {} if rename is None else rename
 
     def outer(v):
         return rename.get(id(v), v)
 
+    class Var:
+        def __init__(self, aval):
+            self.aval = aval
+
     class Eqn:
         def __init__(self, eqn):
             self.primitive = eqn.primitive
             self.invars = [outer(v) for v in eqn.invars]
-            self.outvars = list(eqn.outvars)
+            self.outvars = [Var(v.aval) for v in eqn.outvars]
+            rename.update(zip(map(id, eqn.outvars), self.outvars))
 
         def __repr__(self):
             return self.primitive.name
@@ -47,11 +55,12 @@ def _inlined(jaxpr, rename=None):
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == 'jit':
             inner = eqn.params['jaxpr'].jaxpr
+            inside = dict(rename)
             for v_in, v_out in zip(inner.invars, eqn.invars):
-                rename[id(v_in)] = outer(v_out)
-            out.extend(_inlined(inner, rename))
+                inside[id(v_in)] = outer(v_out)
+            out.extend(_inlined(inner, inside))
             for v_in, v_out in zip(inner.outvars, eqn.outvars):
-                rename[id(v_out)] = outer(v_in)
+                rename[id(v_out)] = inside.get(id(v_in), v_in)
         else:
             out.append(Eqn(eqn))
     return out
