@@ -7,8 +7,9 @@ against the unfused jnp oracle (``mha_reference``: materializes the
 fwd and fwd+bwd, across sequence lengths -- and, with ``--sweep``,
 times each of the three kernels alone over explicit tiles at the
 training cell's shapes, beside the tile the rule derives, and the
-paged decode kernel alone over pages a grid step at the two serving
-cells' shapes (``--sweep-paged`` for that half alone).  This
+paged decode kernel alone over pages a grid step at the three serving
+cells' shapes (``--sweep-paged`` for that half alone, ``--case=<part
+of a name>`` for some of its cases).  This
 quantifies the custom hot-path the reference delegates to hand-written
 native code (``/root/reference/chainermn/nccl/nccl.pyx:153-199``); here
 the native analogue is the Mosaic-compiled kernel.
@@ -28,6 +29,7 @@ Usage::
     python benchmarks/flash_attention_bench.py --sweep    # + tile sweep
     python benchmarks/flash_attention_bench.py --sweep-paged  # only the
                                           # paged decode kernel's sweep
+    python benchmarks/flash_attention_bench.py --sweep-paged --case=olmo
 
 Writes JSONL to ``benchmarks/results/flash_attention_<platform>.jsonl``
 (one line per measurement) and prints a summary table.
@@ -138,6 +140,7 @@ def main():
     cpu = '--cpu' in argv
     sweep = '--sweep' in argv
     sweep_paged_only = '--sweep-paged' in argv
+    cases = [a.split('=', 1)[1] for a in argv if a.startswith('--case=')]
     quick = '--quick' in argv or cpu
     if cpu:
         os.environ.setdefault(
@@ -187,7 +190,7 @@ def main():
         if sweep:
             sweep_tiles(record, cpu)
         if sweep or sweep_paged_only:
-            sweep_paged(record, cpu)
+            sweep_paged(record, cpu, cases)
         done = True
     finally:
         out_file.close()
@@ -331,7 +334,7 @@ def _cell_lengths(rows, prompt, output, seed=0):
             ).astype(np.int32) + 1
 
 
-# the two serving cells' paged decode calls: rows, the page pool, the
+# the serving cells' paged decode calls: rows, the page pool, the
 # table's width, the window and the traffic's prompts and outputs
 PAGED_CASES = {
     'gpt2m-serve-closed32': dict(
@@ -346,6 +349,14 @@ PAGED_CASES = {
         head_major=True, window=2048,
         prompt=(1024, 0.8, 128, 3072), output=(384, 0.6, 64, 1024)),
 }
+# ``olmo-hybrid-serve-closed48``: 30 K/V heads of 128 at group 1, the
+# same 48 x 4,096 positions in pages of 64, 32 (the cell's) and 16
+PAGED_CASES.update({
+    'olmo-hybrid-serve-closed48.page%d' % ps: dict(
+        rows=48, pool=(48 * 4096 // ps + 1, 30, ps, 128),
+        n_max=4096 // ps, head_major=True,
+        prompt=(1024, 0.8, 128, 3072), output=(384, 0.6, 64, 1024))
+    for ps in (64, 32, 16)})
 
 
 def paged_chain(case, lengths, pages, n, cpu=False):
@@ -389,14 +400,19 @@ def paged_chain(case, lengths, pages, n, cpu=False):
     return lambda: run(q).block_until_ready()
 
 
-def sweep_paged(record, cpu):
+def sweep_paged(record, cpu, only=()):
     """The paged decode kernel alone over pages a grid step, at the
-    two serving cells' shapes and a length mix like theirs, next to
+    serving cells' shapes (those whose name holds one of ``only``, or
+    all) and a length mix like theirs, next to
     what ``_paged_pages_per_step`` derives (PERF.md section 6, PR 30).
     ``gb_per_s`` counts the K and V pages a row's live positions touch,
     as the pool stores them.  The ``one`` rows give every sequence one
     live position, one page and one step: what a call costs before it
-    reads anything."""
+    reads anything.  A configuration that has not answered in three
+    minutes ends the process (rows are written as they come): on the
+    chip, 32 pages of 16 a step at 30 heads crashed the compiler and
+    its crash handler hung for the 45 minutes the call had left."""
+    import faulthandler
     import importlib
     import time
 
@@ -405,6 +421,8 @@ def sweep_paged(record, cpu):
     fa = importlib.import_module('chainermn_tpu.ops.flash_attention')
 
     for name, case in PAGED_CASES.items():
+        if only and not any(part in name for part in only):
+            continue
         if cpu:
             case = dict(case, rows=2, n_max=min(case['n_max'], 9),
                         pool=case['pool'][:-1] + (128,),
@@ -440,6 +458,7 @@ def sweep_paged(record, cpu):
                        'pages_read': read,
                        'grid_steps': int((-(-live // pages)).sum()),
                        'derived': pages == derived}
+                faulthandler.dump_traceback_later(180, exit=True)
                 try:
                     once = paged_chain(case, lengths, pages, n, cpu)
                     once()                         # compile, warm
@@ -452,6 +471,7 @@ def sweep_paged(record, cpu):
                     row['gb_per_s'] = read * page_bytes / (best / n) / 1e9
                 except Exception as e:  # Mosaic lowering limits
                     row['error'] = str(e)[-300:]
+                faulthandler.cancel_dump_traceback_later()
                 record(row)
 
 

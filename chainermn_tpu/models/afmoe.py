@@ -125,6 +125,11 @@ class AfmoeLM:
             return 0
         return -(-self.sliding_window // int(page_size)) + 1
 
+    def has_state_row(self):
+        """Does a sequence hold a fixed-size state row beside its
+        pages: no recurrent layers, no."""
+        return False
+
     def param_shapes(self):
         """The parameter tree as shapes (names are the interface the
         plain reference's ``param_spec`` follows)."""

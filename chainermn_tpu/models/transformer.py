@@ -304,6 +304,12 @@ class TransformerLM(nn.Module):
         return 0
 
     @nn.nowrap
+    def has_state_row(self):
+        """Does a sequence hold a fixed-size state row beside its
+        pages: no recurrent layers, no."""
+        return False
+
+    @nn.nowrap
     def init_kv_cache(self, n_slots, max_len=None, int8_kv=False):
         return init_kv_cache(self, n_slots, max_len, int8_kv=int8_kv)
 

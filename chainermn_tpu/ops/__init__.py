@@ -31,3 +31,7 @@ from chainermn_tpu.ops.int8_matmul import (  # noqa
     dequant, dequant_matmul, dequant_matmul_reference)
 from chainermn_tpu.ops.grouped_matmul import (  # noqa
     dropless_experts, grouped_swiglu, grouped_swiglu_reference)
+from chainermn_tpu.ops.gated_delta import (  # noqa
+    causal_conv, causal_conv_step, conv_tail, gated_delta_reference,
+    gated_delta_rule, gated_delta_step, pack_state, pack_tail,
+    state_shape, tail_shape, unpack_state)

@@ -349,10 +349,12 @@ class _Slot:
     """Host-side state of one cache slot."""
 
     __slots__ = ('request', 'position', 'remaining', 'generated',
-                 't_last_token', 't_stage_end', 'pages', 'ring')
+                 't_last_token', 't_stage_end', 'pages', 'ring',
+                 'state_row')
 
     def __init__(self, request, position, remaining, first_token,
-                 t_now, t_stage_end=None, pages=None, ring=()):
+                 t_now, t_stage_end=None, pages=None, ring=(),
+                 state_row=0):
         self.request = request
         self.position = position          # next token's position
         self.remaining = remaining        # tokens still to generate
@@ -367,6 +369,9 @@ class _Slot:
         self.pages = pages
         # ... and its window layers' ring (a model with none: empty)
         self.ring = ring
+        # ... and its recurrent layers' state row (a model with
+        # none: 0, the scratch row)
+        self.state_row = state_row
 
 
 class _PrefillState:
@@ -375,14 +380,15 @@ class _PrefillState:
     scheduler tick, so a long prompt spends several ticks here before
     graduating to a :class:`_Slot`."""
 
-    __slots__ = ('request', 'pages', 'ring', 'pos', 'matched',
-                 'chunks', 't_pop', 't_stage_end')
+    __slots__ = ('request', 'pages', 'ring', 'state_row', 'pos',
+                 'matched', 'chunks', 't_pop', 't_stage_end')
 
     def __init__(self, request, pages, pos, matched, t_pop=None,
-                 t_stage_end=None):
+                 t_stage_end=None, state_row=0):
         self.request = request
         self.pages = pages       # page table so far (refs held)
         self.ring = []           # window layers' ring pages so far
+        self.state_row = state_row     # recurrent layers' row (whole)
         self.pos = pos           # next absolute position to prefill
         self.matched = matched   # prefix tokens reused from the index
         self.chunks = 0          # chunks dispatched so far
@@ -395,8 +401,9 @@ class GenerationEngine:
     The engine names no model family: the cache constructors and the
     prefill / decode / verify bodies are METHODS OF THE MODEL
     (``docs/serving.md``, "the model protocol"), which
-    :class:`~chainermn_tpu.models.TransformerLM` and
-    :class:`~chainermn_tpu.models.AfmoeLM` both have.
+    :class:`~chainermn_tpu.models.TransformerLM`,
+    :class:`~chainermn_tpu.models.AfmoeLM` and
+    :class:`~chainermn_tpu.models.OlmoHybridLM` all have.
 
     Args:
       model: the flax module (``tp_axis`` set when serving over
@@ -570,7 +577,14 @@ class GenerationEngine:
             self.window_pool = (
                 PagePool(1 + self.n_slots * self._ring, self.page_size)
                 if self._ring else None)
-            self._table_width = self.pages_per_seq + self._ring
+            # recurrent layers keep a fixed-size STATE a sequence,
+            # neither a page list nor a ring: one row of leaves of
+            # their own, held from admission to release; it rides the
+            # same operand, [full table | ring | state row]
+            self.state_pool = (PagePool(1 + self.n_slots, 1)
+                               if model.has_state_row() else None)
+            self._table_width = (self.pages_per_seq + self._ring
+                                 + (self.state_pool is not None))
         else:
             if n_pages is not None:
                 raise ValueError('n_pages requires paged=True')
@@ -579,6 +593,7 @@ class GenerationEngine:
             self.pool = None
             self._prefix_index = None
             self._ring, self._window, self.window_pool = 0, None, None
+            self.state_pool = None
             self._table_width = None
         # the GLOBAL cache is built unsharded (tp=1); specs shard it
         cache = self._new_cache(model)
@@ -588,6 +603,11 @@ class GenerationEngine:
         self._cache = jax.device_put(cache, self._cache_sharding())
         self._cache_struct, self._cache_sig = _struct_and_signature(
             cache)
+        # bytes of (one K/V page, one state row) over all layers: what
+        # the tick's cache-bytes attributes are counted in
+        self._cache_bytes = (
+            model.paged_cache_bytes(self._cache_struct)
+            if self.state_pool is not None else None)
 
         # -- speculative decoding: the draft twin ----------------------
         self.spec_tokens = int(spec_tokens)
@@ -755,10 +775,13 @@ class GenerationEngine:
         if not self.paged:
             return model.init_kv_cache(self.n_slots, self.max_len,
                                        int8_kv=self.int8_kv)
-        ring = ({'n_window_pages': self.window_pool.n_pages}
-                if self._ring else {})
+        extra = {}
+        if self._ring:
+            extra['n_window_pages'] = self.window_pool.n_pages
+        if self.state_pool is not None:
+            extra['n_state_rows'] = self.state_pool.n_pages
         return model.init_paged_kv_cache(
-            self.n_pages, self.page_size, int8_kv=self.int8_kv, **ring)
+            self.n_pages, self.page_size, int8_kv=self.int8_kv, **extra)
 
     def _cache_sharding(self):
         if self.plan is None:
@@ -1143,7 +1166,7 @@ class GenerationEngine:
                 doomed.append(sid)
         for sid in doomed:
             slot = self._slots.pop(sid)
-            self._release_pages(slot.pages, slot.ring)
+            self._release_pages(slot.pages, slot.ring, slot.state_row)
             self._free.append(sid)
             self.cancelled += 1
             slot.request.set_error(OverloadError(
@@ -1162,7 +1185,8 @@ class GenerationEngine:
                     if st.request.deadline is not None
                     and now > st.request.deadline]:
             state = self._prefilling.pop(sid)
-            self._release_pages(state.pages, state.ring)
+            self._release_pages(state.pages, state.ring,
+                                state.state_row)
             self._free.append(sid)
             self.cancelled += 1
             doomed.append(sid)
@@ -1178,12 +1202,14 @@ class GenerationEngine:
         return len(doomed)
 
     # -- paged-mode page accounting ------------------------------------
-    def _release_pages(self, pages, ring=()):
+    def _release_pages(self, pages, ring=(), state_row=0):
         if pages:
             for page in pages:
                 self.pool.release(page)
         for page in ring:
             self.window_pool.release(page)
+        if state_row:
+            self.state_pool.release(state_row)
 
     def _grow_ring(self, ring, last_page):
         """Window pages of a sequence whose newest position lies in
@@ -1204,22 +1230,25 @@ class GenerationEngine:
             page = self.pool.alloc()
         return page
 
-    def _table_array(self, pages, ring=(), out=None):
-        """One sequence's table operand, ``[full table | ring]``
-        (into ``out``, a zeroed row of the decode step's tables)."""
+    def _table_array(self, pages, ring=(), state_row=0, out=None):
+        """One sequence's table operand, ``[full table | ring | state
+        row]`` (into ``out``, a zeroed row of the decode step's
+        tables)."""
         table = (np.zeros((self._table_width,), np.int32)
                  if out is None else out)
         table[:len(pages)] = pages
         if ring:
             table[self.pages_per_seq:self.pages_per_seq + len(ring)] \
                 = ring
+        if state_row:
+            table[-1] = state_row
         return table
 
-    def _shed_paged(self, req, pages, where, ring=()):
+    def _shed_paged(self, req, pages, where, ring=(), state_row=0):
         """Typed shed when the page pool is exhausted (the paged twin
         of queue_full): pages retained so far go back, the client
         gets ``OverloadError(reason='kv_pages')``."""
-        self._release_pages(pages, ring)
+        self._release_pages(pages, ring, state_row)
         self.cancelled += 1
         record_shed('kv_pages', request_id=req.request_id,
                     queue_depth=self._last_queue_depth, where=where,
@@ -1401,9 +1430,12 @@ class GenerationEngine:
                         'serve_prefix_tokens_total',
                         help='prompt tokens served from banked '
                              'prefix pages').inc(matched)
+            # the state pool holds a row for every slot: never dry
             self._prefilling[sid] = _PrefillState(
                 req, pages, matched, matched, t_pop=t_pop,
-                t_stage_end=t_pop)
+                t_stage_end=t_pop,
+                state_row=(self.state_pool.alloc()
+                           if self.state_pool is not None else 0))
 
     def _prefill_tick(self, clock):
         """Advance every mid-prefill sequence by ONE chunk (SARATHI
@@ -1442,7 +1474,8 @@ class GenerationEngine:
                 st.pages.append(page)
             if dry:
                 del self._prefilling[sid]
-                self._shed_paged(req, st.pages, 'prefill', st.ring)
+                self._shed_paged(req, st.pages, 'prefill', st.ring,
+                                 st.state_row)
                 self._free.append(sid)
                 continue
             self._grow_ring(st.ring, last_page)
@@ -1453,7 +1486,8 @@ class GenerationEngine:
             args = (jnp.asarray(tokens),
                     jnp.asarray(n, jnp.int32),
                     jnp.asarray(st.pos, jnp.int32),
-                    jnp.asarray(self._table_array(st.pages, st.ring)))
+                    jnp.asarray(self._table_array(st.pages, st.ring,
+                                                  st.state_row)))
             self._guard_call(self._cache_sig, args)
             if rec is not None and st.chunks == 0:
                 t_c0 = rec.now()
@@ -1534,7 +1568,7 @@ class GenerationEngine:
             if self.eos_id is not None and tok == self.eos_id \
                     or req.max_new_tokens == 1:
                 req.set_result([tok])
-                self._release_pages(st.pages, st.ring)
+                self._release_pages(st.pages, st.ring, st.state_row)
                 self._free.append(sid)
                 if rec is not None:
                     rec.event('complete', kind='request',
@@ -1545,7 +1579,8 @@ class GenerationEngine:
                                      req.max_new_tokens - 1, tok,
                                      t_first,
                                      t_stage_end=t_first_tele,
-                                     pages=st.pages, ring=st.ring)
+                                     pages=st.pages, ring=st.ring,
+                                     state_row=st.state_row)
         return worked
 
     def _decode_operands(self):
@@ -1565,7 +1600,8 @@ class GenerationEngine:
                     if page is None:
                         del self._slots[sid]
                         self._shed_paged(slot.request, slot.pages,
-                                         'decode', slot.ring)
+                                         'decode', slot.ring,
+                                         slot.state_row)
                         self._free.append(sid)
                         break
                     slot.pages.append(page)
@@ -1606,7 +1642,7 @@ class GenerationEngine:
                 if sid is not None:
                     slot = self._slots[sid]
                     self._table_array(slot.pages, slot.ring,
-                                      out=tables[i])
+                                      slot.state_row, out=tables[i])
             args = (jnp.asarray(tokens), jnp.asarray(positions),
                     jnp.asarray(tables))
         elif bucket == self.n_slots:
@@ -1709,7 +1745,8 @@ class GenerationEngine:
                                   request_id=slot.request.request_id,
                                   tokens=len(slot.generated), slot=sid,
                                   **ident)
-                    self._release_pages(slot.pages, slot.ring)
+                    self._release_pages(slot.pages, slot.ring,
+                                        slot.state_row)
                     del self._slots[sid]
                     self._free.append(sid)
         self.decode_steps += 1
@@ -2016,6 +2053,15 @@ class GenerationEngine:
             if self._ring:
                 tick.set(full_pages_in_use=self.pool.in_use(),
                          window_pages_in_use=self.window_pool.in_use())
+            if self.state_pool is not None:
+                # what the sequences' cache is made of: state held by
+                # the row, K/V by the page
+                page_bytes, row_bytes = self._cache_bytes
+                rows = self.state_pool.in_use()
+                tick.set(state_rows_in_use=rows,
+                         state_bytes_in_use=rows * row_bytes,
+                         cache_bytes_in_use=rows * row_bytes
+                         + self.pool.in_use() * page_bytes)
         return worked
 
     def _tick(self, queue, clock):
@@ -2106,6 +2152,12 @@ class GenerationEngine:
                     self.window_pool.in_use() if self._ring else 0),
                 'peak_window_pages_in_use': (
                     self.window_pool.peak_in_use if self._ring else 0),
+                'state_rows_in_use': (
+                    self.state_pool.in_use()
+                    if self.state_pool is not None else 0),
+                'peak_state_rows_in_use': (
+                    self.state_pool.peak_in_use
+                    if self.state_pool is not None else 0),
             }
             if self._prefix_index is not None:
                 paged.update(

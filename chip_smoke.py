@@ -21,6 +21,11 @@ the widths ``bench.py`` uses, with random weights made from a seed:
   full layers, dropless experts) through the same engine, its served
   tokens held against the float32 forward; then the same pool check at
   the ``trinity-mini`` cell's shapes, both kinds of cache leaf;
+- *serve, olmo_hybrid*: a small ``OlmoHybridLM`` (gated-delta-rule
+  layers beside full attention, a recurrent state row a sequence)
+  through the same engine, against the float32 forward; then the pool
+  check at the ``olmo-hybrid-7b`` cell's shapes: K/V pools, state
+  leaves and convolution tails;
 - ``--chips 4`` runs ONLY the transformer step over four devices
   (data-parallel, then dp 2 x tp 2) and the one-device loss both are
   compared with.
@@ -408,6 +413,41 @@ def _serve_requests(engine, prompts, max_new, kernels, what):
     return streams
 
 
+def _served_gaps(model, engine, prompts, streams, width, what, held):
+    """Every served token of a frozen-dataclass family held against the
+    float32 kernel-free forward of the same weights: the gap by which
+    its logit lies below that forward's best (``held``: what the cache
+    held at its peak, for the line)."""
+    import jax
+    import jax.numpy as jnp
+
+    exact = dataclasses.replace(model, dtype=jnp.float32)
+    wide = jax.tree_util.tree_map(lambda x: x.astype(jnp.float32),
+                                  engine.params)
+    gaps = []
+    with kernel_free(), jax.default_matmul_precision('highest'):
+        forward = jax.jit(exact.apply)
+        for prompt, out in zip(prompts, streams):
+            row = np.zeros((1, width), np.int32)
+            seq = np.concatenate([prompt, out])
+            row[0, :len(seq)] = seq
+            at = np.arange(len(prompt) - 1, len(seq) - 1)
+            logits = np.asarray(forward(wide, jnp.asarray(row)))[0, at]
+            gaps.append(logits.max(-1)
+                        - logits[np.arange(len(at)), seq[at + 1]])
+    gaps = np.concatenate(gaps)
+    spread = float(np.std(logits))
+    say('%s: %d served tokens lie below the float32 forward\'s best '
+        'logit by at most %.4f, %.5f in the mean (logits spread %.3f); '
+        '%s' % (what, gaps.size, gaps.max(), gaps.mean(), spread, held))
+    require(np.all(np.isfinite(gaps)) and gaps.mean() < 0.1 * spread,
+            '%s: served tokens are %.5f below the float32 forward\'s '
+            'best in the mean, logits spreading %.3f'
+            % (what, gaps.mean(), spread))
+    return {'streams': streams, 'gap_widest': float(gaps.max()),
+            'gap_mean': float(gaps.mean())}
+
+
 def serve(d_model=512, n_heads=8, n_layers=6, d_ff=2048, vocab=32000,
           max_len=512, n_slots=32, max_prompt=128, max_new=32,
           n_requests=8, page_sizes=(16, 128), kernels='native'):
@@ -505,9 +545,12 @@ def serve(d_model=512, n_heads=8, n_layers=6, d_ff=2048, vocab=32000,
 #: result-producing HLO instructions that move no data of their own
 _PLUMBING = ('parameter', 'tuple', 'get-tuple-element', 'bitcast')
 _WRITES = ('scatter', 'dynamic-update-slice')
-#: ... and the Pallas call that is the write of a head-major pool
-#: (``ops.paged_kv_append``: the pools are its aliased outputs)
-_WRITE_KERNEL = 'paged_kv_append'
+#: ... and the Pallas calls that are the write of a head-major pool
+#: (``ops.paged_kv_append``), of a recurrent state leaf
+#: (``ops.gated_delta_step``) and of a convolution tail leaf
+#: (``ops.causal_conv_step``): the leaves are their aliased outputs
+_WRITE_KERNEL = ('paged_kv_append', 'gated_delta_step',
+                 'causal_conv_step')
 _HLO_DTYPE = {'bfloat16': 'bf16', 'float32': 'f32', 'int8': 's8'}
 
 
@@ -735,33 +778,104 @@ def serve_afmoe(hidden=512, heads=8, kv_heads=2, head_dim=128,
             % (what, stats['peak_window_pages_in_use'], n_slots,
                stats['window_ring']))
 
-    exact = dataclasses.replace(model, dtype=jnp.float32)
-    wide = jax.tree_util.tree_map(lambda x: x.astype(jnp.float32),
-                                  engine.params)
-    gaps = []
-    with kernel_free(), jax.default_matmul_precision('highest'):
-        forward = jax.jit(exact.apply)
-        for prompt, out in zip(prompts, streams):
-            row = np.zeros((1, max_prompt + max_new), np.int32)
-            seq = np.concatenate([prompt, out])
-            row[0, :len(seq)] = seq
-            at = np.arange(len(prompt) - 1, len(seq) - 1)
-            logits = np.asarray(forward(wide, jnp.asarray(row)))[0, at]
-            gaps.append(logits.max(-1)
-                        - logits[np.arange(len(at)), seq[at + 1]])
-    gaps = np.concatenate(gaps)
-    spread = float(np.std(logits))
-    say('%s: %d served tokens lie below the float32 forward\'s best '
-        'logit by at most %.4f, %.5f in the mean (logits spread %.3f); '
+    return _served_gaps(
+        model, engine, prompts, streams, max_prompt + max_new, what,
         '%d window pages at the peak in rings of %d'
-        % (what, gaps.size, gaps.max(), gaps.mean(), spread,
-           stats['peak_window_pages_in_use'], stats['window_ring']))
-    require(np.all(np.isfinite(gaps)) and gaps.mean() < 0.1 * spread,
-            '%s: served tokens are %.5f below the float32 forward\'s '
-            'best in the mean, logits spreading %.3f'
-            % (what, gaps.mean(), spread))
-    return {'streams': streams, 'gap_widest': float(gaps.max()),
-            'gap_mean': float(gaps.mean())}
+        % (stats['peak_window_pages_in_use'], stats['window_ring']))
+
+
+# ----------------------------------------------------------------------
+# the olmo_hybrid family
+
+#: ``OlmoHybridLM`` at the ``olmo-hybrid-7b`` cell's depth
+#: (``chipbench/configs/olmo-hybrid-7b.json``: two whole periods); the
+#: defaults are the published widths
+OLMO_HYBRID = dict(num_hidden_layers=8,
+                   layer_types=(('linear_attention',) * 3
+                                + ('full_attention',)) * 2)
+
+
+def serving_pool_check_olmo_hybrid(n_slots=48, max_prompt=3072,
+                                   max_len=4096, page_size=32,
+                                   prompt_bucket=1024, **shape):
+    """:func:`_pool_check` at the shapes of the benchmark's
+    ``olmo-hybrid-serve-closed48`` cell: K/V pools ``(6,145, 30, 32,
+    128)`` for the two full layers only, and per linear layer a state
+    leaf ``(49, 15, 96, 384)`` float32 (two heads side by side in the
+    lanes) and a tail leaf ``(49, 288, 128)`` (3 positions of 96 rows
+    of lanes).  Weights are zeros: nothing runs."""
+    import jax
+    import jax.numpy as jnp
+
+    from chainermn_tpu import serving
+    from chainermn_tpu.models import OlmoHybridLM
+    from chainermn_tpu.precision import Policy
+
+    model = OlmoHybridLM(**dict(OLMO_HYBRID, **shape))
+    params = jax.tree_util.tree_map(
+        lambda shape: jnp.zeros(shape, jnp.bfloat16),
+        model.param_shapes(), is_leaf=lambda x: isinstance(x, tuple))
+    engine = serving.GenerationEngine(
+        model, params, n_slots=n_slots, max_prompt_len=max_prompt,
+        max_len=max_len, paged=True, page_size=page_size,
+        prefix_sharing=False, policy=Policy.bf16())
+    return _pool_check(
+        engine, 'serve olmo_hybrid d%d/L%d %d slots, %d pages of %d, '
+        '%d state rows' % (model.hidden_size, model.num_hidden_layers,
+                           n_slots, engine.n_pages, page_size,
+                           engine.state_pool.n_pages), prompt_bucket)
+
+
+def serve_olmo_hybrid(hidden=512, heads=4, key_dim=96, value_dim=192,
+                      width=1024, vocab=4096, page_size=32, n_slots=8,
+                      max_prompt=256, max_len=512, max_new=48,
+                      n_requests=6, kernels='native'):
+    """A small ``OlmoHybridLM`` with the family's every mechanism (two
+    periods of three gated-delta-rule layers and a full one, the
+    published head sizes 96 / 192 / 128, the convolution) through
+    ``GenerationEngine`` + ``GenerationQueue``: prompts that do and do
+    not fill their bucket, slots and state rows reused, every served
+    token held against the float32 kernel-free forward of the same
+    weights."""
+    import jax
+    import jax.numpy as jnp
+
+    from chainermn_tpu import serving
+    from chainermn_tpu.models import OlmoHybridLM
+    from chainermn_tpu.precision import Policy
+
+    model = OlmoHybridLM(
+        vocab_size=vocab, hidden_size=hidden, intermediate_size=width,
+        num_attention_heads=heads, num_key_value_heads=heads,
+        linear_num_key_heads=heads, linear_num_value_heads=heads,
+        linear_key_head_dim=key_dim, linear_value_head_dim=value_dim,
+        max_position_embeddings=max_len, **OLMO_HYBRID)
+    params = model.init(jax.random.PRNGKey(SEED), jnp.bfloat16)
+    rng = np.random.RandomState(SEED)
+    # on and one over two chunks of the rule (64 at the default size)
+    lengths = [3, max_prompt // 4, max_prompt // 4 + 1, max_prompt] + list(
+        rng.randint(4, max_prompt + 1, size=n_requests - 4))
+    # twice the slots' worth of requests: every slot and row is reused
+    lengths = lengths + lengths[::-1] + lengths[:n_slots // 2]
+    prompts = [rng.randint(0, vocab, size=int(n)).astype(np.int32)
+               for n in lengths]
+    what = 'serve olmo_hybrid d%d/L8/V%d, %d heads of %d x %d' % (
+        hidden, vocab, heads, key_dim, value_dim)
+    engine = serving.GenerationEngine(
+        model, params, n_slots=n_slots, max_prompt_len=max_prompt,
+        max_len=max_len, paged=True, page_size=page_size,
+        prefix_sharing=False, policy=Policy.bf16())
+    streams = _serve_requests(engine, prompts, max_new, kernels, what)
+    stats = engine.stats()
+    require(stats['state_rows_in_use'] == 0
+            and 0 < stats['peak_state_rows_in_use'] <= n_slots,
+            '%s: %d state rows in use after the drain, %d at the peak '
+            'of %d slots' % (what, stats['state_rows_in_use'],
+                             stats['peak_state_rows_in_use'], n_slots))
+
+    return _served_gaps(
+        model, engine, prompts, streams, max_prompt + max_new, what,
+        '%d state rows at the peak' % stats['peak_state_rows_in_use'])
 
 
 # ----------------------------------------------------------------------
@@ -895,7 +1009,10 @@ def main(argv=None):
                       ('serve', serve),
                       ('serving_pool', serving_pool_check),
                       ('serve_afmoe', serve_afmoe),
-                      ('serving_pool_afmoe', serving_pool_check_afmoe)]
+                      ('serving_pool_afmoe', serving_pool_check_afmoe),
+                      ('serve_olmo_hybrid', serve_olmo_hybrid),
+                      ('serving_pool_olmo_hybrid',
+                       serving_pool_check_olmo_hybrid)]
         for phase, fn in phases:
             t0 = time.perf_counter()
             fn()

@@ -31,7 +31,8 @@ from chainermn_tpu import ops
 KERNEL_MODULES = [importlib.import_module('chainermn_tpu.ops.' + name)
                   for name in ('flash_attention', 'layer_norm',
                                'cross_entropy', 'batch_norm_act',
-                               'optimizer', 'grouped_matmul')]
+                               'optimizer', 'grouped_matmul',
+                               'gated_delta')]
 
 BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
 
@@ -84,7 +85,7 @@ def _bn_res(x, scale, bias, res):
 
 
 def _sgd_leaf(g, v):
-    return KERNEL_MODULES[-2]._leaf_update_pallas(g, v, 0.1, 0.9)
+    return KERNEL_MODULES[4]._leaf_update_pallas(g, v, 0.1, 0.9)
 
 
 def _decode_ring(q, k, v, tables, lengths):
@@ -151,7 +152,44 @@ def _page_major(dtype):
                                                  ((32,), I32)]
 
 
+# the olmo-hybrid-serve-closed48 cell's widths: 30 query on 30 K/V heads
+# of 128 (group 1, head-major pages of 32), 48 rows, and 30 heads of
+# 96 x 192 float32 state, two heads side by side in the lanes
+_Q48 = ((48, 30, 128), BF16)
+_STATE = [((49, 15, 96, 384), F32), ((48,), I32)] \
+    + [((48, 30, 96), BF16)] * 2 + [((48, 30, 192), BF16)] \
+    + [((48, 30), F32)] * 2
+
+
+def _decode_group1(q, k, v, tables, lengths):
+    return ops.flash_attention_decode_paged(
+        q, k, v, tables, lengths, group=1, head_major=True)
+
+
+def _delta_step(state, rows, q, k, v, g, beta):
+    return ops.gated_delta_step(state, rows, q, k, v, g, beta)[0]
+
+
+def _conv_step(tail, rows, x, w):
+    return ops.causal_conv_step(tail, rows, x, w)
+
+
 CASES = {
+    'decode_paged_full_group1_30heads_page64': (
+        _decode_group1, [_Q48] + [((3073, 30, 64, 128), BF16)] * 2
+        + [((48, 64), I32), ((48,), I32)]),
+    # ... and at the page size of the ``olmo-hybrid-7b`` cell: two
+    # pages a grid step by the rule
+    'decode_paged_full_group1_30heads_page32': (
+        _decode_group1, [_Q48] + [((6145, 30, 32, 128), BF16)] * 2
+        + [((48, 128), I32), ((48,), I32)]),
+    'paged_kv_append_48rows_30heads': (
+        _append, [((6145, 30, 32, 128), BF16)] * 2
+        + [((48, 30, 128), BF16)] * 2 + [((48,), I32)] * 2),
+    'gated_delta_step_48rows': (_delta_step, _STATE),
+    'causal_conv_step_48rows': (
+        _conv_step, [((49, 288, 128), BF16), ((48,), I32),
+                     ((48, 11520), BF16), ((4, 11520), BF16)]),
     'decode_paged_gpt2m_cell': (_decode_paged, [_Q32] + _page_major(BF16)),
     'decode_paged_gpt2m_cell_int8': (
         _decode_paged, [_Q32] + _page_major(I8)
@@ -214,12 +252,20 @@ CASES = {
 }
 
 
+#: the tail leaf is donated, as every executable of the engine donates
+#: its cache: ``causal_conv_step`` says that its aliased output lies in
+#: HBM, and this compiler aborts where the operand is its own copy of a
+#: parameter that was not given up
+DONATED = {'causal_conv_step_48rows': (0,)}
+
+
 @pytest.mark.parametrize('case', sorted(CASES))
 def test_kernel_compiles_for_v5e(case, one_chip, mosaic):
     fn, shapes = CASES[case]
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
             for s, d in shapes]
-    compiled = jax.jit(fn).lower(*args).compile()
+    compiled = jax.jit(fn, donate_argnums=DONATED.get(case, ())).lower(
+        *args).compile()
     assert 'tpu_custom_call' in compiled.as_text(), (
         '%s compiled without its Mosaic kernel' % case)
 
@@ -384,3 +430,74 @@ def test_afmoe_serving_executable_leaves_both_pools_in_place(
     assert memory.temp_size_in_bytes < 2113 * 4 * 64 * 128 * 2
     # attention, the append (decode) and the expert kernel are all in
     assert compiled.as_text().count('tpu_custom_call') >= 3
+
+
+@pytest.mark.parametrize('body', ['decode', 'prefill'])
+def test_olmo_hybrid_serving_executable_leaves_pools_and_state_in_place(
+        body, one_chip, mosaic):
+    """The ``olmo_hybrid`` serving executables at the widths of the
+    ``olmo-hybrid-7b`` cell (48 rows, 6,145 pages of 32, 49 state rows;
+    one period of its two: three linear layers and a full one),
+    compiled for the described chip: nothing makes a value of a K/V
+    pool's or a state leaf's shape besides the write
+    (``paged_kv_append``, ``gated_delta_step`` and ``causal_conv_step``
+    in decode, the page scatter and the row update in prefill), the
+    cache is held at its nominal bytes (the state's minor dim is 384
+    lanes: two heads side by side; a ``(.., 96, 192)`` leaf would hold
+    a third more), and a
+    linear layer owns no page."""
+    import os
+    import sys
+
+    from chainermn_tpu import models as M
+    from chainermn_tpu.serving.generate import GenerationEngine
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    import chip_smoke
+
+    model = M.OlmoHybridLM(num_hidden_layers=4)
+    params = jax.tree_util.tree_map(
+        lambda shape: jax.ShapeDtypeStruct(shape, BF16,
+                                           sharding=one_chip),
+        model.param_shapes(), is_leaf=lambda x: isinstance(x, tuple))
+    cache = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                       sharding=one_chip),
+        jax.eval_shape(lambda: model.init_paged_kv_cache(
+            6145, 32, n_state_rows=49)))
+    assert (len(cache['k']), len(cache['state']), len(cache['tail'])) \
+        == (1, 3, 3)
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, I32, sharding=one_chip)
+
+    def decode(p, c, tokens, positions, tables):
+        logits, c, counters = model.decode_step_paged(
+            p, c, tokens, positions, tables)
+        return GenerationEngine._sampled(logits, counters), c
+
+    def prefill(p, c, tokens, length, pos0, table):
+        logits, c, counters = model.prefill_paged(
+            p, c, tokens, length, table, pos0)
+        return GenerationEngine._sampled(logits, counters), c
+
+    fn, operands = {
+        'decode': (decode, (ints(48), ints(48), ints(48, 129))),
+        'prefill': (prefill, (ints(1, 1024), ints(), ints(), ints(129))),
+    }[body]
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *operands).compile()
+    leaves = jax.tree_util.tree_leaves(cache)
+    assert {leaf.shape for leaf in leaves} == {
+        (6145, 30, 32, 128), (49, 15, 96, 384), (49, 288, 128)}
+    # the 3.6 MB tails too: ``causal_conv_step`` states that its aliased
+    # output lies in HBM, or the compiler stages each leaf in VMEM whole
+    # around the kernel (a slice / copy pair each way, every call)
+    assert chip_smoke.pool_shaped(compiled.as_text(), leaves) == []
+    memory = compiled.memory_analysis()
+    nominal = sum(leaf.dtype.itemsize * leaf.size for leaf in leaves)
+    assert nominal <= memory.alias_size_in_bytes <= 1.01 * nominal
+    assert memory.temp_size_in_bytes < 6145 * 30 * 32 * 128 * 2
+    if body == 'decode':
+        # attention + the append, and two steps a linear layer
+        assert compiled.as_text().count('tpu_custom_call') >= 8

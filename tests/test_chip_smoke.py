@@ -125,6 +125,36 @@ def test_serving_pool_afmoe_phase_tiny():
             num_experts=8, num_experts_per_tok=2, sliding_window=8)
 
 
+TINY_OLMO = dict(hidden=64, heads=4, key_dim=32, value_dim=64, width=96,
+                 vocab=64, page_size=4)
+
+
+def test_serve_olmo_hybrid_phase_tiny(interpret):
+    out = chip_smoke.serve_olmo_hybrid(
+        n_slots=3, max_prompt=24, max_len=48, max_new=12, n_requests=5,
+        kernels='interpret', **TINY_OLMO)
+    # every slot and state row reused: 5 + 5 + 1 requests on 3 slots
+    assert len(out['streams']) == 11
+    assert all(len(s) == 12 for s in out['streams'])
+    assert out['gap_mean'] < 0.01
+
+
+def test_serving_pool_olmo_hybrid_phase_tiny():
+    """As ``test_serving_pool_afmoe_phase_tiny``: on the CPU the check
+    must bite (the jnp twin of the step gathers and scatters the state
+    leaf through pool-shaped values); the three kinds of leaf are named
+    first."""
+    with pytest.raises(chip_smoke.SmokeFailure,
+                       match='makes pool-shaped values'):
+        chip_smoke.serving_pool_check_olmo_hybrid(
+            n_slots=2, max_prompt=8, max_len=32, page_size=4,
+            prompt_bucket=8, vocab_size=64, hidden_size=64,
+            intermediate_size=96, num_attention_heads=4,
+            num_key_value_heads=4, linear_num_key_heads=4,
+            linear_num_value_heads=4, linear_key_head_dim=32,
+            linear_value_head_dim=64)
+
+
 def test_multichip_phase_on_four_virtual_devices(interpret):
     out = chip_smoke.train_multichip(n_devices=4, seq=16,
                                      global_batch=4, tp=2,

@@ -502,6 +502,11 @@ _PAGED_RULE = {
     'gpt2m_cell': (((16, 16, 128), jnp.bfloat16, 64, False, False), 8),
     'trinity_full': (((4, 64, 128), jnp.bfloat16, 64, False, True), 8),
     'trinity_ring': (((4, 64, 128), jnp.bfloat16, 33, False, True), 8),
+    # 30 K/V heads of 128: a page of 64 is 983 KB of K + V, one a step;
+    # the ``olmo-hybrid-7b`` cell's pages of 32 go two a step
+    'olmo_page64': (((30, 64, 128), jnp.bfloat16, 64, False, True), 1),
+    'olmo_cell_page32': (((30, 32, 128), jnp.bfloat16, 128, False, True),
+                         2),
     'one_page_wide_table': (((16, 16, 128), jnp.bfloat16, 1, False,
                              False), 1),
     'table_of_three': (((16, 16, 128), jnp.bfloat16, 3, False, False), 2),
