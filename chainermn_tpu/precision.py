@@ -30,8 +30,11 @@ the master dtype for free), imposes ``reduce_dtype`` on the
 communicator's ``allreduce_grad`` (every strategy inherits the
 cast/upcast plumbing from ``CommunicatorBase``), keeps BatchNorm
 statistics and metric averages in f32, and casts batches to compute
-dtype on the HOST (``concat_examples(dtype=...)``) so H2D traffic is
-halved too.
+dtype on the HOST so H2D traffic is halved too: ``concat_examples(
+dtype=...)`` allocates each floating column at compute dtype and the
+cast happens in the assignment that writes an example into its row --
+one pass, no float32 batch in between, bit for bit what ``astype`` of
+one would give.
 
 bf16 shares f32's exponent range, so ``Policy.bf16()`` needs no loss
 scaling.  ``Policy.f16()`` pairs the narrow-exponent float16 with

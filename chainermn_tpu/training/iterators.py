@@ -345,6 +345,12 @@ class DevicePrefetchIterator(_PrefetchingIterator):
     ``__next__`` hands the train loop arrays that are already on (or
     in flight to) the chips while the previous step executes.
 
+    The worker is ONE thread and stays one; what it calls is not:
+    ``shard_batch``'s collate (``training/convert.py``) splits the
+    rows of a large column over a small pool, so a 77 MB image batch
+    costs this thread ~35 ms on a TPU v5e host and a 64 KB token
+    batch, written by this thread alone, well under one.
+
     This is the device-side half of the input pipeline
     (:class:`MultiprocessIterator` is the host-side half; they
     compose: wrap one in the other -- ``finalize`` propagates).  On

@@ -51,7 +51,7 @@ from chainermn_tpu import telemetry as _telemetry
 from chainermn_tpu.parallel import zero as zero_helpers
 from chainermn_tpu.parallel.pipeline import (
     Pipeline, assert_collective_free, microbatch, pipeline_1f1b_grads)
-from chainermn_tpu.training.convert import concat_examples
+from chainermn_tpu.training.convert import collate
 from chainermn_tpu.training.placement import owned_device_put
 
 
@@ -212,8 +212,10 @@ class PipelineUpdater:
         ``compute_dtype`` inside the differentiated stage/loss/
         prologue bodies, so gradient cotangents upcast to the master
         dtype at the cast boundary; batches are cast host-side in
-        :meth:`shard_batch`; loss and metrics are pinned to f32
-        before their cross-stage psums.  ``reduce_dtype`` narrows the
+        :meth:`shard_batch`, in the one pass that collates them (each
+        floating column written at compute dtype); loss and metrics
+        are pinned to f32 before their cross-stage psums.
+        ``reduce_dtype`` narrows the
         1f1b schedule's explicit data-axis gradient pmean
         (cast-before, upcast-after); the gpipe schedule's data-axis
         reduction lives inside the shard_map transpose and runs at
@@ -796,12 +798,15 @@ class PipelineUpdater:
         Dict examples flatten in INSERTION order -- the positional
         (x, y) contract of the train step follows that order (same
         convention as ``StandardUpdater.shard_batch``, including the
-        host-side compute-dtype cast under a policy)."""
+        one-pass collate that writes floating columns at compute dtype
+        on the host under a policy, and the ``collate_workers`` /
+        ``collate_bytes`` attributes of the span)."""
         with _telemetry.span('host_batch_prep', kind='host',
-                             iteration=self.iteration):
-            arrays = concat_examples(
+                             iteration=self.iteration) as span:
+            arrays, workers, nbytes = collate(
                 batch, dtype=(self._policy.compute_dtype
                               if self._policy is not None else None))
+            span.set(collate_workers=workers, collate_bytes=nbytes)
             if isinstance(arrays, dict):
                 arrays = tuple(arrays.values())
         data_sharding = NamedSharding(self.mesh, P(self._axis_data))

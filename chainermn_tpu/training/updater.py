@@ -19,7 +19,7 @@ import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from chainermn_tpu import telemetry as _telemetry
-from chainermn_tpu.training.convert import concat_examples
+from chainermn_tpu.training.convert import collate
 from chainermn_tpu.utils import chaos as _chaos
 
 
@@ -121,7 +121,10 @@ class StandardUpdater:
         ahead, so host input work and the host->device transfer
         overlap the running step instead of serializing between
         steps (pair with ``update(sync=False)`` /
-        ``Trainer(async_metrics=True)`` for a gap-free device).
+        ``Trainer(async_metrics=True)`` for a gap-free device).  The
+        collation it runs is ONE pass (``training/convert.py``): each
+        column allocated once at the dtype it ships at, the rows of a
+        large one written by a few pool threads beside the producer.
 
         ``policy`` (a :class:`chainermn_tpu.precision.Policy`, e.g.
         ``Policy.bf16()``): mixed-precision training with master
@@ -132,8 +135,10 @@ class StandardUpdater:
         optimizer update.  The policy's ``reduce_dtype`` is imposed on
         the communicator's ``allreduce_grad`` (or on the ZeRO
         reduce-scatter, subsuming ``zero_reduce_dtype``), batches are
-        cast to compute dtype on the HOST in :meth:`shard_batch`
-        (halved H2D traffic; the prefetch iterator inherits this), and
+        cast to compute dtype on the HOST in :meth:`shard_batch`, in
+        the very assignment that collates them (no float32 batch is
+        ever built; halved H2D traffic; the prefetch iterator inherits
+        this), and
         BatchNorm statistics plus metric averages are pinned to f32.
         A policy with a ``loss_scale`` (``Policy.f16()``) scales the
         loss before the backward pass, unscales gradients before the
@@ -584,13 +589,17 @@ class StandardUpdater:
     @_held_weakly
     def shard_batch(self, batch):
         """Collate a list of examples and place it sharded on the mesh
-        (under a policy, floating columns are cast to compute dtype on
-        the HOST first, halving the host->device bytes)."""
+        (under a policy, floating columns are written at compute dtype
+        on the HOST, in the one pass that collates them, halving the
+        host->device bytes).  The ``host_batch_prep`` span says how
+        wide the collate ran (``collate_workers``) and over how many
+        shipped bytes (``collate_bytes``)."""
         with _telemetry.span('host_batch_prep', kind='host',
-                             iteration=self.iteration):
-            arrays = concat_examples(
+                             iteration=self.iteration) as span:
+            arrays, workers, nbytes = collate(
                 batch, dtype=(self._policy.compute_dtype
                               if self._policy is not None else None))
+            span.set(collate_workers=workers, collate_bytes=nbytes)
             if isinstance(arrays, dict):
                 arrays = tuple(arrays.values())
             if _chaos._active is not None:  # nan_batch fault injection

@@ -119,6 +119,24 @@ def test_concat_examples_dtype_casts_floats_only():
     assert np.issubdtype(cols[1].dtype, np.integer)
 
 
+@pytest.mark.parametrize('form', ['examples', 'collated'])
+@pytest.mark.parametrize('dtype', ['bfloat16', 'float16'])
+def test_collate_cast_rounds_exactly_as_astype(dtype, form):
+    """The cast that happens in the collating assignment is the one
+    ``astype`` makes, on EVERY float32 pattern: ties to even, overflow
+    to inf, subnormals, signed zeros, NaNs."""
+    rng = np.random.RandomState(11)
+    bits = rng.randint(0, 2 ** 32, size=(16, 257), dtype=np.uint64)
+    x = bits.astype(np.uint32).view(np.float32)
+    x[0, :6] = [np.inf, -np.inf, np.nan, -0.0, 1e-45, 65520.0]
+    batch = (x,) if form == 'collated' else [(row,) for row in x]
+    with np.errstate(over='ignore', invalid='ignore'):
+        got, = concat_examples(batch, dtype=dtype)
+        want = x.astype(dtype)
+    assert got.dtype == np.dtype(dtype)
+    assert got.tobytes() == want.tobytes()
+
+
 # ------------------------------------------- strategy reduce dtype
 @pytest.mark.parametrize('strategy', sorted(_COMMUNICATORS))
 def test_reduce_dtype_round_trips_every_strategy(strategy):
@@ -195,9 +213,13 @@ def test_bf16_policy_loss_matches_f32_on_mlp():
     assert abf[0].dtype == jnp.bfloat16  # host-side compute cast
     assert a32[0].dtype == jnp.float32
     for _ in range(20):
-        l32 = u32.update_core(a32)['loss']
-        lbf = ubf.update_core(abf)['loss']
-    l32, lbf = float(l32), float(lbf)
+        # each step read back before the other updater's is dispatched:
+        # two eight-device programs in flight at once can starve each
+        # other's all-reduce of threads on the CPU backend (a 40 s
+        # rendezvous timeout, then abort: a third of runs in which
+        # test_training.py had run first in the process)
+        l32 = float(u32.update_core(a32)['loss'])
+        lbf = float(ubf.update_core(abf)['loss'])
     assert lbf == pytest.approx(l32, rel=5e-2)
     # master weights stayed f32
     for leaf in jax.tree_util.tree_leaves(ubf.params):

@@ -90,6 +90,252 @@ def test_concat_examples_padding():
     np.testing.assert_array_equal(mask, [1, 1, 0, 0])
 
 
+# ------------------------------------------------ one-pass collation
+def _two_pass_collate(batch, padding, dtype):
+    """The oracle: ``np.stack``, then ``astype`` of the floating
+    columns, then ``np.pad`` -- the passes the one-pass collate
+    replaced.  Returns the columns as a list (dict values in order)."""
+    first = batch[0]
+    if isinstance(batch, tuple):
+        cols = list(batch)
+    elif isinstance(first, tuple):
+        cols = [np.stack([np.asarray(b[i]) for b in batch])
+                for i in range(len(first))]
+    elif isinstance(first, dict):
+        cols = [np.stack([np.asarray(b[k]) for b in batch])
+                for k in first]
+    else:
+        cols = [np.stack([np.asarray(b) for b in batch])]
+    if dtype is not None:
+        cols = [c.astype(dtype) if np.issubdtype(c.dtype, np.floating)
+                else c for c in cols]
+    if padding is not None:
+        pad_to, fill = padding
+        n = len(batch)
+        cols = [np.pad(c, [(0, pad_to - n)] + [(0, 0)] * (c.ndim - 1),
+                       constant_values=fill) for c in cols]
+        mask = np.zeros((pad_to,), np.float32)
+        mask[:n] = 1.0
+        cols.append(mask)
+    return cols
+
+
+def _collate_examples(form, n=6):
+    """Examples with a non-contiguous float32 image, a 0-d int32
+    label and a contiguous float32 vector, in the asked form."""
+    rng = np.random.RandomState(7)
+    wide = rng.randn(n, 5, 8, 3).astype(np.float32) * 300.0
+    images = [wide[i, :, ::2] for i in range(n)]      # strided views
+    assert not images[0].flags['C_CONTIGUOUS']
+    labels = [np.int32(v) for v in rng.randint(0, 9, n)]
+    vecs = [rng.rand(4).astype(np.float32) for _ in range(n)]
+    if form == 'tuple':
+        return list(zip(images, labels, vecs))
+    if form == 'dict':
+        return [{'x': x, 'y': y, 'v': v}
+                for x, y, v in zip(images, labels, vecs)]
+    if form == 'bare':
+        return images
+    assert form == 'collated'
+    return (np.stack(images), np.asarray(labels), np.stack(vecs))
+
+
+@pytest.fixture
+def pooled_collate(monkeypatch):
+    """Every column of more than a few bytes takes the pool: the
+    threshold is a module constant (no argument), patched here."""
+    from chainermn_tpu.training import convert
+    monkeypatch.setattr(convert, '_TASK_MIN_BYTES', 8)
+    monkeypatch.setattr(convert, '_CORES', 8)
+
+
+@pytest.mark.parametrize('pooled', [False, True])
+@pytest.mark.parametrize('padding', [None, (9, -1.5)])
+@pytest.mark.parametrize('dtype', ['bfloat16', 'float16', None])
+@pytest.mark.parametrize('form', ['tuple', 'dict', 'bare', 'collated'])
+def test_collate_one_pass_matches_stack_then_astype(
+        form, dtype, padding, pooled, request):
+    """Bit for bit what ``np.stack`` -> ``astype`` -> ``np.pad`` gave,
+    whoever writes the rows; integer labels and the float32 mask are
+    untouched."""
+    from chainermn_tpu.training import convert
+    if pooled:
+        request.getfixturevalue('pooled_collate')
+    batch = _collate_examples(form)
+    if form == 'collated' and padding is not None:
+        with pytest.raises(ValueError, match='pre-collated'):
+            concat_examples(batch, padding=padding, dtype=dtype)
+        return
+    want = _two_pass_collate(batch, padding, dtype)
+    got, workers, nbytes = convert.collate(batch, padding=padding,
+                                           dtype=dtype)
+    if form == 'dict':
+        assert list(got) == ['x', 'y', 'v'] + (
+            ['mask'] if padding else [])
+        got = list(got.values())
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        assert g.tobytes() == w.tobytes()
+    assert nbytes == sum(w.nbytes for w in want)
+    casts = dtype is not None
+    assert (workers > 1) == (pooled and (form != 'collated' or casts))
+    if form != 'bare':
+        assert got[1].dtype == np.int32
+    if form == 'collated' and not casts:
+        assert all(g is b for g, b in zip(got, batch))
+
+
+@pytest.mark.parametrize('case', [
+    'python_scalars', 'zero_d_only', 'mixed_dtypes', 'transposed',
+    'single_example', 'pad_to_equals_n'])
+def test_collate_edge_examples(case):
+    rng = np.random.RandomState(3)
+    padding = None
+    if case == 'python_scalars':
+        batch = [(rng.rand(3).astype(np.float32), 1, 2.5),
+                 (rng.rand(3).astype(np.float32), 2, 3.5)]
+    elif case == 'zero_d_only':
+        batch = [np.float32(v) for v in rng.rand(5)]
+    elif case == 'mixed_dtypes':    # np.stack promotes: so do we
+        batch = [(np.int32(1),), (np.float64(2.5),), (np.int8(3),)]
+    elif case == 'transposed':
+        batch = [rng.rand(4, 6).astype(np.float32).T for _ in range(3)]
+    elif case == 'single_example':
+        batch = [(rng.rand(2, 2).astype(np.float32), np.int32(4))]
+    else:
+        batch = [(rng.rand(3).astype(np.float32), i) for i in range(4)]
+        padding = (4, 0)
+    for dtype in (None, 'bfloat16'):
+        want = _two_pass_collate(batch, padding, dtype)
+        got = concat_examples(batch, padding=padding, dtype=dtype)
+        assert len(got) == len(want)
+        for g, w in zip(got, want):
+            assert g.dtype == w.dtype and g.shape == w.shape
+            assert g.tobytes() == w.tobytes()
+
+
+@pytest.mark.parametrize('pooled', [False, True])
+@pytest.mark.parametrize('bad', [1, 5])
+def test_collate_ragged_batch_names_the_index(bad, pooled, request):
+    if pooled:
+        request.getfixturevalue('pooled_collate')
+    batch = [(np.zeros((4, 3), np.float32), i) for i in range(6)]
+    batch[bad] = (np.zeros((4, 2), np.float32), bad)
+    with pytest.raises(ValueError, match='example %d ' % bad):
+        concat_examples(batch)
+    with pytest.raises(ValueError, match='same shape'):
+        np.stack([b[0] for b in batch])     # what it replaced raised
+
+
+def test_collate_errors_kept():
+    with pytest.raises(ValueError, match='empty'):
+        concat_examples([])
+    with pytest.raises(ValueError, match='pad_to 1 < batch size 2'):
+        concat_examples([np.zeros(2), np.zeros(2)], padding=(1, 0))
+
+
+def test_collate_worker_exception_reaches_the_caller(
+        pooled_collate, monkeypatch):
+    from chainermn_tpu.training import convert
+    write_rows = convert._write_rows
+
+    def failing(out, src, lo, hi):
+        if lo == 0:          # the first range is a pool worker's
+            raise RuntimeError('rows %d..%d' % (lo, hi))
+        write_rows(out, src, lo, hi)
+
+    monkeypatch.setattr(convert, '_write_rows', failing)
+    with pytest.raises(RuntimeError, match='rows 0'):
+        concat_examples(_collate_examples('tuple'))
+
+
+@pytest.mark.parametrize('pooled', [False, True])
+def test_shard_batch_span_says_how_wide_the_collate_was(
+        pooled, request):
+    """``cmn:host_batch_prep`` carries ``collate_workers`` (1: the
+    caller alone, as every small batch must be) and
+    ``collate_bytes``."""
+    from chainermn_tpu import telemetry
+    if pooled:
+        request.getfixturevalue('pooled_collate')
+    upd = _prefetch_updater(0)
+    batch = [upd.iterator.dataset[i] for i in range(32)]
+    telemetry.disable()
+    rec = telemetry.enable()  # in-memory
+    try:
+        upd.shard_batch(batch)
+        spans = [r for r in rec.events
+                 if r.get('name') == 'host_batch_prep']
+    finally:
+        telemetry.disable()
+    assert len(spans) == 1
+    assert spans[0]['collate_bytes'] == 32 * 8 * 4 + 32 * 4
+    if pooled:
+        assert 1 < spans[0]['collate_workers'] <= 4
+    else:
+        assert spans[0]['collate_workers'] == 1
+
+
+def test_collate_width_follows_bytes_rows_and_cores(monkeypatch):
+    """No argument sets the width: it is the column's bytes over what
+    a worker must have to itself, under half the cores, 8 and the
+    rows.  The LM cells' int32 columns (8 and 32 rows of 1,024) stay
+    with the caller."""
+    from chainermn_tpu.training import convert
+    monkeypatch.setattr(convert, '_CORES', 13)
+    task = convert._TASK_MIN_BYTES
+    assert convert._workers_for(8 * 1024 * 4, 8) == 1
+    assert convert._workers_for(32 * 1024 * 4, 32) == 1
+    assert convert._workers_for(2 * task - 1, 256) == 1
+    assert convert._workers_for(2 * task, 256) == 2
+    assert convert._workers_for(256 * 224 * 224 * 3 * 2, 256) == 6
+    assert convert._workers_for(1 << 30, 3) == 3
+    monkeypatch.setattr(convert, '_CORES', 64)
+    assert convert._workers_for(1 << 30, 256) == 8
+    monkeypatch.setattr(convert, '_CORES', 1)
+    assert convert._workers_for(1 << 30, 256) == 1
+
+
+def test_two_threads_collating_at_once_get_their_own_batches(
+        pooled_collate):
+    """``DevicePrefetchIterator``'s thread and a caller's own may be
+    in ``shard_batch`` together: the pool is shared, the buffers are
+    not."""
+    import sys
+    import threading
+    upd = _prefetch_updater(0)
+    ds = upd.iterator.dataset
+    batches = [[ds[i] for i in range(32)],
+               [ds[i] for i in range(32, 64)]]
+    want = [_two_pass_collate(b, None, None) for b in batches]
+    wrong, errors = [], []
+
+    def hammer(k):
+        try:
+            for _ in range(40):
+                got = upd.shard_batch(batches[k])
+                for g, w in zip(got, want[k]):
+                    if not np.array_equal(np.asarray(g), w):
+                        wrong.append(k)
+        except Exception as e:  # noqa: BLE001 - reported below
+            errors.append(e)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=hammer, args=(k % 2,))
+                   for k in range(4)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert not errors and not wrong
+
+
 def test_serializers_roundtrip(tmp_path):
     tree = {'a': jnp.arange(6.).reshape(2, 3),
             'nested': {'b': jnp.ones((4,), jnp.bfloat16)}, 'step': 7}
@@ -311,6 +557,10 @@ def test_dropped_updater_frees_its_state_without_a_collection(
         finalize = getattr(upd.iterator, 'finalize', None)
         if finalize is not None:
             finalize()
+            # finalize() raises the stop flag and does not wait: on a
+            # busy machine the producer can still be inside
+            # shard_batch, its frame holding the updater
+            upd.iterator._thread.join(timeout=30)
         del upd, finalize
         assert me() is None
         assert all(ref() is None for ref in state)
@@ -458,11 +708,15 @@ def _prefetch_updater(device_prefetch):
         device_prefetch=device_prefetch)
 
 
-def test_device_prefetch_matches_unprefetched():
+@pytest.mark.parametrize('pooled', [False, True])
+def test_device_prefetch_matches_unprefetched(pooled, request):
     """device_prefetch=N must be a pure latency optimization: same
     batches in the same order, identical trajectory, and epoch
     accounting that reflects CONSUMED batches (not the worker's
-    read-ahead)."""
+    read-ahead) -- with the collate on the caller alone and with its
+    rows over the pool."""
+    if pooled:
+        request.getfixturevalue('pooled_collate')
     upd_ref = _prefetch_updater(0)
     upd_pre = _prefetch_updater(2)
     # worker reads ahead immediately; the consumer has taken nothing,
