@@ -40,6 +40,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from chainermn_tpu.models import _experts
+
 
 @dataclasses.dataclass(frozen=True)
 class AfmoeLM:
@@ -199,36 +201,15 @@ class AfmoeLM:
                                 x2 * cos + x1 * sin], -1).astype(x.dtype)
 
     def _swiglu(self, x, p):
-        dtype = self.dtype
-        gate = jnp.dot(x, p['w1'].astype(dtype))
-        return jnp.dot(jax.nn.silu(gate) * jnp.dot(
-            x, p['w3'].astype(dtype)), p['w2'].astype(dtype))
+        return _experts.swiglu(x, p, self.dtype)
 
     def _experts(self, m, lp):
         """The sparse feed-forward on rows ``m`` (T, d): returns it and
-        the layer's two counters (experts with a row; the fullest
-        expert's rows over the mean)."""
-        from chainermn_tpu import ops
-
-        k, e = self.num_experts_per_tok, self.num_experts
-        score = jax.nn.sigmoid(jnp.dot(
-            m.astype(jnp.float32), lp['router'].astype(jnp.float32),
-            precision=lax.Precision.HIGHEST))
-        # the stored bias steers WHICH experts, never their weight
-        _, chosen = lax.top_k(
-            score + lp['expert_bias'].astype(jnp.float32), k)
-        gate = jnp.take_along_axis(score, chosen, axis=1)
-        if self.route_norm:
-            gate = gate / (jnp.sum(gate, -1, keepdims=True) + 1e-20)
-        gate = gate * self.route_scale
-        experts = {name: w.astype(self.dtype)
-                   for name, w in lp['experts'].items()}
-        routed, sizes = ops.dropless_experts(m, experts, chosen, gate)
-        out = routed + self._swiglu(m, lp['shared']).astype(jnp.float32)
-        counters = (jnp.sum(sizes > 0).astype(jnp.float32),
-                    jnp.max(sizes).astype(jnp.float32)
-                    * (e / (m.shape[0] * k)))
-        return out.astype(self.dtype), counters
+        the layer's two counters (``models/_experts.py``, the body this
+        family shares with ``xing4``)."""
+        return _experts.sigmoid_routed_experts(
+            m, lp, self.num_experts_per_tok, self.route_norm,
+            self.route_scale, self.dtype)
 
     def _layer(self, layer, x, lp, positions, cache, attend):
         """One layer on ``x`` (..., d) at ``positions`` (...).

@@ -35,3 +35,5 @@ from chainermn_tpu.ops.gated_delta import (  # noqa
     causal_conv, causal_conv_step, conv_tail, gated_delta_reference,
     gated_delta_rule, gated_delta_step, pack_state, pack_tail,
     state_shape, tail_shape, unpack_state)
+from chainermn_tpu.ops.hyper_connection import (  # noqa
+    mhc_coefficients, mhc_coefficients_reference, mhc_rows)

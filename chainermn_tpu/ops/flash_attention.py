@@ -84,7 +84,7 @@ def _flash_vmem_bytes(kernel, block_q, block_k, d, itemsize):
     """A grid step's working set: the float32 score-tile temporaries,
     the double-buffered operand and result blocks, the scratch."""
     tile = _TILE_TEMPS * block_q * block_k * 4
-    lanes = max(d, _LANES)                  # a row pads to 128 lanes
+    lanes = -(-max(d, _LANES) // _LANES) * _LANES  # whole 128-lane rows
     n_q, n_k = {'fwd': (2, 2), 'dq': (3, 2), 'dkv': (2, 4)}[kernel]
     blocks = 2 * (n_q * block_q + n_k * block_k) * lanes * itemsize
     acc_rows = block_k if kernel == 'dkv' else block_q
@@ -290,12 +290,13 @@ def _fwd_pallas(q, k, v, causal, scale, kv_len, block_q, block_k,
     query block.  ``group`` query heads read one K/V head: row ``b``
     of the merged ``(B*H, T, D)`` queries takes its keys from row
     ``b // group`` of the ``(B*H/group, T, D)`` keys, in the index map,
-    so the repeat is never materialised."""
+    so the repeat is never materialised.  ``v`` may have a width of
+    its own (``dv``): the output and the accumulator take it."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, t_q, d = q.shape
-    t_kv = k.shape[1]
+    t_kv, dv = k.shape[1], v.shape[2]
     grid = (bh, t_q // block_q, t_kv // block_k)
 
     def kv_row(b):
@@ -330,23 +331,23 @@ def _fwd_pallas(q, k, v, causal, scale, kv_len, block_q, block_k,
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, block_k, d), kv_ix,
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, d), kv_ix,
+            pl.BlockSpec((1, block_k, dv), kv_ix,
                          memory_space=pltpu.VMEM),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0),
+            pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, t_q, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, t_q, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, 1, t_q), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANES), jnp.float32),  # m (replicated)
             pltpu.VMEM((block_q, _LANES), jnp.float32),  # l (replicated)
-            pltpu.VMEM((block_q, d), jnp.float32),       # acc
+            pltpu.VMEM((block_q, dv), jnp.float32),      # acc
         ],
         compiler_params=_compiler_params(),
         interpret=interpret_flag(),
@@ -359,11 +360,11 @@ def _fwd_blockwise_jnp(q, k, v, causal, scale, kv_len, block_k,
     """Fallback forward: same recurrence as the kernel, via lax.scan
     (grouped K/V heads are repeated here, a key block at a time)."""
     bh, t_q, d = q.shape
-    t_kv = k.shape[1]
+    t_kv, dv = k.shape[1], v.shape[2]
     qf = q.astype(jnp.float32) * scale
     n_blocks = t_kv // block_k
     kb = k.reshape(-1, n_blocks, block_k, d).astype(jnp.float32)
-    vb = v.reshape(-1, n_blocks, block_k, d).astype(jnp.float32)
+    vb = v.reshape(-1, n_blocks, block_k, dv).astype(jnp.float32)
 
     def body(carry, inp):
         m, l, acc = carry
@@ -389,7 +390,7 @@ def _fwd_blockwise_jnp(q, k, v, causal, scale, kv_len, block_k,
 
     m0 = jnp.full((bh, t_q), NEG_INF, jnp.float32)
     l0 = jnp.zeros((bh, t_q), jnp.float32)
-    acc0 = jnp.zeros((bh, t_q, d), jnp.float32)
+    acc0 = jnp.zeros((bh, t_q, dv), jnp.float32)
     (m, l, acc), _ = lax.scan(
         body, (m0, l0, acc0),
         (jnp.arange(n_blocks), jnp.swapaxes(kb, 0, 1),
@@ -670,7 +671,8 @@ def _flash_fwd(q, k, v, causal, scale, kv_len, blocks):
                                       _scan_block(blocks, k.shape[1]))
     else:
         block_q, block_k = blocks or _flash_blocks(
-            q.shape[1], k.shape[1], q.shape[2], q.dtype)
+            q.shape[1], k.shape[1], max(q.shape[2], v.shape[2]),
+            q.dtype)
         out, lse = _fwd_pallas(q, k, v, causal, scale, kv_len,
                                block_q, block_k)
     return out, (q, k, v, out, lse)
@@ -678,6 +680,11 @@ def _flash_fwd(q, k, v, causal, scale, kv_len, blocks):
 
 def _flash_bwd(causal, scale, kv_len, blocks, res, g):
     q, k, v, out, lse = res
+    if v.shape[2] != q.shape[2]:
+        raise NotImplementedError(
+            'flash_attention: no backward for a value width of its own '
+            '(q / k %d wide, v %d): the dQ and dK/dV kernels carry one '
+            'width' % (q.shape[2], v.shape[2]))
     if pallas_mode() == 'fallback':
         return _bwd_blockwise(q, k, v, out, lse, g, causal, scale,
                               kv_len, _scan_block(blocks, k.shape[1]))
@@ -984,7 +991,7 @@ def _decode_paged_blockwise_jnp(q, k, v, page_tables, lengths, scale,
 
     m0 = jnp.full((b, h), NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, h), jnp.float32)
-    acc0 = jnp.zeros((b, h, d), jnp.float32)
+    acc0 = jnp.zeros((b, h, v.shape[-1]), jnp.float32)
     (m, l, acc), _ = lax.scan(body, (m0, l0, acc0),
                               jnp.arange(n_max))
     l_safe = jnp.maximum(l, 1e-30)
@@ -1026,12 +1033,13 @@ def _vmem_bytes(shape, dtype):
             * (-(-shape[-1] // _LANES) * _LANES))
 
 
-def _paged_step_vmem(pages, page_shape, dtype, quantized, head_major):
+def _paged_step_vmem(pages, page_shape, dtype, quantized, head_major,
+                     shared=False):
     """``(fetched, held)`` bytes of a grid step that carries ``pages``
     pages: what its copies bring into VMEM (K and V, with their float32
-    scale tiles), and the two-slot scratch plus the float32 working
-    set of the compute."""
-    tile = 2 * _vmem_bytes(page_shape, dtype)
+    scale tiles; ONE leaf where keys and values share it), and the
+    two-slot scratch plus the float32 working set of the compute."""
+    tile = (1 if shared else 2) * _vmem_bytes(page_shape, dtype)
     if quantized:
         tile += 2 * _vmem_bytes(page_shape[:-1] + (1,), jnp.float32)
     # the head-major path feeds the MXU the pages as stored: its float32
@@ -1042,7 +1050,7 @@ def _paged_step_vmem(pages, page_shape, dtype, quantized, head_major):
 
 
 def _paged_pages_per_step(page_shape, dtype, n_max, quantized=False,
-                          head_major=False):
+                          head_major=False, shared=False):
     """How many pages one grid step of the paged decode kernel carries,
     from what the call can see: the largest power of two that is at
     most the table's width, whose step fetches at most
@@ -1050,13 +1058,14 @@ def _paged_pages_per_step(page_shape, dtype, n_max, quantized=False,
     working set inside ``_VMEM_LIMIT``.  1 where a page is that large
     already or the table is one page wide.  The head-major layout
     places a page at a sublane offset of the step's tile, so a page
-    size off the dtype's sublane tile is one page a step too."""
+    size off the dtype's sublane tile is one page a step too.
+    ``shared``: keys and values are one leaf, fetched once."""
     if head_major and page_shape[-2] % _sublanes(dtype):
         return 1
     pages = 1
     while pages * 2 <= n_max:
         fetched, held = _paged_step_vmem(pages * 2, page_shape, dtype,
-                                         quantized, head_major)
+                                         quantized, head_major, shared)
         if fetched > _PAGED_STEP_BYTES or held > _VMEM_LIMIT:
             break
         pages *= 2
@@ -1076,7 +1085,7 @@ def _paged_live(lengths, page_size, n_max, window, xp=jnp):
 
 
 def decode_paged_grid(lengths, page_shape, dtype, n_max, window=None,
-                      quantized=False, head_major=False):
+                      quantized=False, head_major=False, shared=False):
     """``(pages read, grid steps)`` of one paged decode call over rows
     of these ``lengths`` (host integers; the engine sends a padded row
     as length 1): what the kernel's copies fetch and how many steps
@@ -1084,7 +1093,7 @@ def decode_paged_grid(lengths, page_shape, dtype, n_max, window=None,
     the host, no device read."""
     import numpy as np
     pages = _paged_pages_per_step(page_shape, dtype, n_max, quantized,
-                                  head_major)
+                                  head_major, shared)
     ps = page_shape[-2] if head_major else page_shape[0]
     lengths = np.asarray(lengths, np.int64)    # noqa: shardlint (host)
     live = _paged_live(lengths, ps, n_max, window, xp=np)[1]
@@ -1092,9 +1101,9 @@ def decode_paged_grid(lengths, page_shape, dtype, n_max, window=None,
 
 
 def _decode_paged_kernel(table_ref, len_ref, row_ref, step_ref, q_ref,
-                         k_hbm, v_hbm, *refs, scale, page_size, pages,
-                         n_max, quantized, window=None,
-                         head_major=False):
+                         *refs, scale, page_size, pages, n_max,
+                         quantized, window=None, head_major=False,
+                         value_lanes=None):
     """One LIVE (sequence, step) pair of the flattened grid: the
     online-softmax update of ALL heads' single query rows against
     ``pages`` PAGES of the pool, which the kernel fetches itself.  The
@@ -1120,13 +1129,21 @@ def _decode_paged_kernel(table_ref, len_ref, row_ref, step_ref, q_ref,
     two batched MXU products a step, state (Hkv, G, 1) / (Hkv, G, D).
     ``window``: only the ``window`` positions before ``length`` are
     live, step ``s`` starts at LOGICAL page ``first + s * pages`` and
-    each page is addressed through the ring, ``(first + j) % n_max``."""
+    each page is addressed through the ring, ``(first + j) % n_max``.
+    ``value_lanes`` (head-major): there is ONE pool, a LATENT row a
+    position that all the group's heads share; scores run over the
+    whole row and the values are its first ``value_lanes`` lanes, so a
+    page is fetched once and read twice where it lies in VMEM."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    n_pools = 1 if value_lanes else 2
+    pools, refs = refs[:n_pools], refs[n_pools:]
     n = 2 * pages if quantized else 0      # a scale tile a page
     scales, refs = refs[:n], refs[n:]
-    o_ref, k_buf, v_buf, sem, m_ref, l_ref, acc_ref = refs
+    o_ref, bufs = refs[0], refs[1:1 + n_pools]
+    sem, m_ref, l_ref, acc_ref = refs[1 + n_pools:]
+    k_buf, v_buf = bufs[0], bufs[-1]       # the same slots when shared
     t = pl.program_id(0)
 
     def copies(at, act):
@@ -1142,8 +1159,7 @@ def _decode_paged_kernel(table_ref, len_ref, row_ref, step_ref, q_ref,
             page = table_ref[row, column]
             where = pl.ds(pl.multiple_of(p * page_size, page_size),
                           page_size)
-            for i, (pool, buf) in enumerate(((k_hbm, k_buf),
-                                             (v_hbm, v_buf))):
+            for i, (pool, buf) in enumerate(zip(pools, bufs)):
                 dst = (buf.at[slot, :, where] if head_major
                        else buf.at[slot, where])
                 getattr(pltpu.make_async_copy(
@@ -1188,6 +1204,8 @@ def _decode_paged_kernel(table_ref, len_ref, row_ref, step_ref, q_ref,
             q = q_ref[0]                               # (Hkv, G, D)
             k = k_buf[slot]                            # (Hkv, keys, D)
             v = v_buf[slot]
+            if value_lanes:
+                v = v[..., :value_lanes]
             # one MXU pass in the pool's own dtype, whatever the
             # process-wide default precision (Mosaic refuses 'highest'
             # on bfloat16 operands)
@@ -1239,7 +1257,7 @@ def _decode_paged_kernel(table_ref, len_ref, row_ref, step_ref, q_ref,
 def _decode_paged_pallas(q, k, v, page_tables, lengths, scale,
                          k_scale=None, v_scale=None, group=1,
                          window=None, head_major=False, pages=None,
-                         interpret=False):
+                         interpret=False, value_lanes=None):
     """``pages``: pages a grid step, for the sweep alone
     (``benchmarks/flash_attention_bench.py``); every caller leaves it
     to the rule.  ``interpret``: the caller's ``interpret_flag()``,
@@ -1251,17 +1269,19 @@ def _decode_paged_pallas(q, k, v, page_tables, lengths, scale,
     ps = k.shape[2] if head_major else k.shape[1]
     n_max = page_tables.shape[1]
     quantized = k_scale is not None
+    pools = (k,) if value_lanes else (k, v)
     if pages is None:
         pages = _paged_pages_per_step(k.shape[1:], k.dtype, n_max,
-                                      quantized, head_major)
+                                      quantized, head_major,
+                                      shared=bool(value_lanes))
     pad = -d % _LANES
     if pad:
         # Mosaic copies no slice of an array whose minor dim is off the
         # 128 lanes: a narrower pool is padded, a COPY of it every
         # call (the models keep theirs lane-wide; a zero lane adds
         # nothing to a product)
-        q, k, v = (jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, pad),))
-                   for x in (q, k, v))
+        q, *pools = (jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, pad),))
+                     for x in (q,) + pools)
         d += pad
     # the grid: the rows' live steps one after the other, step t being
     # step ``step_of[t]`` of row ``row_of[t]``
@@ -1305,40 +1325,46 @@ def _decode_paged_pallas(q, k, v, page_tables, lengths, scale,
     else:
         row, state = (1, h, d), (h,)
         tile = (2, pages * ps, h, d)
-    row_spec = pl.BlockSpec(
-        row, lambda t, tables, lens, rows, steps: (rows[t],) + (0,) * (
-            len(row) - 1))
+    d_out = value_lanes or d
+
+    def row_spec(width):
+        block = row[:-1] + (width,)
+        return pl.BlockSpec(
+            block, lambda t, tables, lens, rows, steps: (rows[t],) + (
+                0,) * (len(block) - 1))
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         # page_tables, lengths, row_of, step_of
         num_scalar_prefetch=4,
         grid=(ends[-1],),
-        in_specs=[row_spec,
-                  pl.BlockSpec(memory_space=pl.ANY),
-                  pl.BlockSpec(memory_space=pl.ANY)] + scale_specs,
-        out_specs=row_spec,
+        in_specs=[row_spec(d)] + [pl.BlockSpec(memory_space=pl.ANY)
+                                  for _ in pools] + scale_specs,
+        out_specs=row_spec(d_out),
         scratch_shapes=[
-            pltpu.VMEM(tile, k.dtype),                 # two slots of K
-            pltpu.VMEM(tile, v.dtype),                 # and of V
+            # two slots of K and of V (of the one leaf they share)
+            pltpu.VMEM(tile, pool.dtype) for pool in pools] + [
             pltpu.SemaphoreType.DMA((2, 2)),           # (slot, k / v)
             pltpu.VMEM(state + (1,), jnp.float32),     # m
             pltpu.VMEM(state + (1,), jnp.float32),     # l
-            pltpu.VMEM(state + (d,), jnp.float32),     # acc
+            pltpu.VMEM(state + (d_out,), jnp.float32),  # acc
         ],
     )
     out = pl.pallas_call(
         functools.partial(_decode_paged_kernel, scale=scale,
                           page_size=ps, pages=pages, n_max=n_max,
                           quantized=quantized, window=window,
-                          head_major=head_major),
+                          head_major=head_major, value_lanes=value_lanes),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q.shape[:-1] + (d_out,), q.dtype),
         # the slots and the prefetch run from one step into the next
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=('arbitrary',),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name='flash_attention_decode_paged',
-    )(page_tables, lengths, row_of, step_of, q, k, v, *scales)
+    )(page_tables, lengths, row_of, step_of, q, *pools, *scales)
+    if value_lanes:
+        return out.reshape(b, h, d_out)
     return out.reshape(b, h, d)[..., :d - pad]
 
 
@@ -1350,13 +1376,13 @@ def _decode_paged_pallas(q, k, v, page_tables, lengths, scale,
 _decode_paged_call = jax.jit(
     _decode_paged_pallas,
     static_argnames=('scale', 'group', 'window', 'head_major', 'pages',
-                     'interpret'))
+                     'interpret', 'value_lanes'))
 
 
 def flash_attention_decode_paged(q, k, v, page_tables, lengths,
                                  scale=None, k_scale=None,
                                  v_scale=None, group=1, window=None,
-                                 head_major=False):
+                                 head_major=False, value_lanes=None):
     """Single-token decode attention against a PAGED KV cache.
 
     q: (B, H, D) -- one query row per sequence; k/v:
@@ -1384,7 +1410,7 @@ def flash_attention_decode_paged(q, k, v, page_tables, lengths,
     :func:`chainermn_tpu.precision.quantize_kv`, dequantized per tile
     in VMEM exactly like the slot-cache kernel.
 
-    Three static arguments, all off by default (the call then lowers
+    Four static arguments, all off by default (the call then lowers
     to what it lowered to before they existed):
 
     ``group``: query heads per K/V head.  The pool holds
@@ -1401,6 +1427,12 @@ def flash_attention_decode_paged(q, k, v, page_tables, lengths,
     in column ``(p // page_size) % n_max_pages``.  The ring must hold
     the window from any offset: ``n_max_pages >= ceil(window /
     page_size) + 1``.
+    ``value_lanes``: LATENT attention (absorbed MLA).  ``v`` is None
+    and ``k`` the one head-major leaf (P, Hkv, page_size, D) of latent
+    rows; scores run over all ``D`` lanes of a row, the values are its
+    first ``value_lanes`` lanes, and the result is (B, H, value_lanes).
+    A page is fetched ONCE a step: as two leaves the same bytes would
+    cross HBM twice.
     """
     b, h, d = q.shape
     if k.ndim != 4:
@@ -1409,6 +1441,15 @@ def flash_attention_decode_paged(q, k, v, page_tables, lengths,
     if (k_scale is None) != (v_scale is None):
         raise ValueError('int8 KV decode needs BOTH k_scale and '
                          'v_scale (or neither)')
+    if (value_lanes is None) == (v is None):
+        raise ValueError('pass v, or value_lanes for a latent leaf '
+                         'whose rows hold keys and values (v=None)')
+    if value_lanes and (not head_major or value_lanes % _LANES
+                        or value_lanes > k.shape[-1]):
+        raise ValueError('a latent leaf is head-major and its values '
+                         'are whole 128-lane tiles of a row: '
+                         'value_lanes %r of %d' % (value_lanes,
+                                                   k.shape[-1]))
     if group != 1 and not head_major:
         raise ValueError('grouped K/V heads need the head-major pool '
                          '(head_major=True)')
@@ -1430,20 +1471,23 @@ def flash_attention_decode_paged(q, k, v, page_tables, lengths,
     lens = lengths.astype(jnp.int32)
     if pallas_mode() == 'fallback':
         return _decode_paged_blockwise_jnp(
-            q, k, v, tables, lens, scale, k_scale, v_scale, group, window,
-            head_major)
+            q, k, k[..., :value_lanes] if value_lanes else v, tables,
+            lens, scale, k_scale, v_scale, group, window, head_major)
     return _decode_paged_call(q, k, v, tables, lens, scale, k_scale,
                               v_scale, group, window, head_major,
-                              interpret=interpret_flag())
+                              interpret=interpret_flag(),
+                              value_lanes=value_lanes)
 
 
-def _kv_append_kernel(pages_ref, offsets_ref, k_new_ref, v_new_ref,
-                      k_ref, v_ref, k_out_ref, v_out_ref):
+def _kv_append_kernel(pages_ref, offsets_ref, *refs):
+    """``refs``: the new rows, the pools' pages and the pages out, one
+    of each a pool (K and V, or the one latent leaf)."""
     import jax.experimental.pallas as pl
 
     at = offsets_ref[pl.program_id(0)]
-    for new_ref, page_ref, out_ref in ((k_new_ref, k_ref, k_out_ref),
-                                       (v_new_ref, v_ref, v_out_ref)):
+    n = len(refs) // 3
+    for new_ref, page_ref, out_ref in zip(refs[:n], refs[n:2 * n],
+                                          refs[2 * n:]):
         page = page_ref[0]                             # (Hkv, ps, D)
         row = lax.broadcasted_iota(jnp.int32, page.shape, 1)
         out_ref[0] = jnp.where(row == at, new_ref[0], page)
@@ -1453,7 +1497,8 @@ def paged_kv_append(k, v, k_new, v_new, pages, offsets):
     """One token a sequence into a HEAD-MAJOR page pool, in place:
     ``k`` / ``v`` (P, Hkv, page_size, D), ``k_new`` / ``v_new``
     (B, Hkv, D), written at ``[pages[b], :, offsets[b]]``.  Returns
-    the two pools.
+    the two pools.  ``v`` and ``v_new`` None: ONE pool (a latent leaf,
+    keys and values in one row), returned with None beside it.
 
     Why a kernel: an XLA scatter whose update is a (Hkv, D) slab wants
     the slab contiguous, so on the chip it relays the whole pool out to
@@ -1463,31 +1508,35 @@ def paged_kv_append(k, v, k_new, v_new, pages, offsets):
     with a select; the pools are the call's aliased outputs, so nothing
     else of them moves.  Rows that share a page (idle rows on the
     scratch page) overwrite each other, as a scatter's would."""
+    pools = [(k, k_new)] + ([] if v is None else [(v, v_new)])
     if pallas_mode() == 'fallback':
-        return (k.at[pages, :, offsets].set(k_new.astype(k.dtype)),
-                v.at[pages, :, offsets].set(v_new.astype(v.dtype)))
+        out = [pool.at[pages, :, offsets].set(new.astype(pool.dtype))
+               for pool, new in pools]
+        return tuple(out + [None])[:2]
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, h_kv, d = k_new.shape
-    ps = k.shape[2]
+    ps, n = k.shape[2], len(pools)
     new = pl.BlockSpec((1, h_kv, 1, d), lambda i, p, o: (i, 0, 0, 0))
     page = pl.BlockSpec((1, h_kv, ps, d),
                         lambda i, p, o: (p[i], 0, 0, 0))
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _kv_append_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(b,),
-            in_specs=[new, new, page, page], out_specs=[page, page]),
-        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
-                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        # operands count the two prefetched scalars: k is 4, v is 5
-        input_output_aliases={4: 0, 5: 1},
+            in_specs=[new] * n + [page] * n, out_specs=[page] * n),
+        out_shape=[jax.ShapeDtypeStruct(pool.shape, pool.dtype)
+                   for pool, _ in pools],
+        # operands count the two prefetched scalars and the new rows:
+        # the first pool is operand 2 + n
+        input_output_aliases={2 + n + i: i for i in range(n)},
         interpret=interpret_flag(),
         name='paged_kv_append',
     )(pages.astype(jnp.int32), offsets.astype(jnp.int32),
-      k_new.astype(k.dtype)[:, :, None], v_new.astype(v.dtype)[:, :, None],
-      k, v)
+      *[new.astype(pool.dtype)[:, :, None] for pool, new in pools],
+      *[pool for pool, _ in pools])
+    return tuple(list(out) + [None])[:2]
 
 
 # ----------------------------------------------------------------------
@@ -1681,7 +1730,10 @@ def flash_attention_chunk(q, k_new, v_new, k_ctx, v_ctx, ctx_len,
 
 def flash_attention(q, k, v, causal=False, scale=None,
                     block_q=None, block_k=None, window=None):
-    """Fused attention. q: (B, Tq, H, D), k/v: (B, Tkv, Hkv, D).
+    """Fused attention. q: (B, Tq, H, D), k/v: (B, Tkv, Hkv, D);
+    ``v`` may be (B, Tkv, Hkv, Dv) with a width of its own (latent
+    attention's 192 / 128), the output is then (B, Tq, H, Dv):
+    forward-only, the backward refuses it by name.
 
     Sequence lengths are padded to kernel block multiples internally
     (padded keys are masked out; padded query rows are dropped); with
@@ -1729,7 +1781,7 @@ def flash_attention(q, k, v, causal=False, scale=None,
 
     def merge(x):
         # (B, T, H, D) -> (B*H, T, D)
-        return jnp.swapaxes(x, 1, 2).reshape(-1, x.shape[1], d)
+        return jnp.swapaxes(x, 1, 2).reshape(-1, x.shape[1], x.shape[3])
 
     qm, km, vm = merge(q), merge(k), merge(v)
     if pad_q:
@@ -1744,9 +1796,9 @@ def flash_attention(q, k, v, causal=False, scale=None,
             qm, km, vm, causal, scale, t_kv,
             _scan_block(blocks, km.shape[1]), group, window)
     else:
-        block_q, block_k = blocks or _flash_blocks(t_q, t_kv, d, q.dtype,
-                                                   window)
+        block_q, block_k = blocks or _flash_blocks(
+            t_q, t_kv, max(d, v.shape[3]), q.dtype, window)
         out, _ = _fwd_pallas(qm, km, vm, causal, scale, t_kv, block_q,
                              block_k, group, window)
     out = out[:, :t_q]
-    return jnp.swapaxes(out.reshape(b, h, t_q, d), 1, 2)
+    return jnp.swapaxes(out.reshape(b, h, t_q, v.shape[3]), 1, 2)

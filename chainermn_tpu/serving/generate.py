@@ -604,10 +604,13 @@ class GenerationEngine:
         self._cache_struct, self._cache_sig = _struct_and_signature(
             cache)
         # bytes of (one K/V page, one state row) over all layers: what
-        # the tick's cache-bytes attributes are counted in
+        # the tick's cache-bytes attributes are counted in; a family
+        # whose page is not K/V names its count (``page_counter``)
+        self._page_counter = getattr(model, 'page_counter', None)
         self._cache_bytes = (
             model.paged_cache_bytes(self._cache_struct)
-            if self.state_pool is not None else None)
+            if self.state_pool is not None or self._page_counter
+            else None)
 
         # -- speculative decoding: the draft twin ----------------------
         self.spec_tokens = int(spec_tokens)
@@ -2053,6 +2056,10 @@ class GenerationEngine:
             if self._ring:
                 tick.set(full_pages_in_use=self.pool.in_use(),
                          window_pages_in_use=self.window_pool.in_use())
+            if self._page_counter:
+                pages = self.pool.in_use()
+                tick.set(cache_bytes_in_use=pages * self._cache_bytes[0],
+                         **{self._page_counter: pages})
             if self.state_pool is not None:
                 # what the sequences' cache is made of: state held by
                 # the row, K/V by the page

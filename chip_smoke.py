@@ -879,6 +879,95 @@ def serve_olmo_hybrid(hidden=512, heads=4, key_dim=96, value_dim=192,
 
 
 # ----------------------------------------------------------------------
+# the xing4 family
+
+#: ``Xing4LM`` at the ``xing4-29b-a4b`` cell's depth
+#: (``chipbench/configs/xing4-29b-a4b.json``: one dense layer and five
+#: expert layers); the other defaults are the published widths
+YARN = dict(type='yarn', factor=64, original_max_position_embeddings=4096,
+            beta_fast=32, beta_slow=1, mscale=1, mscale_all_dim=1)
+XING4 = dict(num_hidden_layers=6, first_k_dense_replace=1,
+             rope_scaling=YARN)
+
+
+def serving_pool_check_xing4(n_slots=48, max_prompt=6144, max_len=7680,
+                             page_size=64, prompt_bucket=2048, **shape):
+    """:func:`_pool_check` at the shapes of the benchmark's
+    ``xing4-serve-closed48-long`` cell: ONE latent leaf a layer,
+    ``(5,761, 1, 64, 640)`` (a position's 512 + 64 values in one row of
+    five lane tiles), 2.83 GB over six layers beside 9.59 GB of
+    weights.  Weights are zeros: nothing runs."""
+    import jax
+    import jax.numpy as jnp
+
+    from chainermn_tpu import serving
+    from chainermn_tpu.models import Xing4LM
+
+    model = Xing4LM(**dict(XING4, **shape))
+    params = jax.tree_util.tree_map(
+        lambda x: jnp.zeros(x.shape, x.dtype),
+        jax.eval_shape(lambda: model.init(jax.random.PRNGKey(SEED),
+                                          jnp.bfloat16)))
+    engine = serving.GenerationEngine(
+        model, params, n_slots=n_slots, max_prompt_len=max_prompt,
+        max_len=max_len, paged=True, page_size=page_size,
+        prefix_sharing=False)    # no Policy: the hc_* leaves stay f32
+    return _pool_check(
+        engine, 'serve xing4 d%d/L%d %d slots, %d latent pages of %d'
+        % (model.hidden_size, model.num_hidden_layers, n_slots,
+           engine.n_pages, page_size), prompt_bucket)
+
+
+def serve_xing4(hidden=512, heads=4, experts=8, top_k=2, width=256,
+                dense_width=1024, q_rank=192, vocab=4096, page_size=64,
+                n_slots=8, max_prompt=256, max_len=512, max_new=48,
+                n_requests=6, kernels='native', **shape):
+    """A small ``Xing4LM`` with the family's every mechanism (the
+    published latent: 512 + 64 values a position, heads of 128 + 64 /
+    128; four streams; a dense layer before two expert layers) through
+    ``GenerationEngine`` + ``GenerationQueue``: prefill expanded,
+    decode absorbed over the latent pages, slots and pages reused,
+    every served token held against the float32 kernel-free forward of
+    the same weights."""
+    import jax
+    import jax.numpy as jnp
+
+    from chainermn_tpu import serving
+    from chainermn_tpu.models import Xing4LM
+
+    model = Xing4LM(
+        vocab_size=vocab, hidden_size=hidden,
+        intermediate_size=dense_width, moe_intermediate_size=width,
+        num_hidden_layers=3, first_k_dense_replace=1,
+        num_attention_heads=heads, q_lora_rank=q_rank,
+        n_routed_experts=experts, num_experts_per_tok=top_k,
+        rope_scaling=dict(YARN, original_max_position_embeddings=64),
+        max_position_embeddings=max_len, **shape)
+    params = model.init(jax.random.PRNGKey(SEED), jnp.bfloat16)
+    rng = np.random.RandomState(SEED)
+    lengths = [3, page_size, page_size + 1, max_prompt] + list(
+        rng.randint(4, max_prompt + 1, size=n_requests - 4))
+    lengths = lengths + lengths[::-1] + lengths[:n_slots // 2]
+    prompts = [rng.randint(0, vocab, size=int(n)).astype(np.int32)
+               for n in lengths]
+    what = 'serve xing4 d%d/L3/V%d, latent %d + %d, %d experts top-%d' % (
+        hidden, vocab, model.kv_lora_rank, model.qk_rope_head_dim,
+        experts, top_k)
+    engine = serving.GenerationEngine(
+        model, params, n_slots=n_slots, max_prompt_len=max_prompt,
+        max_len=max_len, paged=True, page_size=page_size,
+        prefix_sharing=False)    # no Policy: the hc_* leaves stay f32
+    streams = _serve_requests(engine, prompts, max_new, kernels, what)
+    stats = engine.stats()
+    require(stats['pages_in_use'] == 0,
+            '%s: %d latent pages in use after the drain'
+            % (what, stats['pages_in_use']))
+    return _served_gaps(
+        model, engine, prompts, streams, max_prompt + max_new, what,
+        '%d latent pages at the peak' % stats['peak_pages_in_use'])
+
+
+# ----------------------------------------------------------------------
 # four chips
 
 def _distinct_devices(tree):
@@ -989,6 +1078,10 @@ def main(argv=None):
         '--chips', type=int, choices=(1, 4), default=1,
         help='4: run only the four-chip mesh phase and its one-device '
              'comparison (default 1: train + serve on one chip)')
+    parser.add_argument(
+        '--phases', default=None,
+        help='comma-separated names: run only these one-chip phases '
+             '(default: all of them, in order)')
     args = parser.parse_args(argv)
 
     if os.environ.get('CHAINERMN_TPU_PALLAS') == '0':
@@ -1012,7 +1105,15 @@ def main(argv=None):
                       ('serving_pool_afmoe', serving_pool_check_afmoe),
                       ('serve_olmo_hybrid', serve_olmo_hybrid),
                       ('serving_pool_olmo_hybrid',
-                       serving_pool_check_olmo_hybrid)]
+                       serving_pool_check_olmo_hybrid),
+                      ('serve_xing4', serve_xing4),
+                      ('serving_pool_xing4', serving_pool_check_xing4)]
+            if args.phases:
+                asked = args.phases.split(',')
+                unknown = set(asked) - {name for name, _ in phases}
+                if unknown:
+                    raise SmokeFailure('no phase %s' % sorted(unknown))
+                phases = [p for p in phases if p[0] in asked]
         for phase, fn in phases:
             t0 = time.perf_counter()
             fn()

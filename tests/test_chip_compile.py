@@ -32,7 +32,7 @@ KERNEL_MODULES = [importlib.import_module('chainermn_tpu.ops.' + name)
                   for name in ('flash_attention', 'layer_norm',
                                'cross_entropy', 'batch_norm_act',
                                'optimizer', 'grouped_matmul',
-                               'gated_delta')]
+                               'gated_delta', 'hyper_connection')]
 
 BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
 
@@ -174,7 +174,49 @@ def _conv_step(tail, rows, x, w):
     return ops.causal_conv_step(tail, rows, x, w)
 
 
+# the xing4-serve-closed48-long cell's widths: 32 query heads of 192
+# (keys) / 128 (values) expanded in prefill; absorbed in decode, 48 rows
+# reading ONE latent leaf of 640-lane rows in pages of 64, values its
+# first 512 lanes; 64 experts of 3584 x 1024, 4 a token; four streams
+# of 3584 under 24 coefficients a token
+_LATENT = ((5761, 1, 64, 640), BF16)
+_EXPERTS_3584 = [((64, 3584, 1024), BF16)] * 2 \
+    + [((64, 1024, 3584), BF16), ((64,), I32)]
+_MHC = [((24, 4 * 3584), F32), ((3,), F32), ((24,), F32)]
+
+
+def _decode_latent(q, pool, tables, lengths):
+    return ops.flash_attention_decode_paged(
+        q, pool, None, tables, lengths, scale=0.1, group=32,
+        head_major=True, value_lanes=512)
+
+
+def _append_latent(pool, new, pages, offsets):
+    return ops.paged_kv_append(pool, None, new, None, pages, offsets)[0]
+
+
+def _mhc(x, phi, alpha, b):
+    return jnp.concatenate([c.reshape(x.shape[0], -1) for c in
+                            ops.mhc_coefficients(x, phi, alpha, b)], -1)
+
+
 CASES = {
+    'flash_fwd_causal_192_128_t6144': (
+        _flash, [((1, 6144, 32, 192), BF16)] * 2
+        + [((1, 6144, 32, 128), BF16)]),
+    'decode_paged_latent_group32_page64': (
+        _decode_latent, [((48, 32, 640), BF16), _LATENT,
+                         ((48, 120), I32), ((48,), I32)]),
+    'paged_kv_append_latent_48rows': (
+        _append_latent, [_LATENT, ((48, 1, 640), BF16), ((48,), I32),
+                         ((48,), I32)]),
+    'grouped_swiglu_decode_192rows_3584x1024': (
+        ops.grouped_swiglu, [((192, 3584), BF16)] + _EXPERTS_3584),
+    'grouped_swiglu_prefill_24576rows_3584x1024': (
+        ops.grouped_swiglu, [((24576, 3584), BF16)] + _EXPERTS_3584),
+    'mhc_coefficients_48rows': (_mhc, [((48, 4 * 3584), BF16)] + _MHC),
+    'mhc_coefficients_6144rows': (
+        _mhc, [((6144, 4 * 3584), BF16)] + _MHC),
     'decode_paged_full_group1_30heads_page64': (
         _decode_group1, [_Q48] + [((3073, 30, 64, 128), BF16)] * 2
         + [((48, 64), I32), ((48,), I32)]),
@@ -501,3 +543,70 @@ def test_olmo_hybrid_serving_executable_leaves_pools_and_state_in_place(
     if body == 'decode':
         # attention + the append, and two steps a linear layer
         assert compiled.as_text().count('tpu_custom_call') >= 8
+
+
+@pytest.mark.parametrize('body', ['decode', 'prefill'])
+def test_xing4_serving_executable_leaves_the_latent_pool_in_place(
+        body, one_chip, mosaic):
+    """The ``xing4`` serving executables at the widths of the
+    ``xing4-29b-a4b`` cell (48 rows, 5,761 latent pages of 64; the
+    dense layer and one of its five expert layers), compiled for the
+    described chip: nothing makes a value of the latent leaf's shape
+    besides the write (``paged_kv_append`` on the ONE leaf in decode,
+    the page scatter in prefill), the cache is held at its nominal
+    bytes (a 640-lane row is five whole tiles: 1,280 B a position a
+    layer), and every kernel of the family is in: the latent decode
+    (or the 192 / 128 forward), the expert kernel, the residual
+    path's coefficients."""
+    import os
+    import sys
+
+    from chainermn_tpu import models as M
+    from chainermn_tpu.serving.generate import GenerationEngine
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    import chip_smoke
+
+    model = M.Xing4LM(**dict(chip_smoke.XING4, num_hidden_layers=2))
+    params = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                       sharding=one_chip),
+        jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), BF16)))
+    cache = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                       sharding=one_chip),
+        jax.eval_shape(lambda: model.init_paged_kv_cache(5761, 64)))
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, I32, sharding=one_chip)
+
+    def decode(p, c, tokens, positions, tables):
+        logits, c, counters = model.decode_step_paged(
+            p, c, tokens, positions, tables)
+        return GenerationEngine._sampled(logits, counters), c
+
+    def prefill(p, c, tokens, length, pos0, table):
+        logits, c, counters = model.prefill_paged(
+            p, c, tokens, length, table, pos0)
+        return GenerationEngine._sampled(logits, counters), c
+
+    fn, operands = {
+        'decode': (decode, (ints(48), ints(48), ints(48, 120))),
+        'prefill': (prefill, (ints(1, 2048), ints(), ints(), ints(120))),
+    }[body]
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *operands).compile()
+    leaves = jax.tree_util.tree_leaves(cache)
+    assert [leaf.shape for leaf in leaves] == [(5761, 1, 64, 640)] * 2
+    text = compiled.as_text()
+    assert chip_smoke.pool_shaped(text, leaves) == []
+    memory = compiled.memory_analysis()
+    nominal = 2 * 5761 * 64 * 640 * 2
+    assert memory.alias_size_in_bytes == nominal
+    assert memory.temp_size_in_bytes < nominal // 2
+    for kernel in ('mhc_coefficients', 'grouped_swiglu',
+                   'flash_attention_decode_paged' if body == 'decode'
+                   else 'flash_attention_fwd'):
+        assert kernel in text, kernel
+    # four solves of the residual path, each ONE kernel
+    assert text.count('custom_call_target="tpu_custom_call"') >= 7

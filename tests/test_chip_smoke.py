@@ -155,6 +155,45 @@ def test_serving_pool_olmo_hybrid_phase_tiny():
             linear_value_head_dim=64)
 
 
+TINY_XING4 = dict(hidden=32, heads=4, experts=8, top_k=2, width=16,
+                  dense_width=48, q_rank=24, vocab=64, page_size=8,
+                  kv_lora_rank=128, qk_nope_head_dim=16,
+                  qk_rope_head_dim=8, v_head_dim=16)
+
+
+def test_serve_xing4_phase_tiny(interpret):
+    out = chip_smoke.serve_xing4(
+        n_slots=3, max_prompt=24, max_len=48, max_new=12, n_requests=5,
+        kernels='interpret', **TINY_XING4)
+    # every slot reused: 5 + 5 + 1 requests on 3 slots
+    assert len(out['streams']) == 11
+    assert all(len(s) == 12 for s in out['streams'])
+    assert out['gap_mean'] < 0.01
+
+
+def test_serving_pool_xing4_phase_tiny():
+    """As ``test_serving_pool_afmoe_phase_tiny``: on the CPU the check
+    must bite (the jnp twin of the append is a scatter through
+    pool-shaped ``convert`` instructions there); the latent leaf is
+    named first."""
+    with pytest.raises(chip_smoke.SmokeFailure,
+                       match='makes pool-shaped values'):
+        chip_smoke.serving_pool_check_xing4(
+            n_slots=2, max_prompt=8, max_len=32, page_size=8,
+            prompt_bucket=8, vocab_size=64, hidden_size=32,
+            intermediate_size=48, moe_intermediate_size=16,
+            num_hidden_layers=2, num_attention_heads=4, q_lora_rank=24,
+            kv_lora_rank=128, qk_nope_head_dim=16, qk_rope_head_dim=8,
+            v_head_dim=16, n_routed_experts=8, num_experts_per_tok=2)
+
+
+def test_phases_option_names_an_unknown_phase(monkeypatch, capsys):
+    monkeypatch.setattr(chip_smoke, 'check_device', lambda chips: {})
+    monkeypatch.delenv('CHAINERMN_TPU_PALLAS', raising=False)
+    assert chip_smoke.main(['--phases', 'serve_nothing']) == 1
+    assert 'no phase' in capsys.readouterr().out
+
+
 def test_multichip_phase_on_four_virtual_devices(interpret):
     out = chip_smoke.train_multichip(n_devices=4, seq=16,
                                      global_batch=4, tp=2,

@@ -572,6 +572,118 @@ def test_decode_paged_grid_counts_pages_and_steps():
     assert steps == 1 + 4 + 5 + 5
 
 
+@pytest.mark.parametrize('pages', [1, 2, 4, 8])
+def test_paged_decode_over_a_latent_leaf(mode, monkeypatch, pages):
+    """ONE leaf whose rows are keys and values at once (absorbed latent
+    attention): scores over all 256 lanes of a row, values its first
+    128, all 4 query heads on the one K/V "head", at every number of
+    pages a grid step; dirty pages past every live prefix (the kernel
+    zeroes its own slots: a dead page's 0 x garbage must stay 0)."""
+    fa = _fa
+    page, n_max = (1, 8, 256), 11
+    lengths = [1, 19, 88, 32, 41, 64]
+    b, n_pages = len(lengths), 1 + len(lengths) * n_max
+    rng = np.random.RandomState(5)
+    q = _rand((b, 4, 256), 50)
+    pool = _rand((n_pages,) + page, 51)
+    tables = 1 + rng.permutation(n_pages - 1).reshape(b, n_max)
+    tables[1, :2] = tables[2, :2]         # a shared two-page prefix
+    live = set()
+    for row, length in zip(tables, lengths):
+        live |= {row[j] for j in range((length - 1) // 8 + 1)}
+    dead = np.asarray(sorted(set(range(n_pages)) - live))
+    dirty = pool.at[dead].set(jnp.asarray(100.0, pool.dtype))
+    monkeypatch.setattr(fa, '_PAGED_STEP_BYTES',
+                        pages * fa._vmem_bytes(page, pool.dtype))
+    assert fa._paged_pages_per_step(page, pool.dtype, n_max, False, True,
+                                    shared=True) == pages
+    out = ops.flash_attention_decode_paged(
+        q, dirty, None, jnp.asarray(tables), jnp.asarray(lengths),
+        group=4, head_major=True, value_lanes=128)
+    assert out.shape == (b, 4, 128)
+    want = _paged_oracle(q, pool, pool, tables, lengths,
+                         head_major=True)[..., :128]
+    np.testing.assert_allclose(out, want, atol=2e-5, rtol=2e-5)
+    # the step rule counts the one leaf once: twice the pages of a
+    # K + V pool of the same page
+    assert fa._paged_step_vmem(4, page, pool.dtype, False, True,
+                               shared=True)[0] * 2 \
+        == fa._paged_step_vmem(4, page, pool.dtype, False, True)[0]
+
+
+def test_latent_leaf_at_the_cells_shape_goes_eight_pages_a_step():
+    """``xing4-serve-closed48-long``: pages of 64 rows of 640 lanes in
+    bfloat16 are 81,920 bytes; eight are 655 KB, sixteen would pass the
+    step's 1 MiB."""
+    fa = _fa
+    page = (1, 64, 640)
+    assert fa._vmem_bytes(page, jnp.bfloat16) == 64 * 640 * 2
+    assert fa._paged_pages_per_step(page, jnp.bfloat16, 120, False, True,
+                                    shared=True) == 8
+    read, steps = fa.decode_paged_grid(
+        [1, 64, 65, 4000, 7680], page, jnp.bfloat16, 120,
+        head_major=True, shared=True)
+    assert read == 1 + 1 + 2 + 63 + 120
+    assert steps == 1 + 1 + 1 + 8 + 15
+
+
+@pytest.mark.parametrize('bad', [
+    dict(v='pool', value_lanes=128), dict(v=None, value_lanes=None),
+    dict(v=None, value_lanes=100), dict(v=None, value_lanes=512),
+    dict(v=None, value_lanes=128, head_major=False)])
+def test_latent_decode_refuses_what_it_cannot_read(bad):
+    pool = jnp.zeros((3, 1, 8, 256))
+    q = jnp.zeros((2, 4, 256))
+    kw = dict(dict(group=4, head_major=True), **bad)
+    v = pool if kw.pop('v') == 'pool' else None
+    with pytest.raises(ValueError):
+        ops.flash_attention_decode_paged(
+            q, pool, v, jnp.zeros((2, 2), jnp.int32),
+            jnp.ones((2,), jnp.int32), **kw)
+
+
+def test_append_into_one_pool(mode):
+    """``paged_kv_append`` with ONE pool: the token's row lands at
+    ``[page, :, offset]`` and nothing else of the leaf moves."""
+    pool = _rand((5, 1, 8, 256), 60)
+    new = _rand((3, 1, 256), 61)
+    pages, offsets = jnp.asarray([2, 4, 1]), jnp.asarray([0, 7, 3])
+    got, none = ops.paged_kv_append(pool, None, new, None, pages, offsets)
+    assert none is None
+    want = np.array(pool)
+    for i, (page, at) in enumerate(zip([2, 4, 1], [0, 7, 3])):
+        want[page, :, at] = np.asarray(new[i])
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize('t, dtype, atol', [
+    (200, jnp.float32, 2e-5), (130, jnp.bfloat16, 2e-2),
+    (16, jnp.float32, 2e-5)])
+def test_flash_forward_with_a_value_width_of_its_own(mode, t, dtype, atol):
+    """Keys of 192 and values of 128 (expanded latent attention): the
+    forward kernel's output and accumulator take the value width; the
+    tiles are still ``_flash_blocks``' (of the wider of the two)."""
+    fa = _fa
+    q = _rand((2, t, 4, 192), 70, dtype)
+    k = _rand((2, t, 4, 192), 71, dtype)
+    v = _rand((2, t, 4, 128), 72, dtype)
+    out = ops.flash_attention(q, k, v, causal=True, scale=0.11)
+    assert out.shape == (2, t, 4, 128) and out.dtype == dtype
+    f = lambda x: np.asarray(x, np.float64)             # noqa: E731
+    s = np.einsum('bqhd,bkhd->bhqk', f(q), f(k)) * 0.11
+    s = np.where(np.tril(np.ones((t, t), bool)), s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    want = np.einsum('bhqk,bkhd->bqhd', p / p.sum(-1, keepdims=True),
+                     f(v))
+    np.testing.assert_allclose(f(out), want, atol=atol)
+    # one width as ever: the same tiles as before the second existed
+    assert fa._flash_blocks(1024, 1024, 64, jnp.bfloat16) == (1024, 1024)
+    assert fa._flash_vmem_bytes('fwd', 1024, 1024, 64, 2) \
+        == fa._flash_vmem_bytes('fwd', 1024, 1024, 128, 2)
+    assert fa._flash_vmem_bytes('fwd', 512, 512, 192, 2) \
+        == fa._flash_vmem_bytes('fwd', 512, 512, 256, 2)
+
+
 class TestChunkAttention:
     """Chunked prefill's attention: a C-token chunk attends causally
     within itself AND to ``ctx_len`` banked context tokens, merged

@@ -164,6 +164,69 @@ def test_decode_paged_mosaic(page, int8):
         assert bool(jnp.all(out == slab))
 
 
+def test_flash_two_widths_mosaic():
+    """Keys of 192, values of 128 (expanded latent attention) at the
+    ``xing4`` cell's head count, a prompt of 1,000."""
+    from chainermn_tpu import ops
+    rng = np.random.RandomState(3)
+    t, h = 1000, 32
+    q = jnp.asarray(rng.randn(1, t, h, 192), jnp.bfloat16) * 0.5
+    k = jnp.asarray(rng.randn(1, t, h, 192), jnp.bfloat16) * 0.5
+    v = jnp.asarray(rng.randn(1, t, h, 128), jnp.bfloat16) * 0.5
+    out = jax.jit(lambda q, k, v: ops.flash_attention(
+        q, k, v, causal=True, scale=0.1))(q, k, v)
+    f = lambda x: x.astype(jnp.float32)                 # noqa: E731
+    s = jnp.einsum('bqhd,bkhd->bhqk', f(q), f(k),
+                   precision='highest') * 0.1
+    s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+    want = jnp.einsum('bhqk,bkhd->bqhd', jax.nn.softmax(s, -1), f(v),
+                      precision='highest')
+    assert out.shape == (1, t, h, 128)
+    _close(out, want, name='fwd 192/128')
+
+
+def test_decode_paged_latent_mosaic():
+    """The absorbed form at the ``xing4`` cell's shapes: 48 rows, ONE
+    leaf of 640-lane rows in pages of 64, values its first 512 lanes,
+    against the dense oracle."""
+    from chainermn_tpu import ops
+    rng = np.random.RandomState(4)
+    b, h, n_max, ps = 48, 32, 120, 64
+    pool = jnp.asarray(rng.randn(1 + b * 16, 1, ps, 640),
+                       jnp.bfloat16) * 0.5
+    q = jnp.asarray(rng.randn(b, h, 640), jnp.bfloat16) * 0.5
+    lengths = rng.randint(1, 16 * ps + 1, b).astype(np.int32)
+    lengths[:3] = [1, ps, 16 * ps]
+    tables = np.zeros((b, n_max), np.int32)
+    tables[:, :16] = 1 + rng.permutation(b * 16).reshape(b, 16)
+    out = ops.flash_attention_decode_paged(
+        q, pool, None, jnp.asarray(tables), jnp.asarray(lengths),
+        scale=0.05, group=h, head_major=True, value_lanes=512)
+    rows = jnp.repeat(jnp.swapaxes(pool, 1, 2), h, axis=2)
+    want = ops.decode_attention_paged_reference(
+        q, rows, rows, jnp.asarray(tables[:, :16]), jnp.asarray(lengths),
+        scale=0.05)[..., :512]
+    assert out.shape == (b, h, 512)
+    _close(out, want, name='latent decode')
+
+
+@pytest.mark.parametrize('rows', [48, 1000])
+def test_mhc_coefficients_mosaic(rows):
+    from chainermn_tpu import ops
+    key = jax.random.PRNGKey(rows)
+    x = jax.random.normal(key, (rows, 4 * 3584), jnp.bfloat16)
+    phi = 0.02 * jax.random.normal(jax.random.fold_in(key, 1),
+                                   (24, 4 * 3584), jnp.float32)
+    alpha = jnp.ones((3,), jnp.float32)
+    b = 0.02 * jax.random.normal(jax.random.fold_in(key, 2), (24,),
+                                 jnp.float32)
+    got = ops.mhc_coefficients(x, phi, alpha, b)
+    want = ops.mhc_coefficients_reference(
+        x, phi, alpha, b, 4, 20, 1e-6, (-30.0, 30.0), 1e-6)
+    for g, w, name in zip(got, want, ('pre', 'post', 'res')):
+        _close(g, w, rtol=1e-4, name=name)
+
+
 def test_layer_norm_mosaic():
     from chainermn_tpu import ops
     from chainermn_tpu.ops.layer_norm import layer_norm_reference
