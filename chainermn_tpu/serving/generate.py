@@ -26,6 +26,10 @@ in-flight batch never waits for stragglers, which is what makes
 tokens/s/chip under a mixed-length workload approach the steady-state
 decode rate instead of the worst sequence's (the batch-level
 alternative idles every finished slot until the whole batch drains).
+The decode tick runs ONE call ahead of the host's reads: call t+1 is
+dispatched with call t's tokens still on the device, and the host
+reads, emits and prepares under it (:meth:`GenerationEngine.
+_decode_once`; ``docs/serving.md``, "The tick, one call ahead").
 
 Both executable families reuse the engine machinery wholesale: AOT
 compilation (``jit(...).lower(...).compile()``) over the persistent
@@ -81,6 +85,15 @@ _WARM_VALUES = {
     'slots': lambda shape, dtype: jnp.arange(shape[0], dtype=dtype),
     'length': jnp.ones,
 }
+
+
+def _merged(tokens, prev, src):
+    """A decode call's input tokens, made on the device: row ``i``
+    takes the PREVIOUS call's sampled token of its row ``src[i]``
+    (``prev`` is that call's result as it left the executable, its
+    counters behind its tokens, which the host may not have read
+    yet), or with ``src[i] < 0`` the host's ``tokens[i]``."""
+    return jnp.where(src >= 0, prev[jnp.maximum(src, 0)], tokens)
 
 
 def _named(fn, name):
@@ -356,8 +369,10 @@ class _Slot:
                  t_now, t_stage_end=None, pages=None, ring=(),
                  state_row=0):
         self.request = request
-        self.position = position          # next token's position
-        self.remaining = remaining        # tokens still to generate
+        # both advance when a decode call is DISPATCHED, not when its
+        # token is read: the host runs one call ahead of the device
+        self.position = position          # next call's position
+        self.remaining = remaining        # calls still to dispatch
         self.generated = [first_token]
         self.t_last_token = t_now
         # telemetry-clock end of this request's newest recorded trace
@@ -372,6 +387,32 @@ class _Slot:
         # ... and its recurrent layers' state row (a model with
         # none: 0, the scratch row)
         self.state_row = state_row
+
+
+class _Flight:
+    """One decode call whose sampled vector the host has not read.
+    ``toks`` is that vector, still on the device; ``rows`` the slot id
+    of every row and ``slots`` the :class:`_Slot` each row held AT
+    DISPATCH: a row whose slot has gone since (expired, shed, ended a
+    call earlier on an EOS) or holds another request by now gets its
+    token dropped.  ``last`` marks the rows that end in this call by
+    length; ``attrs`` is what the ``serve_decode`` span that reads the
+    vector says of the call (``None``: telemetry was off)."""
+
+    __slots__ = ('toks', 'rows', 'slots', 'row_of', 'last', 'ends',
+                 'k', 'bucket', 'attrs')
+
+    def __init__(self, toks, rows, slots, last, k, bucket, attrs):
+        self.toks = toks
+        self.rows = rows
+        self.slots = slots
+        self.row_of = {slot: i for i, slot in enumerate(slots)
+                       if slot is not None}
+        self.last = last
+        self.ends = any(last)     # a foreseen end: a settle point
+        self.k = k
+        self.bucket = bucket
+        self.attrs = attrs
 
 
 class _PrefillState:
@@ -663,6 +704,8 @@ class GenerationEngine:
             else tuple(self.prefill_edges))
 
         self._slots = {}      # slot id -> _Slot (decode phase)
+        self._inflight = None # the decode call not read yet (_Flight)
+        self._zero_prev = {}  # slot bucket -> a call's all-zero `prev`
         self._prefilling = {} # slot id -> _PrefillState (paged only)
         self._free = list(range(self.n_slots))
         self._prefill = {}    # prompt/chunk bucket -> callable
@@ -684,6 +727,8 @@ class GenerationEngine:
         self.prefill_chunks = 0
         self.cow_copies = 0
         self.decode_steps = 0
+        self.decode_calls = 0        # decode calls dispatched ...
+        self.decode_calls_ahead = 0  # ... before their predecessor was read
         self.draft_steps = 0
         self.verify_steps = 0
         self.draft_proposed = 0
@@ -743,6 +788,9 @@ class GenerationEngine:
                 'incumbent weights'
                 % (len(self._slots) + len(self._prefilling)),
                 version=version)
+        # a call may still be in flight whose every row has gone
+        # (expired, shed, ended on an EOS found a call late)
+        self._settle()
         new = self._place_params(params)
         if validate and self.n_slots in self._decode:
             exe = self._decode[self.n_slots][0]
@@ -900,14 +948,23 @@ class GenerationEngine:
                             'copy', True),
     }
 
-    def _operands(self, phase, bucket):
+    def _operands(self, phase, bucket, draft=False):
         """THE statement of what an executable takes after ``(params,
         cache)``: ``(method, operands, arrange)`` -- the model's
         method of that name, a ``(name, shape)`` per int32 operand in
         the order the scheduler passes them, and ``arrange`` putting
         them in the method's own order.  Verify is decode with the
         token vector widened to the ``(bucket, spec_tokens)`` window;
-        the page copy is the engine's own body."""
+        the page copy is the engine's own body.
+
+        The TARGET's decode executables take two operands more, right
+        behind ``tokens``: ``prev``, the previous decode call's result
+        as it left the device, and ``src``, a row each; ``arrange``
+        makes the call's tokens of the three (:func:`_merged`) before
+        the model's method sees them, so a call can be dispatched
+        while the host has not read its predecessor's tokens.  The
+        draft's decode steps and the verify run inside one
+        synchronous tick and take the host's tokens alone."""
         if phase == 'copy':
             return None, (('src', ()), ('dst', ())), _as_given
         if phase == 'prefill':
@@ -926,19 +983,28 @@ class GenerationEngine:
         if self.paged:
             # every bucket reads THROUGH the tables, so there is no
             # full-vs-compacted split
-            return (method + '_paged',
-                    (tokens, positions,
-                     ('tables', (bucket, self._table_width))),
-                    _as_given)
-        if bucket == self.n_slots:
+            method += '_paged'
+            rest = (positions, ('tables', (bucket, self._table_width)))
+            arrange = _as_given
+        elif bucket == self.n_slots:
             # full bucket: every slot decodes, the cache is read IN
             # PLACE (no slots operand); rows are slots in order
-            return method, (tokens, positions), _as_given
-        # compacted bucket
-        return (method, (tokens, ('slots', (bucket,)), positions),
-                lambda t, slots, pos: (t, pos, slots))
+            rest, arrange = (positions,), _as_given
+        else:
+            # compacted bucket
+            rest = (('slots', (bucket,)), positions)
 
-    def _warm_operands(self, phase, bucket=None):
+            def arrange(t, slots, pos):
+                return t, pos, slots
+        if phase != 'decode' or draft:
+            return method, (tokens,) + rest, arrange
+        ahead = (('prev', (bucket + len(self.model.serve_counters),)),
+                 ('src', (bucket,)))
+        return (method, (tokens,) + ahead + rest,
+                lambda t, prev, src, *rest: arrange(
+                    _merged(t, prev, src), *rest))
+
+    def _warm_operands(self, phase, bucket=None, draft=False):
         """Operands of :meth:`_operands`' shapes for a call whose
         result nobody reads (warm-up, swap validation, a linter's
         trace): zeros -- a zero table is the scratch page, and free
@@ -946,14 +1012,14 @@ class GenerationEngine:
         ``slots``, each row its own, and a prefill's ``length``, 1."""
         return tuple(
             _WARM_VALUES.get(name, jnp.zeros)(shape, jnp.int32)
-            for name, shape in self._operands(phase, bucket)[1])
+            for name, shape in self._operands(phase, bucket, draft)[1])
 
     def _traceable(self, phase, bucket=None, draft=False):
         """``(fn, structs)``: the mapped callable of one executable --
         what gets AOT-compiled, and what ``traceable_decode`` /
         ``traceable_verify`` hand shardlint -- and the structs of its
         operands."""
-        method, operands, arrange = self._operands(phase, bucket)
+        method, operands, arrange = self._operands(phase, bucket, draft)
         if method is None:
             body = self._copy_body
         else:
@@ -1089,7 +1155,7 @@ class GenerationEngine:
             cache = '_draft_cache' if draft else '_cache'
             out = exe(self._draft_params if draft else self.params,
                       getattr(self, cache),
-                      *self._warm_operands(phase, bucket))
+                      *self._warm_operands(phase, bucket, draft))
             if phase != 'copy':
                 tok, out = out
                 jax.block_until_ready(tok)
@@ -1169,6 +1235,11 @@ class GenerationEngine:
                 doomed.append(sid)
         for sid in doomed:
             slot = self._slots.pop(sid)
+            # the row may be in the decode call in flight, which
+            # writes these pages (its token is dropped at the read):
+            # safe because whoever gets them next writes them in a
+            # LATER call, ordered behind it on the one device stream,
+            # and the host never touches a page's contents
             self._release_pages(slot.pages, slot.ring, slot.state_row)
             self._free.append(sid)
             self.cancelled += 1
@@ -1206,6 +1277,8 @@ class GenerationEngine:
 
     # -- paged-mode page accounting ------------------------------------
     def _release_pages(self, pages, ring=(), state_row=0):
+        """Host bookkeeping only: a released page may still be written
+        by the decode call in flight (see the call sites)."""
         if pages:
             for page in pages:
                 self.pool.release(page)
@@ -1586,11 +1659,19 @@ class GenerationEngine:
                                      state_row=st.state_row)
         return worked
 
-    def _decode_operands(self):
-        """What one decode step is called with: the rows (slot ids,
-        padded to the smallest slot-count bucket), the live count, the
-        bucket, its executable and the uploaded operands -- or None
-        when growing the page tables shed every live sequence."""
+    def _decode_operands(self, pend):
+        """What the next decode call is called with: the rows (slot
+        ids, padded to the smallest slot-count bucket), the
+        :class:`_Slot` each holds, the live count, the bucket, its
+        executable and the uploaded operands -- or None when no
+        sequence is live (growing the page tables may shed them all).
+        ``pend`` is the call in flight, if any: a row that was in it
+        takes its token from that call's vector on the device
+        (``src``), every other row the host's newest.  Nothing here
+        advances a slot (:meth:`_decode_once` does, at dispatch), so
+        it may be asked twice in a tick: where ``pend`` is of another
+        bucket its vector cannot feed this call, nothing is uploaded
+        and the operands come back None -- settle, and ask again."""
         if self.paged:
             # grow page tables across page boundaries BEFORE dispatch
             # (a sequence whose next token starts a new page gets one
@@ -1602,6 +1683,11 @@ class GenerationEngine:
                     page = self._alloc_page()
                     if page is None:
                         del self._slots[sid]
+                        # the row may be in the call in flight, which
+                        # writes these pages: whoever gets them next
+                        # writes them in a LATER call, ordered behind
+                        # it on the one device stream; the host never
+                        # touches a page's contents
                         self._shed_paged(slot.request, slot.pages,
                                          'decode', slot.ring,
                                          slot.state_row)
@@ -1610,8 +1696,8 @@ class GenerationEngine:
                     slot.pages.append(page)
                 else:           # not shed: its window pages too
                     self._grow_ring(slot.ring, need)
-            if not self._slots:
-                return None
+        if not self._slots:
+            return None
         active = sorted(self._slots)
         k = len(active)
         bucket = bucket_of(k, self.decode_edges)
@@ -1632,45 +1718,99 @@ class GenerationEngine:
             # available: bucket < n_slots and only k are active) --
             # same garbage-write-to-a-free-slot contract as above
             rows = active + self._free[:bucket - k]
-        tokens = np.asarray(
-            [self._slots[s].generated[-1] if s in self._slots else 0
-             for s in rows], np.int32)
-        positions = np.asarray(
-            [self._slots[s].position if s in self._slots else 0
-             for s in rows], np.int32)
+        held = [self._slots.get(sid) for sid in rows]
+        if pend is not None and pend.bucket != bucket:
+            return rows, held, k, bucket, None, None
+        tokens = np.zeros((bucket,), np.int32)
+        src = np.full((bucket,), -1, np.int32)
+        positions = np.zeros((bucket,), np.int32)
+        row_of = pend.row_of if pend is not None else {}
+        for i, slot in enumerate(held):
+            if slot is None:
+                continue
+            positions[i] = slot.position
+            j = row_of.get(slot)
+            if j is None:
+                # a row a prefill just admitted, the first call, any
+                # call after a settle: the host has its newest token
+                tokens[i] = slot.generated[-1]
+            else:
+                # rows are sorted slots and shift when one ends or
+                # fills: a gather, not an identity
+                src[i] = j
         exe = self._get_decode(bucket)
         if self.paged:
             tables = np.zeros((bucket, self._table_width), np.int32)
-            for i, sid in enumerate(rows):
-                if sid is not None:
-                    slot = self._slots[sid]
+            for i, slot in enumerate(held):
+                if slot is not None:
                     self._table_array(slot.pages, slot.ring,
                                       slot.state_row, out=tables[i])
-            args = (jnp.asarray(tokens), jnp.asarray(positions),
-                    jnp.asarray(tables))
+            rest = (positions, tables)
         elif bucket == self.n_slots:
-            args = (jnp.asarray(tokens), jnp.asarray(positions))
+            rest = (positions,)
         else:
-            args = (jnp.asarray(tokens),
-                    jnp.asarray(np.asarray(rows, np.int32)),
-                    jnp.asarray(positions))
+            rest = (np.asarray(rows, np.int32), positions)
+        # ONE transfer for the host's operands; ``prev`` is on the
+        # device already
+        tokens, src, *rest = jax.device_put((tokens, src) + rest)
+        args = (tokens,
+                pend.toks if pend is not None else self._no_prev(bucket),
+                src, *rest)
         self._guard_call(self._cache_sig, args)
-        return rows, k, bucket, exe, args
+        return rows, held, k, bucket, exe, args
+
+    def _no_prev(self, bucket):
+        """The ``prev`` operand of a call that takes every token from
+        the host (``src`` all -1): zeros of a decode result's shape,
+        made once a bucket."""
+        zeros = self._zero_prev.get(bucket)
+        if zeros is None:
+            zeros = self._zero_prev[bucket] = jnp.zeros(
+                (bucket + len(self.model.serve_counters),), jnp.int32)
+        return zeros
 
     def _decode_once(self, clock):
-        """One decode step over every active slot, compacted to the
-        smallest slot-count bucket; finished sequences resolve and
-        free their slots (refilled at the NEXT step).  Three spans
-        split it: ``serve_decode_prep`` (numpy operands and their
-        upload), ``serve_decode`` (the executable until the tokens are
-        on the host), ``serve_emit`` (the per-slot loop)."""
+        """One decode tick, pipelined ONE call deep: call t+1 is
+        dispatched with call t's tokens still on the device (the
+        executable takes them from call t's result, :func:`_merged`),
+        and only then is call t's vector read and emitted, so the host
+        prepares, reads and emits under a running call.  A slot's
+        position, its remaining count and its page table advance at
+        DISPATCH; ``tokens_generated`` and ``decode_steps`` count at
+        emit.  Three spans split the tick: ``serve_decode_prep``
+        (numpy operands and their upload), ``serve_decode`` (the
+        dispatch of call t+1, then the wait for call t's vector),
+        ``serve_emit`` (the per-slot loop over call t's tokens).
+
+        The call in flight is SETTLED first (read and emitted, nothing
+        dispatched ahead of it) where a row of it ends in it by length
+        -- then the tick hands back, the caller refills the slot, and
+        the next tick admits, prefills and dispatches with the new row
+        in the call: a refilled slot misses no call and no prefill
+        queues behind a decode call dispatched ahead of it -- and
+        where the next call's bucket is another (its ``prev`` has the
+        other bucket's shape).  An end the host cannot foresee (an
+        EOS) is found when its call is read, one call late: the row's
+        token of the call already in flight is dropped."""
+        pend = self._inflight
+        if pend is not None and pend.ends:
+            self._settle(clock)
+            return
         ident = self._ident()
         with _telemetry.span('serve_decode_prep', kind='serve',
                              step=self._step_index, **ident):
-            operands = self._decode_operands()
+            operands = self._decode_operands(pend)
+        if pend is not None and (operands is None
+                                 or operands[-1] is None):
+            # occupancy crossed a decode edge (or every row was shed)
+            self._settle(clock)
+            pend = None
+            with _telemetry.span('serve_decode_prep', kind='serve',
+                                 step=self._step_index, **ident):
+                operands = self._decode_operands(None)
         if operands is None:
             return
-        rows, k, bucket, exe, args = operands
+        rows, held, k, bucket, exe, args = operands
         rec = _telemetry.live()
         reg = _telemetry.registry()
         if reg is not None:
@@ -1681,46 +1821,95 @@ class GenerationEngine:
             _chaos.on_serve_slow(
                 self.param_version != self._boot_version)
         t0 = clock()
-        with _telemetry.span('serve_decode', kind='serve',
-                             iteration=self._step_index,
-                             step=self._step_index,
-                             active_slots=k, bucket=bucket,
-                             n_slots=self.n_slots,
-                             queue_depth=self._last_queue_depth,
-                             **ident) as span:
+        # one span a launched call: it carries ``ran_ahead`` of the
+        # call it dispatches and every other attribute of the call
+        # whose vector it READS (a tick later; a settle's span reads
+        # and dispatches nothing, the span after it the reverse)
+        with self._decode_span(
+                ran_ahead=int(pend is not None)) as span:
             toks, cache = exe(self.params, self._cache, *args)
             # rebound BEFORE the wait (here and at every call of the
             # tick): the donated cache is a husk per layer, and they
             # die while the device runs, not after it
             self._cache = cache
-            toks, counters = self._split_sampled(
-                jax.block_until_ready(toks))
-            if rec is not None:
-                span.set(**counters, **self._attended(rows))
+            toks.copy_to_host_async()
+            attrs = None
+            if rec is not None:     # before the slots advance
+                attrs = self._attended(
+                    [slot.position + 1 for slot in held
+                     if slot is not None], bucket)
+            last = [False] * bucket
+            for i, slot in enumerate(held):
+                if slot is not None:
+                    slot.position += 1
+                    slot.remaining -= 1
+                    last[i] = slot.remaining == 0
+            self._inflight = _Flight(toks, rows, held, last, k, bucket,
+                                     attrs)
+            self.decode_calls += 1
+            if pend is not None:
+                self.decode_calls_ahead += 1
+                read = self._read(pend, span)
+        if pend is not None:
+            self._emit(pend, read, t0, clock)
+
+    def _settle(self, clock=time.monotonic):
+        """Read the call in flight, if there is one, and emit its
+        tokens: after it nothing of the scheduler's is on the device
+        and every slot's newest token is the host's."""
+        with self._lock:
+            pend, self._inflight = self._inflight, None
+        if pend is None:
+            return
+        t0 = clock()
+        with self._decode_span() as span:
+            read = self._read(pend, span)
+        self._emit(pend, read, t0, clock)
+
+    def _decode_span(self, **attrs):
+        return _telemetry.span('serve_decode', kind='serve',
+                               iteration=self._step_index,
+                               step=self._step_index,
+                               n_slots=self.n_slots,
+                               queue_depth=self._last_queue_depth,
+                               **attrs, **self._ident())
+
+    def _read(self, pend, span):
+        """Wait for a dispatched call's vector (its copy to the host
+        began at dispatch) and split it; ``span`` gets what its
+        readers take of a decode call."""
+        toks, counters = self._split_sampled(pend.toks)
+        span.set(active_slots=pend.k, bucket=pend.bucket, **counters,
+                 **(pend.attrs or {}))
+        return toks
+
+    def _emit(self, pend, toks, t0, clock):
+        """A read call's tokens to their requests; finished rows
+        resolve and free their slots (refilled at the NEXT step).  A
+        row whose slot is no longer the one it was dispatched for is
+        dropped: its request is dead (expired, shed) or ended a call
+        earlier."""
+        rec = _telemetry.live()
+        reg = _telemetry.registry()
+        ident = self._ident()
         now = clock()
         now_tele = rec.now() if rec is not None else None
-        if reg is not None:
-            reg.histogram('serve_decode_seconds',
-                          help='per-decode-step wall time (s)'
-                          ).observe(now - t0)
-            reg.counter('serve_tokens_total',
-                        help='generated tokens').inc(k)
         itl = (reg.histogram('serve_intertoken_seconds',
                              help='per-sequence gap between '
                                   'consecutive tokens (s)')
                if reg is not None else None)
+        emitted = 0
         with _telemetry.span('serve_emit', kind='serve',
-                             step=self._step_index, active_slots=k,
-                             **ident):
-            for i, sid in enumerate(rows):
-                slot = self._slots.get(sid)
-                if slot is None:
-                    continue   # free pad row (or inactive full row)
+                             step=self._step_index,
+                             active_slots=pend.k, **ident):
+            for i, sid in enumerate(pend.rows):
+                slot = pend.slots[i]
+                if slot is None or self._slots.get(sid) is not slot:
+                    continue   # a pad row, or a token nobody is owed
                 tok = int(toks[i])
                 slot.generated.append(tok)
                 slot.request.notify_tokens([tok])
-                slot.position += 1
-                slot.remaining -= 1
+                emitted += 1
                 if itl is not None:
                     itl.observe(now - slot.t_last_token)
                 slot.t_last_token = now
@@ -1740,28 +1929,36 @@ class GenerationEngine:
                                    token_index=len(slot.generated) - 1,
                                    **ident)
                     slot.t_stage_end = now_tele
-                if slot.remaining == 0 or (self.eos_id is not None
-                                           and tok == self.eos_id):
+                if pend.last[i] or (self.eos_id is not None
+                                    and tok == self.eos_id):
                     slot.request.set_result(slot.generated)
                     if rec is not None:
                         rec.event('complete', kind='request',
                                   request_id=slot.request.request_id,
                                   tokens=len(slot.generated), slot=sid,
                                   **ident)
+                    # after an EOS the row is in the call in flight
+                    # too, which writes these pages: safe because
+                    # whoever gets them next writes them in a LATER
+                    # call, ordered behind it on the one device stream
                     self._release_pages(slot.pages, slot.ring,
                                         slot.state_row)
                     del self._slots[sid]
                     self._free.append(sid)
         self.decode_steps += 1
-        self.tokens_generated += k
+        self.tokens_generated += emitted
+        if reg is not None:
+            reg.histogram('serve_decode_seconds',
+                          help='per-decode-step wall time (s)'
+                          ).observe(now - t0)
+            reg.counter('serve_tokens_total',
+                        help='generated tokens').inc(emitted)
 
-    def _attended(self, rows):
-        """Positions this decode step's rows attend, by layer kind:
-        every live position in a full layer, at most the window in a
-        window layer (what the kernels' roofline shares count bytes
-        from)."""
-        live = [self._slots[s].position + 1 for s in rows
-                if s in self._slots]
+    def _attended(self, live, n_rows):
+        """Positions a decode call's rows attend, by layer kind, from
+        the live rows' lengths: every live position in a full layer,
+        at most the window in a window layer (what the kernels'
+        roofline shares count bytes from)."""
         out = {'kv_positions': sum(live)}
         if self._window is not None:
             out['kv_window_positions'] = sum(
@@ -1772,7 +1969,7 @@ class GenerationEngine:
             # position 0 of the scratch page), every layer's call
             tp = (self.plan.mesh.shape[self.plan.model_axis]
                   if self.plan is not None else 1)
-            lengths = live + [1] * (len(rows) - len(live))
+            lengths = live + [1] * (n_rows - len(live))
             out['kv_pages_read'], out['kv_grid_steps'] = (
                 self.model.decode_paged_grid(
                     self._cache_struct, lengths, self.pages_per_seq,
@@ -1795,6 +1992,9 @@ class GenerationEngine:
         paged mode the page-table tail past the accepted boundary is
         released back to the pool so refcounts track committed tokens
         only."""
+        # a verify's acceptance decides the next draft: data the host
+        # must read, so this tick stays synchronous
+        self._settle(clock)
         kk = self.spec_tokens
         if self.paged:
             # grow page tables to cover the WHOLE window [position,
@@ -2119,6 +2319,11 @@ class GenerationEngine:
             else:
                 self._decode_once(clock)
             worked = True
+        elif self._inflight is not None:
+            # every row of the call in flight has gone since (expired,
+            # shed, ended on an EOS found a call late): nothing is owed
+            self._settle(clock)
+            worked = True
         if not worked:
             return False
         self._step_index += 1
@@ -2194,6 +2399,10 @@ class GenerationEngine:
             'compile_count': self.compile_count,
             'prefills': self.prefills,
             'decode_steps': self.decode_steps,
+            # of the decode calls dispatched, the share that went out
+            # before their predecessor's tokens were read (0 to 1)
+            'decode_runahead_share': (
+                self.decode_calls_ahead / max(self.decode_calls, 1)),
             'tokens_generated': self.tokens_generated,
             'cancelled': self.cancelled,
             'active_slots': len(self._slots),

@@ -246,8 +246,14 @@ def test_decode_span_says_how_the_paged_kernels_grid_engaged():
     request = queue.submit([1, 2, 3, 4, 5], 12)
     while not request.done():
         eng.step(queue)
-    decode = [r for r in recorder.events if r.get('type') == 'span'
-              and r['name'] == 'serve_decode']
+    spans = [r for r in recorder.events if r.get('type') == 'span'
+             and r['name'] == 'serve_decode']
+    # a span that dispatches a call says whether it ran ahead of its
+    # predecessor's read; the span that READS a call's vector, a tick
+    # later, says what the call was: one of each a launched call
+    assert [r['ran_ahead'] for r in spans
+            if 'ran_ahead' in r] == [0] + [1] * 10
+    decode = [r for r in spans if 'bucket' in r]
     assert len(decode) == 11
     leaf = eng._cache_struct['k'][0]
     for i, r in enumerate(decode):
