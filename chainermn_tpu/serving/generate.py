@@ -47,9 +47,13 @@ the decode step is bound by.
 
 The cache is DONATED into every prefill/decode executable and the
 returned buffer rebound, so steady-state decode allocates nothing
-cache-sized.  Telemetry: ``serve_prefill``/``serve_decode`` spans
-(``iteration`` = decode step index), a per-step ``active_slots``
-gauge, ``serve_ttft_seconds`` / ``serve_intertoken_seconds`` /
+cache-sized.  Telemetry: one ``serve_tick`` span a scheduler tick
+whose children tile it (``docs/serving.md``, "The tick's anatomy"),
+among them ``serve_prefill``/``serve_decode`` (``iteration`` = decode
+step index) with the dispatch and the wait as spans of their own, a
+``device_idle`` record where a launch found the device starved, a
+per-step ``active_slots`` gauge, ``serve_ttft_seconds`` /
+``serve_intertoken_seconds`` /
 ``serve_decode_seconds`` raw-sample histograms and
 ``serve_tokens_total`` -- the ``telemetry report``/``doctor`` serve
 section renders tokens/s and TTFT from them (``docs/serving.md``).
@@ -64,6 +68,7 @@ import jax
 import jax.numpy as jnp
 
 from chainermn_tpu import telemetry as _telemetry
+from chainermn_tpu.telemetry import NULL_SPAN
 from chainermn_tpu.analysis.walker import abstract_signature
 from chainermn_tpu.serving.batcher import (bucket_edges, bucket_of,
                                            next_request_id,
@@ -74,6 +79,16 @@ from chainermn_tpu.utils.failure import OverloadError
 
 #: default admission knobs (the generation twins of batcher's)
 DEFAULT_MAX_QUEUE = 256
+
+#: why a decode call did not go out ahead of its predecessor's read
+#: (``reason`` on its ``serve_decode`` span, ``stats()['settles']``):
+#: the call in flight was settled for a row's foreseen ``end``, a
+#: change of ``bucket``, a ``drained`` table, a ``spec``ulative tick or
+#: ``swap_params``; or it is the call that ``prime``s the pipeline
+#: after one of those
+SETTLE_REASONS = ('end', 'bucket', 'prime', 'drained', 'spec', 'swap')
+#: a wait shorter than this found its vector ready (seconds)
+_BLOCKED_S = 50e-6
 
 
 def _as_given(*operands):
@@ -729,6 +744,9 @@ class GenerationEngine:
         self.decode_steps = 0
         self.decode_calls = 0        # decode calls dispatched ...
         self.decode_calls_ahead = 0  # ... before their predecessor was read
+        # ... and why the others were not (SETTLE_REASONS)
+        self.settles = dict.fromkeys(SETTLE_REASONS, 0)
+        self.admissions = 0          # requests popped from the queue
         self.draft_steps = 0
         self.verify_steps = 0
         self.draft_proposed = 0
@@ -737,6 +755,16 @@ class GenerationEngine:
         self.cancelled = 0
         self._step_index = 0
         self._last_queue_depth = 0
+        # the starved-device probe (a recorder live only): the result
+        # of the newest call launched, the first boundary that saw it
+        # ready ``(t, after, exact)``, what the next priming decode
+        # call follows (``end`` | ``other``: the last settle's reason)
+        # and whether a prefill went out since the last decode call
+        self._last_call = None
+        self._idle_since = None
+        self._primes_after = 'other'
+        self._admitting = False
+        self._gauges = None   # the per-tick gauges, looked up once
 
     # -- sharding ------------------------------------------------------
     def _param_sharding(self):
@@ -790,7 +818,9 @@ class GenerationEngine:
                 version=version)
         # a call may still be in flight whose every row has gone
         # (expired, shed, ended on an EOS found a call late)
-        self._settle()
+        self._settle('swap')
+        # the validation decode below is not the scheduler's call
+        self._last_call = self._idle_since = None
         new = self._place_params(params)
         if validate and self.n_slots in self._decode:
             exe = self._decode[self.n_slots][0]
@@ -1216,6 +1246,68 @@ class GenerationEngine:
                 'geometry' % (sig,))
         return sig
 
+    # -- the tick's own account (a recorder live only) ------------------
+    def _phase(self, rec, name, **attrs):
+        """One child span of the tick on ``rec``: every line of
+        :meth:`_tick` runs under exactly one, so what ``serve_tick``
+        leaves uncovered is a few attribute loads.  Its end is a
+        boundary of :meth:`_probe`.  Call sites guard on the recorder
+        (``... if rec is not None else NULL_SPAN``), so with telemetry
+        off no argument is built."""
+        span = rec.span(name, kind='serve', step=self._step_index,
+                        **attrs, **self._ident())
+        span.at_exit = self._phase_end
+        return span
+
+    def _phase_end(self, span):
+        self._probe(span.recorder, span.name)
+
+    def _probe(self, rec, after):
+        """The starved-device probe's boundary: has the device finished
+        the newest call the engine launched?  ``is_ready()`` does not
+        block.  The FIRST boundary that sees it ready is kept, with the
+        name of the span that had just ended (``client``: the time
+        between two ticks)."""
+        if self._idle_since is None and self._last_call is not None \
+                and self._last_call.is_ready():
+            self._idle_since = (rec.now(), after, 0)
+
+    def _launching(self, rec, t_launch, cause):
+        """Called where a call is about to go out, ``t_launch`` its
+        dispatch span's start: where a boundary saw the predecessor
+        done, ONE ``device_idle`` record from that boundary to the
+        launch.
+
+        The record is a LOWER bound on the time the device had nothing
+        to run: the launch's own latency and the time before the first
+        boundary that noticed are left out.  ``exact`` = 1 where its
+        start is the return of a read that had to wait for the call
+        (the return IS the call's end).  ``cause`` is ``admission`` (a
+        prefill, or the first decode call after one, whether it primes
+        the pipeline or goes out ahead of a call in flight, a settle
+        between them or none), ``end`` (a priming call after a settle
+        for a foreseen end or a change of bucket, no prefill since the
+        last decode call), ``steady`` (a call that went out ahead and
+        still found its predecessor done) or ``other`` (a drained
+        table, a speculative tick, ``swap_params``)."""
+        idle, self._idle_since = self._idle_since, None
+        if idle is not None:
+            t0, after, exact = idle
+            rec.interval('device_idle', t0, t_launch, kind='serve',
+                         after=after, cause=cause, exact=exact,
+                         step=self._step_index, **self._ident())
+
+    def _waited(self, rec, wait, result):
+        """The end of a ``serve_*_wait`` span, a probe boundary: a read
+        that BLOCKED on the newest call launched returned when the
+        device finished it, so the device is idle from that instant,
+        exactly."""
+        if self._last_call is result \
+                and wait.t1 - wait.t0 > _BLOCKED_S:
+            self._idle_since = (wait.t1, wait.name, 1)
+        else:
+            self._probe(rec, wait.name)
+
     # -- the continuous-batching scheduler -----------------------------
     def _expire(self, now, force=0):
         """Shed active requests whose deadline passed (or the
@@ -1347,33 +1439,40 @@ class GenerationEngine:
         (bucketed by prompt length), TTFT recorded when its first
         token lands.  With telemetry on, each admitted request gets
         its trace stages recorded: ``queue_wait`` (admission stamp ->
-        pop), ``bucket_pack`` (pop -> prefill dispatch, carrying the
-        prompt bucket + pad fraction) and ``prefill`` (-> first
+        pop), ``admit_wait`` (pop -> the scheduler reaches THIS
+        request: the prefills of the requests popped with it, counted
+        in ``behind``), ``bucket_pack`` (-> prefill dispatch, carrying
+        the prompt bucket + pad fraction) and ``prefill`` (-> first
         token), each starting where the previous ended."""
-        if self.paged:
-            # no executable runs in here but the rare copy-on-write
-            # page copy: queue pop, prefix lookup, page allocation
-            with _telemetry.span('serve_admit', kind='serve',
-                                 step=self._step_index,
-                                 **self._ident()):
-                self._admit_paged(queue, now, clock)
-            return
         rec = _telemetry.live()
+        with (self._phase(rec, 'serve_admit') if rec is not None
+              else NULL_SPAN):
+            if self.paged:
+                # no executable runs in here but the rare copy-on-write
+                # page copy: queue pop, prefix lookup, page allocation
+                self._admit_paged(queue, now, clock)
+            else:
+                # the slot cache prefills where it admits
+                self._admit_slots(queue, clock, rec)
+
+    def _admit_slots(self, queue, clock, rec):
         reg = _telemetry.registry()
         ident = self._ident()
-        with _telemetry.span('serve_admit', kind='serve',
-                             step=self._step_index, **ident):
-            popped = queue.pop(self._admit_budget())
-        for req in popped:
+        popped = queue.pop(self._admit_budget())
+        self.admissions += len(popped)
+        t_pop = rec.now() if rec is not None else None
+        for behind, req in enumerate(popped):
             sid = self._free.pop(0)
             prompt = req.prompt
-            t_pop = rec.now() if rec is not None else None
             if rec is not None:
                 t0 = req.t_trace0
                 if t0 is None:   # telemetry enabled mid-flight
                     t0 = t_pop - (clock() - req.t_submit)
                 rec.child_span(req.request_id, 'queue_wait', t0,
                                t_pop, seq=req.seq, **ident)
+                t_reach = rec.now()
+                rec.child_span(req.request_id, 'admit_wait', t_pop,
+                               t_reach, behind=behind, **ident)
             bucket = bucket_of(prompt.size, self.prefill_edges)
             tokens = np.zeros((1, bucket), np.int32)
             tokens[0, :prompt.size] = prompt
@@ -1385,36 +1484,23 @@ class GenerationEngine:
             t_pf0 = rec.now() if rec is not None else None
             if rec is not None:
                 rec.child_span(
-                    req.request_id, 'bucket_pack', t_pop, t_pf0,
+                    req.request_id, 'bucket_pack', t_reach, t_pf0,
                     bucket=bucket, pad_fraction=round(
                         (bucket - prompt.size) / float(bucket), 4),
                     **ident)
             if _chaos._active is not None:
                 _chaos.on_serve_slow(
                     self.param_version != self._boot_version)
-            with _telemetry.span('serve_prefill', kind='serve',
-                                 bucket=bucket, slot=sid,
-                                 iteration=self._step_index,
-                                 step=self._step_index, **ident):
-                tok, cache = exe(self.params, self._cache, *args)
-                self._cache = cache
-                tok = int(jax.block_until_ready(tok))
+            with (self._phase(rec, 'serve_prefill', bucket=bucket,
+                              slot=sid, iteration=self._step_index)
+                  if rec is not None else NULL_SPAN):
+                tok = int(self._prefill_call(rec, exe, args))
             if self.speculative:
                 # the draft prefills the same prompt into ITS cache at
                 # the same slot (its proposals need the prompt's K/V);
                 # the draft's own first-token logits are discarded --
                 # the target's token is authoritative
-                dexe = self._get_draft_prefill(bucket)
-                self._guard_call(self._draft_cache_sig, args)
-                with _telemetry.span('serve_draft', kind='serve',
-                                     stage='prefill', bucket=bucket,
-                                     slot=sid,
-                                     iteration=self._step_index,
-                                     step=self._step_index, **ident):
-                    dtok, dcache = dexe(self._draft_params,
-                                        self._draft_cache, *args)
-                    self._draft_cache = dcache
-                    jax.block_until_ready(dtok)
+                self._draft_prefill_call(rec, bucket, args, slot=sid)
             self.prefills += 1
             self.tokens_generated += 1
             t_first = clock()
@@ -1447,6 +1533,46 @@ class GenerationEngine:
                                      t_first,
                                      t_stage_end=t_first_tele)
 
+    def _prefill_call(self, rec, exe, args):
+        """One prefill call inside its ``serve_prefill`` span, read
+        back before the tick goes on: the dispatch and the wait for
+        its result are a child span each.  Hands back the sampled
+        vector."""
+        with (rec.span('serve_prefill_dispatch', kind='serve',
+                       step=self._step_index)
+              if rec is not None else NULL_SPAN) as go:
+            if rec is not None:
+                self._launching(rec, go.t0, 'admission')
+            tok, cache = exe(self.params, self._cache, *args)
+            self._cache = cache
+        with (rec.span('serve_prefill_wait', kind='serve',
+                       step=self._step_index)
+              if rec is not None else NULL_SPAN) as wait:
+            jax.block_until_ready(tok)
+        if rec is not None:
+            self._last_call = tok
+            self._admitting = True
+            self._waited(rec, wait, tok)
+        return tok
+
+    def _draft_prefill_call(self, rec, width, args, **where):
+        """The draft's prefill of the same tokens into ITS cache, read
+        back before the tick goes on (``serve_draft``)."""
+        dexe = self._get_draft_prefill(width)
+        self._guard_call(self._draft_cache_sig, args)
+        with (self._phase(rec, 'serve_draft', stage='prefill',
+                          bucket=width, iteration=self._step_index,
+                          **where)
+              if rec is not None else NULL_SPAN) as span:
+            if rec is not None:
+                self._launching(rec, span.t0, 'other')
+            dtok, dcache = dexe(self._draft_params,
+                                self._draft_cache, *args)
+            self._draft_cache = dcache
+            if rec is not None:
+                self._last_call = dtok
+            jax.block_until_ready(dtok)
+
     def _admit_paged(self, queue, now, clock):
         """Paged admission: claim a slot id, walk the prefix index for
         the longest banked prefix (retaining shared FULL pages; a
@@ -1458,7 +1584,9 @@ class GenerationEngine:
         reg = _telemetry.registry()
         ident = self._ident()
         group = self._prefix_index is not None
-        for req in queue.pop(self._admit_budget(), group_prefix=group):
+        popped = queue.pop(self._admit_budget(), group_prefix=group)
+        self.admissions += len(popped)
+        for req in popped:
             sid = self._free.pop(0)
             prompt = req.prompt
             t_pop = rec.now() if rec is not None else None
@@ -1525,141 +1653,152 @@ class GenerationEngine:
         -- emits the ``prefill`` trace stage (so TTFT accounting is
         unchanged); intermediate chunks emit ``prefill_chunk`` spans
         the SLO monitor ignores.  A finished prompt's pages are banked
-        into the prefix index before the sequence moves to decode."""
+        into the prefix index before the sequence moves to decode.
+
+        Three spans a sequence: ``serve_prefill_prep`` (pages, the
+        operands and their upload), ``serve_prefill`` (the call:
+        ``serve_prefill_dispatch`` and ``serve_prefill_wait`` under
+        it) and, behind a final chunk, ``serve_emit`` with ``first=1``
+        (the first token's notify, the prefix insert, the slot).  The
+        sequences are served one after the other, each read back
+        before the next: a request's ``admit_wait`` stage runs from
+        its pop to the moment this loop reaches it, ``behind`` the
+        prefill calls the tick ran before it."""
         rec = _telemetry.live()
         reg = _telemetry.registry()
         ident = self._ident()
         worked = False
+        calls = 0
         for sid in sorted(self._prefilling):
             st = self._prefilling[sid]
             req = st.request
             prompt = req.prompt
-            remaining = prompt.size - st.pos
-            if self.prefill_chunk is not None:
-                width = self.prefill_chunk
-            else:
-                width = bucket_of(remaining, self.prefill_edges)
-            n = min(width, remaining)
-            last_page = (st.pos + n - 1) // self.page_size
-            dry = False
-            while len(st.pages) <= last_page:
-                page = self._alloc_page()
-                if page is None:
-                    dry = True
-                    break
-                st.pages.append(page)
-            if dry:
-                del self._prefilling[sid]
-                self._shed_paged(req, st.pages, 'prefill', st.ring,
-                                 st.state_row)
-                self._free.append(sid)
-                continue
-            self._grow_ring(st.ring, last_page)
-            worked = True
-            tokens = np.zeros((1, width), np.int32)
-            tokens[0, :n] = prompt[st.pos:st.pos + n]
-            exe = self._get_prefill(width)
-            args = (jnp.asarray(tokens),
-                    jnp.asarray(n, jnp.int32),
-                    jnp.asarray(st.pos, jnp.int32),
-                    jnp.asarray(self._table_array(st.pages, st.ring,
-                                                  st.state_row)))
-            self._guard_call(self._cache_sig, args)
-            if rec is not None and st.chunks == 0:
-                t_c0 = rec.now()
-                rec.child_span(
-                    req.request_id, 'bucket_pack', st.t_stage_end,
-                    t_c0, bucket=width,
-                    pad_fraction=round((width - n) / float(width), 4),
-                    prefix_tokens=st.matched, **ident)
-                st.t_stage_end = t_c0
-            if _chaos._active is not None:
-                _chaos.on_serve_slow(
-                    self.param_version != self._boot_version)
-            with _telemetry.span('serve_prefill', kind='serve',
-                                 bucket=width, slot=sid, tokens=n,
-                                 chunk=st.chunks, pos=st.pos,
-                                 iteration=self._step_index,
-                                 step=self._step_index,
-                                 **ident) as span:
-                tok, cache = exe(self.params, self._cache, *args)
-                self._cache = cache
+            with (self._phase(rec, 'serve_prefill_prep', slot=sid)
+                  if rec is not None else NULL_SPAN):
+                if rec is not None and st.chunks == 0:
+                    t_reach = rec.now()
+                    rec.child_span(req.request_id, 'admit_wait',
+                                   st.t_stage_end, t_reach,
+                                   behind=calls, **ident)
+                    st.t_stage_end = t_reach
+                remaining = prompt.size - st.pos
+                if self.prefill_chunk is not None:
+                    width = self.prefill_chunk
+                else:
+                    width = bucket_of(remaining, self.prefill_edges)
+                n = min(width, remaining)
+                last_page = (st.pos + n - 1) // self.page_size
+                dry = False
+                while len(st.pages) <= last_page:
+                    page = self._alloc_page()
+                    if page is None:
+                        dry = True
+                        break
+                    st.pages.append(page)
+                if dry:
+                    del self._prefilling[sid]
+                    self._shed_paged(req, st.pages, 'prefill', st.ring,
+                                     st.state_row)
+                    self._free.append(sid)
+                    continue
+                self._grow_ring(st.ring, last_page)
+                worked = True
+                tokens = np.zeros((1, width), np.int32)
+                tokens[0, :n] = prompt[st.pos:st.pos + n]
+                exe = self._get_prefill(width)
+                args = (jnp.asarray(tokens),
+                        jnp.asarray(n, jnp.int32),
+                        jnp.asarray(st.pos, jnp.int32),
+                        jnp.asarray(self._table_array(
+                            st.pages, st.ring, st.state_row)))
+                self._guard_call(self._cache_sig, args)
+                if rec is not None and st.chunks == 0:
+                    t_c0 = rec.now()
+                    rec.child_span(
+                        req.request_id, 'bucket_pack', st.t_stage_end,
+                        t_c0, bucket=width, pad_fraction=round(
+                            (width - n) / float(width), 4),
+                        prefix_tokens=st.matched, **ident)
+                    st.t_stage_end = t_c0
+                if _chaos._active is not None:
+                    _chaos.on_serve_slow(
+                        self.param_version != self._boot_version)
+            chunk = st.chunks
+            with (self._phase(rec, 'serve_prefill', bucket=width,
+                              slot=sid, tokens=n, chunk=chunk,
+                              pos=st.pos, iteration=self._step_index)
+                  if rec is not None else NULL_SPAN) as span:
                 tok, counters = self._split_sampled(
-                    jax.block_until_ready(tok))
+                    self._prefill_call(rec, exe, args))
                 span.set(**counters)
+                calls += 1
+                st.pos += n
+                st.chunks += 1
+                self.prefill_chunks += 1
+                final = st.pos >= prompt.size
+                if rec is not None and not final:
+                    now_tele = rec.now()
+                    rec.child_span(req.request_id, 'prefill_chunk',
+                                   st.t_stage_end, now_tele,
+                                   bucket=width, slot=sid, chunk=chunk,
+                                   pos=st.pos, **ident)
+                    st.t_stage_end = now_tele
             if self.speculative:
                 # same chunk, same pages, into the draft cache: banked
                 # prefix pages stay valid for BOTH caches, so a future
                 # prefix hit serves the draft too
-                dexe = self._get_draft_prefill(width)
-                self._guard_call(self._draft_cache_sig, args)
-                with _telemetry.span('serve_draft', kind='serve',
-                                     stage='prefill', bucket=width,
-                                     slot=sid, chunk=st.chunks,
-                                     iteration=self._step_index,
-                                     step=self._step_index, **ident):
-                    dtok, dcache = dexe(self._draft_params,
-                                        self._draft_cache, *args)
-                    self._draft_cache = dcache
-                    jax.block_until_ready(dtok)
-            st.pos += n
-            st.chunks += 1
-            self.prefill_chunks += 1
-            if st.pos < prompt.size:
+                self._draft_prefill_call(rec, width, args, slot=sid,
+                                         chunk=chunk)
+            if not final:
+                continue
+            with (self._phase(rec, 'serve_emit', first=1, slot=sid)
+                  if rec is not None else NULL_SPAN):
+                tok = int(tok.reshape(-1)[0])
+                del self._prefilling[sid]
+                self.prefills += 1
+                self.tokens_generated += 1
+                t_first = clock()
+                t_first_tele = None
                 if rec is not None:
-                    now_tele = rec.now()
-                    rec.child_span(req.request_id, 'prefill_chunk',
-                                   st.t_stage_end, now_tele,
+                    t_first_tele = rec.now()
+                    rec.child_span(req.request_id, 'prefill',
+                                   st.t_stage_end, t_first_tele,
                                    bucket=width, slot=sid,
-                                   chunk=st.chunks - 1, pos=st.pos,
-                                   **ident)
-                    st.t_stage_end = now_tele
-                continue
-            tok = int(tok.reshape(-1)[0])
-            del self._prefilling[sid]
-            self.prefills += 1
-            self.tokens_generated += 1
-            t_first = clock()
-            t_first_tele = None
-            if rec is not None:
-                t_first_tele = rec.now()
-                rec.child_span(req.request_id, 'prefill',
-                               st.t_stage_end, t_first_tele,
-                               bucket=width, slot=sid,
-                               prompt_tokens=int(prompt.size),
-                               chunks=st.chunks,
-                               prefix_tokens=st.matched, **ident)
-            if reg is not None:
-                reg.histogram(
-                    'serve_ttft_seconds',
-                    help='submit-to-first-token latency (s)'
-                ).observe(t_first - req.t_submit)
-                reg.counter('serve_tokens_total',
-                            help='generated tokens').inc()
-            if self._prefix_index is not None:
-                n_cover = -(-prompt.size // self.page_size)
-                self._prefix_index.insert(prompt,
-                                          st.pages[:n_cover])
-            req.notify_tokens([tok])
-            if self.eos_id is not None and tok == self.eos_id \
-                    or req.max_new_tokens == 1:
-                req.set_result([tok])
-                self._release_pages(st.pages, st.ring, st.state_row)
-                self._free.append(sid)
-                if rec is not None:
-                    rec.event('complete', kind='request',
-                              request_id=req.request_id, tokens=1,
-                              slot=sid, **ident)
-                continue
-            self._slots[sid] = _Slot(req, prompt.size,
-                                     req.max_new_tokens - 1, tok,
-                                     t_first,
-                                     t_stage_end=t_first_tele,
-                                     pages=st.pages, ring=st.ring,
-                                     state_row=st.state_row)
+                                   prompt_tokens=int(prompt.size),
+                                   chunks=st.chunks,
+                                   prefix_tokens=st.matched, **ident)
+                if reg is not None:
+                    reg.histogram(
+                        'serve_ttft_seconds',
+                        help='submit-to-first-token latency (s)'
+                    ).observe(t_first - req.t_submit)
+                    reg.counter('serve_tokens_total',
+                                help='generated tokens').inc()
+                if self._prefix_index is not None:
+                    n_cover = -(-prompt.size // self.page_size)
+                    self._prefix_index.insert(prompt,
+                                              st.pages[:n_cover])
+                req.notify_tokens([tok])
+                if self.eos_id is not None and tok == self.eos_id \
+                        or req.max_new_tokens == 1:
+                    req.set_result([tok])
+                    self._release_pages(st.pages, st.ring,
+                                        st.state_row)
+                    self._free.append(sid)
+                    if rec is not None:
+                        rec.event('complete', kind='request',
+                                  request_id=req.request_id, tokens=1,
+                                  slot=sid, **ident)
+                    continue
+                self._slots[sid] = _Slot(req, prompt.size,
+                                         req.max_new_tokens - 1, tok,
+                                         t_first,
+                                         t_stage_end=t_first_tele,
+                                         pages=st.pages, ring=st.ring,
+                                         state_row=st.state_row)
         return worked
 
-    def _decode_operands(self, pend):
+    def _decode_operands(self, pend, rec=None):
         """What the next decode call is called with: the rows (slot
         ids, padded to the smallest slot-count bucket), the
         :class:`_Slot` each holds, the live count, the bucket, its
@@ -1671,7 +1810,10 @@ class GenerationEngine:
         advances a slot (:meth:`_decode_once` does, at dispatch), so
         it may be asked twice in a tick: where ``pend`` is of another
         bucket its vector cannot feed this call, nothing is uploaded
-        and the operands come back None -- settle, and ask again."""
+        and the operands come back None -- settle, and ask again.
+        With a recorder live (``rec``) the row loops are boundaries of
+        the starved-device probe too: this is the longest host phase
+        of a steady tick, and the call in flight may end inside it."""
         if self.paged:
             # grow page tables across page boundaries BEFORE dispatch
             # (a sequence whose next token starts a new page gets one
@@ -1725,6 +1867,8 @@ class GenerationEngine:
         src = np.full((bucket,), -1, np.int32)
         positions = np.zeros((bucket,), np.int32)
         row_of = pend.row_of if pend is not None else {}
+        if rec is not None:
+            self._probe(rec, 'serve_decode_prep')
         for i, slot in enumerate(held):
             if slot is None:
                 continue
@@ -1745,6 +1889,8 @@ class GenerationEngine:
                 if slot is not None:
                     self._table_array(slot.pages, slot.ring,
                                       slot.state_row, out=tables[i])
+                if rec is not None and not i % 8:
+                    self._probe(rec, 'serve_decode_prep')
             rest = (positions, tables)
         elif bucket == self.n_slots:
             rest = (positions,)
@@ -1752,6 +1898,8 @@ class GenerationEngine:
             rest = (np.asarray(rows, np.int32), positions)
         # ONE transfer for the host's operands; ``prev`` is on the
         # device already
+        if rec is not None:
+            self._probe(rec, 'serve_decode_prep')
         tokens, src, *rest = jax.device_put((tokens, src) + rest)
         args = (tokens,
                 pend.toks if pend is not None else self._no_prev(bucket),
@@ -1778,8 +1926,9 @@ class GenerationEngine:
         position, its remaining count and its page table advance at
         DISPATCH; ``tokens_generated`` and ``decode_steps`` count at
         emit.  Three spans split the tick: ``serve_decode_prep``
-        (numpy operands and their upload), ``serve_decode`` (the
-        dispatch of call t+1, then the wait for call t's vector),
+        (numpy operands and their upload), ``serve_decode`` (under it
+        ``serve_decode_dispatch``, the launch of call t+1, then
+        ``serve_decode_wait``, the wait for call t's vector),
         ``serve_emit`` (the per-slot loop over call t's tokens).
 
         The call in flight is SETTLED first (read and emitted, nothing
@@ -1791,32 +1940,25 @@ class GenerationEngine:
         where the next call's bucket is another (its ``prev`` has the
         other bucket's shape).  An end the host cannot foresee (an
         EOS) is found when its call is read, one call late: the row's
-        token of the call already in flight is dropped."""
+        token of the call already in flight is dropped.  Every
+        ``serve_decode`` span that did not dispatch ahead says why
+        (``reason``, one of :data:`SETTLE_REASONS`)."""
         pend = self._inflight
         if pend is not None and pend.ends:
-            self._settle(clock)
+            self._settle('end', clock)
             return
-        ident = self._ident()
-        with _telemetry.span('serve_decode_prep', kind='serve',
-                             step=self._step_index, **ident):
-            operands = self._decode_operands(pend)
+        rec = _telemetry.live()
+        operands, attrs = self._decode_prep(rec, pend)
         if pend is not None and (operands is None
                                  or operands[-1] is None):
             # occupancy crossed a decode edge (or every row was shed)
-            self._settle(clock)
+            self._settle('drained' if operands is None else 'bucket',
+                         clock)
             pend = None
-            with _telemetry.span('serve_decode_prep', kind='serve',
-                                 step=self._step_index, **ident):
-                operands = self._decode_operands(None)
+            operands, attrs = self._decode_prep(rec, None)
         if operands is None:
             return
         rows, held, k, bucket, exe, args = operands
-        rec = _telemetry.live()
-        reg = _telemetry.registry()
-        if reg is not None:
-            reg.gauge('active_slots',
-                      help='live sequences at this decode step'
-                      ).set(k)
         if _chaos._active is not None:
             _chaos.on_serve_slow(
                 self.param_version != self._boot_version)
@@ -1825,19 +1967,29 @@ class GenerationEngine:
         # call it dispatches and every other attribute of the call
         # whose vector it READS (a tick later; a settle's span reads
         # and dispatches nothing, the span after it the reverse)
-        with self._decode_span(
-                ran_ahead=int(pend is not None)) as span:
-            toks, cache = exe(self.params, self._cache, *args)
-            # rebound BEFORE the wait (here and at every call of the
-            # tick): the donated cache is a husk per layer, and they
-            # die while the device runs, not after it
-            self._cache = cache
-            toks.copy_to_host_async()
-            attrs = None
-            if rec is not None:     # before the slots advance
-                attrs = self._attended(
-                    [slot.position + 1 for slot in held
-                     if slot is not None], bucket)
+        with (self._decode_span(rec, pend) if rec is not None
+              else NULL_SPAN) as span:
+            with (rec.span('serve_decode_dispatch', kind='serve',
+                           step=self._step_index)
+                  if rec is not None else NULL_SPAN) as go:
+                if rec is not None:
+                    self._launching(
+                        rec, go.t0,
+                        'admission' if self._admitting
+                        else 'steady' if pend is not None
+                        else self._primes_after)
+                    self._admitting = False
+                toks, cache = exe(self.params, self._cache, *args)
+                # rebound BEFORE the wait (here and at every call of
+                # the tick): the donated cache is a husk per layer,
+                # and they die while the device runs, not after it
+                self._cache = cache
+                toks.copy_to_host_async()
+                # the host's handles on the uploaded operands go here,
+                # under the dispatch, not at the tick's return
+                del operands, args
+            if rec is not None:
+                self._last_call = toks
             last = [False] * bucket
             for i, slot in enumerate(held):
                 if slot is not None:
@@ -1849,36 +2001,78 @@ class GenerationEngine:
             self.decode_calls += 1
             if pend is not None:
                 self.decode_calls_ahead += 1
-                read = self._read(pend, span)
+                read = self._read(pend, span, rec)
+            else:
+                self.settles['prime'] += 1
         if pend is not None:
             self._emit(pend, read, t0, clock)
 
-    def _settle(self, clock=time.monotonic):
+    def _decode_prep(self, rec, pend):
+        """``serve_decode_prep``: :meth:`_decode_operands` and, with a
+        recorder live, what the call's span will say its rows attend
+        (taken before the slots advance; the instrument's own work,
+        booked with the host's)."""
+        with (self._phase(rec, 'serve_decode_prep') if rec is not None
+              else NULL_SPAN):
+            operands = self._decode_operands(pend, rec)
+            attrs = None
+            if rec is not None and operands is not None \
+                    and operands[-1] is not None:
+                self._tick_gauges(rec)['active_slots'].set(
+                    operands[2])
+                attrs = self._attended(
+                    [slot.position + 1 for slot in operands[1]
+                     if slot is not None], operands[3])
+        return operands, attrs
+
+    def _settle(self, reason, clock=time.monotonic):
         """Read the call in flight, if there is one, and emit its
         tokens: after it nothing of the scheduler's is on the device
-        and every slot's newest token is the host's."""
+        and every slot's newest token is the host's.  ``reason`` (of
+        :data:`SETTLE_REASONS`) is why nothing was dispatched ahead of
+        it."""
         with self._lock:
             pend, self._inflight = self._inflight, None
         if pend is None:
             return
+        self.settles[reason] += 1
+        rec = _telemetry.live()
         t0 = clock()
-        with self._decode_span() as span:
-            read = self._read(pend, span)
+        with (self._decode_span(rec, None, reason)
+              if rec is not None else NULL_SPAN) as span:
+            read = self._read(pend, span, rec)
+        if rec is not None:
+            self._primes_after = ('end' if reason in ('end', 'bucket')
+                                  else 'other')
         self._emit(pend, read, t0, clock)
 
-    def _decode_span(self, **attrs):
-        return _telemetry.span('serve_decode', kind='serve',
-                               iteration=self._step_index,
-                               step=self._step_index,
-                               n_slots=self.n_slots,
-                               queue_depth=self._last_queue_depth,
-                               **attrs, **self._ident())
+    def _decode_span(self, rec, pend, reason='prime'):
+        """The ``serve_decode`` span of a call that dispatches behind
+        ``pend`` (ahead of its read), of one that primes the pipeline
+        (``pend`` None) or, with a ``reason`` given, of a settle."""
+        attrs = {}
+        if reason == 'prime':
+            attrs['ran_ahead'] = int(pend is not None)
+        if pend is None:
+            attrs['reason'] = reason
+        return self._phase(rec, 'serve_decode',
+                           iteration=self._step_index,
+                           n_slots=self.n_slots,
+                           queue_depth=self._last_queue_depth, **attrs)
 
-    def _read(self, pend, span):
+    def _read(self, pend, span, rec):
         """Wait for a dispatched call's vector (its copy to the host
         began at dispatch) and split it; ``span`` gets what its
-        readers take of a decode call."""
-        toks, counters = self._split_sampled(pend.toks)
+        readers take of a decode call.  The wait is
+        ``serve_decode_wait``: from asking for the vector to having
+        it."""
+        with (rec.span('serve_decode_wait', kind='serve',
+                       step=self._step_index)
+              if rec is not None else NULL_SPAN) as wait:
+            out = np.asarray(pend.toks)
+        if rec is not None:
+            self._waited(rec, wait, pend.toks)
+        toks, counters = self._split_sampled(out)
         span.set(active_slots=pend.k, bucket=pend.bucket, **counters,
                  **(pend.attrs or {}))
         return toks
@@ -1888,20 +2082,19 @@ class GenerationEngine:
         resolve and free their slots (refilled at the NEXT step).  A
         row whose slot is no longer the one it was dispatched for is
         dropped: its request is dead (expired, shed) or ended a call
-        earlier."""
+        earlier.  All of it is the ``serve_emit`` span."""
         rec = _telemetry.live()
-        reg = _telemetry.registry()
-        ident = self._ident()
-        now = clock()
-        now_tele = rec.now() if rec is not None else None
-        itl = (reg.histogram('serve_intertoken_seconds',
-                             help='per-sequence gap between '
-                                  'consecutive tokens (s)')
-               if reg is not None else None)
-        emitted = 0
-        with _telemetry.span('serve_emit', kind='serve',
-                             step=self._step_index,
-                             active_slots=pend.k, **ident):
+        with (self._phase(rec, 'serve_emit') if rec is not None
+              else NULL_SPAN):
+            reg = _telemetry.registry()
+            ident = self._ident()
+            now = clock()
+            now_tele = rec.now() if rec is not None else None
+            itl = (reg.histogram('serve_intertoken_seconds',
+                                 help='per-sequence gap between '
+                                      'consecutive tokens (s)')
+                   if reg is not None else None)
+            emitted = 0
             for i, sid in enumerate(pend.rows):
                 slot = pend.slots[i]
                 if slot is None or self._slots.get(sid) is not slot:
@@ -1945,14 +2138,14 @@ class GenerationEngine:
                                         slot.state_row)
                     del self._slots[sid]
                     self._free.append(sid)
-        self.decode_steps += 1
-        self.tokens_generated += emitted
-        if reg is not None:
-            reg.histogram('serve_decode_seconds',
-                          help='per-decode-step wall time (s)'
-                          ).observe(now - t0)
-            reg.counter('serve_tokens_total',
-                        help='generated tokens').inc(emitted)
+            self.decode_steps += 1
+            self.tokens_generated += emitted
+            if reg is not None:
+                reg.histogram('serve_decode_seconds',
+                              help='per-decode-step wall time (s)'
+                              ).observe(now - t0)
+                reg.counter('serve_tokens_total',
+                            help='generated tokens').inc(emitted)
 
     def _attended(self, live, n_rows):
         """Positions a decode call's rows attend, by layer kind, from
@@ -1994,7 +2187,7 @@ class GenerationEngine:
         only."""
         # a verify's acceptance decides the next draft: data the host
         # must read, so this tick stays synchronous
-        self._settle(clock)
+        self._settle('spec', clock)
         kk = self.spec_tokens
         if self.paged:
             # grow page tables to cover the WHOLE window [position,
@@ -2063,12 +2256,12 @@ class GenerationEngine:
         d_exe = self._get_draft_decode(bucket)
         proposals = np.zeros((bucket, kk), np.int32)
         cur = base_tok
-        with _telemetry.span('serve_draft', kind='serve',
-                             stage='decode',
-                             iteration=self._step_index,
-                             step=self._step_index,
-                             active_slots=k, bucket=bucket,
-                             window=kk, **ident):
+        with (self._phase(rec, 'serve_draft', stage='decode',
+                          iteration=self._step_index, active_slots=k,
+                          bucket=bucket, window=kk)
+              if rec is not None else NULL_SPAN) as span:
+            if rec is not None:
+                self._launching(rec, span.t0, 'other')
             for j in range(kk):
                 # clamp overhang past the cache depth: the write lands
                 # on a not-yet-committed row, the proposal is garbage,
@@ -2080,6 +2273,8 @@ class GenerationEngine:
                 toks, dcache = d_exe(self._draft_params,
                                      self._draft_cache, *args)
                 self._draft_cache = dcache
+                if rec is not None:
+                    self._last_call = toks
                 cur = np.asarray(jax.block_until_ready(toks))
                 proposals[:, j] = cur
                 self.draft_steps += 1
@@ -2094,15 +2289,18 @@ class GenerationEngine:
         v_exe = self._get_verify(bucket)
         vargs = operand_args(win, base_pos)
         self._guard_call(self._cache_sig, vargs)
-        with _telemetry.span('serve_verify', kind='serve',
-                             iteration=self._step_index,
-                             step=self._step_index,
-                             active_slots=k, bucket=bucket,
-                             window=kk, n_slots=self.n_slots,
-                             queue_depth=self._last_queue_depth,
-                             **ident):
+        with (self._phase(rec, 'serve_verify',
+                          iteration=self._step_index, active_slots=k,
+                          bucket=bucket, window=kk,
+                          n_slots=self.n_slots,
+                          queue_depth=self._last_queue_depth)
+              if rec is not None else NULL_SPAN) as span:
+            if rec is not None:
+                self._launching(rec, span.t0, 'other')
             tgt, cache = v_exe(self.params, self._cache, *vargs)
             self._cache = cache
+            if rec is not None:
+                self._last_call = tgt
             tgt = np.asarray(jax.block_until_ready(tgt))
         self.verify_steps += 1
         now = clock()
@@ -2241,18 +2439,31 @@ class GenerationEngine:
         pressure ONSET is visible in captures, not just its latency
         consequences; the engine's in-flight request table is also
         registered as a flight-dump source.  The tick is one
-        ``serve_tick`` span, the parent of ``serve_admit``,
-        ``serve_prefill``, ``serve_decode_prep``, ``serve_decode`` and
-        ``serve_emit``: what they leave uncovered is the tick's own
-        host time."""
-        with _telemetry.span('serve_tick', kind='serve',
-                             step=self._step_index,
-                             **self._ident()) as tick:
-            prefills = self.prefills
-            worked = self._tick(queue, clock)
+        ``serve_tick`` span whose children TILE it, in this order:
+        ``serve_expire``, ``serve_admit``, per prefilled sequence
+        ``serve_prefill_prep`` / ``serve_prefill`` / ``serve_emit``
+        (``first=1``), then ``serve_decode_prep``, ``serve_decode``,
+        ``serve_emit`` (a speculative engine: ``serve_draft``,
+        ``serve_verify``); every child's end, and the tick's two, is a
+        boundary of the starved-device probe (:meth:`_launching`)."""
+        rec = _telemetry.live()
+        if rec is None:
+            if self._last_call is not None:
+                # calls launched from here on go unseen: forget
+                self._last_call = self._idle_since = None
+            return self._tick(queue, clock, None)
+        with rec.span('serve_tick', kind='serve',
+                      step=self._step_index, **self._ident()) as tick:
+            self._probe(rec, 'client')
+            prefills, admissions = self.prefills, self.admissions
+            worked = self._tick(queue, clock, rec)
             tick.set(queue_depth=self._last_queue_depth,
                      prefills=self.prefills - prefills,
                      active_slots=len(self._slots))
+            if self.admissions > admissions:
+                # on admitting ticks only: a reader's mean over the
+                # spans that have it is requests an admitting tick
+                tick.set(admitted=self.admissions - admissions)
             if self._ring:
                 tick.set(full_pages_in_use=self.pool.in_use(),
                          window_pages_in_use=self.window_pool.in_use())
@@ -2269,40 +2480,59 @@ class GenerationEngine:
                          state_bytes_in_use=rows * row_bytes,
                          cache_bytes_in_use=rows * row_bytes
                          + self.pool.in_use() * page_bytes)
+            self._probe(rec, 'serve_tick')
         return worked
 
-    def _tick(self, queue, clock):
-        rec = _telemetry.live()
-        depth = queue.depth()
-        self._last_queue_depth = depth
-        if rec is not None:
-            if rec.flight_sources.get('serve_requests') \
-                    != self._flight_table:
-                rec.flight_sources['serve_requests'] = \
-                    self._flight_table
-            reg = rec.registry
-            reg.gauge('serve_queue_depth',
-                      help='requests waiting in the generation '
-                           'queue at the scheduler tick').set(depth)
-            reg.gauge('serve_prefill_backlog',
-                      help='queued requests still needing their '
-                           'prefill pass (queued + mid-prefill)'
-                      ).set(depth + len(self._prefilling))
-            reg.gauge('serve_decode_backlog',
-                      help='live slots still generating at the '
-                           'scheduler tick').set(len(self._slots))
+    def _tick_gauges(self, rec):
+        """The gauges a tick sets, by name, looked up in ``rec``'s
+        registry once and held (a new recorder: once more)."""
+        held = self._gauges
+        if held is None or held[0] is not rec:
+            helps = {
+                'serve_queue_depth': 'requests waiting in the '
+                                     'generation queue at the '
+                                     'scheduler tick',
+                'serve_prefill_backlog': 'queued requests still needing '
+                                         'their prefill pass (queued + '
+                                         'mid-prefill)',
+                'serve_decode_backlog': 'live slots still generating at '
+                                        'the scheduler tick',
+                'active_slots': 'live sequences at this decode step'}
             if self.paged:
-                reg.gauge('serve_kv_pages_in_use',
-                          help='allocated KV pages (live sequences '
-                               '+ banked prefixes) at the tick'
-                          ).set(self.pool.in_use())
-                reg.gauge('serve_kv_pages_free',
-                          help='free KV pages at the tick'
-                          ).set(self.pool.available())
-        now = clock()
-        force = (_chaos.on_serve_cancel()
-                 if _chaos._active is not None else 0)
-        self._expire(now, force=force)
+                helps.update({
+                    'serve_kv_pages_in_use': 'allocated KV pages (live '
+                                             'sequences + banked '
+                                             'prefixes) at the tick',
+                    'serve_kv_pages_free': 'free KV pages at the tick'})
+            held = self._gauges = (rec, {
+                name: rec.registry.gauge(name, help=text)
+                for name, text in helps.items()})
+        return held[1]
+
+    def _tick(self, queue, clock, rec):
+        with (self._phase(rec, 'serve_expire') if rec is not None
+              else NULL_SPAN):
+            depth = queue.depth()
+            self._last_queue_depth = depth
+            if rec is not None:
+                if rec.flight_sources.get('serve_requests') \
+                        != self._flight_table:
+                    rec.flight_sources['serve_requests'] = \
+                        self._flight_table
+                gauges = self._tick_gauges(rec)
+                gauges['serve_queue_depth'].set(depth)
+                gauges['serve_prefill_backlog'].set(
+                    depth + len(self._prefilling))
+                gauges['serve_decode_backlog'].set(len(self._slots))
+                if self.paged:
+                    gauges['serve_kv_pages_in_use'].set(
+                        self.pool.in_use())
+                    gauges['serve_kv_pages_free'].set(
+                        self.pool.available())
+            now = clock()
+            force = (_chaos.on_serve_cancel()
+                     if _chaos._active is not None else 0)
+            self._expire(now, force=force)
         self._admit(queue, now, clock)
         worked = False
         if self.paged and self._prefilling:
@@ -2322,7 +2552,7 @@ class GenerationEngine:
         elif self._inflight is not None:
             # every row of the call in flight has gone since (expired,
             # shed, ended on an EOS found a call late): nothing is owed
-            self._settle(clock)
+            self._settle('drained', clock)
             worked = True
         if not worked:
             return False
@@ -2403,6 +2633,12 @@ class GenerationEngine:
             # before their predecessor's tokens were read (0 to 1)
             'decode_runahead_share': (
                 self.decode_calls_ahead / max(self.decode_calls, 1)),
+            # why the others did not: the call in flight settled for a
+            # foreseen end, a change of bucket, a drained table, a
+            # speculative tick or a swap, and the calls that primed
+            # the pipeline again (= decode calls not dispatched ahead)
+            'settles': dict(self.settles),
+            'admissions': self.admissions,
             'tokens_generated': self.tokens_generated,
             'cancelled': self.cancelled,
             'active_slots': len(self._slots),

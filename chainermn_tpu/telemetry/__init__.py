@@ -210,8 +210,9 @@ def request_stage(request_id, name, t0, t1=None, **attrs):
     """Record one completed stage of a per-request trace
     (``kind='request'`` span via :meth:`Recorder.child_span`); no-op
     when disabled.  The serving path threads a request's lifecycle
-    through these -- ``queue_wait`` -> ``bucket_pack`` -> ``prefill``
-    -> per-tick ``decode`` (or ``execute`` on the batch path) -- with
+    through these -- ``queue_wait`` -> ``admit_wait`` ->
+    ``bucket_pack`` -> ``prefill`` -> per-tick ``decode`` (or
+    ``execute`` on the batch path) -- with
     each stage's ``t0`` equal to the previous stage's ``t1``, so
     ``telemetry report`` reconstructs a gap-free timeline whose stage
     budgets sum to the end-to-end latency."""

@@ -124,7 +124,9 @@ class Gauge:
 class Histogram:
     """Sample-retaining distribution with p50/p99 summaries.
 
-    Retains raw samples (newest :data:`MAX_SAMPLES`) so per-rank
+    Retains raw samples (at least the newest :data:`MAX_SAMPLES`,
+    trimmed an eighth at a time: a trim moves the whole list, and one
+    per ``observe`` cost a full serving tick 0.3 ms) so per-rank
     snapshots can be MERGED exactly -- aggregated percentiles are
     recomputed from the union of samples, not averaged from per-rank
     percentiles (which would be wrong for skewed step times).
@@ -144,7 +146,7 @@ class Histogram:
         self.count += 1
         self.total += v
         self.samples.append(v)
-        if len(self.samples) > MAX_SAMPLES:
+        if len(self.samples) > MAX_SAMPLES + MAX_SAMPLES // 8:
             del self.samples[:len(self.samples) - MAX_SAMPLES]
 
     def summary(self):
@@ -292,20 +294,23 @@ def snapshot_to_prometheus(snapshot, prefix='chainermn_tpu_'):
 class _SpanHandle:
     """``recorder.span(...)``: the context manager, and what ``with
     ... as sp`` yields -- the caller attaches attributes discovered
-    mid-span with :meth:`set`."""
+    mid-span with :meth:`set`, reads its two ends (``t0``, ``t1``) and
+    may hang ONE callable on ``at_exit``: called with the span once it
+    has ended and its record is written."""
 
-    __slots__ = ('_recorder', '_annotation', 'name', 'kind', 'attrs',
-                 'id', 'parent', 't0')
+    __slots__ = ('recorder', '_annotation', 'name', 'kind', 'attrs',
+                 'id', 'parent', 't0', 't1', 'at_exit')
 
     def __init__(self, recorder, name, kind, attrs):
-        self._recorder = recorder
+        self.recorder = recorder
         self.name, self.kind, self.attrs = name, kind, attrs
+        self.at_exit = None
 
     def set(self, **attrs):
         self.attrs.update(attrs)
 
     def __enter__(self):
-        rec = self._recorder
+        rec = self.recorder
         stack = rec._span_stack()
         self.parent = stack[-1] if stack else None
         self.id = next(rec._span_ids)
@@ -327,8 +332,8 @@ class _SpanHandle:
         return self
 
     def __exit__(self, *exc):
-        rec = self._recorder
-        t1 = rec.now()
+        rec = self.recorder
+        t1 = self.t1 = rec.now()
         self._annotation.__exit__(*exc)
         stack = rec._span_stack()
         if stack and stack[-1] == self.id:
@@ -341,6 +346,8 @@ class _SpanHandle:
         if self.attrs:
             record.update(self.attrs)
         rec._append(record)
+        if self.at_exit is not None:
+            self.at_exit(self)
         return False
 
 
@@ -471,7 +478,10 @@ class Recorder:
 
     def span(self, name, kind='generic', **attrs):
         """Context manager timing the enclosed block: one record here
-        and one ``cmn:<name>`` annotation in the profiler's trace."""
+        and one ``cmn:<name>`` annotation in the profiler's trace.  A
+        caller that must act at the span's end (the serving tick's
+        phases: a boundary of its starved-device probe) sets the
+        handle's ``at_exit``."""
         return _SpanHandle(self, name, kind, attrs)
 
     def event(self, name, kind='event', **attrs):
@@ -497,6 +507,18 @@ class Recorder:
         rec = {'type': 'span', 'name': name, 'kind': kind,
                'request_id': request_id, 't0': t0,
                't1': self.now() if t1 is None else t1}
+        if attrs:
+            rec.update(attrs)
+        self._append(rec)
+
+    def interval(self, name, t0, t1, kind='generic', **attrs):
+        """Record one already-timed span that belongs to no request
+        and to no thread's stack (no ``id``, no ``parent``, no
+        annotation): something the caller only knows the two ends of,
+        on THIS recorder's clock, once it is over -- the serving
+        scheduler's ``device_idle`` records."""
+        rec = {'type': 'span', 'name': name, 'kind': kind, 't0': t0,
+               't1': t1}
         if attrs:
             rec.update(attrs)
         self._append(rec)

@@ -55,6 +55,16 @@ SERVE_PHASES = ('serve_queue_wait', 'serve_h2d', 'serve_execute',
                 'serve_warmup', 'serve_prefill', 'serve_decode',
                 'serve_draft', 'serve_verify')
 
+#: the scheduler tick's anatomy (``GenerationEngine.step``): the
+#: children of a ``serve_tick`` span in the order a tick runs them --
+#: they tile it, so ``serve_tick`` less their sum is span overhead --
+#: and under the two call spans the dispatch and the wait, by name
+TICK_PHASES = ('serve_expire', 'serve_admit', 'serve_prefill_prep',
+               'serve_prefill', 'serve_emit', 'serve_decode_prep',
+               'serve_decode', 'serve_draft', 'serve_verify')
+CALL_PHASES = ('serve_prefill_dispatch', 'serve_prefill_wait',
+               'serve_decode_dispatch', 'serve_decode_wait')
+
 #: span kinds whose time counts as "compute the collective could
 #: hide behind"
 COMPUTE_KINDS = ('compute',)
@@ -63,14 +73,16 @@ COLLECTIVE_KINDS = ('collective',)
 
 #: per-request trace stage vocabulary (``kind='request'`` spans the
 #: serving path records, issue order): the generation path emits
-#: ``queue_wait`` -> ``bucket_pack`` -> ``prefill`` -> one ``decode``
-#: per tick; the batch path emits ``queue_wait`` -> ``bucket_pack``
-#: -> ``execute``.  Stages TILE the request's lifetime (each stage's
-#: t0 is the previous stage's t1), so per-stage budgets telescope to
+#: ``queue_wait`` -> ``admit_wait`` (behind the prefills of the
+#: requests admitted with it) -> ``bucket_pack`` -> ``prefill`` -> one
+#: ``decode`` per tick; the batch path emits ``queue_wait`` ->
+#: ``bucket_pack`` -> ``execute``.  Stages TILE the request's lifetime
+#: (each stage's t0 is the previous stage's t1), so per-stage budgets
+#: telescope to
 #: the end-to-end latency -- the property the p99 decomposition pin
 #: asserts to +-1 ms
-REQUEST_STAGES = ('queue_wait', 'bucket_pack', 'prefill', 'decode',
-                  'execute')
+REQUEST_STAGES = ('queue_wait', 'admit_wait', 'bucket_pack', 'prefill',
+                  'decode', 'execute')
 
 #: terminal ``kind='request'`` event vocabulary
 REQUEST_OUTCOMES = ('complete', 'shed', 'error')
@@ -489,6 +501,78 @@ def serve_summary(metrics):
     return out
 
 
+def serve_tick_summary(spans):
+    """The scheduler tick's own account, from the spans a generation
+    engine writes: per phase (:data:`TICK_PHASES`, and the first-token
+    ``serve_emit`` apart) how often it ran and its mean, the same for
+    the dispatch and the wait of the two calls, what the tick left
+    uncovered, why decode calls did not go out ahead (``reason``), how
+    many requests an admitting tick admitted, and the ``device_idle``
+    records -- a LOWER bound on the time the device had nothing to
+    run -- by ``cause`` and by the phase ``after`` which it was seen
+    idle.  ``None`` for a capture without ``serve_tick`` spans."""
+    ticks = [s for s in spans if s.get('name') == 'serve_tick']
+    if not ticks:
+        return None
+    ids = {s.get('id') for s in ticks}
+    phases, covered = {}, 0.0
+    reasons, idle_cause, idle_after = {}, {}, {}
+    idle_n, idle_s, exact_s = 0, 0.0, 0.0
+    for s in spans:
+        name, dur = s.get('name'), max(s['t1'] - s['t0'], 0.0)
+        if name == 'device_idle':
+            idle_n += 1
+            idle_s += dur
+            exact_s += dur if s.get('exact') else 0.0
+            idle_cause[s.get('cause')] = \
+                idle_cause.get(s.get('cause'), 0.0) + dur
+            idle_after[s.get('after')] = \
+                idle_after.get(s.get('after'), 0.0) + dur
+            continue
+        if name in TICK_PHASES and s.get('parent') in ids:
+            covered += dur
+            if name == 'serve_decode':
+                why = s.get('reason', 'ahead')
+                reasons[why] = reasons.get(why, 0) + 1
+            elif name == 'serve_emit' and s.get('first'):
+                name = 'serve_emit (first)'
+        elif name not in CALL_PHASES:
+            continue
+        agg = phases.setdefault(name, [0, 0.0])
+        agg[0] += 1
+        agg[1] += dur
+    tick_s = sum(max(s['t1'] - s['t0'], 0.0) for s in ticks)
+    admitted = [s['admitted'] for s in ticks if 'admitted' in s]
+    extent = (max(s['t1'] for s in ticks)
+              - min(s['t0'] for s in ticks))
+
+    def ms(table):
+        return {k: round(v * 1e3, 3)
+                for k, v in sorted(table.items(), key=lambda kv: -kv[1])}
+
+    return {
+        'ticks': len(ticks),
+        'tick_mean_ms': round(tick_s / len(ticks) * 1e3, 4),
+        'uncovered_mean_ms': round(
+            (tick_s - covered) / len(ticks) * 1e3, 4),
+        'phases': {name: {'count': n,
+                          'mean_ms': round(total / n * 1e3, 4)}
+                   for name, (n, total) in phases.items()},
+        'decode_reasons': reasons,
+        'admitting_ticks': len(admitted),
+        'admits_per_admit_tick': (sum(admitted) / len(admitted)
+                                  if admitted else None),
+        'device_idle': {
+            'records': idle_n,
+            'total_ms': round(idle_s * 1e3, 3),
+            'exact_ms': round(exact_s * 1e3, 3),
+            'share_of_extent': (idle_s / extent if extent > 0
+                                else None),
+            'by_cause_ms': ms(idle_cause),
+            'by_after_ms': ms(idle_after)},
+    }
+
+
 # ---------------------------------------------------------------------
 # per-request trace reconstruction (kind='request' records)
 
@@ -657,6 +741,7 @@ def build_report(outdir):
         'metrics': aggregate_metrics(rank_metrics),
     }
     report['serve'] = serve_summary(report['metrics'])
+    report['serve_ticks'] = serve_tick_summary(spans)
     report['requests'] = request_summary(spans + events)
     report['pipeline'] = pipeline_summary(events)
     report['input_bound'] = input_bound_stats(steps)
@@ -773,6 +858,39 @@ def render_text(report, max_steps=24):
                 + ('; inter-token p50 %.3f ms p99 %.3f ms'
                    % (itl['p50'], itl['p99'])
                    if itl.get('p50') is not None else ''))
+    ticks = report.get('serve_ticks')
+    if ticks:
+        lines.append(
+            'scheduler ticks: %d, mean %.3f ms, %.3f ms of it under no '
+            'phase' % (ticks['ticks'], ticks['tick_mean_ms'],
+                       ticks['uncovered_mean_ms']))
+        for name in (TICK_PHASES[:5] + ('serve_emit (first)',)
+                     + TICK_PHASES[5:] + CALL_PHASES):
+            row = ticks['phases'].get(name)
+            if row:
+                lines.append('  %-24s %7d x %9.3f ms'
+                             % (name, row['count'], row['mean_ms']))
+        if ticks['decode_reasons']:
+            lines.append('  decode calls: ' + ', '.join(
+                '%s=%d' % kv
+                for kv in sorted(ticks['decode_reasons'].items())))
+        if ticks['admits_per_admit_tick'] is not None:
+            lines.append('  %d admitting tick(s), %.2f request(s) each'
+                         % (ticks['admitting_ticks'],
+                            ticks['admits_per_admit_tick']))
+        idle = ticks['device_idle']
+        if idle['records']:
+            lines.append(
+                '  device seen idle at a launch (a lower bound): '
+                '%.3f ms in %d record(s), %.3f ms of it exact%s'
+                % (idle['total_ms'], idle['records'], idle['exact_ms'],
+                   '' if idle['share_of_extent'] is None else
+                   ', %.1f%% of the ticks\' extent'
+                   % (100 * idle['share_of_extent'])))
+            for label, table in (('cause', idle['by_cause_ms']),
+                                 ('after', idle['by_after_ms'])):
+                lines.append('    by %s: ' % label + ', '.join(
+                    '%s %.3f ms' % kv for kv in table.items()))
     reqs = report.get('requests')
     if reqs:
         e2e = reqs.get('e2e_ms') or {}

@@ -209,8 +209,9 @@ def test_profiler_session_switches_the_schedulers_spans_on(tmp_path):
             pass
 
     traced = _Traced(tmp_path, ticks)
-    children = ('serve_admit', 'serve_prefill', 'serve_decode_prep',
-                'serve_decode', 'serve_emit')
+    children = ('serve_expire', 'serve_admit', 'serve_prefill_prep',
+                'serve_prefill', 'serve_decode_prep', 'serve_decode',
+                'serve_emit')
     tick_ids = {r['id']: r for r in traced.named('serve_tick')}
     assert len(tick_ids) >= 6
     assert len(traced.trace_events('serve_tick')) == len(tick_ids)
@@ -225,8 +226,26 @@ def test_profiler_session_switches_the_schedulers_spans_on(tmp_path):
     assert sum(r['prefills'] for r in tick_ids.values()) == 3
     assert [r['step'] for r in traced.named('serve_tick')] == \
         sorted(r['step'] for r in tick_ids.values())
+    # the dispatch and the wait, by name, under their call's span
+    for call in ('serve_decode', 'serve_prefill'):
+        ids = {r['id'] for r in traced.named(call)}
+        for part in ('_dispatch', '_wait'):
+            assert traced.named(call + part)
+            assert {r['parent'] for r in traced.named(call + part)} \
+                <= ids
+            assert traced.line_of(call + part) == main
+    assert {r.get('reason') for r in traced.named('serve_decode')} \
+        >= {None, 'prime'}
+    assert {r['ran_ahead'] for r in traced.named('serve_decode')
+            if 'ran_ahead' in r} == {0, 1}
+    # an interval the engine only knows the ends of: a record, no
+    # annotation, no place in a thread's stack
+    idle = traced.named('device_idle')
+    assert idle and not traced.trace_events('device_idle')
+    assert all('id' not in r and 'parent' not in r for r in idle)
     # per-request stages: records, no annotation
-    stages = {'queue_wait', 'bucket_pack', 'prefill', 'decode'}
+    stages = {'queue_wait', 'admit_wait', 'bucket_pack', 'prefill',
+              'decode'}
     assert stages <= {r['name'] for r in traced.records}
     for name in stages:
         assert not traced.trace_events(name)
@@ -560,6 +579,160 @@ def test_program_span_reader_on_a_hand_made_run():
     assert 'self 22.000' in run.said[-1]
     with pytest.raises(KeyError):
         read(run, stat='median', span='serve_tick')
+
+
+def _serving_records(rec):
+    """Two ticks of a scheduler that writes ISSUE 37's spans, by hand:
+    tick 50 admits two requests and primes the pipeline, tick 51 runs
+    ahead; every child named, 3 ms and 1 ms of the ticks uncovered."""
+    t = 105.0
+    _record(rec, 'serve_tick', t, t + 0.050, id=50, parent=None,
+            admitted=2)
+    _record(rec, 'serve_expire', t, t + 0.001, id=51, parent=50)
+    _record(rec, 'serve_admit', t + 0.001, t + 0.002, id=52, parent=50)
+    at = t + 0.002
+    for i, rid in enumerate(('r1', 'r2')):
+        _record(rec, 'admit_wait', t + 0.002, at, request_id=rid,
+                behind=i)
+        _record(rec, 'serve_prefill_prep', at, at + 0.002, id=53 + 10 * i,
+                parent=50)
+        _record(rec, 'device_idle', at - 0.001, at + 0.002,
+                cause='admission', after='serve_admit' if i == 0
+                else 'serve_prefill_wait', exact=i)
+        _record(rec, 'serve_prefill', at + 0.002, at + 0.012,
+                id=54 + 10 * i, parent=50)
+        _record(rec, 'serve_prefill_dispatch', at + 0.002, at + 0.003,
+                id=55 + 10 * i, parent=54 + 10 * i)
+        _record(rec, 'serve_prefill_wait', at + 0.003, at + 0.012,
+                id=56 + 10 * i, parent=54 + 10 * i)
+        _record(rec, 'serve_emit', at + 0.012, at + 0.013,
+                id=57 + 10 * i, parent=50, first=1)
+        at += 0.013
+    _record(rec, 'serve_decode_prep', at, at + 0.004, id=80, parent=50)
+    _record(rec, 'device_idle', at - 0.001, at + 0.004,
+            cause='admission', after='serve_prefill_wait', exact=1)
+    _record(rec, 'serve_decode', at + 0.004, at + 0.019, id=81,
+            parent=50, ran_ahead=0, reason='prime')
+    _record(rec, 'serve_decode_dispatch', at + 0.004, at + 0.005,
+            id=82, parent=81)
+    t = 106.0
+    _record(rec, 'serve_tick', t, t + 0.020, id=90, parent=None)
+    _record(rec, 'serve_expire', t, t + 0.001, id=91, parent=90)
+    _record(rec, 'serve_admit', t + 0.001, t + 0.002, id=92, parent=90)
+    _record(rec, 'serve_decode_prep', t + 0.002, t + 0.006, id=93,
+            parent=90)
+    _record(rec, 'device_idle', t + 0.004, t + 0.006, cause='steady',
+            after='serve_decode_prep', exact=0)
+    _record(rec, 'serve_decode', t + 0.006, t + 0.016, id=94,
+            parent=90, ran_ahead=1)
+    _record(rec, 'serve_decode_dispatch', t + 0.006, t + 0.009, id=95,
+            parent=94)
+    _record(rec, 'serve_decode_wait', t + 0.009, t + 0.016, id=96,
+            parent=94)
+    _record(rec, 'serve_emit', t + 0.016, t + 0.019, id=97, parent=90)
+    # a settle: a wait and no dispatch; then a priming call's idle
+    _record(rec, 'serve_decode', 107.0, 107.003, id=98, parent=None,
+            reason='end')
+    _record(rec, 'serve_decode_wait', 107.0, 107.003, id=99, parent=98)
+    _record(rec, 'device_idle', 107.003, 107.009, cause='end',
+            after='serve_decode_wait', exact=1)
+    _record(rec, 'device_idle', 108.0, 108.001, cause='other',
+            after='client', exact=0)
+
+
+def test_program_span_share_reader_on_a_hand_made_run():
+    """The one new reader (ISSUE 37): 100 x the summed durations of
+    the records called ``span`` whose attributes match ``where``, over
+    the traced window's seconds; 0.0 where the program writes the
+    spans and nothing matches; ``None`` for an older program."""
+    read = _reader('program_span_share')
+    args = dict(span='device_idle', since='serve_decode_dispatch')
+    window = (100.0, 110.0)
+    assert read(_run(window), **args) is None      # no recorder
+    rec = telemetry.enable()
+    _record(rec, 'serve_tick', 101.0, 101.1, id=1, parent=None)
+    _record(rec, 'serve_decode', 101.0, 101.05, id=2, parent=1)
+    # a program older than the dispatch span: never a number
+    assert read(_run(window), **args) is None
+    _serving_records(rec)
+    _record(rec, 'device_idle', 109.5, 110.5, cause='steady',
+            after='client', exact=0)   # straddles
+    run = _run(window)
+    run.trace = types.SimpleNamespace(window_s=8.0)
+    whole = read(run, split=['cause', 'after'], **args)
+    # 3 + 3 + 5 (admission) + 2 (steady) + 6 (end) + 1 (other) ms
+    assert whole == pytest.approx(100 * 0.020 / 8.0, abs=1e-4)
+    by_cause, by_after = run.said[-2:]
+    assert 'points by cause: admission 0.14, end 0.08' in by_cause
+    assert 'other 0.01' in by_cause
+    assert 'points by after: serve_prefill_wait 0.10' in by_after
+    parts = {cause: read(run, where={'cause': cause}, **args)
+             for cause in ('admission', 'end', 'steady', 'other')}
+    assert parts['admission'] == pytest.approx(100 * 0.011 / 8.0,
+                                               abs=1e-4)
+    assert sum(parts.values()) == pytest.approx(whole, abs=1e-6)
+    assert read(run, where={'cause': 'absent'}, **args) == 0.0
+    assert read(run, span='absent', since='serve_decode_dispatch') \
+        == 0.0
+    # no trace: over the records' own extent (101.0 .. 108.001)
+    untraced = _run(window)
+    assert read(untraced, **args) == pytest.approx(
+        100 * 0.020 / 7.001, abs=1e-3)
+
+
+ISSUE_37_METRICS = {
+    # 50 ms less 47 and 20 ms less 19 uncovered
+    'tick_uncovered_ms': 2.0,
+    'decode_wait_ms': 5.0,                  # 7 and 3
+    'decode_dispatch_ms': 2.0,              # 1 and 3
+    'device_starved_share': 100 * 0.020 / 8.0,
+    'device_starved_share.admission': 100 * 0.011 / 8.0,
+    'device_starved_share.steady': 100 * 0.002 / 8.0,
+    'admit_wait_p75_ms': 9.75,              # 0 and 13
+    'admit_wait_p90_ms': 11.7,
+    'admits_per_admit_tick': 2.0,           # the one tick that has it
+}
+
+
+@pytest.mark.parametrize('name', sorted(ISSUE_37_METRICS))
+def test_issue_37_metric_files_on_a_hand_made_run(name):
+    """Each of the nine metric files, with the reader and arguments it
+    commits: its value on the hand-made run, and nothing (never a
+    number from elsewhere) from a program that lacks its spans."""
+    import json
+    with open(os.path.join(ROOT, 'chipbench', 'layer_metrics',
+                           name + '.json')) as f:
+        metric = json.load(f)
+    with open(os.path.join(ROOT, 'BENCHMARK.json')) as f:
+        entry, = [m for m in json.load(f)['per_layer']
+                  if m['name'] == name]
+    assert entry['unit'] == metric['unit']
+    assert entry['layer'] == metric['layer']
+    assert entry['moves'] == metric['moves']
+    read = _reader(metric['reader'])
+    window = (100.0, 110.0)
+    rec = telemetry.enable()
+    # the parent of ISSUE 37: a tick, its old children, the old stages
+    _record(rec, 'serve_tick', 101.0, 101.1, id=1, parent=None)
+    _record(rec, 'serve_decode', 101.0, 101.05, id=2, parent=1)
+    _record(rec, 'queue_wait', 101.0, 101.001, request_id='r0')
+    older = _run(window)
+    older.trace = types.SimpleNamespace(window_s=8.0)
+    value = read(older, **metric['args'])
+    if name == 'tick_uncovered_ms':
+        # its span is as old as the tick: what the old tick left
+        # uncovered, which is what the metric is the proof against
+        assert value == _ms(50.0)
+    else:
+        assert value is None
+    telemetry.disable()
+    rec = telemetry.enable()
+    _serving_records(rec)
+    run = _run(window)
+    run.trace = types.SimpleNamespace(window_s=8.0)
+    run.spec = types.SimpleNamespace(cfg={})
+    assert read(run, **metric['args']) == pytest.approx(
+        ISSUE_37_METRICS[name], abs=0.01)
 
 
 def test_trainer_span_metrics_on_a_hand_made_run():

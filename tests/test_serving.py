@@ -1349,6 +1349,41 @@ class TestPagedGeneration:
                 assert serving.prefix_key(p[:aligned] + [31], self.PS)\
                     == serving.prefix_key(p[:aligned], self.PS)
 
+    #: the virtual clock's cost model (seconds): a tick's own host
+    #: work, one decode call, one prefilled token of a call's width
+    TICK_S, DECODE_S, PREFILL_TOKEN_S = 1e-4, 1e-3, 2.5e-4
+
+    def _drive_on_virtual_clock(self, eng, q, rec, arrivals,
+                                max_new_tokens):
+        """Both the engine's injectable ``clock`` and the recorder's
+        run on ONE virtual clock, which only this loop advances: by a
+        tick's cost under the model above, counted from what the tick
+        launched (decode calls from the engine's counter, prefilled
+        tokens from the ``serve_prefill`` spans it wrote).  Arrivals
+        are due on the same clock, so the schedule, every stamp and
+        every verdict read from them are the same on every host."""
+        now = [1000.0]
+
+        def clock():
+            return now[0]
+        rec.now = lambda: rec._wall0 + now[0]
+        t0 = now[0]
+        reqs, due = [], list(arrivals)
+        for _ in range(20000):
+            while due and t0 + due[0][0] <= now[0]:
+                reqs.append(q.submit(due.pop(0)[1], max_new_tokens))
+            if not due and all(r.done() for r in reqs):
+                break
+            n0, calls = len(rec.events), eng.decode_calls
+            eng.step(q, clock=clock)
+            prefilled = sum(r['bucket'] for r in rec.events[n0:]
+                            if r.get('name') == 'serve_prefill')
+            now[0] += (self.TICK_S
+                       + self.DECODE_S * (eng.decode_calls - calls)
+                       + self.PREFILL_TOKEN_S * prefilled)
+        assert not due and all(r.done() for r in reqs)
+        return reqs
+
     def test_chunked_prefill_holds_intertoken_slo_under_longprompt(
             self, tmp_path):
         """THE chunked-prefill acceptance pin, A/B under the
@@ -1358,17 +1393,25 @@ class TestPagedGeneration:
         prompt and breaches the windowed inter-token burn-rate
         verdict; SARATHI chunking interleaves 8-token chunks with
         decode and holds it at ``ok``.  Both verdicts come from the
-        same deterministic ``evaluate_capture`` replay CI runs."""
+        same deterministic ``evaluate_capture`` replay CI runs.
+
+        Both arms run on a virtual clock (a tick costs what it
+        launched: :meth:`_drive_on_virtual_clock`), so the verdicts
+        are the SCHEDULE's and the same on every host, a loaded one
+        under six test workers too; the second judgement needs no
+        clock at all: the prefill tokens a live decode stream waited
+        behind in one tick, counted from the span and stage records."""
         from chainermn_tpu import telemetry
         from chainermn_tpu.telemetry.slo import (default_slos,
                                                  evaluate_capture)
         from chainermn_tpu.models import TransformerLM
-        # big enough that a monolithic 256-token prefill dwarfs one
-        # decode step -- the regime chunked prefill exists for
-        model = TransformerLM(vocab_size=64, d_model=128, n_heads=4,
-                              n_layers=2, d_ff=256, max_len=288)
+        model = TransformerLM(vocab_size=64, d_model=32, n_heads=4,
+                              n_layers=1, d_ff=32, max_len=288)
         params = model.init(jax.random.PRNGKey(0),
                             jnp.zeros((1, 4), jnp.int32))['params']
+        rng = np.random.RandomState(11)
+        prompts = [rng.randint(0, 64, size=n).astype(np.int32)
+                   for n in rng.randint(1, 9, size=12)]
         reports = {}
         for chunk in (8, None):
             eng = serving.GenerationEngine(
@@ -1379,35 +1422,68 @@ class TestPagedGeneration:
             q = serving.GenerationQueue(max_prompt_len=256,
                                         max_queue=64, page_size=16)
             cap = str(tmp_path / ('chunk' if chunk else 'mono'))
-            telemetry.enable(cap)
+            rec = telemetry.enable(cap)
+            long_rng = np.random.RandomState(5)
             try:
+                # the arrival schedule: one request every 1 / 150 s,
+                # and where the chaos site fires a burst of
+                # max-length prompts lands with it
                 chaos.install(chaos.FaultInjector(
                     'seed=7;serve_longprompt=p0.4:2'))
                 try:
-                    rep = serving.open_loop_generate(
-                        eng, q, rate=150.0, n_requests=12, seed=11,
-                        prompt_len_range=(1, 8), max_new_tokens=8,
-                        capture_dir=cap)
+                    arrivals, injected = [], 0
+                    for i, prompt in enumerate(prompts):
+                        for _ in range(chaos.on_serve_longprompt()):
+                            arrivals.append((
+                                i / 150.0, long_rng.randint(
+                                    0, 64, size=256).astype(np.int32)))
+                            injected += 1
+                        arrivals.append((i / 150.0, prompt))
                 finally:
                     chaos.uninstall()
+                reqs = self._drive_on_virtual_clock(eng, q, rec,
+                                                    arrivals, 8)
+                itl = rec.registry.histogram(
+                    'serve_intertoken_seconds').summary()
+                spans = [r for r in rec.events
+                         if r.get('type') == 'span']
+                rec.flush()
             finally:
                 telemetry.disable()
-            rep['capture'] = cap
-            reports[chunk] = rep
+            # prefill tokens launched in a tick in which a live decode
+            # stream was read (the prefills run first): what a token
+            # waited behind
+            decoding = {r['step'] for r in spans
+                        if r['name'] == 'decode'}
+            behind = {}
+            for r in spans:
+                if r['name'] == 'serve_prefill' \
+                        and r['step'] in decoding:
+                    behind[r['step']] = (behind.get(r['step'], 0)
+                                         + r['bucket'])
+            reports[chunk] = {
+                'capture': cap, 'injected': injected,
+                'served': sum(len(r.result(timeout=0)) == 8
+                              for r in reqs),
+                'offered': len(arrivals),
+                'prefill_chunks': eng.stats()['prefill_chunks'],
+                'intertoken_p99_ms': itl['p99'] * 1e3,
+                'behind': max(behind.values())}
         chunked, mono = reports[8], reports[None]
-        # identical offered load: same arrival seed, same chaos draws
-        assert chunked['longprompt_injected'] \
-            == mono['longprompt_injected'] > 0
+        # identical offered load: same prompts, same chaos draws
+        assert chunked['injected'] == mono['injected'] > 0
         assert chunked['served'] == mono['served'] \
-            == chunked['offered']
-        assert chunked['paged']['prefill_chunks'] \
-            > 32 * chunked['longprompt_injected']  # 256/8 per burst
+            == chunked['offered'] == mono['offered']
+        assert chunked['prefill_chunks'] \
+            > 32 * chunked['injected']  # 256/8 per burst
+        # no clock: a token of the chunked arm never waited behind
+        # more than a chunk a slot, one of the monolithic arm behind a
+        # whole prompt
+        assert chunked['behind'] <= 4 * 8
+        assert mono['behind'] >= 256
         chunk_p99 = chunked['intertoken_p99_ms']
         mono_p99 = mono['intertoken_p99_ms']
-        if mono_p99 < 2.0 * chunk_p99:
-            pytest.skip('no prefill-stall separation on this host '
-                        '(mono p99 %.1f ms vs chunked %.1f ms)'
-                        % (mono_p99, chunk_p99))
+        assert mono_p99 >= 2.0 * chunk_p99, (mono_p99, chunk_p99)
         # adaptive target between the two arms' tails: clear of every
         # chunked sample, inside the monolithic stall plateau
         target_ms = max((chunk_p99 * mono_p99) ** 0.5,
@@ -1666,6 +1742,430 @@ class TestSpeculativeDecoding:
         assert gen['speculative']['draft_proposed'] > 0
         rate = gen['speculative']['accepted_draft_rate']
         assert rate is None or 0.0 <= rate <= 1.0
+
+
+class TestTickAccounting:
+    """ISSUE 37: the serving tick accounts for itself.  The children
+    of ``serve_tick`` tile it, a decode call's dispatch and the wait
+    for its vector are spans of their own, every launch says whether
+    it found the device starved (``device_idle``), a call that did not
+    go out ahead says why, and a first token says what it waited
+    behind (``admit_wait``).  All of it only with a recorder live."""
+
+    PS = 8
+    CHILDREN = {'serve_expire', 'serve_admit', 'serve_prefill_prep',
+                'serve_prefill', 'serve_emit', 'serve_decode_prep',
+                'serve_decode'}
+    WORK = (([1, 2, 3], 6), ([4, 5], 4), ([6], 5), ([7, 8, 9, 10], 7),
+            ([11], 3), ([12, 13], 9))
+
+    @pytest.fixture(autouse=True)
+    def _telemetry_off(self):
+        from chainermn_tpu import telemetry
+        telemetry.disable()
+        yield
+        telemetry.disable()
+
+    def _engine(self, mode):
+        kw = dict(n_slots=4, max_prompt_len=8, max_len=32)
+        if mode != 'slots':
+            kw.update(paged=True, page_size=self.PS)
+        if mode == 'spec':
+            draft, dparams = _tiny_lm(n_layers=1)
+            kw.update(draft_model=draft, draft_params=dparams)
+        eng = serving.GenerationEngine(*_tiny_lm(n_layers=2), **kw)
+        eng.warmup()
+        return eng, serving.GenerationQueue(
+            max_prompt_len=8,
+            page_size=self.PS if eng.paged else None)
+
+    def _serve(self, eng, q, work=WORK, late=2):
+        """``work`` through the engine: all but the last ``late``
+        requests submitted before the first tick (so several are
+        admitted in ONE tick), the rest a few ticks in."""
+        work = list(work)
+        reqs = [q.submit(p, n) for p, n in work[:len(work) - late]]
+        for tick in range(400):
+            if tick in (3, 5) and len(reqs) < len(work):
+                reqs.append(q.submit(*work[len(reqs)]))
+            eng.step(q)
+            if len(reqs) == len(work) and all(r.done() for r in reqs):
+                break
+        return [[int(t) for t in r.result(timeout=0)] for r in reqs]
+
+    def _recorded(self, mode, **kw):
+        from chainermn_tpu import telemetry
+        eng, q = self._engine(mode)
+        rec = telemetry.enable()
+        out = self._serve(eng, q, **kw)
+        eng.step(q)     # one idle tick more
+        spans = [r for r in rec.events if r.get('type') == 'span']
+        return eng, out, spans
+
+    @staticmethod
+    def _named(spans, name):
+        return [r for r in spans if r['name'] == name]
+
+    @pytest.mark.parametrize('mode', ['paged', 'spec'])
+    def test_the_ticks_children_bear_the_names_and_do_not_overlap(
+            self, mode):
+        _, _, spans = self._recorded(mode)
+        names = set(self.CHILDREN)
+        if mode == 'spec':
+            names |= {'serve_draft', 'serve_verify'}
+        ticks = {r['id']: [] for r in self._named(spans, 'serve_tick')}
+        assert len(ticks) > 8
+        for r in spans:
+            if r.get('parent') in ticks:
+                assert r['name'] in names, r['name']
+                ticks[r['parent']].append(r)
+        by_id = {r['id']: r for r in spans if 'id' in r}
+        seen = set()
+        for tick, children in ticks.items():
+            children.sort(key=lambda r: r['t0'])
+            assert children[0]['name'] == 'serve_expire'
+            assert children[1]['name'] == 'serve_admit'
+            for a, b in zip(children, children[1:]):
+                assert a['t1'] <= b['t0'], (a['name'], b['name'])
+            assert by_id[tick]['t0'] <= children[0]['t0']
+            assert children[-1]['t1'] <= by_id[tick]['t1']
+            seen |= {r['name'] for r in children}
+        if mode == 'spec':      # its decode tick is draft and verify
+            names -= {'serve_decode_prep', 'serve_decode'}
+        assert seen == names
+
+    def test_first_token_emit_is_told_apart_by_its_attribute(self):
+        eng, _, spans = self._recorded('paged')
+        emits = self._named(spans, 'serve_emit')
+        first = [r for r in emits if r.get('first') == 1]
+        assert len(first) == eng.prefills == len(self.WORK)
+        assert len(emits) - len(first) == eng.decode_steps
+        assert all('active_slots' not in r for r in emits)
+        # a sequence's three spans, in order, in one tick
+        for r in first:
+            prep, = [p for p in self._named(spans, 'serve_prefill_prep')
+                     if p['parent'] == r['parent']
+                     and p['slot'] == r['slot']]
+            call, = [p for p in self._named(spans, 'serve_prefill')
+                     if p['parent'] == r['parent']
+                     and p['slot'] == r['slot']]
+            assert prep['t1'] <= call['t0'] <= call['t1'] <= r['t0']
+
+    @pytest.mark.parametrize('mode', ['paged', 'slots'])
+    def test_the_wait_is_split_from_the_dispatch(self, mode):
+        _, _, spans = self._recorded(mode)
+        kids = {}
+        for r in spans:
+            if r['name'].startswith(('serve_decode_', 'serve_prefill_')) \
+                    and r['name'] != 'serve_prefill_prep':
+                kids.setdefault(r['parent'], []).append(r['name'])
+        decodes = self._named(spans, 'serve_decode')
+        assert {r.get('reason') for r in decodes} >= {None, 'prime',
+                                                      'end'}
+        for r in decodes:
+            mine = sorted(kids.get(r['id'], []))
+            if r.get('ran_ahead') == 1:
+                assert mine == ['serve_decode_dispatch',
+                                'serve_decode_wait']
+            elif r['reason'] == 'prime':   # a dispatch and no wait
+                assert mine == ['serve_decode_dispatch']
+            else:                          # a settle: the reverse
+                assert mine == ['serve_decode_wait']
+        for r in self._named(spans, 'serve_prefill'):
+            assert kids[r['id']] == ['serve_prefill_dispatch',
+                                     'serve_prefill_wait']
+
+    @pytest.mark.parametrize('mode', ['paged', 'slots', 'spec'])
+    def test_a_call_that_did_not_go_out_ahead_says_why(self, mode):
+        from chainermn_tpu.serving.generate import SETTLE_REASONS
+        eng, _, spans = self._recorded(mode)
+        decodes = self._named(spans, 'serve_decode')
+        for r in decodes:
+            assert ('reason' in r) == (r.get('ran_ahead') != 1)
+            assert r.get('reason', 'end') in SETTLE_REASONS
+        settles = eng.stats()['settles']
+        assert tuple(settles) == SETTLE_REASONS
+        # six always-on counts: the priming calls are the calls that
+        # did not go out ahead, the rest settles without a dispatch
+        assert settles['prime'] == \
+            eng.decode_calls - eng.decode_calls_ahead
+        for reason in SETTLE_REASONS:
+            assert settles[reason] == sum(
+                1 for r in decodes if r.get('reason') == reason)
+        assert sum(settles.values()) == len(
+            [r for r in decodes if 'reason' in r])
+        if mode == 'spec':
+            assert not decodes and eng.verify_steps > 0
+        else:
+            assert settles['prime'] > 0 and settles['end'] > 0
+
+    def test_a_change_of_bucket_and_a_swap_are_reasons_too(self):
+        from chainermn_tpu import telemetry
+        eng, q = self._engine('paged')
+        rec = telemetry.enable()
+        # two rows of unequal length: when the short one ends by an
+        # EOS the host could not foresee, the next call's bucket is
+        # another while a call is in flight
+        self._serve(eng, q, work=(([1, 2, 3], 12), ([4, 5], 3),
+                                  ([6], 2)), late=0)
+        long_req = q.submit([1, 2], 6)
+        for _ in range(3):
+            eng.step(q)
+        assert eng._inflight is not None
+        for slot in list(eng._slots.values()):     # the rows go
+            eng._release_pages(slot.pages, slot.ring, slot.state_row)
+        eng._free += list(eng._slots)
+        eng._slots.clear()
+        eng.step(q)                                 # a drained table
+        long_req.set_result([])
+        eng.swap_params(eng.params, validate=False)
+        reasons = [r['reason'] for r in rec.events
+                   if r.get('name') == 'serve_decode' and 'reason' in r]
+        assert 'drained' in reasons
+        assert eng.stats()['settles']['drained'] == 1
+        assert eng.stats()['settles']['swap'] == 0   # nothing in flight
+
+    @pytest.mark.parametrize('mode', ['paged', 'slots'])
+    def test_a_first_token_says_what_it_waited_behind(self, mode):
+        eng, _, spans = self._recorded(mode)
+        stages = ('queue_wait', 'admit_wait', 'bucket_pack', 'prefill')
+        by_request = {}
+        for r in spans:
+            if r['name'] in stages:
+                by_request.setdefault(r['request_id'], {})[
+                    r['name']] = r
+        assert len(by_request) == len(self.WORK)
+        for found in by_request.values():
+            chain = [found[name] for name in stages]
+            for a, b in zip(chain, chain[1:]):
+                assert a['t1'] == b['t0']      # they telescope
+            total = sum(r['t1'] - r['t0'] for r in chain)
+            assert total == pytest.approx(
+                chain[-1]['t1'] - chain[0]['t0'], abs=1e-7)
+        # four were waiting at the first tick: admitted together,
+        # served one after the other
+        behind = sorted(r['behind']
+                        for r in self._named(spans, 'admit_wait'))
+        assert behind == [0, 0, 0, 1, 2, 3]
+        waits = {r['behind']: r for r in self._named(spans, 'admit_wait')
+                 if r['t0'] <= min(x['t0'] for x in
+                                   self._named(spans, 'admit_wait'))
+                 + 1e-3 or r['behind']}
+        calls = sorted(self._named(spans, 'serve_prefill'),
+                       key=lambda r: r['t0'])
+        for k in (1, 2, 3):
+            # the k-th waited at least the k prefill calls before it
+            assert waits[k]['t1'] >= calls[k - 1]['t1']
+            assert waits[k]['t1'] - waits[k]['t0'] >= sum(
+                c['t1'] - c['t0'] for c in calls[:k])
+
+    def test_admitting_ticks_say_how_many_they_admitted(self):
+        eng, _, spans = self._recorded('paged')
+        ticks = self._named(spans, 'serve_tick')
+        admitted = [r['admitted'] for r in ticks if 'admitted' in r]
+        assert admitted == [4, 1, 1]
+        assert sum(admitted) == eng.stats()['admissions'] == 6
+        assert all(r['admitted'] >= 1 for r in ticks
+                   if 'admitted' in r)
+        assert len(ticks) > len(admitted)
+
+    @pytest.mark.parametrize('mode', ['paged', 'slots', 'spec'])
+    def test_every_launch_says_whether_it_found_the_device_idle(
+            self, mode):
+        _, _, spans = self._recorded(mode)
+        idle = self._named(spans, 'device_idle')
+        assert idle
+        launches = {r['t0']: r for r in spans
+                    if r['name'].endswith('_dispatch')
+                    or r['name'] in ('serve_draft', 'serve_verify')}
+        blocked = [r for r in spans if r['name'] in (
+            'serve_decode_wait', 'serve_prefill_wait')
+            and r['t1'] - r['t0'] > 50e-6]
+        for r in idle:
+            assert r['kind'] == 'serve' and 'id' not in r
+            assert r['t0'] <= r['t1']
+            assert r['cause'] in ('admission', 'end', 'steady', 'other')
+            assert r['exact'] in (0, 1)
+            # it ends where a launch begins; a prefill is an admission
+            launch = launches[r['t1']]
+            if launch['name'] == 'serve_prefill_dispatch':
+                assert r['cause'] == 'admission'
+            for w in blocked:       # never inside a wait that blocked
+                assert r['t1'] <= w['t0'] or w['t1'] <= r['t0']
+            if r['exact']:
+                assert r['after'].endswith('_wait')
+                assert any(w['t1'] == r['t0'] for w in blocked)
+        assert len({r['t1'] for r in idle}) == len(idle)
+        if mode == 'spec':
+            assert {r['cause'] for r in idle} == {'other', 'admission'}
+        else:
+            # a prefill, and the priming call after one
+            assert {(r['cause'], launches[r['t1']]['name'])
+                    for r in idle} >= {
+                ('admission', 'serve_prefill_dispatch'),
+                ('admission', 'serve_decode_dispatch')}
+            # the CPU's calls end at once: every one is seen idle
+            assert all(r['after'] != 'client' or r['cause'] != 'other'
+                       for r in idle)
+
+    def test_an_admission_beside_a_call_in_flight_is_admissions(self):
+        """The first decode call after a prefill is booked
+        ``admission`` whatever stands between them: it went out ahead
+        of a call in flight (not ``steady``), behind a settle for a
+        change of bucket, or a tick later behind a settle for a
+        foreseen end (not ``end``)."""
+        from chainermn_tpu import telemetry
+        eng, q = self._engine('paged')
+        rec = telemetry.enable()
+        reqs = [q.submit([1, 2, 3], 16), q.submit([4, 5], 16)]
+        late = {4: ([6], 5),        # two rows -> three: another bucket
+                8: ([7, 8], 9),     # as the third request ends
+                10: ([9], 3)}       # a call in flight, the same bucket
+        for tick in range(400):
+            if tick in late:
+                assert eng._inflight is not None
+                reqs.append(q.submit(*late[tick]))
+            eng.step(q)
+            if len(reqs) == 5 and all(r.done() for r in reqs):
+                break
+        spans = [r for r in rec.events if r.get('type') == 'span']
+        by_id = {r['id']: r for r in spans if 'id' in r}
+        decodes = self._named(spans, 'serve_decode')
+        launches = self._named(spans, 'serve_decode_dispatch')
+        idle = {r['t1']: r for r in self._named(spans, 'device_idle')}
+        shapes = set()
+        for call in self._named(spans, 'serve_prefill')[2:]:
+            first = min((r for r in launches if r['t0'] > call['t1']),
+                        key=lambda r: r['t0'])
+            between = [r['reason'] for r in decodes
+                       if call['t1'] < r['t0'] and r['t1'] < first['t0']]
+            shapes.add((by_id[first['parent']]['ran_ahead'],
+                        tuple(between)))
+            # the CPU's calls end at once: the prefill's read-back saw
+            # the device idle, so this launch has its record
+            assert idle[first['t0']]['cause'] == 'admission'
+        assert shapes == {(0, ('bucket',)), (0, ('end',)), (1, ())}
+        # (``steady`` where a call ahead found the CPU done already)
+        assert {'admission', 'end'} <= {
+            r['cause'] for r in idle.values()} <= {
+            'admission', 'end', 'steady'}
+        # ... and a launch without a prefill before it is not
+        for r in launches:
+            prior = [c for c in self._named(spans, 'serve_prefill')
+                     if c['t1'] < r['t0']]
+            since = [d for d in launches
+                     if prior and prior[-1]['t1'] < d['t0'] < r['t0']]
+            if since and r['t0'] in idle:
+                assert idle[r['t0']]['cause'] != 'admission'
+
+    def test_the_tick_gauges_are_looked_up_once(self, monkeypatch):
+        from chainermn_tpu import telemetry
+        from chainermn_tpu.telemetry import recorder as rec_mod
+        looked_up = []
+        real = rec_mod.Registry.gauge
+
+        def gauge(self, name, help=''):
+            looked_up.append(name)
+            return real(self, name, help)
+
+        monkeypatch.setattr(rec_mod.Registry, 'gauge', gauge)
+        eng, q = self._engine('paged')
+        rec = telemetry.enable()
+        self._serve(eng, q)
+        assert sorted(looked_up) == [
+            'active_slots', 'serve_decode_backlog', 'serve_kv_pages_free',
+            'serve_kv_pages_in_use', 'serve_prefill_backlog',
+            'serve_queue_depth']
+        snap = rec.registry.snapshot()
+        assert snap['serve_queue_depth']['value'] == 0.0
+        assert snap['active_slots']['value'] == 1.0
+        assert snap['serve_kv_pages_in_use']['value'] is not None
+
+    @pytest.mark.parametrize('mode', ['paged', 'slots', 'spec'])
+    def test_with_telemetry_off_nothing_of_it_runs(self, mode,
+                                                   monkeypatch):
+        """The same tokens, and neither ``is_ready()`` nor a record:
+        the account exists only where a recorder is live."""
+        from chainermn_tpu import telemetry
+        _, traced, _ = self._recorded(mode)
+        telemetry.disable()
+        eng, q = self._engine(mode)
+
+        def boom(self):
+            raise AssertionError('is_ready() with telemetry off')
+
+        monkeypatch.setattr(type(jnp.zeros(1)), 'is_ready', boom)
+        assert self._serve(eng, q) == traced
+        assert telemetry.active() is None
+        assert eng._last_call is None and eng._idle_since is None
+        assert eng._gauges is None
+        # ... and the always-on counts count all the same
+        assert eng.stats()['admissions'] == len(self.WORK)
+        if mode != 'spec':
+            assert eng.stats()['settles']['prime'] > 0
+
+    def test_the_report_knows_the_ticks_anatomy(self, tmp_path):
+        """``telemetry report``: the tick's phases in order, the calls'
+        dispatch and wait, the reasons, the idle account by cause and
+        by phase; ``--request`` decomposes through ``admit_wait``."""
+        from chainermn_tpu import telemetry
+        from chainermn_tpu.telemetry import report
+        eng, q = self._engine('paged')
+        rec = telemetry.enable(str(tmp_path))
+        self._serve(eng, q)
+        rec.flush()
+        built = report.build_report(str(tmp_path))
+        ticks = built['serve_ticks']
+        assert ticks['ticks'] > 8
+        assert set(ticks['phases']) == self.CHILDREN | {
+            'serve_emit (first)', 'serve_prefill_dispatch',
+            'serve_prefill_wait', 'serve_decode_dispatch',
+            'serve_decode_wait'}
+        assert ticks['phases']['serve_emit (first)']['count'] == 6
+        assert 0 <= ticks['uncovered_mean_ms'] < ticks['tick_mean_ms']
+        assert ticks['decode_reasons']['prime'] == \
+            eng.stats()['settles']['prime']
+        assert ticks['admits_per_admit_tick'] == 2.0     # 4, 1, 1
+        idle = ticks['device_idle']
+        assert idle['records'] > 0
+        assert sum(idle['by_cause_ms'].values()) == pytest.approx(
+            idle['total_ms'], abs=0.01)
+        assert 'admission' in idle['by_cause_ms']
+        text = report.render_text(built)
+        assert 'scheduler ticks:' in text
+        assert 'serve_prefill_prep' in text and 'by after:' in text
+        worst = built['requests']['worst']
+        assert 'admit_wait' in worst['stage_ms']
+        assert 'admit_wait' in text
+        assert report.REQUEST_STAGES.index('admit_wait') == 1
+        trace = report.request_traces(rec.events)[worst['request_id']]
+        assert [s['name'] for s in trace['stages']][:4] == [
+            'queue_wait', 'admit_wait', 'bucket_pack', 'prefill']
+        assert 'admit_wait' in report.render_request_text(trace)
+        assert report.serve_tick_summary([]) is None
+
+    def test_a_recorder_that_goes_away_leaves_no_stale_probe(self):
+        """Calls launched while no recorder is live go unseen, so the
+        engine forgets the last one it saw: a recorder that comes back
+        does not take a finished, long-gone call for an idle device."""
+        from chainermn_tpu import telemetry
+        eng, q = self._engine('paged')
+        telemetry.enable()
+        self._serve(eng, q, work=self.WORK[:2], late=0)
+        assert eng._last_call is not None
+        telemetry.disable()
+        self._serve(eng, q, work=self.WORK[2:4], late=0)
+        assert eng._last_call is None and eng._idle_since is None
+        rec = telemetry.enable()
+        req = q.submit([1, 2, 3], 4)
+        eng.step(q)
+        first, = [r for r in rec.events
+                  if r.get('name') == 'serve_prefill_dispatch']
+        assert not [r for r in rec.events
+                    if r.get('name') == 'device_idle'
+                    and r['t1'] <= first['t0']]
+        while not req.done():
+            eng.step(q)
 
 
 class TestGenerateTelemetry:
