@@ -10,8 +10,12 @@ Selection guide (parity with the reference's table at
 ============== ========== ===========================================
 Name           Mesh       Use case
 ============== ========== ===========================================
-xla            2-D        flagship: let XLA lower the fused allreduce
-                          (recommended; no reference equivalent)
+xla            2-D        flagship: every large gradient its own
+                          collective in its own shape, small ones in
+                          packed buckets, issued outside the
+                          optimizer's cond so XLA runs them under the
+                          backward (recommended; no reference
+                          equivalent)
 hierarchical   2-D        explicit ICI reduce-scatter -> DCN psum ->
                           ICI all-gather (reference default)
 two_dimensional 2-D       full-mesh reduce-scatter/all-gather
@@ -20,9 +24,9 @@ naive          2-D        per-parameter pmean; CPU testing
 single_node    1 host     ICI-only; asserts inter_size == 1
 non_cuda_aware 2-D        hierarchical with f32-staged DCN leg
 dummy          any        no communication; fusion-overhead probe
-bucketed       2-D        ~25MB fused chunks in backward order: lets
-                          XLA overlap collectives with the backward
-                          pass (no reference equivalent)
+bucketed       2-D        ``xla`` with the cap of a packed bucket
+                          (``bucket_mb``, default 25) in the caller's
+                          hands (no reference equivalent)
 ============== ========== ===========================================
 """
 

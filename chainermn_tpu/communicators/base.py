@@ -264,6 +264,32 @@ class CommunicatorBase:
         return jax.tree_util.tree_map(
             lambda r, g: r.astype(jnp.result_type(g)), reduced, grads)
 
+    def allreduce_plan(self, grads):
+        """What one :meth:`allreduce_grad` of ``grads`` puts into the
+        program, from shapes alone (nothing is traced): ``leaves`` and
+        their ``bytes`` as reduced (after the :attr:`reduce_dtype`
+        cast), and where the strategy decides it per leaf
+        (``xla``, ``bucketed``) the ``collectives`` it issues and the
+        ``packed_leaves`` that share one.  The attributes of the
+        multi-node optimizer's ``allreduce_grad`` trace event."""
+        from chainermn_tpu.precision import cast_floating
+        reduced = jax.eval_shape(
+            lambda g: cast_floating(g, self.reduce_dtype), grads)
+        return self._plan_summary(jax.tree_util.tree_leaves(reduced))
+
+    def _plan_summary(self, leaves):
+        return {'leaves': len(leaves),
+                'bytes': sum(leaf.size * leaf.dtype.itemsize
+                             for leaf in leaves)}
+
+    def step_compiler_options(self):
+        """XLA options the train step that holds this strategy's
+        :meth:`allreduce_grad` should be compiled under (``jax.jit``'s
+        ``compiler_options``; :class:`StandardUpdater` passes them).
+        None by default: a strategy asks for what its own collectives
+        need, where the step is compiled, and no user sets a flag."""
+        return {}
+
     def declared_reduce_dtypes(self):
         """Dtype names this strategy declares its gradient reduction
         may narrow to (shardlint SL004 introspection hook; the dtype

@@ -1,4 +1,4 @@
-"""Bucketed allreduce: fused chunks sized for compute/comm overlap.
+"""Bucketed allreduce: ``xla`` with the bucket cap in the caller's hands.
 
 TPU-native extension beyond the reference's strategy set (its closest
 relatives are ``flat`` -- one giant buffer, reference
@@ -8,29 +8,26 @@ reducing until EVERY gradient of the backward pass exists, while
 per-leaf collectives drown small tensors in per-collective latency.
 
 The modern middle ground (the bucketing every DDP-style framework
-converged on): pack leaves in backward-completion order -- the model's
-reversed leaf order, since backprop produces last-layer gradients
-first -- into ~``bucket_mb`` fused buffers, one ``pmean`` per bucket.
-Inside the single jitted train step XLA sees each bucket's psum depend
-only on that bucket's gradients, so its latency-hiding scheduler can
-launch the first buckets' collectives while the backward pass is still
-computing earlier layers' gradients, and overlap buckets with one
-another on the ICI.
+converged on) is what :class:`XlaCommunicator` does, and this class is
+that one with ``bucket_mb`` for the cap of a packed bucket: leaves
+under ``xla_communicator.LARGE_LEAF_BYTES`` are packed in backward-
+completion order -- the model's reversed leaf order, since backprop
+produces last-layer gradients first -- into ~``bucket_mb`` buffers, one
+``pmean`` per bucket, and a leaf at or over it is reduced alone in its
+own shape, never packed.  Inside the single jitted train step XLA sees
+each collective depend only on its own gradients, so its
+latency-hiding scheduler can launch the first ones while the backward
+pass is still computing earlier layers' gradients, and overlap them
+with one another on the ICI.
 
 Buckets group by dtype first (mixed-precision models must not share a
 buffer across dtypes), then split at the size threshold.
 """
 
-import jax
-import jax.numpy as jnp
-from jax import lax
-
-from chainermn_tpu.communicators import memory_utility
-from chainermn_tpu.communicators.base import CommunicatorBase
-from chainermn_tpu.communicators.mesh_utility import AXES
+from chainermn_tpu.communicators.xla_communicator import XlaCommunicator
 
 
-class BucketedCommunicator(CommunicatorBase):
+class BucketedCommunicator(XlaCommunicator):
 
     def __init__(self, mesh=None, mesh_shape=None, devices=None,
                  bucket_mb=25.0, reduce_dtype=None):
@@ -39,35 +36,3 @@ class BucketedCommunicator(CommunicatorBase):
         if bucket_mb <= 0:
             raise ValueError('bucket_mb must be positive')
         self.bucket_bytes = int(bucket_mb * 1e6)
-
-    def plan_buckets(self, leaves):
-        """Partition leaf indices into fused buckets: backward-
-        completion order (reversed leaf order approximates "last layer
-        first", letting early buckets close early), one OPEN bucket
-        per dtype -- interleaved mixed-precision leaf orders (bf16
-        weights alternating with f32 norm scales) must still fuse into
-        big buckets, not flush on every dtype flip -- split at
-        ``bucket_bytes``."""
-        buckets = []       # list of lists of leaf indices
-        open_buckets = {}  # dtype -> (indices, bytes)
-        for i in reversed(range(len(leaves))):
-            leaf = leaves[i]
-            dt = jnp.dtype(leaf.dtype)
-            nbytes = leaf.size * dt.itemsize
-            cur, cur_bytes = open_buckets.get(dt, ([], 0))
-            if cur and cur_bytes + nbytes > self.bucket_bytes:
-                buckets.append(cur)
-                cur, cur_bytes = [], 0
-            cur.append(i)
-            open_buckets[dt] = (cur, cur_bytes + nbytes)
-        for cur, _ in open_buckets.values():
-            if cur:
-                buckets.append(cur)
-        return buckets
-
-    def _allreduce_impl(self, grads):
-        if not jax.tree_util.tree_leaves(grads):
-            return grads
-        return memory_utility.fused_reduce(
-            grads, lambda buf: lax.pmean(buf, AXES),
-            plan=self.plan_buckets)

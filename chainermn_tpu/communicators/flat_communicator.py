@@ -6,7 +6,8 @@ performs a single CUDA-aware MPI ``Allreduce`` over it
 leaves are promoted to one common dtype and fused into a single buffer
 for a single ``pmean`` -- one collective total, maximal fusion, at the
 cost of upcasting narrow dtypes in mixed-precision models.  (Contrast
-``xla``, which fuses per dtype: no upcast, one collective per dtype.)
+``xla``, which reduces each large leaf alone in its own dtype and
+packs the small ones per dtype: no upcast, no whole-tree buffer.)
 Original dtypes are restored on unpack.
 """
 

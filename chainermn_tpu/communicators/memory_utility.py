@@ -82,19 +82,25 @@ def plan_by_dtype(leaves):
                                        key=lambda kv: kv[0].name)]
 
 
-def fused_reduce(tree, reduce_buf, plan=plan_by_dtype):
+def fused_reduce(tree, reduce_buf):
     """Apply ``reduce_buf(flat_buffer) -> flat_buffer`` to a pytree,
-    one fused buffer per group of ``plan(leaves) -> [[leaf_idx, ...]]``.
+    one fused buffer per dtype (:func:`plan_by_dtype`), so the
+    collective count is O(#dtypes), not O(#params).
 
-    The default plan groups per dtype, so the collective count is
-    O(#dtypes), not O(#params); strategies with other fusion policies
-    (e.g. the bucketed communicator's size-capped backward-order
-    groups) pass their own plan and share this pack/reduce/unpack
-    path.
+    Who still packs the whole tree: the strategies whose reduction
+    NEEDS a flat buffer -- ``hierarchical``, ``two_dimensional`` and
+    ``non_cuda_aware`` reduce-scatter over a padded 1-D buffer,
+    ``single_node`` and ``dummy`` keep the reference's one-buffer shape
+    (``flat`` packs by itself, across dtypes).  ``xla`` and
+    ``bucketed`` do NOT come through here since PR 38: they reduce
+    every large leaf in its own shape and pack only the small ones
+    (``xla_communicator.py``), because one buffer over all gradients
+    cannot start reducing before the last of them exists and costs two
+    relayouting passes over every byte.
     """
     leaves, treedef = jax.tree_util.tree_flatten(tree)
     out = [None] * len(leaves)
-    for idxs in plan(leaves):
+    for idxs in plan_by_dtype(leaves):
         buf, schema = pack_params([leaves[i] for i in idxs])
         buf = reduce_buf(buf)
         for i, leaf in zip(idxs, unpack_params(buf, schema)):

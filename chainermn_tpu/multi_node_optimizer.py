@@ -96,8 +96,11 @@ def create_multi_node_optimizer(actual_optimizer, communicator,
                 'broadcast_first=False to opt out')
 
         def first_call(_):
-            # Initial weight sync in place of a step (reference :23-26);
-            # like the reference, no gradient allreduce is paid here.
+            # Initial weight sync in place of a step (reference :23-26).
+            # Unlike the reference, the program of this call holds the
+            # gradient allreduce too (hoisted out of the cond, below):
+            # it runs once, its result is dropped here, and what the
+            # call returns is bit-for-bit the sync alone.
             if _telemetry.live() is not None:
                 # trace-time mark: the L4 wrapper's broadcast is in
                 # the program.  Fires once per COMPILATION -- the
@@ -111,26 +114,33 @@ def create_multi_node_optimizer(actual_optimizer, communicator,
                 lambda s, p: (s - p).astype(p.dtype), synced, params)
             return updates, state.actual_state
 
-        def reduce_now():
-            if _telemetry.live() is not None:
-                _telemetry.event('multi_node_optimizer:allreduce_grad',
-                                 kind='collective_trace')
-            g = grads
-            if allreduce_dtype is not None:
-                g = jax.tree_util.tree_map(
-                    lambda x: x.astype(allreduce_dtype), g)
-            with jax.named_scope('grad_allreduce'):
-                reduced = communicator.allreduce_grad(g)
-            if allreduce_dtype is not None:
-                reduced = jax.tree_util.tree_map(
-                    lambda r, orig: r.astype(orig.dtype), reduced,
-                    grads)
-            return reduced
+        # The gradient reduction, OUTSIDE the cond: a `conditional` is
+        # one operation on the core's serial line, which starts when
+        # ALL its operands exist (the last gradient of the backward)
+        # and beside which nothing runs.  Out here every collective
+        # depends on its own gradients only, so the compiler is free
+        # to place it behind the backward step that produced them and
+        # to run it under the rest of the backward.  The cond keeps
+        # what differs between the two calls: the weight sync against
+        # the inner optimizer's update.
+        g = grads
+        if allreduce_dtype is not None:
+            g = jax.tree_util.tree_map(
+                lambda x: x.astype(allreduce_dtype), g)
+        if _telemetry.live() is not None:
+            # trace-time mark, once per COMPILATION: what the strategy
+            # puts into the program for this tree (`collectives`,
+            # `leaves`, `packed_leaves`, `bytes`)
+            _telemetry.event('multi_node_optimizer:allreduce_grad',
+                             kind='collective_trace',
+                             **communicator.allreduce_plan(g))
+        with jax.named_scope('grad_allreduce'):
+            reduced = communicator.allreduce_grad(g)
+        if allreduce_dtype is not None:
+            reduced = jax.tree_util.tree_map(
+                lambda r, orig: r.astype(orig.dtype), reduced, grads)
 
         def later_call(_):
-            # The predicate is replica-uniform, so collectives inside
-            # the branch are issued (or not) in lockstep on all devices.
-            reduced = reduce_now()
             if not double_buffering:
                 with jax.named_scope('optimizer_update'):
                     return actual_optimizer.update(

@@ -584,6 +584,12 @@ class StandardUpdater:
             return fn(*args)
 
         jit_kwargs = {'donate_argnums': (0, 1, 2)} if donate else {}
+        # what the strategy's collectives need from the compiler (the
+        # `xla` strategy on several TPU chips: all-reduces that run
+        # under the backward), asked for HERE and by no flag
+        options = comm.step_compiler_options()
+        if options:
+            jit_kwargs['compiler_options'] = options
         return jax.jit(train_step, static_argnums=(), **jit_kwargs)
 
     @_held_weakly

@@ -48,6 +48,30 @@ def hlo_collective_counts(fn, mesh, in_specs, out_specs, ops, *args):
     return {k: len(re.findall(k, txt)) for k in ops}
 
 
+def stablehlo_case_branches(txt):
+    """``(outside, [branch, ...])`` of a StableHLO text that holds ONE
+    top-level ``stablehlo.case`` (what ``lax.cond`` lowers to; branch
+    0 is the FALSE function): the text around the op and the text of
+    each of its regions, so a test can say WHERE a collective sits.
+    Keyed on the printer's indentation: a region's own ops (a nested
+    ``case`` too) are indented deeper than the op that holds them."""
+    lines = txt.splitlines()
+    start = next(i for i, line in enumerate(lines)
+                 if '"stablehlo.case"' in line)
+    pad = lines[start][:len(lines[start]) - len(lines[start].lstrip())]
+    branches, cur = [], []
+    for end in range(start + 1, len(lines)):
+        if lines[end].startswith(pad + '}, {'):
+            branches.append('\n'.join(cur))
+            cur = []
+        elif lines[end].startswith(pad + '}) :'):
+            branches.append('\n'.join(cur))
+            break
+        else:
+            cur.append(lines[end])
+    return '\n'.join(lines[:start] + lines[end + 1:]), branches
+
+
 def pytest_addoption(parser):
     parser.addoption(
         '--runslow', action='store_true', default=False,

@@ -610,3 +610,107 @@ def test_xing4_serving_executable_leaves_the_latent_pool_in_place(
         assert kernel in text, kernel
     # four solves of the residual path, each ONE kernel
     assert text.count('custom_call_target="tpu_custom_call"') >= 7
+
+
+@pytest.fixture
+def four_chips(one_chip):
+    """The four devices of the described ``v5e:2x2`` (after
+    ``one_chip``: its skip and its cache handling hold here too)."""
+    from jax.experimental import topologies
+    return list(topologies.get_topology_desc(
+        platform='tpu', topology_name='v5e:2x2').devices)
+
+
+def test_dp4_step_reduces_leaf_by_leaf_outside_the_conditional(four_chips):
+    """ISSUE 38, in the program the CHIP's compiler makes of a
+    data-parallel step over four chips (the multi-node optimizer +
+    `xla`, compiled as ``StandardUpdater`` compiles it): the gradient
+    all-reduces sit in the entry computation and not in a branch of the
+    optimizer's `conditional` (conditional code motion did not sink
+    them), each large one has one operand in the weight's own shape,
+    some of them are the compiler's asynchronous fused pairs, and
+    nothing holds the whole tree in one 1-D buffer."""
+    import re
+
+    import chainermn_tpu
+    import optax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    comm = chainermn_tpu.create_communicator('xla', devices=four_chips)
+    assert comm.size == 4
+    options = comm.step_compiler_options()
+    assert options, 'no overlap options for a four-chip TPU mesh'
+    opt = chainermn_tpu.create_multi_node_optimizer(optax.adam(1e-3), comm)
+    n_layers, width = 6, 1024
+
+    def loss(p, x):
+        h = x
+        for k in range(n_layers):
+            h = jnp.tanh(h @ p['w%d' % k].astype(BF16) + p['b%d' % k])
+        return jnp.mean(h.astype(F32) ** 2)
+
+    def step(params, opt_state, x):
+        grads = jax.grad(loss)(params, x)
+        updates, opt_state = opt.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state
+
+    repl = NamedSharding(comm.mesh, P())
+
+    def sds(tree):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=repl), tree)
+
+    params = {}
+    for k in range(n_layers):
+        params['w%d' % k] = jax.ShapeDtypeStruct((width, width), F32)
+        params['b%d' % k] = jax.ShapeDtypeStruct((width,), BF16)
+    x = jax.ShapeDtypeStruct(
+        (4 * 512, width), BF16,
+        sharding=NamedSharding(comm.mesh, comm.batch_spec()))
+    txt = jax.jit(
+        jax.shard_map(step, mesh=comm.mesh,
+                      in_specs=(P(), P(), comm.batch_spec()),
+                      out_specs=(P(), P()), check_vma=False),
+        donate_argnums=(0, 1), compiler_options=options,
+    ).lower(sds(params), sds(jax.eval_shape(opt.init, params)),
+            x).compile().as_text()
+
+    # computations by name; the ENTRY one and the conditional's branches
+    comps, entry, cur = {}, None, None
+    for line in txt.split('\n'):
+        m = re.match(r'^(ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$', line)
+        if m:
+            cur = m.group(2)
+            comps[cur] = []
+            entry = cur if m.group(1) else entry
+        elif line.startswith('}'):
+            cur = None
+        elif cur is not None:
+            comps[cur].append(line)
+    cond, = [line for line in comps[entry] if ' conditional(' in line]
+    # branch 0 is the FALSE function of `lax.cond`: the later calls
+    later = re.search(r'branch_computations=\{%?([\w.\-]+),',
+                      cond).group(1)
+    stepping = comps[later]
+    assert any('divide' in line or 'sqrt' in line for line in stepping), (
+        'Adam is not in the branch taken for it')
+    assert not any('all-reduce' in line for line in stepping), (
+        'an all-reduce sits in the branch that steps the optimizer')
+    # in the entry computation: one collective a weight, in the
+    # weight's own shape, synchronous or as the compiler's fused
+    # asynchronous pair, and one packed bucket of the biases
+    sync = [line for line in comps[entry]
+            if re.search(r'= \S+ all-reduce\(', line)]
+    started = [line for line in comps[entry]
+               if re.search(r'%async-collective-start[.\d]* = ', line)]
+    assert len(started) >= 1
+    assert len(sync) + len(started) == n_layers + 1
+    weights = [line for line in sync + started if re.search(
+        r'= \(?(bf16\[\d+,\d+\]\S*, )?f32\[%d,%d\]' % (width, width), line)]
+    bucket = [line for line in sync + started if re.search(
+        r'= \(?bf16\[%d\]' % (n_layers * width), line)]
+    assert (len(weights), len(bucket)) == (n_layers, 1)
+    total = n_layers * (width * width + width)
+    assert not re.search(r'f32\[%d\]' % total, txt)
+    assert not re.search(r'f32\[%d\]' % (n_layers * width * width), txt)

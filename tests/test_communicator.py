@@ -120,6 +120,83 @@ def test_bucketed_interleaved_dtypes_still_fuse():
         assert len(dts) == 1
 
 
+F32, BF16 = jnp.float32, jnp.bfloat16
+
+#: trees of (shape, dtype): what `xla` reduces leaf by leaf, packs, or
+#: both, each against `flat`'s one buffer
+XLA_TREES = {
+    'f32_only': [((600, 512), F32), ((64,), F32), ((520, 520), F32),
+                 ((8,), F32)],
+    'bf16_f32_interleaved': [((1024, 600), BF16), ((16,), F32),
+                             ((256,), BF16), ((520, 520), F32),
+                             ((32,), F32), ((8,), BF16)],
+    'leaf_at_threshold': [((512, 512), F32), ((512, 511), F32),
+                          ((4,), F32)],
+    'only_tiny': [((8,), F32), ((16,), F32), ((3, 2), F32)],
+    'only_large': [((520, 520), F32), ((600, 512), F32)],
+    'empty': [],
+}
+
+
+def _seeded_grads(comm, tree):
+    """Rank-dependent gradients: float32 leaves normal draws, bfloat16
+    leaves small whole numbers (their sum over 8 devices and its
+    eighth are exact in bfloat16 AND in the float32 `flat` promotes
+    them to, so the two means can be compared bit for bit)."""
+    key = jax.random.fold_in(jax.random.PRNGKey(7), comm.axis_rank())
+    grads = {}
+    for k, (shape, dtype) in enumerate(tree):
+        sub = jax.random.fold_in(key, k)
+        if dtype == BF16:
+            leaf = jax.random.randint(sub, shape, -15, 16).astype(BF16)
+        else:
+            leaf = jax.random.normal(sub, shape, dtype)
+        grads['p%02d' % k] = leaf
+    return grads
+
+
+@pytest.mark.parametrize('case, reduce_dtype', [
+    (name, None) for name in XLA_TREES] + [('f32_only', 'bfloat16')])
+def test_xla_reduces_what_flat_reduces_bitwise(case, reduce_dtype):
+    """The per-leaf collectives and the packed buckets of `xla` give,
+    bit for bit, the mean `flat` takes over ONE buffer: a mean is
+    elementwise, so which buffer an element rides in cannot show."""
+    from chainermn_tpu.communicators import xla_communicator
+    tree = XLA_TREES[case]
+    out = {}
+    for name in ('xla', 'flat'):
+        comm = chainermn_tpu.create_communicator(
+            name, mesh_shape=(2, 4), reduce_dtype=reduce_dtype)
+        out[name] = jax.jit(_shard_map(
+            comm, lambda: comm.allreduce_grad(
+                _seeded_grads(comm, tree))))()
+    assert sorted(out['xla']) == sorted(out['flat'])
+    for k, (shape, dtype) in enumerate(tree):
+        got, want = out['xla']['p%02d' % k], out['flat']['p%02d' % k]
+        assert got.dtype == want.dtype == dtype
+        np.testing.assert_array_equal(
+            np.asarray(got.astype(F32)), np.asarray(want.astype(F32)))
+    # and the plan is the one the sizes call for: every leaf once,
+    # the large ones alone
+    leaves = [jax.ShapeDtypeStruct(shape, reduce_dtype or dtype)
+              for shape, dtype in tree]
+    groups = chainermn_tpu.create_communicator(
+        'xla', mesh_shape=(2, 4)).plan_buckets(leaves)
+    assert sorted(i for g in groups for i in g) == list(
+        range(len(leaves)))
+    for i, leaf in enumerate(leaves):
+        if (leaf.size * leaf.dtype.itemsize
+                >= xla_communicator.LARGE_LEAF_BYTES):
+            assert [i] in groups
+    if case == 'leaf_at_threshold':
+        # exactly 1 MiB goes alone; four bytes a row less is packed
+        assert groups == [[0], [2, 1]]
+    if reduce_dtype is not None:
+        # planned on the bytes REDUCED: the 1.2 MB float32 leaves are
+        # 0.6 MB on the wire, so all four ride one bucket
+        assert groups == [[3, 2, 1, 0]]
+
+
 def test_dummy_communicator_is_identity():
     comm = chainermn_tpu.create_communicator('dummy', mesh_shape=(2, 4))
 
@@ -234,7 +311,9 @@ def test_strategy_lowerings_are_distinct():
 
     grads = {'a': jnp.ones((4096,), jnp.float32),
              'b': jnp.ones((128, 32), jnp.float32),
-             'c': jnp.ones((64,), jnp.float32)}
+             'c': jnp.ones((64,), jnp.float32),
+             'd': jnp.ones((600, 512), jnp.float32),    # 1.2 MB
+             'e': jnp.ones((512, 600), jnp.float32)}
 
     def counts(name, **kwargs):
         comm = chainermn_tpu.create_communicator(
@@ -247,6 +326,10 @@ def test_strategy_lowerings_are_distinct():
     assert counts('naive')['all_reduce'] == len(grads)
     # flat: ONE fused buffer, one collective, regardless of leaves
     assert counts('flat')['all_reduce'] == 1
+    # xla: every leaf of 1 MiB or more alone, the small ones in one
+    # packed bucket -- more collectives than flat's one, fewer than
+    # naive's one a leaf
+    assert counts('xla')['all_reduce'] == 2 + 1
     # hierarchical: staged scatter(intra) -> reduce(inter) ->
     # gather(intra)
     h = counts('hierarchical')
