@@ -37,3 +37,5 @@ from chainermn_tpu.ops.gated_delta import (  # noqa
     state_shape, tail_shape, unpack_state)
 from chainermn_tpu.ops.hyper_connection import (  # noqa
     mhc_coefficients, mhc_coefficients_reference, mhc_rows)
+from chainermn_tpu.ops.selective_scan import (  # noqa
+    selective_scan, selective_scan_reference, selective_scan_step)

@@ -116,10 +116,11 @@ def _conv_step_kernel(rows_ref, x_ref, w_ref, tail_ref, y_ref,
     tail_out_ref[0, (taps - 2) * rows:] = x
 
 
-def causal_conv_step(tail, rows, x, w):
+def causal_conv_step(tail, rows, x, w, bias=None):
     """One position a row, the tails where they lie: ``tail`` the leaf
     of :func:`tail_shape`, ``rows`` (N,) each sequence's row of it,
-    ``x`` (N, C) the new position before the convolution, ``w`` (K, C).
+    ``x`` (N, C) the new position before the convolution, ``w`` (K, C),
+    ``bias`` (C,) added to the result where the convolution has one.
     Returns ``(y (N, C) float32, tail)``: the convolution at the new
     position and the leaf with each row shifted by one position, in
     place (a Pallas kernel on the chip: one row's tail through VMEM a
@@ -168,7 +169,8 @@ def causal_conv_step(tail, rows, x, w):
             interpret=interpret_flag(),
             name='causal_conv_step',
         )(rows.astype(jnp.int32), x, w, tail)
-    return y.reshape(n, p * _LANES)[:, :c], tail
+    y = y.reshape(n, p * _LANES)[:, :c]
+    return y if bias is None else y + bias.astype(f32), tail
 
 
 # ----------------------------------------------------------------------

@@ -659,9 +659,10 @@ class GenerationEngine:
         self._cache = jax.device_put(cache, self._cache_sharding())
         self._cache_struct, self._cache_sig = _struct_and_signature(
             cache)
-        # bytes of (one K/V page, one state row) over all layers: what
-        # the tick's cache-bytes attributes are counted in; a family
-        # whose page is not K/V names its count (``page_counter``)
+        # bytes of (one K/V page, one state row[, one window page])
+        # over the layers that HOLD one: what the tick's cache-bytes
+        # attributes are counted in; a family whose page is not K/V
+        # names its count (``page_counter``)
         self._page_counter = getattr(model, 'page_counter', None)
         self._cache_bytes = (
             model.paged_cache_bytes(self._cache_struct)
@@ -2473,13 +2474,16 @@ class GenerationEngine:
                          **{self._page_counter: pages})
             if self.state_pool is not None:
                 # what the sequences' cache is made of: state held by
-                # the row, K/V by the page
-                page_bytes, row_bytes = self._cache_bytes
+                # the row, K/V by the page (a model that holds window
+                # pages too gives their bytes third)
+                page_bytes, row_bytes, *ring_bytes = self._cache_bytes
                 rows = self.state_pool.in_use()
+                held = rows * row_bytes + self.pool.in_use() * page_bytes
+                if ring_bytes:
+                    held += self.window_pool.in_use() * ring_bytes[0]
                 tick.set(state_rows_in_use=rows,
                          state_bytes_in_use=rows * row_bytes,
-                         cache_bytes_in_use=rows * row_bytes
-                         + self.pool.in_use() * page_bytes)
+                         cache_bytes_in_use=held)
             self._probe(rec, 'serve_tick')
         return worked
 

@@ -26,6 +26,11 @@ the widths ``bench.py`` uses, with random weights made from a seed:
   through the same engine, against the float32 forward; then the pool
   check at the ``olmo-hybrid-7b`` cell's shapes: K/V pools, state
   leaves and convolution tails;
+- *serve, xing4* and *serve, phi4flash*: the same pair for the latent
+  (MLA) family and for the Mamba / differential-attention hybrid
+  (``Phi4FlashLM``: full pages, ring pages and a state row in one
+  table; the pool check at the ``phi4-mini-flash`` cell's shapes, the
+  model whole);
 - ``--chips 4`` runs ONLY the transformer step over four devices
   (data-parallel, then dp 2 x tp 2) and the one-device loss both are
   compared with.
@@ -547,10 +552,11 @@ _PLUMBING = ('parameter', 'tuple', 'get-tuple-element', 'bitcast')
 _WRITES = ('scatter', 'dynamic-update-slice')
 #: ... and the Pallas calls that are the write of a head-major pool
 #: (``ops.paged_kv_append``), of a recurrent state leaf
-#: (``ops.gated_delta_step``) and of a convolution tail leaf
-#: (``ops.causal_conv_step``): the leaves are their aliased outputs
+#: (``ops.gated_delta_step``, ``ops.selective_scan_step``) and of a
+#: convolution tail leaf (``ops.causal_conv_step``): the leaves are
+#: their aliased outputs
 _WRITE_KERNEL = ('paged_kv_append', 'gated_delta_step',
-                 'causal_conv_step')
+                 'selective_scan_step', 'causal_conv_step')
 _HLO_DTYPE = {'bfloat16': 'bf16', 'float32': 'f32', 'int8': 's8'}
 
 
@@ -968,6 +974,96 @@ def serve_xing4(hidden=512, heads=4, experts=8, top_k=2, width=256,
 
 
 # ----------------------------------------------------------------------
+# the phi4flash family
+
+def serving_pool_check_phi4flash(n_slots=96, max_prompt=1024,
+                                 max_len=5120, page_size=64,
+                                 prompt_bucket=1024, **shape):
+    """:func:`_pool_check` at the shapes of the benchmark's
+    ``phi4flash-serve-closed96-think`` cell, the model WHOLE (7.7 GB of
+    zero weights): ONE full K/V leaf pair ``(7,681, 10, 64, 128)`` (K/V
+    heads packed by pair: 128 lanes) that layer 17 writes and 8 layers
+    read, 8 window layers' ring leaves ``(865, 10, 64, 128)``, and per
+    Mamba layer a state leaf ``(97, 1, 16, 5120)`` float32 and a tail
+    leaf ``(97, 144, 128)``.  Weights are zeros: nothing runs."""
+    import jax
+    import jax.numpy as jnp
+
+    from chainermn_tpu import serving
+    from chainermn_tpu.models import Phi4FlashLM
+
+    model = Phi4FlashLM(**shape)
+    params = jax.tree_util.tree_map(
+        lambda x: jnp.zeros(x.shape, x.dtype),
+        jax.eval_shape(lambda: model.init(jax.random.PRNGKey(SEED),
+                                          jnp.bfloat16)))
+    engine = serving.GenerationEngine(
+        model, params, n_slots=n_slots, max_prompt_len=max_prompt,
+        max_len=max_len, paged=True, page_size=page_size,
+        prefix_sharing=False)    # no Policy: A_log, D, lambdas stay f32
+    return _pool_check(
+        engine, 'serve phi4flash d%d/L%d %d slots, %d + %d pages of %d, '
+        '%d state rows' % (model.hidden_size, model.num_hidden_layers,
+                           n_slots, engine.n_pages,
+                           engine.window_pool.n_pages, page_size,
+                           engine.state_pool.n_pages), prompt_bucket)
+
+
+def serve_phi4flash(hidden=512, heads=8, kv_heads=4, width=1024,
+                    layers=8, vocab=4096, window=128, page_size=64,
+                    n_slots=8, max_prompt=256, max_len=512, max_new=48,
+                    n_requests=6, kernels='native'):
+    """A small ``Phi4FlashLM`` with the family's every mechanism at the
+    published head size (pairs of 64: 128-lane K/V rows): two Mamba /
+    window pairs, the memory layer, the K/V layer, a gated memory unit
+    and a cross layer, the tied head; through ``GenerationEngine`` +
+    ``GenerationQueue``: prompts on both sides of the window, slots,
+    pages, ring pages and state rows reused, every served token held
+    against the float32 kernel-free forward of the same weights (which
+    runs every layer at every position)."""
+    import jax
+    import jax.numpy as jnp
+
+    from chainermn_tpu import serving
+    from chainermn_tpu.models import Phi4FlashLM
+
+    model = Phi4FlashLM(
+        vocab_size=vocab, hidden_size=hidden, intermediate_size=width,
+        num_hidden_layers=layers, num_attention_heads=heads,
+        num_key_value_heads=kv_heads, sliding_window=window,
+        max_position_embeddings=max_len)
+    params = model.init(jax.random.PRNGKey(SEED), jnp.bfloat16)
+    rng = np.random.RandomState(SEED)
+    lengths = [3, window - 7, window + 9, max_prompt] + list(
+        rng.randint(4, max_prompt + 1, size=n_requests - 4))
+    lengths = lengths + lengths[::-1] + lengths[:n_slots // 2]
+    prompts = [rng.randint(0, vocab, size=int(n)).astype(np.int32)
+               for n in lengths]
+    what = 'serve phi4flash d%d/L%d/V%d, %d heads of %d, window %d' % (
+        hidden, layers, vocab, heads, model.head_dim, window)
+    engine = serving.GenerationEngine(
+        model, params, n_slots=n_slots, max_prompt_len=max_prompt,
+        max_len=max_len, paged=True, page_size=page_size,
+        prefix_sharing=False)    # no Policy: A_log, D, lambdas stay f32
+    streams = _serve_requests(engine, prompts, max_new, kernels, what)
+    stats = engine.stats()
+    require(stats['state_rows_in_use'] == 0 and stats['pages_in_use'] == 0
+            and stats['window_pages_in_use'] == 0
+            and 0 < stats['peak_state_rows_in_use'] <= n_slots
+            and stats['peak_window_pages_in_use']
+            <= n_slots * stats['window_ring'],
+            '%s: after the drain %d state rows, %d pages and %d window '
+            'pages in use' % (what, stats['state_rows_in_use'],
+                              stats['pages_in_use'],
+                              stats['window_pages_in_use']))
+    return _served_gaps(
+        model, engine, prompts, streams, max_prompt + max_new, what,
+        '%d state rows and %d window pages at the peak'
+        % (stats['peak_state_rows_in_use'],
+           stats['peak_window_pages_in_use']))
+
+
+# ----------------------------------------------------------------------
 # four chips
 
 def _distinct_devices(tree):
@@ -1107,7 +1203,10 @@ def main(argv=None):
                       ('serving_pool_olmo_hybrid',
                        serving_pool_check_olmo_hybrid),
                       ('serve_xing4', serve_xing4),
-                      ('serving_pool_xing4', serving_pool_check_xing4)]
+                      ('serving_pool_xing4', serving_pool_check_xing4),
+                      ('serve_phi4flash', serve_phi4flash),
+                      ('serving_pool_phi4flash',
+                       serving_pool_check_phi4flash)]
             if args.phases:
                 asked = args.phases.split(',')
                 unknown = set(asked) - {name for name, _ in phases}

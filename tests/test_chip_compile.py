@@ -32,7 +32,8 @@ KERNEL_MODULES = [importlib.import_module('chainermn_tpu.ops.' + name)
                   for name in ('flash_attention', 'layer_norm',
                                'cross_entropy', 'batch_norm_act',
                                'optimizer', 'grouped_matmul',
-                               'gated_delta', 'hyper_connection')]
+                               'gated_delta', 'hyper_connection',
+                               'selective_scan')]
 
 BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
 
@@ -200,7 +201,67 @@ def _mhc(x, phi, alpha, b):
                             ops.mhc_coefficients(x, phi, alpha, b)], -1)
 
 
+# the phi4flash-serve-closed96-think cell's widths: 96 rows; K/V heads
+# packed by pair (10 rows of 128 lanes a position), 40 padded query
+# heads in groups of 4; pages of 64: 7,681 in the ONE full leaf, 865 in
+# a ring leaf (96 rings of 9 for a window of 512); d_inner 5120, 16
+# state values, 4 taps; prompts up to 1,024
+_FLASH_KV = lambda pages: [((pages, 10, 64, 128), BF16)] * 2  # noqa: E731
+_Q96 = ((96, 40, 128), BF16)
+_SCAN = lambda t: [((t, 5120), BF16), ((t, 5120), F32),       # noqa: E731
+                   ((5120, 16), F32), ((t, 16), F32), ((t, 16), F32),
+                   ((5120,), F32)]
+
+
+def _decode_pairs(q, k, v, tables, lengths):
+    return ops.flash_attention_decode_paged(
+        q, k, v, tables, lengths, scale=0.125, group=4, head_major=True)
+
+
+def _decode_pairs_ring(q, k, v, tables, lengths):
+    return ops.flash_attention_decode_paged(
+        q, k, v, tables, lengths, scale=0.125, group=4, window=512,
+        head_major=True)
+
+
+def _flash_pairs_window(q, k, v):
+    return ops.flash_attention(q, k, v, causal=True, scale=0.125,
+                               window=512)
+
+
+def _scan_prompt(x, delta, a, b, c, d):
+    return ops.selective_scan(x, delta, a, b, c, d, length=1000)[0]
+
+
+def _scan_step(state, rows, x, delta, a, b, c, d):
+    return ops.selective_scan_step(state, rows, x, delta, a, b, c, d)[0]
+
+
+def _conv_step_bias(tail, rows, x, w, bias):
+    return ops.causal_conv_step(tail, rows, x, w, bias)
+
+
 CASES = {
+    'selective_scan_prompt_1024': (_scan_prompt, _SCAN(1024)),
+    'selective_scan_prompt_64': (_scan_prompt, _SCAN(64)),
+    'selective_scan_step_96rows': (
+        _scan_step, [((97, 1, 16, 5120), F32), ((96,), I32)] + _SCAN(96)),
+    'causal_conv_step_96rows_bias': (
+        _conv_step_bias, [((97, 144, 128), BF16), ((96,), I32),
+                          ((96, 5120), BF16), ((4, 5120), BF16),
+                          ((5120,), BF16)]),
+    'decode_paged_pairs_group4_page64': (
+        _decode_pairs, [_Q96] + _FLASH_KV(7681)
+        + [((96, 80), I32), ((96,), I32)]),
+    'decode_paged_pairs_ring9_window512': (
+        _decode_pairs_ring, [_Q96] + _FLASH_KV(865)
+        + [((96, 9), I32), ((96,), I32)]),
+    'flash_fwd_pairs_window512_group4_t1024': (
+        _flash_pairs_window, [((1, 1024, 40, 128), BF16)]
+        + [((1, 1024, 10, 128), BF16)] * 2),
+    'paged_kv_append_96rows_10pairs': (
+        _append, _FLASH_KV(7681) + [((96, 10, 128), BF16)] * 2
+        + [((96,), I32)] * 2),
     'flash_fwd_causal_192_128_t6144': (
         _flash, [((1, 6144, 32, 192), BF16)] * 2
         + [((1, 6144, 32, 128), BF16)]),
@@ -298,7 +359,8 @@ CASES = {
 #: its cache: ``causal_conv_step`` says that its aliased output lies in
 #: HBM, and this compiler aborts where the operand is its own copy of a
 #: parameter that was not given up
-DONATED = {'causal_conv_step_48rows': (0,)}
+DONATED = {'causal_conv_step_48rows': (0,),
+           'causal_conv_step_96rows_bias': (0,)}
 
 
 @pytest.mark.parametrize('case', sorted(CASES))
@@ -610,6 +672,88 @@ def test_xing4_serving_executable_leaves_the_latent_pool_in_place(
         assert kernel in text, kernel
     # four solves of the residual path, each ONE kernel
     assert text.count('custom_call_target="tpu_custom_call"') >= 7
+
+
+@pytest.mark.parametrize('body', ['decode', 'prefill'])
+def test_phi4flash_serving_executable_leaves_pool_rings_and_states_in_place(
+        body, one_chip, mosaic):
+    """The ``phi4flash`` serving executables at the widths AND depth of
+    the ``phi4-mini-flash`` cell (the model whole: 96 rows, 7,681 full
+    pages and 865 ring pages of 64, 97 state rows), compiled for the
+    described chip: the ONE full K/V leaf pair, the 8 rings and the 9
+    state and tail leaves are updated where they lie (nothing makes a
+    value of a leaf's shape besides ``paged_kv_append``,
+    ``selective_scan_step`` and ``causal_conv_step`` in decode, the
+    page scatter and the row update in prefill), the cache is held at
+    its nominal bytes, and the 14 layers of the cross-decoder own no
+    leaf."""
+    import os
+    import sys
+
+    from chainermn_tpu import models as M
+    from chainermn_tpu.models.phi4flash import F32_LEAVES
+    from chainermn_tpu.serving.generate import GenerationEngine
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    import chip_smoke
+
+    model = M.Phi4FlashLM()
+    paths, treedef = jax.tree_util.tree_flatten_with_path(
+        model.param_shapes(), is_leaf=lambda x: isinstance(x, tuple))
+    params = jax.tree_util.tree_unflatten(treedef, [
+        jax.ShapeDtypeStruct(
+            shape, F32 if path[-1].key in F32_LEAVES else BF16,
+            sharding=one_chip) for path, shape in paths])
+    cache = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                       sharding=one_chip),
+        jax.eval_shape(lambda: model.init_paged_kv_cache(
+            7681, 64, n_window_pages=865, n_state_rows=97)))
+    assert (len(cache['k']), len(cache['state']), len(cache['tail'])) \
+        == (9, 9, 9)
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, I32, sharding=one_chip)
+
+    def decode(p, c, tokens, positions, tables):
+        logits, c, counters = model.decode_step_paged(
+            p, c, tokens, positions, tables)
+        return GenerationEngine._sampled(logits, counters), c
+
+    def prefill(p, c, tokens, length, pos0, table):
+        logits, c, counters = model.prefill_paged(
+            p, c, tokens, length, table, pos0)
+        return GenerationEngine._sampled(logits, counters), c
+
+    width = 80 + 9 + 1                  # full table | ring | state row
+    fn, operands = {
+        'decode': (decode, (ints(96), ints(96), ints(96, width))),
+        'prefill': (prefill, (ints(1, 1024), ints(), ints(),
+                              ints(width))),
+    }[body]
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *operands).compile()
+    leaves = jax.tree_util.tree_leaves(cache)
+    assert {leaf.shape for leaf in leaves} == {
+        (7681, 10, 64, 128), (865, 10, 64, 128), (97, 1, 16, 5120),
+        (97, 144, 128)}
+    assert chip_smoke.pool_shaped(compiled.as_text(), leaves) == []
+    memory = compiled.memory_analysis()
+    nominal = sum(leaf.dtype.itemsize * leaf.size for leaf in leaves)
+    assert nominal == 2 * (7681 + 8 * 865) * 10 * 64 * 128 * 2 \
+        + 9 * 97 * (16 * 5120 * 4 + 144 * 128 * 2)
+    assert nominal <= memory.alias_size_in_bytes <= 1.01 * nominal
+    # weights and cache together leave the chip room: 12.8 of 16 GB
+    assert memory.argument_size_in_bytes < 12.9e9
+    assert memory.temp_size_in_bytes < 7681 * 10 * 64 * 128 * 2
+    calls = compiled.as_text().count('tpu_custom_call')
+    if body == 'decode':
+        # 16 attentions, 9 appends, 9 convolution steps, 9 scan steps
+        assert calls >= 43
+    else:
+        # 8 window attentions and 9 scans; the cross-decoder's one
+        # query row is plain XLA
+        assert calls >= 17
 
 
 @pytest.fixture

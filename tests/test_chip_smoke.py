@@ -187,6 +187,32 @@ def test_serving_pool_xing4_phase_tiny():
             v_head_dim=16, n_routed_experts=8, num_experts_per_tok=2)
 
 
+def test_serve_phi4flash_phase_tiny(interpret):
+    out = chip_smoke.serve_phi4flash(
+        hidden=64, heads=4, kv_heads=2, width=96, vocab=64, window=8,
+        page_size=4, n_slots=3, max_prompt=24, max_len=48, max_new=12,
+        n_requests=5, kernels='interpret')
+    # every slot, ring and state row reused: 5 + 5 + 1 requests on 3
+    assert len(out['streams']) == 11
+    assert all(len(s) == 12 for s in out['streams'])
+    assert out['gap_mean'] < 0.01
+
+
+def test_serving_pool_phi4flash_phase_tiny():
+    """As ``test_serving_pool_olmo_hybrid_phase_tiny``: on the CPU the
+    check must bite (the jnp twins gather and scatter the leaves
+    through pool-shaped values); the four kinds of leaf are named
+    first."""
+    with pytest.raises(chip_smoke.SmokeFailure,
+                       match='makes pool-shaped values'):
+        chip_smoke.serving_pool_check_phi4flash(
+            n_slots=2, max_prompt=8, max_len=32, page_size=4,
+            prompt_bucket=8, vocab_size=64, hidden_size=64,
+            intermediate_size=96, num_hidden_layers=8,
+            num_attention_heads=4, num_key_value_heads=2,
+            sliding_window=8)
+
+
 def test_phases_option_names_an_unknown_phase(monkeypatch, capsys):
     monkeypatch.setattr(chip_smoke, 'check_device', lambda chips: {})
     monkeypatch.delenv('CHAINERMN_TPU_PALLAS', raising=False)

@@ -901,3 +901,65 @@ def test_flash_blocks_rule(case, kernel):
     if t >= 1024:
         # the point of the rule: a step that carries work
         assert bq * bk >= 256 * 256
+
+
+# -- the selective scan (ops/selective_scan.py) ------------------------
+
+def _scan_operands(t, di=128, n=16, seed=0):
+    """``x``, ``delta`` in (0, ~1), ``A`` < 0, ``B``, ``C``, ``D``."""
+    x, delta, a, b, c, d = (_rand(shape, seed + i) for i, shape in
+                            enumerate([(t, di), (t, di), (di, n),
+                                       (t, n), (t, n), (di,)]))
+    return x, jax.nn.softplus(delta - 2.0), -jnp.exp(a), b, c, d
+
+
+@pytest.mark.parametrize('t', [1, 7, 32, 33, 150])
+def test_selective_scan_is_the_per_token_recurrence(mode, t):
+    """One position, under a chunk, a whole chunk, over its boundary
+    (32 in the jnp form), and over the kernel's (128)."""
+    operands = _scan_operands(t)
+    m, state = ops.selective_scan(*operands)
+    want_m, want_state = ops.selective_scan_reference(*operands)
+    np.testing.assert_allclose(m, want_m, atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(state, want_state, atol=2e-5, rtol=2e-5)
+
+
+def test_selective_scan_continues_from_a_state(mode):
+    operands = _scan_operands(40, seed=3)
+    _, mid = ops.selective_scan(*(a[:25] if a.shape[0] == 40 else a
+                                  for a in operands))
+    m, state = ops.selective_scan(
+        *(a[25:] if a.shape[0] == 40 else a for a in operands),
+        state0=mid)
+    want_m, want_state = ops.selective_scan_reference(*operands)
+    np.testing.assert_allclose(m, want_m[25:], atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(state, want_state, atol=2e-5, rtol=2e-5)
+
+
+def test_selective_scan_step_moves_its_rows_alone(mode):
+    di, n = 256, 16
+    x, delta, a, b, c, d = _scan_operands(3, di=di, seed=5)
+    leaf = _rand(ops.state_shape(6, 1, n, di), 9)
+    rows = jnp.asarray([4, 1, 2], jnp.int32)
+    m, out = ops.selective_scan_step(leaf, rows, x, delta, a, b, c, d)
+    assert out.shape == leaf.shape == (6, 1, n, di)
+    for i, row in enumerate([4, 1, 2]):
+        want_m, want = ops.selective_scan_reference(
+            x[i:i + 1], delta[i:i + 1], a, b[i:i + 1], c[i:i + 1], d,
+            state0=leaf[row, 0])
+        np.testing.assert_allclose(m[i], want_m[0], atol=2e-5, rtol=2e-5)
+        np.testing.assert_allclose(out[row, 0], want, atol=2e-5,
+                                   rtol=2e-5)
+    for row in (0, 3, 5):
+        np.testing.assert_array_equal(out[row], leaf[row])
+
+
+def test_convolution_step_adds_its_bias(mode):
+    taps, c = 4, 200
+    w, bias, x = _rand((taps, c), 0), _rand((c,), 1), _rand((2, c), 2)
+    tail = jnp.zeros(ops.tail_shape(3, taps, c, jnp.float32),
+                     jnp.float32)
+    rows = jnp.asarray([2, 1], jnp.int32)
+    plain, _ = ops.causal_conv_step(tail, rows, x, w)
+    biased, _ = ops.causal_conv_step(tail, rows, x, w, bias)
+    np.testing.assert_allclose(biased, plain + bias, atol=1e-6)

@@ -227,6 +227,57 @@ def test_mhc_coefficients_mosaic(rows):
         _close(g, w, rtol=1e-4, name=name)
 
 
+def _scan_operands(t, di=5120, n=16):
+    rng = np.random.RandomState(3)
+    f32 = jnp.float32
+    return (jnp.asarray(rng.randn(t, di), jnp.bfloat16),
+            jnp.asarray(np.exp(rng.uniform(np.log(1e-3), np.log(0.3),
+                                           (t, di))), f32),
+            -jnp.asarray(np.tile(np.arange(1, n + 1), (di, 1)), f32),
+            jnp.asarray(rng.randn(t, n), f32),
+            jnp.asarray(rng.randn(t, n), f32),
+            jnp.asarray(rng.randn(di), f32))
+
+
+@pytest.mark.parametrize('t, length', [(64, 64), (100, 93), (1024, 1000)])
+def test_selective_scan_mosaic(t, length):
+    """The prompt kernel at the ``phi4-mini-flash`` cell's widths: under
+    a chunk of 128, over one and a whole bucket, pad positions past
+    ``length`` leaving the state alone."""
+    from chainermn_tpu import ops
+    operands = _scan_operands(t)
+    m, state = jax.jit(lambda *a: ops.selective_scan(*a, length=length))(
+        *operands)
+    want_m, want_state = jax.jit(ops.selective_scan_reference)(
+        *(a[:length] if a.shape[0] == t else a for a in operands))
+    _close(m[:length], want_m, rtol=1e-4, name='m')
+    _close(state, want_state, rtol=1e-4, name='state')
+
+
+def test_selective_scan_step_mosaic():
+    """The decode kernel at the cell's widths: 96 rows of a 97-row leaf
+    in place, pad rows sharing row 0, the other rows untouched."""
+    from chainermn_tpu import ops
+    di, n, n_rows = 5120, 16, 96
+    x, delta, a, b, c, d = _scan_operands(n_rows)
+    rng = np.random.RandomState(4)
+    leaf = jnp.asarray(rng.randn(*ops.state_shape(97, 1, n, di)),
+                       jnp.float32)
+    rows = np.zeros((n_rows,), np.int32)
+    rows[:60] = rng.permutation(np.arange(1, 97))[:60]
+    m, out = jax.jit(ops.selective_scan_step, donate_argnums=(0,))(
+        jnp.array(leaf), jnp.asarray(rows), x, delta, a, b, c, d)
+    xf = x.astype(jnp.float32)
+    h = jnp.exp(delta[:, None, :] * a.T) * leaf[rows, 0] \
+        + b[:, :, None] * (delta * xf)[:, None, :]
+    want_m = jnp.einsum('rnd,rn->rd', h, c) + d * xf
+    _close(m[:60], want_m[:60], rtol=1e-4, name='m')
+    _close(out[rows[:60], 0], h[:60], rtol=1e-4, name='state')
+    idle = np.setdiff1d(np.arange(1, 97), rows)
+    np.testing.assert_array_equal(np.asarray(out[idle]),
+                                  np.asarray(leaf[idle]))
+
+
 def test_layer_norm_mosaic():
     from chainermn_tpu import ops
     from chainermn_tpu.ops.layer_norm import layer_norm_reference
