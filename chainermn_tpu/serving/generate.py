@@ -748,6 +748,7 @@ class GenerationEngine:
         # ... and why the others were not (SETTLE_REASONS)
         self.settles = dict.fromkeys(SETTLE_REASONS, 0)
         self.admissions = 0          # requests popped from the queue
+        self.pages_allocated = 0     # by _alloc_page, evicting or not
         self.draft_steps = 0
         self.verify_steps = 0
         self.draft_proposed = 0
@@ -1397,7 +1398,28 @@ class GenerationEngine:
         while page is None and self._prefix_index is not None \
                 and self._prefix_index.evict(1):
             page = self.pool.alloc()
+        if page is not None:
+            self.pages_allocated += 1
         return page
+
+    def _paging(self):
+        """Pages allocated and index references dropped so far: a
+        prep span's ``pages`` / ``evicted`` are their growth under
+        it."""
+        idx = self._prefix_index
+        return (self.pages_allocated,
+                idx.evictions if idx is not None else 0)
+
+    def _set_paging(self, span, before):
+        """``pages`` / ``evicted`` on ``span``, each only where it
+        is not 0 (as ``admitted`` on ``serve_tick``: a reader's mean
+        over the spans that have it is pages a span that paged)."""
+        pages, evicted = (now - was for now, was
+                          in zip(self._paging(), before))
+        if pages:
+            span.set(pages=pages)
+        if evicted:
+            span.set(evicted=evicted)
 
     def _table_array(self, pages, ring=(), state_row=0, out=None):
         """One sequence's table operand, ``[full table | ring | state
@@ -1675,7 +1697,7 @@ class GenerationEngine:
             req = st.request
             prompt = req.prompt
             with (self._phase(rec, 'serve_prefill_prep', slot=sid)
-                  if rec is not None else NULL_SPAN):
+                  if rec is not None else NULL_SPAN) as prep:
                 if rec is not None and st.chunks == 0:
                     t_reach = rec.now()
                     rec.child_span(req.request_id, 'admit_wait',
@@ -1690,12 +1712,15 @@ class GenerationEngine:
                 n = min(width, remaining)
                 last_page = (st.pos + n - 1) // self.page_size
                 dry = False
+                paging = self._paging() if rec is not None else None
                 while len(st.pages) <= last_page:
                     page = self._alloc_page()
                     if page is None:
                         dry = True
                         break
                     st.pages.append(page)
+                if rec is not None:
+                    self._set_paging(prep, paging)
                 if dry:
                     del self._prefilling[sid]
                     self._shed_paged(req, st.pages, 'prefill', st.ring,
@@ -2014,8 +2039,11 @@ class GenerationEngine:
         (taken before the slots advance; the instrument's own work,
         booked with the host's)."""
         with (self._phase(rec, 'serve_decode_prep') if rec is not None
-              else NULL_SPAN):
+              else NULL_SPAN) as prep:
+            paging = self._paging() if rec is not None else None
             operands = self._decode_operands(pend, rec)
+            if rec is not None:
+                self._set_paging(prep, paging)
             attrs = None
             if rec is not None and operands is not None \
                     and operands[-1] is not None:
@@ -2508,6 +2536,10 @@ class GenerationEngine:
                                              'sequences + banked '
                                              'prefixes) at the tick',
                     'serve_kv_pages_free': 'free KV pages at the tick'})
+            if self._prefix_index is not None:
+                helps['prefix_evictions'] = (
+                    'banked pages the prefix index has dropped for a '
+                    'dry pool, up to the tick')
             held = self._gauges = (rec, {
                 name: rec.registry.gauge(name, help=text)
                 for name, text in helps.items()})
@@ -2533,6 +2565,9 @@ class GenerationEngine:
                         self.pool.in_use())
                     gauges['serve_kv_pages_free'].set(
                         self.pool.available())
+                if self._prefix_index is not None:
+                    gauges['prefix_evictions'].set(
+                        self._prefix_index.evictions)
             now = clock()
             force = (_chaos.on_serve_cancel()
                      if _chaos._active is not None else 0)
@@ -2610,6 +2645,7 @@ class GenerationEngine:
                     prefix_lookups=self._prefix_index.lookups,
                     prefix_hits=self._prefix_index.hits,
                     prefix_hit_rate=self._prefix_index.hit_rate(),
+                    prefix_evictions=self._prefix_index.evictions,
                     prefix_tokens_reused=(
                         self._prefix_index.tokens_reused))
         base = {

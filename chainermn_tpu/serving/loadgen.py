@@ -220,7 +220,7 @@ def open_loop_generate(engine, queue, rate, n_requests, seed=0,
             'page_size', 'n_pages', 'pages_in_use', 'pages_free',
             'peak_pages_in_use', 'prefill_chunk', 'prefill_chunks',
             'cow_copies', 'copy_trace_count', 'prefix_lookups',
-            'prefix_hits', 'prefix_hit_rate',
+            'prefix_hits', 'prefix_hit_rate', 'prefix_evictions',
             'prefix_tokens_reused')} if st.get('paged') else None),
         'worst_request': worst,
         'speculative': _spec_report(st, st0),

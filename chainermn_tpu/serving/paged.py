@@ -39,6 +39,7 @@ immutable.
 """
 
 import binascii
+import heapq
 
 import numpy as np
 
@@ -123,13 +124,32 @@ class PagePool:
 
 
 class _Node:
-    __slots__ = ('children', 'page', 'tails', 'touch')
+    """One banked page and its place in the trie.  A node reached by a
+    FULL page-sized chunk may have ``children`` and ``tails``; a tail
+    (a banked partial page) is a node that never has either.  The root
+    banks nothing."""
+    __slots__ = ('children', 'tails', 'page', 'touch', 'parent', 'home',
+                 'key', 'queued')
 
-    def __init__(self, page=None):
-        self.children = {}     # page-sized token tuple -> _Node
-        self.page = page       # pool page banking this chunk (root: None)
-        self.tails = {}        # partial-chunk token tuple -> [page, touch]
+    def __init__(self, page=None, parent=None, home=None, key=None):
+        self.children = {}     # page-sized chunk of token ids -> _Node
+        self.tails = {}        # partial chunk of token ids -> _Node
+        self.page = page       # pool page banking this chunk
         self.touch = 0
+        self.parent = parent
+        self.home = home       # the dict of ``parent`` that holds it
+        self.key = key         # ... and its key there
+        self.queued = False    # has a record in the eviction heap
+
+    def evictable(self):
+        return not self.children and not self.tails
+
+
+def _token_bytes(prompt):
+    """The prompt's token ids as the bytes of an int32 vector: a chunk
+    of ``n`` tokens is a slice of ``4 * n`` bytes, hashed and compared
+    at memory speed, and equal exactly where the ids are."""
+    return np.ascontiguousarray(prompt, np.int32).tobytes()
 
 
 class RadixPrefixIndex:
@@ -146,29 +166,38 @@ class RadixPrefixIndex:
     ``lookup`` returns page ids only -- callers retain what they keep.
     Matching is exact on token ids (the radix property: one walk,
     longest banked prefix wins).
+
+    Eviction order is kept as the index goes, in a heap: every
+    EVICTABLE entry (a tail, or a node with no children and no tails)
+    has exactly one record ``(touch, serial, entry)`` there, made when
+    it became evictable.  A later touch does not move the record (so
+    ``lookup`` stays a walk of the matched path): the recorded touch is
+    never newer than the entry's own, hence the heap's top is a lower
+    bound on every evictable entry's touch, and :meth:`evict` re-keys a
+    record that surfaces stale and drops one whose node has gained a
+    child since.  An eviction therefore costs O(log n) amortised and
+    visits no trie node it does not evict or re-key.
     """
 
     def __init__(self, pool):
         self.pool = pool
         self._root = _Node()
         self._clock = 0
+        self._heap = []
+        self._serial = 0       # breaks ties: entries do not compare
+        self._banked = 0
         self.lookups = 0
         self.hits = 0
         self.tokens_reused = 0
+        self.evictions = 0     # index references dropped, ever
+        self.examined = 0      # heap records :meth:`evict` has popped
 
     # -- stats ---------------------------------------------------------
     def hit_rate(self):
         return self.hits / self.lookups if self.lookups else 0.0
 
     def banked_pages(self):
-        n = 0
-        stack = [self._root]
-        while stack:
-            node = stack.pop()
-            n += len(node.tails) + sum(
-                1 for _ in node.children)
-            stack.extend(node.children.values())
-        return n
+        return self._banked
 
     # -- queries -------------------------------------------------------
     def lookup(self, prompt):
@@ -181,42 +210,55 @@ class RadixPrefixIndex:
         -- the caller retains exactly the pages it keeps.
         """
         ps = self.pool.page_size
-        toks = tuple(int(t) for t in np.asarray(prompt).reshape(-1))
+        step = 4 * ps
+        toks = _token_bytes(prompt)
         self.lookups += 1
         self._clock += 1
         node, pages = self._root, []
         i = 0
-        while i + ps <= len(toks):
-            child = node.children.get(toks[i:i + ps])
+        while i + step <= len(toks):
+            child = node.children.get(toks[i:i + step])
             if child is None:
                 break
             child.touch = self._clock
             pages.append(child.page)
             node = child
-            i += ps
-        tail_page, tail_len = None, 0
+            i += step
         # longest banked partial page continuing the match
-        rest = toks[i:]
-        for tail, entry in node.tails.items():
-            n = len(tail)
-            if n > tail_len and rest[:n] == tail:
-                tail_page, tail_len = entry[0], n
-        if tail_page is not None:
-            node.tails[self._tail_key(node, tail_page)][1] = self._clock
+        best = None
+        if node.tails:
+            rest = toks[i:i + step]
+            for key, tail in node.tails.items():
+                if rest.startswith(key) and (
+                        best is None or len(key) > len(best.key)):
+                    best = tail
+        tail_page, tail_len = None, 0
+        if best is not None:
+            best.touch = self._clock
+            tail_page, tail_len = best.page, len(best.key) // 4
         matched = len(pages) * ps + tail_len
         if matched:
             self.hits += 1
             self.tokens_reused += matched
         return pages, tail_page, tail_len
 
-    @staticmethod
-    def _tail_key(node, page):
-        for key, entry in node.tails.items():
-            if entry[0] == page:
-                return key
-        raise KeyError(page)
-
     # -- updates -------------------------------------------------------
+    def _bank(self, parent, home, key, page):
+        self.pool.retain(page)
+        node = home[key] = _Node(page, parent, home, key)
+        self._banked += 1
+        return node
+
+    def _queue(self, entry):
+        """``entry`` is evictable: one record of it in the heap, at its
+        present touch -- unless an older record is still there, which
+        :meth:`evict` re-keys when it surfaces."""
+        if not entry.queued:
+            entry.queued = True
+            self._serial += 1
+            heapq.heappush(self._heap,
+                           (entry.touch, self._serial, entry))
+
     def insert(self, prompt, pages):
         """Bank a finished prompt's pages: ``pages`` cover
         ``ceil(len(prompt) / page_size)`` pages in position order.
@@ -224,70 +266,65 @@ class RadixPrefixIndex:
         wins -- later duplicates are simply not indexed); each NEWLY
         indexed page gains one index-owned reference.
         """
-        ps = self.pool.page_size
-        toks = tuple(int(t) for t in np.asarray(prompt).reshape(-1))
+        step = 4 * self.pool.page_size
+        toks = _token_bytes(prompt)
         self._clock += 1
         node = self._root
         i = 0
-        while i + ps <= len(toks):
-            chunk = toks[i:i + ps]
+        while i + step <= len(toks):
+            chunk = toks[i:i + step]
             child = node.children.get(chunk)
             if child is None:
-                page = pages[i // ps]
-                child = _Node(page)
-                self.pool.retain(page)
-                node.children[chunk] = child
+                child = self._bank(node, node.children, chunk,
+                                   pages[i // step])
             child.touch = self._clock
             node = child
-            i += ps
+            i += step
         rest = toks[i:]
-        if rest and rest not in node.tails:
-            page = pages[i // ps]
-            self.pool.retain(page)
-            node.tails[rest] = [page, self._clock]
-        elif rest:
-            node.tails[rest][1] = self._clock
+        if rest:
+            tail = node.tails.get(rest)
+            if tail is None:
+                tail = self._bank(node, node.tails, rest,
+                                  pages[i // step])
+            tail.touch = self._clock
+            node = tail
+        # of the path only its end can be evictable (the root, an
+        # empty prompt's end, banks nothing)
+        if node is not self._root and node.evictable():
+            self._queue(node)
 
     def evict(self, n_needed=1):
         """LRU-drop banked leaves until ``n_needed`` pages could be
-        freed or nothing evictable remains.  Only drops the INDEX's
-        reference -- a page still used by live sequences stays
-        allocated (and stays counted in ``in_use``) until they finish.
-        Returns the number of references dropped."""
+        freed or nothing evictable remains: each victim is an
+        evictable entry of the least ``touch`` among all of them.
+        Only drops the INDEX's reference -- a page still used by live
+        sequences stays allocated (and stays counted in ``in_use``)
+        until they finish.  Returns the number of references
+        dropped."""
         dropped = 0
-        while dropped < n_needed:
-            victim = self._lru_leaf()
-            if victim is None:
-                break
-            parent, kind, key, page = victim
-            if kind == 'tail':
-                del parent.tails[key]
-            else:
-                del parent.children[key]
-            self.pool.release(page)
+        heap = self._heap
+        while dropped < n_needed and heap:
+            touch, _, entry = heapq.heappop(heap)
+            self.examined += 1
+            entry.queued = False
+            if not entry.evictable():
+                continue       # queued again when its last child goes
+            if entry.touch != touch:
+                self._queue(entry)
+                continue
+            del entry.home[entry.key]
+            self.pool.release(entry.page)
+            self._banked -= 1
             dropped += 1
+            parent = entry.parent
+            if parent is not self._root and parent.evictable():
+                # a leaf from now on, at ITS OWN touch (a touch stamps
+                # the whole path: never older than the victim's)
+                self._queue(parent)
+        self.evictions += dropped
         return dropped
-
-    def _lru_leaf(self):
-        best = None
-        stack = [(self._root, None, None)]
-        while stack:
-            node, parent, key = stack.pop()
-            for tkey, (page, touch) in node.tails.items():
-                if best is None or touch < best[0]:
-                    best = (touch, node, 'tail', tkey, page)
-            for ckey, child in node.children.items():
-                if not child.children and not child.tails:
-                    if best is None or child.touch < best[0]:
-                        best = (child.touch, node, 'child', ckey,
-                                child.page)
-                stack.append((child, node, ckey))
-        if best is None:
-            return None
-        return best[1], best[2], best[3], best[4]
 
     def flush(self):
         """Drop every banked reference (used by tests and by engines
         tearing down)."""
-        while self.evict(1):
-            pass
+        self.evict(self._banked)

@@ -1322,6 +1322,38 @@ class TestPagedGeneration:
         for key, value in base.items():
             assert st[key] == value, key
 
+    def test_dry_pool_evicts_banked_pages_and_serves_the_same_tokens(
+            self):
+        """ISSUE 40: a pool dry of free pages (every finished prompt
+        is banked, every new page is an eviction) serves token for
+        token what the engine without an index serves."""
+        model, params = _tiny_lm()
+        rng = np.random.RandomState(7)
+        prompts = [rng.randint(1, 32, size=n).tolist()
+                   for n in (9, 16, 3, 12, 8, 15, 10, 5, 16, 11)]
+        prompts.append(prompts[1])      # a hit, while the pool is dry
+        outs, stats = {}, {}
+        for sharing in (False, True):
+            # 8 pages and two slots of up to 4: nothing to spare
+            eng = self._engine(model, params, True,
+                               prefix_sharing=sharing)
+            eng.warmup()
+            q = self._queue(eng, max_queue=16)
+            reqs = [q.submit(p, 12) for p in prompts]
+            outs[sharing] = self._drain(eng, q, reqs)
+            stats[sharing] = eng.stats()
+            idx = eng._prefix_index
+            if sharing:
+                assert idx.evictions == stats[True]['prefix_evictions']
+                assert eng.pool.in_use() == idx.banked_pages() > 0
+                idx.flush()
+            assert eng.pool.in_use() == 0
+        for plain, shared in zip(outs[False], outs[True]):
+            assert len(plain) == 12 and np.array_equal(plain, shared)
+        assert 'prefix_evictions' not in stats[False]
+        assert stats[True]['prefix_evictions'] >= 10
+        assert stats[True]['prefix_lookups'] == len(prompts)
+
     def test_prefix_key_invariant_under_arrival_order(self):
         """The admission satellite pin: a request's ``prefix_key`` is
         a pure function of its token ids -- submission order across
@@ -1875,6 +1907,81 @@ class TestTickAccounting:
             assert kids[r['id']] == ['serve_prefill_dispatch',
                                      'serve_prefill_wait']
 
+    def test_prep_spans_say_what_they_allocated_and_evicted(self):
+        """ISSUE 40: ``serve_prefill_prep`` / ``serve_decode_prep``
+        carry ``pages`` and ``evicted`` exactly when pages were
+        allocated / index references dropped under them, and the
+        ``prefix_evictions`` gauge is their sum."""
+        from chainermn_tpu import telemetry
+        eng = serving.GenerationEngine(
+            *_tiny_lm(n_layers=2), n_slots=2, max_prompt_len=16,
+            max_len=32, paged=True, page_size=self.PS)
+        eng.warmup()
+        q = serving.GenerationQueue(max_prompt_len=16,
+                                    page_size=self.PS)
+        rec = telemetry.enable()
+        # the engine's own calls, stamped on the recorder's clock
+        allocated, evicted = [], []
+        alloc, evict = eng._alloc_page, eng._prefix_index.evict
+
+        def stamped_alloc():
+            page = alloc()
+            assert page is not None
+            allocated.append(rec.now())
+            return page
+
+        def stamped_evict(n_needed=1):
+            dropped = evict(n_needed)
+            evicted.extend([rec.now()] * dropped)
+            return dropped
+
+        eng._alloc_page = stamped_alloc
+        eng._prefix_index.evict = stamped_evict
+        rng = np.random.RandomState(3)
+        work = [(rng.randint(1, 32, size=n).tolist(), out)
+                for n, out in ((9, 10), (16, 12), (3, 4), (12, 14),
+                               (8, 9), (15, 3), (10, 12), (16, 16))]
+        self._serve(eng, q, work=work)
+        eng.step(q)     # one idle tick more: the gauges' last word
+        spans = [r for r in rec.events if r.get('type') == 'span']
+        preps = (self._named(spans, 'serve_prefill_prep')
+                 + self._named(spans, 'serve_decode_prep'))
+        for r in preps:
+            under = [sum(r['t0'] <= t <= r['t1'] for t in stamps)
+                     for stamps in (allocated, evicted)]
+            assert [r.get('pages', 0), r.get('evicted', 0)] == under
+            assert r.get('pages') != 0 and r.get('evicted') != 0
+        # nothing allocates or evicts outside the two spans here (no
+        # shared prefix: no copy-on-write page at admission)
+        assert sum(r.get('pages', 0) for r in preps) \
+            == len(allocated) == eng.pages_allocated
+        assert sum(r.get('evicted', 0) for r in preps) \
+            == len(evicted) == eng.stats()['prefix_evictions'] \
+            == rec.registry.gauge('prefix_evictions').value
+        first = self._named(spans, 'serve_prefill_prep')
+        assert [r['pages'] for r in first] \
+            == [-(-len(prompt) // self.PS) for prompt, _ in work]
+        for name in ('serve_prefill_prep', 'serve_decode_prep'):
+            mine = self._named(spans, name)
+            assert any('evicted' in r for r in mine), name
+            assert any('evicted' not in r for r in mine), name
+        assert any('pages' not in r
+                   for r in self._named(spans, 'serve_decode_prep'))
+        # an engine without an index: pages, and never ``evicted``
+        telemetry.disable()
+        eng = serving.GenerationEngine(
+            *_tiny_lm(n_layers=2), n_slots=2, max_prompt_len=16,
+            max_len=32, paged=True, page_size=self.PS,
+            prefix_sharing=False)
+        eng.warmup()
+        rec = telemetry.enable()
+        self._serve(eng, q, work=work)
+        spans = [r for r in rec.events if r.get('type') == 'span']
+        assert sum(r.get('pages', 0) for r in spans) \
+            == eng.pages_allocated > 0
+        assert not any('evicted' in r for r in spans)
+        assert 'prefix_evictions' not in rec.registry.snapshot()
+
     @pytest.mark.parametrize('mode', ['paged', 'slots', 'spec'])
     def test_a_call_that_did_not_go_out_ahead_says_why(self, mode):
         from chainermn_tpu.serving.generate import SETTLE_REASONS
@@ -2073,9 +2180,9 @@ class TestTickAccounting:
         rec = telemetry.enable()
         self._serve(eng, q)
         assert sorted(looked_up) == [
-            'active_slots', 'serve_decode_backlog', 'serve_kv_pages_free',
-            'serve_kv_pages_in_use', 'serve_prefill_backlog',
-            'serve_queue_depth']
+            'active_slots', 'prefix_evictions', 'serve_decode_backlog',
+            'serve_kv_pages_free', 'serve_kv_pages_in_use',
+            'serve_prefill_backlog', 'serve_queue_depth']
         snap = rec.registry.snapshot()
         assert snap['serve_queue_depth']['value'] == 0.0
         assert snap['active_slots']['value'] == 1.0
