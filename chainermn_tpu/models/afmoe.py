@@ -205,11 +205,12 @@ class AfmoeLM:
 
     def _experts(self, m, lp):
         """The sparse feed-forward on rows ``m`` (T, d): returns it and
-        the layer's two counters (``models/_experts.py``, the body this
-        family shares with ``xing4``)."""
-        return _experts.sigmoid_routed_experts(
+        the layer's two serve counters (``models/_experts.py``, the
+        body this family shares with ``xing4`` and ``deepseek_v3``)."""
+        out, counters = _experts.sigmoid_routed_experts(
             m, lp, self.num_experts_per_tok, self.route_norm,
             self.route_scale, self.dtype)
+        return out, counters[:2]
 
     def _layer(self, layer, x, lp, positions, cache, attend):
         """One layer on ``x`` (..., d) at ``positions`` (...).

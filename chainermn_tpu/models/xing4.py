@@ -53,7 +53,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from chainermn_tpu.models import _experts
+from chainermn_tpu.models import _experts, _mla
 
 _LANES = 128
 
@@ -235,35 +235,13 @@ class Xing4LM:
         """YaRN's blend of the trained and the interpolated
         frequencies (DeepSeek-V3's form), ``qk_rope_head_dim / 2`` of
         them."""
-        dim, base = self.qk_rope_head_dim, float(self.rope_theta)
-        exponent = jnp.arange(0, dim, 2, dtype=jnp.float32) / dim
-        extra = base ** -exponent
-        scaling = dict(self.rope_scaling or ())
-        if not scaling:
-            return extra
-        orig = scaling['original_max_position_embeddings']
-
-        def correction(rotations):
-            return (dim * math.log(orig / (rotations * 2 * math.pi))
-                    / (2 * math.log(base)))
-
-        low = max(math.floor(correction(scaling['beta_fast'])), 0)
-        high = min(math.ceil(correction(scaling['beta_slow'])), dim - 1)
-        ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
-                        / max(high - low, 0.001), 0.0, 1.0)
-        return extra / scaling['factor'] * ramp + extra * (1.0 - ramp)
+        return _mla.inv_freq(self.qk_rope_head_dim, self.rope_theta,
+                             self.rope_scaling)
 
     def _rope(self, x, positions):
-        """Rotary positions over ``x``'s last dim, rotate-half pairing;
-        ``x`` (..., D) with ``positions`` broadcasting against its
-        leading dims."""
-        half = x.shape[-1] // 2
-        angle = positions.astype(jnp.float32)[..., None] * self._inv_freq()
-        cos, sin = jnp.cos(angle), jnp.sin(angle)
-        xf = x.astype(jnp.float32)
-        x1, x2 = xf[..., :half], xf[..., half:]
-        return jnp.concatenate([x1 * cos - x2 * sin,
-                                x2 * cos + x1 * sin], -1).astype(x.dtype)
+        """Rotary positions over ``x``'s last dim, rotate-half
+        pairing."""
+        return _mla.rope(x, positions, self._inv_freq())
 
     def _coefficients(self, x, hp):
         """``(H_pre, H_post, H_res)`` of streams ``x`` (T, n, d)."""
@@ -301,26 +279,15 @@ class Xing4LM:
 
     def _kvb(self, lp):
         """``W_kvb`` as ``(W_k (C, H, 128), W_v (C, H, 128))``."""
-        w = lp['wkv_b'].astype(self.dtype).reshape(
-            self.kv_lora_rank, self.num_attention_heads, -1)
-        return (w[..., :self.qk_nope_head_dim],
-                w[..., self.qk_nope_head_dim:])
+        return _mla.split_kvb(
+            lp['wkv_b'].astype(self.dtype), self.kv_lora_rank,
+            self.num_attention_heads, self.qk_nope_head_dim)
 
     def _expanded(self, lp, q_nope, q_rope, c, k_r):
         """Causal attention over the rows themselves, every head's keys
         and values made from the latent: (T, H * v_head_dim)."""
-        from chainermn_tpu import ops
-        w_k, w_v = self._kvb(lp)
-        h = self.num_attention_heads
-        k = jnp.concatenate([
-            jnp.einsum('tc,chn->thn', c, w_k),
-            jnp.broadcast_to(k_r[:, None, :], (k_r.shape[0], h,
-                                               k_r.shape[1]))], -1)
-        v = jnp.einsum('tc,chv->thv', c, w_v)
-        q = jnp.concatenate([q_nope, q_rope], -1)
-        out = ops.flash_attention(q[None], k[None], v[None], causal=True,
-                                  scale=self.softmax_scale)[0]
-        return out.reshape(out.shape[0], -1)
+        return _mla.expanded_attention(q_nope, q_rope, c, k_r,
+                                       *self._kvb(lp), self.softmax_scale)
 
     def _latent_rows(self, c, k_r):
         """``[c | k_r | 0]``: what a cached position holds, lane-wide."""
@@ -346,9 +313,10 @@ class Xing4LM:
             m = self._rms(u, lp['mlp_norm'])
             if 'mlp' in lp:
                 return _experts.swiglu(m, lp['mlp'], dtype), None
-            return _experts.sigmoid_routed_experts(
+            out, counters = _experts.sigmoid_routed_experts(
                 m, lp, self.num_experts_per_tok, self.norm_topk_prob,
                 self.routed_scaling_factor, dtype)
+            return out, counters[:2]
 
         x, cache = self._hyper(x, lp['hc_attn'], attention)
         x, counters = self._hyper(x, lp['hc_mlp'], feed_forward)

@@ -493,16 +493,19 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _bwd_specs(d, block_q, block_k):
+def _bwd_specs(block_q, block_k):
+    """Block specs of the backward kernels; ``width`` is the block's
+    last dim: the key width for q / k / dq / dk, the value width for
+    v / g / dv."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    def q_blk(ix):
-        return pl.BlockSpec((1, block_q, d), ix,
+    def q_blk(ix, width):
+        return pl.BlockSpec((1, block_q, width), ix,
                             memory_space=pltpu.VMEM)
 
-    def kv_blk(ix):
-        return pl.BlockSpec((1, block_k, d), ix,
+    def kv_blk(ix, width):
+        return pl.BlockSpec((1, block_k, width), ix,
                             memory_space=pltpu.VMEM)
 
     def row_blk(ix):
@@ -522,8 +525,8 @@ def _bwd_dq_pallas(q, k, v, g, lse, delta, causal, scale, kv_len,
     from jax.experimental.pallas import tpu as pltpu
 
     bh, t_q, d = q.shape
-    t_kv = k.shape[1]
-    q_blk, kv_blk, row_blk = _bwd_specs(d, block_q, block_k)
+    t_kv, dv = k.shape[1], v.shape[2]
+    q_blk, kv_blk, row_blk = _bwd_specs(block_q, block_k)
     # (b, i=query block, j=key block)
     by_i = lambda b, i, j: (b, i, 0)   # noqa: E731
     if causal:
@@ -538,9 +541,9 @@ def _bwd_dq_pallas(q, k, v, g, lse, delta, causal, scale, kv_len,
                           kv_len=kv_len if kv_len < t_kv else None,
                           block_q=block_q, block_k=block_k),
         grid=(bh, t_q // block_q, t_kv // block_k),
-        in_specs=[q_blk(by_i), kv_blk(by_j), kv_blk(by_j), q_blk(by_i),
-                  row_blk(by_i), row_blk(by_i)],
-        out_specs=q_blk(by_i),
+        in_specs=[q_blk(by_i, d), kv_blk(by_j, d), kv_blk(by_j, dv),
+                  q_blk(by_i, dv), row_blk(by_i), row_blk(by_i)],
+        out_specs=q_blk(by_i, d),
         out_shape=jax.ShapeDtypeStruct((bh, t_q, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
                         pltpu.VMEM((block_q, _LANES), jnp.float32),
@@ -557,8 +560,8 @@ def _bwd_dkv_pallas(q, k, v, g, lse, delta, causal, scale, kv_len,
     from jax.experimental.pallas import tpu as pltpu
 
     bh, t_q, d = q.shape
-    t_kv = k.shape[1]
-    q_blk, kv_blk, row_blk = _bwd_specs(d, block_q, block_k)
+    t_kv, dv = k.shape[1], v.shape[2]
+    q_blk, kv_blk, row_blk = _bwd_specs(block_q, block_k)
     # (b, i=key block, j=query block); for causal, query blocks before
     # the key block are skipped -- clamp the fetch from below so the
     # leading dead steps re-fetch (elide) the first contributing block
@@ -573,13 +576,13 @@ def _bwd_dkv_pallas(q, k, v, g, lse, delta, causal, scale, kv_len,
                           kv_len=kv_len if kv_len < t_kv else None,
                           block_q=block_q, block_k=block_k),
         grid=(bh, t_kv // block_k, t_q // block_q),
-        in_specs=[q_blk(by_jq), kv_blk(by_i), kv_blk(by_i),
-                  q_blk(by_jq), row_blk(by_jq), row_blk(by_jq)],
-        out_specs=[kv_blk(by_i), kv_blk(by_i)],
+        in_specs=[q_blk(by_jq, d), kv_blk(by_i, d), kv_blk(by_i, dv),
+                  q_blk(by_jq, dv), row_blk(by_jq), row_blk(by_jq)],
+        out_specs=[kv_blk(by_i, d), kv_blk(by_i, dv)],
         out_shape=[jax.ShapeDtypeStruct((bh, t_kv, d), k.dtype),
-                   jax.ShapeDtypeStruct((bh, t_kv, d), v.dtype)],
+                   jax.ShapeDtypeStruct((bh, t_kv, dv), v.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
+                        pltpu.VMEM((block_k, dv), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=interpret_flag(),
         name='flash_attention_bwd_dkv',
@@ -589,9 +592,11 @@ def _bwd_dkv_pallas(q, k, v, g, lse, delta, causal, scale, kv_len,
 def _bwd_pallas(q, k, v, out, lse, g, causal, scale, kv_len, blocks):
     """``lse`` as the forward kernel left it, ``(B*H, 1, T)``.
     ``blocks``: the caller's explicit ``(block_q, block_k)`` for both
-    kernels, or None for each kernel's own from the shapes."""
-    t_q, d = q.shape[1:]
-    t_kv = k.shape[1]
+    kernels, or None for each kernel's own from the shapes.  ``v``,
+    ``out`` and ``g`` may have a width of their own: dQ / dK come at
+    the key width, dV and ``delta`` at the value width."""
+    t_q = q.shape[1]
+    t_kv, d = k.shape[1], max(q.shape[2], v.shape[2])
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)[:, None, :]               # (bh, 1, t_q)
 
@@ -612,13 +617,13 @@ def _bwd_pallas(q, k, v, out, lse, g, causal, scale, kv_len, blocks):
 
 def _bwd_blockwise(q, k, v, out, lse, g, causal, scale, kv_len, block_k):
     bh, t_q, d = q.shape
-    t_kv = k.shape[1]
+    t_kv, d_v = k.shape[1], v.shape[2]
     n_blocks = t_kv // block_k
     qf = q.astype(jnp.float32)
     gf = g.astype(jnp.float32)
     delta = jnp.sum(gf * out.astype(jnp.float32), axis=-1)   # (bh, t_q)
     kb = jnp.swapaxes(k.reshape(bh, n_blocks, block_k, d), 0, 1)
-    vb = jnp.swapaxes(v.reshape(bh, n_blocks, block_k, d), 0, 1)
+    vb = jnp.swapaxes(v.reshape(bh, n_blocks, block_k, d_v), 0, 1)
 
     def body(dq, inp):
         j, kj, vj = inp
@@ -642,7 +647,7 @@ def _bwd_blockwise(q, k, v, out, lse, g, causal, scale, kv_len, block_k):
     dq, (dk, dv) = lax.scan(
         body, dq0, (jnp.arange(n_blocks), kb, vb))
     dk = jnp.swapaxes(dk, 0, 1).reshape(bh, t_kv, d)
-    dv = jnp.swapaxes(dv, 0, 1).reshape(bh, t_kv, d)
+    dv = jnp.swapaxes(dv, 0, 1).reshape(bh, t_kv, d_v)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
@@ -680,11 +685,6 @@ def _flash_fwd(q, k, v, causal, scale, kv_len, blocks):
 
 def _flash_bwd(causal, scale, kv_len, blocks, res, g):
     q, k, v, out, lse = res
-    if v.shape[2] != q.shape[2]:
-        raise NotImplementedError(
-            'flash_attention: no backward for a value width of its own '
-            '(q / k %d wide, v %d): the dQ and dK/dV kernels carry one '
-            'width' % (q.shape[2], v.shape[2]))
     if pallas_mode() == 'fallback':
         return _bwd_blockwise(q, k, v, out, lse, g, causal, scale,
                               kv_len, _scan_block(blocks, k.shape[1]))
@@ -1732,8 +1732,8 @@ def flash_attention(q, k, v, causal=False, scale=None,
                     block_q=None, block_k=None, window=None):
     """Fused attention. q: (B, Tq, H, D), k/v: (B, Tkv, Hkv, D);
     ``v`` may be (B, Tkv, Hkv, Dv) with a width of its own (latent
-    attention's 192 / 128), the output is then (B, Tq, H, Dv):
-    forward-only, the backward refuses it by name.
+    attention's 192 / 128), the output is then (B, Tq, H, Dv); the
+    backward gives dQ / dK at the key width and dV at the value width.
 
     Sequence lengths are padded to kernel block multiples internally
     (padded keys are masked out; padded query rows are dropped); with

@@ -178,6 +178,11 @@ class StandardUpdater:
         self.comm = comm
         self.loss_fn = loss_fn
         self._has_aux = has_aux
+        #: aux keys the loss marks as the step's counters
+        #: (``loss_fn.span_counters``): ``update()`` hangs their
+        #: synchronised values on its ``train_update`` span
+        self._span_counters = tuple(getattr(loss_fn, 'span_counters',
+                                            ()))
         self._has_state = model_state is not None
         self._zero = zero
         self._zero_reduce_dtype = (jnp.dtype(zero_reduce_dtype)
@@ -681,7 +686,7 @@ class StandardUpdater:
         ``Trainer(async_metrics=True)``)."""
         iteration = self.iteration
         with _telemetry.span('train_update', kind='step',
-                             iteration=iteration):
+                             iteration=iteration) as step_span:
             # the consumer's wait for a batch: what the producer
             # threads (``batch_fetch``; ``host_batch_prep`` and ``h2d``
             # under ``device_prefetch``) did not hide
@@ -696,7 +701,13 @@ class StandardUpdater:
             # the host-device round trip the sync=True contract pays
             with _telemetry.span('metrics_sync', kind='host',
                                  iteration=iteration):
-                return {k: float(v) for k, v in metrics.items()}
+                out = {k: float(v) for k, v in metrics.items()}
+            if self._span_counters and step_span is not _telemetry.NULL_SPAN:
+                # what the loss names as the step's counters, already
+                # on the host: attributes of this step's span
+                step_span.set(**{k: out[k] for k in self._span_counters
+                                 if k in out})
+            return out
 
     def compiled_cost_analysis(self, arrays):
         """XLA cost analysis (flops etc.) of the compiled train step

@@ -20,7 +20,10 @@ import sys
 
 MAX_LEN = 79
 EXCLUDE = {'.git', '__pycache__', 'build', 'docs', '.jax_compile_cache',
-           'result', '.pytest_cache'}
+           'result', '.pytest_cache',
+           # the chip benchmark's git-ignored scratch: a builder's copies
+           # of other commits and its chip scripts are not the repo's code
+           '.chipbench_copy', '.chipbench_cache', '.chipbench_trace'}
 #: directories whose code runs per-iteration (traced or driving the
 #: device loop) -- the SHL01 host-sync rule applies only here
 HOT_PATHS = ('chainermn_tpu/communicators/', 'chainermn_tpu/training/',

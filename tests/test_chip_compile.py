@@ -241,7 +241,20 @@ def _conv_step_bias(tail, rows, x, w, bias):
     return ops.causal_conv_step(tail, rows, x, w, bias)
 
 
+# the kanana-train-8k-ep8share cell's widths: one 8,192-token sequence,
+# 32 heads of 192 (keys) / 128 (values) with a backward; 16 held
+# experts of 2048 x 768 under the rows of ALL 8,192 x 6 assignments
+# (the held ones sorted first, about an eighth of them)
+_EXPERTS_768 = [((16, 2048, 768), BF16)] * 2 + [((16, 768, 2048), BF16),
+                                                ((16,), I32)]
+
 CASES = {
+    'flash_fwd_bwd_causal_192_128_t8192_train_cell': (
+        jax.grad(_sum_sq(_flash), argnums=(0, 1, 2)),
+        [((1, 8192, 32, 192), BF16)] * 2 + [((1, 8192, 32, 128), BF16)]),
+    'grouped_swiglu_fwd_bwd_49152rows_2048x768_train_cell': (
+        jax.grad(_sum_sq(ops.grouped_swiglu), argnums=(0, 1, 2, 3)),
+        [((49152, 2048), BF16)] + _EXPERTS_768),
     'selective_scan_prompt_1024': (_scan_prompt, _SCAN(1024)),
     'selective_scan_prompt_64': (_scan_prompt, _SCAN(64)),
     'selective_scan_step_96rows': (

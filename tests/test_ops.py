@@ -963,3 +963,107 @@ def test_convolution_step_adds_its_bias(mode):
     plain, _ = ops.causal_conv_step(tail, rows, x, w)
     biased, _ = ops.causal_conv_step(tail, rows, x, w, bias)
     np.testing.assert_allclose(biased, plain + bias, atol=1e-6)
+
+
+# -- training: the two-width flash backward, the grouped SwiGLU's VJP ---
+
+def _causal_attention(q, k, v):
+    """Plain causal softmax attention, ``v`` of its own width."""
+    t = q.shape[1]
+    s = jnp.einsum('bqhd,bkhd->bhqk', q, k) * q.shape[-1] ** -0.5
+    s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+    return jnp.einsum('bhqk,bkhd->bqhd', jax.nn.softmax(s, -1), v)
+
+
+@pytest.mark.parametrize('d,dv,t', [(24, 16, 200), (192, 128, 256),
+                                    (16, 24, 72)])
+def test_flash_backward_with_a_value_width_of_its_own(mode, d, dv, t):
+    """dQ / dK at the key width, dV at the value width, against
+    autodiff of a jnp attention: the Mosaic kernels in the interpreter
+    and the blockwise fallback."""
+    q, k = _rand((2, t, 3, d), 0), _rand((2, t, 3, d), 1)
+    v, w = _rand((2, t, 3, dv), 2), _rand((2, t, 3, dv), 3)
+
+    def grads(fn):
+        return jax.grad(lambda *a: jnp.sum(fn(*a) * w), (0, 1, 2))(q, k, v)
+
+    got = grads(lambda q, k, v: ops.flash_attention(q, k, v, causal=True))
+    for a, b, width in zip(got, grads(_causal_attention), (d, d, dv)):
+        assert a.shape[-1] == width
+        np.testing.assert_allclose(a, b, atol=3e-5, rtol=3e-5)
+
+
+def _grouped_case(sizes, extra, d=32, f=48):
+    sizes = jnp.asarray(sizes, jnp.int32)
+    n, e = int(sizes.sum()) + extra, sizes.shape[0]
+    x, c = _rand((n, d), 0), _rand((n, d), 4)
+    weights = [0.2 * _rand(shape, key) for key, shape in
+               ((1, (e, d, f)), (2, (e, d, f)), (3, (e, f, d)))]
+    # rows past the groups' total take no part: nothing flows into them
+    return sizes, x, weights, c.at[n - extra:].set(0.0)
+
+
+@pytest.mark.parametrize('sizes,extra', [
+    ([5, 0, 37, 16, 1, 0, 70, 3], 0),     # uneven, two empty groups
+    ([5, 0, 37, 16, 1, 0, 70, 3], 200),   # a share: rows held by nobody
+    ([0, 0, 0, 0], 77),                   # no assignment is held
+    ([0, 130, 0], 0),                     # one group over several tiles
+    ([16, 16, 16, 16], 0)])               # every boundary on a tile's
+def test_grouped_swiglu_vjp_against_ragged_dots(mode, sizes, extra):
+    sizes, x, weights, c = _grouped_case(sizes, extra)
+
+    def grads(fn):
+        return jax.grad(lambda x, *w: jnp.sum(fn(x, *w, sizes) * c),
+                        (0, 1, 2, 3))(x, *weights)
+
+    got = grads(lambda *a: ops.grouped_swiglu(*a, tile_m=16))
+    for a, b in zip(got, grads(ops.grouped_swiglu_reference)):
+        np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-5)
+    # an expert no row chose: exact zeros, and the rows past the total
+    empty = np.asarray(sizes) == 0
+    for g in got[1:]:
+        assert not np.asarray(g)[empty].any()
+    if extra:
+        assert not np.asarray(got[0])[-extra:].any()
+
+
+@pytest.mark.parametrize('first', [0, 4, 12])
+def test_dropless_experts_over_a_share(mode, first):
+    """A layer told which experts it holds: the held experts' part of
+    the sum over a token's chosen experts, absent ones adding nothing,
+    and its gradient by the rows and the weights."""
+    tokens, k, router, held, d, f = 40, 3, 16, 4, 16, 24
+    x = _rand((tokens, d), 0)
+    experts = {name: 0.3 * _rand(shape, key) for key, (name, shape) in
+               enumerate((('w1', (held, d, f)), ('w3', (held, d, f)),
+                          ('w2', (held, f, d))), 1)}
+    selected = jnp.argsort(_rand((tokens, router), 5), -1)[:, :k]
+    gates = jax.nn.softmax(_rand((tokens, k), 6), -1)
+
+    def dense(x, experts):
+        out = jnp.zeros_like(x)
+        for e in range(held):
+            y = (jax.nn.silu(x @ experts['w1'][e]) * (x @ experts['w3'][e])
+                 ) @ experts['w2'][e]
+            g = jnp.sum(jnp.where(selected == first + e, gates, 0.0), -1)
+            out = out + g[:, None] * y
+        return out
+
+    def sparse(x, experts):
+        return ops.dropless_experts(x, experts, selected, gates,
+                                    tile_m=8, first=first)[0]
+
+    np.testing.assert_allclose(sparse(x, experts), dense(x, experts),
+                               atol=3e-5, rtol=3e-5)
+    _, sizes = ops.dropless_experts(x, experts, selected, gates, tile_m=8,
+                                    first=first)
+    want = [(np.asarray(selected) == first + e).sum() for e in range(held)]
+    assert np.asarray(sizes).tolist() == want
+
+    def loss(fn):
+        return jax.grad(lambda x, w: jnp.sum(fn(x, w) ** 2), (0, 1))(
+            x, experts)
+
+    for a, b in zip(jax.tree_util.tree_leaves(loss(sparse)),
+                    jax.tree_util.tree_leaves(loss(dense))):
+        np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
