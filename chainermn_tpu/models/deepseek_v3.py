@@ -28,8 +28,16 @@ has: expert parallelism's share of a layer, without the exchange
 (``docs/mesh_parallelism.md``).
 
 ``train_recompute='layer'`` puts each layer's body under
-``jax.checkpoint``: the backward holds one layer's internals at a
-time and keeps a layer's input (T x d) a layer.
+``jax.checkpoint`` with a policy that keeps what
+``ops/flash_attention.py`` names (``RESIDUAL_NAMES``): the attention
+kernel's output (T x H x v) and its rows' statistics (T x H float32),
+which only the kernel can make, and its merged ``q`` / ``k`` / ``v``
+(T x H x 192, 192 and 128).  The backward holds one layer's internals
+at a time and recomputes a layer's forward from its input (T x d, kept
+a layer) BUT FOR the attention kernel, which runs once, and the query
+projection, rotary and latent expansion that feed it.  The step's
+counters say what that costs: ``checkpoint_kept_bytes``, the named
+values' bytes summed over the layers (0 without the rule).
 
 The layer is written once (:meth:`DeepseekV3LM._layer`).  Serving entry
 points raise by name: served, this family is ``xing4``'s path less the
@@ -44,6 +52,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from chainermn_tpu.models import _experts, _mla
+from chainermn_tpu.ops.flash_attention import (RESIDUAL_NAMES,
+                                               residual_bytes)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -80,13 +90,17 @@ class DeepseekV3LM:
     max_position_embeddings: int = 32768
     router_experts: Optional[int] = None
     first_expert: int = 0
+    #: None, or 'layer': the backward recomputes a layer's forward but
+    #: for the attention kernel: its inputs, output and statistics are
+    #: kept
     train_recompute: Optional[str] = None
     dtype: Any = jnp.bfloat16
 
     #: keys of the loss's aux that ``StandardUpdater.update`` hangs on
     #: its ``train_update`` span
     span_counters = ('held_assignments', 'assignments',
-                     'experts_with_row', 'expert_load_max_over_mean')
+                     'experts_with_row', 'expert_load_max_over_mean',
+                     'checkpoint_kept_bytes')
 
     def __post_init__(self):
         if self.scoring_func != 'sigmoid' or self.topk_method != 'noaux_tc':
@@ -235,13 +249,19 @@ class DeepseekV3LM:
         step's counters)``.  The counters: assignments on held experts
         and all assignments, summed over the expert layers; held
         experts with a row and the fullest held expert's rows over the
-        held mean, the mean over them."""
+        held mean, the mean over them; the bytes the layers'
+        checkpoints keep beside their inputs."""
         x = jnp.take(params['embed']['embedding'], tokens,
                      axis=0).astype(self.dtype)
         positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)
-        layer = self._layer
+        layer, kept = self._layer, 0
         if self.train_recompute == 'layer':
-            layer = jax.checkpoint(layer)
+            layer = jax.checkpoint(
+                layer, policy=jax.checkpoint_policies
+                .save_only_these_names(*RESIDUAL_NAMES))
+            kept = self.num_hidden_layers * sum(residual_bytes(
+                *tokens.shape, self.num_attention_heads,
+                self.qk_head_dim, self.v_head_dim, self.dtype).values())
         seen = []
         for i in range(self.num_hidden_layers):
             x, counters = layer(x, params['layer_%d' % i], positions)
@@ -256,7 +276,8 @@ class DeepseekV3LM:
             'assignments': zero + (len(seen) * tokens.size
                                    * self.num_experts_per_tok),
             'experts_with_row': touched / n,
-            'expert_load_max_over_mean': load / n}
+            'expert_load_max_over_mean': load / n,
+            'checkpoint_kept_bytes': zero + kept}
         return self._rms(x, params['final_norm']), counters
 
     def _logits(self, params, x):

@@ -37,6 +37,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from chainermn_tpu.ops._common import NEG_INF, interpret_flag, pallas_mode
 
@@ -670,6 +671,30 @@ def _scan_block(blocks, t_kv):
     return blocks[1] if blocks else min(_LANES, t_kv)
 
 
+#: the names :func:`_flash_fwd` gives its five residuals, in their
+#: order: the merged ``q``, ``k``, ``v`` (the caller's arithmetic,
+#: relaid for the kernels), the kernel's output and its rows'
+#: log-sum-exp (which only the kernel can make).  A caller's
+#: ``jax.checkpoint(f, policy=jax.checkpoint_policies.
+#: save_only_these_names(...))`` keeps the ones it lists, and its
+#: backward then makes ``f`` again but for them; under any other
+#: checkpoint, or none, a name is the identity and lowers to nothing.
+RESIDUAL_NAMES = ('flash_q', 'flash_k', 'flash_v', 'flash_out',
+                  'flash_lse')
+
+
+def residual_bytes(b, t, h, d, dv, dtype):
+    """``{name: bytes}`` of the residuals of ONE self-attention call
+    :func:`flash_attention` makes with its own tiles: ``b * h`` rows of
+    ``t`` positions (padded as the call pads them), ``q`` / ``k`` at
+    the key width ``d``, ``v`` / the output at the value width ``dv``,
+    in ``dtype``, and one float32 statistic a row."""
+    rows, size = b * h * _padded_len(t), jnp.dtype(dtype).itemsize
+    return dict(zip(RESIDUAL_NAMES, (rows * d * size, rows * d * size,
+                                     rows * dv * size, rows * dv * size,
+                                     rows * 4)))
+
+
 def _flash_fwd(q, k, v, causal, scale, kv_len, blocks):
     if pallas_mode() == 'fallback':
         out, lse = _fwd_blockwise_jnp(q, k, v, causal, scale, kv_len,
@@ -680,7 +705,9 @@ def _flash_fwd(q, k, v, causal, scale, kv_len, blocks):
             q.dtype)
         out, lse = _fwd_pallas(q, k, v, causal, scale, kv_len,
                                block_q, block_k)
-    return out, (q, k, v, out, lse)
+    q, k, v, out, lse = res = tuple(map(
+        checkpoint_name, (q, k, v, out, lse), RESIDUAL_NAMES))
+    return out, res
 
 
 def _flash_bwd(causal, scale, kv_len, blocks, res, g):

@@ -6,10 +6,13 @@ steps through ``StandardUpdater``; the share of the experts a layer is
 told it holds against the uncut layer; the rotary's pairing; the
 shared MLA module and the edited expert body against what ``xing4`` and
 ``afmoe`` computed before the move; the step's counters on the
-trainer's span."""
+trainer's span; what a layer's checkpoint keeps (the flash kernel's
+residuals: its forward once a layer, no number moved)."""
 
+import collections
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +23,7 @@ from jax import lax
 
 import chainermn_tpu
 from chainermn_tpu import ops, telemetry, training
+from chainermn_tpu.analysis import walker
 from chainermn_tpu.models import DeepseekV3LM, _experts, _mla
 from chipbench.reference import common as ref_common
 from chipbench.reference import deepseek_v3 as ref
@@ -60,6 +64,18 @@ def _paths(tree):
 @pytest.fixture(scope='module')
 def params():
     return ref.init_params(CFG, 7)
+
+
+@pytest.fixture(params=['fallback', 'interpret'])
+def mode(request, monkeypatch):
+    """The flash and grouped kernels as jnp twins, or the Pallas
+    kernels in the interpreter."""
+    if request.param == 'interpret':
+        monkeypatch.setenv('CHAINERMN_TPU_PALLAS_INTERPRET', '1')
+    else:
+        monkeypatch.delenv('CHAINERMN_TPU_PALLAS_INTERPRET',
+                           raising=False)
+    return request.param
 
 
 @pytest.fixture(scope='module')
@@ -157,13 +173,14 @@ def test_gradients_through_the_kernels_in_the_interpreter(
         np.testing.assert_allclose(a, b, atol=5e-5 * scale, rtol=5e-4)
 
 
-def _updater(params, policy, examples, batch=2):
+def _updater(params, policy, examples, batch=2, recompute='layer'):
     comm = chainermn_tpu.create_communicator(
         'xla', devices=jax.devices()[:1])
     opt = chainermn_tpu.create_multi_node_optimizer(
         optax.adam(CFG['train']['lr']), comm)
     model = DeepseekV3LM.from_config(
-        CFG, dtype=jnp.bfloat16 if policy else jnp.float32)
+        CFG, dtype=jnp.bfloat16 if policy else jnp.float32,
+        train_recompute=recompute)
     return training.StandardUpdater(
         training.SerialIterator(examples, batch, shuffle=False), opt,
         model.loss_fn(), params, comm, has_aux=True, policy=policy)
@@ -198,13 +215,14 @@ def test_three_adam_steps_through_the_updater(params, policy):
             params['layer_%d' % i]['expert_bias'])
 
 
-def test_the_counters_ride_the_train_update_span(params):
+@pytest.mark.parametrize('recompute', ['layer', None])
+def test_the_counters_ride_the_train_update_span(params, recompute):
     """With a recorder live, ``update()`` hangs the aux values the loss
     marks as counters on its ``train_update`` span; without one it
     costs a tuple lookup."""
     examples = _examples()
     upd = _updater(jax.tree_util.tree_map(jnp.array, params), None,
-                   examples)
+                   examples, recompute=recompute)
     assert upd._span_counters == DeepseekV3LM.span_counters
     telemetry.disable()
     out = upd.update()
@@ -220,12 +238,118 @@ def test_the_counters_ride_the_train_update_span(params):
     assert spans[0]['assignments'] == 2 * 2 * 24 * 3
     assert 0 <= spans[0]['held_assignments'] <= spans[0]['assignments']
     assert spans[0]['expert_load_max_over_mean'] >= 1.0
+    # three layers' kept q, k (2 rows x 2 heads x 24 x 12, float32),
+    # v, output (x 8) and statistics (one float32 a row), or nothing
+    assert spans[0]['checkpoint_kept_bytes'] == (
+        3 * 4 * 24 * (12 + 12 + 8 + 8 + 1) * 4 if recompute else 0)
     # a loss that marks nothing: nothing hung, nothing looked up
     plain = training.StandardUpdater(
         training.SerialIterator(examples, 2, shuffle=False),
         upd.optimizer, lambda p, x, y: _model().loss_fn()(p, x, y)[0],
         upd.params, upd.comm, donate=False)
     assert plain._span_counters == ()
+
+
+# -- what a layer's checkpoint keeps -----------------------------------
+
+def _flash_calls(jaxpr):
+    """The kernels of a jaxpr by name: a Pallas kernel's own (the
+    interpreter), the fallback's two scans of ``ops/flash_attention.py``
+    told apart by what they carry (``m, l, acc`` forward, ``dq``
+    backward)."""
+    found = collections.Counter()
+    for eqn, _ in walker.iter_eqns(jaxpr):
+        if eqn.primitive.name == 'pallas_call':
+            found[eqn.params['name']] += 1
+        elif (eqn.primitive.name == 'scan' and 'flash_attention.py' in
+              eqn.params['jaxpr'].jaxpr.debug_info.func_src_info):
+            found['flash_attention_fwd' if eqn.params['num_carry'] == 3
+                  else 'flash_attention_bwd'] += 1
+    return found
+
+
+def _loss_of(recompute, tokens, targets, dtype=jnp.float32):
+    model = DeepseekV3LM.from_config(CFG, dtype=dtype,
+                                     train_recompute=recompute)
+    return lambda p: model.loss_fn()(p, jnp.asarray(tokens),
+                                     jnp.asarray(targets))
+
+
+@pytest.mark.parametrize('how,forwards', [
+    ('layer', 1), (None, 1), ('bare checkpoint', 2)])
+def test_flash_forward_runs_once_a_layer(params, mode, how, forwards):
+    """Under ``train_recompute='layer'`` the gradient's jaxpr holds the
+    flash forward ONCE a layer, as with no checkpoint at all, while the
+    rest of a layer is still made again (the expert kernels twice);
+    under a checkpoint with no policy the walker sees it twice."""
+    layers = CFG['num_hidden_layers']
+    loss = _loss_of('layer' if how == 'layer' else None, *_batch())
+    scalar = (lambda p: loss(p)[0]) if how != 'bare checkpoint' else \
+        jax.checkpoint(lambda p: loss(p)[0])
+    calls = _flash_calls(jax.make_jaxpr(jax.grad(scalar))(params))
+    assert calls['flash_attention_fwd'] == forwards * layers
+    if mode == 'interpret':
+        assert calls['flash_attention_bwd_dq'] == layers
+        assert calls['flash_attention_bwd_dkv'] == layers
+        assert calls['grouped_swiglu'] == (layers - 1) * (
+            1 if how is None else 2)
+    else:
+        assert calls['flash_attention_bwd'] == layers
+
+
+@pytest.mark.parametrize('t', [24, 200])
+def test_a_layer_keeps_the_flash_kernels_residuals(params, mode, t,
+                                                   capsys):
+    """What ``jax.ad_checkpoint.print_saved_residuals`` lists from
+    ``ops/flash_attention.py`` under ``'layer'``: the kernel's output
+    and statistics and its merged ``q`` / ``k`` / ``v`` once a layer,
+    at the padded length, each kept by its name (one the forward also
+    hands on is listed as the ``reduce_precision`` JAX puts on it);
+    their bytes are the step's ``checkpoint_kept_bytes``."""
+    layers, rows = CFG['num_hidden_layers'], 2 * CFG['num_attention_heads']
+    padded = {24: 24, 200: 256}[t]
+    key = CFG['qk_nope_head_dim'] + CFG['qk_rope_head_dim']
+    loss = _loss_of('layer', *_batch(t=t))
+    jax.ad_checkpoint.print_saved_residuals(lambda p: loss(p)[0], params)
+    kept = [line for line in capsys.readouterr().out.splitlines()
+            if 'flash_attention.py' in line]
+    assert len(kept) == 5 * layers, kept
+    assert all('named' in line or 'reduce_precision' in line
+               for line in kept)
+    assert sum("named 'flash_lse'" in line for line in kept) == layers
+    shapes = collections.Counter(
+        tuple(map(int, re.match(r'f32\[([\d,]+)\]', line).group(
+            1).split(','))) for line in kept)
+    assert shapes == {
+        (rows, padded, key): 2 * layers,                    # q, k
+        (rows, padded, CFG['v_head_dim']): 2 * layers,      # v, out
+        ((rows, 1, padded) if mode == 'interpret'
+         else (rows, padded)): layers}                      # lse
+    aux = jax.eval_shape(loss, params)[1]
+    assert set(DeepseekV3LM.span_counters) == set(aux)
+    kept_bytes = float(jax.jit(lambda p: loss(p)[1][
+        'checkpoint_kept_bytes'])(params))
+    assert kept_bytes == sum(4 * int(np.prod(shape)) * n
+                             for shape, n in shapes.items())
+    none = _loss_of(None, *_batch(t=t))
+    assert float(jax.jit(lambda p: none(p)[1]['checkpoint_kept_bytes'])(
+        params)) == 0.0
+
+
+@pytest.mark.parametrize('dtype', [jnp.float32, jnp.bfloat16])
+def test_what_is_kept_moves_no_number(params, mode, dtype):
+    """A kept tensor is the tensor the recomputation would have made:
+    the loss and every leaf's gradient under ``'layer'`` are those
+    with no checkpoint, bit for bit."""
+    batch = _batch(5)
+    (kept_loss, _), kept = jax.value_and_grad(
+        _loss_of('layer', *batch, dtype=dtype), has_aux=True)(params)
+    (loss, _), grads = jax.value_and_grad(
+        _loss_of(None, *batch, dtype=dtype), has_aux=True)(params)
+    assert float(kept_loss) == float(loss)
+    for name, a, b in zip(_paths(grads), jax.tree_util.tree_leaves(kept),
+                          jax.tree_util.tree_leaves(grads)):
+        np.testing.assert_array_equal(a, b, err_msg=name)
 
 
 def test_all_the_shares_add_up_to_the_uncut_layer():

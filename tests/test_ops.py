@@ -993,6 +993,46 @@ def test_flash_backward_with_a_value_width_of_its_own(mode, d, dv, t):
         np.testing.assert_allclose(a, b, atol=3e-5, rtol=3e-5)
 
 
+@pytest.mark.parametrize('d,dv', [(64, 64), (192, 128)])
+def test_flash_residual_names_are_inert_without_a_checkpoint(
+        mode, monkeypatch, d, dv):
+    """``_flash_fwd`` names its residuals for a caller's checkpoint
+    policy (``RESIDUAL_NAMES``).  With no checkpoint around the call
+    the value, the gradient and the jaxpr's equations but for the five
+    ``name`` ones are what the kernel gives with no name at all."""
+    from chainermn_tpu.analysis import walker
+    q, k = _rand((1, 160, 2, d), 0), _rand((1, 160, 2, d), 1)
+    v, w = _rand((1, 160, 2, dv), 2), _rand((1, 160, 2, dv), 3)
+
+    def both():
+        fn = jax.value_and_grad(lambda *a: jnp.sum(
+            ops.flash_attention(*a, causal=True) * w), (0, 1, 2))
+        eqns = [(e.primitive.name, e.params.get('name'),
+                 [str(o.aval) for o in e.outvars])
+                for e, _ in walker.iter_eqns(jax.make_jaxpr(fn)(q, k, v))]
+        return fn(q, k, v), eqns
+
+    named, named_eqns = both()
+    assert sorted(name for prim, name, _ in named_eqns
+                  if prim == 'name') == sorted(_fa.RESIDUAL_NAMES)
+    monkeypatch.setattr(_fa, 'checkpoint_name', lambda x, name: x)
+    jax.clear_caches()       # the forward rule's trace is cached
+    try:
+        plain, plain_eqns = both()
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    assert not any(prim == 'name' for prim, _, _ in plain_eqns)
+    assert [e for e in named_eqns if e[0] != 'name'] == plain_eqns
+    for a, b in zip(jax.tree_util.tree_leaves(named),
+                    jax.tree_util.tree_leaves(plain)):
+        np.testing.assert_array_equal(a, b)
+    # and the bytes a caller's policy would keep, at the padded length
+    assert sum(_fa.residual_bytes(1, 160, 2, d, dv, jnp.float32
+                                  ).values()) == \
+        2 * 256 * (2 * d + 2 * dv + 1) * 4
+
+
 def _grouped_case(sizes, extra, d=32, f=48):
     sizes = jnp.asarray(sizes, jnp.int32)
     n, e = int(sizes.sum()) + extra, sizes.shape[0]
