@@ -340,6 +340,13 @@ PAGED_CASES = {
     'gpt2m-serve-closed32': dict(
         rows=32, pool=(2049, 16, 16, 128), n_max=64,
         prompt=(128, 0.8, 16, 512), output=(96, 0.6, 16, 256)),
+    # the same 16 heads of 64, two a 128-lane row of a head-major page
+    # (PERF.md section 6, PR 44): the float pool's layout since then;
+    # the case above is what an int8 pool still reads
+    'gpt2m-serve-closed32.packed': dict(
+        rows=32, pool=(2049, 8, 16, 128), n_max=64, group=2,
+        head_major=True,
+        prompt=(128, 0.8, 16, 512), output=(96, 0.6, 16, 256)),
     'trinity-mini-serve-closed64.full': dict(
         rows=64, pool=(4097, 4, 64, 128), n_max=64, group=8,
         head_major=True,

@@ -1520,29 +1520,12 @@ def _kv_append_kernel(pages_ref, offsets_ref, *refs):
         out_ref[0] = jnp.where(row == at, new_ref[0], page)
 
 
-def paged_kv_append(k, v, k_new, v_new, pages, offsets):
-    """One token a sequence into a HEAD-MAJOR page pool, in place:
-    ``k`` / ``v`` (P, Hkv, page_size, D), ``k_new`` / ``v_new``
-    (B, Hkv, D), written at ``[pages[b], :, offsets[b]]``.  Returns
-    the two pools.  ``v`` and ``v_new`` None: ONE pool (a latent leaf,
-    keys and values in one row), returned with None beside it.
-
-    Why a kernel: an XLA scatter whose update is a (Hkv, D) slab wants
-    the slab contiguous, so on the chip it relays the whole pool out to
-    ``(P, page_size, Hkv, D)`` and back, every call (what
-    ``chip_smoke.serving_pool_check`` catches).  Here a grid step
-    takes one row's page through VMEM and puts the token's row into it
-    with a select; the pools are the call's aliased outputs, so nothing
-    else of them moves.  Rows that share a page (idle rows on the
-    scratch page) overwrite each other, as a scatter's would."""
-    pools = [(k, k_new)] + ([] if v is None else [(v, v_new)])
-    if pallas_mode() == 'fallback':
-        out = [pool.at[pages, :, offsets].set(new.astype(pool.dtype))
-               for pool, new in pools]
-        return tuple(out + [None])[:2]
+def _kv_append_pallas(k, v, k_new, v_new, pages, offsets,
+                      interpret=False):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    pools = [(k, k_new)] + ([] if v is None else [(v, v_new)])
     b, h_kv, d = k_new.shape
     ps, n = k.shape[2], len(pools)
     new = pl.BlockSpec((1, h_kv, 1, d), lambda i, p, o: (i, 0, 0, 0))
@@ -1558,12 +1541,42 @@ def paged_kv_append(k, v, k_new, v_new, pages, offsets):
         # operands count the two prefetched scalars and the new rows:
         # the first pool is operand 2 + n
         input_output_aliases={2 + n + i: i for i in range(n)},
-        interpret=interpret_flag(),
+        interpret=interpret,
         name='paged_kv_append',
     )(pages.astype(jnp.int32), offsets.astype(jnp.int32),
       *[new.astype(pool.dtype)[:, :, None] for pool, new in pools],
       *[pool for pool, _ in pools])
     return tuple(list(out) + [None])[:2]
+
+
+# As ``_decode_paged_call``: one traced and lowered kernel for every
+# layer of an executable that appends into leaves of one shape.
+_kv_append_call = jax.jit(_kv_append_pallas,
+                          static_argnames=('interpret',))
+
+
+def paged_kv_append(k, v, k_new, v_new, pages, offsets):
+    """One token a sequence into a HEAD-MAJOR page pool, in place:
+    ``k`` / ``v`` (P, Hkv, page_size, D), ``k_new`` / ``v_new``
+    (B, Hkv, D), written at ``[pages[b], :, offsets[b]]``.  Returns
+    the two pools.  ``v`` and ``v_new`` None: ONE pool (a latent leaf,
+    keys and values in one row), returned with None beside it.
+
+    Why a kernel: an XLA scatter whose update is a (Hkv, D) slab wants
+    the slab contiguous, so on the chip it relays the whole pool out to
+    ``(P, page_size, Hkv, D)`` and back, every call (what
+    ``chip_smoke.serving_pool_check`` catches).  Here a grid step
+    takes one row's page through VMEM and puts the token's row into it
+    with a select; the pools are the call's aliased outputs, so nothing
+    else of them moves.  Rows that share a page (idle rows on the
+    scratch page) overwrite each other, as a scatter's would."""
+    if pallas_mode() == 'fallback':
+        out = [pool.at[pages, :, offsets].set(new.astype(pool.dtype))
+               for pool, new in ((k, k_new), (v, v_new))
+               if pool is not None]
+        return tuple(out + [None])[:2]
+    return _kv_append_call(k, v, k_new, v_new, pages, offsets,
+                           interpret=interpret_flag())
 
 
 # ----------------------------------------------------------------------

@@ -651,8 +651,10 @@ class GenerationEngine:
             self._ring, self._window, self.window_pool = 0, None, None
             self.state_pool = None
             self._table_width = None
-        # the GLOBAL cache is built unsharded (tp=1); specs shard it
-        cache = self._new_cache(model)
+        # the GLOBAL cache is built unsharded; specs shard it ``tp``
+        # ways (a paged pool lays its rows out for that many)
+        tp = plan.model_size if plan is not None else 1
+        cache = self._new_cache(model, tp)
         self._cache_specs = (
             model.kv_cache_specs(cache, plan.model_axis)
             if plan is not None else None)
@@ -664,10 +666,21 @@ class GenerationEngine:
         # attributes are counted in; a family whose page is not K/V
         # names its count (``page_counter``)
         self._page_counter = getattr(model, 'page_counter', None)
+        # what of a stored row of the pool a decode call reads is K/V,
+        # where the family says (``serve_decode``'s lane attributes)
+        self._kv_lanes = (
+            dict(zip(('kv_live_lanes', 'kv_lanes'),
+                     model.kv_lanes(self._cache_struct)))
+            if self.paged and hasattr(model, 'kv_lanes') else {})
         self._cache_bytes = (
             model.paged_cache_bytes(self._cache_struct)
             if self.state_pool is not None or self._page_counter
             else None)
+        # the compiler's options for the executables, where the family
+        # has some for the platform it is served on
+        self._compiler_options = (
+            model.serve_compiler_options(jax.devices()[0].platform)
+            if hasattr(model, 'serve_compiler_options') else {})
 
         # -- speculative decoding: the draft twin ----------------------
         self.spec_tokens = int(spec_tokens)
@@ -852,13 +865,15 @@ class GenerationEngine:
             load_params(path, self._params_template), version=version,
             validate=validate)
 
-    def _new_cache(self, model):
+    def _new_cache(self, model, tp=1):
         """Zeroed cache of this engine's geometry for ``model`` (the
-        target or the draft), from the model's own constructor."""
+        target, which a plan shards ``tp`` ways, or the replicated
+        draft), from the model's own constructor."""
         if not self.paged:
             return model.init_kv_cache(self.n_slots, self.max_len,
                                        int8_kv=self.int8_kv)
-        extra = {}
+        # only a family that serves under a plan is told of one
+        extra = {'tp': tp} if tp > 1 else {}
         if self._ring:
             extra['n_window_pages'] = self.window_pool.n_pages
         if self.state_pool is not None:
@@ -1084,7 +1099,9 @@ class GenerationEngine:
             fn, structs = self._traceable(phase, bucket, draft)
             args = (self._draft_cache_struct if draft
                     else self._cache_struct,) + structs
-            exe = jax.jit(_named(fn, name), donate_argnums=(1,))
+            exe = jax.jit(_named(fn, name), donate_argnums=(1,),
+                          compiler_options=self._compiler_options
+                          or None)
             aot = self.aot_requested
             if aot:
                 exe = exe.lower(
@@ -2181,7 +2198,7 @@ class GenerationEngine:
         the live rows' lengths: every live position in a full layer,
         at most the window in a window layer (what the kernels'
         roofline shares count bytes from)."""
-        out = {'kv_positions': sum(live)}
+        out = dict(self._kv_lanes, kv_positions=sum(live))
         if self._window is not None:
             out['kv_window_positions'] = sum(
                 min(n, self._window) for n in live)

@@ -453,6 +453,34 @@ def _served_gaps(model, engine, prompts, streams, width, what, held):
             'gap_mean': float(gaps.mean())}
 
 
+def _require_parting_at_ties(model, params, prompts, slab, got, what):
+    """Two engines' greedy streams of the same requests may part only
+    where the model is undecided.  Up to a request's FIRST differing
+    token both saw the same context; at that token the float32 forward
+    must hold BOTH choices within the logits bound of its best logit
+    (what follows a parting has another context and says nothing)."""
+    parted = [(r, next(i for i, (a, b) in enumerate(zip(s, g)) if a != b))
+              for r, (s, g) in enumerate(zip(slab, got)) if s != g]
+    if not parted:
+        return
+    contexts = [np.concatenate([prompts[r], slab[r][:i]]).astype(np.int32)
+                for r, i in parted]
+    after, _ = _float32_logits(model, params, contexts, None)
+    for (r, i), logits in zip(parted, after):
+        best = float(logits.max())
+        room = BF16_LOGITS_BOUND * (abs(best) + 1.0)
+        picks = (slab[r][i], got[r][i])
+        below = [best - float(logits[t]) for t in picks]
+        say('%s: request %d parts from the slab at token %d, %d / %d: '
+            '%.4f / %.4f below the float32 forward\'s best (room %.4f)'
+            % ((what, r, i) + picks + tuple(below) + (room,)))
+        require(max(below) < room,
+                '%s leaves the slab at token %d of request %d where the '
+                'float32 forward is decided: slab %d, paged %d lie %r '
+                'below its best logit, room %.4f'
+                % ((what, i, r) + picks + (below, room)))
+
+
 def serve(d_model=512, n_heads=8, n_layers=6, d_ff=2048, vocab=32000,
           max_len=512, n_slots=32, max_prompt=128, max_new=32,
           n_requests=8, page_sizes=(16, 128), kernels='native'):
@@ -523,11 +551,14 @@ def serve(d_model=512, n_heads=8, n_layers=6, d_ff=2048, vocab=32000,
                 '%s %s: logits off the plain forward / float32 jnp by '
                 '%r' % (base, name, errors[name]))
 
-    # token streams: slab vs paged.  Equal token for token where the
-    # page IS the slab's key block (identical arithmetic by
-    # construction); elsewhere a different block order may flip a
-    # bf16 near-tie, so the count is information
-    slab_block = min(128, max_len)    # flash_attention_decode's key block
+    # token streams, slab vs paged.  A float page pool is read by the
+    # paged kernel's head-major branch (``p`` rounded to the pool's
+    # dtype for the MXU) and the slab by float32 products, so the two
+    # may part where the model itself is undecided, and only there: up
+    # to a request's FIRST differing token both saw the same context,
+    # and at that token the float32 forward must hold BOTH choices
+    # within the logits bound of its best (217 of 256 tokens agreed on
+    # the chip at pages of 16 and of 128, PR 44)
     total = n_requests * max_new
     for page in page_sizes:
         got = streams['paged%d' % page]
@@ -535,11 +566,30 @@ def serve(d_model=512, n_heads=8, n_layers=6, d_ff=2048, vocab=32000,
                    for a, b in zip(s, g))
         say('%s: slab vs paged(page %d) token streams agree on %d/%d'
             % (base, page, same, total))
-        if page == slab_block:
-            require(same == total,
-                    '%s: paged(page %d) must equal slab token for '
-                    'token: %r vs %r' % (base, page, got,
-                                         streams['slab']))
+        _require_parting_at_ties(
+            model, served, prompts, streams['slab'], got,
+            '%s paged(page %d)' % (base, page))
+
+    # an int8 pool keeps the page-major layout and the kernel branch
+    # that shares its arithmetic with the slab kernel (float32
+    # products, the same online-softmax recurrence): where the page IS
+    # the slab's key block the two engines emit the same tokens, and
+    # that is REQUIRED
+    slab_block = min(128, max_len)    # flash_attention_decode's key block
+    if slab_block in page_sizes:
+        int8 = {name: _serve_requests(
+            engine(int8_kv=True, **kw), prompts, max_new, kernels,
+            '%s int8 %s' % (base, name))
+            for name, kw in (('slab', {}), ('paged%d' % slab_block, dict(
+                paged=True, page_size=slab_block)))}
+        streams.update(('int8_' + name, got) for name, got in int8.items())
+        require(int8['paged%d' % slab_block] == int8['slab'],
+                '%s: int8 paged(page %d) must equal the int8 slab token '
+                'for token: %r vs %r'
+                % (base, slab_block, int8['paged%d' % slab_block],
+                   int8['slab']))
+        say('%s: int8 slab vs int8 paged(page %d) token streams agree on '
+            '%d/%d' % (base, slab_block, total, total))
     say('%s: slab stream of request 0: %s' % (base, streams['slab'][0]))
     return {'streams': streams, 'errors': errors}
 
@@ -670,7 +720,8 @@ def serving_pool_check(d_model=1024, n_heads=16, n_layers=24,
     A jaxpr shows that the program asks for no copy
     (``tests/test_transformer.py``); it cannot show what XLA
     materialises on the TPU, where a ``(pages, 16, 16, 64)`` array
-    lies page-minor (hence the cache's head dim padded to 128 lanes)
+    lies page-minor (hence the pool's rows of 128 lanes: two 64-wide
+    heads side by side, head-major, since PR 44)
     and a custom call takes a buffer, never a view: PR 25's trace held
     two copies of the whole pool in every call behind a jaxpr pin
     that passed.  Weights are zeros: nothing runs."""

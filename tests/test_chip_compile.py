@@ -142,15 +142,27 @@ def _head_major(n_pages):
 _EXPERTS = [((128, 2048, 1024), BF16)] * 2 + [((128, 1024, 2048), BF16),
                                               ((128,), I32)]
 
-# the gpt2m-serve-closed32 cell's paged decode call: 32 rows, pages of
-# 16 positions x 16 heads x 128 lanes (the head dim 64 padded), a table
-# 64 wide
+# the gpt2m-serve-closed32 cell's paged decode call: 32 rows, a table
+# 64 wide, the float pool head-major with two 64-wide heads a 128-lane
+# row (8 packed heads of 16 positions, each query one of its row's
+# group of 2); an int8 pool page-major, pages of 16 positions x 16
+# heads x 128 lanes (the head dim 64 padded)
 _Q32 = ((32, 16, 128), BF16)
+_TABLE32 = [((32, 64), I32), ((32,), I32)]
 
 
 def _page_major(dtype):
-    return [((2049, 16, 16, 128), dtype)] * 2 + [((32, 64), I32),
-                                                 ((32,), I32)]
+    return [((2049, 16, 16, 128), dtype)] * 2 + _TABLE32
+
+
+def _packed():
+    return [((2049, 8, 16, 128), BF16)] * 2
+
+
+def _decode_packed(q, k, v, tables, lengths):
+    return ops.flash_attention_decode_paged(
+        q, k, v, tables, lengths, scale=64 ** -0.5, group=2,
+        head_major=True)
 
 
 # the olmo-hybrid-serve-closed48 cell's widths: 30 query on 30 K/V heads
@@ -306,7 +318,11 @@ CASES = {
     'causal_conv_step_48rows': (
         _conv_step, [((49, 288, 128), BF16), ((48,), I32),
                      ((48, 11520), BF16), ((4, 11520), BF16)]),
-    'decode_paged_gpt2m_cell': (_decode_paged, [_Q32] + _page_major(BF16)),
+    'decode_paged_gpt2m_cell': (
+        _decode_packed, [_Q32] + _packed() + _TABLE32),
+    'paged_kv_append_gpt2m_cell': (
+        _append, _packed() + [((32, 8, 128), BF16)] * 2
+        + [((32,), I32)] * 2),
     'decode_paged_gpt2m_cell_int8': (
         _decode_paged, [_Q32] + _page_major(I8)
         + [((2049, 16, 16), F32)] * 2),
@@ -393,7 +409,8 @@ def test_kernel_compiles_for_v5e(case, one_chip, mosaic):
 def test_paged_decode_carries_several_pages_inside_its_vmem(
         case, one_chip, mosaic):
     """At both serving cells' exact shapes the rule gives a grid step
-    several pages, and the VMEM Mosaic allocates for the kernel
+    several pages (16 of the ``gpt2m`` cell's lane-dense 32 KB pages, 8
+    of an int8 pool's), and the VMEM Mosaic allocates for the kernel
     (``used_scoped_memory_configs`` of the compiled custom call) holds
     the two slots of that many pages inside the limit the call
     states."""
@@ -403,10 +420,12 @@ def test_paged_decode_carries_several_pages_inside_its_vmem(
     int8 = len(shapes) == 7
     pool, dtype = shapes[1]
     n_max = shapes[3][0][1]
-    head_major = 'group8' in case
+    head_major = not int8
     pages = fa._paged_pages_per_step(pool[1:], dtype, n_max, int8,
                                      head_major)
     assert pages > 1
+    if case == 'decode_paged_gpt2m_cell':
+        assert pages == 16
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
             for s, d in shapes]
     call, = [line for line in
@@ -424,14 +443,20 @@ def test_paged_decode_carries_several_pages_inside_its_vmem(
     assert used <= held, 'the rule counts less than Mosaic allocates'
 
 
-@pytest.mark.parametrize('body', ['decode', 'prefill'])
+@pytest.mark.parametrize('body,int8_kv', [
+    ('decode', False), ('prefill', False), ('prefill', True)])
 def test_serving_executable_leaves_the_page_pool_in_place(
-        body, one_chip, mosaic):
+        body, int8_kv, one_chip, mosaic):
     """The serving executables at the widths of the benchmark's cell
     (gpt2-medium, 2,049 pages of 16, 32 rows; two layers of its 24),
     jitted as ``GenerationEngine._compile`` jits them and compiled for
     the described chip: besides the in-place write nothing makes a
     value of a pool leaf's shape, and the scratch is under one leaf.
+    Both layouts: the float pool head-major and lane-dense (the
+    cell's), an int8 pool page-major with its scale leaves, whose
+    prefill passes too; its DECODE executable does not (ten
+    ``slice-start`` of ``s8[2049,16,16,128]``: no cell serves int8,
+    ROADMAP M1 has it).
     With the head dim left at 64 (the array then lies page-minor on
     the chip), or with one stacked array for all layers, this compile
     holds whole-pool ``copy`` and per-layer ``slice`` instructions
@@ -454,7 +479,8 @@ def test_serving_executable_leaves_the_page_pool_in_place(
     cache = jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
                                        sharding=one_chip),
-        jax.eval_shape(lambda: M.init_paged_kv_cache(model, 2049, 16)))
+        jax.eval_shape(lambda: M.init_paged_kv_cache(
+            model, 2049, 16, int8_kv=int8_kv)))
 
     def ints(*shape):
         return jax.ShapeDtypeStruct(shape, I32, sharding=one_chip)
@@ -473,12 +499,22 @@ def test_serving_executable_leaves_the_page_pool_in_place(
         'decode': (decode, (ints(32), ints(32), ints(32, 64))),
         'prefill': (prefill, (ints(1, 128), ints(), ints(), ints(64))),
     }[body]
-    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+    compiled = jax.jit(
+        fn, donate_argnums=(1,),
+        compiler_options=model.serve_compiler_options('tpu')).lower(
         params, cache, *operands).compile()
-    leaves = jax.tree_util.tree_leaves(cache)
-    assert {leaf.shape for leaf in leaves} == {(2049, 16, 16, 128)}
+    # the family's options: a weight is prefetched into VMEM whole, not
+    # in four slices (left to itself the compiler makes 32
+    # ``slice-start`` / ``slice-done`` pairs in these two layers' decode
+    # executable and joins them with ``ConcatBitcast`` calls)
+    if not int8_kv:
+        assert ' slice-start(' not in compiled.as_text()
+        assert 'ConcatBitcast' not in compiled.as_text()
+    leaves = cache['k'] + cache['v']
+    page = (16, 16, 128) if int8_kv else (8, 16, 128)
+    assert {leaf.shape for leaf in leaves} == {(2049,) + page}
     assert chip_smoke.pool_shaped(compiled.as_text(), leaves) == []
-    leaf_bytes = 2049 * 16 * 16 * 128 * 2
+    leaf_bytes = 2049 * 16 * 16 * 128
     assert compiled.memory_analysis().temp_size_in_bytes < leaf_bytes
     if body == 'decode':
         assert 'tpu_custom_call' in compiled.as_text()

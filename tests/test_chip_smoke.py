@@ -90,11 +90,59 @@ def test_serve_phase_tiny(interpret):
                            max_new=3, n_requests=2, page_sizes=(16,),
                            kernels='interpret', **TINY_LM)
     streams = out['streams']
-    assert sorted(streams) == ['paged16', 'slab']
-    # page 16 == the slab key block here: equal token for token
+    assert sorted(streams) == ['int8_paged16', 'int8_slab', 'paged16',
+                               'slab']
+    # page 16 == the slab key block here.  The int8 pool keeps the
+    # kernel branch that shares the slab's arithmetic: the phase
+    # REQUIRES equal tokens of it.  The float pool's branch rounds
+    # ``p`` to bfloat16 and may part from the slab at a near-tie (the
+    # phase requires that it be one); two requests of three tokens
+    # hold none
+    assert streams['int8_paged16'] == streams['int8_slab']
     assert streams['paged16'] == streams['slab']
     assert all(len(s) == 3 for s in streams['slab'])
     assert max(max(e) for e in out['errors'].values()) < 5e-2
+
+
+def test_serve_streams_may_part_only_at_a_near_tie(monkeypatch):
+    """The float paged stream against the slab's: nothing is asked of
+    equal streams; a stream that leaves the slab for the float32
+    forward's runner-up passes where the two logits lie within the
+    bound and fails where they do not; one that leaves it for the
+    worst token fails; what follows the first parting is not read."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from chainermn_tpu.models import TransformerLM
+    lm = dict(TINY_LM)
+    model = TransformerLM(
+        vocab_size=lm['vocab'], d_model=lm['d_model'],
+        n_heads=lm['n_heads'], n_layers=lm['n_layers'], d_ff=lm['d_ff'],
+        max_len=16)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 8), jnp.int32))['params']
+    prompts = [np.array([3, 1, 4], np.int32), np.array([1, 5], np.int32)]
+    first, _ = chip_smoke._float32_logits(model, params, prompts, None)
+    order = np.argsort(first, axis=-1)
+    best, second, worst = order[:, -1], order[:, -2], order[:, 0]
+    slab = [[int(best[0]), 7, 7], [int(best[1]), 2, 2]]
+    check = chip_smoke._require_parting_at_ties
+    check(model, params, prompts, slab, [list(s) for s in slab], 'same')
+    # request 1 leaves at its FIRST token for the worst one
+    got = [list(slab[0]), [int(worst[1]), 2, 2]]
+    with pytest.raises(chip_smoke.SmokeFailure, match='token 0 of '
+                       'request 1'):
+        check(model, params, prompts, slab, got, 'worst')
+    # the runner-up: a tie or not by the bound
+    gap = float(first[0, best[0]] - first[0, second[0]])
+    scale = abs(float(first[0].max())) + 1.0
+    got = [[int(second[0]), int(worst[0]), 0], list(slab[1])]
+    monkeypatch.setattr(chip_smoke, 'BF16_LOGITS_BOUND', 2 * gap / scale)
+    check(model, params, prompts, slab, got, 'runner-up, a tie')
+    monkeypatch.setattr(chip_smoke, 'BF16_LOGITS_BOUND', gap / scale / 2)
+    with pytest.raises(chip_smoke.SmokeFailure, match='token 0 of '
+                       'request 0'):
+        check(model, params, prompts, slab, got, 'runner-up, decided')
 
 
 TINY_AFMOE = dict(hidden=32, heads=4, kv_heads=2, head_dim=8, experts=8,

@@ -58,12 +58,13 @@ def _lm():
                              jnp.zeros((1, 4), jnp.int32))['params']
 
 
-def _engine():
+def _engine(page_size=8):
     eng = serving.GenerationEngine(*_lm(), n_slots=2,
                                    max_prompt_len=8, paged=True,
-                                   page_size=8)
+                                   page_size=page_size)
     eng.warmup()
-    return eng, serving.GenerationQueue(max_prompt_len=8, page_size=8)
+    return eng, serving.GenerationQueue(max_prompt_len=8,
+                                        page_size=page_size)
 
 
 class _Traced:
@@ -254,13 +255,17 @@ def test_profiler_session_switches_the_schedulers_spans_on(tmp_path):
     traced.check_records_inside_the_window()
 
 
-def test_decode_span_says_how_the_paged_kernels_grid_engaged():
+@pytest.mark.parametrize('page_size', [8, 16])
+def test_decode_span_says_how_the_paged_kernels_grid_engaged(page_size):
     """``serve_decode`` carries the pages the paged decode kernel's
     copies fetched and the grid steps it took, over rows and layers,
     from the lengths and the rule alone (``decode_pages_per_grid_step``
-    reads their ratio)."""
+    reads their ratio), and what of a stored row of the pool it reads
+    is K/V (``kv_lane_fill``).  Pages of 16 bfloat16 positions make a
+    head-major pool, pages of 8 (off the dtype's sublane tile) a
+    page-major one: the counters follow the branch that reads it."""
     from chainermn_tpu import ops
-    eng, queue = _engine()
+    eng, queue = _engine(page_size)
     recorder = telemetry.enable()
     request = queue.submit([1, 2, 3, 4, 5], 12)
     while not request.done():
@@ -274,17 +279,26 @@ def test_decode_span_says_how_the_paged_kernels_grid_engaged():
             if 'ran_ahead' in r] == [0] + [1] * 10
     decode = [r for r in spans if 'bucket' in r]
     assert len(decode) == 11
+    head_major = page_size == 16
+    assert ('head_major' in eng._cache_struct) == head_major
     leaf = eng._cache_struct['k'][0]
+    assert leaf.shape[1:] == ((4, 16, 128) if head_major
+                              else (8, 4, 128))
     for i, r in enumerate(decode):
-        # one live row of 6 + i positions on pages of 8, the bucket's
-        # other rows on the scratch page; one layer
+        # one live row of 6 + i positions, the bucket's other rows on
+        # the scratch page; one layer
         lengths = [6 + i] + [1] * (r['bucket'] - 1)
         assert r['kv_positions'] == 6 + i
         assert (r['kv_pages_read'], r['kv_grid_steps']) == \
             ops.decode_paged_grid(lengths, leaf.shape[1:], leaf.dtype,
-                                  eng.pages_per_seq)
-        assert r['kv_pages_read'] == -(-(6 + i) // 8) + r['bucket'] - 1
-        # every row's live pages fit one step of the rule's 8
+                                  eng.pages_per_seq,
+                                  head_major=head_major)
+        # this model's head of 8 values alone in a 128-lane row (4
+        # heads of 8 do not fill one), in either layout
+        assert (r['kv_live_lanes'], r['kv_lanes']) == (8, 128)
+        assert r['kv_pages_read'] == \
+            -(-(6 + i) // page_size) + r['bucket'] - 1
+        # every row's live pages fit one step of either rule's
         assert r['kv_grid_steps'] == r['bucket']
 
 

@@ -96,6 +96,10 @@ def test_decode_slab_mosaic(int8):
 # group, window), each with rows of length 1, ending mid-page and
 # mid-step, full, and (the ring) past the window
 _CELL_CALLS = {
+    # the cell's float pool since PR 44: two 64-wide heads a 128-lane
+    # row, head-major, each query one of its row's group of 2
+    'gpt2m_packed': (32, (2049, 8, 16, 128), 64, 2, None, True),
+    # what an int8 pool of the same model still reads (here in bf16)
     'gpt2m_page_major': (32, (2049, 16, 16, 128), 64, 1, None, False),
     'trinity_full': (64, (4097, 4, 64, 128), 64, 8, None, True),
     'trinity_ring': (64, (2113, 4, 64, 128), 33, 8, 2048, True),
@@ -110,8 +114,9 @@ def test_decode_paged_cells_mosaic(case):
     fa = importlib.import_module('chainermn_tpu.ops.flash_attention')
     rows, pool, n_max, group, window, head_major = _CELL_CALLS[case]
     ps = pool[2] if head_major else pool[1]
-    assert fa._paged_pages_per_step(
-        pool[1:], jnp.bfloat16, n_max, head_major=head_major) > 1
+    pages = fa._paged_pages_per_step(
+        pool[1:], jnp.bfloat16, n_max, head_major=head_major)
+    assert pages == 16 if case == 'gpt2m_packed' else pages > 1
     rng = np.random.RandomState(7)
     top = n_max * ps if window is None else 4096
     lengths = rng.randint(1, top + 1, rows)
