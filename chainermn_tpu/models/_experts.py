@@ -1,8 +1,9 @@
-"""The dropless sparse feed-forward two families share: a sigmoid
-router whose stored bias steers WHICH ``k`` experts a token takes and
-never their weight (``afmoe``'s ``expert_bias``, DeepSeek-V3's
-``noaux_tc`` with ``e_score_correction_bias``), gates the chosen scores
-normalised and scaled, beside a shared expert every token takes.
+"""The layer parts families share: an RMSNorm and a SwiGLU as functions
+of their arguments, and the dropless sparse feed-forward of three
+families: a sigmoid router whose stored bias steers WHICH ``k`` experts
+a token takes and never their weight (``afmoe``'s ``expert_bias``,
+DeepSeek-V3's ``noaux_tc`` with ``e_score_correction_bias``), gates the
+chosen scores normalised and scaled, beside a shared expert every token takes.
 
 A layer may be told which experts it HOLDS (expert parallelism's share:
 ``docs/mesh_parallelism.md``): it routes over all of them all the same
@@ -13,6 +14,14 @@ is not built: on one chip there is none."""
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+
+def rms(x, weight, eps, dtype):
+    """RMSNorm over the last dim in float32, out in ``dtype``."""
+    xf = x.astype(jnp.float32)
+    xf = xf * lax.rsqrt(jnp.mean(jnp.square(xf), -1, keepdims=True)
+                        + eps)
+    return (xf * weight.astype(jnp.float32)).astype(dtype)
 
 
 def swiglu(x, p, dtype):

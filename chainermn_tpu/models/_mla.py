@@ -10,7 +10,9 @@ and training's.  The absorbed form is decode's and lives with the
 family that serves (``models/xing4.py``).
 
 Plain functions of arrays and sizes: a family keeps its own parameter
-names, norm and query path."""
+names, norm and query path.  :func:`rope` is the one rotate-half rotary
+of the package: ``afmoe``'s window layers turn their heads with it
+too."""
 
 import math
 

@@ -40,11 +40,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from chainermn_tpu.models import _experts
+from chainermn_tpu.models import _experts, _mla, _served
 
 
 @dataclasses.dataclass(frozen=True)
-class AfmoeLM:
+class AfmoeLM(_served.ServedLM):
     """Hyper-parameters under their published ``config.json`` keys."""
 
     vocab_size: int = 200192
@@ -73,7 +73,7 @@ class AfmoeLM:
 
     #: what the engine's executables hand back beside the tokens
     serve_counters = ('experts_touched', 'expert_load_max')
-    tp_axis = None
+    family = 'afmoe'
 
     def __post_init__(self):
         if self.layer_types is None:
@@ -97,21 +97,7 @@ class AfmoeLM:
                              % (self.num_key_value_heads,
                                 self.num_attention_heads))
 
-    @classmethod
-    def from_config(cls, cfg, **overrides):
-        """The model of a ``config.json``-shaped dict; keys this class
-        does not know are left where they are."""
-        known = {f.name for f in dataclasses.fields(cls)}
-        kw = {k: (tuple(v) if isinstance(v, list) else v)
-              for k, v in cfg.items() if k in known}
-        kw.update(overrides)
-        return cls(**kw)
-
     # -- shapes --------------------------------------------------------
-    @property
-    def max_len(self):
-        return self.max_position_embeddings
-
     @property
     def group(self):
         return self.num_attention_heads // self.num_key_value_heads
@@ -126,11 +112,6 @@ class AfmoeLM:
         if 'sliding_attention' not in self.layer_types:
             return 0
         return -(-self.sliding_window // int(page_size)) + 1
-
-    def has_state_row(self):
-        """Does a sequence hold a fixed-size state row beside its
-        pages: no recurrent layers, no."""
-        return False
 
     def param_shapes(self):
         """The parameter tree as shapes (names are the interface the
@@ -181,24 +162,15 @@ class AfmoeLM:
 
     # -- the layer, once -----------------------------------------------
     def _rms(self, x, weight):
-        xf = x.astype(jnp.float32)
-        xf = xf * lax.rsqrt(jnp.mean(jnp.square(xf), -1, keepdims=True)
-                            + self.rms_norm_eps)
-        return (xf * weight.astype(jnp.float32)).astype(self.dtype)
+        return _experts.rms(x, weight, self.rms_norm_eps, self.dtype)
 
     def _rope(self, x, positions):
         """Rotary positions over all of ``head_dim``, rotate-half
-        pairing (dim ``i`` with ``i + head_dim / 2``); ``x`` (..., H,
-        D), ``positions`` (...)."""
+        pairing; ``x`` (..., H, D), ``positions`` (...)."""
         half = self.head_dim // 2
         inv = self.rope_theta ** (
             -jnp.arange(half, dtype=jnp.float32) / half)
-        angle = positions.astype(jnp.float32)[..., None, None] * inv
-        cos, sin = jnp.cos(angle), jnp.sin(angle)
-        xf = x.astype(jnp.float32)
-        x1, x2 = xf[..., :half], xf[..., half:]
-        return jnp.concatenate([x1 * cos - x2 * sin,
-                                x2 * cos + x1 * sin], -1).astype(x.dtype)
+        return _mla.rope(x, positions[..., None], inv)
 
     def _swiglu(self, x, p):
         return _experts.swiglu(x, p, self.dtype)
@@ -291,21 +263,7 @@ class AfmoeLM:
 
     __call__ = apply
 
-    # -- the serving protocol (what GenerationEngine calls) ------------
-    def check_serving(self, **asked):
-        """One refusal for every engine option this family has no path
-        for; ``paged=True`` and greedy decoding is the path there is."""
-        wrong = [name for name, value in sorted(asked.items())
-                 if name != 'paged' and value]
-        if not asked.get('paged'):
-            wrong.insert(0, 'paged=False')
-        if wrong:
-            raise ValueError(
-                'an afmoe model is served through the paged cache only '
-                '(paged=True, prefix_sharing=False, no prefill_chunk, '
-                'int8_kv, draft model or plan): asked for %s'
-                % ', '.join(wrong))
-
+    # -- the serving protocol (``_served.ServedLM``) --------------------
     def init_paged_kv_cache(self, n_pages, page_size, n_window_pages=0,
                             int8_kv=False, dtype=None):
         """``{'k' | 'v': one leaf a layer}``, a leaf ``(pages,
@@ -326,14 +284,6 @@ class AfmoeLM:
                 for i in range(self.num_hidden_layers))
 
         return {'k': leaves(), 'v': leaves()}
-
-    @staticmethod
-    def _with_layer(cache, layer, k_leaf, v_leaf):
-        """``cache`` with ``layer``'s two leaves replaced (each written
-        once a call, so the donated buffer is updated where it lies)."""
-        return {name: cache[name][:layer] + (leaf,)
-                + cache[name][layer + 1:]
-                for name, leaf in (('k', k_leaf), ('v', v_leaf))}
 
     def _tables(self, cache, page_tables):
         page_size = cache['k'][0].shape[2]
@@ -361,9 +311,10 @@ class AfmoeLM:
             window = self._window(layer)
             pages, table = ((full_pages, full) if window is None
                             else (ring_pages, ring))
-            cache = self._with_layer(cache, layer, *ops.paged_kv_append(
+            k_leaf, v_leaf = ops.paged_kv_append(
                 cache['k'][layer], cache['v'][layer], k, v, pages,
-                offsets))
+                offsets)
+            cache = _served.with_leaves(cache, layer, k=k_leaf, v=v_leaf)
             return ops.flash_attention_decode_paged(
                 q, cache['k'][layer], cache['v'][layer], table, lengths,
                 scale=self.head_dim ** -0.5, group=self.group,
@@ -431,10 +382,10 @@ class AfmoeLM:
         def attend(cache, layer, q, k, v):
             window = self._window(layer)
             ids = full_ids if window is None else ring_ids
-            cache = self._with_layer(cache, layer, *(
-                leaf.at[ids].set(pages_of(new[0]).astype(leaf.dtype))
-                for leaf, new in ((cache['k'][layer], k),
-                                  (cache['v'][layer], v))))
+            cache = _served.with_leaves(cache, layer, **{
+                name: cache[name][layer].at[ids].set(
+                    pages_of(new[0]).astype(cache[name][layer].dtype))
+                for name, new in (('k', k), ('v', v))})
             return ops.flash_attention(q, k, v, causal=True,
                                        window=window), cache
 
@@ -445,26 +396,3 @@ class AfmoeLM:
             attend)
         x_last = lax.dynamic_slice_in_dim(x[0], length - 1, 1, axis=0)
         return self._logits(params, x_last)[0], cache, counters
-
-    # -- what this family has no path for yet --------------------------
-    def _not_yet(self, what):
-        raise NotImplementedError('AfmoeLM.%s: not in this family yet '
-                                  '(paged cache, one chip)' % what)
-
-    def init_kv_cache(self, *a, **kw):
-        self._not_yet('init_kv_cache (slot-addressed cache)')
-
-    def prefill(self, *a, **kw):
-        self._not_yet('prefill (slot-addressed cache)')
-
-    def decode_step(self, *a, **kw):
-        self._not_yet('decode_step (slot-addressed cache)')
-
-    def spec_verify(self, *a, **kw):
-        self._not_yet('spec_verify (speculative decoding)')
-
-    def spec_verify_paged(self, *a, **kw):
-        self._not_yet('spec_verify_paged (speculative decoding)')
-
-    def kv_cache_specs(self, *a, **kw):
-        self._not_yet('kv_cache_specs (tensor parallelism)')

@@ -40,8 +40,9 @@ counters say what that costs: ``checkpoint_kept_bytes``, the named
 values' bytes summed over the layers (0 without the rule).
 
 The layer is written once (:meth:`DeepseekV3LM._layer`).  Serving entry
-points raise by name: served, this family is ``xing4``'s path less the
-residual streams, the query latent and YaRN, and is not built.
+points raise by name (``unserved``, through ``_served.ServedLM``):
+served, this family is ``xing4``'s path less the residual streams, the
+query latent and YaRN, and is not built.
 """
 
 import dataclasses
@@ -49,15 +50,14 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
-from chainermn_tpu.models import _experts, _mla
+from chainermn_tpu.models import _experts, _mla, _served
 from chainermn_tpu.ops.flash_attention import (RESIDUAL_NAMES,
                                                residual_bytes)
 
 
 @dataclasses.dataclass(frozen=True)
-class DeepseekV3LM:
+class DeepseekV3LM(_served.ServedLM):
     """Hyper-parameters under their published ``config.json`` keys
     (defaults: Kanana-2-30B-A3B's), then the share and the recompute
     rule, which no ``config.json`` has."""
@@ -101,6 +101,10 @@ class DeepseekV3LM:
     span_counters = ('held_assignments', 'assignments',
                      'experts_with_row', 'expert_load_max_over_mean',
                      'checkpoint_kept_bytes')
+    #: every serving member refuses with this
+    unserved = ('this family is trained, not served (served, it is '
+                'xing4\'s path less the residual streams, the query '
+                'latent and YaRN)')
 
     def __post_init__(self):
         if self.scoring_func != 'sigmoid' or self.topk_method != 'noaux_tc':
@@ -130,17 +134,14 @@ class DeepseekV3LM:
 
     @classmethod
     def from_config(cls, cfg, **overrides):
-        """The model of a ``config.json``-shaped dict; keys this class
-        does not know are left where they are.  A configuration cut to
-        a chip's share says so beside the published keys
-        (``router_experts``, ``first_expert``); ``train.recompute`` is
-        the recompute rule."""
-        known = {f.name for f in dataclasses.fields(cls)}
-        kw = {k: v for k, v in cfg.items() if k in known}
-        if 'recompute' in cfg.get('train', {}):
-            kw.setdefault('train_recompute', cfg['train']['recompute'])
-        kw.update(overrides)
-        return cls(**kw)
+        """As the base's.  A configuration cut to a chip's share says
+        so beside the published keys (``router_experts``,
+        ``first_expert``); ``train.recompute`` is the recompute rule
+        where no ``train_recompute`` key is."""
+        train = cfg.get('train', {})
+        if 'recompute' in train:
+            cfg = {'train_recompute': train['recompute'], **cfg}
+        return super().from_config(cfg, **overrides)
 
     # -- shapes --------------------------------------------------------
     @property
@@ -204,10 +205,7 @@ class DeepseekV3LM:
 
     # -- the layer, once -----------------------------------------------
     def _rms(self, x, weight):
-        xf = x.astype(jnp.float32)
-        xf = xf * lax.rsqrt(jnp.mean(jnp.square(xf), -1, keepdims=True)
-                            + self.rms_norm_eps)
-        return (xf * weight.astype(jnp.float32)).astype(self.dtype)
+        return _experts.rms(x, weight, self.rms_norm_eps, self.dtype)
 
     def _attention(self, lp, a, positions):
         """Expanded latent attention on normed rows ``a`` (B, T, d) at
@@ -308,31 +306,3 @@ class DeepseekV3LM:
 
         loss.span_counters = self.span_counters
         return loss
-
-    # -- what this family has no path for yet --------------------------
-    def _not_yet(self, what):
-        raise NotImplementedError(
-            'DeepseekV3LM.%s: this family is trained, not served '
-            '(served, it is xing4\'s path less the residual streams, '
-            'the query latent and YaRN)' % what)
-
-    def check_serving(self, **asked):
-        self._not_yet('check_serving')
-
-    def init_kv_cache(self, *a, **kw):
-        self._not_yet('init_kv_cache')
-
-    def init_paged_kv_cache(self, *a, **kw):
-        self._not_yet('init_paged_kv_cache')
-
-    def prefill(self, *a, **kw):
-        self._not_yet('prefill')
-
-    def prefill_paged(self, *a, **kw):
-        self._not_yet('prefill_paged')
-
-    def decode_step(self, *a, **kw):
-        self._not_yet('decode_step')
-
-    def decode_step_paged(self, *a, **kw):
-        self._not_yet('decode_step_paged')

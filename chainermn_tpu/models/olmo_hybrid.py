@@ -49,6 +49,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from chainermn_tpu.models import _experts, _served
+
 _KINDS = ('linear_attention', 'full_attention')
 #: seeded leaves that are not N(0, 0.02): (mean, std) by name.  The
 #: decay ``exp(g)`` then spreads over about (0.5, 1) and the
@@ -57,7 +59,7 @@ _INIT = {'A_log': (-1.2, 0.3), 'dt_bias': (0.0, 0.5), 'conv': (0.0, 0.5)}
 
 
 @dataclasses.dataclass(frozen=True)
-class OlmoHybridLM:
+class OlmoHybridLM(_served.ServedLM):
     """Hyper-parameters under their published ``config.json`` keys."""
 
     vocab_size: int = 100352
@@ -81,7 +83,7 @@ class OlmoHybridLM:
     #: state rows the call moved, the real prompt tokens it ran through
     #: the chunked rule
     serve_counters = ('state_rows', 'scan_tokens')
-    tp_axis = None
+    family = 'olmo_hybrid'
 
     def __post_init__(self):
         if self.layer_types is None:
@@ -107,21 +109,7 @@ class OlmoHybridLM:
                 % (self.linear_num_key_heads,
                    self.linear_num_value_heads))
 
-    @classmethod
-    def from_config(cls, cfg, **overrides):
-        """The model of a ``config.json``-shaped dict; keys this class
-        does not know are left where they are."""
-        known = {f.name for f in dataclasses.fields(cls)}
-        kw = {k: (tuple(v) if isinstance(v, list) else v)
-              for k, v in cfg.items() if k in known}
-        kw.update(overrides)
-        return cls(**kw)
-
     # -- shapes --------------------------------------------------------
-    @property
-    def max_len(self):
-        return self.max_position_embeddings
-
     @property
     def head_dim(self):
         return self.hidden_size // self.num_attention_heads
@@ -143,10 +131,6 @@ class OlmoHybridLM:
         """``layer``'s place among the layers of its kind: its index in
         the cache's tuples of leaves."""
         return self.layer_types[:layer].count(self.layer_types[layer])
-
-    def window_ring(self, page_size):
-        """Pages in a window layer's ring: no window layers, 0."""
-        return 0
 
     def has_state_row(self):
         """Does a sequence hold a fixed-size state row beside its
@@ -201,18 +185,8 @@ class OlmoHybridLM:
         return jax.tree_util.tree_unflatten(treedef, out)
 
     # -- the layer, once -----------------------------------------------
-    def _rms(self, x, weight, dtype=None):
-        xf = x.astype(jnp.float32)
-        xf = xf * lax.rsqrt(jnp.mean(jnp.square(xf), -1, keepdims=True)
-                            + self.rms_norm_eps)
-        return (xf * weight.astype(jnp.float32)).astype(
-            dtype or self.dtype)
-
-    def _swiglu(self, x, p):
-        dtype = self.dtype
-        gate = jnp.dot(x, p['w1'].astype(dtype))
-        return jnp.dot(jax.nn.silu(gate) * jnp.dot(
-            x, p['w3'].astype(dtype)), p['w2'].astype(dtype))
+    def _rms(self, x, weight):
+        return _experts.rms(x, weight, self.rms_norm_eps, self.dtype)
 
     def _qkv(self, y):
         """The convolution's float32 output ``y`` (..., channels) to the
@@ -268,7 +242,7 @@ class OlmoHybridLM:
         out = jnp.dot(mixed.reshape(lead + (-1,)).astype(dtype),
                       lp['wo'].astype(dtype))
         x = x + self._rms(out, lp['post_attn_norm'])
-        return x + self._rms(self._swiglu(x, lp['mlp']),
+        return x + self._rms(_experts.swiglu(x, lp['mlp'], dtype),
                              lp['post_mlp_norm']), cache
 
     def _layers(self, params, tokens, cache, attend, recur):
@@ -310,21 +284,7 @@ class OlmoHybridLM:
 
     __call__ = apply
 
-    # -- the serving protocol (what GenerationEngine calls) ------------
-    def check_serving(self, **asked):
-        """One refusal for every engine option this family has no path
-        for; ``paged=True`` and greedy decoding is the path there is."""
-        wrong = [name for name, value in sorted(asked.items())
-                 if name != 'paged' and value]
-        if not asked.get('paged'):
-            wrong.insert(0, 'paged=False')
-        if wrong:
-            raise ValueError(
-                'an olmo_hybrid model is served through the paged cache '
-                'only (paged=True, prefix_sharing=False, no '
-                'prefill_chunk, int8_kv, draft model or plan): asked '
-                'for %s' % ', '.join(wrong))
-
+    # -- the serving protocol (``_served.ServedLM``) --------------------
     def init_paged_kv_cache(self, n_pages, page_size, n_state_rows=0,
                             int8_kv=False, dtype=None):
         """``{'k' | 'v': a page pool a FULL layer, 'state' | 'tail': a
@@ -362,19 +322,8 @@ class OlmoHybridLM:
         """``(bytes of one K/V page, bytes of one state row)``, each
         over all the layers that hold one; ``cache`` may be its
         structs."""
-        def per_row(names):
-            return sum(leaf.dtype.itemsize * leaf.size // leaf.shape[0]
-                       for name in names for leaf in cache[name])
-        return per_row(('k', 'v')), per_row(('state', 'tail'))
-
-    def _put(self, cache, layer, **leaves):
-        """``cache`` with ``layer``'s named leaves replaced (each
-        written once a call, so the donated buffer is updated where it
-        lies)."""
-        at = self._nth(layer)
-        return dict(cache, **{
-            name: cache[name][:at] + (leaf,) + cache[name][at + 1:]
-            for name, leaf in leaves.items()})
+        return (_served.row_bytes(cache['k'] + cache['v']),
+                _served.row_bytes(cache['state'] + cache['tail']))
 
     def _tables(self, page_tables):
         """``[full table | state row]`` apart (the row of a model with
@@ -409,8 +358,8 @@ class OlmoHybridLM:
             return ops.flash_attention_decode_paged(
                 q, k_leaf, v_leaf, full, positions + 1,
                 scale=self.head_dim ** -0.5, group=self.group,
-                head_major=True), self._put(cache, layer, k=k_leaf,
-                                            v=v_leaf)
+                head_major=True), _served.with_leaves(
+                    cache, at, k=k_leaf, v=v_leaf)
 
         def recur(cache, layer, taps, qkv, g, beta):
             at = self._nth(layer)
@@ -418,7 +367,8 @@ class OlmoHybridLM:
                 cache['tail'][at], state_rows, qkv, taps)
             o, state = ops.gated_delta_step(
                 cache['state'][at], state_rows, *self._qkv(y), g, beta)
-            return o, self._put(cache, layer, state=state, tail=tail)
+            return o, _served.with_leaves(cache, at, state=state,
+                                          tail=tail)
 
         x, cache = self._layers(params, tokens, cache, attend, recur)
         return (self._logits(params, x), cache,
@@ -473,16 +423,16 @@ class OlmoHybridLM:
                 return leaf.at[ids].set(new.astype(leaf.dtype))
 
             return (ops.flash_attention(q, k, v, causal=True),
-                    self._put(cache, layer,
-                              k=banked(cache['k'][at], k[0]),
-                              v=banked(cache['v'][at], v[0])))
+                    _served.with_leaves(
+                        cache, at, k=banked(cache['k'][at], k[0]),
+                        v=banked(cache['v'][at], v[0])))
 
         def recur(cache, layer, taps, qkv, g, beta):
             at = self._nth(layer)
             o, state = self._scan(taps, qkv[0], g[0], beta[0], length)
             tail = ops.conv_tail(qkv[0], length, taps.shape[0])
-            return o[None], self._put(
-                cache, layer,
+            return o[None], _served.with_leaves(
+                cache, at,
                 state=cache['state'][at].at[state_row].set(
                     ops.pack_state(state)),
                 tail=cache['tail'][at].at[state_row].set(
@@ -493,26 +443,3 @@ class OlmoHybridLM:
         return (self._logits(params, x_last)[0], cache,
                 self._counters(self.has_state_row(),
                                length * self.has_state_row()))
-
-    # -- what this family has no path for yet --------------------------
-    def _not_yet(self, what):
-        raise NotImplementedError('OlmoHybridLM.%s: not in this family '
-                                  'yet (paged cache, one chip)' % what)
-
-    def init_kv_cache(self, *a, **kw):
-        self._not_yet('init_kv_cache (slot-addressed cache)')
-
-    def prefill(self, *a, **kw):
-        self._not_yet('prefill (slot-addressed cache)')
-
-    def decode_step(self, *a, **kw):
-        self._not_yet('decode_step (slot-addressed cache)')
-
-    def spec_verify(self, *a, **kw):
-        self._not_yet('spec_verify (speculative decoding)')
-
-    def spec_verify_paged(self, *a, **kw):
-        self._not_yet('spec_verify_paged (speculative decoding)')
-
-    def kv_cache_specs(self, *a, **kw):
-        self._not_yet('kv_cache_specs (tensor parallelism)')

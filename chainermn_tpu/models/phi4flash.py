@@ -68,6 +68,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from chainermn_tpu.models import _served
+
 #: leaves that stay float32 whatever dtype the weights take, by name
 #: (the reference's ``F32_LEAVES`` has the same)
 F32_LEAVES = ('A_log', 'D', 'dt_bias', 'lambda_q1', 'lambda_k1',
@@ -75,7 +77,7 @@ F32_LEAVES = ('A_log', 'D', 'dt_bias', 'lambda_q1', 'lambda_k1',
 
 
 @dataclasses.dataclass(frozen=True)
-class Phi4FlashLM:
+class Phi4FlashLM(_served.ServedLM):
     """Hyper-parameters under their published ``config.json`` keys;
     the Mamba sizes (which ``config.json`` does not give) under the
     family's names and defaults."""
@@ -104,7 +106,7 @@ class Phi4FlashLM:
     #: the scan, and the positions of the SHARED K/V leaf it attended,
     #: times the layers that read them
     serve_counters = ('state_rows', 'scan_tokens', 'shared_kv_positions')
-    tp_axis = None
+    family = 'phi4flash'
 
     def __post_init__(self):
         if self.mamba_dt_rank is None:
@@ -128,20 +130,7 @@ class Phi4FlashLM:
                 '%d query heads on %d K/V heads of hidden %d do not '
                 'pair' % (h, hkv, self.hidden_size))
 
-    @classmethod
-    def from_config(cls, cfg, **overrides):
-        """The model of a ``config.json``-shaped dict; keys this class
-        does not know are left where they are."""
-        known = {f.name for f in dataclasses.fields(cls)}
-        kw = {k: v for k, v in cfg.items() if k in known}
-        kw.update(overrides)
-        return cls(**kw)
-
     # -- shapes --------------------------------------------------------
-    @property
-    def max_len(self):
-        return self.max_position_embeddings
-
     @property
     def head_dim(self):
         return self.hidden_size // self.num_attention_heads
@@ -441,21 +430,7 @@ class Phi4FlashLM:
 
     __call__ = apply
 
-    # -- the serving protocol (what GenerationEngine calls) ------------
-    def check_serving(self, **asked):
-        """One refusal for every engine option this family has no path
-        for; ``paged=True`` and greedy decoding is the path there is."""
-        wrong = [name for name, value in sorted(asked.items())
-                 if name != 'paged' and value]
-        if not asked.get('paged'):
-            wrong.insert(0, 'paged=False')
-        if wrong:
-            raise ValueError(
-                'a phi4flash model is served through the paged cache '
-                'only (paged=True, prefix_sharing=False, no '
-                'prefill_chunk, int8_kv, draft model or plan): asked '
-                'for %s' % ', '.join(wrong))
-
+    # -- the serving protocol (``_served.ServedLM``) --------------------
     def init_paged_kv_cache(self, n_pages, page_size, n_window_pages=0,
                             n_state_rows=0, int8_kv=False, dtype=None):
         """``{'k' | 'v': a ring leaf of ``n_window_pages`` a WINDOW
@@ -494,21 +469,9 @@ class Phi4FlashLM:
         page)``, each over all the layers that HOLD one: the full page
         once, whatever the number of layers that read it.  ``cache``
         may be its structs."""
-        def per_row(leaves):
-            return sum(leaf.dtype.itemsize * leaf.size // leaf.shape[0]
-                       for leaf in leaves)
-        return (per_row((cache['k'][-1], cache['v'][-1])),
-                per_row(cache['state'] + cache['tail']),
-                per_row(cache['k'][:-1] + cache['v'][:-1]))
-
-    def _put(self, cache, layer, **leaves):
-        """``cache`` with ``layer``'s named leaves replaced (each
-        written once a call, so the donated buffer is updated where it
-        lies)."""
-        at = self._nth(layer)
-        return dict(cache, **{
-            name: cache[name][:at] + (leaf,) + cache[name][at + 1:]
-            for name, leaf in leaves.items()})
+        return (_served.row_bytes((cache['k'][-1], cache['v'][-1])),
+                _served.row_bytes(cache['state'] + cache['tail']),
+                _served.row_bytes(cache['k'][:-1] + cache['v'][:-1]))
 
     def _tables(self, cache, page_tables):
         """``[full table | ring | state row]`` apart, and the page
@@ -551,7 +514,8 @@ class Phi4FlashLM:
                     cache['k'][at], cache['v'][at], k, v,
                     full_pages if window is None else ring_pages,
                     offsets)
-                cache = self._put(cache, layer, k=k_leaf, v=v_leaf)
+                cache = _served.with_leaves(cache, at, k=k_leaf,
+                                            v=v_leaf)
             return ops.flash_attention_decode_paged(
                 q, cache['k'][at], cache['v'][at],
                 full if window is None else ring, lengths,
@@ -565,7 +529,8 @@ class Phi4FlashLM:
                 lp['conv_bias'])
             m, state = ops.selective_scan_step(
                 cache['state'][at], state_rows, *self._ssm(lp, y))
-            return m, self._put(cache, layer, state=state, tail=tail)
+            return m, _served.with_leaves(cache, at, state=state,
+                                          tail=tail)
 
         x, cache, _ = self._layers(
             params, self._embed(params, tokens), cache, None,
@@ -630,7 +595,7 @@ class Phi4FlashLM:
                     x.reshape((n_pages, ps) + x.shape[1:]), 1, 2)
 
             at = self._nth(layer)
-            return self._put(cache, layer, **{
+            return _served.with_leaves(cache, at, **{
                 name: cache[name][at].at[ids].set(
                     pages_of(new[0]).astype(cache[name][at].dtype))
                 for name, new in (('k', k), ('v', v))})
@@ -645,8 +610,8 @@ class Phi4FlashLM:
             at = self._nth(layer)
             m, state = self._sequence(lp, xt[0], length)
             tail = ops.conv_tail(xt[0], length, self.mamba_d_conv)
-            return m[None], self._put(
-                cache, layer,
+            return m[None], _served.with_leaves(
+                cache, at,
                 state=cache['state'][at].at[state_row].set(
                     ops.pack_state(state[None])),
                 tail=cache['tail'][at].at[state_row].set(
@@ -682,26 +647,3 @@ class Phi4FlashLM:
             range(shared_at, self.num_hidden_layers), attend_last, None)
         return (self._logits(params, x_last)[0], cache, self._counters(
             1, length, length * self._readers))
-
-    # -- what this family has no path for yet --------------------------
-    def _not_yet(self, what):
-        raise NotImplementedError('Phi4FlashLM.%s: not in this family '
-                                  'yet (paged cache, one chip)' % what)
-
-    def init_kv_cache(self, *a, **kw):
-        self._not_yet('init_kv_cache (slot-addressed cache)')
-
-    def prefill(self, *a, **kw):
-        self._not_yet('prefill (slot-addressed cache)')
-
-    def decode_step(self, *a, **kw):
-        self._not_yet('decode_step (slot-addressed cache)')
-
-    def spec_verify(self, *a, **kw):
-        self._not_yet('spec_verify (speculative decoding)')
-
-    def spec_verify_paged(self, *a, **kw):
-        self._not_yet('spec_verify_paged (speculative decoding)')
-
-    def kv_cache_specs(self, *a, **kw):
-        self._not_yet('kv_cache_specs (tensor parallelism)')

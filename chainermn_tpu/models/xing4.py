@@ -53,13 +53,13 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from chainermn_tpu.models import _experts, _mla
+from chainermn_tpu.models import _experts, _mla, _served
 
 _LANES = 128
 
 
 @dataclasses.dataclass(frozen=True)
-class Xing4LM:
+class Xing4LM(_served.ServedLM):
     """Hyper-parameters under their published ``config.json`` keys."""
 
     vocab_size: int = 131072
@@ -99,7 +99,8 @@ class Xing4LM:
                       'latent_positions')
     #: what the engine calls a page of this family on its tick span
     page_counter = 'latent_pages_in_use'
-    tp_axis = None
+    family = 'xing4'
+    cache_name = 'paged latent cache'
 
     def __post_init__(self):
         if isinstance(self.rope_scaling, dict):
@@ -120,20 +121,7 @@ class Xing4LM:
             raise ValueError('kv_lora_rank %d is not whole 128-lane '
                              'tiles' % self.kv_lora_rank)
 
-    @classmethod
-    def from_config(cls, cfg, **overrides):
-        """The model of a ``config.json``-shaped dict; keys this class
-        does not know are left where they are."""
-        known = {f.name for f in dataclasses.fields(cls)}
-        kw = {k: v for k, v in cfg.items() if k in known}
-        kw.update(overrides)
-        return cls(**kw)
-
     # -- shapes --------------------------------------------------------
-    @property
-    def max_len(self):
-        return self.max_position_embeddings
-
     @property
     def qk_head_dim(self):
         return self.qk_nope_head_dim + self.qk_rope_head_dim
@@ -159,13 +147,6 @@ class Xing4LM:
                  * math.log(scaling['factor']) + 1.0)
             scale *= m * m
         return scale
-
-    def window_ring(self, page_size):
-        """No window layer: no ring."""
-        return 0
-
-    def has_state_row(self):
-        return False
 
     def param_shapes(self):
         """The parameter tree as shapes (names are the interface the
@@ -226,10 +207,7 @@ class Xing4LM:
 
     # -- the layer, once -----------------------------------------------
     def _rms(self, x, weight):
-        xf = x.astype(jnp.float32)
-        xf = xf * lax.rsqrt(jnp.mean(jnp.square(xf), -1, keepdims=True)
-                            + self.rms_norm_eps)
-        return (xf * weight.astype(jnp.float32)).astype(self.dtype)
+        return _experts.rms(x, weight, self.rms_norm_eps, self.dtype)
 
     def _inv_freq(self):
         """YaRN's blend of the trained and the interpolated
@@ -368,21 +346,7 @@ class Xing4LM:
 
     __call__ = apply
 
-    # -- the serving protocol (what GenerationEngine calls) ------------
-    def check_serving(self, **asked):
-        """One refusal for every engine option this family has no path
-        for; ``paged=True`` and greedy decoding is the path there is."""
-        wrong = [name for name, value in sorted(asked.items())
-                 if name != 'paged' and value]
-        if not asked.get('paged'):
-            wrong.insert(0, 'paged=False')
-        if wrong:
-            raise ValueError(
-                'a xing4 model is served through the paged latent cache '
-                'only (paged=True, prefix_sharing=False, no '
-                'prefill_chunk, int8_kv, draft model or plan): asked '
-                'for %s' % ', '.join(wrong))
-
+    # -- the serving protocol (``_served.ServedLM``) --------------------
     def init_paged_kv_cache(self, n_pages, page_size, int8_kv=False,
                             dtype=None):
         """``{'latent': one leaf a layer}``, a leaf ``(pages, 1,
@@ -398,15 +362,7 @@ class Xing4LM:
     def paged_cache_bytes(cache):
         """``(bytes of one page over all layers, 0)``: no state row;
         ``cache`` may be its structs."""
-        return sum(leaf.dtype.itemsize * leaf.size // leaf.shape[0]
-                   for leaf in cache['latent']), 0
-
-    @staticmethod
-    def _with_layer(cache, layer, leaf):
-        """``cache`` with ``layer``'s leaf replaced (written once a
-        call, so the donated buffer is updated where it lies)."""
-        leaves = cache['latent']
-        return {'latent': leaves[:layer] + (leaf,) + leaves[layer + 1:]}
+        return _served.row_bytes(cache['latent']), 0
 
     def decode_step_paged(self, params, cache, tokens, positions,
                           page_tables):
@@ -428,7 +384,7 @@ class Xing4LM:
                 cache['latent'][layer], None,
                 self._latent_rows(c, k_r)[:, None, :], None, pages,
                 offsets)
-            cache = self._with_layer(cache, layer, leaf)
+            cache = _served.with_leaves(cache, layer, latent=leaf)
             w_k, w_v = self._kvb(lp)
             # absorbed: the key half of W_kvb goes into the query, the
             # value half onto what comes back
@@ -487,7 +443,7 @@ class Xing4LM:
             leaf = leaf.at[ids].set(
                 rows.reshape(n_pages, 1, ps, -1).astype(leaf.dtype))
             return (self._expanded(lp, q_nope, q_rope, c, k_r),
-                    self._with_layer(cache, layer, leaf))
+                    _served.with_leaves(cache, layer, latent=leaf))
 
         positions = (jnp.asarray(pos0, jnp.int32)
                      + jnp.arange(c_len, dtype=jnp.int32))
@@ -497,26 +453,3 @@ class Xing4LM:
         x_last = lax.dynamic_slice_in_dim(x, length - 1, 1, axis=0)
         return (self._logits(params, x_last)[0], cache,
                 counters + (jnp.zeros((), jnp.float32),))
-
-    # -- what this family has no path for yet --------------------------
-    def _not_yet(self, what):
-        raise NotImplementedError('Xing4LM.%s: not in this family yet '
-                                  '(paged latent cache, one chip)' % what)
-
-    def init_kv_cache(self, *a, **kw):
-        self._not_yet('init_kv_cache (slot-addressed cache)')
-
-    def prefill(self, *a, **kw):
-        self._not_yet('prefill (slot-addressed cache)')
-
-    def decode_step(self, *a, **kw):
-        self._not_yet('decode_step (slot-addressed cache)')
-
-    def spec_verify(self, *a, **kw):
-        self._not_yet('spec_verify (speculative decoding)')
-
-    def spec_verify_paged(self, *a, **kw):
-        self._not_yet('spec_verify_paged (speculative decoding)')
-
-    def kv_cache_specs(self, *a, **kw):
-        self._not_yet('kv_cache_specs (tensor parallelism)')

@@ -456,7 +456,8 @@ class GenerationEngine:
     """Continuous-batching autoregressive server for one causal LM.
     The engine names no model family: the cache constructors and the
     prefill / decode / verify bodies are METHODS OF THE MODEL
-    (``docs/serving.md``, "the model protocol"), which
+    (:class:`~chainermn_tpu.models._served.ServedLM` states them;
+    ``docs/serving.md``, "the model protocol"), which
     :class:`~chainermn_tpu.models.TransformerLM`,
     :class:`~chainermn_tpu.models.AfmoeLM` and
     :class:`~chainermn_tpu.models.OlmoHybridLM` all have.
@@ -665,22 +666,22 @@ class GenerationEngine:
         # over the layers that HOLD one: what the tick's cache-bytes
         # attributes are counted in; a family whose page is not K/V
         # names its count (``page_counter``)
-        self._page_counter = getattr(model, 'page_counter', None)
+        self._page_counter = model.page_counter
         # what of a stored row of the pool a decode call reads is K/V,
-        # where the family says (``serve_decode``'s lane attributes)
+        # where the family says (``serve_decode``'s lane attributes:
+        # none from a family that gives no lanes)
         self._kv_lanes = (
             dict(zip(('kv_live_lanes', 'kv_lanes'),
                      model.kv_lanes(self._cache_struct)))
-            if self.paged and hasattr(model, 'kv_lanes') else {})
+            if self.paged else {})
         self._cache_bytes = (
             model.paged_cache_bytes(self._cache_struct)
             if self.state_pool is not None or self._page_counter
             else None)
         # the compiler's options for the executables, where the family
         # has some for the platform it is served on
-        self._compiler_options = (
-            model.serve_compiler_options(jax.devices()[0].platform)
-            if hasattr(model, 'serve_compiler_options') else {})
+        self._compiler_options = model.serve_compiler_options(
+            jax.devices()[0].platform)
 
         # -- speculative decoding: the draft twin ----------------------
         self.spec_tokens = int(spec_tokens)

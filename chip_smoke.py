@@ -17,20 +17,13 @@ the widths ``bench.py`` uses, with random weights made from a seed:
   and prefill executables at the benchmark's serving shapes
   (gpt2-medium, 2,049 pages of 16), compiled and read for copies of
   the KV page pool -- what only the chip's compiler can show;
-- *serve, afmoe*: a small ``AfmoeLM`` (grouped K/V heads, window and
-  full layers, dropless experts) through the same engine, its served
-  tokens held against the float32 forward; then the same pool check at
-  the ``trinity-mini`` cell's shapes, both kinds of cache leaf;
-- *serve, olmo_hybrid*: a small ``OlmoHybridLM`` (gated-delta-rule
-  layers beside full attention, a recurrent state row a sequence)
-  through the same engine, against the float32 forward; then the pool
-  check at the ``olmo-hybrid-7b`` cell's shapes: K/V pools, state
-  leaves and convolution tails;
-- *serve, xing4* and *serve, phi4flash*: the same pair for the latent
-  (MLA) family and for the Mamba / differential-attention hybrid
-  (``Phi4FlashLM``: full pages, ring pages and a state row in one
-  table; the pool check at the ``phi4-mini-flash`` cell's shapes, the
-  model whole);
+- *serve, a family* of ``FAMILIES`` (``afmoe``, ``olmo_hybrid``,
+  ``xing4``, ``phi4flash``: the families served from the paged cache
+  only): the row's small model, with the family's every mechanism,
+  through the same engine, its served tokens held against the float32
+  forward; then the same pool check at the shapes of the family's
+  benchmark cell, every kind of cache leaf it has (K/V pools, rings,
+  the latent, state rows and convolution tails);
 - ``--chips 4`` runs ONLY the transformer step over four devices
   (data-parallel, then dp 2 x tp 2) and the one-device loss both are
   compared with.
@@ -49,6 +42,7 @@ import argparse
 import collections
 import contextlib
 import dataclasses
+import functools
 import json
 import os
 import sys
@@ -750,7 +744,7 @@ def serving_pool_check(d_model=1024, n_heads=16, n_layers=24,
 
 
 # ----------------------------------------------------------------------
-# the afmoe family
+# the families served from the paged cache only, from one table
 
 #: ``AfmoeLM`` at the ``trinity-mini`` cell's widths and depth
 #: (``chipbench/configs/trinity-mini.json``); the defaults are the
@@ -758,360 +752,205 @@ def serving_pool_check(d_model=1024, n_heads=16, n_layers=24,
 TRINITY_MINI = dict(num_hidden_layers=5, num_dense_layers=1,
                     layer_types=('sliding_attention',) * 4
                     + ('full_attention',))
-
-
-def serving_pool_check_afmoe(n_slots=64, max_prompt=3072, max_len=4096,
-                             page_size=64, prompt_bucket=1024,
-                             **shape):
-    """:func:`_pool_check` at the shapes of the benchmark's
-    ``trinity-mini-serve-closed64`` cell: two kinds of cache leaf,
-    ``(pages, 4, 64, 128)`` with the full layer's 4,097 pages or a
-    window layer's 2,113 (64 rings of 33), each of whose minor pair is
-    one whole bfloat16 tile.  Weights are zeros: nothing runs."""
-    import jax
-    import jax.numpy as jnp
-
-    from chainermn_tpu import serving
-    from chainermn_tpu.models import AfmoeLM
-    from chainermn_tpu.precision import Policy
-
-    model = AfmoeLM(**dict(TRINITY_MINI, **shape))
-    params = jax.tree_util.tree_map(
-        lambda shape: jnp.zeros(shape, jnp.bfloat16),
-        model.param_shapes(), is_leaf=lambda x: isinstance(x, tuple))
-    engine = serving.GenerationEngine(
-        model, params, n_slots=n_slots, max_prompt_len=max_prompt,
-        max_len=max_len, paged=True, page_size=page_size,
-        prefix_sharing=False, policy=Policy.bf16())
-    return _pool_check(
-        engine, 'serve afmoe d%d/L%d %d slots, %d + %d pages of %d'
-        % (model.hidden_size, model.num_hidden_layers, n_slots,
-           engine.n_pages, engine.window_pool.n_pages, page_size),
-        prompt_bucket)
-
-
-def serve_afmoe(hidden=512, heads=8, kv_heads=2, head_dim=128,
-                experts=8, top_k=2, width=256, dense_width=1024,
-                vocab=4096, window=128, page_size=64, n_slots=8,
-                max_prompt=256, max_len=512, max_new=48, n_requests=6,
-                kernels='native'):
-    """A small ``AfmoeLM`` with the family's every mechanism (grouped
-    K/V heads at the published head size, three window layers and a
-    full one behind a dense one, dropless experts beside a shared one)
-    through ``GenerationEngine`` + ``GenerationQueue``: prompts on
-    both sides of the window, every served token held against the
-    float32 kernel-free forward of the same weights."""
-    import jax
-    import jax.numpy as jnp
-
-    from chainermn_tpu import serving
-    from chainermn_tpu.models import AfmoeLM
-    from chainermn_tpu.precision import Policy
-
-    model = AfmoeLM(
-        vocab_size=vocab, hidden_size=hidden, intermediate_size=dense_width,
-        moe_intermediate_size=width, num_attention_heads=heads,
-        num_key_value_heads=kv_heads, head_dim=head_dim,
-        num_experts=experts, num_experts_per_tok=top_k,
-        sliding_window=window, max_position_embeddings=max_len,
-        **TRINITY_MINI)
-    params = model.init(jax.random.PRNGKey(SEED), jnp.bfloat16)
-    rng = np.random.RandomState(SEED)
-    lengths = [3, window - 7, window + 9, max_prompt] + list(
-        rng.randint(4, max_prompt + 1, size=n_requests - 4))
-    prompts = [rng.randint(0, vocab, size=int(n)).astype(np.int32)
-               for n in lengths]
-    what = 'serve afmoe d%d/L5/V%d %d experts top-%d, window %d' % (
-        hidden, vocab, experts, top_k, window)
-    engine = serving.GenerationEngine(
-        model, params, n_slots=n_slots, max_prompt_len=max_prompt,
-        max_len=max_len, paged=True, page_size=page_size,
-        prefix_sharing=False, policy=Policy.bf16())
-    streams = _serve_requests(engine, prompts, max_new, kernels, what)
-    stats = engine.stats()
-    require(stats['peak_window_pages_in_use']
-            <= n_slots * stats['window_ring'],
-            '%s: %d window pages in use, over %d rings of %d'
-            % (what, stats['peak_window_pages_in_use'], n_slots,
-               stats['window_ring']))
-
-    return _served_gaps(
-        model, engine, prompts, streams, max_prompt + max_new, what,
-        '%d window pages at the peak in rings of %d'
-        % (stats['peak_window_pages_in_use'], stats['window_ring']))
-
-
-# ----------------------------------------------------------------------
-# the olmo_hybrid family
-
 #: ``OlmoHybridLM`` at the ``olmo-hybrid-7b`` cell's depth
-#: (``chipbench/configs/olmo-hybrid-7b.json``: two whole periods); the
-#: defaults are the published widths
+#: (``chipbench/configs/olmo-hybrid-7b.json``: two whole periods)
 OLMO_HYBRID = dict(num_hidden_layers=8,
                    layer_types=(('linear_attention',) * 3
                                 + ('full_attention',)) * 2)
-
-
-def serving_pool_check_olmo_hybrid(n_slots=48, max_prompt=3072,
-                                   max_len=4096, page_size=32,
-                                   prompt_bucket=1024, **shape):
-    """:func:`_pool_check` at the shapes of the benchmark's
-    ``olmo-hybrid-serve-closed48`` cell: K/V pools ``(6,145, 30, 32,
-    128)`` for the two full layers only, and per linear layer a state
-    leaf ``(49, 15, 96, 384)`` float32 (two heads side by side in the
-    lanes) and a tail leaf ``(49, 288, 128)`` (3 positions of 96 rows
-    of lanes).  Weights are zeros: nothing runs."""
-    import jax
-    import jax.numpy as jnp
-
-    from chainermn_tpu import serving
-    from chainermn_tpu.models import OlmoHybridLM
-    from chainermn_tpu.precision import Policy
-
-    model = OlmoHybridLM(**dict(OLMO_HYBRID, **shape))
-    params = jax.tree_util.tree_map(
-        lambda shape: jnp.zeros(shape, jnp.bfloat16),
-        model.param_shapes(), is_leaf=lambda x: isinstance(x, tuple))
-    engine = serving.GenerationEngine(
-        model, params, n_slots=n_slots, max_prompt_len=max_prompt,
-        max_len=max_len, paged=True, page_size=page_size,
-        prefix_sharing=False, policy=Policy.bf16())
-    return _pool_check(
-        engine, 'serve olmo_hybrid d%d/L%d %d slots, %d pages of %d, '
-        '%d state rows' % (model.hidden_size, model.num_hidden_layers,
-                           n_slots, engine.n_pages, page_size,
-                           engine.state_pool.n_pages), prompt_bucket)
-
-
-def serve_olmo_hybrid(hidden=512, heads=4, key_dim=96, value_dim=192,
-                      width=1024, vocab=4096, page_size=32, n_slots=8,
-                      max_prompt=256, max_len=512, max_new=48,
-                      n_requests=6, kernels='native'):
-    """A small ``OlmoHybridLM`` with the family's every mechanism (two
-    periods of three gated-delta-rule layers and a full one, the
-    published head sizes 96 / 192 / 128, the convolution) through
-    ``GenerationEngine`` + ``GenerationQueue``: prompts that do and do
-    not fill their bucket, slots and state rows reused, every served
-    token held against the float32 kernel-free forward of the same
-    weights."""
-    import jax
-    import jax.numpy as jnp
-
-    from chainermn_tpu import serving
-    from chainermn_tpu.models import OlmoHybridLM
-    from chainermn_tpu.precision import Policy
-
-    model = OlmoHybridLM(
-        vocab_size=vocab, hidden_size=hidden, intermediate_size=width,
-        num_attention_heads=heads, num_key_value_heads=heads,
-        linear_num_key_heads=heads, linear_num_value_heads=heads,
-        linear_key_head_dim=key_dim, linear_value_head_dim=value_dim,
-        max_position_embeddings=max_len, **OLMO_HYBRID)
-    params = model.init(jax.random.PRNGKey(SEED), jnp.bfloat16)
-    rng = np.random.RandomState(SEED)
-    # on and one over two chunks of the rule (64 at the default size)
-    lengths = [3, max_prompt // 4, max_prompt // 4 + 1, max_prompt] + list(
-        rng.randint(4, max_prompt + 1, size=n_requests - 4))
-    # twice the slots' worth of requests: every slot and row is reused
-    lengths = lengths + lengths[::-1] + lengths[:n_slots // 2]
-    prompts = [rng.randint(0, vocab, size=int(n)).astype(np.int32)
-               for n in lengths]
-    what = 'serve olmo_hybrid d%d/L8/V%d, %d heads of %d x %d' % (
-        hidden, vocab, heads, key_dim, value_dim)
-    engine = serving.GenerationEngine(
-        model, params, n_slots=n_slots, max_prompt_len=max_prompt,
-        max_len=max_len, paged=True, page_size=page_size,
-        prefix_sharing=False, policy=Policy.bf16())
-    streams = _serve_requests(engine, prompts, max_new, kernels, what)
-    stats = engine.stats()
-    require(stats['state_rows_in_use'] == 0
-            and 0 < stats['peak_state_rows_in_use'] <= n_slots,
-            '%s: %d state rows in use after the drain, %d at the peak '
-            'of %d slots' % (what, stats['state_rows_in_use'],
-                             stats['peak_state_rows_in_use'], n_slots))
-
-    return _served_gaps(
-        model, engine, prompts, streams, max_prompt + max_new, what,
-        '%d state rows at the peak' % stats['peak_state_rows_in_use'])
-
-
-# ----------------------------------------------------------------------
-# the xing4 family
-
 #: ``Xing4LM`` at the ``xing4-29b-a4b`` cell's depth
 #: (``chipbench/configs/xing4-29b-a4b.json``: one dense layer and five
-#: expert layers); the other defaults are the published widths
+#: expert layers)
 YARN = dict(type='yarn', factor=64, original_max_position_embeddings=4096,
             beta_fast=32, beta_slow=1, mscale=1, mscale_all_dim=1)
 XING4 = dict(num_hidden_layers=6, first_k_dense_replace=1,
              rope_scaling=YARN)
 
 
-def serving_pool_check_xing4(n_slots=48, max_prompt=6144, max_len=7680,
-                             page_size=64, prompt_bucket=2048, **shape):
-    """:func:`_pool_check` at the shapes of the benchmark's
-    ``xing4-serve-closed48-long`` cell: ONE latent leaf a layer,
-    ``(5,761, 1, 64, 640)`` (a position's 512 + 64 values in one row of
-    five lane tiles), 2.83 GB over six layers beside 9.59 GB of
-    weights.  Weights are zeros: nothing runs."""
+def _window_edges(model, page_size, longest):
+    return model.sliding_window - 7, model.sliding_window + 9
+
+
+#: a family a row.  ``cls``; ``cell``, what the benchmark's cell changes
+#: of the published defaults, and ``engine``, that cell's engine (the
+#: pool check's shapes); ``policy``: does the engine cast the weights
+#: (not where float32 leaves must stay so: ``hc_*``; ``A_log``, ``D``,
+#: the lambdas); ``small``, the model the smoke serves, with the
+#: family's every mechanism; ``page_size`` and ``edges``, two prompt
+#: lengths either side of what the family counts in; ``reuse``: the
+#: requests come twice over, so that every slot, page and row is used
+#: again; ``what``, the model in the phase's lines; of
+#: ``engine.stats()`` after the drain (and ``n_slots``), ``ok``, what
+#: is said when it is not (``short``) and what the cache ``held``.
+FAMILIES = {
+    'afmoe': dict(
+        cls='AfmoeLM', cell=TRINITY_MINI, policy=True,
+        engine=dict(n_slots=64, max_prompt=3072, max_len=4096,
+                    page_size=64, prompt_bucket=1024),
+        # grouped K/V heads at the published head size, three window
+        # layers and a full one behind a dense one, dropless experts
+        small=dict(TRINITY_MINI, hidden_size=512, intermediate_size=1024,
+                   moe_intermediate_size=256, num_attention_heads=8,
+                   num_key_value_heads=2, head_dim=128, num_experts=8,
+                   num_experts_per_tok=2, sliding_window=128),
+        page_size=64, edges=_window_edges, reuse=False,
+        what='{m.num_experts} experts top-{m.num_experts_per_tok}, '
+             'window {m.sliding_window}',
+        ok=lambda s: s['peak_window_pages_in_use']
+        <= s['n_slots'] * s['window_ring'],
+        short='%(peak_window_pages_in_use)d window pages in use, over '
+              '%(n_slots)d rings of %(window_ring)d',
+        held='%(peak_window_pages_in_use)d window pages at the peak in '
+             'rings of %(window_ring)d'),
+    'olmo_hybrid': dict(
+        cls='OlmoHybridLM', cell=OLMO_HYBRID, policy=True,
+        engine=dict(n_slots=48, max_prompt=3072, max_len=4096,
+                    page_size=32, prompt_bucket=1024),
+        # two periods of three gated-delta-rule layers and a full one,
+        # the published head sizes 96 / 192 / 128, the convolution
+        small=dict(OLMO_HYBRID, hidden_size=512, intermediate_size=1024,
+                   num_attention_heads=4, num_key_value_heads=4,
+                   linear_num_key_heads=4, linear_num_value_heads=4),
+        # on and one over two chunks of the rule (64 at the default)
+        page_size=32, reuse=True,
+        edges=lambda model, page_size, longest: (longest // 4,
+                                                 longest // 4 + 1),
+        what='{m.linear_num_value_heads} heads of '
+             '{m.linear_key_head_dim} x {m.linear_value_head_dim}',
+        ok=lambda s: s['state_rows_in_use'] == 0
+        and 0 < s['peak_state_rows_in_use'] <= s['n_slots'],
+        short='%(state_rows_in_use)d state rows in use after the drain, '
+              '%(peak_state_rows_in_use)d at the peak of %(n_slots)d '
+              'slots',
+        held='%(peak_state_rows_in_use)d state rows at the peak'),
+    'xing4': dict(
+        cls='Xing4LM', cell=XING4, policy=False,
+        engine=dict(n_slots=48, max_prompt=6144, max_len=7680,
+                    page_size=64, prompt_bucket=2048),
+        # the published latent (512 + 64 values a position, heads of
+        # 128 + 64 / 128), four streams, a dense layer before two
+        # expert layers; prefill expanded, decode absorbed
+        small=dict(hidden_size=512, intermediate_size=1024,
+                   moe_intermediate_size=256, num_hidden_layers=3,
+                   first_k_dense_replace=1, num_attention_heads=4,
+                   q_lora_rank=192, n_routed_experts=8,
+                   num_experts_per_tok=2, rope_scaling=dict(
+                       YARN, original_max_position_embeddings=64)),
+        page_size=64, reuse=True,
+        edges=lambda model, page_size, longest: (page_size,
+                                                 page_size + 1),
+        what='latent {m.kv_lora_rank} + {m.qk_rope_head_dim}, '
+             '{m.n_routed_experts} experts top-{m.num_experts_per_tok}',
+        ok=lambda s: s['pages_in_use'] == 0,
+        short='%(pages_in_use)d latent pages in use after the drain',
+        held='%(peak_pages_in_use)d latent pages at the peak'),
+    'phi4flash': dict(
+        cls='Phi4FlashLM', cell={}, policy=False,     # the model WHOLE
+        engine=dict(n_slots=96, max_prompt=1024, max_len=5120,
+                    page_size=64, prompt_bucket=1024),
+        # the published head size (pairs of 64: 128-lane K/V rows), two
+        # Mamba / window pairs, the memory layer, the K/V layer, a
+        # gated memory unit and a cross layer, the tied head
+        small=dict(hidden_size=512, intermediate_size=1024,
+                   num_hidden_layers=8, num_attention_heads=8,
+                   num_key_value_heads=4, sliding_window=128),
+        page_size=64, edges=_window_edges, reuse=True,
+        what='{m.num_attention_heads} heads of {m.head_dim}, window '
+             '{m.sliding_window}',
+        ok=lambda s: s['state_rows_in_use'] == s['pages_in_use']
+        == s['window_pages_in_use'] == 0
+        and 0 < s['peak_state_rows_in_use'] <= s['n_slots']
+        and s['peak_window_pages_in_use']
+        <= s['n_slots'] * s['window_ring'],
+        short='after the drain %(state_rows_in_use)d state rows, '
+              '%(pages_in_use)d pages and %(window_pages_in_use)d '
+              'window pages in use',
+        held='%(peak_state_rows_in_use)d state rows and '
+             '%(peak_window_pages_in_use)d window pages at the peak'),
+}
+
+
+def _family_engine(name, model, params, n_slots, max_prompt, max_len,
+                   page_size):
+    from chainermn_tpu import serving
+    from chainermn_tpu.precision import Policy
+
+    return serving.GenerationEngine(
+        model, params, n_slots=n_slots, max_prompt_len=max_prompt,
+        max_len=max_len, paged=True, page_size=page_size,
+        prefix_sharing=False,
+        policy=Policy.bf16() if FAMILIES[name]['policy'] else None)
+
+
+def serving_pool_check_family(name, **shape):
+    """:func:`_pool_check` at the shapes of the family's benchmark cell
+    (``FAMILIES[name]``: the model's ``cell`` at the published widths
+    in the cell's ``engine``; ``shape`` overrides either): every kind
+    of cache leaf the family has -- K/V pools, rings, the latent, state
+    and tail leaves -- under the copy check.  Weights are zeros:
+    nothing runs."""
     import jax
     import jax.numpy as jnp
 
-    from chainermn_tpu import serving
-    from chainermn_tpu.models import Xing4LM
+    from chainermn_tpu import models
 
-    model = Xing4LM(**dict(XING4, **shape))
+    row = FAMILIES[name]
+    sizes = {k: shape.pop(k, v) for k, v in row['engine'].items()}
+    bucket = sizes.pop('prompt_bucket')
+    model = getattr(models, row['cls'])(**dict(row['cell'], **shape))
     params = jax.tree_util.tree_map(
         lambda x: jnp.zeros(x.shape, x.dtype),
         jax.eval_shape(lambda: model.init(jax.random.PRNGKey(SEED),
                                           jnp.bfloat16)))
-    engine = serving.GenerationEngine(
-        model, params, n_slots=n_slots, max_prompt_len=max_prompt,
-        max_len=max_len, paged=True, page_size=page_size,
-        prefix_sharing=False)    # no Policy: the hc_* leaves stay f32
+    engine = _family_engine(name, model, params, **sizes)
+    pools = (('pages', engine.pool), ('window pages', engine.window_pool),
+             ('state rows', engine.state_pool))
     return _pool_check(
-        engine, 'serve xing4 d%d/L%d %d slots, %d latent pages of %d'
-        % (model.hidden_size, model.num_hidden_layers, n_slots,
-           engine.n_pages, page_size), prompt_bucket)
+        engine, 'serve %s d%d/L%d %d slots, pages of %d: %s'
+        % (name, model.hidden_size, model.num_hidden_layers,
+           engine.n_slots, engine.page_size, ', '.join(
+               '%d %s' % (pool.n_pages, kind) for kind, pool in pools
+               if pool is not None)), bucket)
 
 
-def serve_xing4(hidden=512, heads=4, experts=8, top_k=2, width=256,
-                dense_width=1024, q_rank=192, vocab=4096, page_size=64,
-                n_slots=8, max_prompt=256, max_len=512, max_new=48,
-                n_requests=6, kernels='native', **shape):
-    """A small ``Xing4LM`` with the family's every mechanism (the
-    published latent: 512 + 64 values a position, heads of 128 + 64 /
-    128; four streams; a dense layer before two expert layers) through
-    ``GenerationEngine`` + ``GenerationQueue``: prefill expanded,
-    decode absorbed over the latent pages, slots and pages reused,
-    every served token held against the float32 kernel-free forward of
-    the same weights."""
+def serve_family(name, n_slots=8, max_prompt=256, max_len=512,
+                 max_new=48, n_requests=6, page_size=None, vocab_size=4096,
+                 kernels='native', **shape):
+    """The family's ``small`` model (``FAMILIES[name]``; ``shape``
+    overrides its fields) through ``GenerationEngine`` +
+    ``GenerationQueue``: prompts of 3 tokens, of the family's two
+    ``edges``, of the longest the engine takes and of seeded lengths
+    between, every served token held against the float32 kernel-free
+    forward of the same weights (which runs every layer at every
+    position), the pools' counts against what the family may hold."""
     import jax
     import jax.numpy as jnp
 
-    from chainermn_tpu import serving
-    from chainermn_tpu.models import Xing4LM
+    from chainermn_tpu import models
 
-    model = Xing4LM(
-        vocab_size=vocab, hidden_size=hidden,
-        intermediate_size=dense_width, moe_intermediate_size=width,
-        num_hidden_layers=3, first_k_dense_replace=1,
-        num_attention_heads=heads, q_lora_rank=q_rank,
-        n_routed_experts=experts, num_experts_per_tok=top_k,
-        rope_scaling=dict(YARN, original_max_position_embeddings=64),
-        max_position_embeddings=max_len, **shape)
+    row = FAMILIES[name]
+    page_size = page_size or row['page_size']
+    model = getattr(models, row['cls'])(**dict(
+        row['small'], vocab_size=vocab_size,
+        max_position_embeddings=max_len, **shape))
     params = model.init(jax.random.PRNGKey(SEED), jnp.bfloat16)
     rng = np.random.RandomState(SEED)
-    lengths = [3, page_size, page_size + 1, max_prompt] + list(
+    lengths = [3, *row['edges'](model, page_size, max_prompt),
+               max_prompt] + list(
         rng.randint(4, max_prompt + 1, size=n_requests - 4))
-    lengths = lengths + lengths[::-1] + lengths[:n_slots // 2]
-    prompts = [rng.randint(0, vocab, size=int(n)).astype(np.int32)
+    if row['reuse']:
+        lengths = lengths + lengths[::-1] + lengths[:n_slots // 2]
+    prompts = [rng.randint(0, vocab_size, size=int(n)).astype(np.int32)
                for n in lengths]
-    what = 'serve xing4 d%d/L3/V%d, latent %d + %d, %d experts top-%d' % (
-        hidden, vocab, model.kv_lora_rank, model.qk_rope_head_dim,
-        experts, top_k)
-    engine = serving.GenerationEngine(
-        model, params, n_slots=n_slots, max_prompt_len=max_prompt,
-        max_len=max_len, paged=True, page_size=page_size,
-        prefix_sharing=False)    # no Policy: the hc_* leaves stay f32
+    what = 'serve %s d%d/L%d/V%d, %s' % (
+        name, model.hidden_size, model.num_hidden_layers, vocab_size,
+        row['what'].format(m=model))
+    engine = _family_engine(name, model, params, n_slots, max_prompt,
+                            max_len, page_size)
     streams = _serve_requests(engine, prompts, max_new, kernels, what)
-    stats = engine.stats()
-    require(stats['pages_in_use'] == 0,
-            '%s: %d latent pages in use after the drain'
-            % (what, stats['pages_in_use']))
-    return _served_gaps(
-        model, engine, prompts, streams, max_prompt + max_new, what,
-        '%d latent pages at the peak' % stats['peak_pages_in_use'])
-
-
-# ----------------------------------------------------------------------
-# the phi4flash family
-
-def serving_pool_check_phi4flash(n_slots=96, max_prompt=1024,
-                                 max_len=5120, page_size=64,
-                                 prompt_bucket=1024, **shape):
-    """:func:`_pool_check` at the shapes of the benchmark's
-    ``phi4flash-serve-closed96-think`` cell, the model WHOLE (7.7 GB of
-    zero weights): ONE full K/V leaf pair ``(7,681, 10, 64, 128)`` (K/V
-    heads packed by pair: 128 lanes) that layer 17 writes and 8 layers
-    read, 8 window layers' ring leaves ``(865, 10, 64, 128)``, and per
-    Mamba layer a state leaf ``(97, 1, 16, 5120)`` float32 and a tail
-    leaf ``(97, 144, 128)``.  Weights are zeros: nothing runs."""
-    import jax
-    import jax.numpy as jnp
-
-    from chainermn_tpu import serving
-    from chainermn_tpu.models import Phi4FlashLM
-
-    model = Phi4FlashLM(**shape)
-    params = jax.tree_util.tree_map(
-        lambda x: jnp.zeros(x.shape, x.dtype),
-        jax.eval_shape(lambda: model.init(jax.random.PRNGKey(SEED),
-                                          jnp.bfloat16)))
-    engine = serving.GenerationEngine(
-        model, params, n_slots=n_slots, max_prompt_len=max_prompt,
-        max_len=max_len, paged=True, page_size=page_size,
-        prefix_sharing=False)    # no Policy: A_log, D, lambdas stay f32
-    return _pool_check(
-        engine, 'serve phi4flash d%d/L%d %d slots, %d + %d pages of %d, '
-        '%d state rows' % (model.hidden_size, model.num_hidden_layers,
-                           n_slots, engine.n_pages,
-                           engine.window_pool.n_pages, page_size,
-                           engine.state_pool.n_pages), prompt_bucket)
-
-
-def serve_phi4flash(hidden=512, heads=8, kv_heads=4, width=1024,
-                    layers=8, vocab=4096, window=128, page_size=64,
-                    n_slots=8, max_prompt=256, max_len=512, max_new=48,
-                    n_requests=6, kernels='native'):
-    """A small ``Phi4FlashLM`` with the family's every mechanism at the
-    published head size (pairs of 64: 128-lane K/V rows): two Mamba /
-    window pairs, the memory layer, the K/V layer, a gated memory unit
-    and a cross layer, the tied head; through ``GenerationEngine`` +
-    ``GenerationQueue``: prompts on both sides of the window, slots,
-    pages, ring pages and state rows reused, every served token held
-    against the float32 kernel-free forward of the same weights (which
-    runs every layer at every position)."""
-    import jax
-    import jax.numpy as jnp
-
-    from chainermn_tpu import serving
-    from chainermn_tpu.models import Phi4FlashLM
-
-    model = Phi4FlashLM(
-        vocab_size=vocab, hidden_size=hidden, intermediate_size=width,
-        num_hidden_layers=layers, num_attention_heads=heads,
-        num_key_value_heads=kv_heads, sliding_window=window,
-        max_position_embeddings=max_len)
-    params = model.init(jax.random.PRNGKey(SEED), jnp.bfloat16)
-    rng = np.random.RandomState(SEED)
-    lengths = [3, window - 7, window + 9, max_prompt] + list(
-        rng.randint(4, max_prompt + 1, size=n_requests - 4))
-    lengths = lengths + lengths[::-1] + lengths[:n_slots // 2]
-    prompts = [rng.randint(0, vocab, size=int(n)).astype(np.int32)
-               for n in lengths]
-    what = 'serve phi4flash d%d/L%d/V%d, %d heads of %d, window %d' % (
-        hidden, layers, vocab, heads, model.head_dim, window)
-    engine = serving.GenerationEngine(
-        model, params, n_slots=n_slots, max_prompt_len=max_prompt,
-        max_len=max_len, paged=True, page_size=page_size,
-        prefix_sharing=False)    # no Policy: A_log, D, lambdas stay f32
-    streams = _serve_requests(engine, prompts, max_new, kernels, what)
-    stats = engine.stats()
-    require(stats['state_rows_in_use'] == 0 and stats['pages_in_use'] == 0
-            and stats['window_pages_in_use'] == 0
-            and 0 < stats['peak_state_rows_in_use'] <= n_slots
-            and stats['peak_window_pages_in_use']
-            <= n_slots * stats['window_ring'],
-            '%s: after the drain %d state rows, %d pages and %d window '
-            'pages in use' % (what, stats['state_rows_in_use'],
-                              stats['pages_in_use'],
-                              stats['window_pages_in_use']))
-    return _served_gaps(
-        model, engine, prompts, streams, max_prompt + max_new, what,
-        '%d state rows and %d window pages at the peak'
-        % (stats['peak_state_rows_in_use'],
-           stats['peak_window_pages_in_use']))
+    stats = dict(engine.stats(), n_slots=n_slots)
+    require(row['ok'](stats), '%s: %s' % (what, row['short'] % stats))
+    return _served_gaps(model, engine, prompts, streams,
+                        max_prompt + max_new, what, row['held'] % stats)
 
 
 # ----------------------------------------------------------------------
@@ -1247,17 +1086,13 @@ def main(argv=None):
             phases = [('train_resnet', train_resnet),
                       ('train_transformer', train_transformer),
                       ('serve', serve),
-                      ('serving_pool', serving_pool_check),
-                      ('serve_afmoe', serve_afmoe),
-                      ('serving_pool_afmoe', serving_pool_check_afmoe),
-                      ('serve_olmo_hybrid', serve_olmo_hybrid),
-                      ('serving_pool_olmo_hybrid',
-                       serving_pool_check_olmo_hybrid),
-                      ('serve_xing4', serve_xing4),
-                      ('serving_pool_xing4', serving_pool_check_xing4),
-                      ('serve_phi4flash', serve_phi4flash),
-                      ('serving_pool_phi4flash',
-                       serving_pool_check_phi4flash)]
+                      ('serving_pool', serving_pool_check)]
+            for name in FAMILIES:
+                phases += [
+                    ('serve_' + name,
+                     functools.partial(serve_family, name)),
+                    ('serving_pool_' + name,
+                     functools.partial(serving_pool_check_family, name))]
             if args.phases:
                 asked = args.phases.split(',')
                 unknown = set(asked) - {name for name, _ in phases}

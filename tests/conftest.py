@@ -19,6 +19,7 @@ if '--xla_force_host_platform_device_count' not in _flags:
         _flags + ' --xla_force_host_platform_device_count=8').strip()
 
 import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
 
 jax.config.update('jax_default_matmul_precision', 'highest')
 
@@ -101,3 +102,32 @@ def flat_params(updater):
     return np.concatenate([
         np.asarray(leaf).ravel() for leaf in
         jax.tree_util.tree_leaves(jax.device_get(updater.params))])
+
+
+def mlp_setup(n_units=16, n_in=48, n_out=10, seed=0):
+    """A seeded MLP for the serving suites: ``(model, params, apply_fn,
+    one zero example)``."""
+    import numpy as np
+
+    from chainermn_tpu.models import MLP
+    model = MLP(n_units=n_units, n_out=n_out)
+    params = model.init(jax.random.PRNGKey(seed),
+                        jnp.zeros((1, n_in)))['params']
+
+    def apply_fn(p, x):
+        return model.apply({'params': p}, x)
+
+    return model, params, apply_fn, np.zeros((n_in,), np.float32)
+
+
+def tiny_lm(dtype=jnp.float32, n_layers=1, max_len=64, d_model=32,
+            n_heads=4):
+    """A seeded one-layer ``TransformerLM`` for the generation suites:
+    ``(model, params)``."""
+    from chainermn_tpu.models import TransformerLM
+    model = TransformerLM(vocab_size=32, d_model=d_model,
+                          n_heads=n_heads, n_layers=n_layers, d_ff=32,
+                          max_len=max_len, dtype=dtype)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 4), jnp.int32))['params']
+    return model, params

@@ -145,120 +145,77 @@ def test_serve_streams_may_part_only_at_a_near_tie(monkeypatch):
         check(model, params, prompts, slab, got, 'runner-up, decided')
 
 
-TINY_AFMOE = dict(hidden=32, heads=4, kv_heads=2, head_dim=8, experts=8,
-                  top_k=2, width=16, dense_width=48, vocab=64, window=8,
-                  page_size=4)
+#: each family's model at toy widths, under its own field names: what
+#: ``serve_family`` lays over the table's ``small`` model and
+#: ``serving_pool_check_family`` over its ``cell``
+TINY = {
+    'afmoe': dict(
+        vocab_size=64, hidden_size=32, intermediate_size=48,
+        moe_intermediate_size=16, num_attention_heads=4,
+        num_key_value_heads=2, head_dim=8, num_experts=8,
+        num_experts_per_tok=2, sliding_window=8, page_size=4),
+    'olmo_hybrid': dict(
+        vocab_size=64, hidden_size=64, intermediate_size=96,
+        num_attention_heads=4, num_key_value_heads=4,
+        linear_num_key_heads=4, linear_num_value_heads=4,
+        linear_key_head_dim=32, linear_value_head_dim=64, page_size=4),
+    'xing4': dict(
+        vocab_size=64, hidden_size=32, intermediate_size=48,
+        moe_intermediate_size=16, num_attention_heads=4, q_lora_rank=24,
+        kv_lora_rank=128, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=16, n_routed_experts=8, num_experts_per_tok=2,
+        page_size=8),
+    'phi4flash': dict(
+        vocab_size=64, hidden_size=64, intermediate_size=96,
+        num_hidden_layers=8, num_attention_heads=4,
+        num_key_value_heads=2, sliding_window=8, page_size=4),
+}
 
 
-def test_serve_afmoe_phase_tiny(interpret):
-    out = chip_smoke.serve_afmoe(n_slots=3, max_prompt=24, max_len=48,
-                                 max_new=12, n_requests=5,
-                                 kernels='interpret', **TINY_AFMOE)
-    assert len(out['streams']) == 5
+@pytest.mark.parametrize('family', sorted(TINY))
+def test_serve_family_phase_tiny(family, interpret):
+    out = chip_smoke.serve_family(
+        family, n_slots=3, max_prompt=24, max_len=48, max_new=12,
+        n_requests=5, kernels='interpret', **TINY[family])
+    # every slot (ring, state row) reused: 5 + 5 + 1 requests on 3
+    reused = chip_smoke.FAMILIES[family]['reuse']
+    assert len(out['streams']) == (11 if reused else 5)
     assert all(len(s) == 12 for s in out['streams'])
     assert out['gap_mean'] < 0.01
 
 
-def test_serving_pool_afmoe_phase_tiny():
+@pytest.mark.parametrize('family', sorted(TINY))
+def test_serving_pool_family_phase_tiny(family):
     """As ``test_serving_pool_phase_tiny``: on the CPU the check must
     bite (a bfloat16 scatter goes through pool-shaped ``convert``
-    instructions there); both kinds of leaf are named first."""
+    instructions there, the jnp twins of the steps gather and scatter
+    the state leaves through pool-shaped values); every kind of leaf
+    the family has is named first."""
+    depth = {'xing4': dict(num_hidden_layers=2)}.get(family, {})
     with pytest.raises(chip_smoke.SmokeFailure,
                        match='makes pool-shaped values'):
-        chip_smoke.serving_pool_check_afmoe(
-            n_slots=2, max_prompt=8, max_len=32, page_size=4,
-            prompt_bucket=8, vocab_size=64, hidden_size=32,
-            intermediate_size=48, moe_intermediate_size=16,
-            num_attention_heads=4, num_key_value_heads=2, head_dim=8,
-            num_experts=8, num_experts_per_tok=2, sliding_window=8)
+        chip_smoke.serving_pool_check_family(
+            family, n_slots=2, max_prompt=8, max_len=32,
+            prompt_bucket=8, **dict(TINY[family], **depth))
 
 
-TINY_OLMO = dict(hidden=64, heads=4, key_dim=32, value_dim=64, width=96,
-                 vocab=64, page_size=4)
-
-
-def test_serve_olmo_hybrid_phase_tiny(interpret):
-    out = chip_smoke.serve_olmo_hybrid(
-        n_slots=3, max_prompt=24, max_len=48, max_new=12, n_requests=5,
-        kernels='interpret', **TINY_OLMO)
-    # every slot and state row reused: 5 + 5 + 1 requests on 3 slots
-    assert len(out['streams']) == 11
-    assert all(len(s) == 12 for s in out['streams'])
-    assert out['gap_mean'] < 0.01
-
-
-def test_serving_pool_olmo_hybrid_phase_tiny():
-    """As ``test_serving_pool_afmoe_phase_tiny``: on the CPU the check
-    must bite (the jnp twin of the step gathers and scatters the state
-    leaf through pool-shaped values); the three kinds of leaf are named
-    first."""
-    with pytest.raises(chip_smoke.SmokeFailure,
-                       match='makes pool-shaped values'):
-        chip_smoke.serving_pool_check_olmo_hybrid(
-            n_slots=2, max_prompt=8, max_len=32, page_size=4,
-            prompt_bucket=8, vocab_size=64, hidden_size=64,
-            intermediate_size=96, num_attention_heads=4,
-            num_key_value_heads=4, linear_num_key_heads=4,
-            linear_num_value_heads=4, linear_key_head_dim=32,
-            linear_value_head_dim=64)
-
-
-TINY_XING4 = dict(hidden=32, heads=4, experts=8, top_k=2, width=16,
-                  dense_width=48, q_rank=24, vocab=64, page_size=8,
-                  kv_lora_rank=128, qk_nope_head_dim=16,
-                  qk_rope_head_dim=8, v_head_dim=16)
-
-
-def test_serve_xing4_phase_tiny(interpret):
-    out = chip_smoke.serve_xing4(
-        n_slots=3, max_prompt=24, max_len=48, max_new=12, n_requests=5,
-        kernels='interpret', **TINY_XING4)
-    # every slot reused: 5 + 5 + 1 requests on 3 slots
-    assert len(out['streams']) == 11
-    assert all(len(s) == 12 for s in out['streams'])
-    assert out['gap_mean'] < 0.01
-
-
-def test_serving_pool_xing4_phase_tiny():
-    """As ``test_serving_pool_afmoe_phase_tiny``: on the CPU the check
-    must bite (the jnp twin of the append is a scatter through
-    pool-shaped ``convert`` instructions there); the latent leaf is
-    named first."""
-    with pytest.raises(chip_smoke.SmokeFailure,
-                       match='makes pool-shaped values'):
-        chip_smoke.serving_pool_check_xing4(
-            n_slots=2, max_prompt=8, max_len=32, page_size=8,
-            prompt_bucket=8, vocab_size=64, hidden_size=32,
-            intermediate_size=48, moe_intermediate_size=16,
-            num_hidden_layers=2, num_attention_heads=4, q_lora_rank=24,
-            kv_lora_rank=128, qk_nope_head_dim=16, qk_rope_head_dim=8,
-            v_head_dim=16, n_routed_experts=8, num_experts_per_tok=2)
-
-
-def test_serve_phi4flash_phase_tiny(interpret):
-    out = chip_smoke.serve_phi4flash(
-        hidden=64, heads=4, kv_heads=2, width=96, vocab=64, window=8,
-        page_size=4, n_slots=3, max_prompt=24, max_len=48, max_new=12,
-        n_requests=5, kernels='interpret')
-    # every slot, ring and state row reused: 5 + 5 + 1 requests on 3
-    assert len(out['streams']) == 11
-    assert all(len(s) == 12 for s in out['streams'])
-    assert out['gap_mean'] < 0.01
-
-
-def test_serving_pool_phi4flash_phase_tiny():
-    """As ``test_serving_pool_olmo_hybrid_phase_tiny``: on the CPU the
-    check must bite (the jnp twins gather and scatter the leaves
-    through pool-shaped values); the four kinds of leaf are named
-    first."""
-    with pytest.raises(chip_smoke.SmokeFailure,
-                       match='makes pool-shaped values'):
-        chip_smoke.serving_pool_check_phi4flash(
-            n_slots=2, max_prompt=8, max_len=32, page_size=4,
-            prompt_bucket=8, vocab_size=64, hidden_size=64,
-            intermediate_size=96, num_hidden_layers=8,
-            num_attention_heads=4, num_key_value_heads=2,
-            sliding_window=8)
+def test_the_phases_keep_their_names(monkeypatch, capsys):
+    ran = []
+    monkeypatch.setattr(
+        chip_smoke, 'check_device',
+        lambda chips: {'platform': 'tpu', 'kind': 'none', 'count': 1})
+    monkeypatch.setattr(chip_smoke, 'serve_family',
+                        lambda name: ran.append('serve ' + name))
+    monkeypatch.setattr(chip_smoke, 'serving_pool_check_family',
+                        lambda name: ran.append('pool ' + name))
+    monkeypatch.delenv('CHAINERMN_TPU_PALLAS', raising=False)
+    assert chip_smoke.main(['--phases', ','.join(
+        kind + name for name in TINY
+        for kind in ('serve_', 'serving_pool_'))]) == 0
+    assert ran == [kind + name for name in chip_smoke.FAMILIES
+                   for kind in ('serve ', 'pool ')]
+    assert list(chip_smoke.FAMILIES) == [
+        'afmoe', 'olmo_hybrid', 'xing4', 'phi4flash']
 
 
 def test_phases_option_names_an_unknown_phase(monkeypatch, capsys):

@@ -619,472 +619,6 @@ class TestIncrementalDecode:
                                        rtol=1e-5, atol=1e-5)
 
 
-class TestPagedDecode:
-    """Paged-KV parity pins (this PR's tentpole): prefill_paged /
-    decode_step_paged through a pooled cache addressed by page tables
-    must reproduce the full-sequence causal forward -- f32 rtol 1e-5,
-    int8-KV 5e-2 -- with non-contiguous tables, across chunked
-    prefill, across page REUSE (dirty pages from a previous
-    occupant), across a shared-prefix table (two sequences reading
-    the same physical pages), and composed with tp=2 shard_map."""
-
-    PS = 8
-
-    #: the pool's layouts: name -> (d_model, n_heads, int8_kv, rtol).
-    #: A float pool is head-major with ``pack`` heads a 128-lane row
-    #: (1: a head of 8 padded to the lanes; 2: two of 64; 4: four of
-    #: 32); an int8 pool stays page-major with its scale leaves.
-    LAYOUTS = {'pack1': (32, 4, False, 1e-5),
-               'pack2': (128, 2, False, 1e-5),
-               'pack4': (128, 4, False, 1e-5),
-               'int8': (32, 4, True, 5e-2)}
-
-    @pytest.fixture(params=sorted(LAYOUTS))
-    def layout(self, request):
-        d_model, n_heads, int8_kv, rtol = self.LAYOUTS[request.param]
-        model = self._model(d_model=d_model, n_heads=n_heads)
-        packs = {'pack1': 1, 'pack2': 2, 'pack4': 4}
-        if not int8_kv:
-            leaf = self._cache(model, False, n_pages=2)['k'][0]
-            assert leaf.shape == (
-                2, n_heads // packs[request.param], self.PS, 128)
-        return model, int8_kv, rtol
-
-    def _model(self, dtype=jnp.float32, max_len=64, d_model=32,
-               n_heads=4):
-        return TransformerLM(vocab_size=64, d_model=d_model,
-                             n_heads=n_heads, n_layers=2, d_ff=64,
-                             max_len=max_len, dtype=dtype)
-
-    def _cache(self, model, int8_kv, n_pages):
-        from chainermn_tpu.models import init_paged_kv_cache
-        return init_paged_kv_cache(model, n_pages=n_pages,
-                                   page_size=self.PS, int8_kv=int8_kv)
-
-    def _stepwise(self, model, params, cache, toks, t_pre, table,
-                  chunk=None, start=0):
-        """Prefill ``toks[start:t_pre]`` in ``chunk``-token pieces
-        (whole remainder when None) through ``table``, then
-        teacher-force the rest via decode_step_paged; returns
-        (logits at each position >= t_pre - 1, cache)."""
-        from chainermn_tpu.models import (decode_step_paged,
-                                          prefill_paged)
-        width = chunk or (t_pre - start)
-        out = {}
-        pos = start
-        while pos < t_pre:
-            n = min(width, t_pre - pos)
-            pad = np.zeros((1, width), np.int32)
-            pad[0, :n] = toks[pos:pos + n]
-            lg, cache = prefill_paged(
-                model, params, cache, jnp.asarray(pad),
-                jnp.asarray(n, jnp.int32),
-                jnp.asarray(table, jnp.int32),
-                jnp.asarray(pos, jnp.int32))
-            pos += n
-        out[t_pre - 1] = np.asarray(lg)
-        for p in range(t_pre, len(toks)):
-            lg, cache = decode_step_paged(
-                model, params, cache,
-                jnp.asarray([toks[p]], jnp.int32),
-                jnp.asarray([p], jnp.int32),
-                jnp.asarray([table], jnp.int32))
-            out[p] = np.asarray(lg[0])
-        return out, cache
-
-    def test_matches_full_forward(self, layout):
-        model, int8_kv, rtol = layout
-        rng = np.random.RandomState(10)
-        toks = rng.randint(0, 64, size=20).astype(np.int32)
-        params = model.init(jax.random.PRNGKey(1),
-                            jnp.asarray([toks]))['params']
-        full = np.asarray(model.apply({'params': params},
-                                      jnp.asarray([toks])))[0]
-        cache = self._cache(model, int8_kv, n_pages=9)
-        # deliberately non-contiguous, non-monotone table
-        table = np.array([5, 2, 7, 1, 3, 8, 4, 6], np.int32)
-        got, _ = self._stepwise(model, params, cache, toks,
-                                t_pre=6, table=table)
-        for p, lg in got.items():
-            np.testing.assert_allclose(lg, full[p], rtol=rtol,
-                                       atol=rtol)
-
-    def test_chunked_prefill_identical_logits(self, layout):
-        """Chunking is a schedule, not an approximation: prefilling
-        in 4-token chunks (every other one ends mid-page) must
-        produce the SAME first-token logits and decode trajectory as
-        one monolithic prefill (an int8 pool: to its rounding, a later
-        chunk reads the earlier ones' K/V dequantized)."""
-        model, int8_kv, rtol = layout
-        rng = np.random.RandomState(11)
-        toks = rng.randint(0, 64, size=18).astype(np.int32)
-        params = model.init(jax.random.PRNGKey(1),
-                            jnp.asarray([toks]))['params']
-        table = np.array([3, 1, 4, 2, 5], np.int32)
-        mono, _ = self._stepwise(
-            model, params, self._cache(model, int8_kv, 6), toks,
-            t_pre=13, table=table)
-        chunked, _ = self._stepwise(
-            model, params, self._cache(model, int8_kv, 6), toks,
-            t_pre=13, table=table, chunk=4)
-        tol = rtol if int8_kv else 1e-6
-        for p in mono:
-            np.testing.assert_allclose(chunked[p], mono[p],
-                                       rtol=tol, atol=tol)
-
-    def test_parity_across_page_reuse(self, layout):
-        """Reclaim safety: sequence B prefilled through pages A just
-        DIRTIED (no zeroing) must reproduce B's fresh-pool logits
-        exactly -- reads mask by live length, never by page history."""
-        model, int8_kv, _ = layout
-        rng = np.random.RandomState(12)
-        tok_a = rng.randint(0, 64, size=20).astype(np.int32)
-        tok_b = rng.randint(0, 64, size=11).astype(np.int32)
-        params = model.init(jax.random.PRNGKey(1),
-                            jnp.asarray([tok_a]))['params']
-        cache = self._cache(model, int8_kv, n_pages=4)
-        table = np.array([2, 1, 3], np.int32)
-        _, cache = self._stepwise(model, params, cache, tok_a,
-                                  t_pre=7, table=table)
-        got_b, _ = self._stepwise(model, params, cache, tok_b,
-                                  t_pre=5, table=table)
-        fresh = self._cache(model, int8_kv, n_pages=4)
-        want_b, _ = self._stepwise(model, params, fresh, tok_b,
-                                   t_pre=5, table=table)
-        for p in got_b:
-            np.testing.assert_allclose(got_b[p], want_b[p],
-                                       rtol=1e-6, atol=1e-6)
-
-    def test_shared_prefix_pages_reproduce(self, layout):
-        """Prefix sharing numerics: sequence B's table points at the
-        pages sequence A banked for their common 2-page prefix; B
-        prefills ONLY its suffix (pos0 = 16) into private pages.
-        B's logits must match its own full forward -- reading a
-        neighbor's physical pages is invisible to the math."""
-        model, int8_kv, rtol = layout
-        rng = np.random.RandomState(13)
-        shared = rng.randint(0, 64, size=16).astype(np.int32)
-        tok_a = np.concatenate(
-            [shared, rng.randint(0, 64, size=6).astype(np.int32)])
-        tok_b = np.concatenate(
-            [shared, rng.randint(0, 64, size=8).astype(np.int32)])
-        params = model.init(jax.random.PRNGKey(1),
-                            jnp.asarray([tok_a]))['params']
-        cache = self._cache(model, int8_kv, n_pages=6)
-        table_a = np.array([1, 2, 3], np.int32)
-        _, cache = self._stepwise(model, params, cache, tok_a,
-                                  t_pre=20, table=table_a)
-        # B: A's prefix pages 1,2 + a private tail page 4
-        table_b = np.array([1, 2, 4], np.int32)
-        got_b, _ = self._stepwise(model, params, cache, tok_b,
-                                  t_pre=20, table=table_b, start=16)
-        full_b = np.asarray(model.apply({'params': params},
-                                        jnp.asarray([tok_b])))[0]
-        for p, lg in got_b.items():
-            np.testing.assert_allclose(lg, full_b[p], rtol=rtol,
-                                       atol=rtol)
-
-    def test_copied_tail_page_then_divergent_decode(self, layout):
-        """Copy-on-write numerics: B shares A's 12-token prefix, whose
-        second page is HALF full.  The engine's page copy (every
-        leaf's page ``src`` to ``dst``, the first axis in both
-        layouts) gives B its own tail page; B banks its suffix from
-        ``pos0 = 12``, mid-page, and both decode on, each matching its
-        own full forward: B's writes keep what the copied page held
-        before ``pos0`` and never reach A's."""
-        model, int8_kv, rtol = layout
-        rng = np.random.RandomState(15)
-        shared = rng.randint(0, 64, size=12).astype(np.int32)
-        tok_a = np.concatenate(
-            [shared, rng.randint(0, 64, size=9).astype(np.int32)])
-        tok_b = np.concatenate(
-            [shared, rng.randint(0, 64, size=10).astype(np.int32)])
-        params = model.init(jax.random.PRNGKey(1),
-                            jnp.asarray([tok_a]))['params']
-        cache = self._cache(model, int8_kv, n_pages=7)
-        table_a = np.array([1, 2, 3], np.int32)
-        _, cache = self._stepwise(model, params, cache, tok_a,
-                                  t_pre=12, table=table_a)
-        cache = jax.tree_util.tree_map(
-            lambda leaf: leaf.at[5].set(leaf[2]), cache)
-        table_b = np.array([1, 5, 6], np.int32)
-        got_b, cache = self._stepwise(model, params, cache, tok_b,
-                                      t_pre=17, table=table_b,
-                                      start=12)
-        got_a, _ = self._stepwise(model, params, cache, tok_a,
-                                  t_pre=15, table=table_a, start=12)
-        for toks, got in ((tok_a, got_a), (tok_b, got_b)):
-            full = np.asarray(model.apply({'params': params},
-                                          jnp.asarray([toks])))[0]
-            for p, lg in got.items():
-                np.testing.assert_allclose(lg, full[p], rtol=rtol,
-                                           atol=rtol)
-
-    @pytest.mark.slow
-    @pytest.mark.parametrize('int8_kv,rtol', [(False, 1e-5),
-                                              (True, 5e-2)])
-    def test_tp_paged_decode_matches_oracle(self, int8_kv, rtol):
-        """The paged x tp composition pin: prefill_paged +
-        decode_step_paged under shard_map tp=2 must match the
-        unsharded f32 full forward, with int8 pages within the int8
-        5e-2 budget (kv_cache_specs shards either pool on its head
-        axis: axis 1 of a float leaf, axis 2 of an int8 one)."""
-        from chainermn_tpu.models import (
-            decode_step_paged, init_paged_kv_cache, kv_cache_specs,
-            prefill_paged, tp_param_specs)
-        from chainermn_tpu.parallel.meshplan import MeshPlan
-        if jax.device_count() < 2:
-            pytest.skip('needs 2 devices')
-        plan = MeshPlan.create(tp=2)
-        model = self._model().clone(tp_axis=plan.model_axis)
-        oracle = self._model()
-        rng = np.random.RandomState(14)
-        toks = rng.randint(0, 64, size=(1, 14)).astype(np.int32)
-        params = oracle.init(jax.random.PRNGKey(1),
-                             jnp.asarray(toks))['params']
-        full = np.asarray(oracle.apply({'params': params},
-                                       jnp.asarray(toks)))[0]
-        specs = tp_param_specs(params, plan.model_axis)
-        cache = init_paged_kv_cache(oracle, n_pages=4,
-                                    page_size=self.PS, int8_kv=int8_kv)
-        cspecs = kv_cache_specs(cache, plan.model_axis)
-        assert cspecs['k'][0] == (P(None, None, plan.model_axis, None)
-                                  if int8_kv else
-                                  P(None, plan.model_axis, None, None))
-        pp = jax.device_put(params, plan.param_shardings(specs))
-        cd = jax.device_put(cache, plan.param_shardings(cspecs))
-        pre = jax.shard_map(
-            lambda p, c, t, n, tab, o: prefill_paged(
-                model, p, c, t, n, tab, o),
-            mesh=plan.mesh,
-            in_specs=(specs, cspecs, P(), P(), P(), P()),
-            out_specs=(P(), cspecs), check_vma=False)
-        dec = jax.shard_map(
-            lambda p, c, t, pos, tab: decode_step_paged(
-                model, p, c, t, pos, tab),
-            mesh=plan.mesh,
-            in_specs=(specs, cspecs, P(), P(), P()),
-            out_specs=(P(), cspecs), check_vma=False)
-        table = np.array([2, 1, 3], np.int32)
-        lg, cd = pre(pp, cd, jnp.asarray(toks[:, :9]),
-                     jnp.asarray(9, jnp.int32),
-                     jnp.asarray(table, jnp.int32),
-                     jnp.asarray(0, jnp.int32))
-        np.testing.assert_allclose(np.asarray(lg), full[8],
-                                   rtol=rtol, atol=rtol)
-        for p in range(9, 14):
-            lg, cd = dec(pp, cd, jnp.asarray(toks[:, p]),
-                         jnp.full((1,), p, jnp.int32),
-                         jnp.asarray(table[None], jnp.int32))
-            np.testing.assert_allclose(np.asarray(lg)[0], full[p],
-                                       rtol=rtol, atol=rtol)
-
-
-class TestPagedPoolLayout:
-    """The float paged pool's layout (PR 44): head-major, ``pack``
-    heads side by side in a 128-lane row, ``pack`` from the shapes
-    alone; what every paged entry point reads and writes through it
-    equals the slot cache's path, which it does not touch."""
-
-    @pytest.mark.parametrize('d_head,heads,tp,page', [
-        (64, 16, 1, (8, 16, 128)),      # gpt2-medium: two a row
-        (32, 16, 1, (4, 16, 128)),      # four a row
-        (128, 16, 1, (16, 16, 128)),    # a head fills the lanes
-        (96, 16, 1, (16, 16, 128)),     # 96 does not divide 128: padded
-        (64, 3, 1, (3, 16, 128)),       # an odd number of heads: padded
-        (64, 16, 2, (4, 16, 128)),      # tp 2 of 16 heads: 8 local
-        (64, 16, 16, (1, 16, 128)),     # one local head: nothing to pair
-    ])
-    def test_pack_comes_from_the_shapes(self, d_head, heads, tp, page):
-        from chainermn_tpu.models import init_paged_kv_cache
-        model = TransformerLM(vocab_size=64, d_model=d_head * heads,
-                              n_heads=heads, n_layers=1, d_ff=64,
-                              max_len=64, dtype=jnp.bfloat16)
-        cache = jax.eval_shape(
-            lambda: init_paged_kv_cache(model, 5, 16, tp=tp))
-        # the layout is in the cache itself: an entry with no leaf
-        assert set(cache) == {'k', 'v', 'head_major'}
-        assert len(jax.tree_util.tree_leaves(cache)) == 2
-        assert cache['k'][0].shape == cache['v'][0].shape == (5,) + page
-        # the GLOBAL pool that shards ``tp`` ways: ``tp`` such pools
-        # side by side on the head axis, the rows laid out for a shard
-        if heads % tp == 0:
-            whole = jax.eval_shape(
-                lambda: init_paged_kv_cache(model, 5, 16, shards=tp))
-            assert whole['k'][0].shape == (5, tp * page[0]) + page[1:]
-        # an int8 pool: page-major, every head padded to the lanes
-        cache = jax.eval_shape(
-            lambda: init_paged_kv_cache(model, 5, 16, tp=tp,
-                                        int8_kv=True))
-        assert 'head_major' not in cache
-        assert cache['k'][0].shape == (5, 16, heads // tp, 128)
-        assert cache['k_scale'][0].shape == (5, 16, heads // tp)
-
-    @pytest.mark.parametrize('dtype,page_size,head_major', [
-        (jnp.bfloat16, 16, True), (jnp.bfloat16, 32, True),
-        (jnp.bfloat16, 8, False), (jnp.bfloat16, 24, False),
-        (jnp.float32, 8, True), (jnp.float32, 4, False)])
-    def test_a_page_off_the_sublane_tile_stays_page_major(
-            self, dtype, page_size, head_major):
-        """The kernel's head-major branch carries ONE page a grid step
-        where the page is not whole sublane tiles of the pool's dtype
-        (16 rows of bfloat16, 8 of float32) and its page-major branch
-        eight: such a float pool keeps the page-major layout, and
-        ``decode_paged_grid`` counts that branch's steps."""
-        import importlib
-
-        from chainermn_tpu import ops
-        from chainermn_tpu.models import init_paged_kv_cache, kv_cache_specs
-        fa = importlib.import_module('chainermn_tpu.ops.flash_attention')
-        model = TransformerLM(vocab_size=64, d_model=1024, n_heads=16,
-                              n_layers=2, d_ff=64, max_len=1024,
-                              dtype=dtype)
-        cache = jax.eval_shape(
-            lambda: init_paged_kv_cache(model, 9, page_size))
-        assert ('head_major' in cache) == head_major
-        assert cache['k'][0].shape == (
-            (9, 8, page_size, 128) if head_major
-            else (9, page_size, 16, 128))
-        assert model.kv_lanes(cache) == ((128, 128) if head_major
-                                         else (64, 128))
-        assert kv_cache_specs(cache, 'm')['k'][0] == (
-            P(None, 'm', None, None) if head_major
-            else P(None, None, 'm', None))
-        n_max = 1024 // page_size
-        page = cache['k'][0].shape[1:]
-        assert fa._paged_pages_per_step(
-            page, dtype, n_max, head_major=head_major) > 1
-        lengths = [1, 100, 1000]
-        assert model.decode_paged_grid(cache, lengths, n_max) == tuple(
-            2 * n for n in ops.decode_paged_grid(
-                lengths, page, dtype, n_max, head_major=head_major))
-
-    def test_pool_bytes_halve_at_d_head_64(self):
-        """gpt2-medium's pool: 48 leaves of bf16[2049,8,16,128], half
-        the bytes of the lane-padded (2049, 16, 16, 128); the slot
-        cache keeps its layout."""
-        from chainermn_tpu.models import (init_kv_cache,
-                                          init_paged_kv_cache)
-        model = TransformerLM(vocab_size=64, d_model=1024, n_heads=16,
-                              n_layers=24, d_ff=64, max_len=1024,
-                              dtype=jnp.bfloat16)
-        cache = jax.eval_shape(
-            lambda: init_paged_kv_cache(model, 2049, 16))
-        leaves = jax.tree_util.tree_leaves(cache)
-        assert {(leaf.shape, leaf.dtype.name) for leaf in leaves} == {
-            ((2049, 8, 16, 128), 'bfloat16')}
-        nbytes = sum(leaf.size * leaf.dtype.itemsize for leaf in leaves)
-        assert nbytes == 48 * 2049 * 16 * 16 * 64 * 2
-        assert 2 * nbytes == 48 * 2049 * 16 * 16 * 128 * 2
-        assert model.kv_lanes(cache) == (128, 128)
-        int8 = jax.eval_shape(
-            lambda: init_paged_kv_cache(model, 2049, 16, int8_kv=True))
-        assert model.kv_lanes(int8) == (64, 128)
-        slot = jax.eval_shape(lambda: init_kv_cache(model, 2, 32))
-        assert slot['k'][0].shape == (2, 32, 16, 128)
-
-    @pytest.mark.parametrize('d_model,heads,pack,ps', [
-        (64, 2, 1, 8), (128, 2, 2, 8), (128, 4, 4, 8), (192, 2, 1, 8),
-        (128, 2, None, 4), (64, 2, None, 4)])
-    def test_every_paged_entry_point_equals_the_slot_oracle(
-            self, d_model, heads, pack, ps):
-        """Three rows through both caches: a prompt banked whole, one
-        in two chunks (``pos0 > 0``, the first chunk ending mid-page),
-        decode steps across a page boundary, then a verify window: the
-        paged logits are the slot cache's.  ``pack`` None: pages of 4
-        float32 positions, a float pool that stays page-major."""
-        from chainermn_tpu import models as M
-        model = TransformerLM(vocab_size=97, d_model=d_model,
-                              n_heads=heads, n_layers=2, d_ff=128,
-                              max_len=64, dtype=jnp.float32)
-        params = model.init(jax.random.PRNGKey(0),
-                            jnp.zeros((1, 8), jnp.int32))['params']
-        n_max = 32 // ps
-        cache = M.init_paged_kv_cache(model, 1 + 3 * n_max, ps)
-        assert cache['k'][0].shape == (
-            (1 + 3 * n_max, ps, heads, 128) if pack is None
-            else (1 + 3 * n_max, heads // pack, ps, 128))
-        slot = M.init_kv_cache(model, 3, 32)
-        toks = jax.random.randint(jax.random.PRNGKey(1), (3, 22), 0, 97)
-        tables = jnp.asarray(1 + np.random.RandomState(0).permutation(
-            3 * n_max).reshape(3, n_max), jnp.int32)
-
-        def close(a, b):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=2e-5, atol=2e-5)
-
-        for i in range(3):
-            want, slot = M.prefill(
-                model, params, slot,
-                jnp.pad(toks[i:i + 1, :11], ((0, 0), (0, 5))), 11, i)
-            if i == 0:
-                got, cache = M.prefill_paged(
-                    model, params, cache,
-                    jnp.pad(toks[:1, :11], ((0, 0), (0, 5))), 11,
-                    tables[0], 0)
-            else:
-                # 7 + 4: the first chunk ends mid-page
-                _, cache = M.prefill_paged(
-                    model, params, cache,
-                    jnp.pad(toks[i:i + 1, :7], ((0, 0), (0, 1))), 7,
-                    tables[i], 0)
-                got, cache = M.prefill_paged(
-                    model, params, cache,
-                    jnp.pad(toks[i:i + 1, 7:11], ((0, 0), (0, 4))), 4,
-                    tables[i], 7)
-            close(got, want)
-        for p in range(11, 18):
-            at = jnp.full((3,), p, jnp.int32)
-            want, slot = M.decode_step(model, params, slot, toks[:, p],
-                                       at)
-            got, cache = M.decode_step_paged(model, params, cache,
-                                             toks[:, p], at, tables)
-            close(got, want)
-        at = jnp.full((3,), 18, jnp.int32)
-        want, _ = M.spec_verify(model, params, slot, toks[:, 18:22], at)
-        got, _ = M.spec_verify_paged(model, params, cache,
-                                     toks[:, 18:22], at, tables)
-        close(got, want)
-
-    def test_decode_paged_grid_is_the_kernels_own(self):
-        """The engine's counters at the cell's shapes: pages read and
-        grid steps are those of the kernel's head-major call on the
-        packed page, 16 pages a step; an int8 pool's those of the
-        page-major call at its own rule's pages."""
-        import importlib
-
-        from chainermn_tpu import ops
-        from chainermn_tpu.models import init_paged_kv_cache
-        fa = importlib.import_module('chainermn_tpu.ops.flash_attention')
-        model = TransformerLM(vocab_size=64, d_model=1024, n_heads=16,
-                              n_layers=24, d_ff=64, max_len=1024,
-                              dtype=jnp.bfloat16)
-        lengths = [1, 16, 17, 255, 256, 257, 700, 1024]
-        for int8_kv, page, dtype in (
-                (False, (8, 16, 128), jnp.bfloat16),
-                (True, (16, 16, 128), jnp.int8)):
-            cache = jax.eval_shape(lambda: init_paged_kv_cache(
-                model, 2049, 16, int8_kv=int8_kv))
-            pages = fa._paged_pages_per_step(page, dtype, 64, int8_kv,
-                                             not int8_kv)
-            assert int8_kv or pages == 16
-            read, steps = ops.decode_paged_grid(
-                lengths, page, dtype, 64, quantized=int8_kv,
-                head_major=not int8_kv)
-            assert model.decode_paged_grid(cache, lengths, 64) == (
-                24 * read, 24 * steps)
-            assert read == sum(-(-n // 16) for n in lengths)
-            assert steps == sum(-(-n // (16 * pages)) for n in lengths)
-            # under tp 2 a chip holds half the heads of every page
-            half = page[:int8_kv] + (page[int8_kv] // 2,) \
-                + page[int8_kv + 1:]
-            assert model.decode_paged_grid(
-                cache, lengths, 64, tp=2) == tuple(
-                    24 * n for n in ops.decode_paged_grid(
-                        lengths, half, dtype, 64, quantized=int8_kv,
-                        head_major=not int8_kv))
-
-
 class TestSpecVerify:
     """Speculative-decoding verify twin (ISSUE 19): ``spec_verify`` /
     ``spec_verify_paged`` score a k-token window in ONE pass and must
