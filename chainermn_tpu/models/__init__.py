@@ -25,6 +25,7 @@ from chainermn_tpu.models.olmo_hybrid import OlmoHybridLM  # noqa
 from chainermn_tpu.models.xing4 import Xing4LM  # noqa
 from chainermn_tpu.models.phi4flash import Phi4FlashLM  # noqa
 from chainermn_tpu.models.deepseek_v3 import DeepseekV3LM  # noqa
+from chainermn_tpu.models.solar_open2 import SolarOpen2LM  # noqa
 from chainermn_tpu.models.transformer import (  # noqa
     TransformerLM, TransformerBlock, decode_step, decode_step_paged,
     init_kv_cache, init_paged_kv_cache, kv_cache_specs, lm_loss,

@@ -30,6 +30,27 @@ Three forms of it:
   through VMEM, the leaf its aliased output; elsewhere a gather, the
   update and a scatter.
 
+A decay PER KEY CHANNEL (Kimi Delta Attention, Kimi Linear,
+arXiv:2510.26692): ``g`` one rank higher, ``(T, H, dk)``, and ``S~ =
+diag(exp(g_t)) S_{t-1}``.  The three forms read the rank off ``g`` and
+each keeps a body of its own for it (the scalar bodies are untouched: a
+``(c, c)`` pair matrix a head is cheaper than what follows).  In chunks
+the decay now sits INSIDE the contraction, ``A_ij = sum_d k_i[d] k_j[d]
+exp(G_i[d] - G_j[d])`` with ``G`` the running sum of ``g``; the obvious
+factoring ``(k_i exp(G_i)) . (k_j exp(-G_j))`` overflows float32 within
+a chunk (``exp(g)`` 1e-3 a step on one channel is ``exp(-G)`` 1e48
+after 16 steps).  No positive difference is ever exponentiated here
+(:func:`_channel_pairs`): a chunk is cut into sub-blocks of 16; a pair
+in two sub-blocks is factored through the LATER sub-block's first
+position ``r``, ``exp(G_i - G_r) * exp(G_r - G_j)``, both at most 1 (a
+factor that underflows belongs to a product that is smaller still); a
+pair inside one sub-block takes the three-index form ``(16, 16, dk)``,
+masked before the exponential.  All of a call's chunks are solved at
+once, and the three-index arrays are 524,288 B a position at 64 heads
+of 128: a caller takes a long prompt through in segments, the state
+carried from one to the next (``state0``), as ``models/solar_open2.py``
+does 1,024 positions at a time.
+
 The state leaf's shape is :func:`state_shape`: ``(rows, H / P, dk,
 P * dv)`` float32, ``P`` heads side by side in the lanes so that the
 minor dim is a whole number of 128-lane tiles (``dv`` 192: two heads,
@@ -54,6 +75,9 @@ _VMEM_LIMIT = 32 * 1024 * 1024
 #: rule at 30 heads of 96 x 192 took 0.68 / 0.91 / 0.85 ms (1,024
 #: positions) and 3.27 / 3.43 / 5.11 ms (3,072) at 32 / 64 / 128
 CHUNK = 32
+#: the same for a decay per key channel: four sub-blocks of 16, and half
+#: the dependent steps of the scan over chunks
+CHANNEL_CHUNK = 64
 
 
 # ----------------------------------------------------------------------
@@ -214,17 +238,19 @@ def unpack_state(s, heads):
 
 def _one_step(s, q, k, v, g, beta):
     """The update on ``s`` (..., dk, dv) with ``q`` / ``k`` (..., dk),
-    ``v`` (..., dv), ``g`` / ``beta`` (...): ``(o, s)``."""
-    s = s * jnp.exp(g)[..., None, None]
+    ``v`` (..., dv), ``beta`` (...) and ``g`` (...) or, a decay per key
+    channel, (..., dk): ``(o, s)``."""
+    s = s * (jnp.exp(g)[..., None] if g.ndim == k.ndim
+             else jnp.exp(g)[..., None, None])
     u = (v - jnp.einsum('...kv,...k->...v', s, k)) * beta[..., None]
     s = s + k[..., :, None] * u[..., None, :]
     return jnp.einsum('...kv,...k->...v', s, q), s
 
 
 def gated_delta_reference(q, k, v, g, beta, state0=None):
-    """``q`` / ``k`` (T, H, dk), ``v`` (T, H, dv), ``g`` / ``beta``
-    (T, H), ``state0`` (H, dk, dv) or zeros: ``(o (T, H, dv), state)``
-    in float32, one position at a time."""
+    """``q`` / ``k`` (T, H, dk), ``v`` (T, H, dv), ``beta`` (T, H),
+    ``g`` (T, H) or (T, H, dk), ``state0`` (H, dk, dv) or zeros: ``(o
+    (T, H, dv), state)`` in float32, one position at a time."""
     f32 = jnp.float32
     t, h, dk = q.shape
     if state0 is None:
@@ -285,9 +311,103 @@ def _unit_lower_inverse(a):
     return blocks[..., 0, :, :]
 
 
+def _channel_pairs(qc, kc, kb, decay):
+    """A chunk's two pair matrices with the decay INSIDE the
+    contraction: ``a_ij = sum_d kb_i[d] k_j[d] exp(G_i[d] - G_j[d])``
+    for ``j < i`` (the triangular system's) and ``attn_ij``, the same
+    of ``q_i`` for ``j <= i``.  Operands (H, n, c, dk) float32,
+    ``decay`` the running sums ``G`` (falling, <= 0).  No exponent is
+    positive (the module's note): a pair inside a sub-block of 16 takes
+    the three-index form, a pair across two is factored through the
+    later one's first position."""
+    f32 = jnp.float32
+    h, n, c, dk = kc.shape
+    sub = min(c, _SOLVE_BLOCK)
+    m = c // sub
+    qs, ks, bs, gs = (x.reshape(h, n, m, sub, dk)
+                      for x in (qc, kc, kb, decay))
+    lower = jnp.tril(jnp.ones((sub, sub), bool))
+    # (.., i, j, d): k_j exp(G_i - G_j), masked BEFORE the exponential
+    ke = ks[..., None, :, :] * jnp.exp(jnp.where(
+        lower[:, :, None], gs[..., :, None, :] - gs[..., None, :, :],
+        -jnp.inf))
+    a_diag = jnp.where(jnp.tril(lower, -1),
+                       jnp.sum(bs[..., :, None, :] * ke, -1), 0.0)
+    attn_diag = jnp.sum(qs[..., :, None, :] * ke, -1)
+    dot = functools.partial(jnp.einsum, preferred_element_type=f32)
+    a_rows, attn_rows = [], []
+    for i in range(m):
+        a_row, attn_row = [a_diag[..., i, :, :]], [attn_diag[..., i, :, :]]
+        if i:
+            first = gs[..., i, :1, :]                  # G at r
+            into = jnp.exp(gs[..., i, :, :] - first)   # r -> i, <= 1
+            out_of = kc[..., :i * sub, :] * jnp.exp(
+                first - decay[..., :i * sub, :])       # j -> r, <= 1
+            a_row.insert(0, dot(
+                'hnid,hnjd->hnij', bs[..., i, :, :] * into, out_of,
+                precision=lax.Precision.HIGHEST))
+            attn_row.insert(0, dot('hnid,hnjd->hnij',
+                                   qs[..., i, :, :] * into, out_of))
+        if i < m - 1:
+            zeros = jnp.zeros((h, n, sub, (m - 1 - i) * sub), f32)
+            a_row.append(zeros)
+            attn_row.append(zeros)
+        a_rows.append(jnp.concatenate(a_row, -1))
+        attn_rows.append(jnp.concatenate(attn_row, -1))
+    return jnp.concatenate(a_rows, -2), jnp.concatenate(attn_rows, -2)
+
+
+def _channel_rule(q, k, v, g, beta, state):
+    """The chunked rule with a decay per key channel, ``g`` (T, H, dk)
+    (float32, masked past the prompt's length by the caller): every
+    chunk of ``CHANNEL_CHUNK`` positions' system at once, then the
+    state through the chunks in turn."""
+    f32 = jnp.float32
+    t, h, dk = q.shape
+    dv = v.shape[-1]
+    c = min(CHANNEL_CHUNK, 1 << (t - 1).bit_length())
+    pad = -t % c
+    n = (t + pad) // c
+
+    def chunks(x):
+        # (T, H, ...) -> (H, n, c, ...); a padded position is an
+        # identity step (g = 0, beta = 0)
+        x = jnp.pad(x.astype(f32),
+                    ((0, pad),) + ((0, 0),) * (x.ndim - 1))
+        return jnp.moveaxis(x.reshape((n, c) + x.shape[1:]), 2, 0)
+
+    qc, kc, vc, gc, bc = (chunks(x) for x in (q, k, v, g, beta))
+    dot = functools.partial(jnp.einsum, preferred_element_type=f32)
+    decay = jnp.cumsum(gc, axis=2)                 # (H, n, c, dk), <= 0
+    kb = kc * bc[..., None]
+    a, attn = _channel_pairs(qc, kc, kb, decay)
+    # u = T (beta v), w = T (beta exp(G) k), T = (I + a)^-1
+    rhs = jnp.concatenate([vc * bc[..., None], kb * jnp.exp(decay)], -1)
+    solved = dot('hnij,hnjd->hnid', _unit_lower_inverse(a), rhs,
+                 precision=lax.Precision.HIGHEST)
+    u, w = solved[..., :dv], solved[..., dv:]
+    qd = qc * jnp.exp(decay)
+    kd = kc * jnp.exp(decay[..., -1:, :] - decay)
+    last = jnp.exp(decay[..., -1, :])              # (H, n, dk)
+
+    def body(s, x):
+        w_i, u_i, qd_i, kd_i, attn_i, last_i = x
+        v_new = u_i - dot('hck,hkv->hcv', w_i, s)
+        o = dot('hck,hkv->hcv', qd_i, s) + dot('hij,hjv->hiv', attn_i,
+                                               v_new)
+        s = s * last_i[:, :, None] + dot('hck,hcv->hkv', kd_i, v_new)
+        return s, o
+
+    state, o = lax.scan(body, state, tuple(
+        jnp.moveaxis(x, 1, 0) for x in (w, u, qd, kd, attn, last)))
+    # (n, H, c, dv) -> (T, H, dv)
+    return jnp.moveaxis(o, 1, 2).reshape(n * c, h, dv)[:t], state
+
+
 def gated_delta_rule(q, k, v, g, beta, state0=None, length=None):
     """The same function as :func:`gated_delta_reference`, in chunks of
-    ``CHUNK`` positions; positions at or past ``length`` change
+    ``CHUNK`` positions (``g`` (T, H, dk): of ``CHANNEL_CHUNK``, by
+    :func:`_channel_rule`); positions at or past ``length`` change
     nothing (their ``o`` is what the final state gives their ``q``).
     The big products take their operands as stored (bfloat16 in
     serving) and accumulate in float32; the decays, the triangular
@@ -298,10 +418,12 @@ def gated_delta_rule(q, k, v, g, beta, state0=None, length=None):
     g, beta = g.astype(f32), beta.astype(f32)
     if length is not None:
         live = jnp.arange(t) < length
-        g = jnp.where(live[:, None], g, 0.0)
+        g = jnp.where(live.reshape((t,) + (1,) * (g.ndim - 1)), g, 0.0)
         beta = jnp.where(live[:, None], beta, 0.0)
     if state0 is None:
         state0 = jnp.zeros((h, dk, dv), f32)
+    if g.ndim == 3:
+        return _channel_rule(q, k, v, g, beta, state0.astype(f32))
     c = min(CHUNK, 1 << (t - 1).bit_length())
     pad = -t % c
     n = (t + pad) // c
@@ -357,11 +479,13 @@ def gated_delta_rule(q, k, v, g, beta, state0=None, length=None):
 # ----------------------------------------------------------------------
 
 def _step_kernel(rows_ref, qt_ref, kt_ref, v_ref, decay_ref, beta_ref,
-                 s_ref, o_ref, s_out_ref, *, pack, dv):
+                 s_ref, o_ref, s_out_ref, *, pack, dv, channel):
     """One row: every head group's ``(dk, P * dv)`` tile through the
     update.  ``qt`` / ``kt`` are (dk, H): a head's column is broadcast
     over its ``dv`` lanes; ``v``, the decay and ``beta`` come spread
-    over the lanes already, (H / P, P * dv)."""
+    over the lanes already, (H / P, P * dv).  ``channel``: the decay is
+    per key channel and comes as ``kt`` does, a column over ``dk`` a
+    head."""
     del rows_ref
     groups = s_ref.shape[1]
     lane = lax.broadcasted_iota(jnp.int32, (1, pack * dv), 1)
@@ -375,7 +499,8 @@ def _step_kernel(rows_ref, qt_ref, kt_ref, v_ref, decay_ref, beta_ref,
 
     for group in range(groups):
         kx, qx = columns(kt_ref, group), columns(qt_ref, group)
-        s = s_ref[0, group] * decay_ref[0, group:group + 1]
+        s = s_ref[0, group] * (columns(decay_ref, group) if channel
+                               else decay_ref[0, group:group + 1])
         u = (v_ref[0, group:group + 1]
              - jnp.sum(s * kx, axis=0, keepdims=True)) \
             * beta_ref[0, group:group + 1]
@@ -405,11 +530,15 @@ def _step_pallas(state, rows, q, k, v, g, beta):
     cols, wide = row((dk, h)), row((groups, lanes))
     leaf = pl.BlockSpec((1, groups, dk, lanes),
                         lambda i, rows: (rows[i], 0, 0, 0))
+    channel = g.ndim == 3
+    decay = jnp.exp(g.astype(f32))
     o, state = pl.pallas_call(
-        functools.partial(_step_kernel, pack=pack, dv=dv),
+        functools.partial(_step_kernel, pack=pack, dv=dv,
+                          channel=channel),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(n,),
-            in_specs=[cols, cols, wide, wide, wide, leaf],
+            in_specs=[cols, cols, wide, cols if channel else wide, wide,
+                      leaf],
             out_specs=[wide, leaf]),
         out_shape=[jax.ShapeDtypeStruct((n, groups, lanes), f32),
                    jax.ShapeDtypeStruct(state.shape, state.dtype)],
@@ -422,7 +551,8 @@ def _step_pallas(state, rows, q, k, v, g, beta):
         name='gated_delta_step',
     )(rows.astype(jnp.int32),
       jnp.swapaxes(q.astype(f32), 1, 2), jnp.swapaxes(k.astype(f32), 1, 2),
-      v.astype(f32).reshape(n, groups, lanes), spread(jnp.exp(g)),
+      v.astype(f32).reshape(n, groups, lanes),
+      jnp.swapaxes(decay, 1, 2) if channel else spread(decay),
       spread(beta), state)
     return o.reshape(n, h, dv), state
 
@@ -430,7 +560,8 @@ def _step_pallas(state, rows, q, k, v, g, beta):
 def gated_delta_step(state, rows, q, k, v, g, beta):
     """One position a row.  ``state``: the leaf of :func:`state_shape`;
     ``rows`` (N,) the row of each sequence in it; ``q`` / ``k`` (N, H,
-    dk), ``v`` (N, H, dv), ``g`` / ``beta`` (N, H).  Returns ``(o (N,
+    dk), ``v`` (N, H, dv), ``beta`` (N, H), ``g`` (N, H) or, a decay
+    per key channel, (N, H, dk).  Returns ``(o (N,
     H, dv) float32, state)``: the rows updated in place, nothing else of
     the leaf moved.  Rows that share a state row (idle rows on row 0)
     overwrite each other, as a scatter's would."""

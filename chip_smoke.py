@@ -18,8 +18,9 @@ the widths ``bench.py`` uses, with random weights made from a seed:
   (gpt2-medium, 2,049 pages of 16), compiled and read for copies of
   the KV page pool -- what only the chip's compiler can show;
 - *serve, a family* of ``FAMILIES`` (``afmoe``, ``olmo_hybrid``,
-  ``xing4``, ``phi4flash``: the families served from the paged cache
-  only): the row's small model, with the family's every mechanism,
+  ``xing4``, ``phi4flash``, ``solar_open2``: the families served from
+  the paged cache only): the row's small model, with the family's
+  every mechanism,
   through the same engine, its served tokens held against the float32
   forward; then the same pool check at the shapes of the family's
   benchmark cell, every kind of cache leaf it has (K/V pools, rings,
@@ -764,6 +765,12 @@ YARN = dict(type='yarn', factor=64, original_max_position_embeddings=4096,
             beta_fast=32, beta_slow=1, mscale=1, mscale_all_dim=1)
 XING4 = dict(num_hidden_layers=6, first_k_dense_replace=1,
              rope_scaling=YARN)
+#: ``SolarOpen2LM`` at the ``solar-open2-250b`` cell's share
+#: (``chipbench/configs/solar-open2-250b.json``: one period, 40 of 320
+#: experts, an eighth of the vocabulary)
+SOLAR_OPEN2 = dict(num_hidden_layers=4, gqa_layers=(0,),
+                   n_routed_experts=40, router_experts=320,
+                   vocab_size=24576)
 
 
 def _window_edges(model, page_size, longest):
@@ -866,6 +873,34 @@ FAMILIES = {
               'window pages in use',
         held='%(peak_state_rows_in_use)d state rows and '
              '%(peak_window_pages_in_use)d window pages at the peak'),
+    'solar_open2': dict(
+        cls='SolarOpen2LM', cell=SOLAR_OPEN2, policy=True,
+        engine=dict(n_slots=64, max_prompt=12288, max_len=13824,
+                    page_size=64, prompt_bucket=2048),
+        # one period: a gated gqa layer (8 query on 2 K/V heads of the
+        # published 128) and three Kimi-delta layers (heads of 128 x
+        # 128, a decay per key channel, the three convolutions), every
+        # layer sparse: a share of 4 of the router's 16 experts
+        small=dict(hidden_size=512, moe_intermediate_size=256,
+                   num_hidden_layers=4, num_attention_heads=8,
+                   num_key_value_heads=2,
+                   linear_attn_config=dict(num_heads=4),
+                   n_routed_experts=4, router_experts=16, first_expert=4,
+                   num_experts_per_tok=2),
+        # on and one over a chunk of the per-channel rule (64)
+        page_size=64, reuse=True,
+        edges=lambda model, page_size, longest: (longest // 4,
+                                                 longest // 4 + 1),
+        what='{m.linear_heads} kda heads of {m.linear_head_dim}, '
+             '{m.n_routed_experts} of {m.router_width} experts '
+             'top-{m.num_experts_per_tok}',
+        ok=lambda s: s['state_rows_in_use'] == s['pages_in_use'] == 0
+        and 0 < s['peak_state_rows_in_use'] <= s['n_slots'],
+        short='%(state_rows_in_use)d state rows and %(pages_in_use)d '
+              'pages in use after the drain, '
+              '%(peak_state_rows_in_use)d rows at the peak of '
+              '%(n_slots)d slots',
+        held='%(peak_state_rows_in_use)d state rows at the peak'),
 }
 
 

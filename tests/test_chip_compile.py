@@ -570,6 +570,21 @@ SERVED = {
         nominal=2 * (7681 + 8 * 865) * 10 * 64 * 128 * 2
         + 9 * 97 * (16 * 5120 * 4 + 144 * 128 * 2),
         arguments=12.9e9),
+    # solar-open2-250b's share as the cell holds it, one period: the
+    # gqa layer's K/V pools (8 heads of 128: the two minor dims a whole
+    # tile), three kda layers' state leaves, a head a 128-lane tile
+    # (4,194,304 B a row), and the 24,576 channels' tails; 6.62 GB of
+    # weights beside 4.47 GB of cache.  Decode: an expert kernel a
+    # layer, attention + the append, two steps a kda layer (the state's
+    # with the decay a column over dk); prefill: the expert kernels and
+    # the flash forward at group 8, the per-channel rule is XLA's
+    'solar_open2': dict(
+        depth={},
+        leaves={(13825, 8, 64, 128), (65, 64, 128, 128), (65, 576, 128)},
+        counts=(1, 3, 3), scratch=(13825, 8, 64, 128), calls=(12, 5),
+        nominal=2 * 13825 * 8 * 64 * 128 * 2
+        + 3 * 65 * (64 * 128 * 128 * 4 + 576 * 128 * 2),
+        arguments=11.1e9, kernels=('grouped_swiglu',)),
 }
 
 

@@ -169,6 +169,12 @@ TINY = {
         vocab_size=64, hidden_size=64, intermediate_size=96,
         num_hidden_layers=8, num_attention_heads=4,
         num_key_value_heads=2, sliding_window=8, page_size=4),
+    'solar_open2': dict(
+        vocab_size=64, hidden_size=64, moe_intermediate_size=32,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+        linear_attn_config=dict(head_dim=16, num_heads=4),
+        n_routed_experts=4, router_experts=16, num_experts_per_tok=2,
+        page_size=4),
 }
 
 
@@ -215,7 +221,7 @@ def test_the_phases_keep_their_names(monkeypatch, capsys):
     assert ran == [kind + name for name in chip_smoke.FAMILIES
                    for kind in ('serve ', 'pool ')]
     assert list(chip_smoke.FAMILIES) == [
-        'afmoe', 'olmo_hybrid', 'xing4', 'phi4flash']
+        'afmoe', 'olmo_hybrid', 'xing4', 'phi4flash', 'solar_open2']
 
 
 def test_phases_option_names_an_unknown_phase(monkeypatch, capsys):

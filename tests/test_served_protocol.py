@@ -43,6 +43,12 @@ TINY = {
         vocab_size=97, hidden_size=64, intermediate_size=96,
         num_hidden_layers=8, num_attention_heads=4, num_key_value_heads=2,
         sliding_window=8),
+    'SolarOpen2LM': dict(
+        vocab_size=97, hidden_size=64, moe_intermediate_size=32,
+        num_hidden_layers=4, num_attention_heads=4, num_key_value_heads=2,
+        head_dim=16, linear_attn_config=dict(head_dim=16, num_heads=4),
+        n_routed_experts=4, router_experts=16, first_expert=4,
+        num_experts_per_tok=3),
 }
 PAGED_ONLY = sorted(set(TINY) - {'TransformerLM'})
 N_SLOTS, MAX_PROMPT, MAX_LEN = 2, 8, 16
@@ -215,7 +221,8 @@ def test_the_protocol_is_written_once():
     assert defined['_not_yet'] == ['_served.py']
     assert defined['check_serving'] == ['_served.py', 'transformer.py']
     assert defined['from_config'] == ['_served.py', 'deepseek_v3.py']
-    served = ['afmoe.py', 'olmo_hybrid.py', 'phi4flash.py', 'xing4.py']
+    served = ['afmoe.py', 'olmo_hybrid.py', 'phi4flash.py',
+              'solar_open2.py', 'xing4.py']
     for stub in ('init_kv_cache', 'prefill', 'decode_step', 'spec_verify',
                  'spec_verify_paged', 'kv_cache_specs'):
         assert defined[stub] == ['_served.py', 'transformer.py'], stub
@@ -224,6 +231,7 @@ def test_the_protocol_is_written_once():
         assert defined[member] == sorted(
             ['_served.py', 'transformer.py'] + served), member
     assert defined['paged_cache_bytes'] == [
-        '_served.py', 'olmo_hybrid.py', 'phi4flash.py', 'xing4.py']
+        '_served.py', 'olmo_hybrid.py', 'phi4flash.py', 'solar_open2.py',
+        'xing4.py']
     assert np.all([issubclass(getattr(models, name), ServedLM)
                    for name in list(TINY) + ['DeepseekV3LM']])
