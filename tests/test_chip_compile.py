@@ -117,6 +117,12 @@ _Q1 = ((32, 8, 64), BF16)
 _LEN = ((32,), I32)
 
 
+def _layer_norm(dtype, *shape):
+    """``x`` of ``shape`` in ``dtype`` under float32 ``gamma`` and
+    ``beta``, as the transformer family keeps them."""
+    return [(shape, dtype)] + [(shape[-1:], F32)] * 2
+
+
 def _slab(dtype):
     return [_Q1, ((32, 512, 8, 64), dtype), ((32, 512, 8, 64), dtype),
             _LEN]
@@ -372,6 +378,18 @@ CASES = {
     'layer_norm_fwd_bwd': (
         jax.grad(_sum_sq(ops.layer_norm), argnums=(0, 1, 2)),
         [((8, 1024, 512), BF16), ((512,), F32), ((512,), F32)]),
+    'layer_norm_fwd_bwd_train_cell': (
+        jax.grad(_sum_sq(ops.layer_norm), argnums=(0, 1, 2)),
+        _layer_norm(BF16, 8, 1024, 1024)),
+    # float32 rows: the widest tile the rule emits; 1,031 rows leave a
+    # ragged last tile
+    'layer_norm_fwd_bwd_f32_ragged': (
+        jax.grad(_sum_sq(ops.layer_norm), argnums=(0, 1, 2)),
+        _layer_norm(F32, 1031, 1024)),
+    'layer_norm_fwd_decode_96rows_d2560': (
+        ops.layer_norm, _layer_norm(BF16, 96, 2560)),
+    'layer_norm_fwd_decode_32rows_d1024': (
+        ops.layer_norm, _layer_norm(BF16, 32, 1024)),
     'cross_entropy_v32k_fwd_bwd': (
         jax.grad(lambda lg, y: jnp.sum(
             ops.softmax_cross_entropy(lg, y))),
@@ -401,6 +419,23 @@ def test_kernel_compiles_for_v5e(case, one_chip, mosaic):
         *args).compile()
     assert 'tpu_custom_call' in compiled.as_text(), (
         '%s compiled without its Mosaic kernel' % case)
+
+
+@pytest.mark.parametrize('case', ['layer_norm_fwd_bwd_train_cell',
+                                  'layer_norm_fwd_bwd_f32_ragged'])
+def test_layer_norm_gradient_holds_the_forward_kernel_alone(
+        case, one_chip, mosaic):
+    """The compiled gradient holds one Pallas call, ``layer_norm_fwd``
+    (the backward is XLA's on every platform), and where the rows end
+    in a ragged tile no pad of them beside it."""
+    fn, shapes = CASES[case]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    calls = [line for line in text.split('\n')
+             if 'custom-call(' in line and 'tpu_custom_call' in line]
+    assert len(calls) == 1 and 'layer_norm_fwd' in calls[0]
+    assert ' pad(' not in text
 
 
 @pytest.mark.parametrize('case', [

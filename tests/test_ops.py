@@ -787,30 +787,80 @@ class TestCrossEntropy:
             atol=1e-5, rtol=1e-5)
 
 
+#: ``x``'s shape and dtype (``gamma`` and ``beta`` are float32), then
+#: the forward's and the gradients' tolerance.  The two small float32
+#: cases are the ones these tests always had, at ``atol = rtol = tol``;
+#: the others (``scaled``) compare against the largest entry, ``atol =
+#: tol * max|want|``: a column sum over a thousand rows carries the
+#: rounding of its largest terms, and bfloat16 rounds a float32 result
+#: once on each side.  At 1,024 columns a tile is 512 rows and at 2,560
+#: it is 192 (float32: 200), so 520, 1,031 and 200 rows end in a ragged
+#: tile and 5 or 96 are one tile that is no multiple of the sublane
+#: packing
+_LN_CASES = [
+    ((3, 7, 32), jnp.float32, 1e-5, 1e-4, False),
+    ((5, 16), jnp.float32, 1e-5, 1e-4, False),
+    ((5, 1024), jnp.float32, 1e-4, 1e-4, True),
+    ((520, 1024), jnp.bfloat16, 2.0 ** -7, 2.0 ** -7, True),
+    ((1031, 1024), jnp.float32, 1e-4, 1e-4, True),
+    ((96, 2560), jnp.bfloat16, 2.0 ** -7, 2.0 ** -7, True),
+    ((200, 2560), jnp.bfloat16, 2.0 ** -7, 2.0 ** -7, True),
+]
+_ln = importlib.import_module('chainermn_tpu.ops.layer_norm')
+
+
+def _ln_operands(shape, dtype, key):
+    d = shape[-1]
+    return (_rand(shape, key).astype(dtype),
+            1.0 + 0.1 * _rand((d,), key + 1), 0.1 * _rand((d,), key + 2))
+
+
+def _ln_close(got, want, tol, scaled):
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    np.testing.assert_allclose(
+        got, want, rtol=tol,
+        atol=tol * np.abs(want).max() if scaled else tol)
+
+
+@pytest.mark.parametrize('shape,dtype,fwd_tol,grad_tol,scaled', _LN_CASES)
 class TestLayerNorm:
-    def test_matches_reference(self, mode):
-        x = _rand((3, 7, 32), 2)
-        gamma = 1.0 + 0.1 * _rand((32,), 3)
-        beta = 0.1 * _rand((32,), 4)
+    def test_matches_reference(self, mode, shape, dtype, fwd_tol,
+                               grad_tol, scaled):
+        x, gamma, beta = _ln_operands(shape, dtype, 2)
         out = ops.layer_norm(x, gamma, beta)
-        ref = ops.layer_norm_reference(x, gamma, beta)
-        np.testing.assert_allclose(out, ref, atol=1e-5, rtol=1e-5)
+        assert out.dtype == x.dtype and out.shape == x.shape
+        _ln_close(out, ops.layer_norm_reference(x, gamma, beta),
+                  fwd_tol, scaled)
 
-    def test_gradients(self, mode):
-        x = _rand((5, 16), 5)
-        gamma = 1.0 + 0.1 * _rand((16,), 6)
-        beta = 0.1 * _rand((16,), 7)
+    def test_gradients(self, mode, shape, dtype, fwd_tol, grad_tol,
+                       scaled):
+        x, gamma, beta = _ln_operands(shape, dtype, 5)
 
-        def f(x, g, b):
-            return jnp.sum(ops.layer_norm(x, g, b) ** 2)
+        def loss(ln):
+            return lambda x, g, b: jnp.sum(
+                ln(x, g, b).astype(jnp.float32) ** 2)
 
-        def f_ref(x, g, b):
-            return jnp.sum(ops.layer_norm_reference(x, g, b) ** 2)
-
-        got = jax.grad(f, argnums=(0, 1, 2))(x, gamma, beta)
-        want = jax.grad(f_ref, argnums=(0, 1, 2))(x, gamma, beta)
+        got = jax.grad(loss(ops.layer_norm), argnums=(0, 1, 2))(
+            x, gamma, beta)
+        want = jax.grad(loss(ops.layer_norm_reference),
+                        argnums=(0, 1, 2))(x, gamma, beta)
         for a, b in zip(got, want):
-            np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
+            assert a.dtype == b.dtype and a.shape == b.shape
+            _ln_close(a, b, grad_tol, scaled)
+
+
+@pytest.mark.parametrize('rows,d,dtype,tile', [
+    (8192, 1024, jnp.bfloat16, 512),     # gpt2m-train-1k: 16 grid steps
+    (32, 1024, jnp.bfloat16, 32),        # decode calls: one step
+    (96, 2560, jnp.bfloat16, 96),
+    (5, 1024, jnp.float32, 5),
+    (1031, 1024, jnp.float32, 512),
+    (8192, 2560, jnp.bfloat16, 192),
+    (4096, 2500, jnp.float32, 200),      # a row pads to 2,560 lanes
+    (64, 65536, jnp.bfloat16, 16),       # never under the packing
+])
+def test_layer_norm_tile_is_sized_in_bytes(rows, d, dtype, tile):
+    assert _ln._rows_tile(rows, d, dtype) == tile
 
 
 class TestFusedSGD:

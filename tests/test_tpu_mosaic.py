@@ -302,6 +302,39 @@ def test_layer_norm_mosaic():
         _close(a, b_, rtol=1e-2, name='ln ' + name)
 
 
+@pytest.mark.parametrize('rows,d', [
+    (8192, 1024),      # gpt2m-train-1k's call: 16 tiles of 512 rows
+    (1031, 1024),      # a ragged last tile of 7 rows
+    (96, 2560),        # a decode call: one tile
+])
+def test_layer_norm_mosaic_bf16_tiles(rows, d):
+    """bfloat16 rows under float32 ``gamma`` / ``beta``, as the
+    transformer family calls it.  Gradients are compared relative to
+    their largest entry: a column sum over 8,192 rows is no nearer."""
+    from chainermn_tpu import ops
+    from chainermn_tpu.ops.layer_norm import layer_norm_reference
+    rng = np.random.RandomState(1)
+    x = jnp.asarray(rng.randn(rows, d), jnp.bfloat16)
+    g = jnp.asarray(rng.randn(d), jnp.float32)
+    b = jnp.asarray(rng.randn(d), jnp.float32)
+    _close(jax.jit(ops.layer_norm)(x, g, b),
+           layer_norm_reference(x, g, b), name='ln fwd')
+
+    # float32 from the output on: what is compared is the arithmetic,
+    # not where bfloat16 rounds the loss's cotangent
+    def loss(ln):
+        return lambda x, g, b: (ln(x, g, b).astype(jnp.float32)
+                                ** 2).mean()
+
+    gp = jax.jit(jax.grad(loss(ops.layer_norm), argnums=(0, 1, 2)))(
+        x, g, b)
+    gr = jax.grad(loss(layer_norm_reference), argnums=(0, 1, 2))(x, g, b)
+    for name, a, b_ in zip(('dx', 'dg', 'db'), gp, gr):
+        scale = float(jnp.max(jnp.abs(b_.astype(jnp.float32))))
+        _close(a.astype(jnp.float32) / scale,
+               b_.astype(jnp.float32) / scale, name='ln ' + name)
+
+
 def test_cross_entropy_mosaic():
     from chainermn_tpu import ops
     from chainermn_tpu.ops.cross_entropy import (
